@@ -31,7 +31,9 @@ import threading
 import time
 
 os.environ.setdefault("RAY_TPU_SKIP_TPU_DETECTION", "1")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# CPU-only by contract: host counts of the engine and serve plumbing on a
+# toy model; the engine on the chip is chip_smoke.py's serve phase.
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax.numpy as jnp
 

@@ -1,25 +1,16 @@
-"""Version-portability shims for the handful of jax APIs that moved
-between jax 0.4.x and 0.5+.
-
-The model/parallelism code targets the modern ambient-mesh world
-(``jax.set_mesh`` + ``jax.shard_map`` + abstract meshes). Older jax
-(< 0.5) spells these ``jax.experimental.shard_map.shard_map`` (with
-``check_rep`` instead of ``check_vma``) and has no ambient abstract
-mesh — only the ``with mesh:`` physical-mesh context. These wrappers
-pick whichever spelling the installed jax provides so importing the
-library never raises AttributeError on an older jax; call sites that
-genuinely need ``jax.set_mesh`` semantics should gate on
-:data:`HAS_SET_MESH` (tests skip via the same flag).
+"""Thin helpers over the ambient-mesh API of the installed jax (0.9):
+``jax.set_mesh``, ``jax.shard_map`` and
+``jax.sharding.get_abstract_mesh`` are used directly; what lives here is
+the ``None``-mesh convenience the engine and the kernels share, the rule
+for when a pallas kernel interprets, and the probe for multi-process
+execution on the CPU backend.
 """
 
 from __future__ import annotations
 
-import jax
+import contextlib
 
-#: True when this jax has the ambient-mesh API (jax.set_mesh /
-#: jax.sharding.get_abstract_mesh). Tests that drive models under
-#: ``with jax.set_mesh(...)`` skip when False.
-HAS_SET_MESH = hasattr(jax, "set_mesh")
+import jax
 
 _CPU_MULTIPROCESS: "bool | None" = None
 
@@ -93,85 +84,23 @@ def has_cpu_multiprocess(timeout_s: float = 120.0) -> bool:
 
 
 def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh for the
-    jitted calls inside — the TP path the LLM engine / llm.py docs
-    reference. ``mesh=None`` is a no-op (single-chip).
-
-    New jax: ``jax.set_mesh(mesh)`` (a context manager since 0.7; on
-    the in-between releases where it sets globally we fall back to the
-    ``jax.sharding.use_mesh`` spelling). Old jax (< 0.5, no ambient
-    API): the ``with mesh:`` physical-mesh context, which is exactly
-    what pjit-era code used — so engine code written against
-    ``jax_compat.set_mesh`` imports AND runs clean on jax 0.4.x.
-    """
-    import contextlib
-
+    """``jax.set_mesh(mesh)`` as a context manager; ``mesh=None`` is a
+    no-op (single-chip)."""
     if mesh is None:
         return contextlib.nullcontext()
-    new = getattr(jax, "set_mesh", None)
-    if new is not None:
-        ctx = new(mesh)
-        if hasattr(ctx, "__enter__"):
-            return ctx
-        use_mesh = getattr(jax.sharding, "use_mesh", None)
-        if use_mesh is not None:
-            return use_mesh(mesh)
-        return contextlib.nullcontext()  # already installed globally
-
-    @contextlib.contextmanager
-    def _physical(mesh):
-        with mesh:
-            yield mesh
-
-    return _physical(mesh)
+    return jax.set_mesh(mesh)
 
 
 def ambient_mesh():
-    """The ambient mesh, or None when none is set (or unknowable).
-
-    New jax: ``jax.sharding.get_abstract_mesh()`` (empty mesh → None).
-    Old jax: the ``with mesh:`` physical-mesh context, which is what
-    pjit-era code used as its ambient mesh.
-    """
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        mesh = getter()
-        return None if mesh.empty else mesh
-    try:
-        from jax.interpreters import pxla
-
-        mesh = pxla.thread_resources.env.physical_mesh
-        return None if mesh.empty else mesh
-    except Exception:  # noqa: BLE001 — no context machinery at all
-        return None
+    """The ambient (abstract) mesh, or None when none is set."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
-def shard_map(f, *, mesh=None, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` (new) or the jax.experimental spelling (old).
-
-    ``mesh=None`` means "use the ambient mesh": passed through on new
-    jax, resolved via :func:`ambient_mesh` for the legacy API (which
-    requires an explicit mesh). ``check_vma`` maps to the legacy
-    ``check_rep``.
-    """
-    new = getattr(jax, "shard_map", None)
-    if new is not None:
-        kwargs = {"in_specs": in_specs, "out_specs": out_specs}
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return new(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    if mesh is None:
-        mesh = ambient_mesh()
-        if mesh is None:
-            raise RuntimeError(
-                "shard_map needs a mesh: this jax has no ambient-mesh "
-                "API (jax.set_mesh) — pass mesh= explicitly, enter a "
-                "`with mesh:` context, or upgrade jax")
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    if check_vma is not None:
-        kwargs["check_rep"] = check_vma
-    return legacy(f, **kwargs)
+def interpret_kernels() -> bool:
+    """Whether a pallas kernel that was not told runs in interpret mode:
+    only where the platform is the CPU (tests, rehearsals). Every other
+    backend, a TPU or one nobody has heard of, gets the compiled kernel,
+    so a platform it cannot run on fails in its compiler and never
+    quietly interprets."""
+    return jax.default_backend() == "cpu"

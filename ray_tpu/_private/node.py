@@ -292,13 +292,10 @@ def _install_daemon_recorder(role: str, executor) -> "object":
 
 
 def default_resources() -> dict:
-    resources = {"CPU": float(os.cpu_count() or 1)}
-    try:
-        from ray_tpu._private import accelerators
+    from ray_tpu._private import accelerators
 
-        resources.update(accelerators.detect_resources())
-    except Exception:  # noqa: BLE001 — detection is best-effort
-        pass
+    resources = {"CPU": float(os.cpu_count() or 1)}
+    resources.update(accelerators.detect_resources())
     return resources
 
 

@@ -37,6 +37,7 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable
 
+from ray_tpu._private import accelerators
 from ray_tpu._private import perf_plane as perf
 from ray_tpu._private import serialization
 from ray_tpu._private.ids import ObjectID
@@ -986,7 +987,8 @@ class _DaemonActor:
 
     def __init__(self, cls_blob: bytes, args_blob: bytes,
                  runtime_env: dict | None, max_concurrency: int,
-                 extra_env: dict | None, allow_tpu: bool,
+                 extra_env: dict | None,
+                 tpu_chips: "list[int] | None",
                  sys_path: list | None, worker=None):
         from ray_tpu._private.worker_pool import PoolWorker
 
@@ -996,7 +998,7 @@ class _DaemonActor:
         # worker_pool.h "Starts a number of workers ahead of time") —
         # creation then skips the fork on the critical path.
         self._worker = worker if worker is not None else PoolWorker(
-            -1, extra_env=extra_env, allow_tpu=allow_tpu)
+            -1, extra_env=extra_env, tpu_chips=tpu_chips)
         self._mux = None
         reply = self._worker.request(
             ("actor_new", cls_blob, args_blob, runtime_env,
@@ -1143,6 +1145,10 @@ class NodeExecutorService:
         # spill-protected for _SHM_ARG_GRACE_S.
         self._shm_out_stamp: dict[bytes, float] = {}
         self._resources = dict(resources or {})
+        # One process per chip: this daemon's own threads (in-daemon
+        # TPU tasks) or its actor children, never both.
+        self._chip_leases = accelerators.ChipLeases(
+            int(self._resources.get("TPU", 0)))
         self._running_lock = lock_witness.Lock(
             "node_executor.NodeExecutorService.running")
         self._running: dict[str, dict[str, float]] = {}
@@ -3018,29 +3024,29 @@ class NodeExecutorService:
             extra_env = {}
             if client_addr:
                 extra_env["RAY_TPU_DRIVER_CLIENT_ADDR"] = client_addr
-            # TPU actors own the accelerator from their process. Whole-
-            # chip demands are safe: admission then rejects TPU tasks on
-            # this node (the daemon process would contend for the same
-            # runtime). Fractional TPU sharing across processes is the
-            # user's risk — same caveat as the reference's fractional
-            # GPUs (reference: TPU_VISIBLE_CHIPS isolation, tpu.py:30).
-            allow_tpu = any(k.startswith("TPU") for k in demand)
+            # A TPU actor owns whole chips from its own process, seeing
+            # only those leased to it (reference: TPU_VISIBLE_CHIPS
+            # isolation, tpu.py:30); a chip is never shared, so a
+            # fractional demand still takes a whole one.
+            tpu_chips = None
+            n_chips = accelerators.tpu_chip_demand(
+                demand, self._chip_leases.num_chips)
             worker = None
-            if not allow_tpu:
+            if n_chips:
+                tpu_chips = self._chip_leases.lease(
+                    actor_key, n_chips, "actor on this node")
+            else:
                 worker = self._take_standby(extra_env)
             actor = _DaemonActor(cls_blob, init_blob, runtime_env,
-                                 max_concurrency, extra_env, allow_tpu,
+                                 max_concurrency, extra_env, tpu_chips,
                                  sys_path, worker=worker)
-        except _ActorNewError as exc:
-            with self._running_lock:
-                self._running.pop(token, None)
-            self._notify_load()
-            return ("err", exc.blob)
         except BaseException as exc:  # noqa: BLE001 — shipped to driver
+            self._chip_leases.release(actor_key)
             with self._running_lock:
                 self._running.pop(token, None)
             self._notify_load()
-            return ("err", _exc_blob(exc))
+            return ("err", exc.blob if isinstance(exc, _ActorNewError)
+                    else _exc_blob(exc))
         actor.owner = client_addr  # owner-death sweep kills orphans
         with self._actors_lock:
             self._actors[actor_key] = actor
@@ -3191,8 +3197,7 @@ class NodeExecutorService:
                                 self._standby_target:
                             return
                     try:
-                        worker = PoolWorker(-1, extra_env=dict(key),
-                                            allow_tpu=False)
+                        worker = PoolWorker(-1, extra_env=dict(key))
                     except Exception:  # noqa: BLE001 — next take forks
                         return
                     with self._standby_lock:
@@ -3219,6 +3224,7 @@ class NodeExecutorService:
         if actor is None:
             return False
         actor.kill()
+        self._chip_leases.release(actor_key)
         return True
 
     def _packed_to_blob(self, id_bytes: bytes, packed: tuple):
@@ -3911,14 +3917,17 @@ class NodeExecutorService:
              runtime_env, resources, task_token=None,
              client_addr=None, trace=None, trace_stages=None) -> list:
         if any(k.startswith("TPU") for k in resources):
-            # TPU tasks run in the daemon process: it owns this node's
-            # JAX/TPU runtime (pool workers are pinned to CPU). Each
+            # TPU tasks run in the daemon process, which must then be
+            # the one owner of this node's chips (pool workers are
+            # pinned to CPU; with a chip leased to an actor child this
+            # raises ChipOwnershipError, typed, to the caller). Each
             # runs on its own dispatch thread (mux server), so a long
             # TPU task never blocks the connection loop; concurrency
             # between TPU tasks is bounded by admission (TPU resource
             # units), and JAX dispatch itself is thread-safe — a mutual-
             # exclusion lock here would deadlock nested TPU-task
             # submission (outer holds it while blocked in get()).
+            self._chip_leases.claim_in_process("a TPU task on this node")
             if perf.PERF_ON:
                 # In-daemon run: this dispatch thread IS the executor,
                 # so thread_time here is the task's real cpu-seconds.

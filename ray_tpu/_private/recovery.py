@@ -238,8 +238,19 @@ class NodeHealthMonitor:
                     self._gcs.heartbeat(record.node_id)
 
     def _check_loop(self) -> None:
+        last_round = time.monotonic()
         while not self._stop.wait(self._period):
             now = time.monotonic()
+            stalled = now - last_round > self._period * self._threshold
+            last_round = now
+            if stalled:
+                # This round came as late as a heartbeat may be stale:
+                # the whole process stood still (a sibling process
+                # initialising a TPU chip freezes the machine for
+                # seconds at a time), the beater with it, and stale
+                # heartbeats then prove nothing. It beats twice a
+                # period, so the next round judges fresh ones.
+                continue
             for record in self._gcs.list_nodes():
                 if not record.alive:
                     continue

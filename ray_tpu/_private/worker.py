@@ -59,6 +59,7 @@ from ray_tpu._private.actor_runtime import LocalActor, _ActorCall
 from ray_tpu.util import tracing
 from ray_tpu.exceptions import (
     ActorDiedError,
+    ChipOwnershipError,
     GetTimeoutError,
     SystemOverloadedError,
     TaskCancelledError,
@@ -800,6 +801,9 @@ class Runtime:
             {k: v for k, v in detected.items() if k not in head_resources})
         if resources:
             head_resources.update({k: float(v) for k, v in resources.items()})
+        # One process per chip: this driver's threads, or leased children.
+        self.chip_leases = accelerators.ChipLeases(
+            int(head_resources.get("TPU", 0)))
         self.head_node_id = self.add_node(head_resources, labels={"node_type": "head"})
         self.gcs.register_job(JobRecord(self.job_id))
 
@@ -2598,6 +2602,9 @@ class Runtime:
             else:
                 ran_on_pool = False
             if not ran_on_pool:
+                if any(k.startswith("TPU") for k in spec.resources):
+                    self.chip_leases.claim_in_process(
+                        f"task {spec.name!r}")
                 if spec.runtime_env:
                     _warn_runtime_env_ignored(
                         f"task {spec.name!r} runs in-thread")
@@ -2721,7 +2728,8 @@ class Runtime:
         boundary. Returns False (caller falls back to in-thread execution)
         when the function/args cannot cross it (unpicklable closures) or
         the task needs accelerator resources (pool workers are CPU
-        processes; the driver's process owns the TPU-backed JAX).
+        processes; a TPU task runs on this process's own JAX, which
+        must then be the one owner of the host's chips).
         """
         from ray_tpu._private.worker_pool import _RemoteTaskError
 
@@ -4090,6 +4098,7 @@ class Runtime:
                 if name is not None:
                     self._unpublish_named_actor(ns, name)
                 self._release_actor_lease(aid)
+                self.chip_leases.release(aid)
 
             def on_restart(aid):
                 actor = self._actors.get(aid)
@@ -4108,6 +4117,24 @@ class Runtime:
             if node_id is not None and serializable:
                 with self._remote_nodes_lock:
                     remote_handle = self._remote_nodes.get(node_id)
+            tpu_chips = None
+            n_chips = accelerators.tpu_chip_demand(
+                resources, self.chip_leases.num_chips)
+            if n_chips and remote_handle is None:
+                # The actor computes on THIS host: in its own process
+                # on chips leased to it, or on this process's threads.
+                try:
+                    if process:
+                        tpu_chips = self.chip_leases.lease(
+                            actor_id, n_chips,
+                            f"process actor {cls.__name__}")
+                    else:
+                        self.chip_leases.claim_in_process(
+                            f"thread actor {cls.__name__}")
+                except ChipOwnershipError as exc:
+                    self.store.put_error(creation_rid, exc)
+                    on_death(actor_id, repr(exc))
+                    return
             if remote_handle is not None:
                 from ray_tpu._private.remote_actor import RemoteActor
 
@@ -4140,7 +4167,8 @@ class Runtime:
                     max_concurrency=max_concurrency,
                     creation_return_id=creation_rid, on_death=on_death,
                     on_restart=on_restart,
-                    runtime_env=self._package_runtime_env(runtime_env))
+                    runtime_env=self._package_runtime_env(runtime_env),
+                    tpu_chips=tpu_chips)
             else:
                 if runtime_env:
                     _warn_runtime_env_ignored(
@@ -4224,7 +4252,13 @@ class Runtime:
                             self._actors_changed.wait(0.25)
                             actor = self._actors.get(actor_id)
                 if actor is None:
-                    err = ActorDiedError(actor_id, "actor failed to start")
+                    # The creation ref carries the typed error; calls
+                    # at least say why (e.g. a ChipOwnershipError).
+                    rec = self.gcs.get_actor(actor_id)
+                    cause = rec.death_cause if rec is not None else None
+                    err = ActorDiedError(
+                        actor_id, "actor failed to start"
+                        + (f": {cause}" if cause else ""))
                     for rid in call.return_ids:
                         self.store.put_error(rid, err)
                     call = None  # see below

@@ -127,6 +127,13 @@ class CacheExhaustedError(SystemOverloadedError):
                  self.retry_after_s))
 
 
+class ChipOwnershipError(RayTpuError):
+    """TPU work was asked of a process that cannot have the chip: a chip
+    belongs to one process at a time, and it is already held by the host
+    process or leased to another child. Never answered by running the
+    work on a CPU instead."""
+
+
 class TaskCancelledError(RayTpuError):
     """The task was cancelled before or during execution."""
 

@@ -25,9 +25,16 @@ capability of the TPU build. Design per the pallas guide
 - causal programs stop their k loop at the diagonal (work ∝ L²/2), and
   the dk/dv kernel starts its q loop there.
 
-On CPU (tests / virtual mesh) the kernels run in interpret mode
-automatically. ``_blockwise_reference`` remains as the correctness
-oracle for tests.
+On the CPU platform (tests / virtual mesh) the kernels run in interpret
+mode; on every other backend they are compiled, and a test or a
+rehearsal that wants interpret mode elsewhere passes ``interpret=True``.
+``_blockwise_reference`` remains as the correctness oracle for tests.
+
+The k/v blocks of the forward and dq kernels, and the q/o/dO/lse blocks
+of the dk/dv kernel, are whole-sequence blocks held in VMEM, so the
+sequence length a call can take is bounded: ``check_vmem_fit`` refuses a
+shape beyond the bound at trace time (interpret mode never notices, the
+chip's compiler says RESOURCE_EXHAUSTED).
 """
 
 from __future__ import annotations
@@ -38,16 +45,51 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
-try:  # TPU backend only; tests run interpret mode on CPU.
-    from jax.experimental.pallas import tpu as pltpu
-
-    _MEMSPACE = pltpu.VMEM
-except Exception:  # pragma: no cover - pallas TPU backend unavailable
-    pltpu = None
-    _MEMSPACE = None
+from ray_tpu._private import jax_compat
 
 NEG_INF = -1e30
+
+# Mosaic's scoped-VMEM limit for one kernel on a v5e (libtpu 0.0.34).
+VMEM_LIMIT_BYTES = 16 * 2 ** 20
+_TILE_BYTES = 2 ** 19  # the q/o (or k/v, dk/dv) tiles beside the blocks
+
+
+def check_vmem_fit(seq_len: int, head_dim: int, dtype,
+                   backward: bool = False) -> None:
+    """Raise ValueError for a shape whose whole-sequence blocks cannot
+    stay resident under ``VMEM_LIMIT_BYTES``.
+
+    Forward (and dq): the k and v blocks. Backward (dk/dv): the q, o
+    and dO blocks with the head padded to a full 128-lane tile, and the
+    lse column, which pads to a lane tile too. All are double-buffered.
+    This counts the kernel's own buffers; XLA also places operands in
+    VMEM as it sees fit, so the chip's compiler draws the real line a
+    little to either side. Checked by deviceless compiles for a v5e in
+    bf16 at head widths 64 and 128 (tests/test_chip_compile.py holds
+    both sides): what it refuses at 4 query groups per kv head fails to
+    compile, L=8192 at 1 or 2 groups is refused though it would still
+    compile, L=6144 at 4 groups is admitted and the compiler refuses it.
+    Streaming these blocks lifts the bound (ROADMAP S2).
+    """
+    itemsize = jnp.dtype(dtype).itemsize
+    if backward:
+        row = 2 * (3 * max(head_dim, 128) * itemsize + 128 * 4)
+        what = "q, o, dO and lse blocks of the dk/dv kernel"
+    else:
+        row = 2 * 2 * head_dim * itemsize
+        what = "k and v blocks"
+    need = seq_len * row + _TILE_BYTES
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"flash_attention: sequence length {seq_len} at head width "
+            f"{head_dim} ({jnp.dtype(dtype).name}) keeps about "
+            f"{need / 2 ** 20:.1f} MiB of whole-sequence {what} "
+            f"resident, over the {VMEM_LIMIT_BYTES // 2 ** 20} MiB VMEM "
+            f"limit of one kernel; the longest sequence this shape can "
+            f"take is {(VMEM_LIMIT_BYTES - _TILE_BYTES) // row}")
 
 
 # ------------------------------------------------------------------ forward
@@ -108,9 +150,7 @@ def _fit_block(requested: int, seq_len: int) -> int:
 
 
 def _specs(shapes_and_maps, interpret):
-    kwargs = {}
-    if _MEMSPACE is not None and not interpret:
-        kwargs["memory_space"] = _MEMSPACE
+    kwargs = {} if interpret else {"memory_space": pltpu.VMEM}
     return [pl.BlockSpec(shape, index_map, **kwargs)
             for shape, index_map in shapes_and_maps]
 
@@ -326,6 +366,8 @@ def _flash_core(q, k, v, causal, block_q, block_k, interpret):
 
 
 def _core_fwd(q, k, v, causal, block_q, block_k, interpret):
+    # Only a differentiated call gets here.
+    check_vmem_fit(q.shape[1], q.shape[2], q.dtype, backward=True)
     o, lse = _flash_fwd(q, k, v, causal, block_q, block_k, interpret)
     return o, (q, k, v, o, lse)
 
@@ -352,20 +394,14 @@ def flash_attention_gspmd(q, k, v, causal: bool = True,
     ambient mesh — or a mesh whose dp/fsdp/tp axes are all singleton —
     this is exactly ``flash_attention``.
     """
-    import functools
-
-    from ray_tpu._private import jax_compat
-
     mesh = jax_compat.ambient_mesh()
     if mesh is None or all(dict(mesh.shape).get(a, 1) == 1
                            for a in ("dp", "fsdp", "tp")):
         return flash_attention(q, k, v, causal, block_q, block_k,
                                interpret)
-    from jax.sharding import PartitionSpec as P
-
     spec = P(("dp", "fsdp"), None, "tp", None)
 
-    @functools.partial(jax_compat.shard_map,
+    @functools.partial(jax.shard_map,
                        in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     def inner(q, k, v):
@@ -385,12 +421,13 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
     and capacity exactly where flash is supposed to save it); kv
     gradients from the groups accumulate through autodiff.
     Differentiable via fused pallas backward kernels. ``interpret=None``
-    auto-selects interpret mode off-TPU.
+    interprets on the CPU platform only.
     """
     b, l, h, d = q.shape
     kvh = k.shape[2]
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = jax_compat.interpret_kernels()
+    check_vmem_fit(l, d, q.dtype)
     kt = k.transpose(0, 2, 1, 3).reshape(b * kvh, l, d)
     vt = v.transpose(0, 2, 1, 3).reshape(b * kvh, l, d)
     if kvh == h:

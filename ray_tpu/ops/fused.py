@@ -14,14 +14,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _MEMSPACE = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _MEMSPACE = None
+from ray_tpu._private import jax_compat
 
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
@@ -39,9 +34,7 @@ def _rmsnorm_fwd_impl(x2d, scale, eps: float, interpret: bool):
     max_rows = max(1, (512 * 1024) // max(d, 1))
     while block_rows > max_rows and block_rows % 2 == 0:
         block_rows //= 2
-    spec_kwargs = {}
-    if _MEMSPACE is not None and not interpret:
-        spec_kwargs["memory_space"] = _MEMSPACE
+    spec_kwargs = {} if interpret else {"memory_space": pltpu.VMEM}
     return pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=(rows // block_rows,),
@@ -86,9 +79,10 @@ _rmsnorm_core.defvjp(_rms_fwd, _rms_bwd)
 
 
 def rms_norm(x, scale, eps: float = 1e-5, interpret: bool | None = None):
-    """Fused RMSNorm over the last axis. x: [..., D], scale: [D]."""
+    """Fused RMSNorm over the last axis. x: [..., D], scale: [D].
+    ``interpret=None`` interprets on the CPU platform only."""
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu",)
+        interpret = jax_compat.interpret_kernels()
     shape = x.shape
     x2d = x.reshape(-1, shape[-1])
     out = _rmsnorm_core(x2d, scale, eps, interpret)

@@ -79,14 +79,13 @@ def build_mesh(config: MeshConfig | None = None,
     shape = tuple(config.axis_sizes[a] for a in AXIS_ORDER)
     names = tuple(axis_names or AXIS_ORDER)
     if devices and devices[0].platform == "tpu":
-        try:
-            from jax.experimental import mesh_utils
+        # A layout the physical topology cannot give is an error: a
+        # naive ordering would run, with collectives off the fast links.
+        from jax.experimental import mesh_utils
 
-            mesh_devices = mesh_utils.create_device_mesh(shape, devices=devices)
-            return Mesh(mesh_devices, names)
-        except Exception:
-            pass  # fall back to naive ordering
-    mesh_devices = np.array(devices).reshape(shape)
+        mesh_devices = mesh_utils.create_device_mesh(shape, devices=devices)
+    else:
+        mesh_devices = np.array(devices).reshape(shape)
     return Mesh(mesh_devices, names)
 
 

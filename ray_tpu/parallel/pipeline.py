@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu._private import jax_compat
 
 
 def split_stages(stacked: Any, num_stages: int) -> Any:
@@ -79,7 +78,7 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array, *,
     x_spec = P(batch_axes)
     out_specs = (x_spec, P()) if with_aux else x_spec
 
-    @functools.partial(jax_compat.shard_map,
+    @functools.partial(jax.shard_map,
                        in_specs=(param_specs, x_spec),
                        out_specs=out_specs, check_vma=False)
     def run(local_params, x_local):

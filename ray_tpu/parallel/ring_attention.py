@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu._private import jax_compat
 
 
 def _block_attention(q, k, v, bias, scale):
@@ -107,7 +106,7 @@ def ring_attention_sharded(q: jax.Array, k: jax.Array, v: jax.Array,
     spec = P(("dp", "fsdp"), "sp", "tp", None)
 
     @functools.partial(
-        jax_compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)
     def inner(q, k, v):
@@ -127,7 +126,7 @@ def ring_attention_gspmd(q: jax.Array, k: jax.Array, v: jax.Array,
     """
     spec = P(("dp", "fsdp"), "sp", "tp", None)
 
-    @functools.partial(jax_compat.shard_map,
+    @functools.partial(jax.shard_map,
                        in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     def inner(q, k, v):

@@ -64,8 +64,25 @@ def create_train_state(params: Any, optimizer: optax.GradientTransformation,
                        mesh: Mesh | None = None,
                        logical_axes: Any | None = None) -> TrainState:
     """Build a TrainState; with a mesh, params (and hence the optimizer
-    moments, which are derived from them) are placed per the rules."""
-    if mesh is not None:
+    moments, which are derived from them) are placed per the rules.
+
+    ``params`` is a pytree of arrays, or a zero-argument function that
+    builds one. The function is jitted with the rule shardings as its
+    ``out_shardings``, so the parameters are sharded from birth: a model
+    that does not fit one device never exists unsharded on the default
+    device, and no second copy is made. A pytree that already exists is
+    copied (see below), which holds the model twice while it lasts.
+    """
+    if callable(params):
+        init = params
+        if mesh is None:
+            params = jax.jit(init)()
+        else:
+            if logical_axes is None:
+                logical_axes = infer_param_logical_axes(jax.eval_shape(init))
+            params = jax.jit(
+                init, out_shardings=tree_shardings(mesh, logical_axes))()
+    elif mesh is not None:
         if logical_axes is None:
             logical_axes = infer_param_logical_axes(params)
         shardings = tree_shardings(mesh, logical_axes)

@@ -82,8 +82,7 @@ class LLMEngine:
         from ray_tpu.models import llama
 
         self.config = config or llama.LlamaConfig.tiny()
-        self.params = params if params is not None else llama.init_params(
-            self.config, jax.random.PRNGKey(seed))
+        self.params = paged_model.serving_params(self.config, params, seed)
         self.max_batch = int(max_batch_size)
         self.max_len = int(max_seq_len or self.config.max_seq_len)
         self.block_size = int(block_size or GLOBAL_CONFIG.llm_block_size)

@@ -36,6 +36,30 @@ from jax import lax
 from ray_tpu.models import llama
 
 
+def serving_params(config, params: "dict | None" = None,
+                   seed: int = 0) -> dict:
+    """The weights an engine serves, held in the compute dtype.
+
+    ``params=None`` builds them here from ``PRNGKey(seed)``: weights at
+    a real width are made (or loaded) inside the replica, not pickled
+    through ``bind()``. The cast runs in the same jit as the
+    initialisation, so the float32 model never exists whole; every use
+    in the step functions casts to ``config.dtype`` anyway, so holding
+    that dtype changes no result and halves what each step reads.
+    A caller that wants a reference rebuilds the same weights by calling
+    this with the same seed.
+    """
+    def cast(tree):
+        return jax.tree.map(lambda x: x.astype(config.dtype), tree)
+
+    if params is None:
+        return jax.jit(lambda key: cast(llama.init_params(config, key)))(
+            jax.random.PRNGKey(seed))
+    if all(x.dtype == config.dtype for x in jax.tree.leaves(params)):
+        return params
+    return jax.jit(cast)(params)
+
+
 def _paged_attention_block(layer: dict, x: jax.Array,
                            positions: jax.Array, pk: jax.Array,
                            pv: jax.Array, block_tables: jax.Array,

@@ -25,7 +25,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu._private.jax_compat import shard_map
 
 # In-SPMD primitives (layer 1).
 psum = lax.psum
@@ -63,7 +62,7 @@ def device_allreduce(x, mesh: Mesh | None = None, axis_name: str = "x"):
 
     @jax.jit
     def fn(x):
-        return shard_map(
+        return jax.shard_map(
             lambda s: psum(s, axis_name), mesh=mesh,
             in_specs=P(axis_name), out_specs=P())(x)
 
@@ -78,7 +77,7 @@ def device_allgather(x, mesh: Mesh | None = None, axis_name: str = "x"):
     def fn(x):
         # all_gather's replication isn't statically inferred → check_vma
         # off for this one wrapper.
-        return shard_map(
+        return jax.shard_map(
             lambda s: all_gather(s, axis_name, axis=0, tiled=True),
             mesh=mesh, in_specs=P(axis_name), out_specs=P(),
             check_vma=False)(x)
@@ -94,7 +93,7 @@ def device_reducescatter(x, mesh: Mesh | None = None,
 
     @jax.jit
     def fn(x):
-        return shard_map(
+        return jax.shard_map(
             lambda s: lax.psum_scatter(
                 s[0], axis_name, scatter_dimension=0, tiled=True)[None],
             mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name))(x)
@@ -112,7 +111,7 @@ def device_ring_shift(x, mesh: Mesh | None = None, axis_name: str = "x",
 
     @jax.jit
     def fn(x):
-        return shard_map(
+        return jax.shard_map(
             lambda s: ppermute(s, axis_name, perm), mesh=mesh,
             in_specs=P(axis_name), out_specs=P(axis_name))(x)
 

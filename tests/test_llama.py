@@ -13,11 +13,6 @@ from ray_tpu.parallel.train_step import (
     default_optimizer,
     shard_batch,
 )
-from ray_tpu._private.jax_compat import HAS_SET_MESH
-
-requires_ambient_mesh = pytest.mark.skipif(
-    not HAS_SET_MESH,
-    reason="needs jax.set_mesh (ambient-mesh API, jax>=0.5)")
 
 
 @pytest.fixture(scope="module")
@@ -60,7 +55,6 @@ def test_loss_finite(tiny_cfg, tiny_params):
         tiny_cfg.vocab_size)
 
 
-@requires_ambient_mesh
 def test_sharded_train_step_dp_fsdp_tp(tiny_cfg, tiny_params):
     """Full GSPMD training step over dp×fsdp×tp; loss must decrease."""
     mesh = build_mesh(MeshConfig(dp=2, fsdp=2, tp=2))
@@ -89,7 +83,6 @@ def test_sharded_train_step_dp_fsdp_tp(tiny_cfg, tiny_params):
         assert all(hasattr(p, "sharding") for p in flat)
 
 
-@requires_ambient_mesh
 def test_ring_attention_model_matches_plain(tiny_params):
     """config.attention='ring' over sp must match plain attention logits.
 

@@ -384,9 +384,8 @@ def test_server_fallback_equivalence(paged_engine):
 
 
 def test_mesh_context_portable(paged_engine):
-    """jax_compat.set_mesh: the engine TP path's version-portable
-    ambient-mesh context — on jax 0.4.x it is the `with mesh:`
-    physical-mesh context, and None is a no-op."""
+    """jax_compat.set_mesh: the engine TP path's ambient-mesh context;
+    None is a no-op."""
     import numpy as np
     from jax.sharding import Mesh
 

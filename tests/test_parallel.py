@@ -17,11 +17,6 @@ from ray_tpu.parallel.sharding import (
     named_sharding,
     shard_params,
 )
-from ray_tpu._private.jax_compat import HAS_SET_MESH
-
-requires_ambient_mesh = pytest.mark.skipif(
-    not HAS_SET_MESH,
-    reason="needs jax.set_mesh (ambient-mesh API, jax>=0.5)")
 
 
 def test_mesh_config_wildcard():
@@ -108,7 +103,6 @@ def test_ring_attention_grads_flow():
                                atol=2e-4, rtol=2e-4)
 
 
-@requires_ambient_mesh
 @pytest.mark.parametrize("causal", [True, False])
 def test_ulysses_attention_matches_plain(causal):
     import functools

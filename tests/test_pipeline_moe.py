@@ -16,7 +16,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu.models import llama
 from ray_tpu.models.moe import init_moe_params, moe_mlp
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-from ray_tpu._private.jax_compat import HAS_SET_MESH
 from ray_tpu.parallel.pipeline import (
     llama_pipeline_forward,
     merge_stages,
@@ -25,18 +24,12 @@ from ray_tpu.parallel.pipeline import (
 )
 
 
-requires_ambient_mesh = pytest.mark.skipif(
-    not HAS_SET_MESH,
-    reason="needs jax.set_mesh (ambient-mesh API, jax>=0.5)")
-
-
 def _tiny(num_experts=0):
     return dataclasses.replace(
         llama.LlamaConfig.tiny(), dtype=jnp.float32,
         num_experts=num_experts)
 
 
-@requires_ambient_mesh
 def test_pipeline_stage_count_must_match_mesh():
     mesh = build_mesh(MeshConfig(pp=2, dp=4))
     w = jnp.ones((8, 4, 4))
@@ -72,7 +65,6 @@ def test_split_merge_stages_roundtrip():
         split_stages(params, 3)
 
 
-@requires_ambient_mesh
 def test_pipeline_apply_matches_sequential():
     """Generic pipeline over a toy stage function == sequential apply."""
     mesh = build_mesh(MeshConfig(pp=4, dp=2))
@@ -100,7 +92,6 @@ def test_pipeline_apply_matches_sequential():
                                atol=1e-5, rtol=1e-5)
 
 
-@requires_ambient_mesh
 def test_llama_pipeline_forward_matches_sequential():
     cfg = _tiny()
     mesh = build_mesh(MeshConfig(pp=2, dp=2, tp=2))
@@ -115,7 +106,6 @@ def test_llama_pipeline_forward_matches_sequential():
                                atol=2e-4, rtol=2e-4)
 
 
-@requires_ambient_mesh
 def test_pipeline_is_differentiable():
     cfg = _tiny()
     mesh = build_mesh(MeshConfig(pp=2, dp=2, tp=2))
@@ -166,7 +156,6 @@ def test_moe_capacity_drops_tokens():
                                   np.zeros_like(np.asarray(out[0, 2:])))
 
 
-@requires_ambient_mesh
 def test_moe_ep_sharded_matches_single_device():
     cfg = _tiny(num_experts=4)
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
@@ -189,7 +178,6 @@ def test_moe_ep_sharded_matches_single_device():
     assert float(aux) == pytest.approx(float(aux_single), rel=1e-4)
 
 
-@requires_ambient_mesh
 def test_moe_train_step_learns():
     """A full train step over dp x ep decreases loss on a tiny corpus."""
     from ray_tpu.parallel.train_step import (
@@ -221,7 +209,6 @@ def test_moe_train_step_learns():
         assert float(m["loss"]) < float(m0["loss"])
 
 
-@requires_ambient_mesh
 def test_llama_pipeline_tp_inside_stage_matches_sequential():
     """pp x tp composition (VERDICT r2 #8): Megatron-style tensor
     parallelism inside each pipeline stage must reproduce the plain
@@ -240,7 +227,6 @@ def test_llama_pipeline_tp_inside_stage_matches_sequential():
                                atol=2e-4, rtol=2e-4)
 
 
-@requires_ambient_mesh
 def test_llama_pipeline_tp_gqa_matches_sequential():
     """GQA under tp (kv heads sharded too): the per-shard head-group
     repeat must keep q/kv pairing intact."""
@@ -258,7 +244,6 @@ def test_llama_pipeline_tp_gqa_matches_sequential():
                                atol=2e-4, rtol=2e-4)
 
 
-@requires_ambient_mesh
 def test_llama_pipeline_tp_differentiable():
     cfg = _tiny()
     mesh = build_mesh(MeshConfig(pp=2, dp=2, tp=2))
@@ -280,7 +265,6 @@ def test_llama_pipeline_tp_differentiable():
     assert gnorm > 0 and np.isfinite(gnorm)
 
 
-@requires_ambient_mesh
 def test_llama_pipeline_moe_matches_sequential_with_aux():
     """MoE inside the pipeline (VERDICT r2 #8): logits AND the router
     aux loss (threaded through the scan carry) must match the

@@ -208,6 +208,8 @@ def test_tpu_topology_from_gke_env(monkeypatch):
     monkeypatch.setenv("TPU_WORKER_ID", "0")
     monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "h0,h1,h2,h3")
     monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+    # The count is what the host really exposes; the env names the slice.
+    monkeypatch.setattr(accelerators, "local_chips", lambda: (4, "v5e"))
 
     topo = accelerators.detect_tpu_topology()
     assert topo == {"accelerator_type": "v5litepod-16", "worker_id": 0,
