@@ -184,6 +184,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((bh, seq_len, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -302,6 +303,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, block_q: int,
         out_specs=_specs([q_tile], interpret)[0],
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, o, lse, do)
 
     dk, dv = pl.pallas_call(
@@ -313,6 +315,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, block_q: int,
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, o, lse, do)
     return dq, dk, dv
 

@@ -46,6 +46,7 @@ def _rmsnorm_fwd_impl(x2d, scale, eps: float, interpret: bool):
                                **spec_kwargs),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
         interpret=interpret,
+        name="rmsnorm_fwd",
     )(x2d, scale)
 
 

@@ -14,6 +14,17 @@ slot-per-request ``serve.llm.LLMServer``. Counters ship as
 (``ray_tpu_node_engine`` /metrics family) via the process-local
 engine registry below.
 
+On the record: every phase of a loop pass is a ``tracing.phase`` (an
+``engine.iteration`` span with leaf spans ``engine.sweep``,
+``engine.prefill.schedule`` / ``.launch`` / ``.first_token``,
+``engine.decode.schedule`` / ``.split_key`` / ``.launch`` / ``.fetch``
+/ ``.emit`` and ``engine.idle``), so a profiler session shows on the
+device trace's clock what the host did in a device idle gap; the time
+counters (``loop_wall_us`` ... ``decode_host_us``) say the same to
+``/metrics`` without a session; a request's four stamps become the
+spans ``llm.request`` > ``llm.queue`` / ``llm.prefill`` /
+``llm.decode`` at its seal while tracing is armed.
+
 Chaos: ``llm.slow_step`` wedges one decode step for
 ``RAY_TPU_LLM_SLOW_S`` seconds before the jitted call — the
 deterministic proof that a wedged decode trips the request deadline
@@ -39,6 +50,7 @@ from ray_tpu.serve.llm_engine.scheduler import (
     EngineRequest,
     Scheduler,
 )
+from ray_tpu.util import tracing
 
 __all__ = ["ENGINE_STAT_KEYS", "LLMEngine", "PAGED_ON",
            "merged_engine_stats", "merged_engine_load"]
@@ -58,12 +70,24 @@ ENGINE_STAT_KEYS = (
     "decode_steps", "batched_decode_steps", "decode_tokens",
     "preemptions", "resumes", "finished", "deadline_expired",
     "slow_steps", "blocks_allocated", "blocks_freed",
+    # Where the time went, in microseconds, summed on the engine
+    # thread: per request up to its first token, and per loop pass
+    # that made progress.
+    "first_tokens", "queue_wait_us", "prefill_us",
+    "loop_wall_us", "loop_cpu_us", "fetch_wait_us", "decode_host_us",
 )
 
 # Live engines in THIS process (serve replicas are co-hosted with the
 # node executor, so daemon heartbeats pick these up; driver-local
 # engines surface under node="driver" in the scrape).
 _LIVE: "weakref.WeakSet" = weakref.WeakSet()
+
+
+class _PassClock:
+    """Of the loop pass under way, the engine thread's alone: ns spent
+    blocked on the device's answer, and whether a decode step ran."""
+
+    __slots__ = ("fetch_ns", "decoded")
 
 
 class LLMEngine:
@@ -108,6 +132,7 @@ class LLMEngine:
                                             self.block_size)
         self._key = jax.random.PRNGKey(seed + 1)
         self._counters: "dict[str, int]" = {k: 0 for k in ENGINE_STAT_KEYS}
+        self._pass = _PassClock()
         self._lock = lock_witness.Condition("llm_engine.LLMEngine.state")
         self._shutdown = threading.Event()
         _LIVE.add(self)
@@ -216,11 +241,43 @@ class LLMEngine:
                 return False
             req.sealed = True
             req.error = error
+        self._record_sealed(req)
         if req.stream is not None:
             req.stream.put(("err", error) if error is not None
                            else ("end", None))
         req.done.set()
         return True
+
+    @staticmethod
+    def _record_sealed(req: EngineRequest) -> None:
+        """Stamp the seal; while tracing is armed, the request's
+        stamps become spans under the submitter's span: ``llm.request``
+        over ``llm.queue`` (submit to first claim), ``llm.prefill`` (to
+        the first token) and ``llm.decode`` (to the seal), as far as
+        the request got."""
+        req.sealed_ns = time.monotonic_ns()
+        if not tracing.TRACE_ON or req.trace_ctx is None:
+            return
+        trace_id, parent_id, _ = req.trace_ctx
+        now = time.time()
+        to_wall = now - req.sealed_ns / 1e9  # monotonic -> wall clock
+        root = tracing.record_span(
+            "llm.request", to_wall + req.submitted_ns / 1e9, now,
+            trace_id, parent_id,
+            {"req": req.rid, "prompt_tokens": len(req.tokens),
+             "new_tokens": len(req.output), "preempted": req.preempted,
+             **({"error": type(req.error).__name__}
+                if req.error is not None else {})})
+        stamps = (req.submitted_ns, req.claimed_ns, req.first_token_ns,
+                  req.sealed_ns)
+        for name, start, end in zip(
+                ("llm.queue", "llm.prefill", "llm.decode"),
+                stamps, stamps[1:]):
+            if start:
+                tracing.record_span(
+                    name, to_wall + start / 1e9,
+                    to_wall + (end or req.sealed_ns) / 1e9, trace_id,
+                    root, {"req": req.rid})
 
     def _emit(self, req: EngineRequest, token: int) -> None:
         req.output.append(token)
@@ -231,18 +288,46 @@ class LLMEngine:
 
     def _engine_loop(self) -> None:
         while not self._shutdown.is_set():
-            with self._lock:
-                newly_expired = self._sched.sweep_expired()
+            self._iteration()
+
+    def _iteration(self) -> None:
+        """One pass of the loop, on the record: its phases as spans,
+        and, where it made progress, its wall and thread CPU time in
+        the counters (a thread that is on the CPU for less than its
+        wall time outside the device waits was waiting for the
+        interpreter or the engine's lock)."""
+        wall0, cpu0 = time.monotonic_ns(), time.thread_time_ns()
+        clock = self._pass
+        clock.fetch_ns, clock.decoded = 0, False
+        # An empty engine's passes stay out of the span buffer.
+        phase = tracing.phase if self._sched.depth() \
+            else tracing.profiler_phase
+        with phase("engine.iteration"):
+            with phase("engine.sweep") as sweep:
+                with self._lock:
+                    newly_expired = self._sched.sweep_expired()
+                    for req in newly_expired:
+                        self._counters["deadline_expired"] += 1
                 for req in newly_expired:
-                    self._counters["deadline_expired"] += 1
-            for req in newly_expired:
-                self._seal(req, self._sched.expired_error(req))
+                    self._seal(req, self._sched.expired_error(req))
+                sweep.set(expired=len(newly_expired))
             progressed = self._prefill_tick()
             progressed = self._decode_tick() or progressed
             if not progressed:
-                with self._lock:
+                with phase("engine.idle"), self._lock:
                     if self._sched.depth() == 0:
                         self._lock.wait(0.002)
+                return
+        # The CPU interval lies inside the wall interval: cpu <= wall.
+        cpu = (time.thread_time_ns() - cpu0) // 1000
+        wall = (time.monotonic_ns() - wall0) // 1000
+        fetch = clock.fetch_ns // 1000
+        counters = self._counters
+        counters["loop_wall_us"] += wall
+        counters["loop_cpu_us"] += cpu
+        counters["fetch_wait_us"] += fetch
+        if clock.decoded:
+            counters["decode_host_us"] += wall - fetch
 
     def _grow_or_preempt_locked(self, req: EngineRequest,
                                 n_tokens: int) -> str:
@@ -277,7 +362,9 @@ class LLMEngine:
     def _prefill_tick(self) -> bool:
         """At most ONE chunk of ONE request per engine iteration —
         the interleave that keeps long prompts from stalling decode."""
-        with self._lock:
+        if self._sched.prefilling is None and not self._sched.waiting:
+            return False  # only this thread claims: nothing to open
+        with tracing.phase("engine.prefill.schedule") as span, self._lock:
             if self._sched.prefilling is None:
                 claimed = self._sched.claim_prefill()
                 if claimed is not None and claimed.preempted > 0:
@@ -285,6 +372,7 @@ class LLMEngine:
             req = self._sched.prefilling
             if req is None:
                 return False
+            span.set(req=req.rid)
             n = min(self.prefill_chunk_len,
                     len(req.context) - req.prefilled)
             status = self._grow_or_preempt_locked(req, req.prefilled + n)
@@ -298,33 +386,36 @@ class LLMEngine:
         if status == "victim":
             return True  # re-queued; pressure eased — progress made
 
-        chunk = self.prefill_chunk_len
-        tokens = np.zeros((1, chunk), dtype=np.int32)
-        tokens[0, :n] = req.context[start:start + n]
-        positions = np.zeros((1, chunk), dtype=np.int32)
-        positions[0, :n] = np.arange(start, start + n)
-        bt = np.zeros((1, self.blocks_per_seq), dtype=np.int32)
-        bt[0, :len(table)] = table
         import jax.numpy as jnp
 
         from ray_tpu._private import jax_compat
 
-        try:
-            with jax_compat.set_mesh(self._mesh):
-                last_logits, self._pool = self._prefill_step(
-                    self.params, self._pool, jnp.asarray(tokens),
-                    jnp.asarray(positions), jnp.asarray(bt),
-                    np.int32(n), np.int32(n - 1))
-        except Exception as exc:  # noqa: BLE001 — donated pool is gone
-            self._reset_after_failure(exc)
-            return True
-        with self._lock:
-            self._counters["prefill_chunks"] += 1
-            self._counters["prefill_tokens"] += n
-            req.prefilled += n
-            if req.prefilled < len(req.context):
+        with tracing.phase("engine.prefill.launch", req=req.rid, tokens=n):
+            chunk = self.prefill_chunk_len
+            tokens = np.zeros((1, chunk), dtype=np.int32)
+            tokens[0, :n] = req.context[start:start + n]
+            positions = np.zeros((1, chunk), dtype=np.int32)
+            positions[0, :n] = np.arange(start, start + n)
+            bt = np.zeros((1, self.blocks_per_seq), dtype=np.int32)
+            bt[0, :len(table)] = table
+            try:
+                with jax_compat.set_mesh(self._mesh):
+                    last_logits, self._pool = self._prefill_step(
+                        self.params, self._pool, jnp.asarray(tokens),
+                        jnp.asarray(positions), jnp.asarray(bt),
+                        np.int32(n), np.int32(n - 1))
+            except Exception as exc:  # noqa: BLE001 — donated pool is gone
+                self._reset_after_failure(exc)
                 return True
-            # Prompt fully prefilled: enter the decode batch.
+            with self._lock:
+                self._counters["prefill_chunks"] += 1
+                self._counters["prefill_tokens"] += n
+                req.prefilled += n
+                if req.prefilled < len(req.context):
+                    return True
+        # Prompt fully prefilled: enter the decode batch.
+        with tracing.phase("engine.prefill.first_token", req=req.rid), \
+                self._lock:
             req.position = len(req.context)
             first_token = None
             if req.sample_first:
@@ -338,6 +429,13 @@ class LLMEngine:
             if first_token is not None:
                 self._emit(req, first_token)
                 req.last_token = first_token
+                req.first_token_ns = time.monotonic_ns()
+                counters = self._counters
+                counters["first_tokens"] += 1
+                counters["queue_wait_us"] += \
+                    (req.claimed_ns - req.submitted_ns) // 1000
+                counters["prefill_us"] += \
+                    (req.first_token_ns - req.claimed_ns) // 1000
             if req.remaining <= 0 or req.position >= self.max_tokens:
                 self._finish_locked(req)
             else:
@@ -350,9 +448,19 @@ class LLMEngine:
 
         if req.temperature > 0:
             self._key, sub = jax.random.split(self._key)
-            return int(jax.random.categorical(
-                sub, last_logits / max(req.temperature, 1e-4)))
-        return int(jnp.argmax(last_logits))
+            token = jax.random.categorical(
+                sub, last_logits / max(req.temperature, 1e-4))
+        else:
+            token = jnp.argmax(last_logits)
+        return self._fetch(int, token)
+
+    def _fetch(self, read, array):
+        """``read(array)`` blocks until the device has the answer: the
+        time inside it is the pass's device wait, not host work."""
+        t0 = time.monotonic_ns()
+        out = read(array)
+        self._pass.fetch_ns += time.monotonic_ns() - t0
+        return out
 
     def _finish_locked(self, req: EngineRequest) -> None:
         self._sched.cache.release(req.block_table)
@@ -363,12 +471,15 @@ class LLMEngine:
         # _seal re-checks under the same reentrant-safe path; here we
         # mark and set the event after releasing blocks.
         req.sealed = True
+        self._record_sealed(req)
         if req.stream is not None:
             req.stream.put(("end", None))
         req.done.set()
 
     def _decode_tick(self) -> bool:
-        with self._lock:
+        if not self._sched.active:
+            return False
+        with tracing.phase("engine.decode.schedule") as span, self._lock:
             if not self._sched.active:
                 return False
             # Grow every row's table for the token it is about to
@@ -380,6 +491,7 @@ class LLMEngine:
             active = list(self._sched.active)
             if not active:
                 return True  # everything preempted: progress made
+            span.set(rows=len(active))
             B = self.max_batch
             tokens = np.zeros((B, 1), dtype=np.int32)
             positions = np.zeros((B,), dtype=np.int32)
@@ -397,31 +509,40 @@ class LLMEngine:
 
         from ray_tpu._private import jax_compat
 
-        self._key, sub = jax.random.split(self._key)
+        with tracing.phase("engine.decode.split_key"):
+            self._key, sub = jax.random.split(self._key)
         try:
-            with jax_compat.set_mesh(self._mesh):
+            with tracing.phase("engine.decode.launch", rows=len(active)), \
+                    jax_compat.set_mesh(self._mesh):
                 nxt, self._pool = self._decode_step(
                     self.params, self._pool, jnp.asarray(tokens),
                     jnp.asarray(positions), jnp.asarray(tables), sub,
                     jnp.asarray(temps))
-            nxt = np.asarray(nxt)
+            with tracing.phase("engine.decode.fetch"):
+                nxt = self._fetch(np.asarray, nxt)
         except Exception as exc:  # noqa: BLE001 — donated pool is gone
             self._reset_after_failure(exc)
             return True
-        with self._lock:
-            self._counters["decode_steps"] += 1
-            if len(active) >= 2:
-                self._counters["batched_decode_steps"] += 1
-            self._counters["decode_tokens"] += len(active)
-            for i, req in enumerate(active):
-                if req.sealed or req not in self._sched.active:
-                    continue  # expired/externally sealed mid-step
-                self._emit(req, int(nxt[i]))
-                req.last_token = int(nxt[i])
-                req.position += 1
-                req.remaining -= 1
-                if req.remaining <= 0 or req.position >= self.max_tokens:
-                    self._finish_locked(req)
+        self._pass.decoded = True
+        with tracing.phase("engine.decode.emit", rows=len(active)) as span:
+            with self._lock:
+                self._counters["decode_steps"] += 1
+                if len(active) >= 2:
+                    self._counters["batched_decode_steps"] += 1
+                self._counters["decode_tokens"] += len(active)
+                finished = 0
+                for i, req in enumerate(active):
+                    if req.sealed or req not in self._sched.active:
+                        continue  # expired/externally sealed mid-step
+                    self._emit(req, int(nxt[i]))
+                    req.last_token = int(nxt[i])
+                    req.position += 1
+                    req.remaining -= 1
+                    if req.remaining <= 0 \
+                            or req.position >= self.max_tokens:
+                        self._finish_locked(req)
+                        finished += 1
+            span.set(finished=finished)  # annotated with the lock released
         return True
 
     def _maybe_chaos_slow_step(self) -> None:

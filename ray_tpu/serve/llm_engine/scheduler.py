@@ -41,6 +41,7 @@ All methods run on the engine loop thread except ``submit`` /
 
 from __future__ import annotations
 
+import itertools
 import queue as queue_mod
 import threading
 import time
@@ -49,6 +50,7 @@ from typing import Any
 
 from ray_tpu.exceptions import CacheExhaustedError, TaskTimeoutError
 from ray_tpu.serve.llm_engine.kv_cache import PagedKVCache
+from ray_tpu.util import tracing
 
 WAITING = "waiting"
 PREFILL = "prefill"
@@ -67,8 +69,11 @@ class EngineRequest:
         "tokens", "max_new_tokens", "temperature", "deadline", "name",
         "state", "output", "block_table", "position", "context",
         "prefilled", "sample_first", "remaining", "last_token",
-        "preempted", "sealed", "error", "done", "stream", "admitted_ts",
+        "preempted", "sealed", "error", "done", "stream",
+        "rid", "submitted_ns", "claimed_ns", "first_token_ns", "sealed_ns",
+        "trace_ctx",
     )
+    _rids = itertools.count()
 
     def __init__(self, tokens: "list[int]", max_new_tokens: int,
                  temperature: float, deadline: "float | None" = None,
@@ -98,7 +103,17 @@ class EngineRequest:
         # bounded memory is max_new_tokens ints either way.
         self.stream: "queue_mod.SimpleQueue | None" = (
             queue_mod.SimpleQueue() if stream else None)
-        self.admitted_ts = time.monotonic()
+        self.rid = next(self._rids)
+        # Where the request's time went, ``time.monotonic_ns()``: the
+        # engine sums queue wait and prefill time into its counters
+        # from these, and records them as spans at the seal while
+        # tracing is armed. 0 = not reached.
+        self.submitted_ns = time.monotonic_ns()
+        self.claimed_ns = 0      # first claim only: a resume keeps it
+        self.first_token_ns = 0
+        self.sealed_ns = 0
+        # The submitter's span context (None while tracing is off).
+        self.trace_ctx = tracing.make_trace_context()
 
     def stage(self) -> str:
         return STAGE_QUEUE if self.state == WAITING else STAGE_DECODE
@@ -145,6 +160,8 @@ class Scheduler:
         self.prefilling = req
         req.state = PREFILL
         req.prefilled = 0
+        if not req.claimed_ns:
+            req.claimed_ns = time.monotonic_ns()
         # Recompute-on-resume: re-prefill everything whose k/v the
         # preemption dropped — the prompt plus every generated token
         # except the last (its k/v is written by the NEXT decode step,
@@ -167,7 +184,7 @@ class Scheduler:
         if not self.active:
             return None
         return min(self.active,
-                   key=lambda r: (len(r.output), -r.admitted_ts))
+                   key=lambda r: (len(r.output), -r.rid))
 
     def preempt(self, victim: EngineRequest) -> None:
         """Release the victim's blocks and push it to the FRONT of the

@@ -14,6 +14,7 @@ import time
 from typing import Any
 
 from ray_tpu.exceptions import TaskError
+from ray_tpu.util import tracing
 
 
 class BackPressureError(Exception):
@@ -132,7 +133,10 @@ class Replica:
                 result = iter([result])
             for chunk in result:
                 try:
-                    queue.put(("chunk", chunk))
+                    # The entry point's cost per chunk, and through
+                    # the queue actor the core runtime's.
+                    with tracing.phase("serve.stream.put"):
+                        queue.put(("chunk", chunk))
                 except Exception:  # noqa: BLE001 — consumer abandoned
                     # The caller tore down the queue (early break):
                     # stop producing — cancellation, not an error.

@@ -27,19 +27,29 @@ subsystem behind ``ray timeline``. Here the tracer is built in:
   JSON file with one process lane per node/worker; ``get_spans()``
   returns structured spans for programmatic use.
 
+- ``phase(name, **attrs)`` instruments a phase of host work ONCE for
+  two sinks: a ``jax.profiler`` TraceMe, which a profiler session puts
+  in the host plane on the device trace's clock (so a device idle gap
+  can be read against what the host was doing), and a ``Span`` here
+  while tracing is armed. The serving engine's loop and the replica's
+  stream use it.
+
 Cost discipline: when tracing is disabled every instrumentation site
 pays one module-attribute branch (``if tracing.TRACE_ON:``) — the same
-contract as ``chaos.ACTIVE``.
+contract as ``chaos.ACTIVE``; a ``phase`` also pays its TraceMe's
+enter and exit, which are inert outside a profiler session.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import itertools
 import json
+import os
+import sys
 import threading
 import time
-import uuid
 from dataclasses import dataclass, field
 from typing import Any, Iterator
 
@@ -56,6 +66,25 @@ TRACE_ON: bool = False
 # lifecycle and by tests asserting monotonic ordering.
 STAGES = ("submit", "dispatch", "rpc_sent", "admitted", "worker_start",
           "exec_start", "exec_end", "seal")
+
+
+# Span and trace ids: a random prefix drawn once per process, then a
+# counter. A forked child draws its own prefix.
+_id_prefix = os.urandom(4).hex()
+_id_counter = itertools.count()
+
+
+def _reseed_ids() -> None:
+    global _id_prefix, _id_counter
+    _id_prefix = os.urandom(4).hex()
+    _id_counter = itertools.count()
+
+
+os.register_at_fork(after_in_child=_reseed_ids)
+
+
+def _new_id() -> str:
+    return f"{_id_prefix}{next(_id_counter):08x}"
 
 
 @dataclass
@@ -156,35 +185,114 @@ def dropped_spans() -> int:
     return _TRACER.dropped
 
 
+class _OffSpan(Span):
+    """What ``trace_span`` yields while tracing is off: no ids, and
+    attributes written to it go nowhere."""
+
+    @property
+    def attributes(self) -> dict:
+        return {}
+
+    @attributes.setter
+    def attributes(self, value: dict) -> None:
+        pass
+
+
+_OFF_SPAN = _OffSpan(name="", span_id="", parent_id=None, start_time=0.0)
+
+
+class phase:
+    """A phase of host work, instrumented once for two sinks.
+
+    A ``jax.profiler.TraceAnnotation`` whenever jax is already imported
+    in this process (never an import of its own: daemons without jax
+    stay without it): inert while no profiler session runs, and inside
+    one it lands in the host plane on the device trace's clock. A
+    ``Span`` (parented through the contextvar, sharing the enclosing
+    span's trace id) while ``TRACE_ON``. With neither live it costs the
+    TraceMe's enter and exit and nothing else."""
+
+    __slots__ = ("_name", "_attrs", "_annotation", "_token", "span")
+    _records_span = True
+
+    def __init__(self, name: str, **attrs):
+        self._name = name
+        self._attrs = attrs
+        self._annotation = None
+        self.span: "Span | None" = None
+
+    def __enter__(self) -> "phase":
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            self._annotation = jax.profiler.TraceAnnotation(
+                self._name, **self._attrs)
+            self._annotation.__enter__()
+        if TRACE_ON and self._records_span:
+            parent = _current_span.get()
+            self.span = Span(
+                name=self._name,
+                span_id=_new_id(),
+                parent_id=parent.span_id if parent else None,
+                start_time=time.time(),
+                attributes=self._attrs,
+                thread=threading.current_thread().name,
+                trace_id=(parent.trace_id if parent and parent.trace_id
+                          else _new_id()),
+            )
+            self._token = _current_span.set(self.span)
+        return self
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the phase is under way."""
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
+        if self.span is not None:
+            self.span.attributes.update(attrs)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        span = self.span
+        if span is not None:
+            if exc is not None:
+                span.attributes["error"] = f"{exc_type.__name__}: {exc}"
+            span.end_time = time.time()
+            _current_span.reset(self._token)
+            if _TRACER.enabled:
+                _TRACER.record(span)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+
+
+class profiler_phase(phase):
+    """A ``phase`` for the profiler alone: for the passes of a loop
+    that found nothing to do, which would fill the span buffer while
+    the process idles."""
+
+    __slots__ = ()
+    _records_span = False
+
+
 @contextlib.contextmanager
 def trace_span(name: str, attributes: dict | None = None) -> Iterator[Span]:
-    """Open a span; nests under the current span in this context."""
-    parent = _current_span.get()
-    span = Span(
-        name=name,
-        span_id=uuid.uuid4().hex[:16],
-        parent_id=parent.span_id if parent else None,
-        start_time=time.time(),
-        attributes=dict(attributes or {}),
-        thread=threading.current_thread().name,
-        trace_id=(parent.trace_id if parent and parent.trace_id
-                  else uuid.uuid4().hex[:16]),
-    )
-    token = _current_span.set(span)
-    try:
-        yield span
-    except BaseException as exc:
-        span.attributes["error"] = f"{type(exc).__name__}: {exc}"
-        raise
-    finally:
-        span.end_time = time.time()
-        _current_span.reset(token)
-        if _TRACER.enabled:
-            _TRACER.record(span)
+    """Open a span; nests under the current span in this context.
+    While tracing is off it yields an inert span and records nothing."""
+    with phase(name, **(attributes or {})) as opened:
+        yield opened.span or _OFF_SPAN
 
 
-def get_current_span() -> Span | None:
-    return _current_span.get()
+def record_span(name: str, start_time: float, end_time: float,
+                trace_id: str, parent_id: str | None = None,
+                attributes: dict | None = None) -> str | None:
+    """Record a span whose times are already known (a request's stamps,
+    read at its seal). Returns its id for its children, or None while
+    tracing is off."""
+    if not TRACE_ON:
+        return None
+    span = Span(name=name, span_id=_new_id(), parent_id=parent_id,
+                start_time=start_time, end_time=end_time,
+                attributes=dict(attributes or {}),
+                thread=threading.current_thread().name, trace_id=trace_id)
+    _TRACER.record(span)
+    return span.span_id
 
 
 def get_spans() -> list[Span]:
@@ -204,8 +312,7 @@ def get_spans() -> list[Span]:
 # ClockSync half-RTT estimation on the reply path.
 
 
-def make_trace_context(name: str | None = None,
-                       anchor: float | None = None) -> tuple | None:
+def make_trace_context(anchor: float | None = None) -> tuple | None:
     """Context for an outgoing task submit: links to the current span
     when one is open, else roots a fresh trace. None when disabled —
     the absence of a context IS the cross-process disable signal (the
@@ -214,10 +321,10 @@ def make_trace_context(name: str | None = None,
         return None
     parent = _current_span.get()
     if parent is not None:
-        trace_id = parent.trace_id or uuid.uuid4().hex[:16]
+        trace_id = parent.trace_id or _new_id()
         parent_id = parent.span_id
     else:
-        trace_id = uuid.uuid4().hex[:16]
+        trace_id = _new_id()
         parent_id = None
     return (trace_id, parent_id, anchor if anchor is not None
             else time.time())
@@ -234,9 +341,9 @@ def remote_span(name: str, ctx: tuple | None, proc: str,
     driver corrects them with its ClockSync offset at ingest."""
     span = {
         "name": name,
-        "span_id": uuid.uuid4().hex[:16],
+        "span_id": _new_id(),
         "parent_id": ctx[1] if ctx else None,
-        "trace_id": ctx[0] if ctx else uuid.uuid4().hex[:16],
+        "trace_id": ctx[0] if ctx else _new_id(),
         "start_time": time.time(),
         "end_time": None,
         "thread": threading.current_thread().name,
@@ -275,7 +382,7 @@ def ingest_spans(span_dicts: list[dict], offset_s: float = 0.0) -> int:
             end = d.get("end_time")
             span = Span(
                 name=d["name"],
-                span_id=d.get("span_id", uuid.uuid4().hex[:16]),
+                span_id=d.get("span_id", _new_id()),
                 parent_id=d.get("parent_id"),
                 start_time=float(d["start_time"]) + offset_s,
                 end_time=(float(end) + offset_s) if end else None,
@@ -300,7 +407,7 @@ def instant(name: str, attributes: dict | None = None,
         return
     span = Span(
         name=name,
-        span_id=uuid.uuid4().hex[:16],
+        span_id=_new_id(),
         parent_id=None,
         start_time=time.time(),
         end_time=None,
@@ -319,7 +426,7 @@ def buffer_instant(name: str, proc: str,
         return
     _TRACER.buffer({
         "name": name,
-        "span_id": uuid.uuid4().hex[:16],
+        "span_id": _new_id(),
         "parent_id": None,
         "trace_id": "",
         "start_time": time.time(),

@@ -342,6 +342,108 @@ def test_engine_stats_keys_contract(paged_engine):
     assert set(load) == {"depth", "waiting", "active", "free_blocks"}
 
 
+TIME_COUNTERS = ("first_tokens", "queue_wait_us", "prefill_us",
+                 "loop_wall_us", "loop_cpu_us", "fetch_wait_us",
+                 "decode_host_us")
+
+
+def test_time_counters_account_for_streamed_requests(paged_engine):
+    """Where the time went, without a profiler: one first token per
+    request, and per loop pass wall >= what is blocked on the device,
+    wall >= the thread's own CPU time."""
+    before = paged_engine.engine_stats()
+    requests = [paged_engine.submit([1 + i] * (3 + 5 * i), max_new_tokens=6,
+                                    stream=True) for i in range(5)]
+    snapshots = []
+    for req in requests:
+        assert len(list(paged_engine.stream_tokens(req))) == 6
+        snapshots.append(paged_engine.engine_stats())
+    after = snapshots[-1]
+    assert after["first_tokens"] - before["first_tokens"] == 5
+    for earlier, later in zip([before] + snapshots, snapshots):
+        for key in TIME_COUNTERS:
+            assert later[key] >= earlier[key], key  # monotonic
+    delta = {k: after[k] - before[k] for k in TIME_COUNTERS}
+    assert all(isinstance(after[k], int) for k in TIME_COUNTERS)
+    assert delta["loop_wall_us"] > 0
+    assert delta["loop_cpu_us"] <= delta["loop_wall_us"]
+    assert delta["fetch_wait_us"] <= delta["loop_wall_us"]
+    assert 0 < delta["decode_host_us"] <= delta["loop_wall_us"]
+    # Four of the five queued behind a prefill; every prefill took time.
+    assert delta["queue_wait_us"] > 0 and delta["prefill_us"] > 0
+    for req in requests:
+        assert req.submitted_ns <= req.claimed_ns <= req.first_token_ns \
+            <= req.sealed_ns
+
+
+def test_time_counter_names_clash_with_no_engine_argument():
+    """The benchmark lays the engine's arguments over the counters."""
+    import inspect
+
+    from ray_tpu.serve.llm_engine import ENGINE_STAT_KEYS, LLMEngine
+
+    arguments = set(inspect.signature(LLMEngine.__init__).parameters)
+    assert not arguments & set(ENGINE_STAT_KEYS)
+    assert set(TIME_COUNTERS) <= set(ENGINE_STAT_KEYS)
+
+
+ENGINE_SPANS = (
+    "engine.iteration", "engine.sweep", "engine.prefill.schedule",
+    "engine.prefill.launch", "engine.prefill.first_token",
+    "engine.decode.schedule", "engine.decode.split_key",
+    "engine.decode.launch", "engine.decode.fetch", "engine.decode.emit",
+    "engine.idle")
+
+
+def test_phase_spans_reach_the_profilers_host_plane(paged_engine, tmp_path):
+    """Under a profiler session every phase of the engine loop and the
+    replica's per-chunk put are host events of the trace (on a chip:
+    on the device trace's clock), nested as the loop nests them."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from ray_tpu.serve.replica import Replica
+
+    class Streams:
+        def generate(self, request):
+            req = paged_engine.submit(request["tokens"], max_new_tokens=4,
+                                      stream=True)
+            yield from paged_engine.stream_tokens(req)
+
+    class Chunks(list):
+        put = list.append
+
+    replica = Replica("llm", "llm#0", Streams(), (), {})
+    chunks = Chunks()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        time.sleep(0.02)  # the empty engine idles
+        replica.handle_request_streaming(
+            "generate", ({"tokens": list(range(1, 12))},), {}, chunks)
+    finally:
+        jax.profiler.stop_trace()
+    assert [kind for kind, _ in chunks] == ["chunk"] * 4 + ["end"]
+    found = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    events = [(line, e) for plane in ProfileData.from_file(found[0]).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events]
+    names = {e.name for _, e in events}
+    assert set(ENGINE_SPANS) | {"serve.stream.put"} <= names
+    launch = next(e for _, e in events if e.name == "engine.prefill.launch")
+    assert dict(launch.stats)["tokens"] == 8  # prefill_chunk of the engine
+    # Leaves lie inside an iteration of the same host line.
+    line, fetch = next((line, e) for line, e in events
+                       if e.name == "engine.decode.fetch")
+    assert any(e.name == "engine.iteration"
+               and e.start_ns <= fetch.start_ns
+               and fetch.start_ns + fetch.duration_ns
+               <= e.start_ns + e.duration_ns for e in line.events)
+
+
 def test_engine_stats_ride_executor_stats(paged_engine):
     """Engines co-hosted with a node executor surface as the "engine"
     stats group (the ray_tpu_node_engine heartbeat payload)."""

@@ -157,3 +157,29 @@ def test_flash_attention_non_divisible_seq():
     g1 = jax.grad(lambda q: jnp.sum(flash_attention(q, k, v) ** 2))(q)
     g2 = jax.grad(lambda q: jnp.sum(plain_attention(q, k, v, causal=True) ** 2))(q)
     np.testing.assert_allclose(g1, g2, atol=1e-4, rtol=1e-4)
+
+
+# ------------------------------------------------------------ kernel names
+
+
+def _flash_loss(q, k, v):
+    return jnp.sum(flash_attention(q, k, v, block_q=32, block_k=32))
+
+
+@pytest.mark.parametrize("kernel, traced", [
+    ("flash_fwd", lambda: jax.make_jaxpr(_flash_loss)(*_qkv())),
+    ("flash_bwd_dq", lambda: jax.make_jaxpr(
+        jax.grad(_flash_loss, argnums=(0, 1, 2)))(*_qkv())),
+    ("flash_bwd_dkv", lambda: jax.make_jaxpr(
+        jax.grad(_flash_loss, argnums=(0, 1, 2)))(*_qkv())),
+    ("rmsnorm_fwd", lambda: jax.make_jaxpr(rms_norm)(
+        jnp.ones((4, 64)), jnp.ones((64,)))),
+])
+def test_pallas_calls_carry_their_kernel_name(kernel, traced):
+    """A profiler trace tells kernels apart by the name their custom
+    call carries; the per-kernel shares of the benchmark select by
+    it."""
+    import re
+
+    names = set(re.findall(r"\bname=(\w+)", str(traced())))
+    assert kernel in names, names

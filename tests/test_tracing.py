@@ -180,6 +180,122 @@ def test_export_chrome_trace_conformance(traced, ray_start_regular,
     assert any(e["name"] == "fault:test_pin" for e in pins)
 
 
+# ------------------------------------------------------- phases, requests
+
+
+def test_phase_records_a_parented_span_when_on(traced):
+    with tracing.trace_span("outer") as outer:
+        with tracing.phase("engine.decode.emit", rows=3) as emit:
+            emit.set(finished=1)
+            with tracing.profiler_phase("engine.idle"):
+                # For the profiler alone: no span, and what opens
+                # inside hangs under the last recorded phase.
+                assert tracing.make_trace_context()[1] == emit.span.span_id
+    spans = {s.name: s for s in tracing.get_spans()}
+    assert set(spans) == {"outer", "engine.decode.emit"}
+    recorded = spans["engine.decode.emit"]
+    assert recorded.parent_id == outer.span_id
+    assert recorded.trace_id == outer.trace_id
+    assert recorded.attributes == {"rows": 3, "finished": 1}
+    assert recorded.end_time >= recorded.start_time
+    assert recorded.span_id != outer.span_id
+
+
+def test_phase_and_trace_span_record_nothing_when_off():
+    tracing.clear()
+    assert not tracing.TRACE_ON
+    with tracing.phase("engine.sweep", expired=0) as sweep:
+        sweep.set(expired=2)
+        assert sweep.span is None
+        assert tracing.make_trace_context() is None
+    with tracing.trace_span("user") as inert:
+        inert.attributes["note"] = 1     # usable, and goes nowhere
+        assert inert.span_id == "" and inert.trace_id == ""
+    with tracing.trace_span("again") as again:
+        assert again.attributes == {}
+    assert tracing.get_spans() == []
+    assert tracing.record_span("late", 1.0, 2.0, "t") is None
+
+
+def test_phase_never_imports_jax_by_itself():
+    """A daemon without jax stays without it: the profiler's sink is
+    used only where jax is already imported."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from ray_tpu.util import tracing\n"
+        "tracing.enable()\n"
+        "with tracing.phase('a', k=1) as a:\n"
+        "    a.set(j=2)\n"
+        "with tracing.trace_span('b'):\n"
+        "    pass\n"
+        "assert [s.name for s in tracing.get_spans()] == ['a', 'b']\n"
+        "assert 'jax' not in sys.modules, 'phase imported jax'\n")
+    done = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=120)
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+def test_span_ids_are_unique_per_process_prefix_and_counter(traced):
+    for i in range(200):
+        with tracing.phase(f"p{i}"):
+            pass
+    ids = [s.span_id for s in tracing.get_spans()]
+    assert len(set(ids)) == 200
+    assert all(len(i) == 16 for i in ids)
+    assert len({i[:8] for i in ids}) == 1  # one prefix, then a counter
+
+
+def test_request_spans_hang_under_the_callers_span(traced):
+    """A request's four stamps become llm.request > llm.queue,
+    llm.prefill, llm.decode at its seal: one trace id, parented to the
+    span that was current in the caller's thread at submit()."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    engine = LLMEngine(
+        dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.float32),
+        max_batch_size=2, max_seq_len=32, block_size=8, prefill_chunk=8)
+    try:
+        with tracing.trace_span("caller") as caller:
+            req = engine.submit([5, 9, 2], max_new_tokens=3)
+        assert len(engine.result(req, timeout_s=120)) == 3
+    finally:
+        engine.shutdown()
+    assert 0 < req.submitted_ns <= req.claimed_ns <= req.first_token_ns \
+        <= req.sealed_ns
+    spans = {s.name: s for s in tracing.get_spans()
+             if s.name.startswith("llm.")}
+    assert set(spans) == {"llm.request", "llm.queue", "llm.prefill",
+                          "llm.decode"}
+    root = spans["llm.request"]
+    assert root.parent_id == caller.span_id
+    assert root.attributes["new_tokens"] == 3
+    for name in ("llm.queue", "llm.prefill", "llm.decode"):
+        assert spans[name].parent_id == root.span_id
+    assert {s.trace_id for s in spans.values()} == {caller.trace_id}
+    # Back to back, inside the request, on the wall clock.
+    assert spans["llm.queue"].start_time == \
+        pytest.approx(root.start_time, abs=1e-6)
+    assert spans["llm.queue"].end_time == \
+        pytest.approx(spans["llm.prefill"].start_time, abs=1e-6)
+    assert spans["llm.prefill"].end_time == \
+        pytest.approx(spans["llm.decode"].start_time, abs=1e-6)
+    assert spans["llm.decode"].end_time == \
+        pytest.approx(root.end_time, abs=1e-6)
+    assert abs(root.end_time - time.time()) < 60
+    # The engine's phases of the same passes sit in the same buffer.
+    names = {s.name for s in tracing.get_spans()}
+    assert {"engine.iteration", "engine.prefill.launch",
+            "engine.decode.fetch"} <= names
+
+
 # ---------------------------------------------------------- cluster level
 
 
