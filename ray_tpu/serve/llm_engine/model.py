@@ -3,14 +3,27 @@
 The kernel discipline mirrors ``models.llama.forward_with_cache`` but
 reads/writes the PAGED pool instead of per-slot cache rows:
 
+- **the pool is a carry**: the whole ``[layers, num_blocks, bs, kv, d]``
+  pool rides through the layer scan beside the activations, and each
+  layer reads and writes its own slice by index. With the donation the
+  jitted steps declare, the update happens in the donated buffer: no
+  copy of the pool and no per-layer rewrite of a stacked output;
 - **scatter**: each new token's k/v lands at
-  ``pool[block_table[pos // bs], pos % bs]`` — a 2-level indexed write
-  (``.at[blocks, offsets].set``), one per layer inside the scan;
-- **gather**: attention keys/values materialize as
-  ``pool[block_table]`` → ``[B, M, bs, kv, d]`` reshaped to the flat
-  ``[B, S, kv, d]`` view where flat index ``s`` IS the token's global
-  position (tables are append-ordered), so the standard causal mask
-  ``s <= position`` is unchanged from the dense path;
+  ``pool[layer, block_table[pos // bs], pos % bs]`` — one indexed write
+  (``.at[layer, blocks, offsets].set``) per layer inside the scan;
+- **gather**: attention keys/values are ``pool[layer, block_table]`` →
+  ``[B, M, bs, kv, d]`` reshaped to the flat ``[B, S, kv, d]`` view
+  where flat index ``s`` IS the token's global position (tables are
+  append-ordered), so the standard causal mask ``s <= position`` is
+  unchanged from the dense path. They stay in the pool's dtype and are
+  never repeated per query head;
+- **grouped attention**: queries are viewed ``[B, T, kv, reps, d]`` and
+  contracted against the gathered keys with float32 accumulation
+  (products of bf16 values are exact in float32, so widening the
+  operands first would change nothing); scale, mask and softmax run in
+  float32, the probabilities are cast to ``config.dtype`` and
+  contracted with the gathered values. ``reps == 1`` (no grouping) is
+  the same code;
 - **fixed shapes**: batch ``B``, table width ``M`` and chunk length
   ``C`` are compile-time constants — ONE decode program and ONE
   prefill program total, every step hits the jit cache (the
@@ -61,21 +74,31 @@ def serving_params(config, params: "dict | None" = None,
 
 
 def _paged_attention_block(layer: dict, x: jax.Array,
-                           positions: jax.Array, pk: jax.Array,
-                           pv: jax.Array, block_tables: jax.Array,
-                           config, block_size: int,
+                           positions: jax.Array, pool_k: jax.Array,
+                           pool_v: jax.Array, li: jax.Array,
+                           block_tables: jax.Array, config,
+                           block_size: int,
                            n_valid: "jax.Array | None" = None):
-    """One attention block over the paged pool.
+    """One attention block over layer ``li`` of the paged pool.
 
     x: [B, T, E] new-token activations at global ``positions`` [B, T]
-    (T=1 decode, T=chunk prefill). pk/pv: [num_blocks, bs, kv, d].
-    block_tables: [B, M] (append-ordered block ids, 0-padded).
-    ``n_valid``: optional scalar — positions at/after it scatter to the
-    scratch block instead of the table (prefill chunk padding).
-    Returns (out, pk, pv).
+    (T=1 decode, T=chunk prefill). pool_k/pool_v: the WHOLE pool,
+    [layers, num_blocks, bs, kv, d], written at ``[li, block, offset]``
+    and gathered at ``[li, block_tables]`` (no layer slice is taken
+    out or put back). block_tables: [B, M] (append-ordered block ids,
+    0-padded). ``n_valid``: optional scalar — positions at/after it
+    scatter to the scratch block instead of the table (prefill chunk
+    padding).
+
+    The gathered keys/values stay ``[B, S, kv, d]`` in the pool's
+    dtype; the queries are grouped ``[B, T, kv, reps, d]`` so each
+    key-value head serves its ``reps`` query heads without being
+    repeated. Scores accumulate in float32 and the softmax is float32.
+    Returns (out, pool_k, pool_v).
     """
     dtype = config.dtype
-    h, kv_heads = config.num_heads, config.num_kv_heads
+    h, kv_heads, d = config.num_heads, config.num_kv_heads, config.head_dim
+    (B, T), M = positions.shape, block_tables.shape[1]
     normed = llama.rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
     q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
     k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
@@ -90,33 +113,31 @@ def _paged_attention_block(layer: dict, x: jax.Array,
                                  axis=1)                      # [B, T]
     offsets = positions % block_size
     if n_valid is not None:
-        in_range = jnp.arange(positions.shape[1])[None, :] < n_valid
+        in_range = jnp.arange(T)[None, :] < n_valid
         blocks = jnp.where(in_range, blocks, 0)
         offsets = jnp.where(in_range, offsets, 0)
-    pk = pk.at[blocks, offsets].set(k.astype(pk.dtype))
-    pv = pv.at[blocks, offsets].set(v.astype(pv.dtype))
+    pool_k = pool_k.at[li, blocks, offsets].set(k.astype(pool_k.dtype))
+    pool_v = pool_v.at[li, blocks, offsets].set(v.astype(pool_v.dtype))
 
     # Gather: the request's whole context, by block table. Flat index
     # s == global position (append-ordered tables).
-    B, M = block_tables.shape
     S = M * block_size
-    keys = pk[block_tables].reshape(B, S, kv_heads, config.head_dim)
-    values = pv[block_tables].reshape(B, S, kv_heads, config.head_dim)
-    if kv_heads != h:
-        reps = h // kv_heads
-        keys = jnp.repeat(keys, reps, axis=2)
-        values = jnp.repeat(values, reps, axis=2)
+    keys = pool_k[li, block_tables].reshape(B, S, kv_heads, d)
+    values = pool_v[li, block_tables].reshape(B, S, kv_heads, d)
 
-    scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                        keys.astype(jnp.float32))
-    scores *= config.head_dim ** -0.5
-    s_pos = jnp.arange(S)
-    mask = s_pos[None, None, None, :] <= positions[:, None, :, None]
-    scores = jnp.where(mask, scores, -1e30)
+    # Query head k * reps + r reads key-value head k: the mapping of
+    # llama._attention_block's jnp.repeat(k, reps, axis=2).
+    q = q.reshape(B, T, kv_heads, h // kv_heads, d)
+    scores = jnp.einsum("btkrd,bskd->bkrts", q, keys,
+                        preferred_element_type=jnp.float32)
+    scores *= d ** -0.5
+    mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]  # [B,T,S]
+    scores = jnp.where(mask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-    out = jnp.einsum("bhts,bshd->bthd", probs, values.astype(dtype))
-    out = jnp.einsum("blhd,hde->ble", out, layer["wo"].astype(dtype))
-    return x + out, pk, pv
+    out = jnp.einsum("bkrts,bskd->btkrd", probs, values.astype(dtype))
+    out = jnp.einsum("blhd,hde->ble", out.reshape(B, T, h, d),
+                     layer["wo"].astype(dtype))
+    return x + out, pool_k, pool_v
 
 
 def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
@@ -124,24 +145,27 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
                    config, block_size: int,
                    n_valid: "jax.Array | None" = None):
     """Shared prefill/decode forward over the paged pool. Returns
-    (logits [B, T, V] f32, updated pool)."""
+    (logits [B, T, V] f32, updated pool). The pool is part of the
+    scan's carry, so every layer updates the one (donated) buffer."""
     x = params["embed"]["tokens"].astype(config.dtype)[tokens]
 
-    def layer_step(x, layer_and_pool):
-        layer, pk, pv = layer_and_pool
-        x, pk, pv = _paged_attention_block(
-            layer, x, positions, pk, pv, block_tables, config,
-            block_size, n_valid=n_valid)
+    def layer_step(carry, layer_and_index):
+        x, pool_k, pool_v = carry
+        layer, li = layer_and_index
+        x, pool_k, pool_v = _paged_attention_block(
+            layer, x, positions, pool_k, pool_v, li, block_tables,
+            config, block_size, n_valid=n_valid)
         x = llama._mlp_block(layer, x, config)
-        return x, (pk, pv)
+        return (x, pool_k, pool_v), None
 
-    x, (k_new, v_new) = lax.scan(
-        layer_step, x, (params["layers"], pool["k"], pool["v"]))
+    (x, pool_k, pool_v), _ = lax.scan(
+        layer_step, (x, pool["k"], pool["v"]),
+        (params["layers"], jnp.arange(config.num_layers)))
     x = llama.rms_norm(x, params["final_norm"], config.rms_norm_eps)
     logits = jnp.einsum("ble,ev->blv", x,
                         params["lm_head"].astype(config.dtype),
                         preferred_element_type=jnp.float32)
-    return logits, {"k": k_new, "v": v_new}
+    return logits, {"k": pool_k, "v": pool_v}
 
 
 def make_decode_step(config, block_size: int):

@@ -14,6 +14,7 @@ CPU fallback on the measuring path.
 import dataclasses
 import importlib
 import json
+import math
 import os
 import subprocess
 import sys
@@ -120,18 +121,15 @@ def test_fused_rms_norm_compiles_for_v5e(v5e_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_engine_decode_compiles_for_v5e(v5e_chip):
-    """The paged engine's ONE decode program at chip_smoke.py's widths
-    (Llama-2-7B, 2 layers): fits one chip with room to spare."""
+def _lower_paged_step(program, config, batch, block, table, chip):
+    """``decode_step`` or ``prefill_chunk`` of the engine, lowered on
+    shapes placed on the described chip; the pool's shape beside it."""
+    from ray_tpu._private.config import GLOBAL_CONFIG
     from ray_tpu.models import llama
     from ray_tpu.serve.llm_engine import model as paged_model
 
-    config = dataclasses.replace(
-        llama.LlamaConfig.llama2_7b(), num_layers=2, max_seq_len=1024)
-    batch, block, table = 8, 16, 1024 // 16
-
-    def on_chip(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e_chip)
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
     params = jax.tree.map(
         lambda s: on_chip(s.shape, config.dtype),
@@ -141,13 +139,57 @@ def test_engine_decode_compiles_for_v5e(v5e_chip):
                   config.num_kv_heads, config.head_dim)
     pool = {"k": on_chip(pool_shape, config.dtype),
             "v": on_chip(pool_shape, config.dtype)}
-    compiled = paged_model.make_decode_step(config, block).lower(
-        params, pool, on_chip((batch, 1), jnp.int32),
-        on_chip((batch,), jnp.int32), on_chip((batch, table), jnp.int32),
-        on_chip((2,), jnp.uint32), on_chip((batch,), jnp.float32)).compile()
-    memory = compiled.memory_analysis()
+    if program == "decode_step":
+        lowered = paged_model.make_decode_step(config, block).lower(
+            params, pool, on_chip((batch, 1)), on_chip((batch,)),
+            on_chip((batch, table)), on_chip((2,), jnp.uint32),
+            on_chip((batch,), jnp.float32))
+    else:
+        chunk = GLOBAL_CONFIG.llm_prefill_chunk
+        lowered = paged_model.make_prefill_chunk(config, block).lower(
+            params, pool, on_chip((1, chunk)), on_chip((1, chunk)),
+            on_chip((1, table)), on_chip(()), on_chip(()))
+    return lowered, pool_shape
+
+
+def test_engine_decode_compiles_for_v5e(v5e_chip):
+    """The paged engine's ONE decode program at chip_smoke.py's widths
+    (Llama-2-7B, 2 layers): fits one chip with room to spare."""
+    from ray_tpu.models import llama
+
+    config = dataclasses.replace(
+        llama.LlamaConfig.llama2_7b(), num_layers=2, max_seq_len=1024)
+    lowered, _ = _lower_paged_step("decode_step", config, 8, 16,
+                                   1024 // 16, v5e_chip)
+    memory = lowered.compile().memory_analysis()
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 8 * 2 ** 30)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+def test_paged_steps_update_the_pool_in_place_on_v5e(v5e_chip, program):
+    """The serve cells' widths (Mistral-7B-v0.3: 32 query on 8 key-value
+    heads of 128; 2 of its layers, 16 rows, 128 blocks of 16). The chip's
+    compiler must keep the donated pool where it is and repeat or widen
+    nothing the size of the gathered keys: at these sizes a repeated
+    float32 copy of them is 0.5 GiB and a copy of the 2-layer pool 0.13
+    GiB, and neither shows in a CPU test."""
+    from ray_tpu.models import llama
+
+    config = llama.LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_seq_len=2048, rope_theta=1e6)
+    lowered, pool_shape = _lower_paged_step(program, config, 16, 16, 128,
+                                            v5e_chip)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.25 * 2 ** 30
+    # k and v, two bytes an element, both updated where they were given.
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in compiled.as_text().splitlines()
+            if " copy(" in line and pool_text in line] == []
 
 
 def test_vmem_rule_admits_the_main_path():

@@ -167,6 +167,101 @@ def test_paged_decode_matches_full_forward(paged_engine):
     assert out == expected
 
 
+@pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
+def test_paged_steps_with_grouped_heads_match_full_forward(dtype_name):
+    """The two jitted steps on a GQA configuration (8 query heads on 2
+    key-value heads), driven as the engine drives them: shuffled,
+    interleaved block tables, ragged prompts chunked with a padded last
+    chunk, then batched decode with one inactive row. Held to
+    ``llama.forward`` on the same weights; padding and the inactive row
+    may write the scratch block and nothing else."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm_engine import PagedKVCache
+    from ray_tpu.serve.llm_engine import model as paged_model
+
+    dtype = jnp.dtype(dtype_name)
+    cfg = llama.LlamaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_layers=3, num_heads=8, num_kv_heads=2, head_dim=8,
+        max_seq_len=32, remat=False, dtype=dtype)
+    num_blocks, block, chunk, width, steps = 40, 4, 4, 8, 6
+    prompts = [[7, 3, 11, 200, 5], list(range(20, 31)), [9, 1, 4],
+               list(range(100, 114))]
+    params = paged_model.serving_params(cfg, None, seed=3)
+    rng = np.random.default_rng(0)
+    # Every request will hold ceil((prompt + steps) / block) blocks,
+    # dealt from one shuffled deck so no table is contiguous.
+    deck = [int(b) for b in rng.permutation(np.arange(1, num_blocks))]
+    need = [-(-(len(p) + steps) // block) for p in prompts]
+    tables = [[] for _ in prompts]
+    for turn in range(max(need)):
+        for i, n in enumerate(need):
+            if turn < n:
+                tables[i].append(deck.pop())
+    bt = np.zeros((len(prompts) + 1, width), np.int32)  # last row inactive
+    for i, table in enumerate(tables):
+        bt[i, :len(table)] = table
+    pool = PagedKVCache.init_pool(cfg, num_blocks, block)
+    prefill = paged_model.make_prefill_chunk(cfg, block)
+    decode = paged_model.make_decode_step(cfg, block)
+
+    first_logits = []
+    for i, prompt in enumerate(prompts):
+        for start in range(0, len(prompt), chunk):
+            n = min(chunk, len(prompt) - start)
+            tokens = np.zeros((1, chunk), np.int32)
+            tokens[0, :n] = prompt[start:start + n]
+            positions = np.zeros((1, chunk), np.int32)
+            positions[0, :n] = np.arange(start, start + n)
+            logits, pool = prefill(
+                params, pool, jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(bt[i:i + 1]), np.int32(n), np.int32(n - 1))
+        first_logits.append(np.asarray(logits, np.float32))
+    assert any(len(p) % chunk for p in prompts)  # a padded last chunk ran
+
+    generated = [[int(row.argmax())] for row in first_logits]
+    lengths = [len(p) for p in prompts]
+    for _ in range(steps - 1):
+        last = np.zeros((len(bt), 1), np.int32)
+        last[:len(prompts), 0] = [g[-1] for g in generated]
+        nxt, pool = decode(
+            params, pool, jnp.asarray(last),
+            jnp.asarray(lengths + [0], dtype=jnp.int32), jnp.asarray(bt),
+            jax.random.PRNGKey(0), jnp.zeros((len(bt),), jnp.float32))
+        for g, token in zip(generated, np.asarray(nxt)):
+            g.append(int(token))
+        lengths = [n + 1 for n in lengths]
+
+    # The reference: teacher-forced full-context logits, same weights.
+    for i, prompt in enumerate(prompts):
+        row = prompt + generated[i][:-1]
+        ref = np.asarray(llama.forward(
+            params, jnp.asarray([row], dtype=jnp.int32), cfg)[0],
+            np.float32)[len(prompt) - 1:]
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(first_logits[i], ref[0], atol=1e-4)
+            assert generated[i] == [int(r.argmax()) for r in ref]
+        else:
+            # The benchmark's near-tie rule: 8e-2 on logits of std 1.0.
+            gaps = [float(r.max() - r[t]) for r, t in zip(ref, generated[i])]
+            assert max(gaps) <= 8e-2 * ref.std(), (gaps, ref.std())
+
+    # Written: the positions each table covers, and the scratch block.
+    written = np.zeros((num_blocks, block), bool)
+    written[0, 0] = True
+    for table, n in zip(tables, lengths):
+        for p in range(n):
+            written[table[p // block], p % block] = True
+    for name in ("k", "v"):
+        touched = np.asarray(pool[name].astype(jnp.float32) != 0).any(
+            axis=(0, 3, 4))
+        np.testing.assert_array_equal(touched, written)
+
+
 def test_concurrent_ragged_requests_batch(paged_engine):
     """Ragged concurrent requests share the fixed decode batch
     (batched_decode_steps counts steps with >= 2 active rows)."""
