@@ -17,7 +17,13 @@ random weights made from ``--seed``:
 
 ``--chips 4`` (run by hand; four chips cost four times as much) runs
 instead only the sharded train step on an fsdp=2 x tp=2 mesh and the
-one-device trajectory it must reproduce.  ``--rehearse`` is the only way
+one-device trajectory it must reproduce.  ``--paged-logits <file>`` runs
+instead, for a serve configuration of the benchmark at the size its
+file gives, the paged engine's two programs over shuffled block tables
+(prefill in chunks, then batched decode, for the file's probe lengths
+and for one context as long as the table) against the configuration's
+plain float32 reference: logits, and for a sparse model the expert
+choices that differ.  ``--rehearse`` is the only way
 this script accepts a CPU: tiny sizes, interpreted kernels, to find wrong
 paths and arguments before any chip time is spent.  Every phase fails
 the run; nothing is caught and continued.
@@ -46,6 +52,11 @@ ARGS.add_argument("--rehearse", action="store_true",
 ARGS.add_argument("--chips", type=int, default=1, choices=(1, 4),
                   help="4: only the sharded train step and its "
                        "one-device comparison")
+ARGS.add_argument("--paged-logits", metavar="CONFIG_JSON", default=None,
+                  help="instead of the phases: a serve configuration of "
+                       "the benchmark at its real size, the paged "
+                       "engine's programs against the configuration's "
+                       "plain reference, logits and expert choices")
 ARGS.add_argument("--seed", type=int, default=0)
 
 # Stated tolerances. bf16 keeps 8 bits of mantissa (2^-8 = 4e-3 a
@@ -426,6 +437,19 @@ def phase_train(sz: Sizes, seed: int, device: dict) -> None:
 # ------------------------------------------------------------------- serve
 
 
+def chunk_inputs(context, start: int, n: int, chunk: int):
+    """Tokens and positions [1, chunk] of ``context[start:start + n]``,
+    zero-padded, as the engine hands a prefill chunk to its program."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    tokens = np.zeros((1, chunk), np.int32)
+    tokens[0, :n] = context[start:start + n]
+    positions = np.zeros((1, chunk), np.int32)
+    positions[0, :n] = np.arange(start, start + n)
+    return jnp.asarray(tokens), jnp.asarray(positions)
+
+
 def paged_prefill_logits(config, params, prompt, block_size: int,
                          chunk: int, blocks_per_seq: int):
     """Logits of the first generated position from the engine's own
@@ -444,13 +468,9 @@ def paged_prefill_logits(config, params, prompt, block_size: int,
     logits = None
     for start in range(0, len(prompt), chunk):
         n = min(chunk, len(prompt) - start)
-        tokens = np.zeros((1, chunk), np.int32)
-        tokens[0, :n] = prompt[start:start + n]
-        positions = np.zeros((1, chunk), np.int32)
-        positions[0, :n] = np.arange(start, start + n)
-        logits, pool = prefill(params, pool, jnp.asarray(tokens),
-                               jnp.asarray(positions), jnp.asarray(table),
-                               np.int32(n), np.int32(n - 1))
+        logits, pool, _ = prefill(
+            params, pool, *chunk_inputs(prompt, start, n, chunk),
+            jnp.asarray(table), np.int32(n), np.int32(n - 1))
     return logits
 
 
@@ -595,6 +615,230 @@ def phase_serve(sz: Sizes, seed: int, device: dict) -> None:
     serve.shutdown()
 
 
+# ---------------------------------------- paged programs against a reference
+
+# bf16 programs against the float32 reference on the same bf16 weights,
+# in standard deviations of the reference's logits. Up to the first
+# position of a sequence at which some layer chose other experts than
+# the reference, only roundings differ (a dense model has no such
+# position): the serve phase's LOGIT_ATOL, 8e-2; 0.046 in the CPU
+# rehearsals of PR 25. A differing expert choice (two router
+# probabilities closer than the bf16 noise of the hidden state) swaps
+# one of a token's experts for a near-equal one; that token's output
+# moves by about one expert's contribution and every later position of
+# the sequence sees it through attention. At OLMoE's widths (12 layers,
+# 8 of 64 experts) on the v5e, 0.91% of 288,000 choices differed (0.43%
+# in the first layer, about 1% from the second on) and every sequence
+# had one within its first tokens; the worst logit difference was 0.127
+# (PR 25). The bounds are about twice what was measured: a step
+# computed in a lower precision than bf16 flips far more.
+PAGED_SAME_EXPERTS = 8e-2
+PAGED_OTHER_EXPERT = 0.25
+PAGED_DIFFERING_SHARE = 0.02
+# A rehearsal's model is 3 of 8 experts at width 64: one expert is a
+# third of a token's feed-forward and not a tenth (0.325 seen).
+REHEARSED_OTHER_EXPERT = 0.5
+
+
+def phase_paged_logits(path: str, seed: int, rehearse: bool,
+                       device: dict) -> None:
+    """The engine's jitted steps at a benchmark configuration's size,
+    driven as the engine drives them, outside any timed window."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import spec
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.serve.llm_engine import model as paged_model
+    from ray_tpu.serve.llm_engine.kv_cache import PagedKVCache
+
+    with open(path) as f:
+        config = spec.rehearsed(json.load(f), rehearse)
+    model_config = spec.build_model_config(config)
+    model = spec.model_numbers(config)
+    reference = spec.load_module(
+        [os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                      "benchmark")], "reference", config["reference"])
+    sparse = model_config.num_experts > 0
+    rows, max_len = (config["engine"][k]
+                     for k in ("max_batch_size", "max_seq_len"))
+    block, chunk = GLOBAL_CONFIG.llm_block_size, GLOBAL_CONFIG.llm_prefill_chunk
+    width = -(-max_len // block)
+    steps = config["probes"]["max_new_tokens"]
+    # A row each, and one for the long context.
+    lengths = list(config["probes"]["prompt_lengths"])[:rows - 1]
+    # One context as long as the table: its last `tail` positions are
+    # compared, the last `chunk` of them written by decode steps.
+    tail = 8 * chunk if max_len >= 16 * chunk else 2 * chunk
+    long_prefill = max_len - chunk
+    rng = np.random.default_rng([seed, 25])
+    contexts = [rng.integers(1, model_config.vocab_size, n + steps)
+                for n in lengths]
+    prefilled = list(lengths)
+    if hasattr(reference, "forward_tail"):
+        contexts.append(rng.integers(1, model_config.vocab_size, max_len))
+        prefilled.append(long_prefill)
+
+    params = paged_model.serving_params(model_config, None, seed)
+    widest = max((x for x in jax.tree.leaves(params)), key=lambda x: x.size)
+    say("paged", config=config["name"], layers=model_config.num_layers,
+        params=model_config.num_params, rows=rows, table=max_len,
+        weights_gb=round(sum(x.nbytes for x in jax.tree.leaves(params)) / 1e9,
+                         2),
+        weight_dtypes=sorted({str(x.dtype) for x in jax.tree.leaves(params)}),
+        largest_weight=[list(widest.shape), str(widest.dtype)],
+        device_bytes_in_use=device_bytes(),
+        device_peak_bytes_after_serving_params=(
+            jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use"))
+    check(all(x.dtype == model_config.dtype for x in jax.tree.leaves(params)),
+          "serving_params left a weight wider than the compute dtype")
+
+    # Tables dealt from one shuffled deck, so none is contiguous.
+    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
+    tables = np.zeros((rows, width), np.int32)
+    for turn in range(width):
+        for i, context in enumerate(contexts):
+            if turn < -(-len(context) // block):
+                tables[i, turn] = deck.pop()
+    pool = PagedKVCache.init_pool(model_config, 1 + rows * width, block)
+    prefill = paged_model.make_prefill_chunk(model_config, block)
+    decode = paged_model.make_decode_step(model_config, block)
+    # The same forward the two programs wrap, showing every position's
+    # logits and the experts chosen; the pool donated as they donate it.
+    shown_chunk = jax.jit(
+        lambda params, pool, tokens, positions, table, n_valid:
+        paged_model._forward_paged(params, pool, tokens, positions, table,
+                                   model_config, block, n_valid=n_valid),
+        donate_argnums=(1,))
+    shown_step = jax.jit(
+        lambda params, pool, tokens, positions, tables:
+        paged_model._forward_paged(params, pool, tokens, positions[:, None],
+                                   tables, model_config, block),
+        donate_argnums=(1,))
+
+    got = [[] for _ in contexts]        # (position, logits row)
+    chosen = [{} for _ in contexts]     # position -> experts [layers, k]
+    for i, context in enumerate(contexts):
+        for start in range(0, prefilled[i], chunk):
+            n = min(chunk, prefilled[i] - start)
+            args = (*chunk_inputs(context, start, n, chunk),
+                    jnp.asarray(tables[i:i + 1]))
+            last, pool, _ = prefill(params, pool, *args, np.int32(n),
+                                    np.int32(n - 1))
+            # Again through the showing forward: the same values written
+            # where they were, every position's logits and choices out.
+            logits, pool, _, routing = shown_chunk(params, pool, *args,
+                                                   np.int32(n))
+            logits = np.asarray(logits[0], np.float32)
+            routing = np.asarray(routing) if sparse else None
+            check(np.array_equal(logits[n - 1], np.asarray(last, np.float32)),
+                  "the prefill program and its forward disagree")
+            in_tail = i == len(lengths) and start + n > max_len - tail
+            for j in range(n):
+                if sparse:
+                    chosen[i][start + j] = routing[:, 0, j]
+                if in_tail or start + j == prefilled[i] - 1:
+                    got[i].append((start + j, logits[j]))
+    at = list(prefilled)
+    for step in range(max(steps, chunk)):
+        active = [i for i, context in enumerate(contexts)
+                  if at[i] < len(context)]
+        last = np.zeros((rows, 1), np.int32)
+        positions = np.zeros((rows,), np.int32)
+        for i in active:
+            last[i, 0], positions[i] = contexts[i][at[i]], at[i]
+        # Rows that are done carry an inactive row's zeros.
+        step_tables = np.where(positions[:, None] > 0, tables, 0)
+        args = (jnp.asarray(last), jnp.asarray(positions),
+                jnp.asarray(step_tables))
+        nxt, pool, _ = decode(params, pool, *args, jax.random.PRNGKey(0),
+                              jnp.zeros((rows,), jnp.float32))
+        logits, pool, _, routing = shown_step(params, pool, *args)
+        logits, nxt = np.asarray(logits[:, 0], np.float32), np.asarray(nxt)
+        routing = np.asarray(routing) if sparse else None
+        for i in active:
+            check(int(nxt[i]) == int(logits[i].argmax()),
+                  "the decode program's token is not its logits' argmax")
+            got[i].append((at[i], logits[i]))
+            if sparse:
+                chosen[i][at[i]] = routing[:, i, 0]
+            at[i] += 1
+    check(np.isfinite(np.asarray(pool["k"][:, :, 0, 0, 0],
+                                 np.float32)).all(), "pool not finite")
+    del pool, prefill, decode, shown_chunk, shown_step
+    gc.collect()
+    say("paged", programs="prefill chunks over shuffled tables, then batched "
+        "decode steps through the pool", sequences=[len(c) for c in contexts],
+        positions_compared=sum(len(g) for g in got),
+        device_bytes_in_use=device_bytes())
+
+    # The reference: the short contexts in one padded batch (causal, so
+    # padding changes nothing before it), the long one by its tail.
+    short = contexts[:len(lengths)]
+    padded = np.zeros((len(short), -(-max(map(len, short)) // 32) * 32),
+                      np.int32)
+    for i, context in enumerate(short):
+        padded[i, :len(context)] = context
+    out = jax.jit(lambda p, t: reference.forward(
+        p, t, model, with_routing=True) if sparse
+        else (reference.forward(p, t, model), None))(params,
+                                                     jnp.asarray(padded))
+    want = [(np.asarray(out[0][i]),
+             None if not sparse else np.asarray(out[1][:, i]))
+            for i in range(len(lengths))]
+    if len(contexts) > len(lengths):
+        out = jax.jit(lambda p, t: reference.forward_tail(
+            p, t, model, tail))(params, jnp.asarray(contexts[-1][None]))
+        want.append((np.asarray(out[0][0]), np.asarray(out[1][:, 0])
+                     if sparse else None))
+    offsets = [0] * len(lengths) + [max_len - tail]
+
+    differing = choices = 0
+    differing_by_layer = np.zeros(model_config.num_layers, np.int64)
+    worst_same = worst_other = 0.0
+    for i, context in enumerate(contexts):
+        ref_logits, ref_routing = want[i]
+        std = float(ref_logits.std())
+        # The first position at which any layer chose other experts
+        # than the reference: from there on the sequence is downstream.
+        first_differing = len(context)
+        if sparse:
+            ours = np.stack([chosen[i][p] for p in range(len(context))], 1)
+            theirs = ref_routing[:, :len(context)]
+            # A choice differs if the reference did not make it too.
+            other = ~(ours[..., :, None] == theirs[..., None, :]).any(-1)
+            differing += int(other.sum())                   # [n, L, k]
+            differing_by_layer += other.sum(axis=(1, 2))
+            choices += other.size
+            if other.any():
+                first_differing = int(np.argmax(other.any(axis=(0, 2))))
+        for position, logits in got[i]:
+            error = float(np.abs(
+                logits - ref_logits[position - offsets[i]]).max()) / std
+            if position >= first_differing:
+                worst_other = max(worst_other, error)
+            else:
+                worst_same = max(worst_same, error)
+    other_expert = REHEARSED_OTHER_EXPERT if rehearse else PAGED_OTHER_EXPERT
+    say("paged", check="logits of the paged programs against the float32 "
+        f"reference {config['reference']}", device=device["kind"],
+        worst_diff_same_experts_in_std=round(worst_same, 4),
+        worst_diff_after_a_differing_choice_in_std=round(worst_other, 4),
+        expert_choices_differing=differing, expert_choices=choices,
+        differing_by_layer=differing_by_layer.tolist(),
+        logit_std=round(float(want[0][0].std()), 3),
+        bounds=[PAGED_SAME_EXPERTS, other_expert, PAGED_DIFFERING_SHARE])
+    check(worst_same <= PAGED_SAME_EXPERTS,
+          f"logits off by {worst_same} standard deviations with the same "
+          "experts chosen")
+    check(worst_other <= other_expert,
+          f"logits off by {worst_other} standard deviations after a "
+          "differing expert choice")
+    check(differing <= PAGED_DIFFERING_SHARE * max(choices, 1),
+          f"{differing} of {choices} expert choices differ")
+
+
 # ------------------------------------------------------- four chips: sharded
 
 
@@ -723,7 +967,10 @@ def main(argv: "list[str] | None" = None) -> int:
     say("setup", cuts=sz.cuts, seed=args.seed, compile_cache=cache_dir,
         cache_dir_from_env="JAX_COMPILATION_CACHE_DIR" in os.environ)
 
-    if args.chips == 4:
+    if args.paged_logits:
+        phase_paged_logits(args.paged_logits, args.seed, args.rehearse,
+                           device)
+    elif args.chips == 4:
         phase_sharded(sz, args.seed, device)
     else:
         phase_kernels(sz, args.seed, on_tpu)

@@ -65,11 +65,19 @@ class LlamaConfig:
     # ~2 GiB at [8, 2048, 32000]); the per-chunk logits are recomputed
     # in backward. 0 = classic full-logits path.
     ce_chunk: int = 0
-    # Mixture-of-Experts: >0 replaces the dense SwiGLU MLP with a top-1
-    # routed expert layer (experts sharded over the ep mesh axis).
+    # Mixture-of-Experts: >0 replaces the dense SwiGLU MLP with a routed
+    # expert layer (models/moe.py; experts sharded over the ep mesh
+    # axis): softmax over all experts, the experts_per_token largest,
+    # their weights renormalised only with norm_topk_prob, no token
+    # dropped.
     num_experts: int = 0
-    expert_capacity_factor: float = 1.25
+    experts_per_token: int = 1
+    norm_topk_prob: bool = False
     moe_aux_loss_coef: float = 0.01
+    # QK-norm as OLMoE applies it: an RMSNorm with its own scale over
+    # the WHOLE query projection (all heads together) and another over
+    # the whole key projection, before the heads are rotated.
+    qk_norm: bool = False
 
     @staticmethod
     def llama2_7b() -> "LlamaConfig":
@@ -103,9 +111,10 @@ class LlamaConfig:
             mlp = e * self.num_experts + 3 * e * m * experts_counted
         else:
             mlp = 3 * e * m  # dense swiglu
+        qk_norms = (h + kv) * d if self.qk_norm else 0
         per_layer = (e * h * d + 2 * e * kv * d + h * d * e  # attention
                      + mlp
-                     + 2 * e)  # norms
+                     + 2 * e + qk_norms)  # norms
         return v * e + self.num_layers * per_layer + e + e * v
 
     @property
@@ -114,10 +123,11 @@ class LlamaConfig:
 
     @property
     def num_active_params(self) -> int:
-        """Params touched per token: top-1 routing activates ONE expert,
-        so MoE compute cost is dense-equivalent — MFU accounting must use
+        """Params touched per token: routing activates
+        ``experts_per_token`` of the experts, so MFU accounting must use
         this, not total params."""
-        return self._param_count(1)
+        return self._param_count(self.experts_per_token
+                                 if self.num_experts > 0 else 1)
 
 
 # ---------------------------------------------------------------------- init
@@ -145,6 +155,9 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
         "wo": dense_init(keys[4], h * d, n, h, d, e),
         "mlp_norm": norm_init(n, e),
     }
+    if config.qk_norm:
+        layers.update({"q_norm": norm_init(n, h, d),
+                       "k_norm": norm_init(n, kv, d)})
     if config.num_experts > 0:
         from ray_tpu.models.moe import init_moe_params
 
@@ -176,6 +189,9 @@ def param_logical_axes(config: LlamaConfig | None = None) -> dict:
         "wo": (None, "heads", None, "embed"),
         "mlp_norm": (None, "norm"),
     }
+    if config is not None and config.qk_norm:
+        layers.update({"q_norm": (None, "heads", None),
+                       "k_norm": (None, "kv_heads", None)})
     if config is not None and config.num_experts > 0:
         from ray_tpu.models.moe import moe_logical_axes
 
@@ -216,6 +232,34 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def _norm_over_heads(x: jax.Array, scale: jax.Array,
+                     eps: float) -> jax.Array:
+    """RMSNorm over a whole projection: x [B, L, H, D] is normalised
+    over its H * D values together, scale [H, D]."""
+    flat = rms_norm(x.reshape(*x.shape[:2], -1), scale.reshape(-1), eps)
+    return flat.reshape(x.shape)
+
+
+def qkv_projections(layer: dict, x: jax.Array, positions: jax.Array,
+                    config: LlamaConfig):
+    """The attention block up to the scores, the same on every path
+    (training, the dense cache, the paged pool): input norm, q/k/v
+    projections, QK-norm where the configuration has it, rotary
+    embedding on q and k. x [B, L, E] -> q [B, L, H, D], k, v
+    [B, L, KV, D] (local heads inside a manual-tp body)."""
+    dtype = config.dtype
+    normed = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
+    q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
+    k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
+    v = jnp.einsum("ble,ekd->blkd", normed, layer["wv"].astype(dtype))
+    if config.qk_norm:
+        q = _norm_over_heads(q, layer["q_norm"], config.rms_norm_eps)
+        k = _norm_over_heads(k, layer["k_norm"], config.rms_norm_eps)
+    q = rope(q, positions, config.rope_theta)
+    k = rope(k, positions, config.rope_theta)
+    return q, k, v
+
+
 def _attention_block(layer: dict, x: jax.Array, positions: jax.Array,
                      config: LlamaConfig,
                      tp_axis: str | None = None) -> jax.Array:
@@ -224,16 +268,15 @@ def _attention_block(layer: dict, x: jax.Array, positions: jax.Array,
     automatically elsewhere): q/k/v/o arrive head-sharded over the axis
     and the output projection psums the partial sums."""
     dtype = config.dtype
-    h, kv, d = config.num_heads, config.num_kv_heads, config.head_dim
+    h, kv = config.num_heads, config.num_kv_heads
     if tp_axis is not None:
+        if config.qk_norm:
+            raise NotImplementedError(
+                "QK-norm spans all heads: under manual tp each shard "
+                "sees only its own")
         tp = jax.lax.psum(1, tp_axis)
         h, kv = h // tp, kv // tp
-    normed = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
-    q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
-    k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
-    v = jnp.einsum("ble,ekd->blkd", normed, layer["wv"].astype(dtype))
-    q = rope(q, positions, config.rope_theta)
-    k = rope(k, positions, config.rope_theta)
+    q, k, v = qkv_projections(layer, x, positions, config)
     if kv != h and config.attention != "flash":
         # flash_attention is GQA-native (kernels index head groups);
         # the other paths want materialized full-head kv.
@@ -289,8 +332,8 @@ def _moe_block(layer: dict, x: jax.Array,
 
     normed = rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
     out, aux = moe_mlp(
-        layer, normed, capacity_factor=config.expert_capacity_factor,
-        dtype=config.dtype)
+        layer, normed, experts_per_token=config.experts_per_token,
+        norm_topk_prob=config.norm_topk_prob, dtype=config.dtype)
     return x + out, aux
 
 
@@ -445,12 +488,7 @@ def _cached_attention_block(layer: dict, x: jax.Array, positions: jax.Array,
     """
     dtype = config.dtype
     h, kv = config.num_heads, config.num_kv_heads
-    normed = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
-    q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
-    k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
-    v = jnp.einsum("ble,ekd->blkd", normed, layer["wv"].astype(dtype))
-    q = rope(q, positions, config.rope_theta)
-    k = rope(k, positions, config.rope_theta)
+    q, k, v = qkv_projections(layer, x, positions, config)
 
     # Scatter new k/v into the cache at each row's positions.
     b_idx = jnp.arange(x.shape[0])[:, None]
@@ -488,7 +526,9 @@ def forward_with_cache(params: dict, tokens: jax.Array, cache: dict,
     """
     if config.num_experts > 0:
         raise NotImplementedError(
-            "KV-cache decoding for MoE configs is not implemented yet")
+            "this dense-cache path has no expert layer: sparse "
+            "configurations are served by the paged engine "
+            "(ray_tpu.serve.llm_engine.LLMEngine)")
     x = params["embed"]["tokens"].astype(config.dtype)[tokens]
 
     def layer_step(x, layer_and_cache):
@@ -509,8 +549,9 @@ def forward_with_cache(params: dict, tokens: jax.Array, cache: dict,
 
 def flops_per_token(config: LlamaConfig, seq_len: int | None = None) -> float:
     """6 * active params (fwd+bwd) + attention term — standard MFU
-    accounting. Uses num_active_params so top-1 MoE doesn't count the
-    experts a token never touches."""
+    accounting. Uses num_active_params, so a sparse model counts the
+    ``experts_per_token`` experts a token is routed to and not the
+    ones it never touches."""
     seq = seq_len if seq_len is not None else config.max_seq_len
     attn_flops = (12 * config.num_layers * config.num_heads
                   * config.head_dim * seq)

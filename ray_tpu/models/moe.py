@@ -1,16 +1,31 @@
-"""Mixture-of-Experts SwiGLU layer with expert parallelism.
+"""Mixture-of-Experts SwiGLU layer: top-k routing, nothing dropped.
 
-No reference implementation exists (SURVEY §2.4: EP absent from Ray) —
-built natively for the ``ep`` mesh axis. Design (Mesh-TensorFlow-style
-einsum dispatch, the canonical GSPMD MoE formulation):
+The block as OLMoE publishes it (``OlmoeSparseMoeBlock``), which is
+also Mixtral's with ``norm_topk_prob``: router logits in float32, a
+softmax over ALL experts, the ``k`` largest probabilities with their
+indices, the weights left as they are or renormalised to sum to one,
+and every token served by all ``k`` of its experts (no capacity, no
+dropped token).
 
-- top-1 router with capacity ``C = capacity_factor * T / E``; tokens
-  over capacity are dropped (residual connection carries them through);
-- dispatch/combine tensors [B, T, E, C] turn routing into einsums, so
-  with experts sharded over ``ep`` (logical axis "expert") and batch
-  over dp, XLA lowers token movement to all-to-alls over ICI;
-- load-balancing auxiliary loss (mean fraction x mean router prob per
-  expert, scaled by E) keeps the router from collapsing.
+One routing function (``route``) and one expert feed-forward
+(``expert_ffn``) serve ``llama.forward`` (training) and the paged
+engine (``serve/llm_engine/model.py``).
+
+``expert_ffn`` is an all-experts product: every resident expert is
+applied to every token and the result is weighted by the (mostly zero)
+combine weights. That reads each expert's three matrices once, which is
+the floor of a decode step or a prefill chunk (16 or 32 tokens x k
+choices touch most experts anyway) and costs ``E / k`` times the
+arithmetic of a sparse dispatch, which is why training at scale wants
+tokens sorted by expert and a grouped product instead (ROADMAP R1,
+open). With experts sharded over ``ep`` (logical axis "expert") each
+shard applies its own experts and the contraction over experts becomes
+an all-reduce.
+
+A load-balancing auxiliary loss (fraction of the choices x mean router
+probability per expert, scaled by E: Switch Transformer eq. 4, with the
+``k`` choices of a token counted ``1/k`` each) keeps the router from
+collapsing in training.
 
 Params per MoE layer (leading E = expert dim, logical "expert" -> ep):
   w_router [H, E]; w_gate/w_up [E, H, M]; w_down [E, M, H].
@@ -20,6 +35,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+# The engine's expert counters, in the order ``routing_counts`` fills
+# them (``engine.ENGINE_STAT_KEYS`` documents each).
+EXPERT_COUNTERS = ("expert_choices", "expert_slots", "experts_touched",
+                    "expert_peak_choices")
+_LOW_BITS = 30  # an accumulator word holds 30 bits; the carry goes up
 
 
 def init_moe_params(key: jax.Array, hidden: int, mlp: int,
@@ -47,50 +69,126 @@ def moe_logical_axes() -> dict:
     }
 
 
-def moe_mlp(layer: dict, x: jax.Array, *, capacity_factor: float = 1.25,
+def route(x: jax.Array, w_router: jax.Array, experts_per_token: int,
+          norm_topk_prob: bool = False):
+    """x [..., H] -> (probs [..., E], idx [..., k], weights [..., k]).
+
+    All float32: the logits are a float32 product at the highest
+    precision (on a TPU a float32 matmul otherwise rounds its operands
+    to bf16), the softmax runs over all experts, and ``lax.top_k``
+    takes the ``k`` largest probabilities (ties to the lower index).
+    ``weights`` are those probabilities as they are, or divided by
+    their sum with ``norm_topk_prob``.
+    """
+    logits = jnp.einsum("...h,he->...e", x.astype(jnp.float32),
+                        w_router.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, idx = lax.top_k(probs, experts_per_token)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return probs, idx, weights
+
+
+def combine_weights(idx: jax.Array, weights: jax.Array,
+                    num_experts: int) -> jax.Array:
+    """[..., k] choices -> [..., E] float32: a token's weight for each
+    expert, zero for the experts it did not choose."""
+    chosen = jax.nn.one_hot(idx, num_experts, dtype=jnp.float32)
+    return jnp.einsum("...ke,...k->...e", chosen, weights)
+
+
+def expert_ffn(layer: dict, x: jax.Array, combine: jax.Array,
+               dtype=jnp.bfloat16) -> jax.Array:
+    """sum_e combine[..., e] * W_down_e(silu(W_gate_e x) * W_up_e x).
+
+    x [B, T, H], combine [B, T, E] -> [B, T, H] in ``dtype``. Every
+    expert with a non-zero weight contributes: nothing is dropped. An
+    expert a token did not choose contributes exactly zero, whatever
+    its activations come to (``where``, not a product with zero). The weight
+    is applied before the down-projection (which is linear), so the sum
+    over experts is one contraction accumulated in float32.
+    """
+    b, t, h = x.shape
+    num_experts = combine.shape[-1]
+    # Experts as the batch dimension of every product: an expert's
+    # matrices are then read where and as they lie ([E, H, M] with the
+    # contraction over H is otherwise transposed whole before the
+    # product, which on the chip tripled what a decode step moves).
+    tokens = jnp.broadcast_to(x.astype(dtype).reshape(1, b * t, h),
+                              (num_experts, b * t, h))
+    gate = jnp.einsum("enh,ehm->enm", tokens, layer["w_gate"].astype(dtype))
+    up = jnp.einsum("enh,ehm->enm", tokens, layer["w_up"].astype(dtype))
+    weight = combine.reshape(b * t, num_experts).T[..., None]    # [E, N, 1]
+    hidden = (jax.nn.silu(gate) * up).astype(jnp.float32) * weight
+    hidden = jnp.where(weight > 0, hidden, 0.0).astype(dtype)
+    out = jnp.einsum("enm,emh->nh", hidden, layer["w_down"].astype(dtype))
+    return out.reshape(b, t, h)
+
+
+def load_balance_loss(probs: jax.Array, idx: jax.Array) -> jax.Array:
+    """E * mean over batch rows of sum_e(fraction_e * mean_prob_e):
+    1 when the choices are spread evenly, E when one expert takes all.
+    probs [B, T, E], idx [B, T, k]."""
+    num_experts = probs.shape[-1]
+    chosen = jax.nn.one_hot(idx, num_experts, dtype=jnp.float32)  # [B,T,k,E]
+    fraction = jnp.mean(chosen, axis=(1, 2))           # [B, E]
+    mean_prob = jnp.mean(probs, axis=1)                # [B, E]
+    return num_experts * jnp.mean(jnp.sum(fraction * mean_prob, axis=-1))
+
+
+def moe_mlp(layer: dict, x: jax.Array, *, experts_per_token: int = 1,
+            norm_topk_prob: bool = False,
             dtype=jnp.bfloat16) -> tuple[jax.Array, jax.Array]:
-    """Top-1 MoE SwiGLU: x [B, T, H] -> (out [B, T, H], aux_loss scalar).
+    """Top-k MoE SwiGLU: x [B, T, H] -> (out [B, T, H], aux_loss scalar).
 
     ``layer`` holds one layer's slice: w_router [H, E],
     w_gate/w_up [E, H, M], w_down [E, M, H].
     """
-    b, t, h = x.shape
-    num_experts = layer["w_router"].shape[-1]
-    capacity = max(1, int(capacity_factor * t / num_experts))
+    probs, idx, weights = route(x, layer["w_router"], experts_per_token,
+                                norm_topk_prob)
+    combine = combine_weights(idx, weights, probs.shape[-1])
+    out = expert_ffn(layer, x, combine, dtype)
+    return out.astype(x.dtype), load_balance_loss(probs, idx)
 
-    # Router (f32 for a stable softmax).
-    logits = jnp.einsum("bth,he->bte", x.astype(jnp.float32),
-                        layer["w_router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)            # [B, T, E]
-    gate = jnp.max(probs, axis=-1)                     # [B, T]
-    expert_idx = jnp.argmax(probs, axis=-1)            # [B, T]
-    onehot = jax.nn.one_hot(expert_idx, num_experts, dtype=jnp.float32)
 
-    # Load-balancing aux loss (Switch Transformer eq. 4).
-    fraction = jnp.mean(onehot, axis=1)                # [B, E]
-    mean_prob = jnp.mean(probs, axis=1)                # [B, E]
-    aux_loss = num_experts * jnp.mean(
-        jnp.sum(fraction * mean_prob, axis=-1))
+# ------------------------------------------------ the engine's counters
 
-    # Position of each token within its expert (per batch row); tokens
-    # past the capacity are dropped (the residual stream carries them).
-    position = jnp.cumsum(onehot, axis=1) * onehot     # [B, T, E], 1-based
-    keep = (position > 0) & (position <= capacity)
-    pos_onehot = jax.nn.one_hot((position - 1).astype(jnp.int32), capacity,
-                                dtype=jnp.float32)     # [B, T, E, C]
-    dispatch = pos_onehot * keep.astype(jnp.float32)[..., None]
-    combine = dispatch * gate[..., None, None]
 
-    # Dispatch: [B,T,E,C] x [B,T,H] -> [E, B, C, H] (all-to-all under ep).
-    expert_in = jnp.einsum("btec,bth->ebch", dispatch.astype(dtype),
-                           x.astype(dtype))
-    gate_h = jnp.einsum("ebch,ehm->ebcm", expert_in,
-                        layer["w_gate"].astype(dtype))
-    up_h = jnp.einsum("ebch,ehm->ebcm", expert_in,
-                      layer["w_up"].astype(dtype))
-    hidden = jax.nn.silu(gate_h) * up_h
-    expert_out = jnp.einsum("ebcm,emh->ebch", hidden,
-                            layer["w_down"].astype(dtype))
-    # Combine back: weighted un-dispatch (second all-to-all).
-    out = jnp.einsum("btec,ebch->bth", combine.astype(dtype), expert_out)
-    return out.astype(x.dtype), aux_loss
+def routing_counts(idx: jax.Array, valid: jax.Array,
+                   num_experts: int) -> jax.Array:
+    """What one layer's routing of one step did, int32 in the order of
+    ``EXPERT_COUNTERS``. idx [B, T, k]; valid [B, T]: tokens that
+    carry a request (padding and inactive rows route too, uncounted).
+
+    choices: valid tokens x k. slots: the experts on offer (E).
+    touched: experts that got at least one choice. peak choices: E x
+    the busiest expert's load, so that over ``choices`` it is the
+    largest load over the mean.
+    """
+    chosen = jax.nn.one_hot(idx, num_experts, dtype=jnp.int32)  # [B,T,k,E]
+    load = jnp.sum(chosen * valid[..., None, None], axis=(0, 1, 2))  # [E]
+    return jnp.stack([jnp.sum(load), jnp.int32(num_experts),
+                      jnp.sum(load > 0), num_experts * jnp.max(load)])
+
+
+def init_stats() -> jax.Array:
+    """The accumulator a step carries: int32 [2, len(EXPERT_COUNTERS)],
+    low 30 bits and the carries above them, so that it never wraps."""
+    return jnp.zeros((2, len(EXPERT_COUNTERS)), jnp.int32)
+
+
+def accumulate(stats: jax.Array, counts: jax.Array) -> jax.Array:
+    """``stats`` plus one step's ``counts`` (each under 2**30)."""
+    low = stats[0] + counts
+    return jnp.stack([low & ((1 << _LOW_BITS) - 1),
+                      stats[1] + (low >> _LOW_BITS)])
+
+
+def read_stats(stats) -> dict:
+    """The accumulator as Python ints (one transfer, when asked)."""
+    import numpy as np
+
+    low, high = np.asarray(stats).astype(object)
+    return {key: int(hi) * (1 << _LOW_BITS) + int(lo)
+            for key, lo, hi in zip(EXPERT_COUNTERS, low, high)}

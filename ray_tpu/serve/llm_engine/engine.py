@@ -43,6 +43,7 @@ import numpy as np
 
 from ray_tpu._private import chaos, lock_witness
 from ray_tpu.exceptions import CacheExhaustedError, GetTimeoutError
+from ray_tpu.models.moe import init_stats, read_stats
 from ray_tpu.serve.llm_engine import model as paged_model
 from ray_tpu.serve.llm_engine.kv_cache import PagedKVCache
 from ray_tpu.serve.llm_engine.scheduler import (
@@ -75,6 +76,13 @@ ENGINE_STAT_KEYS = (
     # that made progress.
     "first_tokens", "queue_wait_us", "prefill_us",
     "loop_wall_us", "loop_cpu_us", "fetch_wait_us", "decode_host_us",
+    # What a sparse model's routing did (models/moe.py), summed over
+    # layers and steps ON THE DEVICE in an array the jitted steps carry;
+    # read from it when engine_stats() is asked, by no step. Zero for a
+    # dense model. (moe.EXPERT_COUNTERS, spelled out for the
+    # counter-keys pass, which reads this tuple's literals.)
+    "expert_choices", "expert_slots", "experts_touched",
+    "expert_peak_choices",
 )
 
 # Live engines in THIS process (serve replicas are co-hosted with the
@@ -130,6 +138,11 @@ class LLMEngine:
         self._mesh = mesh
         self._pool = PagedKVCache.init_pool(self.config, cache.num_blocks,
                                             self.block_size)
+        # The expert counters' accumulator: an argument and a result
+        # of every step, never donated, so a reader on another thread
+        # holds an array that stays valid. None for a dense model.
+        self._expert_stats = init_stats() \
+            if self.config.num_experts > 0 else None
         self._key = jax.random.PRNGKey(seed + 1)
         self._counters: "dict[str, int]" = {k: 0 for k in ENGINE_STAT_KEYS}
         self._pass = _PassClock()
@@ -400,10 +413,12 @@ class LLMEngine:
             bt[0, :len(table)] = table
             try:
                 with jax_compat.set_mesh(self._mesh):
-                    last_logits, self._pool = self._prefill_step(
-                        self.params, self._pool, jnp.asarray(tokens),
-                        jnp.asarray(positions), jnp.asarray(bt),
-                        np.int32(n), np.int32(n - 1))
+                    last_logits, self._pool, self._expert_stats = \
+                        self._prefill_step(
+                            self.params, self._pool, jnp.asarray(tokens),
+                            jnp.asarray(positions), jnp.asarray(bt),
+                            np.int32(n), np.int32(n - 1),
+                            self._expert_stats)
             except Exception as exc:  # noqa: BLE001 — donated pool is gone
                 self._reset_after_failure(exc)
                 return True
@@ -514,10 +529,10 @@ class LLMEngine:
         try:
             with tracing.phase("engine.decode.launch", rows=len(active)), \
                     jax_compat.set_mesh(self._mesh):
-                nxt, self._pool = self._decode_step(
+                nxt, self._pool, self._expert_stats = self._decode_step(
                     self.params, self._pool, jnp.asarray(tokens),
                     jnp.asarray(positions), jnp.asarray(tables), sub,
-                    jnp.asarray(temps))
+                    jnp.asarray(temps), self._expert_stats)
             with tracing.phase("engine.decode.fetch"):
                 nxt = self._fetch(np.asarray, nxt)
         except Exception as exc:  # noqa: BLE001 — donated pool is gone
@@ -585,6 +600,11 @@ class LLMEngine:
                for key in ENGINE_STAT_KEYS}
         out["blocks_allocated"] = int(self._sched.cache.blocks_allocated)
         out["blocks_freed"] = int(self._sched.cache.blocks_freed)
+        expert_stats = self._expert_stats
+        if expert_stats is not None:
+            # One transfer, here and nowhere else; it waits for the
+            # step in flight on the caller's thread, not the engine's.
+            out.update(read_stats(expert_stats))
         return out
 
     def engine_load(self) -> dict:

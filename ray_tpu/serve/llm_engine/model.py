@@ -23,7 +23,8 @@ reads/writes the PAGED pool instead of per-slot cache rows:
   operands first would change nothing); scale, mask and softmax run in
   float32, the probabilities are cast to ``config.dtype`` and
   contracted with the gathered values. ``reps == 1`` (no grouping) is
-  the same code;
+  the same code, with a row of zeros beside a decode step's lone query
+  row so that the product stays a matrix product;
 - **fixed shapes**: batch ``B``, table width ``M`` and chunk length
   ``C`` are compile-time constants — ONE decode program and ONE
   prefill program total, every step hits the jit cache (the
@@ -46,7 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import llama
+from ray_tpu.models import llama, moe
 
 
 def serving_params(config, params: "dict | None" = None,
@@ -99,12 +100,7 @@ def _paged_attention_block(layer: dict, x: jax.Array,
     dtype = config.dtype
     h, kv_heads, d = config.num_heads, config.num_kv_heads, config.head_dim
     (B, T), M = positions.shape, block_tables.shape[1]
-    normed = llama.rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
-    q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
-    k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
-    v = jnp.einsum("ble,ekd->blkd", normed, layer["wv"].astype(dtype))
-    q = llama.rope(q, positions, config.rope_theta)
-    k = llama.rope(k, positions, config.rope_theta)
+    q, k, v = llama.qkv_projections(layer, x, positions, config)
 
     # Scatter: token at global position p writes block_table[p // bs]
     # offset p % bs. Padding/inactive rows redirect to scratch block 0
@@ -127,7 +123,16 @@ def _paged_attention_block(layer: dict, x: jax.Array,
 
     # Query head k * reps + r reads key-value head k: the mapping of
     # llama._attention_block's jnp.repeat(k, reps, axis=2).
-    q = q.reshape(B, T, kv_heads, h // kv_heads, d)
+    reps = h // kv_heads
+    q = q.reshape(B, T, kv_heads, reps, d)
+    # One query row per key-value head (a decode step without grouping)
+    # is a matrix-vector product, which the TPU's compiler lowers as a
+    # float32 multiply-reduce over a float32 copy of the gathered keys
+    # (10 ms of a 36 ms step at OLMoE's 16 x 16 heads, PR 25). A second
+    # row of zeros keeps it a matrix product on the keys as they lie.
+    lone_row = T * reps == 1
+    if lone_row:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, 1), (0, 0)))
     scores = jnp.einsum("btkrd,bskd->bkrts", q, keys,
                         preferred_element_type=jnp.float32)
     scores *= d ** -0.5
@@ -135,9 +140,23 @@ def _paged_attention_block(layer: dict, x: jax.Array,
     scores = jnp.where(mask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
     out = jnp.einsum("bkrts,bskd->btkrd", probs, values.astype(dtype))
+    if lone_row:
+        out = out[:, :, :, :reps]
     out = jnp.einsum("blhd,hde->ble", out.reshape(B, T, h, d),
                      layer["wo"].astype(dtype))
     return x + out, pool_k, pool_v
+
+
+def _expert_block(layer: dict, x: jax.Array, config):
+    """The sparse feed-forward of one layer (``models/moe.py``: every
+    token through all ``experts_per_token`` of its experts). Returns
+    (out, the chosen experts [B, T, k])."""
+    normed = llama.rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
+    _, idx, weights = moe.route(normed, layer["w_router"],
+                                config.experts_per_token,
+                                config.norm_topk_prob)
+    combine = moe.combine_weights(idx, weights, config.num_experts)
+    return x + moe.expert_ffn(layer, normed, combine, config.dtype), idx
 
 
 def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
@@ -145,27 +164,56 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
                    config, block_size: int,
                    n_valid: "jax.Array | None" = None):
     """Shared prefill/decode forward over the paged pool. Returns
-    (logits [B, T, V] f32, updated pool). The pool is part of the
-    scan's carry, so every layer updates the one (donated) buffer."""
+    (logits [B, T, V] f32, updated pool, expert counts, routing). The
+    pool is part of the scan's carry, so every layer updates the one
+    (donated) buffer.
+
+    The feed-forward is the configuration's: dense SwiGLU, or the
+    routed experts. For those, ``counts`` is ``moe.routing_counts``
+    summed over the layers (it rides the carry) and ``routing`` the
+    chosen experts [layers, B, T, k], which only a check reads; both
+    are None for a dense model. The tokens counted are the chunk's
+    first ``n_valid``, or without it (decode) the rows at a position
+    past 0: an inactive row carries position 0, and a request's next
+    token never does."""
     x = params["embed"]["tokens"].astype(config.dtype)[tokens]
+    sparse = config.num_experts > 0
+    counts = None
+    if sparse:
+        counts = jnp.zeros((len(moe.EXPERT_COUNTERS),), jnp.int32)
+        valid = positions > 0 if n_valid is None else \
+            jnp.broadcast_to(jnp.arange(tokens.shape[1]) < n_valid,
+                             tokens.shape)
 
     def layer_step(carry, layer_and_index):
-        x, pool_k, pool_v = carry
+        x, pool_k, pool_v, counts = carry
         layer, li = layer_and_index
         x, pool_k, pool_v = _paged_attention_block(
             layer, x, positions, pool_k, pool_v, li, block_tables,
             config, block_size, n_valid=n_valid)
-        x = llama._mlp_block(layer, x, config)
-        return (x, pool_k, pool_v), None
+        if not sparse:
+            return (llama._mlp_block(layer, x, config), pool_k, pool_v,
+                    counts), None
+        x, idx = _expert_block(layer, x, config)
+        counts = counts + moe.routing_counts(idx, valid, config.num_experts)
+        return (x, pool_k, pool_v, counts), idx
 
-    (x, pool_k, pool_v), _ = lax.scan(
-        layer_step, (x, pool["k"], pool["v"]),
+    (x, pool_k, pool_v, counts), routing = lax.scan(
+        layer_step, (x, pool["k"], pool["v"], counts),
         (params["layers"], jnp.arange(config.num_layers)))
     x = llama.rms_norm(x, params["final_norm"], config.rms_norm_eps)
     logits = jnp.einsum("ble,ev->blv", x,
                         params["lm_head"].astype(config.dtype),
                         preferred_element_type=jnp.float32)
-    return logits, {"k": pool_k, "v": pool_v}
+    return logits, {"k": pool_k, "v": pool_v}, counts, routing
+
+
+def _accumulated(stats, counts):
+    """The expert accumulator after this step; None where the caller
+    keeps none or the model has no experts."""
+    if stats is None or counts is None:
+        return None
+    return moe.accumulate(stats, counts)
 
 
 def make_decode_step(config, block_size: int):
@@ -176,9 +224,10 @@ def make_decode_step(config, block_size: int):
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode_step(params, pool, tokens, positions, block_tables, key,
-                    temps):
-        # tokens [B, 1]; positions [B]; block_tables [B, M]; temps [B].
-        logits, pool = _forward_paged(
+                    temps, expert_stats=None):
+        # tokens [B, 1]; positions [B]; block_tables [B, M]; temps [B];
+        # expert_stats: moe.init_stats() of a sparse model, or None.
+        logits, pool, counts, _ = _forward_paged(
             params, pool, tokens, positions[:, None], block_tables,
             config, block_size)
         last = logits[:, -1, :]
@@ -186,7 +235,8 @@ def make_decode_step(config, block_size: int):
         sampled = jax.random.categorical(
             key, last / jnp.maximum(temps, 1e-4)[:, None], axis=-1)
         nxt = jnp.where(temps > 0, sampled, greedy)
-        return nxt.astype(jnp.int32), pool
+        return nxt.astype(jnp.int32), pool, \
+            _accumulated(expert_stats, counts)
 
     return decode_step
 
@@ -198,13 +248,14 @@ def make_prefill_chunk(config, block_size: int):
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, pool, tokens, positions, block_table,
-                      n_valid, last_idx):
+                      n_valid, last_idx, expert_stats=None):
         # tokens [1, C]; positions [1, C]; block_table [1, M];
         # n_valid/last_idx scalars (chunk padding past n_valid goes to
         # scratch; last_idx indexes the final REAL token's logits).
-        logits, pool = _forward_paged(
+        logits, pool, counts, _ = _forward_paged(
             params, pool, tokens, positions, block_table, config,
             block_size, n_valid=n_valid)
-        return logits[0, last_idx, :], pool
+        return logits[0, last_idx, :], pool, \
+            _accumulated(expert_stats, counts)
 
     return prefill_chunk

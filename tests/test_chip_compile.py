@@ -123,9 +123,10 @@ def test_fused_rms_norm_compiles_for_v5e(v5e_chip):
 
 def _lower_paged_step(program, config, batch, block, table, chip):
     """``decode_step`` or ``prefill_chunk`` of the engine, lowered on
-    shapes placed on the described chip; the pool's shape beside it."""
+    shapes placed on the described chip; the pool's shape beside it.
+    A sparse configuration's step carries its expert accumulator."""
     from ray_tpu._private.config import GLOBAL_CONFIG
-    from ray_tpu.models import llama
+    from ray_tpu.models import llama, moe
     from ray_tpu.serve.llm_engine import model as paged_model
 
     def on_chip(shape, dtype=jnp.int32):
@@ -139,16 +140,19 @@ def _lower_paged_step(program, config, batch, block, table, chip):
                   config.num_kv_heads, config.head_dim)
     pool = {"k": on_chip(pool_shape, config.dtype),
             "v": on_chip(pool_shape, config.dtype)}
+    stats = None
+    if config.num_experts > 0:
+        stats = on_chip(jax.eval_shape(moe.init_stats).shape)
     if program == "decode_step":
         lowered = paged_model.make_decode_step(config, block).lower(
             params, pool, on_chip((batch, 1)), on_chip((batch,)),
             on_chip((batch, table)), on_chip((2,), jnp.uint32),
-            on_chip((batch,), jnp.float32))
+            on_chip((batch,), jnp.float32), stats)
     else:
         chunk = GLOBAL_CONFIG.llm_prefill_chunk
         lowered = paged_model.make_prefill_chunk(config, block).lower(
             params, pool, on_chip((1, chunk)), on_chip((1, chunk)),
-            on_chip((1, table)), on_chip(()), on_chip(()))
+            on_chip((1, table)), on_chip(()), on_chip(()), stats)
     return lowered, pool_shape
 
 
@@ -190,6 +194,76 @@ def test_paged_steps_update_the_pool_in_place_on_v5e(v5e_chip, program):
     pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
     assert [line for line in compiled.as_text().splitlines()
             if " copy(" in line and pool_text in line] == []
+
+
+def _olmoe(num_layers=12):
+    """``benchmark/configs/olmoe-1b-7b-serve-1chip.json`` as the
+    harness builds it: OLMoE-1B-7B's widths, 12 of its 16 layers."""
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+        num_layers=num_layers, num_heads=16, num_kv_heads=16, head_dim=128,
+        max_seq_len=2048, num_experts=64, experts_per_token=8, qk_norm=True)
+
+
+# A float32 tensor the size of one layer's experts, alone or stacked.
+F32_EXPERTS = r"f32\[(\d+,)?64,(2048,1024|1024,2048)\]"
+
+
+@pytest.mark.parametrize("program, temporaries_mib", [
+    ("decode_step", 160), ("prefill_chunk", 16)])
+def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
+        v5e_chip, program, temporaries_mib):
+    """The OLMoE serve cell's two programs at its real size (16 rows x
+    2048 positions, 12 layers: 12.76 GiB of arguments). What must not
+    appear: a float32 copy of an expert tensor (1.5 GiB a layer) or a
+    transposed bf16 one (768 MiB a layer: a flat ``bth,ehm->btem``
+    product made the compiler transpose each [64, 2048, 1024] whole);
+    a copy of the donated pool; a float32 copy of a layer's gathered
+    keys (256 MiB: a decode step's lone query row per head made the
+    scores a matrix-vector product, which the compiler widened the keys
+    for, until ``_paged_attention_block`` put a row of zeros beside
+    it). The decode program's 129 MiB of temporaries are one gathered
+    ``bf16[2048,16,16,128]`` (with 16 key-value heads it no longer fits
+    the memory space Mistral's 64 MiB ones live in); the chunk program
+    has none to speak of."""
+    import re
+
+    lowered, pool_shape = _lower_paged_step(program, _olmoe(), 16, 16, 128,
+                                            v5e_chip)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < temporaries_mib * 2 ** 20
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 0.9 * 15.75 * 2 ** 30)
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
+    text = compiled.as_text()
+    assert re.search(F32_EXPERTS, text) is None
+    assert re.search(r"= f32\[(2048,16|16,2048),16,128\]", text) is None
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    # The accumulator rides along: int32 [2, 4] in, the same out.
+    assert "s32[2,4]" in text
+
+
+def test_serving_params_never_hold_a_float32_expert_tensor_on_v5e(v5e_chip):
+    """``[12, 64, 2048, 1024]`` is 6 GiB in float32: the cast runs in
+    the initialisation's own program, which must keep no such buffer
+    (float32 values exist inside its fusions only: the temporaries
+    say so, the text cannot)."""
+    from ray_tpu.models import llama
+
+    config = _olmoe()
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip)
+    compiled = jax.jit(lambda key: jax.tree.map(
+        lambda x: x.astype(config.dtype),
+        llama.init_params(config, key))).lower(key).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    # bf16 throughout, to the tiling's padding of the small scales.
+    assert 0 <= memory.output_size_in_bytes - 2 * config.num_params < 2 ** 20
 
 
 def test_vmem_rule_admits_the_main_path():
@@ -501,3 +575,20 @@ def test_chip_smoke_train_and_serve_phases(smoke, capsys):
     out = capsys.readouterr().out
     assert "step_programs=1 compiles_after_first_step=0" in out
     assert "greedy continuation equals the reference argmax" in out
+
+
+@pytest.mark.parametrize("config", ["olmoe-1b-7b-serve-1chip",
+                                    "mistral7b-serve-1chip"])
+def test_chip_smoke_paged_logits_mode(smoke, capsys, config):
+    """``--paged-logits <configuration>`` at the file's rehearsal size:
+    the engine's two programs against the configuration's own plain
+    reference, a sparse one with its expert choices compared and its
+    long context, a dense one without."""
+    smoke.phase_paged_logits(
+        os.path.join(REPO, "benchmark", "configs", config + ".json"), 7,
+        True, CPU_DEVICE)
+    out = capsys.readouterr().out
+    assert "logits of the paged programs against the float32 reference" in out
+    sparse = config.startswith("olmoe")
+    assert ("expert_choices=0 " not in out) == sparse
+    assert ("sequences=[9, 15, 23, 64]" in out) == sparse

@@ -217,7 +217,7 @@ def test_paged_steps_with_grouped_heads_match_full_forward(dtype_name):
             tokens[0, :n] = prompt[start:start + n]
             positions = np.zeros((1, chunk), np.int32)
             positions[0, :n] = np.arange(start, start + n)
-            logits, pool = prefill(
+            logits, pool, _ = prefill(
                 params, pool, jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(bt[i:i + 1]), np.int32(n), np.int32(n - 1))
         first_logits.append(np.asarray(logits, np.float32))
@@ -228,7 +228,7 @@ def test_paged_steps_with_grouped_heads_match_full_forward(dtype_name):
     for _ in range(steps - 1):
         last = np.zeros((len(bt), 1), np.int32)
         last[:len(prompts), 0] = [g[-1] for g in generated]
-        nxt, pool = decode(
+        nxt, pool, _ = decode(
             params, pool, jnp.asarray(last),
             jnp.asarray(lengths + [0], dtype=jnp.int32), jnp.asarray(bt),
             jax.random.PRNGKey(0), jnp.zeros((len(bt),), jnp.float32))

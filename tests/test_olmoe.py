@@ -1,0 +1,487 @@
+"""The sparse block OLMoE publishes (top-k of E dropless experts,
+QK-norm) through ``models/llama.py``, ``models/moe.py`` and the paged
+engine, held to the plain float32 reference
+``benchmark/reference/olmoe_decoder.py`` at a small size on the CPU.
+Logits are compared, not tokens: with random weights the largest logit
+changes on rounding.
+
+Tolerances. float32 against float32: 1e-4 absolute on logits of
+standard deviation about 1 (summation order only; PR 24's bound for the
+dense step). bf16 program against the float32 reference on the same
+bf16 weights: a bf16 rounding is 2^-8 = 4e-3 of a value and a 3-layer
+stack with 64-wide contractions lands within 6e-2 of a logit's standard
+deviation where both sides chose the same experts. Where they did not
+(two of a token's router probabilities closer than the bf16 noise of
+the hidden state, so each side serves the token with another of the two
+near-equal experts), that token's layer output differs by about one
+expert's contribution, and every later position of the sequence sees it
+through attention: those positions are bounded by 0.5 standard
+deviations, and the differing choices are counted and held under 3% of
+all choices. The routing arithmetic is float32 on both sides.
+"""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark.reference import olmoe_decoder as reference  # noqa: E402
+from ray_tpu.models import llama, moe  # noqa: E402
+from ray_tpu.serve.llm_engine import PagedKVCache  # noqa: E402
+from ray_tpu.serve.llm_engine import model as paged_model  # noqa: E402
+
+F32_ATOL = 1e-4
+BF16_SAME_EXPERTS = 6e-2   # x the reference logits' standard deviation
+BF16_OTHER_EXPERT = 0.5    # the same, downstream of a differing choice
+BF16_DIFFERING_SHARE = 0.03
+
+
+def small(dtype=jnp.float32, **changes) -> llama.LlamaConfig:
+    """3 layers, 8 experts of which 3 per token, 4 heads, QK-norm."""
+    return dataclasses.replace(llama.LlamaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=32, num_layers=3,
+        num_heads=4, num_kv_heads=4, head_dim=16, max_seq_len=48,
+        remat=False, dtype=dtype, num_experts=8, experts_per_token=3,
+        qk_norm=True), **changes)
+
+
+def hf_keys(cfg: llama.LlamaConfig) -> dict:
+    """What the reference is given: the configuration file's keys."""
+    return {"rms_norm_eps": cfg.rms_norm_eps, "rope_theta": cfg.rope_theta,
+            "num_experts_per_tok": cfg.experts_per_token,
+            "norm_topk_prob": cfg.norm_topk_prob}
+
+
+def unit_scales_perturbed(params: dict, seed: int = 11) -> dict:
+    """init_params sets every norm scale to one, which would hide a
+    scale applied to the wrong axis: scatter them around one."""
+    rng = np.random.default_rng(seed)
+    layers = dict(params["layers"])
+    for name in ("attn_norm", "mlp_norm", "q_norm", "k_norm"):
+        if name in layers:
+            scale = 1.0 + 0.2 * rng.standard_normal(layers[name].shape)
+            layers[name] = jnp.asarray(scale, layers[name].dtype)
+    return {**params, "layers": layers}
+
+
+def differing(program_routing, reference_routing) -> np.ndarray:
+    """[..., k] bool: a choice of the program's that the reference did
+    not make too, at the same layer and position."""
+    ours, theirs = np.asarray(program_routing), np.asarray(reference_routing)
+    return ~(ours[..., :, None] == theirs[..., None, :]).any(-1)
+
+
+# ------------------------------------------------------- (c) the routing
+
+
+@pytest.mark.parametrize("k, norm_topk_prob", [
+    (3, False), (3, True), (1, False), (8, True)])
+def test_route_is_top_k_of_a_float32_softmax(k, norm_topk_prob):
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 7, 16), jnp.bfloat16)
+    w_router = jax.random.normal(jax.random.PRNGKey(1), (16, 8), jnp.bfloat16)
+    probs, idx, weights = moe.route(x, w_router, k, norm_topk_prob)
+    assert probs.dtype == weights.dtype == jnp.float32
+    with jax.default_matmul_precision("highest"):
+        want = jax.nn.softmax(
+            x.astype(jnp.float32) @ w_router.astype(jnp.float32), axis=-1)
+    want_weights, want_idx = jax.lax.top_k(want, k)
+    if norm_topk_prob:
+        want_weights = want_weights / want_weights.sum(-1, keepdims=True)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(want_idx))
+    np.testing.assert_allclose(np.asarray(probs), np.asarray(want), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(weights), np.asarray(want_weights),
+                               atol=1e-6)
+    total = np.asarray(weights.sum(-1))
+    if norm_topk_prob or k == 8:
+        np.testing.assert_allclose(total, 1.0, atol=1e-6)
+    else:
+        assert (total < 1.0).all()  # left as they are: not renormalised
+    combine = moe.combine_weights(idx, weights, 8)
+    assert ((np.asarray(combine) > 0).sum(-1) == k).all()
+    np.testing.assert_allclose(np.asarray(combine.sum(-1)), total, atol=1e-6)
+
+
+# ------------------------------------- no token dropped, no expert skipped
+
+
+def crowded_layer(cfg, seed=0):
+    """One layer whose router sends EVERY token to expert 0 (a column
+    of ones against positive inputs) and spreads the other choices."""
+    params = llama.init_params(cfg, jax.random.PRNGKey(seed))
+    layer = jax.tree.map(lambda p: p[0], params["layers"])
+    router = 0.05 * jax.random.normal(jax.random.PRNGKey(seed + 1),
+                                      layer["w_router"].shape)
+    layer["w_router"] = router.at[:, 0].set(1.0)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(seed + 2),
+                                  (2, 12, cfg.hidden_size))) + 0.1
+    return layer, x
+
+
+@pytest.mark.parametrize("path", ["llama._moe_block", "engine._expert_block"])
+def test_one_expert_receives_every_token_and_drops_none(path):
+    """24 tokens on one expert of 8 (any capacity factor under 8 would
+    have dropped some): every token still gets all 3 of its experts,
+    on the training path and on the engine's."""
+    cfg = small()
+    layer, x = crowded_layer(cfg)
+    if path == "llama._moe_block":
+        got, _ = llama._moe_block(layer, x, cfg)
+    else:
+        got, idx = paged_model._expert_block(layer, x, cfg)
+        assert (np.asarray(idx) == 0).any(-1).all()  # expert 0, every token
+        counts = np.asarray(moe.routing_counts(
+            idx, jnp.ones(x.shape[:2], bool), cfg.num_experts))
+        assert counts[0] == 24 * 3 and counts[3] == 8 * 24  # peak: all 24
+    with jax.default_matmul_precision("highest"):
+        m = reference.rms_norm(x, layer["mlp_norm"], cfg.rms_norm_eps)
+        want_idx, want_weights = reference.route(m, layer["w_router"], 3,
+                                                 False)
+        want = x + reference.experts(m, layer, want_idx, want_weights)
+    assert (np.asarray(want_idx)[..., 0] == 0).all()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    # And it matters: without its crowded expert every token changes.
+    without = x + reference.experts(
+        m, layer, want_idx, want_weights.at[..., 0].set(0.0))
+    assert (np.abs(np.asarray(want - without)).max(-1) > 1e-3).all()
+
+
+def test_an_expert_nobody_chose_contributes_nothing():
+    """All-experts product, so an unchosen expert IS computed: whatever
+    its activations come to (here NaN, as an overflow would leave) must
+    not reach a token that did not choose it."""
+    cfg = small()
+    layer, x = crowded_layer(cfg)
+    layer["w_router"] = layer["w_router"].at[:, 5].set(-1.0)  # never chosen
+    clean, _ = llama._moe_block(layer, x, cfg)
+    for name in ("w_gate", "w_up"):
+        layer[name] = layer[name].at[5].set(jnp.nan)
+    poisoned, _ = llama._moe_block(layer, x, cfg)
+    assert np.isfinite(np.asarray(poisoned)).all()
+    np.testing.assert_array_equal(np.asarray(poisoned), np.asarray(clean))
+
+
+# ------------------------------------------ (a) llama.forward, the logits
+
+
+@pytest.mark.parametrize("dtype_name, norm_topk_prob", [
+    ("float32", False), ("float32", True), ("bfloat16", False)])
+def test_forward_matches_the_reference_logits(dtype_name, norm_topk_prob):
+    dtype = jnp.dtype(dtype_name)
+    cfg = small(dtype, norm_topk_prob=norm_topk_prob)
+    params = unit_scales_perturbed(paged_model.serving_params(cfg, None, 5))
+    tokens = jax.random.randint(jax.random.PRNGKey(2), (3, 40), 0,
+                                cfg.vocab_size)
+    got = np.asarray(llama.forward(params, tokens, cfg), np.float32)
+    want, routing = reference.forward(params, tokens, hf_keys(cfg),
+                                      with_routing=True)
+    want = np.asarray(want)
+    assert routing.shape == (3, 3, 40, 3)
+    if dtype == jnp.float32:
+        np.testing.assert_allclose(got, want, atol=F32_ATOL)
+    else:
+        # llama.forward does not say what it chose: bounded as a
+        # position downstream of a differing choice may be, and
+        # almost everywhere as one that is not.
+        error = np.abs(got - want).max(-1) / want.std()
+        assert error.max() <= BF16_OTHER_EXPERT, error.max()
+        assert np.mean(error <= BF16_SAME_EXPERTS) >= 0.9, error
+
+
+def test_a_dense_configuration_still_equals_the_dense_reference():
+    """(d) The changed attention and feed-forward code on a
+    configuration without experts or QK-norm."""
+    from benchmark.reference import dense_decoder
+
+    cfg = small(num_experts=0, qk_norm=False, num_kv_heads=2,
+                intermediate_size=96)
+    params = unit_scales_perturbed(llama.init_params(
+        cfg, jax.random.PRNGKey(4)))
+    assert "q_norm" not in params["layers"]
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 24), 0,
+                                cfg.vocab_size)
+    want = dense_decoder.forward(params, tokens, hf_keys(cfg))
+    np.testing.assert_allclose(
+        np.asarray(llama.forward(params, tokens, cfg)), np.asarray(want),
+        atol=F32_ATOL)
+    # The sparse reference without experts' keys in the tree is not
+    # this model; with QK-norm scales of one it still differs from
+    # dense (the norm itself), so the flag is not a no-op.
+    normed = dataclasses.replace(cfg, qk_norm=True)
+    with_norm = llama.forward(llama.init_params(
+        normed, jax.random.PRNGKey(4)), tokens, normed)
+    assert np.abs(np.asarray(with_norm) - np.asarray(want)).max() > 1e-2
+
+
+def test_reference_tail_equals_its_full_forward():
+    cfg = small()
+    params = unit_scales_perturbed(llama.init_params(
+        cfg, jax.random.PRNGKey(6)))
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (1, 48), 0,
+                                cfg.vocab_size)
+    full, routing = reference.forward(params, tokens, hf_keys(cfg),
+                                      with_routing=True)
+    tail, tail_routing = reference.forward_tail(params, tokens, hf_keys(cfg),
+                                                tail=16)
+    np.testing.assert_allclose(np.asarray(tail), np.asarray(full[:, -16:]),
+                               atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(tail_routing),
+                                  np.asarray(routing))
+
+
+# --------------------- (b) the engine's programs over the pool, the logits
+
+
+@pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
+def test_paged_programs_match_the_reference_logits(dtype_name):
+    """Driven as the engine drives them: shuffled, interleaved block
+    tables, ragged prompts in chunks with a padded last chunk, then
+    batched decode with one inactive row. The logits of every position
+    from the last prompt token on (the chunk program's own output, and
+    the decode program's forward, ``_forward_paged``, jitted here to
+    show them) against the reference's full forward pass; the decode
+    program's token is the argmax of those logits and its pool the
+    same; its expert counters count the tokens that carry a request."""
+    dtype = jnp.dtype(dtype_name)
+    cfg = small(dtype)
+    num_blocks, block, chunk, width, steps = 40, 4, 4, 10, 6
+    prompts = [[7, 3, 11, 200, 5], list(range(20, 31)), [9, 1, 4],
+               list(range(100, 114))]
+    params = unit_scales_perturbed(paged_model.serving_params(cfg, None, 3))
+    rng = np.random.default_rng(0)
+    deck = [int(b) for b in rng.permutation(np.arange(1, num_blocks))]
+    need = [-(-(len(p) + steps) // block) for p in prompts]
+    tables = [[] for _ in prompts]
+    for turn in range(max(need)):
+        for i, n in enumerate(need):
+            if turn < n:
+                tables[i].append(deck.pop())
+    bt = np.zeros((len(prompts) + 1, width), np.int32)  # last row inactive
+    for i, table in enumerate(tables):
+        bt[i, :len(table)] = table
+    pool = PagedKVCache.init_pool(cfg, num_blocks, block)
+    prefill = paged_model.make_prefill_chunk(cfg, block)
+    decode = paged_model.make_decode_step(cfg, block)
+    shown = jax.jit(lambda params, pool, tokens, positions, tables:
+                    paged_model._forward_paged(
+                        params, pool, tokens, positions[:, None], tables,
+                        cfg, block))
+    stats = moe.init_stats()
+
+    got = [[] for _ in prompts]       # logits from the last prompt token on
+    prefilled_tokens = chunks = 0
+    for i, prompt in enumerate(prompts):
+        for start in range(0, len(prompt), chunk):
+            n = min(chunk, len(prompt) - start)
+            tokens = np.zeros((1, chunk), np.int32)
+            tokens[0, :n] = prompt[start:start + n]
+            positions = np.zeros((1, chunk), np.int32)
+            positions[0, :n] = np.arange(start, start + n)
+            logits, pool, stats = prefill(
+                params, pool, jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(bt[i:i + 1]), np.int32(n), np.int32(n - 1),
+                stats)
+            prefilled_tokens += n
+            chunks += 1
+        got[i].append(np.asarray(logits, np.float32))
+    assert any(len(p) % chunk for p in prompts)  # a padded last chunk ran
+
+    generated = [[int(rows[0].argmax())] for rows in got]
+    program_routing = [[] for _ in prompts]
+    lengths = [len(p) for p in prompts]
+    for _ in range(steps - 1):
+        last = np.zeros((len(bt), 1), np.int32)
+        last[:len(prompts), 0] = [g[-1] for g in generated]
+        at = jnp.asarray(lengths + [0], dtype=jnp.int32)
+        logits, want_pool, _, routing = shown(
+            params, pool, jnp.asarray(last), at, jnp.asarray(bt))
+        nxt, pool, stats = decode(
+            params, pool, jnp.asarray(last), at, jnp.asarray(bt),
+            jax.random.PRNGKey(0), jnp.zeros((len(bt),), jnp.float32), stats)
+        for name in ("k", "v"):
+            np.testing.assert_array_equal(
+                np.asarray(pool[name].astype(jnp.float32)),
+                np.asarray(want_pool[name].astype(jnp.float32)))
+        logits = np.asarray(logits, np.float32)[:, 0]
+        for i, (g, token) in enumerate(zip(generated, np.asarray(nxt))):
+            assert int(token) == int(logits[i].argmax())
+            got[i].append(logits[i])
+            program_routing[i].append(np.asarray(routing)[:, i, 0])
+            g.append(int(token))
+        lengths = [n + 1 for n in lengths]
+
+    # The counters: tokens that carried a request, and only those.
+    counted = moe.read_stats(stats)
+    layers, k, experts = cfg.num_layers, cfg.experts_per_token, cfg.num_experts
+    decoded = len(prompts) * (steps - 1)
+    assert counted["expert_choices"] == (prefilled_tokens + decoded) * k * layers
+    assert counted["expert_slots"] == (chunks + steps - 1) * experts * layers
+    assert 0 < counted["experts_touched"] <= counted["expert_slots"]
+    assert counted["expert_peak_choices"] >= counted["expert_choices"]
+
+    # The reference: teacher-forced full-context logits, same weights.
+    differing_choices = choices = 0
+    worst_same = worst_other = 0.0
+    for i, prompt in enumerate(prompts):
+        row = prompt + generated[i][:-1]
+        want, routing = reference.forward(
+            params, jnp.asarray([row], dtype=jnp.int32), hf_keys(cfg),
+            with_routing=True)
+        want = np.asarray(want[0])[len(prompt) - 1:]
+        ours = np.stack(got[i])
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(ours, want, atol=F32_ATOL)
+            assert generated[i] == [int(r.argmax()) for r in want]
+        # Decode positions' choices against the reference's.
+        theirs = np.asarray(routing)[:, 0, len(prompt):]      # [n, steps-1, k]
+        flips = differing(np.stack(program_routing[i], axis=1), theirs)
+        differing_choices += int(flips.sum())
+        choices += flips.size
+        # From the first differing choice on, later positions see it.
+        after = np.concatenate([[False], flips.any((0, 2)).cumsum() > 0])
+        error = np.abs(ours - want).max(-1) / want.std()
+        worst_same = max(worst_same, float(error[~after].max()))
+        if after.any():
+            worst_other = max(worst_other, float(error[after].max()))
+    print(f"{dtype_name}: {differing_choices} of {choices} expert choices "
+          f"differ from the float32 reference's; worst logit difference "
+          f"{worst_same:.4f} of a standard deviation with the same "
+          f"experts, {worst_other:.4f} after a differing choice")
+    if dtype == jnp.float32:
+        assert differing_choices == 0
+    else:
+        assert differing_choices <= BF16_DIFFERING_SHARE * choices
+        assert worst_same <= BF16_SAME_EXPERTS
+        assert worst_other <= BF16_OTHER_EXPERT
+
+    # Written: the positions each table covers, and the scratch block.
+    written = np.zeros((num_blocks, block), bool)
+    written[0, 0] = True
+    for table, n in zip(tables, lengths):
+        for p in range(n):
+            written[table[p // block], p % block] = True
+    for name in ("k", "v"):
+        touched = np.asarray(pool[name].astype(jnp.float32) != 0).any(
+            axis=(0, 3, 4))
+        np.testing.assert_array_equal(touched, written)
+
+
+# --------------------------------------------------- the engine end to end
+
+
+def test_engine_serves_a_sparse_model_and_counts_its_experts():
+    from ray_tpu.serve.llm_engine import ENGINE_STAT_KEYS, LLMEngine
+
+    assert set(moe.EXPERT_COUNTERS) <= set(ENGINE_STAT_KEYS)
+    cfg = small()
+    engine = LLMEngine(cfg, max_batch_size=4, max_seq_len=48, block_size=8,
+                       prefill_chunk=8, seed=0)
+    try:
+        assert engine.engine_stats()["expert_slots"] == 0
+        prompts = [[5, 9, 2, 7], list(range(30, 49)), [1] * 11]
+        requests = [engine.submit(p, max_new_tokens=6) for p in prompts]
+        outputs = [engine.result(r, timeout_s=120) for r in requests]
+        stats = engine.engine_stats()
+        assert set(stats) == set(ENGINE_STAT_KEYS)
+        assert all(isinstance(stats[k], int) for k in moe.EXPERT_COUNTERS)
+        per_token = cfg.experts_per_token * cfg.num_layers
+        assert stats["expert_choices"] == per_token * (
+            stats["prefill_tokens"] + stats["decode_tokens"])
+        assert stats["expert_slots"] == cfg.num_experts * cfg.num_layers * (
+            stats["prefill_chunks"] + stats["decode_steps"])
+        assert stats["expert_choices"] <= stats["expert_peak_choices"]
+        assert stats["expert_peak_choices"] // cfg.num_experts <= \
+            stats["expert_choices"] // cfg.experts_per_token
+        for prompt, output in zip(prompts, outputs):
+            row = prompt + output[:-1]
+            want = np.asarray(reference.forward(
+                engine.params, jnp.asarray([row], jnp.int32),
+                hf_keys(cfg))[0])[len(prompt) - 1:]
+            assert output == [int(r.argmax()) for r in want]
+    finally:
+        engine.shutdown()
+
+
+def test_a_dense_engine_keeps_no_expert_accumulator(monkeypatch):
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    engine = LLMEngine(small(num_experts=0, qk_norm=False),
+                       max_batch_size=2, max_seq_len=32, block_size=8,
+                       prefill_chunk=8, seed=0)
+    try:
+        assert engine._expert_stats is None
+        engine.result(engine.submit([3, 4, 5], max_new_tokens=3),
+                      timeout_s=120)
+        assert engine._expert_stats is None
+        stats = engine.engine_stats()
+        assert all(stats[k] == 0 for k in moe.EXPERT_COUNTERS)
+    finally:
+        engine.shutdown()
+
+
+def test_the_accumulator_never_wraps():
+    """Two 30-bit words a counter: 2**31 and more is carried, not
+    wrapped (at 92k choices a second an int32 would wrap in 6.5 h)."""
+    big = jnp.asarray([2 ** 30 - 1, 7, 0, 2 ** 29], jnp.int32)
+    stats = moe.init_stats()
+    for _ in range(5):
+        stats = jax.jit(moe.accumulate)(stats, big)
+    assert np.asarray(stats).dtype == np.int32
+    assert list(moe.read_stats(stats).values()) == [
+        5 * (2 ** 30 - 1), 35, 0, 5 * 2 ** 29]
+
+
+def test_routing_counts_skip_tokens_that_carry_no_request():
+    idx = jnp.asarray([[[0, 1]], [[0, 2]], [[0, 3]], [[5, 6]]])  # [B=4,T=1,k]
+    valid = jnp.asarray([[True], [True], [True], [False]])
+    assert list(np.asarray(moe.routing_counts(idx, valid, 8))) == [
+        6, 8, 4, 8 * 3]  # 3 tokens x 2; E; experts 0..3; E x load 3
+
+
+# ------------------------------------------------- configuration, counting
+
+
+def test_parameter_counts_follow_the_tree():
+    cfg = small()
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    assert cfg.num_params == sum(x.size for x in jax.tree.leaves(params))
+    assert set(llama.param_logical_axes(cfg)["layers"]) == \
+        set(params["layers"])
+    inactive = (cfg.num_experts - cfg.experts_per_token) * 3 \
+        * cfg.hidden_size * cfg.intermediate_size * cfg.num_layers
+    assert cfg.num_active_params == cfg.num_params - inactive
+    one = dataclasses.replace(cfg, experts_per_token=1)
+    assert llama.flops_per_token(cfg, 32) - llama.flops_per_token(one, 32) \
+        == 6.0 * 2 * 3 * cfg.hidden_size * cfg.intermediate_size \
+        * cfg.num_layers
+
+
+def test_olmoe_at_published_widths_counts_as_published():
+    """6.92G parameters, 1.3G active (the model card's 7B / 1B)."""
+    cfg = llama.LlamaConfig(
+        vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+        num_layers=16, num_heads=16, num_kv_heads=16, head_dim=128,
+        num_experts=64, experts_per_token=8, qk_norm=True)
+    assert round(cfg.num_params / 1e9, 2) == 6.92
+    assert round(cfg.num_active_params / 1e9, 2) == 1.28
+
+
+def test_paths_that_cannot_serve_it_say_so():
+    cfg = small()
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    cache = llama.init_kv_cache(cfg, 1, 8)
+    with pytest.raises(NotImplementedError, match="paged engine"):
+        llama.forward_with_cache(params, jnp.zeros((1, 2), jnp.int32), cache,
+                                 jnp.zeros((1, 2), jnp.int32), cfg)
+    layer = jax.tree.map(lambda p: p[0], params["layers"])
+    with pytest.raises(NotImplementedError, match="QK-norm"):
+        llama._attention_block(layer, jnp.zeros((1, 2, 64)),
+                               jnp.zeros((1, 2), jnp.int32), cfg,
+                               tp_axis="tp")
