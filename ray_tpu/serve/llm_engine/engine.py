@@ -17,11 +17,12 @@ engine registry below.
 On the record: every phase of a loop pass is a ``tracing.phase`` (an
 ``engine.iteration`` span with leaf spans ``engine.sweep``,
 ``engine.prefill.schedule`` / ``.launch`` / ``.first_token``,
-``engine.decode.schedule`` / ``.split_key`` / ``.launch`` / ``.fetch``
-/ ``.emit`` and ``engine.idle``), so a profiler session shows on the
-device trace's clock what the host did in a device idle gap; the time
-counters (``loop_wall_us`` ... ``decode_host_us``) say the same to
-``/metrics`` without a session; a request's four stamps become the
+``engine.decode.schedule`` / ``.launch`` / ``.fetch`` / ``.emit`` and
+``engine.idle``), so a profiler session shows on the device trace's
+clock what the host did in a device idle gap; the time counters
+(``loop_wall_us`` ... ``decode_host_us``) say the same to ``/metrics``
+without a session, and ``host_calls`` counts the calls into JAX, each
+of which lets go of the interpreter; a request's four stamps become the
 spans ``llm.request`` > ``llm.queue`` / ``llm.prefill`` /
 ``llm.decode`` at its seal while tracing is armed.
 
@@ -41,7 +42,7 @@ import weakref
 
 import numpy as np
 
-from ray_tpu._private import chaos, lock_witness
+from ray_tpu._private import chaos, jax_compat, lock_witness
 from ray_tpu.exceptions import CacheExhaustedError, GetTimeoutError
 from ray_tpu.models.moe import init_stats, read_stats
 from ray_tpu.serve.llm_engine import model as paged_model
@@ -76,6 +77,10 @@ ENGINE_STAT_KEYS = (
     # that made progress.
     "first_tokens", "queue_wait_us", "prefill_us",
     "loop_wall_us", "loop_cpu_us", "fetch_wait_us", "decode_host_us",
+    # Calls the engine thread made into JAX (a dispatch with its one
+    # host array, a blocking read): two per decode step, one per
+    # prefill chunk, and the first token's sampling and read.
+    "host_calls",
     # What a sparse model's routing did (models/moe.py), summed over
     # layers and steps ON THE DEVICE in an array the jitted steps carry;
     # read from it when engine_stats() is asked, by no step. Zero for a
@@ -143,7 +148,12 @@ class LLMEngine:
         # holds an array that stays valid. None for a dense model.
         self._expert_stats = init_stats() \
             if self.config.num_experts > 0 else None
-        self._key = jax.random.PRNGKey(seed + 1)
+        # The sampling key lives on the device: the decode program
+        # splits it and returns the carry. Made by a program under the
+        # engine's mesh, it is placed as that carry will be, so the
+        # first step's program is every later step's too.
+        with jax_compat.set_mesh(mesh):
+            self._key = jax.jit(lambda: jax.random.PRNGKey(seed + 1))()
         self._counters: "dict[str, int]" = {k: 0 for k in ENGINE_STAT_KEYS}
         self._pass = _PassClock()
         self._lock = lock_witness.Condition("llm_engine.LLMEngine.state")
@@ -157,11 +167,13 @@ class LLMEngine:
 
     @functools.cached_property
     def _decode_step(self):
-        return paged_model.make_decode_step(self.config, self.block_size)
+        return paged_model.make_engine_decode_step(
+            self.config, self.block_size)
 
     @functools.cached_property
     def _prefill_step(self):
-        return paged_model.make_prefill_chunk(self.config, self.block_size)
+        return paged_model.make_engine_prefill_chunk(
+            self.config, self.block_size, self.prefill_chunk_len)
 
     # ----------------------------------------------------------- public API
 
@@ -399,30 +411,20 @@ class LLMEngine:
         if status == "victim":
             return True  # re-queued; pressure eased — progress made
 
-        import jax.numpy as jnp
-
-        from ray_tpu._private import jax_compat
-
         with tracing.phase("engine.prefill.launch", req=req.rid, tokens=n):
-            chunk = self.prefill_chunk_len
-            tokens = np.zeros((1, chunk), dtype=np.int32)
-            tokens[0, :n] = req.context[start:start + n]
-            positions = np.zeros((1, chunk), dtype=np.int32)
-            positions[0, :n] = np.arange(start, start + n)
-            bt = np.zeros((1, self.blocks_per_seq), dtype=np.int32)
-            bt[0, :len(table)] = table
+            chunk = paged_model.pack_prefill_chunk(
+                self.prefill_chunk_len, self.blocks_per_seq,
+                req.context[start:start + n], start, table)
             try:
                 with jax_compat.set_mesh(self._mesh):
                     last_logits, self._pool, self._expert_stats = \
-                        self._prefill_step(
-                            self.params, self._pool, jnp.asarray(tokens),
-                            jnp.asarray(positions), jnp.asarray(bt),
-                            np.int32(n), np.int32(n - 1),
-                            self._expert_stats)
+                        self._prefill_step(self.params, self._pool, chunk,
+                                           self._expert_stats)
             except Exception as exc:  # noqa: BLE001 — donated pool is gone
                 self._reset_after_failure(exc)
                 return True
             with self._lock:
+                self._counters["host_calls"] += 1
                 self._counters["prefill_chunks"] += 1
                 self._counters["prefill_tokens"] += n
                 req.prefilled += n
@@ -467,6 +469,9 @@ class LLMEngine:
                 sub, last_logits / max(req.temperature, 1e-4))
         else:
             token = jnp.argmax(last_logits)
+        # Host-dispatched, once a request: the argmax, or the split,
+        # its unpacking, the division and the draw; then the read.
+        self._counters["host_calls"] += 5 if req.temperature > 0 else 2
         return self._fetch(int, token)
 
     def _fetch(self, read, array):
@@ -507,40 +512,30 @@ class LLMEngine:
             if not active:
                 return True  # everything preempted: progress made
             span.set(rows=len(active))
-            B = self.max_batch
-            tokens = np.zeros((B, 1), dtype=np.int32)
-            positions = np.zeros((B,), dtype=np.int32)
-            tables = np.zeros((B, self.blocks_per_seq), dtype=np.int32)
-            temps = np.zeros((B,), dtype=np.float32)
-            for i, req in enumerate(active):
-                tokens[i, 0] = req.last_token
-                positions[i] = req.position
-                tables[i, :len(req.block_table)] = req.block_table
-                temps[i] = req.temperature
+            rows = paged_model.pack_decode_rows(
+                self.max_batch, self.blocks_per_seq,
+                ((req.last_token, req.position, req.temperature,
+                  req.block_table) for req in active))
 
         self._maybe_chaos_slow_step()
-        import jax
-        import jax.numpy as jnp
-
-        from ray_tpu._private import jax_compat
-
-        with tracing.phase("engine.decode.split_key"):
-            self._key, sub = jax.random.split(self._key)
         try:
             with tracing.phase("engine.decode.launch", rows=len(active)), \
                     jax_compat.set_mesh(self._mesh):
-                nxt, self._pool, self._expert_stats = self._decode_step(
-                    self.params, self._pool, jnp.asarray(tokens),
-                    jnp.asarray(positions), jnp.asarray(tables), sub,
-                    jnp.asarray(temps), self._expert_stats)
+                nxt, self._pool, self._expert_stats, key = \
+                    self._decode_step(self.params, self._pool, rows,
+                                      self._key, self._expert_stats)
             with tracing.phase("engine.decode.fetch"):
                 nxt = self._fetch(np.asarray, nxt)
         except Exception as exc:  # noqa: BLE001 — donated pool is gone
             self._reset_after_failure(exc)
             return True
+        # The step's own split of the key, kept once the step is known
+        # to have run: a failed step leaves the key it was given.
+        self._key = key
         self._pass.decoded = True
         with tracing.phase("engine.decode.emit", rows=len(active)) as span:
             with self._lock:
+                self._counters["host_calls"] += 2  # the call, the read
                 self._counters["decode_steps"] += 1
                 if len(active) >= 2:
                     self._counters["batched_decode_steps"] += 1

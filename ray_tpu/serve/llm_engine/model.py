@@ -45,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ray_tpu.models import llama, moe
@@ -216,13 +217,9 @@ def _accumulated(stats, counts):
     return moe.accumulate(stats, counts)
 
 
-def make_decode_step(config, block_size: int):
-    """The ONE batched decode program: every active ragged request
-    advances one token through a shared ``[B, 1]`` step. Inactive rows
-    carry all-zero tables/positions (scratch writes, discarded
-    samples)."""
+def _decode_body(config, block_size: int):
+    """One decode step, traced by whichever program wraps it."""
 
-    @functools.partial(jax.jit, donate_argnums=(1,))
     def decode_step(params, pool, tokens, positions, block_tables, key,
                     temps, expert_stats=None):
         # tokens [B, 1]; positions [B]; block_tables [B, M]; temps [B];
@@ -241,12 +238,9 @@ def make_decode_step(config, block_size: int):
     return decode_step
 
 
-def make_prefill_chunk(config, block_size: int):
-    """The ONE prefill program: a fixed-length chunk of one request's
-    prompt scatters into its block table; only the final chunk's
-    ``last_idx`` logits row is consumed (the first generated token)."""
+def _prefill_body(config, block_size: int):
+    """One prefill chunk, traced by whichever program wraps it."""
 
-    @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_chunk(params, pool, tokens, positions, block_table,
                       n_valid, last_idx, expert_stats=None):
         # tokens [1, C]; positions [1, C]; block_table [1, M];
@@ -257,5 +251,90 @@ def make_prefill_chunk(config, block_size: int):
             block_size, n_valid=n_valid)
         return logits[0, last_idx, :], pool, \
             _accumulated(expert_stats, counts)
+
+    return prefill_chunk
+
+
+def make_decode_step(config, block_size: int):
+    """The ONE batched decode program: every active ragged request
+    advances one token through a shared ``[B, 1]`` step. Inactive rows
+    carry all-zero tables/positions (scratch writes, discarded
+    samples)."""
+    return jax.jit(_decode_body(config, block_size), donate_argnums=(1,))
+
+
+def make_prefill_chunk(config, block_size: int):
+    """The ONE prefill program: a fixed-length chunk of one request's
+    prompt scatters into its block table; only the final chunk's
+    ``last_idx`` logits row is consumed (the first generated token)."""
+    return jax.jit(_prefill_body(config, block_size), donate_argnums=(1,))
+
+
+# The engine's programs: the same two bodies, with everything a pass
+# decides on the host in ONE int32 array, so that a pass is one call
+# into JAX (the transfer rides the dispatch), and with the sampling key
+# carried on the device. They are traced under the plain programs'
+# names, by which the benchmark's readers find them in a trace. Each
+# array's layout is known to its packer and its program, here, alone.
+
+
+def pack_decode_rows(batch: int, width: int, active) -> np.ndarray:
+    """The decode program's host array, int32 ``[batch, 3 + width]``:
+    per row its token, position, temperature (the float32's bits) and
+    block table, from ``active``'s ``(token, position, temperature,
+    table)``; the rows past them stay zero (inactive)."""
+    rows = np.zeros((batch, 3 + width), dtype=np.int32)
+    temps = rows[:, 2].view(np.float32)
+    for i, (token, position, temperature, table) in enumerate(active):
+        rows[i, 0], rows[i, 1], temps[i] = token, position, temperature
+        rows[i, 3:3 + len(table)] = table
+    return rows
+
+
+def make_engine_decode_step(config, block_size: int):
+    """``make_decode_step`` as the engine calls it: on
+    ``pack_decode_rows``' array, and on the carried sampling key, which
+    is split here exactly as the host used to split it and comes back
+    as the fourth result (not donated: a failed step leaves the
+    caller's key usable)."""
+    body = _decode_body(config, block_size)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode_step(params, pool, rows, key, expert_stats=None):
+        key, sub = jax.random.split(key)
+        temps = lax.bitcast_convert_type(rows[:, 2], jnp.float32)
+        return (*body(params, pool, rows[:, :1], rows[:, 1], rows[:, 3:],
+                      sub, temps, expert_stats), key)
+
+    return decode_step
+
+
+def pack_prefill_chunk(chunk_len: int, width: int, tokens, start: int,
+                       table) -> np.ndarray:
+    """The prefill program's host array, int32 ``[2 + 2 * chunk_len +
+    width]``: ``n_valid``, ``last_idx``, then the chunk's ``tokens``
+    (at most ``chunk_len``, at global positions ``start...``), their
+    positions and the request's block table, each zero-padded."""
+    n = len(tokens)
+    chunk = np.zeros((2 + 2 * chunk_len + width,), dtype=np.int32)
+    chunk[0], chunk[1] = n, n - 1
+    chunk[2:2 + n] = tokens
+    chunk[2 + chunk_len:2 + chunk_len + n] = np.arange(start, start + n)
+    chunk[2 + 2 * chunk_len:2 + 2 * chunk_len + len(table)] = table
+    return chunk
+
+
+def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
+    """``make_prefill_chunk`` as the engine calls it: on
+    ``pack_prefill_chunk``'s array."""
+    body = _prefill_body(config, block_size)
+    positions_at, table_at = 2 + chunk_len, 2 + 2 * chunk_len
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_chunk(params, pool, chunk, expert_stats=None):
+        return body(params, pool, chunk[None, 2:positions_at],
+                    chunk[None, positions_at:table_at],
+                    chunk[None, table_at:], chunk[0], chunk[1],
+                    expert_stats)
 
     return prefill_chunk

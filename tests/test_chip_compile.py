@@ -122,9 +122,11 @@ def test_fused_rms_norm_compiles_for_v5e(v5e_chip):
 
 
 def _lower_paged_step(program, config, batch, block, table, chip):
-    """``decode_step`` or ``prefill_chunk`` of the engine, lowered on
-    shapes placed on the described chip; the pool's shape beside it.
-    A sparse configuration's step carries its expert accumulator."""
+    """``decode_step`` or ``prefill_chunk``, the plain program or, as
+    ``engine_...``, the one the engine calls (one host array, the key
+    carried), lowered on shapes placed on the described chip; the
+    pool's shape beside it. A sparse configuration's step carries its
+    expert accumulator."""
     from ray_tpu._private.config import GLOBAL_CONFIG
     from ray_tpu.models import llama, moe
     from ray_tpu.serve.llm_engine import model as paged_model
@@ -143,13 +145,21 @@ def _lower_paged_step(program, config, batch, block, table, chip):
     stats = None
     if config.num_experts > 0:
         stats = on_chip(jax.eval_shape(moe.init_stats).shape)
-    if program == "decode_step":
+    chunk = GLOBAL_CONFIG.llm_prefill_chunk
+    if program == "engine_decode_step":
+        lowered = paged_model.make_engine_decode_step(config, block).lower(
+            params, pool, on_chip((batch, 3 + table)),
+            on_chip((2,), jnp.uint32), stats)
+    elif program == "engine_prefill_chunk":
+        lowered = paged_model.make_engine_prefill_chunk(
+            config, block, chunk).lower(
+                params, pool, on_chip((2 + 2 * chunk + table,)), stats)
+    elif program == "decode_step":
         lowered = paged_model.make_decode_step(config, block).lower(
             params, pool, on_chip((batch, 1)), on_chip((batch,)),
             on_chip((batch, table)), on_chip((2,), jnp.uint32),
             on_chip((batch,), jnp.float32), stats)
     else:
-        chunk = GLOBAL_CONFIG.llm_prefill_chunk
         lowered = paged_model.make_prefill_chunk(config, block).lower(
             params, pool, on_chip((1, chunk)), on_chip((1, chunk)),
             on_chip((1, table)), on_chip(()), on_chip(()), stats)
@@ -170,7 +180,9 @@ def test_engine_decode_compiles_for_v5e(v5e_chip):
             < 8 * 2 ** 30)
 
 
-@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk"])
+@pytest.mark.parametrize("program", [
+    "decode_step", "prefill_chunk", "engine_decode_step",
+    "engine_prefill_chunk"])
 def test_paged_steps_update_the_pool_in_place_on_v5e(v5e_chip, program):
     """The serve cells' widths (Mistral-7B-v0.3: 32 query on 8 key-value
     heads of 128; 2 of its layers, 16 rows, 128 blocks of 16). The chip's
@@ -212,7 +224,8 @@ F32_EXPERTS = r"f32\[(\d+,)?64,(2048,1024|1024,2048)\]"
 
 
 @pytest.mark.parametrize("program, temporaries_mib", [
-    ("decode_step", 160), ("prefill_chunk", 16)])
+    ("decode_step", 160), ("prefill_chunk", 16),
+    ("engine_decode_step", 160), ("engine_prefill_chunk", 16)])
 def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
         v5e_chip, program, temporaries_mib):
     """The OLMoE serve cell's two programs at its real size (16 rows x

@@ -485,9 +485,8 @@ def test_time_counter_names_clash_with_no_engine_argument():
 ENGINE_SPANS = (
     "engine.iteration", "engine.sweep", "engine.prefill.schedule",
     "engine.prefill.launch", "engine.prefill.first_token",
-    "engine.decode.schedule", "engine.decode.split_key",
-    "engine.decode.launch", "engine.decode.fetch", "engine.decode.emit",
-    "engine.idle")
+    "engine.decode.schedule", "engine.decode.launch",
+    "engine.decode.fetch", "engine.decode.emit", "engine.idle")
 
 
 def test_phase_spans_reach_the_profilers_host_plane(paged_engine, tmp_path):
@@ -528,6 +527,8 @@ def test_phase_spans_reach_the_profilers_host_plane(paged_engine, tmp_path):
               for line in plane.lines for e in line.events]
     names = {e.name for _, e in events}
     assert set(ENGINE_SPANS) | {"serve.stream.put"} <= names
+    # The key is split inside the decode program: no span times it.
+    assert "engine.decode.split_key" not in names
     launch = next(e for _, e in events if e.name == "engine.prefill.launch")
     assert dict(launch.stats)["tokens"] == 8  # prefill_chunk of the engine
     # Leaves lie inside an iteration of the same host line.
@@ -537,6 +538,152 @@ def test_phase_spans_reach_the_profilers_host_plane(paged_engine, tmp_path):
                and e.start_ns <= fetch.start_ns
                and fetch.start_ns + fetch.duration_ns
                <= e.start_ns + e.duration_ns for e in line.events)
+
+
+# ------------------------------- one call into JAX per program and pass
+
+# What the engine of PR 25 (commit a01396e, the parent of the PR that
+# moved the split into the decode program) served on this CPU for
+# LLMEngine(_f32_tiny(), max_batch_size=4, max_seq_len=64, block_size=8,
+# prefill_chunk=8, seed=7) and these three requests, one after another.
+SAMPLED_REQUESTS = (([5, 9, 2, 7], 0.8),
+                    ([3, 1, 4, 1, 5, 9, 2, 6, 5, 3], 1.5), ([8, 8], 0.0))
+PARENT_TOKENS = (
+    [254, 221, 46, 49, 11, 152, 244, 83, 47, 30],
+    [183, 98, 99, 144, 155, 139, 185, 162, 38, 26],
+    [4, 133, 245, 214, 217, 85, 121, 35, 47, 206])
+
+
+def _turnover(engine, rounds=3):
+    """Requests of mixed lengths and temperatures come and go."""
+    for i in range(rounds):
+        requests = [engine.submit([1 + i, 2, 3 + j], max_new_tokens=4 + j,
+                                  temperature=0.5 * (j % 2))
+                    for j in range(3)]
+        for j, req in enumerate(requests):
+            assert len(engine.result(req, timeout_s=120)) == 4 + j
+
+
+@pytest.mark.parametrize("run", ["first", "again"])
+def test_sampled_tokens_are_the_parents_for_a_seed(run):
+    """The split is the same function on the same words in the same
+    order, on the device now: a seed gives the tokens it gave when the
+    host split the key, in every fresh engine."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    engine = LLMEngine(_f32_tiny(), max_batch_size=4, max_seq_len=64,
+                       block_size=8, prefill_chunk=8, seed=7)
+    try:
+        for (prompt, temperature), want in zip(SAMPLED_REQUESTS,
+                                               PARENT_TOKENS):
+            req = engine.submit(prompt, max_new_tokens=10,
+                                temperature=temperature)
+            assert engine.result(req, timeout_s=120) == want
+    finally:
+        engine.shutdown()
+
+
+def test_host_calls_are_two_a_decode_step(paged_engine):
+    """``host_calls`` counts the engine thread's calls into JAX: the
+    decode program with its one array and the read of its tokens (the
+    loop before it made nine: two programs of the split, five
+    transfers, the dispatch, the read), one per prefill chunk, and a
+    greedy first token's argmax and read."""
+    before = paged_engine.engine_stats()
+    requests = [paged_engine.submit([2 + i] * (5 + 7 * i), max_new_tokens=9)
+                for i in range(3)]
+    for req in requests:
+        assert len(paged_engine.result(req, timeout_s=120)) == 9
+    after = paged_engine.engine_stats()
+    delta = {k: after[k] - before[k] for k in after}
+    assert delta["first_tokens"] == 3 and delta["prefill_chunks"] >= 5
+    assert delta["decode_steps"] >= 8
+    assert delta["host_calls"] - delta["prefill_chunks"] \
+        - 2 * delta["first_tokens"] == 2 * delta["decode_steps"]
+    # Decode alone: rows already in the batch, nothing left to prefill.
+    req = paged_engine.submit([4, 4], max_new_tokens=30, stream=True)
+    tokens = paged_engine.stream_tokens(req)
+    next(tokens)
+    while True:
+        with paged_engine._lock:  # a step's counters move under it
+            low = paged_engine.engine_stats()
+        if low["first_tokens"] == after["first_tokens"] + 1:
+            break
+    assert len(list(tokens)) == 29
+    high = paged_engine.engine_stats()
+    steps = high["decode_steps"] - low["decode_steps"]
+    assert steps >= 20 and high["prefill_chunks"] == low["prefill_chunks"]
+    assert high["host_calls"] - low["host_calls"] == 2 * steps
+
+
+@pytest.mark.parametrize("meshed", [False, True], ids=["no_mesh", "mesh"])
+def test_decode_program_is_compiled_once_over_turnover(meshed):
+    """The key goes into the decode program as it came out of it. Under
+    a mesh what comes out is committed to the mesh: a key made on the
+    host would compile the program a second time at the second step."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("x",)) \
+        if meshed else None
+    engine = LLMEngine(_f32_tiny(), max_batch_size=3, max_seq_len=64,
+                       block_size=8, prefill_chunk=8, seed=1, mesh=mesh)
+    try:
+        _turnover(engine)
+        assert engine.engine_stats()["decode_steps"] >= 12
+        # One entry: every call found the first call's program.
+        assert engine._decode_step._cache_size() == 1
+        assert engine._key.committed == meshed
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("fails_at", ["the_call", "the_read"])
+def test_failed_step_leaves_a_usable_key(fails_at):
+    """The key is not donated and is replaced only once a step's
+    tokens were read: after ``_reset_after_failure`` the engine holds
+    the key the failed step was given, and samples with it."""
+    import numpy as np
+
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    class Unreadable:
+        def __array__(self, *args, **kwargs):
+            raise RuntimeError("the device lost the step")
+
+    engine = LLMEngine(_f32_tiny(), max_batch_size=2, max_seq_len=64,
+                       block_size=8, prefill_chunk=8, seed=3)
+    try:
+        warm = engine.submit([1, 2, 3], max_new_tokens=4, temperature=0.9)
+        assert len(engine.result(warm, timeout_s=120)) == 4
+        step, key_before, failures = engine._decode_step, \
+            np.asarray(engine._key), []
+
+        def failing(params, pool, rows, key, expert_stats):
+            if failures:
+                return step(params, pool, rows, key, expert_stats)
+            failures.append(fails_at)
+            if fails_at == "the_call":
+                raise RuntimeError("the device refused the step")
+            _, pool, expert_stats, key = step(params, pool, rows, key,
+                                              expert_stats)
+            return Unreadable(), pool, expert_stats, key
+
+        engine.__dict__["_decode_step"] = failing
+        doomed = engine.submit([4, 5], max_new_tokens=6)
+        with pytest.raises(RuntimeError, match="the device"):
+            engine.result(doomed, timeout_s=120)
+        assert failures == [fails_at]
+        np.testing.assert_array_equal(np.asarray(engine._key), key_before)
+        after = engine.submit([6, 7, 8], max_new_tokens=5, temperature=0.9)
+        out = engine.result(after, timeout_s=120)
+        assert len(out) == 5 and all(
+            0 <= t < engine.config.vocab_size for t in out)
+        assert (np.asarray(engine._key) != key_before).any()
+    finally:
+        engine.shutdown()
 
 
 def test_engine_stats_ride_executor_stats(paged_engine):

@@ -373,6 +373,89 @@ def test_paged_programs_match_the_reference_logits(dtype_name):
         np.testing.assert_array_equal(touched, written)
 
 
+# ------------- (c) the engine's programs: one host array a call, the key carried
+
+
+@pytest.mark.parametrize("dtype_name", ["float32", "bfloat16"])
+@pytest.mark.parametrize("family", ["dense", "olmoe"])
+def test_engine_programs_equal_the_plain_programs(family, dtype_name):
+    """``make_engine_prefill_chunk`` and ``make_engine_decode_step`` are
+    the plain programs behind an unpacking of ONE int32 array and a
+    split of the carried key: the same logits, tokens (greedy and
+    sampled rows), pool and expert counters, bit for bit, and the key
+    that comes back is the one the host's split used to keep."""
+    dtype = jnp.dtype(dtype_name)
+    cfg = small(dtype) if family == "olmoe" \
+        else small(dtype, num_experts=0, qk_norm=False)
+    num_blocks, block, chunk, width = 24, 4, 8, 6
+    params = paged_model.serving_params(cfg, None, 3)
+    prompts = [[7, 3, 11, 200, 5, 9, 42, 8, 77, 13, 1], [9, 1, 4]]
+    bt = np.zeros((len(prompts) + 1, width), np.int32)  # last row inactive
+    bt[0, :4], bt[1, :2] = [5, 17, 2, 9], [11, 3]
+    stats = moe.init_stats() if cfg.num_experts else None
+
+    def copied(pool):
+        return jax.tree.map(jnp.copy, pool)  # every call donates its pool
+
+    def same(got, want):
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.dtype == w.dtype and g.shape == w.shape
+            np.testing.assert_array_equal(np.asarray(g.astype(jnp.float32)),
+                                          np.asarray(w.astype(jnp.float32)))
+
+    plain_prefill = paged_model.make_prefill_chunk(cfg, block)
+    engine_prefill = paged_model.make_engine_prefill_chunk(cfg, block, chunk)
+    pool = PagedKVCache.init_pool(cfg, num_blocks, block)
+    first = []
+    for i, prompt in enumerate(prompts):
+        for start in range(0, len(prompt), chunk):
+            n = min(chunk, len(prompt) - start)
+            tokens = np.zeros((1, chunk), np.int32)
+            tokens[0, :n] = prompt[start:start + n]
+            positions = np.zeros((1, chunk), np.int32)
+            positions[0, :n] = np.arange(start, start + n)
+            packed = paged_model.pack_prefill_chunk(
+                chunk, width, prompt[start:start + n], start,
+                [int(b) for b in bt[i] if b])
+            assert packed.dtype == np.int32 and packed.ndim == 1
+            got = engine_prefill(params, copied(pool), packed, stats)
+            want = plain_prefill(
+                params, pool, jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(bt[i:i + 1]), np.int32(n), np.int32(n - 1), stats)
+            same(got, want)
+            logits, pool, stats = want
+        first.append(int(np.asarray(logits).argmax()))
+
+    plain_decode = paged_model.make_decode_step(cfg, block)
+    engine_decode = paged_model.make_engine_decode_step(cfg, block)
+    temps = np.asarray([0.0, 0.7, 0.0], np.float32)
+    last, lengths = first + [0], [len(p) for p in prompts] + [0]
+    key = jax.random.PRNGKey(5)
+    for _ in range(3):
+        rows = paged_model.pack_decode_rows(len(bt), width, [
+            (last[i], lengths[i], temps[i], [int(b) for b in bt[i] if b])
+            for i in range(len(prompts))])
+        assert rows.dtype == np.int32 and rows.shape[0] == len(bt)
+        *got, got_key = engine_decode(params, copied(pool), rows, key, stats)
+        key, sub = jax.random.split(key)  # what the host did before PR 27
+        want = plain_decode(
+            params, pool, jnp.asarray(last, jnp.int32)[:, None],
+            jnp.asarray(lengths, jnp.int32), jnp.asarray(bt), sub,
+            jnp.asarray(temps), stats)
+        same(tuple(got), want)
+        np.testing.assert_array_equal(np.asarray(got_key), np.asarray(key))
+        nxt, pool, stats = want
+        last = [int(t) for t in np.asarray(nxt)[:2]] + [0]
+        lengths = [n + 1 for n in lengths[:2]] + [0]
+    if cfg.num_experts:
+        assert moe.read_stats(stats)["expert_choices"] > 0
+    # Found in a trace by these names (the benchmark's readers).
+    assert engine_decode.__name__ == plain_decode.__name__ == "decode_step"
+    assert engine_prefill.__name__ == plain_prefill.__name__ \
+        == "prefill_chunk"
+
+
 # --------------------------------------------------- the engine end to end
 
 
