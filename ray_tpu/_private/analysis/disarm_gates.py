@@ -43,8 +43,6 @@ KNOB_GATES: "dict[str, tuple[str, str]]" = {
     "lock_witness": ("ray_tpu/_private/lock_witness.py", "WITNESS_ON"),
     "driver_sharded_dispatch": ("ray_tpu/_private/dispatch_lanes.py",
                                 "SHARD_ON"),
-    "llm_paged_engine": ("ray_tpu/serve/llm_engine/engine.py",
-                         "PAGED_ON"),
     "gcs_shards": ("ray_tpu/_private/gcs_shard.py", "SHARDS_ON"),
     "metrics_history": ("ray_tpu/_private/metrics_history.py",
                         "HISTORY_ON"),

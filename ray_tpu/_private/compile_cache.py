@@ -3,7 +3,7 @@
 A cold process compiles every program from nothing, which on the chip is
 a large part of a short run. The cache's directory is part of what a run
 can rely on, so there is one rule for it, used by ``chip_smoke.py``,
-``bench.py`` and every child the runtime starts with a chip: where
+``benchmark/run.py`` and every child the runtime starts with a chip: where
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it and nothing is set in
 code; otherwise the cache sits at a fixed path inside the checkout,
 derived from this package's location (a directory that moves never

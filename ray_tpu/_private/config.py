@@ -295,11 +295,7 @@ _DEFAULTS: dict[str, Any] = {
     # (SystemOverloadedError) instead of queueing unboundedly.
     "gcs_shard_max_queued_writes": 512,
     # LLM inference engine (serve/llm_engine): paged KV-cache
-    # continuous batching with prefill/decode scheduling. Disarmed
-    # (llm_paged_engine=0), LLMEngineServer falls back to the legacy
-    # slot-per-request llm.LLMServer byte-identically; every gated
-    # site costs one module-attribute branch (llm_engine PAGED_ON).
-    "llm_paged_engine": True,
+    # continuous batching with prefill/decode scheduling.
     # Tokens per KV block (the page size of the paged cache): small
     # blocks waste less memory on ragged tails, large blocks shrink
     # the block tables. Must divide into max_seq_len cleanly for a

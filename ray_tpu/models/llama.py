@@ -460,104 +460,6 @@ def _chunked_nll(x: jax.Array, lm_head: jax.Array, targets: jax.Array,
     return nll.transpose(1, 0, 2).reshape(B, L)
 
 
-# ------------------------------------------------------- KV-cache inference
-
-
-def init_kv_cache(config: LlamaConfig, batch_size: int, max_len: int,
-                  dtype: Any = None) -> dict:
-    """Allocate a zeroed KV cache: {"k","v"}: [layers, B, max_len, kv, d].
-
-    Static shapes so the decode step compiles once; per-row fill levels
-    are tracked by the caller via ``positions`` (continuous batching keeps
-    different rows at different lengths inside one batch).
-    """
-    dtype = dtype or config.dtype
-    shape = (config.num_layers, batch_size, max_len,
-             config.num_kv_heads, config.head_dim)
-    return {"k": jnp.zeros(shape, dtype=dtype),
-            "v": jnp.zeros(shape, dtype=dtype)}
-
-
-def _cached_attention_block(layer: dict, x: jax.Array, positions: jax.Array,
-                            k_cache: jax.Array, v_cache: jax.Array,
-                            config: LlamaConfig):
-    """One attention block reading/writing a per-layer KV cache.
-
-    x: [B, T, E] new-token activations at global ``positions`` [B, T].
-    k_cache/v_cache: [B, S, kv, d]. Returns (out, k_cache, v_cache).
-    """
-    dtype = config.dtype
-    h, kv = config.num_heads, config.num_kv_heads
-    q, k, v = qkv_projections(layer, x, positions, config)
-
-    # Scatter new k/v into the cache at each row's positions.
-    b_idx = jnp.arange(x.shape[0])[:, None]
-    k_cache = k_cache.at[b_idx, positions].set(k.astype(k_cache.dtype))
-    v_cache = v_cache.at[b_idx, positions].set(v.astype(v_cache.dtype))
-
-    keys, values = k_cache, v_cache
-    if kv != h:
-        reps = h // kv
-        keys = jnp.repeat(keys, reps, axis=2)
-        values = jnp.repeat(values, reps, axis=2)
-
-    scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                        keys.astype(jnp.float32))
-    scores *= config.head_dim ** -0.5
-    # Valid keys: cache slot s holds a token at global position s; a query
-    # at position p attends to s <= p (rows start at position 0, so every
-    # slot <= p has been written).
-    s_pos = jnp.arange(k_cache.shape[1])
-    mask = s_pos[None, None, None, :] <= positions[:, None, :, None]
-    scores = jnp.where(mask, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
-    out = jnp.einsum("bhts,bshd->bthd", probs, values.astype(dtype))
-    out = jnp.einsum("blhd,hde->ble", out, layer["wo"].astype(dtype))
-    return x + out, k_cache, v_cache
-
-
-def forward_with_cache(params: dict, tokens: jax.Array, cache: dict,
-                       positions: jax.Array, config: LlamaConfig):
-    """Prefill or decode step with a KV cache.
-
-    tokens: [B, T] new tokens at global ``positions`` [B, T] (T=1 for a
-    decode step, T=prompt_len for prefill). Returns (logits [B, T, V] f32,
-    updated cache). Same-shape calls hit the jit cache.
-    """
-    if config.num_experts > 0:
-        raise NotImplementedError(
-            "this dense-cache path has no expert layer: sparse "
-            "configurations are served by the paged engine "
-            "(ray_tpu.serve.llm_engine.LLMEngine)")
-    x = params["embed"]["tokens"].astype(config.dtype)[tokens]
-
-    def layer_step(x, layer_and_cache):
-        layer, k_c, v_c = layer_and_cache
-        x, k_c, v_c = _cached_attention_block(
-            layer, x, positions, k_c, v_c, config)
-        x = _mlp_block(layer, x, config)
-        return x, (k_c, v_c)
-
-    x, (k_new, v_new) = lax.scan(
-        layer_step, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    logits = jnp.einsum("ble,ev->blv", x,
-                        params["lm_head"].astype(config.dtype),
-                        preferred_element_type=jnp.float32)
-    return logits, {"k": k_new, "v": v_new}
-
-
-def flops_per_token(config: LlamaConfig, seq_len: int | None = None) -> float:
-    """6 * active params (fwd+bwd) + attention term — standard MFU
-    accounting. Uses num_active_params, so a sparse model counts the
-    ``experts_per_token`` experts a token is routed to and not the
-    ones it never touches."""
-    seq = seq_len if seq_len is not None else config.max_seq_len
-    attn_flops = (12 * config.num_layers * config.num_heads
-                  * config.head_dim * seq)
-    return 6.0 * config.num_active_params + attn_flops
-
-
 def cross_entropy(logits: jax.Array, targets: jax.Array,
                   mask: jax.Array | None = None) -> jax.Array:
     """Fused mean next-token CE: logsumexp(logits) - logits[target]

@@ -8,8 +8,7 @@ TPU-native specifics live in ray_tpu.serve.llm_engine: a paged
 KV-cache continuous-batching inference engine (prefill/decode
 scheduling, gather-by-block-table attention, latency-driven replica
 autoscaling) so many HTTP requests share one MXU-friendly decode
-batch. ray_tpu.serve.llm keeps the legacy slot-per-request prototype
-as the llm_paged_engine=0 fallback.
+batch.
 """
 
 from ray_tpu.serve.api import (

@@ -1,7 +1,6 @@
 """LLM inference engine: paged KV-cache continuous batching.
 
-Supersedes the slot-per-request prototype in ``ray_tpu.serve.llm``:
-ragged request lengths share ONE fixed-shape decode batch through a
+Ragged request lengths share ONE fixed-shape decode batch through a
 paged KV cache (the Ragged Paged Attention design — fixed-size blocks
 in a preallocated pool, per-request block tables, gather-by-block-table
 attention), a prefill/decode scheduler interleaves chunked prefill with
@@ -15,8 +14,7 @@ Layout:
 - ``model``     the jitted gather-by-block-table prefill/decode steps
 - ``scheduler`` request lifecycle: bounded admission, chunked-prefill
   interleave, preemption on cache pressure, deadline sweep
-- ``engine``    the engine loop + counters (``ENGINE_STAT_KEYS``) +
-  the ``llm_paged_engine`` disarm gate (``PAGED_ON``)
+- ``engine``    the engine loop + counters (``ENGINE_STAT_KEYS``)
 - ``server``    the ``LLMEngineServer`` serve deployment class
 - ``autoscale`` the latency-driven replica-count policy
 """

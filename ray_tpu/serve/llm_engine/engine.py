@@ -6,13 +6,9 @@ step for every active stream — tokens stream out per step, finished
 rows free their blocks between steps, and cache pressure preempts the
 lowest-progress stream (recompute-on-resume) instead of failing it.
 
-Disarm discipline: the ``llm_paged_engine`` knob arms the ONE module
-attribute ``PAGED_ON`` (the ``TRACE_ON``/``SPILL_ON`` idiom);
-``LLMEngineServer`` branches on it to fall back to the legacy
-slot-per-request ``serve.llm.LLMServer``. Counters ship as
-``ENGINE_STAT_KEYS`` through the node-stats heartbeat piggyback
-(``ray_tpu_node_engine`` /metrics family) via the process-local
-engine registry below.
+Counters ship as ``ENGINE_STAT_KEYS`` through the node-stats heartbeat
+piggyback (``ray_tpu_node_engine`` /metrics family) via the
+process-local engine registry below.
 
 On the record: every phase of a loop pass is a ``tracing.phase`` (an
 ``engine.iteration`` span with leaf spans ``engine.sweep``,
@@ -54,13 +50,8 @@ from ray_tpu.serve.llm_engine.scheduler import (
 )
 from ray_tpu.util import tracing
 
-__all__ = ["ENGINE_STAT_KEYS", "LLMEngine", "PAGED_ON",
+__all__ = ["ENGINE_STAT_KEYS", "LLMEngine",
            "merged_engine_stats", "merged_engine_load"]
-
-# The ONE production branch: LLMEngineServer checks this module
-# attribute to pick the paged engine vs the legacy slot-per-request
-# path. Armed from the llm_paged_engine knob at import/init.
-PAGED_ON: bool = True
 
 # Counter contract: code increments exactly these keys, engine_stats()
 # serves them, the README "LLM serving" section documents them, and
@@ -569,8 +560,8 @@ class LLMEngine:
 
     def _reset_after_failure(self, exc: Exception) -> None:
         """A failed jitted call invalidated the donated pool: fail
-        every in-flight request typed and rebuild (the legacy engine's
-        ADVICE-r1 discipline, kept)."""
+        every in-flight request typed and rebuild: the loop stays
+        alive for the next request."""
         with self._lock:
             sched = self._sched
             victims = list(sched.waiting) + list(sched.active)
@@ -665,31 +656,3 @@ def merged_engine_load() -> dict:
         for key, value in engine.engine_load().items():
             totals[key] += int(value)
     return totals
-
-
-# --------------------------------------------------------------------------
-# Arm/disarm
-# --------------------------------------------------------------------------
-
-
-def enable() -> None:
-    global PAGED_ON
-    PAGED_ON = True
-
-
-def disable() -> None:
-    global PAGED_ON
-    PAGED_ON = False
-
-
-def init_from_config() -> None:
-    from ray_tpu._private.config import GLOBAL_CONFIG
-
-    global PAGED_ON
-    PAGED_ON = bool(GLOBAL_CONFIG.llm_paged_engine)
-
-
-try:
-    init_from_config()
-except Exception:  # noqa: BLE001 — config unavailable mid-bootstrap
-    pass

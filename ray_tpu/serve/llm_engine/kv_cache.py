@@ -1,12 +1,11 @@
 """Paged KV cache: a preallocated block pool + per-request block tables.
 
 The Ragged Paged Attention memory model (PAPERS.md: arxiv 2604.15464):
-instead of one ``[max_batch, max_len]`` cache row per slot (the
-``serve.llm`` prototype — every admitted request reserves its WORST
-CASE length), the cache is a pool of fixed-size blocks
-(``[num_blocks, block_size, kv_heads, head_dim]`` per layer) and each
-request holds an append-only table of block ids covering exactly the
-tokens it has written. Ragged lengths pack tightly: a 7-token request
+instead of one ``[max_batch, max_len]`` cache row per slot (every
+admitted request reserving its WORST CASE length), the cache is a pool
+of fixed-size blocks (``[num_blocks, block_size, kv_heads, head_dim]``
+per layer) and each request holds an append-only table of block ids
+covering exactly the tokens it has written. Ragged lengths pack tightly: a 7-token request
 holds one 16-token block while its 900-token batchmate holds 57, and
 blocks return to the free list the moment a request finishes — so the
 SAME pool admits far more concurrent ragged requests than slot rows
@@ -113,9 +112,8 @@ class PagedKVCache:
     def init_pool(config: Any, num_blocks: int, block_size: int,
                   dtype: Any = None) -> dict:
         """Allocate the zeroed device pool:
-        ``{"k","v"}: [layers, num_blocks, block_size, kv, d]`` — the
-        paged analogue of ``llama.init_kv_cache`` (static shapes, so
-        the decode step compiles once)."""
+        ``{"k","v"}: [layers, num_blocks, block_size, kv, d]``
+        (static shapes, so the decode step compiles once)."""
         dtype = dtype or config.dtype
         shape = (config.num_layers, num_blocks, block_size,
                  config.num_kv_heads, config.head_dim)

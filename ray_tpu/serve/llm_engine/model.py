@@ -1,7 +1,7 @@
 """Jitted paged-attention steps: gather-by-block-table prefill/decode.
 
-The kernel discipline mirrors ``models.llama.forward_with_cache`` but
-reads/writes the PAGED pool instead of per-slot cache rows:
+The forward of ``models.llama`` for one chunk or one token per row,
+reading and writing the PAGED pool:
 
 - **the pool is a carry**: the whole ``[layers, num_blocks, bs, kv, d]``
   pool rides through the layer scan beside the activations, and each
@@ -15,8 +15,8 @@ reads/writes the PAGED pool instead of per-slot cache rows:
   ``[B, M, bs, kv, d]`` reshaped to the flat ``[B, S, kv, d]`` view
   where flat index ``s`` IS the token's global position (tables are
   append-ordered), so the standard causal mask ``s <= position`` is
-  unchanged from the dense path. They stay in the pool's dtype and are
-  never repeated per query head;
+  the one a contiguous cache would use. They stay in the pool's dtype
+  and are never repeated per query head;
 - **grouped attention**: queries are viewed ``[B, T, kv, reps, d]`` and
   contracted against the gathered keys with float32 accumulation
   (products of bf16 values are exact in float32, so widening the
@@ -27,8 +27,7 @@ reads/writes the PAGED pool instead of per-slot cache rows:
   row so that the product stays a matrix product;
 - **fixed shapes**: batch ``B``, table width ``M`` and chunk length
   ``C`` are compile-time constants — ONE decode program and ONE
-  prefill program total, every step hits the jit cache (the
-  ``serve.llm`` prototype's discipline, kept);
+  prefill program total, every step hits the jit cache;
 - **donation**: the pool is donated through every call (decode updates
   in place in HBM); on TPU wrap the calls in
   ``jax_compat.set_mesh(mesh)`` and the same jitted fns become pjit

@@ -1,7 +1,6 @@
 """``LLMEngineServer``: the serve deployment hosting one paged engine.
 
-Request (token-in/token-out, no tokenizer dependency — the
-``serve.llm`` contract, kept)::
+Request (token-in/token-out, no tokenizer dependency)::
 
     {"tokens": [int], "max_new_tokens": int, "temperature": float}
       -> {"tokens": [int]}               (__call__, unary)
@@ -18,18 +17,11 @@ queue refuses dead work typed (``TaskTimeoutError`` stage
 waiting for. A full waiting queue or unservable request sheds
 ``CacheExhaustedError`` through the ``SystemOverloadedError`` path
 (HTTP 503 + Retry-After).
-
-Disarmed (``llm_paged_engine=0`` → ``engine.PAGED_ON`` False) the
-class hosts the legacy slot-per-request ``serve.llm.LLMServer``
-byte-identically — the A/B the BENCH_SERVE_LLM refresh guard refuses
-to accept numbers from.
 """
 
 from __future__ import annotations
 
-from typing import Any
-
-from ray_tpu.serve.llm_engine import engine as engine_mod
+from ray_tpu.serve.llm_engine.engine import LLMEngine
 
 
 class LLMEngineServer:
@@ -44,20 +36,11 @@ class LLMEngineServer:
                  prefill_chunk: "int | None" = None,
                  max_waiting: "int | None" = None,
                  seed: int = 0, mesh=None):
-        self._legacy = None
-        self._engine = None
-        if engine_mod.PAGED_ON:
-            self._engine = engine_mod.LLMEngine(
-                config, params, max_batch_size=max_batch_size,
-                max_seq_len=max_seq_len, block_size=block_size,
-                num_blocks=num_blocks, prefill_chunk=prefill_chunk,
-                max_waiting=max_waiting, seed=seed, mesh=mesh)
-        else:
-            from ray_tpu.serve.llm import LLMServer
-
-            self._legacy = LLMServer(
-                config, params, max_batch_size=max_batch_size,
-                max_seq_len=max_seq_len, seed=seed)
+        self._engine = LLMEngine(
+            config, params, max_batch_size=max_batch_size,
+            max_seq_len=max_seq_len, block_size=block_size,
+            num_blocks=num_blocks, prefill_chunk=prefill_chunk,
+            max_waiting=max_waiting, seed=seed, mesh=mesh)
 
     # ------------------------------------------------------------ data path
 
@@ -75,8 +58,6 @@ class LLMEngineServer:
         return get_runtime_context().get_task_deadline()
 
     def __call__(self, request: dict) -> dict:
-        if self._engine is None:
-            return self._legacy(request)
         req = self._engine.submit(
             list(request.get("tokens") or []),
             max_new_tokens=int(request.get("max_new_tokens", 16)),
@@ -87,12 +68,6 @@ class LLMEngineServer:
     def generate(self, request: dict):
         """Streaming generation — tokens yield as decode steps emit
         them (pair with ``handle.options(stream=True)``)."""
-        if self._engine is None:
-            # Legacy path has no incremental decode hook: yield the
-            # finished tokens one by one (unary latency, stream shape).
-            for token in self._legacy(request)["tokens"]:
-                yield token
-            return
         req = self._engine.submit(
             list(request.get("tokens") or []),
             max_new_tokens=int(request.get("max_new_tokens", 16)),
@@ -103,31 +78,22 @@ class LLMEngineServer:
     # --------------------------------------------------------- control path
 
     def engine_stats(self) -> dict:
-        """ENGINE_STAT_KEYS counters + the armed flag (bench rows and
-        tests read this through the deployment handle)."""
-        stats = {"paged_engine": self._engine is not None}
-        if self._engine is not None:
-            stats.update(self._engine.engine_stats())
-        return stats
+        """ENGINE_STAT_KEYS counters (the benchmark and tests read
+        this through the deployment handle)."""
+        return self._engine.engine_stats()
 
     def serve_metrics(self) -> dict:
         """Live load gauges merged into ``Replica.get_metrics()`` —
         the engine-depth signal the latency autoscaler folds in."""
-        if self._engine is None:
-            return {}
         load = self._engine.engine_load()
         return {"engine_depth": load["depth"],
                 "engine_free_blocks": load["free_blocks"]}
 
     def check_health(self) -> None:
-        if self._engine is not None:
-            self._engine.check_health()
-        elif self._legacy is not None:
-            self._legacy.check_health()
+        self._engine.check_health()
 
     def __del__(self):
         try:
-            if self._engine is not None:
-                self._engine.shutdown()
+            self._engine.shutdown()
         except Exception:  # noqa: BLE001 — interpreter teardown
             pass

@@ -207,6 +207,6 @@ class Replica:
         hook = getattr(self._callable, "__del__", None)
         if hook is not None:
             try:
-                hook()  # e.g. LLMServer.__del__ stops its engine thread
+                hook()  # e.g. LLMEngineServer.__del__ stops its engine thread
             except Exception:  # noqa: BLE001 — best-effort teardown
                 pass

@@ -650,94 +650,3 @@ def test_bench_envelope_spill_restore_overhead_bounded():
     assert cur <= bound, (
         f"spill restore_p50_ms regressed: {cur:.1f}ms vs committed "
         f"{base:.1f}ms (bound {bound:.1f}ms)")
-
-
-BENCH_SERVE_LLM = REPO_ROOT / "BENCH_SERVE_LLM.json"
-
-
-def _serve_llm_rows() -> dict:
-    rows = {}
-    for line in BENCH_SERVE_LLM.read_text().splitlines():
-        if line.strip():
-            row = json.loads(line)
-            rows[row["metric"]] = row
-    return rows
-
-
-def test_bench_serve_llm_records_engine_rows():
-    """ISSUE 14 acceptance: BENCH_SERVE_LLM.json must carry the TTFT
-    p50/p99, per-token latency and tokens/s rows from the closed-loop
-    generator, measured THROUGH the paged engine — a refresh recorded
-    with the engine disarmed (legacy slot path) or with zero
-    batched-decode steps (no continuous batching actually happened)
-    is refused outright."""
-    if not BENCH_SERVE_LLM.exists():
-        pytest.skip("BENCH_SERVE_LLM.json not present in the working "
-                    "tree")
-    rows = _serve_llm_rows()
-    for metric in ("llm_ttft_p50_ms", "llm_ttft_p99_ms",
-                   "llm_per_token_ms", "llm_tokens_per_s",
-                   "llm_overload_shed"):
-        assert metric in rows, (
-            f"BENCH_SERVE_LLM.json lost the {metric} row; rerun "
-            f"bench_serve_llm.py")
-    assert rows["llm_tokens_per_s"]["value"] > 0
-    assert rows["llm_ttft_p99_ms"]["value"] >= \
-        rows["llm_ttft_p50_ms"]["value"]
-    engine = rows["llm_tokens_per_s"]["detail"].get("engine") or {}
-    assert engine.get("paged_engine") is True, (
-        "BENCH_SERVE_LLM refreshed with the paged engine DISARMED "
-        "(llm_paged_engine=0 records the legacy slot path) — rerun "
-        "armed")
-    assert engine.get("batched_decode_steps", 0) > 0, (
-        "zero batched-decode steps: the bench never actually shared a "
-        "decode batch across requests — refusing the refresh")
-    assert engine.get("finished", 0) > 0
-
-
-def test_bench_serve_llm_overload_row_typed_and_lossless():
-    """Under 2x closed-loop overload the engine must shed TYPED (shed
-    > 0 via the CacheExhaustedError -> SystemOverloadedError path)
-    with zero hung requests and zero lost/doubled streams — the
-    zero-loss overload contract the engine was built to."""
-    if not BENCH_SERVE_LLM.exists():
-        pytest.skip("BENCH_SERVE_LLM.json not present in the working "
-                    "tree")
-    rows = _serve_llm_rows()
-    detail = rows["llm_overload_shed"]["detail"]
-    for key in ("ok", "shed", "hung", "lost", "doubled", "timeouts",
-                "overload_factor", "clients", "engine"):
-        assert key in detail, f"overload row lost detail key {key!r}"
-    assert detail["overload_factor"] >= 2
-    assert detail["ok"] > 0, detail
-    assert detail["shed"] > 0, (
-        "zero sheds under 2x overload: the row was not measured under "
-        "overload at all — refusing the refresh")
-    assert detail["hung"] == 0, f"{detail['hung']} requests HUNG"
-    assert detail["lost"] == 0 and detail["doubled"] == 0, (
-        f"lost={detail['lost']} doubled={detail['doubled']} streams "
-        f"across the overload window")
-    assert detail["engine"].get("paged_engine") is True
-
-
-def test_bench_serve_llm_no_silent_regression():
-    """Committed-refresh guard for the throughput-shaped LLM rows:
-    tokens/s may not silently drop more than the envelope tolerance
-    vs the committed baseline (TTFT/per-token are latency-shaped and
-    box-noise-prone; the schema tests above keep them honest)."""
-    if not BENCH_SERVE_LLM.exists():
-        pytest.skip("BENCH_SERVE_LLM.json not present in the working "
-                    "tree")
-    baseline_text = _committed("BENCH_SERVE_LLM.json")
-    if baseline_text is None:
-        pytest.skip("no committed BENCH_SERVE_LLM.json baseline")
-    baseline = _parse_metrics(baseline_text)
-    current = _parse_metrics(BENCH_SERVE_LLM.read_text())
-    base = baseline.get("llm_tokens_per_s", 0.0)
-    if base <= 0:
-        pytest.skip("committed baseline predates the tokens/s row")
-    cur = current.get("llm_tokens_per_s", 0.0)
-    drop = (base - cur) / base
-    assert drop <= ENVELOPE_TOLERANCE, (
-        f"llm_tokens_per_s: {base:g} -> {cur:g} "
-        f"(-{drop * 100:.1f}% > {ENVELOPE_TOLERANCE:.0%})")

@@ -834,7 +834,7 @@ def test_llm_engine_knobs_documented(llm_text):
 
     knobs = [k for k in _DEFAULTS if k.startswith("llm_")]
     knobs.append("serve_latency_report_s")
-    assert len(knobs) >= 5, f"llm knobs vanished from config: {knobs}"
+    assert len(knobs) >= 4, f"llm knobs vanished from config: {knobs}"
     missing = [k for k in knobs if f"`{k}`" not in llm_text]
     assert not missing, (
         f"LLM engine knobs missing from the README knob table: "
@@ -874,18 +874,32 @@ def test_llm_scheduler_and_paging_semantics_documented(llm_text):
                    "lowest-progress", "recompute-on-resume",
                    "`CacheExhaustedError`", "`target_p99_s`",
                    "`engine_depth`", "latency_stats()",
-                   "ray_tpu_node_engine", "BENCH_SERVE_LLM.json"):
+                   "ray_tpu_node_engine"):
         assert phrase in flat, (
             f"LLM serving section lost {phrase!r}")
 
 
 def test_llm_engine_disarm_gate_registered():
-    """The llm_paged_engine knob rides the disarm-gate analysis pass
-    (one module attribute, PAGED_ON) like every other plane."""
+    """Nothing is registered, because nothing selects: the paged engine
+    is the one way to serve a language model. No knob or gate names
+    another, ``ray_tpu.serve`` holds no second engine module, and the
+    model file holds no serving cache."""
+    import importlib
+    import pkgutil
+
+    from ray_tpu import serve
     from ray_tpu._private.analysis.disarm_gates import KNOB_GATES
-
-    assert KNOB_GATES.get("llm_paged_engine") == (
-        "ray_tpu/serve/llm_engine/engine.py", "PAGED_ON")
     from ray_tpu._private.config import _DEFAULTS
+    from ray_tpu.models import llama
 
-    assert "llm_paged_engine" in _DEFAULTS
+    assert {k for k in _DEFAULTS if k.startswith("llm_")} == {
+        "llm_block_size", "llm_prefill_chunk", "llm_max_waiting"}
+    assert len(KNOB_GATES) == 12
+    assert not [home for home, _ in KNOB_GATES.values()
+                if home.startswith("ray_tpu/serve/")]
+    modules = {m.name for m in pkgutil.iter_modules(serve.__path__)}
+    assert "llm_engine" in modules and "llm" not in modules
+    with pytest.raises(ImportError):
+        importlib.import_module(".llm", serve.__name__)
+    assert not [name for name, value in vars(llama).items()
+                if callable(value) and "cache" in name]

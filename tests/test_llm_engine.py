@@ -640,11 +640,13 @@ def test_decode_program_is_compiled_once_over_turnover(meshed):
         engine.shutdown()
 
 
-@pytest.mark.parametrize("fails_at", ["the_call", "the_read"])
+@pytest.mark.parametrize("fails_at", ["the_call", "the_read", "the_chunk"])
 def test_failed_step_leaves_a_usable_key(fails_at):
     """The key is not donated and is replaced only once a step's
     tokens were read: after ``_reset_after_failure`` the engine holds
-    the key the failed step was given, and samples with it."""
+    the key the failed step was given, and samples with it. A prefill
+    program that raises fails its request the same way, and the engine
+    serves the next."""
     import numpy as np
 
     from ray_tpu.serve.llm_engine import LLMEngine
@@ -658,8 +660,14 @@ def test_failed_step_leaves_a_usable_key(fails_at):
     try:
         warm = engine.submit([1, 2, 3], max_new_tokens=4, temperature=0.9)
         assert len(engine.result(warm, timeout_s=120)) == 4
-        step, key_before, failures = engine._decode_step, \
-            np.asarray(engine._key), []
+        step, chunk_step, key_before, failures = engine._decode_step, \
+            engine._prefill_step, np.asarray(engine._key), []
+
+        def failing_chunk(*args):
+            if failures:
+                return chunk_step(*args)
+            failures.append(fails_at)
+            raise RuntimeError("the device refused the chunk")
 
         def failing(params, pool, rows, key, expert_stats):
             if failures:
@@ -671,7 +679,10 @@ def test_failed_step_leaves_a_usable_key(fails_at):
                                               expert_stats)
             return Unreadable(), pool, expert_stats, key
 
-        engine.__dict__["_decode_step"] = failing
+        if fails_at == "the_chunk":
+            engine.__dict__["_prefill_step"] = failing_chunk
+        else:
+            engine.__dict__["_decode_step"] = failing
         doomed = engine.submit([4, 5], max_new_tokens=6)
         with pytest.raises(RuntimeError, match="the device"):
             engine.result(doomed, timeout_s=120)
@@ -699,32 +710,94 @@ def test_engine_stats_ride_executor_stats(paged_engine):
         paged_engine.engine_stats()["decode_steps"]
 
 
-def test_server_fallback_equivalence(paged_engine):
-    """llm_paged_engine=0 (PAGED_ON False) hosts the legacy
-    slot-per-request LLMServer — same contract, same greedy tokens."""
+@pytest.fixture(scope="module")
+def engine_server(paged_engine):
     from ray_tpu.serve.llm_engine import LLMEngineServer
-    from ray_tpu.serve.llm_engine import engine as engine_mod
 
+    server = LLMEngineServer(paged_engine.config, paged_engine.params,
+                             max_batch_size=2, max_seq_len=64,
+                             block_size=8, prefill_chunk=8)
+    yield server
+    server._engine.shutdown()
+
+
+def test_server_fallback_equivalence(paged_engine, engine_server):
+    """``LLMEngineServer.__call__`` and ``generate`` return, for one
+    greedy request, the tokens ``LLMEngine.result`` returns."""
     request = {"tokens": [5, 9, 2, 7], "max_new_tokens": 5}
-    armed = LLMEngineServer(paged_engine.config, paged_engine.params,
-                            max_batch_size=2, max_seq_len=64)
-    try:
-        armed_out = armed(request)
-        assert armed._engine is not None and armed._legacy is None
-    finally:
-        armed._engine.shutdown()
-    engine_mod.disable()
-    try:
-        legacy = LLMEngineServer(paged_engine.config,
-                                 paged_engine.params,
+    expected = paged_engine.result(
+        paged_engine.submit(request["tokens"], max_new_tokens=5),
+        timeout_s=120)
+    assert engine_server(request) == {"tokens": expected}
+    assert list(engine_server.generate(request)) == expected
+
+
+@pytest.mark.parametrize("case", [
+    "metrics", "healthy",
+    pytest.param("loop_died", marks=pytest.mark.filterwarnings(
+        "ignore::pytest.PytestUnhandledThreadExceptionWarning")),
+    "own_deadline"])
+def test_server_control_path(paged_engine, engine_server, case):
+    """What a replica asks of the deployment besides tokens: the load
+    gauges the autoscaler reads, the health check, and which deadline
+    a request runs under."""
+    from ray_tpu._private import request_context
+    from ray_tpu.serve.llm_engine import LLMEngineServer
+
+    if case == "metrics":
+        engine = engine_server._engine
+        idle = engine_server.serve_metrics()
+        assert idle["engine_depth"] == 0 and idle["engine_free_blocks"] > 0
+        step, release = engine._decode_step, threading.Event()
+
+        def held(*args):
+            release.wait(timeout=60)
+            return step(*args)
+
+        engine.__dict__["_decode_step"] = held
+        try:
+            stream = engine_server.generate(
+                {"tokens": [1, 2, 3], "max_new_tokens": 4})
+            assert isinstance(next(stream), int)  # now a decode row
+            busy = engine_server.serve_metrics()
+        finally:
+            release.set()
+            engine.__dict__["_decode_step"] = step
+        assert busy["engine_depth"] == 1
+        assert busy["engine_free_blocks"] < idle["engine_free_blocks"]
+        assert len(list(stream)) == 3
+        assert engine_server.serve_metrics() == idle
+    elif case == "healthy":
+        assert len(engine_server({"tokens": [8], "max_new_tokens": 2})
+                   ["tokens"]) == 2
+        assert engine_server.check_health() is None
+    elif case == "loop_died":
+        server = LLMEngineServer(paged_engine.config, paged_engine.params,
                                  max_batch_size=2, max_seq_len=64)
-        assert legacy._engine is None and legacy._legacy is not None
-        legacy_out = legacy(request)
-        assert legacy.engine_stats() == {"paged_engine": False}
-        assert legacy.serve_metrics() == {}
-    finally:
-        engine_mod.enable()
-    assert armed_out == legacy_out
+        engine = server._engine
+
+        def broken():
+            raise RuntimeError("the loop broke")
+
+        engine.__dict__["_iteration"] = broken
+        engine._loop_thread.join(timeout=30)
+        assert not engine._loop_thread.is_alive()
+        with pytest.raises(RuntimeError, match="loop died"):
+            server.check_health()
+        engine.shutdown()
+        server.check_health()  # a shut-down engine is not a dead one
+    else:
+        request = {"tokens": [1, 2], "max_new_tokens": 2}
+        token = request_context.set_deadline(time.time() - 1.0)
+        try:
+            # The call's inherited budget is dead ...
+            with pytest.raises(TaskTimeoutError):
+                engine_server(request)
+            # ... and the request's own beats it.
+            out = engine_server({**request, "deadline_s": 60.0})
+        finally:
+            request_context.reset_deadline(token)
+        assert len(out["tokens"]) == 2
 
 
 def test_mesh_context_portable(paged_engine):

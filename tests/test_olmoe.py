@@ -541,9 +541,8 @@ def test_parameter_counts_follow_the_tree():
         * cfg.hidden_size * cfg.intermediate_size * cfg.num_layers
     assert cfg.num_active_params == cfg.num_params - inactive
     one = dataclasses.replace(cfg, experts_per_token=1)
-    assert llama.flops_per_token(cfg, 32) - llama.flops_per_token(one, 32) \
-        == 6.0 * 2 * 3 * cfg.hidden_size * cfg.intermediate_size \
-        * cfg.num_layers
+    assert cfg.num_active_params - one.num_active_params \
+        == 2 * 3 * cfg.hidden_size * cfg.intermediate_size * cfg.num_layers
 
 
 def test_olmoe_at_published_widths_counts_as_published():
@@ -559,10 +558,6 @@ def test_olmoe_at_published_widths_counts_as_published():
 def test_paths_that_cannot_serve_it_say_so():
     cfg = small()
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    cache = llama.init_kv_cache(cfg, 1, 8)
-    with pytest.raises(NotImplementedError, match="paged engine"):
-        llama.forward_with_cache(params, jnp.zeros((1, 2), jnp.int32), cache,
-                                 jnp.zeros((1, 2), jnp.int32), cfg)
     layer = jax.tree.map(lambda p: p[0], params["layers"])
     with pytest.raises(NotImplementedError, match="QK-norm"):
         llama._attention_block(layer, jnp.zeros((1, 2, 64)),
