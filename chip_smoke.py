@@ -57,6 +57,12 @@ ARGS.add_argument("--paged-logits", metavar="CONFIG_JSON", default=None,
                        "the benchmark at its real size, the paged "
                        "engine's programs against the configuration's "
                        "plain reference, logits and expert choices")
+ARGS.add_argument("--round-weights", metavar="DTYPE", default=None,
+                  help="with --paged-logits of a hybrid configuration: the "
+                       "programs run on weights rounded through this dtype "
+                       "(float8_e4m3fn: the nearest precision below "
+                       "bfloat16) while the reference keeps the weights as "
+                       "they are; the comparison then has to FAIL")
 ARGS.add_argument("--seed", type=int, default=0)
 
 # Stated tolerances. bf16 keeps 8 bits of mantissa (2^-8 = 4e-3 a
@@ -641,7 +647,8 @@ REHEARSED_OTHER_EXPERT = 0.5
 
 
 def phase_paged_logits(path: str, seed: int, rehearse: bool,
-                       device: dict) -> None:
+                       device: dict, round_weights: "str | None" = None
+                       ) -> None:
     """The engine's jitted steps at a benchmark configuration's size,
     driven as the engine drives them, outside any timed window."""
     import jax
@@ -660,6 +667,9 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
     reference = spec.load_module(
         [os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "benchmark")], "reference", config["reference"])
+    if paged_model.family(model_config).recurrent:
+        return phase_hybrid_logits(config, model_config, model, reference,
+                                   seed, rehearse, device, round_weights)
     sparse = model_config.num_experts > 0
     rows, max_len = (config["engine"][k]
                      for k in ("max_batch_size", "max_seq_len"))
@@ -839,6 +849,175 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
           f"{differing} of {choices} expert choices differ")
 
 
+# Hybrid family (PR 33), on the v5e at the published widths: worst
+# difference of a logit over the reference's logit standard deviation.
+HYBRID_LOGITS = 0.4
+REHEARSED_HYBRID_LOGITS = 0.35  # 64 wide: a rounding is a larger share
+
+
+def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
+                        seed: int, rehearse: bool, device: dict,
+                        round_weights: "str | None") -> None:
+    """A hybrid configuration's three caches at its real size: every
+    row of the engine busy (the probes' lengths, one context nearly as
+    long as the table, the rest a few chunks long), prefilled chunk by
+    chunk and then decoded together, as the engine drives its two
+    programs; every compared position's logits against the float32
+    reference's full forward. A recurrent state advances with every
+    call, so the forward the two programs wrap is run once, showing
+    every position's logits (the cell's probes hold the programs
+    themselves to the reference)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.serve.llm_engine import hybrid
+    from ray_tpu.serve.llm_engine import model as paged_model
+
+    engine = config["engine"]
+    rows, max_len = engine["max_batch_size"], engine["max_seq_len"]
+    block = engine.get("block_size") or GLOBAL_CONFIG.llm_block_size
+    chunk = engine.get("prefill_chunk") or GLOBAL_CONFIG.llm_prefill_chunk
+    width = -(-max_len // block)
+    steps = config["probes"]["max_new_tokens"]
+    lengths = list(config["probes"]["prompt_lengths"])[:rows - 1]
+    # One long context, a multiple of the reference's query block; its
+    # last `tail` positions are compared, the last `steps` decoded.
+    long_len = (max_len - max_len // 8) // 512 * 512 or max_len - 2 * chunk
+    tail = 4 * chunk
+    rng = np.random.default_rng([seed, 33])
+    filler = rng.integers(chunk, 20 * chunk, max(0, rows - len(lengths) - 1))
+    filler = np.minimum(filler, max_len - steps)
+    contexts = [rng.integers(1, model_config.vocab_size, int(n) + steps)
+                for n in (*lengths, long_len - steps, *filler)]
+    prefilled = [len(c) - steps for c in contexts]
+    compared = range(len(lengths) + 1)        # the probes and the long one
+
+    served = paged_model.serving_params(model_config, None, seed)
+    if round_weights:
+        # To the nearest value with that dtype's mantissa, by the bits: the
+        # v5e's compiler folds a conversion to float8 and back away (the
+        # first control read the same 0.2106 as the sound run). The
+        # narrower exponent range is not imitated, which only flatters
+        # the control. In place of the weights: both sets do not fit.
+        from jax import lax
+
+        def rounded(x):
+            drop = jnp.finfo(x.dtype).nmant - jnp.finfo(round_weights).nmant
+            bits = lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+            bits = (bits + (1 << (drop - 1))) & ~((1 << drop) - 1)
+            return lax.bitcast_convert_type(bits.astype(jnp.uint16), x.dtype)
+
+        served = jax.jit(lambda tree: jax.tree.map(rounded, tree),
+                         donate_argnums=(0,))(served)
+    say("hybrid", config=config["name"], layers=model_config.num_layers,
+        params=model_config.num_params, rows=rows, table=max_len,
+        contexts=[len(c) for c in contexts], round_weights=round_weights,
+        ring=hybrid.ring_positions(model_config, block, chunk),
+        device_bytes_in_use=device_bytes())
+    cache = hybrid.init_cache(model_config, 1 + rows * width, block, rows,
+                              chunk)
+    say("hybrid", cache={k: [list(v.shape), str(v.dtype)]
+                         for k, v in cache.items()},
+        cache_gib=round(sum(v.nbytes for v in cache.values()) / 2 ** 30, 3))
+    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
+    tables = np.zeros((rows, width), np.int32)
+    for turn in range(width):                 # no table is contiguous
+        for i, context in enumerate(contexts):
+            if turn < -(-len(context) // block):
+                tables[i, turn] = deck.pop()
+    shown_chunk = jax.jit(
+        lambda params, cache, tokens, positions, table, slot, n_valid:
+        hybrid.chunk_forward(params, cache, tokens, positions, table, slot,
+                             n_valid, model_config, block),
+        donate_argnums=(1,))
+    shown_step = jax.jit(
+        lambda params, cache, tokens, positions, tables:
+        hybrid.decode_forward(params, cache, tokens, positions, tables,
+                              model_config, block), donate_argnums=(1,))
+
+    got = [{} for _ in contexts]               # position -> logits row
+    for i, context in enumerate(contexts):
+        first_kept = prefilled[i] - 1 if i < len(lengths) \
+            else len(context) - tail
+        for start in range(0, prefilled[i], chunk):
+            n = min(chunk, prefilled[i] - start)
+            logits, cache = shown_chunk(
+                served, cache, *chunk_inputs(context, start, n, chunk),
+                jnp.asarray(tables[i:i + 1]), np.int32(i), np.int32(n))
+            if i in compared and start + n > first_kept:
+                logits = np.asarray(logits[0], np.float32)
+                for j in range(n):
+                    if start + j >= first_kept:
+                        got[i][start + j] = logits[j]
+    for step in range(steps):
+        last = np.zeros((rows, 1), np.int32)
+        positions = np.zeros((rows,), np.int32)
+        for i, context in enumerate(contexts):
+            last[i, 0] = context[prefilled[i] + step]
+            positions[i] = prefilled[i] + step
+        logits, cache = shown_step(served, cache, jnp.asarray(last),
+                                   jnp.asarray(positions),
+                                   jnp.asarray(tables))
+        logits = np.asarray(logits[:, 0], np.float32)
+        for i in compared:
+            got[i][int(positions[i])] = logits[i]
+    check(all(bool(jnp.isfinite(v.astype(jnp.float32)).all())
+              for v in cache.values()), "a cache is not finite")
+    del cache, shown_chunk, shown_step
+    if round_weights:
+        del served
+        gc.collect()
+        served = paged_model.serving_params(model_config, None, seed)
+    params = served            # the reference's: as the seed gives them
+    gc.collect()
+    say("hybrid", programs="prefill chunks over shuffled tables, then "
+        f"{steps} decode steps of {rows} busy rows",
+        positions_compared=sum(len(g) for g in got),
+        device_bytes_in_use=device_bytes())
+
+    short = contexts[:len(lengths)]
+    padded = np.zeros((len(short), -(-max(map(len, short)) // 128) * 128),
+                      np.int32)
+    for i, context in enumerate(short):
+        padded[i, :len(context)] = context
+    want = list(np.asarray(jax.jit(
+        lambda p, t: reference.forward(p, t, model))(params,
+                                                     jnp.asarray(padded))))
+    want.append(np.asarray(jax.jit(
+        lambda p, t: reference.forward(p, t, model, tail=tail))(
+            params, jnp.asarray(contexts[len(lengths)][None])))[0])
+    offsets = [0] * len(lengths) + [long_len - tail]
+    worst, worst_gap, by_context = 0.0, 0.0, []
+    for i in compared:
+        std = float(want[i][:len(contexts[i]) - offsets[i]].std())
+        here = 0.0
+        for position, logits in got[i].items():
+            row = want[i][position - offsets[i]]
+            here = max(here, float(np.abs(logits - row).max()) / std)
+            # What the cell's probes measure: how far under the
+            # reference's best logit the program's own choice lies.
+            worst_gap = max(worst_gap,
+                            float(row.max() - row[logits.argmax()]))
+        by_context.append(round(here, 4))
+        worst = max(worst, here)
+    bound = REHEARSED_HYBRID_LOGITS if rehearse else HYBRID_LOGITS
+    say("hybrid", check="logits through the three caches against the "
+        f"float32 reference {config['reference']}", device=device["kind"],
+        worst_diff_in_std=round(worst, 4), by_context=by_context,
+        contexts=[len(contexts[i]) for i in compared],
+        worst_argmax_gap=round(worst_gap, 4),
+        logit_std=round(float(want[0].std()), 3), bound=bound,
+        round_weights=round_weights)
+    if round_weights:
+        check(worst > bound, f"weights rounded through {round_weights} "
+              f"stayed inside the bound ({worst} <= {bound}): the "
+              "comparison cannot tell a lower precision")
+    else:
+        check(worst <= bound, f"logits off by {worst} standard deviations")
+
+
 # ------------------------------------------------------- four chips: sharded
 
 
@@ -969,7 +1148,7 @@ def main(argv: "list[str] | None" = None) -> int:
 
     if args.paged_logits:
         phase_paged_logits(args.paged_logits, args.seed, args.rehearse,
-                           device)
+                           device, args.round_weights)
     elif args.chips == 4:
         phase_sharded(sz, args.seed, device)
     else:

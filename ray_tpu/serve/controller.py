@@ -53,6 +53,21 @@ class _DeploymentState:
     latency_policy: Any = None
 
 
+def _constructed(handle) -> bool:
+    """The actor's constructor has returned or raised (a handle that
+    does not say counts as constructed)."""
+    import ray_tpu
+
+    ref = getattr(handle, "_creation_ref", None)
+    if ref is None:
+        return True
+    try:
+        ready, _ = ray_tpu.wait([ref], timeout=0)
+    except Exception:  # noqa: BLE001 — the probe will say what is wrong
+        return True
+    return bool(ready)
+
+
 class ServeController:
     """Runs as a named actor; methods are the control-plane API."""
 
@@ -317,8 +332,13 @@ class ServeController:
         one outstanding check_health ref; a probe that raises → dead, a
         probe unanswered past health_check_timeout_s → dead (hung
         replica), otherwise keep waiting. A slow replica never stalls
-        the reconcile thread, and a replica with a long __init__ only
-        fails once the timeout genuinely elapses."""
+        the reconcile thread. A replica is first probed when its
+        constructor has returned or raised: a constructor is
+        initialisation, not a hung call, and one that builds a model
+        outlasts the timeout on a healthy replica (compiling the
+        weights' initialisation from an empty cache took over 30 s on
+        the v5e, PR 33: the replica was killed and replaced, over and
+        over, each replacement starting the same compilation again)."""
         import ray_tpu
 
         with self._lock:
@@ -331,6 +351,8 @@ class ServeController:
                 replicas = list(state.replicas)
             for replica in replicas:
                 if replica.probe is None:
+                    if not _constructed(replica.handle):
+                        continue
                     try:
                         replica.probe = (
                             replica.handle.check_health.remote(), now)

@@ -6,6 +6,13 @@ step for every active stream — tokens stream out per step, finished
 rows free their blocks between steps, and cache pressure preempts the
 lowest-progress stream (recompute-on-resume) instead of failing it.
 
+The model's family (``model.family(config)``, looked up once) gives the
+weights, the cache and the two programs: a stack of identical layers
+over one paged pool, or a hybrid's three caches (``hybrid.py``: the one
+full-attention pool, a ring of blocks a row for the window layers, a
+recurrent state a row). A request holds a row slot from its claim to
+its release: its row of the decode step and of every per-row cache.
+
 Counters ship as ``ENGINE_STAT_KEYS`` through the node-stats heartbeat
 piggyback (``ray_tpu_node_engine`` /metrics family) via the
 process-local engine registry below.
@@ -32,6 +39,7 @@ from __future__ import annotations
 
 import functools
 import os
+import sys
 import threading
 import time
 import weakref
@@ -79,7 +87,30 @@ ENGINE_STAT_KEYS = (
     # counter-keys pass, which reads this tuple's literals.)
     "expert_choices", "expert_slots", "experts_touched",
     "expert_peak_choices",
+    # Summed over decode steps: positions the rows' contexts hold in
+    # the full-attention pool, and positions the step gathered from it
+    # (the table's whole width for every row). Then a hybrid model's
+    # per-row caches: blocks of a window ring written over with newer
+    # positions, and recurrent states started from zero (a request's
+    # first chunk, and its first again after a preemption).
+    "kv_positions_live", "kv_positions_read",
+    "window_blocks_recycled", "state_resets",
 )
+
+# The engine thread lets go of the interpreter inside every program call
+# and every read of its tokens, several times a step. To get it back it
+# waits for whichever thread is running Python to give it up, which a
+# thread that does not block does only when the switch interval is over:
+# at the default 5 ms, with a hundred stream and client threads in the
+# process, ``engine.decode.launch`` took 31 ms a step for 1 to 2 (the
+# traced host plane of a slow run, PR 33). A process that hosts an engine
+# of ``_MANY_ROWS`` rows or more hands the interpreter over at most this
+# long after it is asked for: at 32 rows (a hundred and fifty threads)
+# that took the slow phases away (six runs within 1.2%); at 16 rows,
+# where the launch waits 1 to 3 ms, it cost 8% of the tokens a second
+# (634 and 646 for 695), so fewer rows leave the interpreter alone.
+_SWITCH_INTERVAL_S = 0.0005
+_MANY_ROWS = 32
 
 # Live engines in THIS process (serve replicas are co-hosted with the
 # node executor, so daemon heartbeats pick these up; driver-local
@@ -110,6 +141,7 @@ class LLMEngine:
         from ray_tpu.models import llama
 
         self.config = config or llama.LlamaConfig.tiny()
+        self._family = paged_model.family(self.config)
         self.params = paged_model.serving_params(self.config, params, seed)
         self.max_batch = int(max_batch_size)
         self.max_len = int(max_seq_len or self.config.max_seq_len)
@@ -132,8 +164,10 @@ class LLMEngine:
             int(max_waiting or GLOBAL_CONFIG.llm_max_waiting),
             self.max_tokens)
         self._mesh = mesh
-        self._pool = PagedKVCache.init_pool(self.config, cache.num_blocks,
-                                            self.block_size)
+        self._pool = self._new_pool()
+        # Positions a row of the family's window ring holds (0: none).
+        self._ring = self._family.ring_positions(
+            self.config, self.block_size, self.prefill_chunk_len)
         # The expert counters' accumulator: an argument and a result
         # of every step, never donated, so a reader on another thread
         # holds an array that stays valid. None for a dense model.
@@ -149,6 +183,9 @@ class LLMEngine:
         self._pass = _PassClock()
         self._lock = lock_witness.Condition("llm_engine.LLMEngine.state")
         self._shutdown = threading.Event()
+        if self.max_batch >= _MANY_ROWS \
+                and sys.getswitchinterval() > _SWITCH_INTERVAL_S:
+            sys.setswitchinterval(_SWITCH_INTERVAL_S)
         _LIVE.add(self)
         self._loop_thread = threading.Thread(
             target=self._engine_loop, name="llm-paged-engine", daemon=True)
@@ -158,13 +195,27 @@ class LLMEngine:
 
     @functools.cached_property
     def _decode_step(self):
-        return paged_model.make_engine_decode_step(
+        return self._family.make_engine_decode_step(
             self.config, self.block_size)
 
     @functools.cached_property
     def _prefill_step(self):
-        return paged_model.make_engine_prefill_chunk(
+        return self._family.make_engine_prefill_chunk(
             self.config, self.block_size, self.prefill_chunk_len)
+
+    def _new_pool(self) -> dict:
+        """The family's cache, zeroed: one dict, donated to every step."""
+        return self._family.init_cache(
+            self.config, self._sched.cache.num_blocks, self.block_size,
+            self.max_batch, self.prefill_chunk_len)
+
+    def _recycled(self, start: int, end: int) -> int:
+        """Blocks of a row's window ring that writing positions
+        ``start..end - 1`` begins to write over."""
+        if not self._ring:
+            return 0
+        bs = self.block_size
+        return max(0, -(-end // bs) - -(-max(start, self._ring) // bs))
 
     # ----------------------------------------------------------- public API
 
@@ -222,6 +273,13 @@ class LLMEngine:
         """Yield tokens AS the engine emits them (consumption overlaps
         decode). Terminates with the sealed result: StopIteration on
         success, the typed error otherwise."""
+        for tokens in self.stream_token_batches(req):
+            yield from tokens
+
+    def stream_token_batches(self, req: EngineRequest):
+        """``stream_tokens`` for a consumer that pays per delivery: yields
+        LISTS, each the tokens that were waiting when it looked (one
+        while the consumer keeps up; more once it has fallen behind)."""
         import queue as queue_mod
 
         assert req.stream is not None, "submit(stream=True) first"
@@ -231,11 +289,18 @@ class LLMEngine:
             except queue_mod.Empty:
                 self._check_caller_deadline(req)
                 continue
-            if kind == "tok":
-                yield payload
-            elif kind == "end":
+            tokens = []
+            while kind == "tok":
+                tokens.append(payload)
+                try:
+                    kind, payload = req.stream.get_nowait()
+                except queue_mod.Empty:
+                    kind = None
+            if tokens:
+                yield tokens
+            if kind == "end":
                 return
-            else:
+            if kind == "err":
                 raise payload
 
     def _check_caller_deadline(self, req: EngineRequest) -> None:
@@ -365,7 +430,7 @@ class LLMEngine:
                     # reachable while sealed-but-unswept holders pin
                     # blocks — the next sweep frees them).
                     self._sched.prefilling = None
-                    self._sched.cache.release(req.block_table)
+                    self._sched.release(req)
                     self._counters["shed_cache"] += 1
                     return "shed"
                 if victim is None:
@@ -403,9 +468,9 @@ class LLMEngine:
             return True  # re-queued; pressure eased — progress made
 
         with tracing.phase("engine.prefill.launch", req=req.rid, tokens=n):
-            chunk = paged_model.pack_prefill_chunk(
+            chunk = self._family.pack_prefill_chunk(
                 self.prefill_chunk_len, self.blocks_per_seq,
-                req.context[start:start + n], start, table)
+                req.context[start:start + n], start, table, req.slot)
             try:
                 with jax_compat.set_mesh(self._mesh):
                     last_logits, self._pool, self._expert_stats = \
@@ -418,6 +483,10 @@ class LLMEngine:
                 self._counters["host_calls"] += 1
                 self._counters["prefill_chunks"] += 1
                 self._counters["prefill_tokens"] += n
+                self._counters["window_blocks_recycled"] += \
+                    self._recycled(start, start + n)
+                if start == 0 and self._family.recurrent:
+                    self._counters["state_resets"] += 1
                 req.prefilled += n
                 if req.prefilled < len(req.context):
                     return True
@@ -474,7 +543,7 @@ class LLMEngine:
         return out
 
     def _finish_locked(self, req: EngineRequest) -> None:
-        self._sched.cache.release(req.block_table)
+        self._sched.release(req)
         if req in self._sched.active:
             self._sched.active.remove(req)
         self._counters["finished"] += 1
@@ -503,10 +572,12 @@ class LLMEngine:
             if not active:
                 return True  # everything preempted: progress made
             span.set(rows=len(active))
-            rows = paged_model.pack_decode_rows(
+            # As held now: a row sealed while the step runs loses its.
+            slots = [req.slot for req in active]
+            rows = self._family.pack_decode_rows(
                 self.max_batch, self.blocks_per_seq,
                 ((req.last_token, req.position, req.temperature,
-                  req.block_table) for req in active))
+                  req.block_table) for req in active), slots)
 
         self._maybe_chaos_slow_step()
         try:
@@ -531,12 +602,18 @@ class LLMEngine:
                 if len(active) >= 2:
                     self._counters["batched_decode_steps"] += 1
                 self._counters["decode_tokens"] += len(active)
+                self._counters["kv_positions_live"] += sum(
+                    req.position + 1 for req in active)
+                self._counters["kv_positions_read"] += \
+                    self.max_batch * self.max_tokens
                 finished = 0
-                for i, req in enumerate(active):
+                for slot, req in zip(slots, active):
+                    self._counters["window_blocks_recycled"] += \
+                        self._recycled(req.position, req.position + 1)
                     if req.sealed or req not in self._sched.active:
                         continue  # expired/externally sealed mid-step
-                    self._emit(req, int(nxt[i]))
-                    req.last_token = int(nxt[i])
+                    self._emit(req, int(nxt[slot]))
+                    req.last_token = int(nxt[slot])
                     req.position += 1
                     req.remaining -= 1
                     if req.remaining <= 0 \
@@ -571,11 +648,10 @@ class LLMEngine:
             sched.active.clear()
             sched.prefilling = None
             for req in victims:
-                sched.cache.release(req.block_table)
+                sched.release(req)
         for req in victims:
             self._seal(req, exc)
-        self._pool = PagedKVCache.init_pool(
-            self.config, self._sched.cache.num_blocks, self.block_size)
+        self._pool = self._new_pool()
 
     # ---------------------------------------------------------------- stats
 
@@ -623,7 +699,7 @@ class LLMEngine:
             sched.active.clear()
             sched.prefilling = None
             for req in victims:
-                sched.cache.release(req.block_table)
+                sched.release(req)
         for req in victims:
             self._seal(req, RuntimeError("LLM engine shut down"))
         self._loop_thread.join(timeout=5.0)

@@ -33,6 +33,11 @@ reading and writing the PAGED pool:
   ``jax_compat.set_mesh(mesh)`` and the same jitted fns become pjit
   (params/pool sharded via ``ray_tpu.parallel.sharding``).
 
+A configuration of another family (``family(config)``, the one place it
+is looked up) brings its own forward, cache and packers under the same
+two program names and the same one-array-a-pass contract:
+``hybrid.py`` for layers of several kinds over three caches.
+
 Runs on CPU under tier-1 (plain jnp/einsum — no pallas dependency);
 the block/gather structure is what the Ragged Paged Attention kernel
 (arxiv 2604.15464) implements natively on TPU.
@@ -40,7 +45,9 @@ the block/gather structure is what the Ragged Paged Attention kernel
 
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +55,38 @@ import numpy as np
 from jax import lax
 
 from ray_tpu.models import llama, moe
+from ray_tpu.serve.llm_engine.kv_cache import PagedKVCache
+
+
+@dataclasses.dataclass(frozen=True)
+class Family:
+    """What the engine asks of a model family, chosen ONCE from the
+    configuration by ``family``: its weights, its cache (one dict,
+    donated through every step), its two programs and the packers of
+    their host arrays. ``ring_positions(config, block_size, chunk_len)``
+    is how many positions a row of its window cache holds (0: it has
+    none); ``recurrent``: a row owns a state slot that a request's first
+    chunk resets."""
+    init_params: Callable
+    init_cache: Callable    # (config, num_blocks, block_size, rows, chunk)
+    make_engine_decode_step: Callable
+    make_engine_prefill_chunk: Callable
+    pack_decode_rows: Callable
+    pack_prefill_chunk: Callable
+    ring_positions: Callable = lambda config, block_size, chunk_len: 0
+    recurrent: bool = False
+
+
+def family(config) -> Family:
+    """The one place a configuration's family is looked up: a
+    configuration names it (``family = "hybrid"``:
+    ``models/phi4flash.py``); without a name it is the stack of
+    identical layers over one paged pool of this module."""
+    if getattr(config, "family", "paged") == "hybrid":
+        from ray_tpu.serve.llm_engine import hybrid
+
+        return hybrid.FAMILY
+    return PAGED
 
 
 def serving_params(config, params: "dict | None" = None,
@@ -67,7 +106,8 @@ def serving_params(config, params: "dict | None" = None,
         return jax.tree.map(lambda x: x.astype(config.dtype), tree)
 
     if params is None:
-        return jax.jit(lambda key: cast(llama.init_params(config, key)))(
+        init_params = family(config).init_params
+        return jax.jit(lambda key: cast(init_params(config, key)))(
             jax.random.PRNGKey(seed))
     if all(x.dtype == config.dtype for x in jax.tree.leaves(params)):
         return params
@@ -216,6 +256,15 @@ def _accumulated(stats, counts):
     return moe.accumulate(stats, counts)
 
 
+def sample_next(last, key, temps):
+    """The next token of every row from its logits ``last`` [B, V]:
+    the argmax at temperature 0, else a draw."""
+    greedy = jnp.argmax(last, axis=-1)
+    sampled = jax.random.categorical(
+        key, last / jnp.maximum(temps, 1e-4)[:, None], axis=-1)
+    return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+
+
 def _decode_body(config, block_size: int):
     """One decode step, traced by whichever program wraps it."""
 
@@ -226,12 +275,7 @@ def _decode_body(config, block_size: int):
         logits, pool, counts, _ = _forward_paged(
             params, pool, tokens, positions[:, None], block_tables,
             config, block_size)
-        last = logits[:, -1, :]
-        greedy = jnp.argmax(last, axis=-1)
-        sampled = jax.random.categorical(
-            key, last / jnp.maximum(temps, 1e-4)[:, None], axis=-1)
-        nxt = jnp.where(temps > 0, sampled, greedy)
-        return nxt.astype(jnp.int32), pool, \
+        return sample_next(logits[:, -1, :], key, temps), pool, \
             _accumulated(expert_stats, counts)
 
     return decode_step
@@ -277,14 +321,18 @@ def make_prefill_chunk(config, block_size: int):
 # array's layout is known to its packer and its program, here, alone.
 
 
-def pack_decode_rows(batch: int, width: int, active) -> np.ndarray:
+def pack_decode_rows(batch: int, width: int, active,
+                     slots=None) -> np.ndarray:
     """The decode program's host array, int32 ``[batch, 3 + width]``:
     per row its token, position, temperature (the float32's bits) and
     block table, from ``active``'s ``(token, position, temperature,
-    table)``; the rows past them stay zero (inactive)."""
+    table)``, each in the row ``slots`` gives it (the engine: the
+    request's row slot; without ``slots`` in order); the other rows stay
+    zero (inactive)."""
     rows = np.zeros((batch, 3 + width), dtype=np.int32)
     temps = rows[:, 2].view(np.float32)
-    for i, (token, position, temperature, table) in enumerate(active):
+    for i, (token, position, temperature, table) in zip(
+            slots or range(batch), active):
         rows[i, 0], rows[i, 1], temps[i] = token, position, temperature
         rows[i, 3:3 + len(table)] = table
     return rows
@@ -309,11 +357,13 @@ def make_engine_decode_step(config, block_size: int):
 
 
 def pack_prefill_chunk(chunk_len: int, width: int, tokens, start: int,
-                       table) -> np.ndarray:
+                       table, slot: int = 0) -> np.ndarray:
     """The prefill program's host array, int32 ``[2 + 2 * chunk_len +
     width]``: ``n_valid``, ``last_idx``, then the chunk's ``tokens``
     (at most ``chunk_len``, at global positions ``start...``), their
-    positions and the request's block table, each zero-padded."""
+    positions and the request's block table, each zero-padded. The
+    request's row ``slot`` is of no use to a model without a per-row
+    cache."""
     n = len(tokens)
     chunk = np.zeros((2 + 2 * chunk_len + width,), dtype=np.int32)
     chunk[0], chunk[1] = n, n - 1
@@ -337,3 +387,14 @@ def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
                     expert_stats)
 
     return prefill_chunk
+
+
+PAGED = Family(
+    init_params=llama.init_params,
+    init_cache=lambda config, num_blocks, block_size, rows, chunk_len:
+    PagedKVCache.init_pool(config, num_blocks, block_size),
+    make_engine_decode_step=make_engine_decode_step,
+    make_engine_prefill_chunk=make_engine_prefill_chunk,
+    pack_decode_rows=pack_decode_rows,
+    pack_prefill_chunk=pack_prefill_chunk,
+)

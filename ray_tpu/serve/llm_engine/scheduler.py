@@ -71,7 +71,7 @@ class EngineRequest:
         "prefilled", "sample_first", "remaining", "last_token",
         "preempted", "sealed", "error", "done", "stream",
         "rid", "submitted_ns", "claimed_ns", "first_token_ns", "sealed_ns",
-        "trace_ctx",
+        "trace_ctx", "slot",
     )
     _rids = itertools.count()
 
@@ -86,6 +86,9 @@ class EngineRequest:
         self.state = WAITING
         self.output: "list[int]" = []
         self.block_table: "list[int]" = []
+        # The row it holds while it prefills and decodes (its row of the
+        # decode step and of every per-row cache); -1: none.
+        self.slot = -1
         self.position = 0
         # Tokens to (re)prefill this attempt; recomputed on resume.
         self.context: "list[int]" = list(self.tokens)
@@ -131,6 +134,9 @@ class Scheduler:
         self.waiting: "deque[EngineRequest]" = deque()
         self.prefilling: "EngineRequest | None" = None
         self.active: "list[EngineRequest]" = []
+        # Row slots: the prefilling request and the active ones hold one
+        # each (a claim needs a free decode row, so there is always one).
+        self._free_slots = list(range(max_batch - 1, -1, -1))
 
     # ------------------------------------------------------------ admission
 
@@ -158,6 +164,7 @@ class Scheduler:
             return None
         req = self.waiting.popleft()
         self.prefilling = req
+        req.slot = self._free_slots.pop()
         req.state = PREFILL
         req.prefilled = 0
         if not req.claimed_ns:
@@ -175,6 +182,14 @@ class Scheduler:
             req.sample_first = True
         return req
 
+    def release(self, req: EngineRequest) -> None:
+        """Give back what ``req`` holds: its blocks and its row slot
+        (finish, preemption, shed, deadline expiry, shutdown)."""
+        self.cache.release(req.block_table)
+        if req.slot >= 0:
+            self._free_slots.append(req.slot)
+            req.slot = -1
+
     # ----------------------------------------------------------- preemption
 
     def pick_victim(self) -> "EngineRequest | None":
@@ -187,9 +202,10 @@ class Scheduler:
                    key=lambda r: (len(r.output), -r.rid))
 
     def preempt(self, victim: EngineRequest) -> None:
-        """Release the victim's blocks and push it to the FRONT of the
-        waiting queue (it resumes as soon as pressure eases)."""
-        self.cache.release(victim.block_table)
+        """Release the victim's blocks and row slot and push it to the
+        FRONT of the waiting queue (it resumes as soon as pressure
+        eases)."""
+        self.release(victim)
         if victim in self.active:
             self.active.remove(victim)
         if self.prefilling is victim:
@@ -224,7 +240,7 @@ class Scheduler:
             self.active.remove(req)
             expired.append(req)
         for req in expired:
-            self.cache.release(req.block_table)
+            self.release(req)
         return [r for r in expired if not r.sealed]
 
     # -------------------------------------------------------------- queries

@@ -68,12 +68,23 @@ class LLMEngineServer:
     def generate(self, request: dict):
         """Streaming generation — tokens yield as decode steps emit
         them (pair with ``handle.options(stream=True)``)."""
-        req = self._engine.submit(
+        yield from self._engine.stream_tokens(self._submit_stream(request))
+
+    def generate_batches(self, request: dict):
+        """``generate`` as a replica streams it: lists of the tokens that
+        were waiting, so a stream that has fallen behind the engine is
+        delivered a call at a time (``Replica.handle_request_streaming``
+        looks for this sibling); a caller of the handle sees single
+        tokens either way."""
+        yield from self._engine.stream_token_batches(
+            self._submit_stream(request))
+
+    def _submit_stream(self, request: dict):
+        return self._engine.submit(
             list(request.get("tokens") or []),
             max_new_tokens=int(request.get("max_new_tokens", 16)),
             temperature=float(request.get("temperature", 0.0)),
             deadline=self._deadline(request), stream=True)
-        yield from self._engine.stream_tokens(req)
 
     # --------------------------------------------------------- control path
 

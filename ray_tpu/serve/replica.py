@@ -127,22 +127,36 @@ class Replica:
         ("chunk", value)* then ("end", n) | ("err", exc)."""
         kwargs, token = self._admit(kwargs)
         n = 0
+        # A streaming method may have a sibling ``<name>_batches`` that
+        # yields LISTS: the chunks that were already waiting when it
+        # looked (one, while the consumers keep up). Each list crosses
+        # to the queue actor in one call, so a producer that has fallen
+        # behind catches up a call at a time, not a chunk at a time; the
+        # consumer sees the same chunks in the same order.
+        batched = getattr(self._callable, method_name + "_batches", None) \
+            if method_name != "__call__" else None
         try:
-            result = self._invoke(method_name, args, kwargs)
+            result = batched(*args, **kwargs) if batched is not None \
+                else self._invoke(method_name, args, kwargs)
             if not inspect.isgenerator(result):
                 result = iter([result])
             for chunk in result:
+                chunks = chunk if batched is not None else (chunk,)
                 try:
                     # The entry point's cost per chunk, and through
                     # the queue actor the core runtime's.
                     with tracing.phase("serve.stream.put"):
-                        queue.put(("chunk", chunk))
+                        if len(chunks) == 1:
+                            queue.put(("chunk", chunks[0]))
+                        else:
+                            queue.put_batch([("chunk", c) for c in chunks])
                 except Exception:  # noqa: BLE001 — consumer abandoned
-                    # The caller tore down the queue (early break):
+                    # The caller tore down the queue (early break), or
+                    # has taken nothing for the queue's put_timeout_s:
                     # stop producing — cancellation, not an error.
                     getattr(result, "close", lambda: None)()
                     return n
-                n += 1
+                n += len(chunks)
             queue.put(("end", n))
             return n
         except BaseException as exc:  # noqa: BLE001 — shipped to caller

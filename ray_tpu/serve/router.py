@@ -32,6 +32,13 @@ class DeploymentStreamingResponse:
     """
 
     _POLL_S = 0.2
+    # Chunks taken from the queue actor in one call. A consumer that
+    # keeps up takes one at a time, as before; one that has fallen
+    # behind (32 streams of a token every 30 ms outran a call a chunk:
+    # 1,069 tokens/s made, 852 delivered, the rest queued until the
+    # clients hung at shutdown; my chip run, PR 33) catches up a call at
+    # a time.
+    _TAKE = 64
 
     def __init__(self, queue, object_ref, router=None, replica_idx=None,
                  request=None, model_id=None, timeout_s: float = 300.0,
@@ -106,11 +113,14 @@ class DeploymentStreamingResponse:
         # BackPressureError, not livelock hammering the router).
         retries_left = 100
         backoff_s = 0.01
+        taken: list = []  # from the queue, not yet handled; oldest last
         try:
             while not self._done:
                 try:
-                    kind, payload = self._queue.get(
-                        block=True, timeout=self._POLL_S)
+                    if not taken:
+                        taken = self._queue.get_available(
+                            self._TAKE, timeout=self._POLL_S)[::-1]
+                    kind, payload = taken.pop()
                 except Empty:
                     if _time.monotonic() > deadline:
                         raise TimeoutError(
@@ -654,7 +664,17 @@ class DeploymentHandle:
             # One channel per streaming call; BOUNDED so a producer
             # outpacing the consumer blocks instead of buffering the
             # whole stream in the queue actor.
-            stream_queue = Queue(maxsize=256)
+            # A consumer that takes nothing from the full queue for a
+            # minute is gone: without the bound a replica's call slot (a
+            # non-daemon pool thread when max_concurrency > 1) retries
+            # its put for ever and holds the interpreter's exit (a hung
+            # client after a closed-loop run; my chip run, PR 33).
+            # The consumer waits INSIDE the queue actor for the next put
+            # (48 callers asking every 10 ms were 4,800 calls a second
+            # with nothing to fetch, and kept the interpreter from the
+            # engine's thread: 40 ms a step of host time; PR 33).
+            stream_queue = Queue(maxsize=256, put_timeout_s=60.0,
+                                 waiting_get=True)
         try:
             return router.assign_request(
                 self._method_name, args, kwargs, model_id=model_id,

@@ -24,9 +24,13 @@ class Full(Exception):
 class _QueueActor:
     def __init__(self, maxsize: int = 0):
         import collections
+        import threading
 
         self.maxsize = maxsize
         self._items: collections.deque = collections.deque()
+        # A queue made with ``waiting_get`` runs two calls at a time: a
+        # consumer's ``get_available`` waits HERE for a producer's put.
+        self._arrived = threading.Condition()
 
     def qsize(self) -> int:
         return len(self._items)
@@ -38,16 +42,21 @@ class _QueueActor:
         return 0 < self.maxsize <= len(self._items)
 
     def put_nowait(self, item: Any) -> bool:
-        if self.full():
-            return False
-        self._items.append(item)
-        return True
+        with self._arrived:
+            if self.full():
+                return False
+            self._items.append(item)
+            self._arrived.notify_all()
+            return True
 
     def put_nowait_batch(self, items: list) -> bool:
-        if self.maxsize and len(self._items) + len(items) > self.maxsize:
-            return False
-        self._items.extend(items)
-        return True
+        with self._arrived:
+            if self.maxsize \
+                    and len(self._items) + len(items) > self.maxsize:
+                return False
+            self._items.extend(items)
+            self._arrived.notify_all()
+            return True
 
     def get_nowait(self):
         if not self._items:
@@ -59,23 +68,51 @@ class _QueueActor:
             return False, None
         return True, [self._items.popleft() for _ in range(num_items)]
 
+    def get_available(self, max_items: int, wait_s: float = 0.0) -> list:
+        """Whatever is queued, oldest first, at most ``max_items``
+        (possibly nothing), after waiting up to ``wait_s`` for the
+        first (only a ``waiting_get`` queue is asked to wait: with one
+        call at a time the put that would end the wait could not run)."""
+        with self._arrived:
+            if not self._items and wait_s > 0:
+                self._arrived.wait(wait_s)
+            take = min(max_items, len(self._items))
+            return [self._items.popleft() for _ in range(take)]
+
 
 class Queue:
     """Cluster-visible FIFO queue; handles are shareable across tasks
     and actors like any ActorHandle."""
 
-    def __init__(self, maxsize: int = 0, actor_options: dict | None = None):
+    def __init__(self, maxsize: int = 0, actor_options: dict | None = None,
+                 put_timeout_s: float | None = None,
+                 waiting_get: bool = False):
         self.maxsize = maxsize
-        options = actor_options or {}
+        # ``get_available`` waits inside the actor for a put (the actor
+        # runs two calls at a time) instead of asking every 10 ms: a
+        # consumer costs a call per delivery, not a hundred a second.
+        self.waiting_get = waiting_get
+        # What a blocking put waits for room when it is given no timeout
+        # of its own (None: for ever, as ``queue.Queue``): a producer
+        # whose consumer is gone then raises Full instead of retrying
+        # for the life of its thread.
+        self.put_timeout_s = put_timeout_s
+        options = dict(actor_options or {})
+        if waiting_get:
+            options.setdefault("max_concurrency", 2)
         self.actor = ray_tpu.remote(_QueueActor).options(
             **options).remote(maxsize)
 
     def __getstate__(self):
-        return {"maxsize": self.maxsize, "actor": self.actor}
+        return {"maxsize": self.maxsize, "actor": self.actor,
+                "put_timeout_s": self.put_timeout_s,
+                "waiting_get": self.waiting_get}
 
     def __setstate__(self, state):
         self.maxsize = state["maxsize"]
         self.actor = state["actor"]
+        self.put_timeout_s = state.get("put_timeout_s")
+        self.waiting_get = state.get("waiting_get", False)
 
     # -- inspection ---------------------------------------------------
     def qsize(self) -> int:
@@ -97,6 +134,7 @@ class Queue:
             if not ray_tpu.get(self.actor.put_nowait.remote(item)):
                 raise Full
             return
+        timeout = self.put_timeout_s if timeout is None else timeout
         deadline = None if timeout is None else time.monotonic() + timeout
         while True:
             if ray_tpu.get(self.actor.put_nowait.remote(item)):
@@ -107,6 +145,20 @@ class Queue:
 
     def put_nowait(self, item: Any) -> None:
         self.put(item, block=False)
+
+    def put_batch(self, items: list, timeout: float | None = None) -> None:
+        """Block until ALL of ``items`` are queued, in order: one actor
+        call where they fit, a call per ``maxsize`` of them otherwise."""
+        items = list(items)
+        step = self.maxsize or len(items) or 1
+        timeout = self.put_timeout_s if timeout is None else timeout
+        deadline = None if timeout is None else time.monotonic() + timeout
+        for at in range(0, len(items), step):
+            part = items[at:at + step]
+            while not ray_tpu.get(self.actor.put_nowait_batch.remote(part)):
+                if deadline is not None and time.monotonic() >= deadline:
+                    raise Full
+                time.sleep(0.01)
 
     def put_nowait_batch(self, items: list) -> None:
         if not ray_tpu.get(self.actor.put_nowait_batch.remote(
@@ -137,6 +189,26 @@ class Queue:
         if not ok:
             raise Empty
         return items
+
+    def get_available(self, max_items: int,
+                      timeout: float | None = None) -> list:
+        """Block until something is queued, then take all of it (at most
+        ``max_items``) in ONE actor call: a consumer that has fallen
+        behind catches up a call at a time, not an item at a time."""
+        deadline = None if timeout is None else time.monotonic() + timeout
+        while True:
+            wait_s = 0.0
+            if self.waiting_get:
+                wait_s = 0.2 if deadline is None else \
+                    max(0.0, min(0.2, deadline - time.monotonic()))
+            items = ray_tpu.get(
+                self.actor.get_available.remote(max_items, wait_s))
+            if items:
+                return items
+            if deadline is not None and time.monotonic() >= deadline:
+                raise Empty
+            if not self.waiting_get:
+                time.sleep(0.01)
 
     def shutdown(self) -> None:
         ray_tpu.kill(self.actor)
