@@ -5,6 +5,8 @@ budgets, claim/advance ONE prefill chunk, then ONE fixed-shape decode
 step for every active stream — tokens stream out per step, finished
 rows free their blocks between steps, and cache pressure preempts the
 lowest-progress stream (recompute-on-resume) instead of failing it.
+The step's table is as wide as its longest live row needs, of three
+widths (``table_widths``), each a program the constructor has built.
 
 The model's family (``model.family(config)``, looked up once) gives the
 weights, the cache and the two programs: a stack of identical layers
@@ -89,12 +91,15 @@ ENGINE_STAT_KEYS = (
     "expert_peak_choices",
     # Summed over decode steps: positions the rows' contexts hold in
     # the full-attention pool, and positions the step gathered from it
-    # (the table's whole width for every row). Then a hybrid model's
+    # (the step's table width for every row). Then a hybrid model's
     # per-row caches: blocks of a window ring written over with newer
     # positions, and recurrent states started from zero (a request's
     # first chunk, and its first again after a preemption).
     "kv_positions_live", "kv_positions_read",
     "window_blocks_recycled", "state_resets",
+    # Decode steps run at a table narrower than the whole
+    # (``table_widths``): how often the width followed the context.
+    "decode_steps_narrow",
 )
 
 # The engine thread lets go of the interpreter inside every program call
@@ -116,6 +121,21 @@ _MANY_ROWS = 32
 # node executor, so daemon heartbeats pick these up; driver-local
 # engines surface under node="driver" in the scrape).
 _LIVE: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def table_widths(blocks_per_seq: int) -> "tuple[int, ...]":
+    """The table widths a decode step may be given, in blocks, narrowest
+    first: a quarter, a half and the whole of a row's table, each in
+    whole blocks. Every row of a step gathers and attends over the
+    step's whole width, so a step takes the narrowest that holds its
+    longest live table. Three, because each is a program built before
+    the engine serves: halving twice keeps the read within twice the
+    longest context down to a quarter of the table, and a further rung
+    would add a compile for ever less. A table under four blocks has
+    the one width."""
+    if blocks_per_seq < 4:
+        return (blocks_per_seq,)
+    return (-(-blocks_per_seq // 4), -(-blocks_per_seq // 2), blocks_per_seq)
 
 
 class _PassClock:
@@ -148,10 +168,14 @@ class LLMEngine:
         self.block_size = int(block_size or GLOBAL_CONFIG.llm_block_size)
         self.prefill_chunk_len = int(
             prefill_chunk or GLOBAL_CONFIG.llm_prefill_chunk)
-        # Table width: blocks covering max_len, rounded up — ONE decode
-        # program at [max_batch, M * block_size] attention width.
+        # Table width: blocks covering max_len, rounded up. The prefill
+        # program takes the whole table; a decode step the narrowest of
+        # ``_widths`` that holds its longest row's, so the decode program
+        # exists once a width, at [max_batch, width * block_size]
+        # attention width.
         self.blocks_per_seq = -(-self.max_len // self.block_size)
         self.max_tokens = self.blocks_per_seq * self.block_size
+        self._widths = table_widths(self.blocks_per_seq)
         if num_blocks is None:
             # Default pool: every row can hold a full-length sequence
             # (+ scratch). Smaller pools oversubscribe and lean on
@@ -186,6 +210,7 @@ class LLMEngine:
         if self.max_batch >= _MANY_ROWS \
                 and sys.getswitchinterval() > _SWITCH_INTERVAL_S:
             sys.setswitchinterval(_SWITCH_INTERVAL_S)
+        self._build_decode_programs()
         _LIVE.add(self)
         self._loop_thread = threading.Thread(
             target=self._engine_loop, name="llm-paged-engine", daemon=True)
@@ -203,11 +228,38 @@ class LLMEngine:
         return self._family.make_engine_prefill_chunk(
             self.config, self.block_size, self.prefill_chunk_len)
 
+    def _build_decode_programs(self) -> None:
+        """The decode program at every width, before the loop takes a
+        request: a width first met while serving would compile while
+        rows wait. Each is run once on rows that are all inactive (they
+        write the scratch block, advance no state, and their samples
+        are thrown away); the key and the expert counters the engine
+        holds are neither donated nor replaced, so a seeded request
+        samples what it would have without these runs. Built in turn:
+        from a warm cache a width costs 0.4 s, nearly all of it tracing
+        in Python, which threads do not share (three at once took 1.7 s
+        for 1.2 on the chip, PR 34). Lowered and compiled by name
+        first: the call then finds the program, and the two together
+        take 0.4 s where the call alone took 0.6."""
+        for width in self._widths:
+            rows = self._family.pack_decode_rows(self.max_batch, width, ())
+            args = (self.params, self._pool, rows, self._key,
+                    self._expert_stats)
+            with jax_compat.set_mesh(self._mesh):
+                self._decode_step.lower(*args).compile()
+                _, self._pool, _, _ = self._decode_step(*args)
+
     def _new_pool(self) -> dict:
-        """The family's cache, zeroed: one dict, donated to every step."""
-        return self._family.init_cache(
-            self.config, self._sched.cache.num_blocks, self.block_size,
-            self.max_batch, self.prefill_chunk_len)
+        """The family's cache, zeroed: one dict, donated to every step.
+        Made by a program under the engine's mesh, as the key is, so it
+        is placed as the steps return it: the program a fresh pool
+        meets is the one every later pool meets."""
+        import jax
+
+        with jax_compat.set_mesh(self._mesh):
+            return jax.jit(lambda: self._family.init_cache(
+                self.config, self._sched.cache.num_blocks, self.block_size,
+                self.max_batch, self.prefill_chunk_len))()
 
     def _recycled(self, start: int, end: int) -> int:
         """Blocks of a row's window ring that writing positions
@@ -574,8 +626,10 @@ class LLMEngine:
             span.set(rows=len(active))
             # As held now: a row sealed while the step runs loses its.
             slots = [req.slot for req in active]
+            longest = max(len(req.block_table) for req in active)
+            width = next(w for w in self._widths if w >= longest)
             rows = self._family.pack_decode_rows(
-                self.max_batch, self.blocks_per_seq,
+                self.max_batch, width,
                 ((req.last_token, req.position, req.temperature,
                   req.block_table) for req in active), slots)
 
@@ -605,7 +659,9 @@ class LLMEngine:
                 self._counters["kv_positions_live"] += sum(
                     req.position + 1 for req in active)
                 self._counters["kv_positions_read"] += \
-                    self.max_batch * self.max_tokens
+                    self.max_batch * width * self.block_size
+                if width < self.blocks_per_seq:
+                    self._counters["decode_steps_narrow"] += 1
                 finished = 0
                 for slot, req in zip(slots, active):
                     self._counters["window_blocks_recycled"] += \

@@ -120,12 +120,15 @@ def test_fused_rms_norm_compiles_for_v5e(v5e_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _lower_paged_step(program, config, batch, block, table, chip):
+def _lower_paged_step(program, config, batch, block, table, chip,
+                      width=None):
     """``decode_step`` or ``prefill_chunk``, the plain program or, as
     ``engine_...``, the one the engine calls (one host array, the key
     carried), lowered on shapes placed on the described chip; the
     pool's shape beside it. A sparse configuration's step carries its
-    expert accumulator."""
+    expert accumulator. ``width``: the blocks of a row's table the
+    engine's decode step is given (``engine.table_widths``; the pool
+    stays ``table`` blocks a row)."""
     from ray_tpu._private.config import GLOBAL_CONFIG
     from ray_tpu.models import llama, moe
     from ray_tpu.serve.llm_engine import model as paged_model
@@ -147,7 +150,7 @@ def _lower_paged_step(program, config, batch, block, table, chip):
     chunk = GLOBAL_CONFIG.llm_prefill_chunk
     if program == "engine_decode_step":
         lowered = paged_model.make_engine_decode_step(config, block).lower(
-            params, pool, on_chip((batch, 3 + table)),
+            params, pool, on_chip((batch, 3 + (width or table))),
             on_chip((2,), jnp.uint32), stats)
     elif program == "engine_prefill_chunk":
         lowered = paged_model.make_engine_prefill_chunk(
@@ -179,6 +182,16 @@ def test_engine_decode_compiles_for_v5e(v5e_chip):
             < 8 * 2 ** 30)
 
 
+def _mistral_serve():
+    """The Mistral serve cells' widths, 2 of their 16 layers."""
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_seq_len=2048, rope_theta=1e6)
+
+
 @pytest.mark.parametrize("program", [
     "decode_step", "prefill_chunk", "engine_decode_step",
     "engine_prefill_chunk"])
@@ -189,14 +202,8 @@ def test_paged_steps_update_the_pool_in_place_on_v5e(v5e_chip, program):
     nothing the size of the gathered keys: at these sizes a repeated
     float32 copy of them is 0.5 GiB and a copy of the 2-layer pool 0.13
     GiB, and neither shows in a CPU test."""
-    from ray_tpu.models import llama
-
-    config = llama.LlamaConfig(
-        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
-        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
-        max_seq_len=2048, rope_theta=1e6)
-    lowered, pool_shape = _lower_paged_step(program, config, 16, 16, 128,
-                                            v5e_chip)
+    lowered, pool_shape = _lower_paged_step(program, _mistral_serve(), 16,
+                                            16, 128, v5e_chip)
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 0.25 * 2 ** 30
@@ -258,6 +265,94 @@ def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
             if " copy(" in line and pool_text in line] == []
     # The accumulator rides along: int32 [2, 4] in, the same out.
     assert "s32[2,4]" in text
+
+
+@pytest.mark.parametrize("width", [32, 64, 128])
+@pytest.mark.parametrize("model", ["mistral", "olmoe"])
+def test_decode_step_at_each_table_width_on_v5e(v5e_chip, model, width):
+    """The engine's decode program at the three widths it is built at
+    (``engine.table_widths`` of 128 blocks: 512, 1024 and 2048 positions
+    a row), 16 rows over the whole pool, for both 16-row serve
+    configurations: what the cases above hold the whole width to holds
+    at a quarter and a half of it. The pool is updated where it lies and
+    never copied; the gathered keys are never widened to float32 (D9's
+    row of zeros keeps the scores a bf16 matrix product with 16
+    key-value heads and no grouping); under the whole width there are
+    no temporaries to speak of."""
+    import re
+
+    from ray_tpu.serve.llm_engine.engine import table_widths
+
+    assert width in table_widths(128)
+    config = _mistral_serve() if model == "mistral" else _olmoe(2)
+    lowered, pool_shape = _lower_paged_step(
+        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    # Under the whole width the gathered keys leave HBM's temporaries.
+    assert memory.temp_size_in_bytes < (160 if width == 128 else 16) * 2 ** 20
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
+    text = compiled.as_text()
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    positions, kv = width * 16, config.num_kv_heads
+    assert re.search(rf"= f32\[({positions},16|16,{positions}),{kv},128\]",
+                     text) is None
+    assert re.search(F32_EXPERTS, text) is None
+    # The gather is of this width, in the pool's dtype.
+    assert re.search(rf"bf16\[({positions},16|16,{positions}),{kv},128\]",
+                     text) is not None
+    if width < 128:
+        assert f"[2048,16,{kv},128]" not in text
+
+
+@pytest.mark.parametrize("width", [64, 128, 256])
+def test_hybrid_decode_step_at_each_table_width_on_v5e(v5e_chip, width):
+    """Phi-4-mini-flash's decode program (published widths, 32 rows, a
+    table of 256 blocks of 16; 8 of its 32 layers: the scans make the
+    program the same but for their length) at its three widths, 1024,
+    2048 and 4096 positions a row: the three caches updated where they
+    lie, the one pool gathered at the step's width in bf16 and never
+    widened, and the temporaries (the gathered keys and values: 0.64 GiB
+    at the whole width) shrinking with it."""
+    import re
+
+    from ray_tpu.models import phi4flash
+    from ray_tpu.serve.llm_engine import hybrid
+    from ray_tpu.serve.llm_engine.engine import table_widths
+
+    assert width in table_widths(256)
+    config = phi4flash.Phi4FlashConfig(num_layers=8)
+    rows, block, table, chunk = 32, 16, 256, 32
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: hybrid.FAMILY.init_params(
+        config, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
+        config, 1 + rows * table, block, rows, chunk)))
+    compiled = hybrid.make_engine_decode_step(config, block).lower(
+        params, cache,
+        on_chip(hybrid.pack_decode_rows(rows, width, ()), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip),
+        None).compile()
+    memory = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
+                      for c in jax.tree.leaves(cache))
+    assert memory.alias_size_in_bytes >= cache_bytes
+    positions = width * block
+    gathered = 2 * rows * positions * 1280 * 2   # keys and values, bf16
+    assert memory.temp_size_in_bytes < gathered + 64 * 2 ** 20
+    text = compiled.as_text()
+    assert f"bf16[{rows},{positions},1280]" in text
+    assert re.search(rf"= f32\[{rows},{positions},1280\]", text) is None
+    assert [line for line in text.splitlines()
+            if " copy(" in line and "= bf16[1,8193,16,1280]" in line] == []
+    if width < table:
+        assert f"[{rows},4096,1280]" not in text
 
 
 def test_serving_params_never_hold_a_float32_expert_tensor_on_v5e(v5e_chip):

@@ -620,7 +620,9 @@ def test_host_calls_are_two_a_decode_step(paged_engine):
 def test_decode_program_is_compiled_once_over_turnover(meshed):
     """The key goes into the decode program as it came out of it. Under
     a mesh what comes out is committed to the mesh: a key made on the
-    host would compile the program a second time at the second step."""
+    host would compile the program a second time at the second step.
+    The program exists once a table width (``engine.table_widths``),
+    each built by the constructor."""
     import jax
     import numpy as np
 
@@ -631,10 +633,12 @@ def test_decode_program_is_compiled_once_over_turnover(meshed):
     engine = LLMEngine(_f32_tiny(), max_batch_size=3, max_seq_len=64,
                        block_size=8, prefill_chunk=8, seed=1, mesh=mesh)
     try:
+        assert engine._widths == (2, 4, 8)
+        assert engine._decode_step._cache_size() == 3
         _turnover(engine)
         assert engine.engine_stats()["decode_steps"] >= 12
-        # One entry: every call found the first call's program.
-        assert engine._decode_step._cache_size() == 1
+        # Every call found a program the constructor had built.
+        assert engine._decode_step._cache_size() == 3
         assert engine._key.committed == meshed
     finally:
         engine.shutdown()

@@ -492,7 +492,12 @@ def test_the_engine_serves_the_references_greedy_tokens(engine):
                                           prompt.tolist(), 8)
     stats = engine.engine_stats()
     assert stats["state_resets"] == stats["admitted"]
-    assert stats["kv_positions_read"] == stats["decode_steps"] * ROWS * 64
+    # Contexts of at most 26 + 8 positions: the table's 64 are read only
+    # while the longest row is past 32 (``engine.table_widths``).
+    narrow = stats["decode_steps_narrow"]
+    assert 0 < narrow < stats["decode_steps"]
+    assert stats["decode_steps"] * ROWS * 16 < stats["kv_positions_read"] \
+        <= ROWS * (32 * narrow + 64 * (stats["decode_steps"] - narrow))
     assert 0 < stats["kv_positions_live"] < stats["kv_positions_read"]
     # 26 + 8 positions pass the ring's 20: blocks 5.. are written over.
     assert stats["window_blocks_recycled"] >= 3
