@@ -58,7 +58,8 @@ ARGS.add_argument("--paged-logits", metavar="CONFIG_JSON", default=None,
                        "engine's programs against the configuration's "
                        "plain reference, logits and expert choices")
 ARGS.add_argument("--round-weights", metavar="DTYPE", default=None,
-                  help="with --paged-logits of a hybrid configuration: the "
+                  help="with --paged-logits of a hybrid or block-diffusion "
+                       "configuration: the "
                        "programs run on weights rounded through this dtype "
                        "(float8_e4m3fn: the nearest precision below "
                        "bfloat16) while the reference keeps the weights as "
@@ -670,6 +671,9 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
     if paged_model.family(model_config).recurrent:
         return phase_hybrid_logits(config, model_config, model, reference,
                                    seed, rehearse, device, round_weights)
+    if model_config.block_length:
+        return phase_block_logits(config, model_config, model, reference,
+                                  seed, rehearse, device, round_weights)
     sparse = model_config.num_experts > 0
     rows, max_len = (config["engine"][k]
                      for k in ("max_batch_size", "max_seq_len"))
@@ -849,6 +853,27 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
           f"{differing} of {choices} expert choices differ")
 
 
+def round_mantissa(tree, dtype_name: str):
+    """bfloat16 weights rounded to the nearest value with
+    ``dtype_name``'s mantissa, by the bits: the v5e's compiler folds a
+    conversion to float8 and back away (PR 33's first control read the
+    same as its sound run). The narrower exponent range is not imitated,
+    which only flatters the control. In place of the weights: both sets
+    do not fit."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def rounded(x):
+        drop = jnp.finfo(x.dtype).nmant - jnp.finfo(dtype_name).nmant
+        bits = lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+        bits = (bits + (1 << (drop - 1))) & ~((1 << drop) - 1)
+        return lax.bitcast_convert_type(bits.astype(jnp.uint16), x.dtype)
+
+    return jax.jit(lambda tree: jax.tree.map(rounded, tree),
+                   donate_argnums=(0,))(tree)
+
+
 # Hybrid family (PR 33), on the v5e at the published widths: worst
 # difference of a logit over the reference's logit standard deviation.
 HYBRID_LOGITS = 0.4
@@ -896,21 +921,7 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
 
     served = paged_model.serving_params(model_config, None, seed)
     if round_weights:
-        # To the nearest value with that dtype's mantissa, by the bits: the
-        # v5e's compiler folds a conversion to float8 and back away (the
-        # first control read the same 0.2106 as the sound run). The
-        # narrower exponent range is not imitated, which only flatters
-        # the control. In place of the weights: both sets do not fit.
-        from jax import lax
-
-        def rounded(x):
-            drop = jnp.finfo(x.dtype).nmant - jnp.finfo(round_weights).nmant
-            bits = lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
-            bits = (bits + (1 << (drop - 1))) & ~((1 << drop) - 1)
-            return lax.bitcast_convert_type(bits.astype(jnp.uint16), x.dtype)
-
-        served = jax.jit(lambda tree: jax.tree.map(rounded, tree),
-                         donate_argnums=(0,))(served)
+        served = round_mantissa(served, round_weights)
     say("hybrid", config=config["name"], layers=model_config.num_layers,
         params=model_config.num_params, rows=rows, table=max_len,
         contexts=[len(c) for c in contexts], round_weights=round_weights,
@@ -1016,6 +1027,225 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
               "comparison cannot tell a lower precision")
     else:
         check(worst <= bound, f"logits off by {worst} standard deviations")
+
+
+# Diffusion over blocks (PR 35), on the v5e at the published widths
+# (my chip runs, PR 35; four seeds sound, three with weights rounded to
+# float8's mantissa). The cell's own measure, how far under the
+# reference's best logit the program's choice lies: 0.10 to 0.234
+# sound, 0.95 to 2.21 rounded; its limit in the cell is 0.45. The worst
+# difference of a logit over the reference's logit standard deviation,
+# a heavy-tailed measure here (a mixture of 128 experts with
+# renormalised weights moves far on one differing choice): 0.37 to 1.47
+# sound (0.20 to 0.23 where both sides chose the same experts), 2.36 to
+# 3.20 rounded.
+BLOCK_ARGMAX_GAP = 0.45
+BLOCK_LOGITS = 1.9
+REHEARSED_BLOCK_LOGITS = 0.35
+
+
+def phase_block_logits(config: dict, model_config, model: dict, reference,
+                       seed: int, rehearse: bool, device: dict,
+                       round_weights: "str | None") -> None:
+    """A block-diffusion configuration at its real size, every row of
+    the engine busy: the probes' lengths, one context half the table
+    long, the rest a few chunks to most of the table. Each row's whole
+    blocks are prefilled chunk by chunk; then its last
+    ``probes.max_new_tokens`` positions are made block by block as the
+    engine makes them under the ``sequential`` rule (a pass with the
+    block's earlier-fixed positions known and the rest masked, for each
+    of ``denoising_steps``, then the finishing pass), the rows staggered
+    so that every pass carries rows in every phase, the tokens fed
+    being the context's own. Every pass goes through the engine's block
+    program (whose fixed tokens must be its logits' argmax) and through
+    the forward it wraps, showing the logits, which are held to the
+    float32 reference's ``forward`` (entry p - 1: the logits that
+    decided p) for the probes and the long context."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.serve.llm_engine import model as paged_model
+    from ray_tpu.serve.llm_engine.kv_cache import PagedKVCache
+
+    engine = config["engine"]
+    rows, max_len = engine["max_batch_size"], engine["max_seq_len"]
+    block = engine.get("block_size") or GLOBAL_CONFIG.llm_block_size
+    chunk = engine.get("prefill_chunk") or GLOBAL_CONFIG.llm_prefill_chunk
+    width = -(-max_len // block)
+    size, mask_id = model_config.block_length, model_config.mask_token_id
+    steps, new = model_config.denoising_steps, \
+        config["probes"]["max_new_tokens"]
+    family = paged_model.family(model_config)
+    lengths = list(config["probes"]["prompt_lengths"])[:rows - 1]
+    long_len = max_len // 2
+    rng = np.random.default_rng([seed, 35])
+    filler = rng.integers(chunk // size, (max_len - new) // size,
+                          max(0, rows - len(lengths) - 1)) * size
+    contexts = [rng.integers(1, model_config.vocab_size, int(n) + new)
+                for n in (*lengths, long_len - new, *filler)]
+    prefilled = [len(c) - new for c in contexts]
+    compared = range(len(lengths) + 1)
+
+    served = paged_model.serving_params(model_config, None, seed)
+    if round_weights:
+        served = round_mantissa(served, round_weights)
+    say("blocks", config=config["name"], layers=model_config.num_layers,
+        params=model_config.num_params, rows=rows, table=max_len,
+        contexts=[len(c) for c in contexts], round_weights=round_weights,
+        block_length=size, denoising_steps=steps,
+        device_bytes_in_use=device_bytes())
+    pool = PagedKVCache.init_pool(model_config, 1 + rows * width, block)
+    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
+    tables = np.zeros((rows, width), np.int32)
+    for turn in range(width):                 # no table is contiguous
+        for i, context in enumerate(contexts):
+            if turn < -(-len(context) // block):
+                tables[i, turn] = deck.pop()
+    prefill = paged_model.make_prefill_chunk(model_config, block)
+    step = family.make_engine_decode_step(model_config, block)
+    def show(params, pool, tokens, positions, tables, busy):
+        logits, pool, _, routing = paged_model._forward_paged(
+            params, pool, tokens, positions, tables, model_config, block,
+            busy=busy)
+        return logits, pool, routing
+
+    shown = jax.jit(show, donate_argnums=(1,))
+    for i, context in enumerate(contexts):
+        for start in range(0, prefilled[i], chunk):
+            n = min(chunk, prefilled[i] - start)
+            _, pool, _ = prefill(
+                served, pool, *chunk_inputs(context, start, n, chunk),
+                jnp.asarray(tables[i:i + 1]), np.int32(n), np.int32(n - 1))
+
+    # Row i's turn t (its t-th pass) is given at global pass t + i % 3:
+    # turn t is pass t % (steps + 1) of block t // (steps + 1).
+    fixed_before = np.cumsum([0] + [paged_model.fix_count(size, steps, t)
+                                    for t in range(steps)])
+    turns = new // size * (steps + 1)
+    got = [{} for _ in contexts]               # position -> logits row
+    chosen = [{} for _ in contexts]            # position -> [layers, k]
+    key = jax.random.PRNGKey(seed)
+    for global_pass in range(turns + 2):
+        active, at, done = [], {}, {}
+        for i, context in enumerate(contexts):
+            turn = global_pass - i % 3
+            if 0 <= turn < turns:
+                start = prefilled[i] + turn // (steps + 1) * size
+                t = turn % (steps + 1)
+                known = size if t == steps else int(fixed_before[t])
+                at[i], done[i] = start, t
+                active.append((
+                    [int(x) for x in context[start:start + known]]
+                    + [-1] * (size - known), start, 0.0,
+                    paged_model.fix_count(size, steps, t) if t < steps
+                    else 0, 0, 0.9, tables[i]))
+        slots = sorted(at)
+        after, pool, _, key = step(served, pool, jnp.asarray(
+            family.pack_decode_rows(rows, width, active, slots)), key)
+        # The same pass through the showing forward, from the same rows
+        # (an inactive row: zeros, as the packer leaves it).
+        tokens = np.zeros((rows, size), np.int32)
+        starts = np.zeros((rows, 1), np.int32)
+        for i, (row_block, start, *_) in zip(slots, active):
+            tokens[i] = [mask_id if t < 0 else t for t in row_block]
+            starts[i] = start
+        busy = np.isin(np.arange(rows), slots)
+        logits, pool, routing = shown(
+            served, pool, jnp.asarray(tokens),
+            jnp.asarray(starts + np.arange(size)),
+            jnp.asarray(np.where(busy[:, None], tables, 0)),
+            jnp.asarray(busy))
+        logits, after = np.array(logits, np.float32), np.asarray(after)
+        routing = np.asarray(routing)                  # [layers, B, T, k]
+        logits[..., mask_id] = -np.inf
+        for i in slots:
+            if done[i] == steps:
+                continue
+            for o in range(int(fixed_before[done[i]]),
+                           int(fixed_before[done[i] + 1])):
+                check(int(after[i, o]) == int(logits[i, o].argmax()),
+                      "the block program's token is not its logits' argmax")
+                if i in compared:
+                    got[i][at[i] + o] = logits[i, o]
+                    chosen[i][at[i] + o] = routing[:, i, o]
+    check(np.isfinite(np.asarray(pool["k"][:, :, 0, 0, 0],
+                                 np.float32)).all(), "pool not finite")
+    del pool, prefill, step, shown
+    if round_weights:
+        del served
+        gc.collect()
+        served = paged_model.serving_params(model_config, None, seed)
+    params = served            # the reference's: as the seed gives them
+    gc.collect()
+    say("blocks", programs="prefill chunks over shuffled tables, then "
+        f"{turns + 2} passes of up to {rows} busy rows in every phase",
+        positions_compared=sum(len(g) for g in got),
+        device_bytes_in_use=device_bytes())
+
+    short = contexts[:len(lengths)]
+    padded = np.zeros((len(short), -(-max(map(len, short)) // 128) * 128),
+                      np.int32)
+    for i, context in enumerate(short):
+        padded[i, :len(context)] = context
+    forward = jax.jit(lambda p, t: reference.forward(p, t, model,
+                                                     with_routing=True))
+    short_logits, short_routing = forward(params, jnp.asarray(padded))
+    want = list(np.asarray(short_logits))
+    theirs = list(np.moveaxis(np.asarray(short_routing), 1, 0))
+    long_logits, long_routing = forward(
+        params, jnp.asarray(contexts[len(lengths)][None]))
+    want.append(np.asarray(long_logits)[0])
+    theirs.append(np.asarray(long_routing)[:, 0])       # [layers, L, k]
+    kept = np.arange(model_config.vocab_size) != mask_id
+    worst, by_context, gaps = 0.0, [], []
+    differing = choices = 0
+    worst_same = worst_other = 0.0
+    for i in compared:
+        std = float(want[i][:len(contexts[i]) - 1][:, kept].std())
+        here = gap = 0.0
+        for position, logits in got[i].items():
+            row = want[i][position - 1]
+            error = float(np.abs(logits - row)[kept].max()) / std
+            # A choice differs if the reference did not make it too, at
+            # the same layer, for this position's deciding pass.
+            ours, ref = chosen[i][position], theirs[i][:, position - 1]
+            other = ~(ours[:, :, None] == ref[:, None, :]).any(-1)
+            differing += int(other.sum())
+            choices += other.size
+            if other.any():
+                worst_other = max(worst_other, error)
+            else:
+                worst_same = max(worst_same, error)
+            here = max(here, error)
+            # What the cell's probes measure: how far under the
+            # reference's best logit the program's own choice lies.
+            gap = max(gap, float(row.max() - row[logits.argmax()]))
+        by_context.append(round(here, 4))
+        gaps.append(round(gap, 4))
+        worst = max(worst, here)
+    worst_gap = max(gaps)
+    bound = REHEARSED_BLOCK_LOGITS if rehearse else BLOCK_LOGITS
+    say("blocks", check="logits of the block passes against the float32 "
+        f"reference {config['reference']}", device=device["kind"],
+        worst_diff_in_std=round(worst, 4), by_context=by_context,
+        contexts=[len(contexts[i]) for i in compared],
+        worst_argmax_gap=worst_gap, argmax_gap_by_context=gaps, bound=bound,
+        own_expert_choices_differing=differing, own_expert_choices=choices,
+        worst_diff_own_choices_same=round(worst_same, 4),
+        worst_diff_own_choice_differing=round(worst_other, 4),
+        round_weights=round_weights)
+    if round_weights:
+        check(worst > bound and (rehearse or worst_gap > BLOCK_ARGMAX_GAP),
+              f"weights rounded through {round_weights} stayed inside the "
+              f"bounds ({worst} <= {bound} or {worst_gap} <= "
+              f"{BLOCK_ARGMAX_GAP}): the comparison cannot tell a lower "
+              "precision")
+    else:
+        check(worst <= bound, f"logits off by {worst} standard deviations")
+        check(worst_gap <= BLOCK_ARGMAX_GAP, f"the program's choice lies "
+              f"{worst_gap} under the reference's best logit")
 
 
 # ------------------------------------------------------- four chips: sharded

@@ -74,10 +74,30 @@ class LlamaConfig:
     experts_per_token: int = 1
     norm_topk_prob: bool = False
     moe_aux_loss_coef: float = 0.01
-    # QK-norm as OLMoE applies it: an RMSNorm with its own scale over
-    # the WHOLE query projection (all heads together) and another over
-    # the whole key projection, before the heads are rotated.
-    qk_norm: bool = False
+    # Of RANDOM weights only (``init_params``): the standard deviation
+    # of a router's logits on a unit-RMS input. At 1, of 128 logits the
+    # 8th and 9th largest lie 0.06 apart on average and the 8 chosen
+    # hold a quarter of the softmax; a trained router is more decided.
+    router_init_scale: float = 1.0
+    # QK-norm, before the heads are rotated. True, as OLMoE applies
+    # it: an RMSNorm with its own scale over the WHOLE query projection
+    # (all heads together) and another over the whole key projection.
+    # "head", as Qwen3 and SDAR apply it: an RMSNorm over each head's
+    # head_dim values, with one scale [head_dim] for all query heads and
+    # one for all key heads.
+    qk_norm: "bool | str" = False
+    # Generation by diffusion over blocks (SDAR): > 0 makes a position
+    # see ALL of its own block of this many positions, both ways, and
+    # every earlier block; the logits at a position are then for the
+    # token AT it (a masked position is filled in place). The serving
+    # engine alone generates so (serve/llm_engine/model.py); the other
+    # three are a request's defaults there: passes a block, which masked
+    # positions a pass fixes, and the confidence that fixes one early.
+    block_length: int = 0
+    mask_token_id: int = 0
+    denoising_steps: int = 1
+    remasking: str = "sequential"
+    confidence_threshold: float = 0.9
 
     @staticmethod
     def llama2_7b() -> "LlamaConfig":
@@ -111,7 +131,7 @@ class LlamaConfig:
             mlp = e * self.num_experts + 3 * e * m * experts_counted
         else:
             mlp = 3 * e * m  # dense swiglu
-        qk_norms = (h + kv) * d if self.qk_norm else 0
+        qk_norms = {False: 0, True: (h + kv) * d, "head": 2 * d}[self.qk_norm]
         per_layer = (e * h * d + 2 * e * kv * d + h * d * e  # attention
                      + mlp
                      + 2 * e + qk_norms)  # norms
@@ -155,13 +175,16 @@ def init_params(config: LlamaConfig, key: jax.Array) -> dict:
         "wo": dense_init(keys[4], h * d, n, h, d, e),
         "mlp_norm": norm_init(n, e),
     }
-    if config.qk_norm:
+    if config.qk_norm == "head":
+        layers.update({"q_norm": norm_init(n, d), "k_norm": norm_init(n, d)})
+    elif config.qk_norm:
         layers.update({"q_norm": norm_init(n, h, d),
                        "k_norm": norm_init(n, kv, d)})
     if config.num_experts > 0:
         from ray_tpu.models.moe import init_moe_params
 
-        layers.update(init_moe_params(keys[5], e, m, config.num_experts, n))
+        layers.update(init_moe_params(keys[5], e, m, config.num_experts, n,
+                                      config.router_init_scale))
     else:
         layers.update({
             "w_gate": dense_init(keys[5], e, n, e, m),
@@ -189,7 +212,9 @@ def param_logical_axes(config: LlamaConfig | None = None) -> dict:
         "wo": (None, "heads", None, "embed"),
         "mlp_norm": (None, "norm"),
     }
-    if config is not None and config.qk_norm:
+    if config is not None and config.qk_norm == "head":
+        layers.update({"q_norm": (None, None), "k_norm": (None, None)})
+    elif config is not None and config.qk_norm:
         layers.update({"q_norm": (None, "heads", None),
                        "k_norm": (None, "kv_heads", None)})
     if config is not None and config.num_experts > 0:
@@ -252,7 +277,10 @@ def qkv_projections(layer: dict, x: jax.Array, positions: jax.Array,
     q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
     k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
     v = jnp.einsum("ble,ekd->blkd", normed, layer["wv"].astype(dtype))
-    if config.qk_norm:
+    if config.qk_norm == "head":
+        q = rms_norm(q, layer["q_norm"], config.rms_norm_eps)
+        k = rms_norm(k, layer["k_norm"], config.rms_norm_eps)
+    elif config.qk_norm:
         q = _norm_over_heads(q, layer["q_norm"], config.rms_norm_eps)
         k = _norm_over_heads(k, layer["k_norm"], config.rms_norm_eps)
     q = rope(q, positions, config.rope_theta)
@@ -270,7 +298,7 @@ def _attention_block(layer: dict, x: jax.Array, positions: jax.Array,
     dtype = config.dtype
     h, kv = config.num_heads, config.num_kv_heads
     if tp_axis is not None:
-        if config.qk_norm:
+        if config.qk_norm is True:
             raise NotImplementedError(
                 "QK-norm spans all heads: under manual tp each shard "
                 "sees only its own")

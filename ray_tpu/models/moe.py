@@ -45,14 +45,18 @@ _LOW_BITS = 30  # an accumulator word holds 30 bits; the carry goes up
 
 
 def init_moe_params(key: jax.Array, hidden: int, mlp: int,
-                    num_experts: int, num_layers: int) -> dict:
+                    num_experts: int, num_layers: int,
+                    router_scale: float = 1.0) -> dict:
+    """Random weights; a router's logits on a unit-RMS input have the
+    standard deviation ``router_scale``."""
     keys = jax.random.split(key, 4)
 
     def dense(k, fan_in, *shape):
         return jax.random.normal(k, shape, dtype=jnp.float32) * fan_in ** -0.5
 
     return {
-        "w_router": dense(keys[0], hidden, num_layers, hidden, num_experts),
+        "w_router": dense(keys[0], hidden / router_scale ** 2, num_layers,
+                          hidden, num_experts),
         "w_gate": dense(keys[1], hidden, num_layers, num_experts, hidden, mlp),
         "w_up": dense(keys[2], hidden, num_layers, num_experts, hidden, mlp),
         "w_down": dense(keys[3], mlp, num_layers, num_experts, mlp, hidden),

@@ -15,6 +15,16 @@ full-attention pool, a ring of blocks a row for the window layers, a
 recurrent state a row). A request holds a row slot from its claim to
 its release: its row of the decode step and of every per-row cache.
 
+The loop counts tokens, not steps: a pass of the decode program makes
+0 or more tokens final for each busy row (``Family.advance``). An
+autoregressive row yields one a pass. A model that generates by
+diffusion over blocks (``config.block_length``) carries a block of
+positions in flight: a denoising pass fixes some of them, the block's
+tokens are emitted together once none is masked (a stream sees whole
+blocks), and one more pass, which yields nothing, stores the block's
+keys and values; its prefill yields no first token, and its table grows
+a block at a time.
+
 Counters ship as ``ENGINE_STAT_KEYS`` through the node-stats heartbeat
 piggyback (``ray_tpu_node_engine`` /metrics family) via the
 process-local engine registry below.
@@ -70,7 +80,14 @@ __all__ = ["ENGINE_STAT_KEYS", "LLMEngine",
 ENGINE_STAT_KEYS = (
     "admitted", "shed_queue_full", "shed_cache",
     "prefill_chunks", "prefill_tokens",
+    # ``decode_tokens``: tokens the decode passes made final and
+    # emitted (an autoregressive row yields one a step, so there: the
+    # rows of the steps, less a row sealed while its step ran).
+    # ``block_rows``: busy rows summed over the passes; ``commit_rows``:
+    # those of them on a finishing pass of diffusion over blocks, which
+    # stores a whole block's keys and values and yields no token.
     "decode_steps", "batched_decode_steps", "decode_tokens",
+    "block_rows", "commit_rows",
     "preemptions", "resumes", "finished", "deadline_expired",
     "slow_steps", "blocks_allocated", "blocks_freed",
     # Where the time went, in microseconds, summed on the engine
@@ -183,10 +200,21 @@ class LLMEngine:
             num_blocks = 1 + self.max_batch * self.blocks_per_seq
         cache = PagedKVCache(int(num_blocks), self.block_size,
                              self.blocks_per_seq)
+        # Positions a busy row's pass writes: one, or (diffusion over
+        # blocks) its block, which lies inside one paged block and one
+        # prefill chunk.
+        block_length = int(getattr(self.config, "block_length", 0))
+        self._span = block_length or 1
+        if self.block_size % self._span \
+                or self.prefill_chunk_len % self._span:
+            raise ValueError(
+                f"block_length {self._span} must divide block_size "
+                f"{self.block_size} and prefill_chunk "
+                f"{self.prefill_chunk_len}")
         self._sched = Scheduler(
             cache, self.max_batch,
             int(max_waiting or GLOBAL_CONFIG.llm_max_waiting),
-            self.max_tokens)
+            self.max_tokens, block_length)
         self._mesh = mesh
         self._pool = self._new_pool()
         # Positions a row of the family's window ring holds (0: none).
@@ -274,17 +302,38 @@ class LLMEngine:
     def submit(self, tokens, max_new_tokens: int = 16,
                temperature: float = 0.0,
                deadline: "float | None" = None, stream: bool = False,
-               name: str = "llm_generate") -> EngineRequest:
+               name: str = "llm_generate",
+               denoising_steps: "int | None" = None,
+               remasking: "str | None" = None,
+               confidence_threshold: "float | None" = None
+               ) -> EngineRequest:
         """Admit one request (bounded; full queue / never-fits sheds
         typed through the SystemOverloadedError path). ``deadline`` is
         ABSOLUTE (time.time()); inherit it from the serve call via
-        ``get_runtime_context().get_task_deadline()``."""
+        ``get_runtime_context().get_task_deadline()``. The last three
+        are a request's schedule under diffusion over blocks (passes a
+        block, ``model.REMASKING``, the dynamic rule's threshold); left
+        out, the model configuration's; of no use to another model."""
         max_new = max(1, min(int(max_new_tokens), self.max_tokens - 2))
         prompt = list(tokens) or [0]
         keep = max(1, self.max_tokens - max_new - 1)
         prompt = prompt[-keep:]
+        schedule = {}
+        if self._span > 1:
+            schedule = {
+                "denoising_steps": self.config.denoising_steps
+                if denoising_steps is None else int(denoising_steps),
+                "remasking": remasking or self.config.remasking,
+                "confidence_threshold": self.config.confidence_threshold
+                if confidence_threshold is None
+                else float(confidence_threshold)}
+            if schedule["remasking"] not in paged_model.REMASKING:
+                raise ValueError(
+                    f"remasking={schedule['remasking']!r}: one of "
+                    f"{paged_model.REMASKING}")
         req = EngineRequest(prompt, max_new, temperature,
-                            deadline=deadline, name=name, stream=stream)
+                            deadline=deadline, name=name, stream=stream,
+                            **schedule)
         with self._lock:
             if self._shutdown.is_set():
                 raise RuntimeError("LLM engine is shut down")
@@ -412,10 +461,24 @@ class LLMEngine:
                     to_wall + (end or req.sealed_ns) / 1e9, trace_id,
                     root, {"req": req.rid})
 
-    def _emit(self, req: EngineRequest, token: int) -> None:
-        req.output.append(token)
-        if req.stream is not None:
-            req.stream.put(("tok", token))
+    def _deliver_locked(self, req: EngineRequest, tokens: list) -> bool:
+        """Emit the tokens a pass made final for ``req``, in order; the
+        request's first are stamped. True when the request is over: its
+        limit reached, or its table's end."""
+        for token in tokens:
+            req.output.append(token)
+            if req.stream is not None:
+                req.stream.put(("tok", token))
+        req.remaining = req.max_new_tokens - len(req.output)
+        if tokens and not req.first_token_ns:
+            req.first_token_ns = time.monotonic_ns()
+            counters = self._counters
+            counters["first_tokens"] += 1
+            counters["queue_wait_us"] += \
+                (req.claimed_ns - req.submitted_ns) // 1000
+            counters["prefill_us"] += \
+                (req.first_token_ns - req.claimed_ns) // 1000
+        return req.remaining <= 0 or req.position >= self.max_tokens
 
     # --------------------------------------------------------------- engine
 
@@ -518,6 +581,10 @@ class LLMEngine:
             return True
         if status == "victim":
             return True  # re-queued; pressure eased — progress made
+        if n == 0:
+            # Diffusion over blocks, a prompt shorter than one block:
+            # nothing to prefill, all of it opens the block in flight.
+            return self._enter_decode(req, None)
 
         with tracing.phase("engine.prefill.launch", req=req.rid, tokens=n):
             chunk = self._family.pack_prefill_chunk(
@@ -542,30 +609,23 @@ class LLMEngine:
                 req.prefilled += n
                 if req.prefilled < len(req.context):
                     return True
-        # Prompt fully prefilled: enter the decode batch.
+        return self._enter_decode(req, last_logits)
+
+    def _enter_decode(self, req: EngineRequest, last_logits) -> bool:
+        """The context is prefilled: the request enters the decode
+        batch, with its first token where prefill yields one (not under
+        diffusion over blocks, and not on a resume)."""
         with tracing.phase("engine.prefill.first_token", req=req.rid), \
                 self._lock:
             req.position = len(req.context)
-            first_token = None
+            first = []
             if req.sample_first:
-                first_token = self._sample_first(req, last_logits)
-            else:
-                req.last_token = req.output[-1]
+                first = [self._sample_first(req, last_logits)]
+            if self._span == 1:
+                req.last_token = (first or req.output)[-1]
             self._sched.prefilling = None
             req.state = DECODE
-            req.remaining = req.max_new_tokens - len(req.output) \
-                - (1 if first_token is not None else 0)
-            if first_token is not None:
-                self._emit(req, first_token)
-                req.last_token = first_token
-                req.first_token_ns = time.monotonic_ns()
-                counters = self._counters
-                counters["first_tokens"] += 1
-                counters["queue_wait_us"] += \
-                    (req.claimed_ns - req.submitted_ns) // 1000
-                counters["prefill_us"] += \
-                    (req.first_token_ns - req.claimed_ns) // 1000
-            if req.remaining <= 0 or req.position >= self.max_tokens:
+            if self._deliver_locked(req, first):
                 self._finish_locked(req)
             else:
                 self._sched.active.append(req)
@@ -619,7 +679,7 @@ class LLMEngine:
             for req in list(self._sched.active):
                 if req not in self._sched.active:
                     continue  # already preempted as a victim
-                self._grow_or_preempt_locked(req, req.position + 1)
+                self._grow_or_preempt_locked(req, req.position + self._span)
             active = list(self._sched.active)
             if not active:
                 return True  # everything preempted: progress made
@@ -629,19 +689,18 @@ class LLMEngine:
             longest = max(len(req.block_table) for req in active)
             width = next(w for w in self._widths if w >= longest)
             rows = self._family.pack_decode_rows(
-                self.max_batch, width,
-                ((req.last_token, req.position, req.temperature,
-                  req.block_table) for req in active), slots)
+                self.max_batch, width, map(self._family.row_of, active),
+                slots)
 
         self._maybe_chaos_slow_step()
         try:
             with tracing.phase("engine.decode.launch", rows=len(active)), \
                     jax_compat.set_mesh(self._mesh):
-                nxt, self._pool, self._expert_stats, key = \
+                out, self._pool, self._expert_stats, key = \
                     self._decode_step(self.params, self._pool, rows,
                                       self._key, self._expert_stats)
             with tracing.phase("engine.decode.fetch"):
-                nxt = self._fetch(np.asarray, nxt)
+                out = self._fetch(np.asarray, out)
         except Exception as exc:  # noqa: BLE001 — donated pool is gone
             self._reset_after_failure(exc)
             return True
@@ -655,28 +714,30 @@ class LLMEngine:
                 self._counters["decode_steps"] += 1
                 if len(active) >= 2:
                     self._counters["batched_decode_steps"] += 1
-                self._counters["decode_tokens"] += len(active)
+                self._counters["block_rows"] += len(active)
                 self._counters["kv_positions_live"] += sum(
-                    req.position + 1 for req in active)
+                    req.position + self._span for req in active)
                 self._counters["kv_positions_read"] += \
                     self.max_batch * width * self.block_size
                 if width < self.blocks_per_seq:
                     self._counters["decode_steps_narrow"] += 1
-                finished = 0
+                finished = whole = 0
                 for slot, req in zip(slots, active):
                     self._counters["window_blocks_recycled"] += \
-                        self._recycled(req.position, req.position + 1)
+                        self._recycled(req.position,
+                                       req.position + self._span)
                     if req.sealed or req not in self._sched.active:
                         continue  # expired/externally sealed mid-step
-                    self._emit(req, int(nxt[slot]))
-                    req.last_token = int(nxt[slot])
-                    req.position += 1
-                    req.remaining -= 1
-                    if req.remaining <= 0 \
-                            or req.position >= self.max_tokens:
+                    tokens, committed = self._family.advance(req, out[slot])
+                    self._counters["decode_tokens"] += len(tokens)
+                    self._counters["commit_rows"] += committed
+                    whole += bool(tokens)
+                    if self._deliver_locked(req, tokens):
                         self._finish_locked(req)
                         finished += 1
-            span.set(finished=finished)  # annotated with the lock released
+            # Annotated with the lock released: requests that ended, and
+            # rows whose pass completed a block (its tokens went out).
+            span.set(finished=finished, blocks=whole)
         return True
 
     def _maybe_chaos_slow_step(self) -> None:
@@ -761,7 +822,8 @@ class LLMEngine:
         self._loop_thread.join(timeout=5.0)
 
     def __del__(self):
-        self._shutdown.set()
+        if hasattr(self, "_shutdown"):  # the constructor got that far
+            self._shutdown.set()
 
 
 # --------------------------------------------------------------------------

@@ -36,7 +36,11 @@ reading and writing the PAGED pool:
 A configuration of another family (``family(config)``, the one place it
 is looked up) brings its own forward, cache and packers under the same
 two program names and the same one-array-a-pass contract:
-``hybrid.py`` for layers of several kinds over three caches.
+``hybrid.py`` for layers of several kinds over three caches, and
+``BLOCKWISE`` below for generation by diffusion over blocks: the same
+layers and pool, ``T = block_length`` query rows a row of the batch, a
+mask that lets a position see all of its own block, and a pass that
+fixes 0 to ``block_length`` of a row's masked positions.
 
 Runs on CPU under tier-1 (plain jnp/einsum — no pallas dependency);
 the block/gather structure is what the Ragged Paged Attention kernel
@@ -56,6 +60,19 @@ from jax import lax
 
 from ray_tpu.models import llama, moe
 from ray_tpu.serve.llm_engine.kv_cache import PagedKVCache
+from ray_tpu.serve.llm_engine.scheduler import MASKED
+
+
+def _row_of_one(req):
+    """An autoregressive row: its last token, at the next position."""
+    return req.last_token, req.position, req.temperature, req.block_table
+
+
+def _advance_one(req, out):
+    """An autoregressive row's pass: the next token, one position on."""
+    req.last_token = int(out)
+    req.position += 1
+    return [req.last_token], False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,17 +92,27 @@ class Family:
     pack_prefill_chunk: Callable
     ring_positions: Callable = lambda config, block_size, chunk_len: 0
     recurrent: bool = False
+    # A busy row on the host: what ``pack_decode_rows`` is given for a
+    # request (``row_of(req)``), and what a pass made of it
+    # (``advance(req, out)``, ``out`` the row's slice of the program's
+    # first result): the request's state moved on, and (the tokens the
+    # pass made final for emission, whether it was a finishing pass).
+    row_of: Callable = _row_of_one
+    advance: Callable = _advance_one
 
 
 def family(config) -> Family:
     """The one place a configuration's family is looked up: a
     configuration names it (``family = "hybrid"``:
-    ``models/phi4flash.py``); without a name it is the stack of
-    identical layers over one paged pool of this module."""
+    ``models/phi4flash.py``) or has a ``block_length`` (generation by
+    diffusion over blocks); otherwise it is the stack of identical
+    layers over one paged pool of this module, one token a row a step."""
     if getattr(config, "family", "paged") == "hybrid":
         from ray_tpu.serve.llm_engine import hybrid
 
         return hybrid.FAMILY
+    if getattr(config, "block_length", 0) > 0:
+        return _blockwise(config.block_length)
     return PAGED
 
 
@@ -176,7 +203,12 @@ def _paged_attention_block(layer: dict, x: jax.Array,
     scores = jnp.einsum("btkrd,bskd->bkrts", q, keys,
                         preferred_element_type=jnp.float32)
     scores *= d ** -0.5
-    mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]  # [B,T,S]
+    horizon = positions  # the last position a query sees: causal
+    if config.block_length:
+        # Diffusion over blocks: all of its own block, and the earlier.
+        horizon = positions // config.block_length * config.block_length \
+            + config.block_length - 1
+    mask = jnp.arange(S)[None, None, :] <= horizon[:, :, None]    # [B,T,S]
     scores = jnp.where(mask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
     out = jnp.einsum("bkrts,bskd->btkrd", probs, values.astype(dtype))
@@ -202,7 +234,8 @@ def _expert_block(layer: dict, x: jax.Array, config):
 def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
                    positions: jax.Array, block_tables: jax.Array,
                    config, block_size: int,
-                   n_valid: "jax.Array | None" = None):
+                   n_valid: "jax.Array | None" = None,
+                   busy: "jax.Array | None" = None):
     """Shared prefill/decode forward over the paged pool. Returns
     (logits [B, T, V] f32, updated pool, expert counts, routing). The
     pool is part of the scan's carry, so every layer updates the one
@@ -215,15 +248,20 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
     are None for a dense model. The tokens counted are the chunk's
     first ``n_valid``, or without it (decode) the rows at a position
     past 0: an inactive row carries position 0, and a request's next
-    token never does."""
+    token never does (a block in flight can start at 0, so its program
+    says which rows are ``busy`` [B])."""
     x = params["embed"]["tokens"].astype(config.dtype)[tokens]
     sparse = config.num_experts > 0
     counts = None
     if sparse:
         counts = jnp.zeros((len(moe.EXPERT_COUNTERS),), jnp.int32)
-        valid = positions > 0 if n_valid is None else \
-            jnp.broadcast_to(jnp.arange(tokens.shape[1]) < n_valid,
-                             tokens.shape)
+        if n_valid is not None:
+            valid = jnp.broadcast_to(jnp.arange(tokens.shape[1]) < n_valid,
+                                     tokens.shape)
+        elif busy is not None:
+            valid = jnp.broadcast_to(busy[:, None], tokens.shape)
+        else:
+            valid = positions > 0
 
     def layer_step(carry, layer_and_index):
         x, pool_k, pool_v, counts = carry
@@ -389,6 +427,155 @@ def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
     return prefill_chunk
 
 
+# ------------------------------------------------------------------------
+# Generation by diffusion over blocks (``config.block_length`` > 0)
+# ------------------------------------------------------------------------
+#
+# A row carries a block of ``block_length`` positions in flight, from
+# ``position`` on: its known tokens, and ``MASKED`` where none is fixed
+# yet (which positions are fixed is this bookkeeping, never a comparison
+# of a token with the mask's id). A DENOISING pass runs the block against
+# the cache and itself and fixes some of its masked positions; when none
+# is left the block's tokens are emitted, and a FINISHING pass runs it
+# once more with its final tokens, which stores the keys and values
+# later blocks read (inside a block every position's keys depend on all
+# of its tokens, so what a denoising pass wrote is stale; each pass
+# simply writes the block's positions again). The mask's id is never
+# served: it is left out of the argmax, the sample and the confidence.
+
+#: Which masked positions a denoising pass fixes: the leftmost; those
+#: of the largest confidence; every one whose confidence is over the
+#: request's threshold, and the largest if fewer pass it.
+REMASKING = ("sequential", "low_confidence_static", "low_confidence_dynamic")
+_INACTIVE, _DENOISING, _FINISHING = 0, 1, 2
+_BLOCK_HEAD = 6  # columns before a row's block: see pack_block_rows
+
+
+def fix_count(block_length: int, denoising_steps: int, done: int) -> int:
+    """How many masked positions the pass after ``done`` passes of a
+    block fixes: ``block_length // steps``, the remainder on the first
+    passes (a block that opened with known positions runs out of masked
+    ones sooner, and takes fewer passes)."""
+    steps = min(max(int(denoising_steps), 1), block_length)
+    return block_length // steps + (done < block_length % steps)
+
+
+def block_row_of(req):
+    """``Family.row_of``: the request's block in flight and the pass
+    its schedule asks for next."""
+    fix = 0 if MASKED not in req.block else \
+        fix_count(len(req.block), req.denoising_steps, req.passes)
+    return (req.block, req.position, req.temperature, fix,
+            REMASKING.index(req.remasking), req.confidence_threshold,
+            req.block_table)
+
+
+def advance_block(req, out):
+    """``Family.advance``: after a finishing pass the next block opens,
+    all masked; after a denoising pass the block is what the program
+    made of it, and once whole, its tokens past those the request
+    already had (a prompt's remainder) are due, up to its limit."""
+    size = len(req.block)
+    if MASKED not in req.block:
+        req.position += size
+        req.block, req.passes = [MASKED] * size, 0
+        return [], True
+    req.block, req.passes = [int(t) for t in out], req.passes + 1
+    if MASKED in req.block:
+        return [], False
+    known = len(req.tokens) + len(req.output) - req.position
+    return req.block[known:known + req.remaining], False
+
+
+def pack_block_rows(block_length: int, batch: int, width: int, active,
+                    slots=None) -> np.ndarray:
+    """The block-pass program's host array, int32 ``[batch, 6 +
+    block_length + width]``: per row its block's first position, its
+    temperature (the float32's bits), how many masked positions this
+    pass fixes, the rule (an index into ``REMASKING``), the confidence
+    threshold (bits), the phase (0 inactive, 1 denoising, 2 finishing:
+    a block with nothing masked), the block's tokens (``MASKED`` where
+    none is fixed) and the block table; from ``active``'s
+    ``block_row_of`` tuples, placed as ``pack_decode_rows`` places
+    them. Rule, count and phase are values, not programs: rows in every
+    phase share one pass."""
+    table_at = _BLOCK_HEAD + block_length
+    rows = np.zeros((batch, table_at + width), np.int32)
+    floats = rows.view(np.float32)
+    for i, (block, position, temperature, fix, rule, threshold, table) \
+            in zip(slots or range(batch), active):
+        rows[i, 0], floats[i, 1], rows[i, 2] = position, temperature, fix
+        rows[i, 3], floats[i, 4] = rule, threshold
+        rows[i, 5] = _DENOISING if MASKED in block else _FINISHING
+        rows[i, _BLOCK_HEAD:table_at] = block
+        rows[i, table_at:table_at + len(table)] = table
+    return rows
+
+
+def denoise(logits, block, fix, rule, threshold, temps, key, mask_id: int):
+    """One pass over every row's block: logits [B, T, V] float32 at the
+    block's positions, block [B, T] (``MASKED`` where unfixed), fix /
+    rule / threshold / temps [B]. ``x0`` is the argmax (or a draw at
+    the row's temperature), never the mask's id; its confidence ``c``
+    the softmax's probability of it, at that temperature. Of a row's
+    masked positions the pass fixes, at ``x0``: ``sequential`` the
+    ``fix`` leftmost; ``low_confidence_static`` the ``fix`` of largest
+    ``c`` (ties to the left); ``low_confidence_dynamic`` those and every
+    one with ``c`` over the threshold (if ``fix`` or more pass it, they
+    are the largest, and if fewer, the largest hold them: the union is
+    the rule). Returns the blocks after the pass."""
+    T = block.shape[1]
+    logits = logits.at[..., mask_id].set(-jnp.inf)
+    scaled = logits / jnp.where(temps > 0, jnp.maximum(temps, 1e-4),
+                                1.0)[:, None, None]
+    sampled = jax.random.categorical(key, scaled, axis=-1)
+    x0 = jnp.where(temps[:, None] > 0, sampled,
+                   jnp.argmax(logits, axis=-1)).astype(jnp.int32)
+    picked = jnp.take_along_axis(scaled, x0[..., None], axis=-1)[..., 0]
+    masked = block == MASKED
+    c = jnp.where(masked, jnp.exp(picked - jax.nn.logsumexp(scaled, axis=-1)),
+                  -1.0)
+    # A position's rank among its row's masked ones, by place or by
+    # confidence: how many of them come before it.
+    at = jnp.arange(T)
+    before = jnp.where(
+        rule[:, None, None] == 0, at[None, :] < at[:, None],
+        (c[:, None, :] > c[:, :, None])
+        | ((c[:, None, :] == c[:, :, None]) & (at[None, :] < at[:, None])))
+    rank = jnp.sum(before & masked[:, None, :], axis=-1)
+    fixed = (rank < fix[:, None]) \
+        | ((rule == 2)[:, None] & (c > threshold[:, None]))
+    return jnp.where(masked & fixed, x0, block)
+
+
+def make_engine_block_step(config, block_size: int):
+    """The block family's ONE decode program, under the plain one's
+    name: every busy row's block of ``block_length`` positions through
+    the layers against the pool (its keys and values written at its
+    positions first and gathered with the rest, as a decode step does,
+    so a later pass and the finishing pass write them again), then
+    ``denoise``. On ``pack_block_rows``' array and the carried key;
+    returns the blocks after the pass ``[B, block_length]``, the pool,
+    the expert counters and the key, as ``make_engine_decode_step``."""
+    size, mask_id = config.block_length, config.mask_token_id
+    table_at = _BLOCK_HEAD + size
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode_step(params, pool, rows, key, expert_stats=None):
+        key, sub = jax.random.split(key)
+        floats = lax.bitcast_convert_type(rows[:, :_BLOCK_HEAD], jnp.float32)
+        block = rows[:, _BLOCK_HEAD:table_at]
+        logits, pool, counts, _ = _forward_paged(
+            params, pool, jnp.where(block == MASKED, mask_id, block),
+            rows[:, :1] + jnp.arange(size), rows[:, table_at:], config,
+            block_size, busy=rows[:, 5] != _INACTIVE)
+        after = denoise(logits, block, rows[:, 2], rows[:, 3], floats[:, 4],
+                        floats[:, 1], sub, mask_id)
+        return after, pool, _accumulated(expert_stats, counts), key
+
+    return decode_step
+
+
 PAGED = Family(
     init_params=llama.init_params,
     init_cache=lambda config, num_blocks, block_size, rows, chunk_len:
@@ -398,3 +585,20 @@ PAGED = Family(
     pack_decode_rows=pack_decode_rows,
     pack_prefill_chunk=pack_prefill_chunk,
 )
+
+
+
+@functools.lru_cache(maxsize=None)
+def _blockwise(block_length: int) -> Family:
+    """The family of diffusion over blocks of ``block_length``: the
+    paged pool and the plain prefill program (under the block mask: a
+    paged block and a chunk hold whole blocks, the engine checks; what
+    it returns of logits is not read, prefill yields no token), and the
+    block pass for a decode step."""
+    return dataclasses.replace(
+        PAGED,
+        make_engine_decode_step=make_engine_block_step,
+        pack_decode_rows=functools.partial(pack_block_rows, block_length),
+        row_of=block_row_of,
+        advance=advance_block,
+    )

@@ -31,7 +31,9 @@ work stays in engine.py):
   ``output[-1]`` — with greedy sampling the final token stream is
   byte-identical to the unpreempted run, and the caller observes
   exactly-once completion either way (the sealed flag is the single
-  commit point);
+  commit point). A model that generates by diffusion over blocks
+  (``block_length``) resumes from its prompt and the whole blocks it
+  has emitted, its half-made block thrown away;
 - **deadline sweep**: every iteration seals requests whose inherited
   PR-7 budget died, typed, with the stage recorded.
 
@@ -61,6 +63,9 @@ DECODE = "decode"
 STAGE_QUEUE = "llm_queue"
 STAGE_DECODE = "llm_decode"
 
+#: In a block in flight (diffusion over blocks): no token fixed here yet.
+MASKED = -1
+
 
 class EngineRequest:
     """One generation request moving through the engine."""
@@ -72,12 +77,16 @@ class EngineRequest:
         "preempted", "sealed", "error", "done", "stream",
         "rid", "submitted_ns", "claimed_ns", "first_token_ns", "sealed_ns",
         "trace_ctx", "slot",
+        "block", "passes", "denoising_steps", "remasking",
+        "confidence_threshold",
     )
     _rids = itertools.count()
 
     def __init__(self, tokens: "list[int]", max_new_tokens: int,
                  temperature: float, deadline: "float | None" = None,
-                 name: str = "llm_generate", stream: bool = False):
+                 name: str = "llm_generate", stream: bool = False,
+                 denoising_steps: int = 1, remasking: str = "sequential",
+                 confidence_threshold: float = 0.9):
         self.tokens = list(tokens) or [0]
         self.max_new_tokens = int(max_new_tokens)
         self.temperature = float(temperature)
@@ -98,6 +107,16 @@ class EngineRequest:
         self.sample_first = True
         self.remaining = int(max_new_tokens)
         self.last_token = 0
+        # Generation by diffusion over blocks (``Scheduler.block_length``):
+        # the block in flight from ``position`` on (``MASKED`` where no
+        # token is fixed yet), the denoising passes it has had,
+        # and the request's schedule: passes a block, the rule that
+        # picks what a pass fixes, and the rule's threshold.
+        self.block: "list[int]" = []
+        self.passes = 0
+        self.denoising_steps = int(denoising_steps)
+        self.remasking = remasking
+        self.confidence_threshold = float(confidence_threshold)
         self.preempted = 0
         self.sealed = False
         self.error: "Exception | None" = None
@@ -126,8 +145,11 @@ class Scheduler:
     """Owns the request queues and the paged-cache block accounting."""
 
     def __init__(self, cache: PagedKVCache, max_batch: int,
-                 max_waiting: int, max_tokens_per_seq: int):
+                 max_waiting: int, max_tokens_per_seq: int,
+                 block_length: int = 0):
         self.cache = cache
+        # > 0: generation by diffusion over blocks of so many positions.
+        self.block_length = block_length
         self.max_batch = max_batch
         self.max_waiting = max_waiting
         self.max_tokens_per_seq = max_tokens_per_seq
@@ -173,7 +195,19 @@ class Scheduler:
         # preemption dropped — the prompt plus every generated token
         # except the last (its k/v is written by the NEXT decode step,
         # exactly as in the unpreempted trajectory).
-        if req.output:
+        if self.block_length:
+            # Diffusion over blocks: the whole blocks of what is known
+            # (the prompt and the tokens emitted; a half-made block was
+            # thrown away with the preemption) are prefilled, and what
+            # is left over opens the block in flight as known positions.
+            known = req.tokens + req.output
+            whole = len(known) // self.block_length * self.block_length
+            req.context = known[:whole]
+            req.block = known[whole:] + [MASKED] * (
+                whole + self.block_length - len(known))
+            req.passes = 0
+            req.sample_first = False  # prefill yields no token
+        elif req.output:
             req.context = req.tokens + req.output[:-1]
             req.sample_first = False
             req.last_token = req.output[-1]

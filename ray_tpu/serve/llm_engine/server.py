@@ -4,6 +4,10 @@ Request (token-in/token-out, no tokenizer dependency)::
 
     {"tokens": [int], "max_new_tokens": int, "temperature": float}
       -> {"tokens": [int]}               (__call__, unary)
+    and, for a model that generates by diffusion over blocks, optionally
+    "denoising_steps": int, "remasking": "sequential" |
+    "low_confidence_static" | "low_confidence_dynamic",
+    "confidence_threshold": float (left out: the model configuration's)
     generate(request)  -> yields int tokens  (streaming: run with
       handle.options(stream=True).generate.remote(...) and TTFT is
       the first chunk's arrival)
@@ -57,18 +61,24 @@ class LLMEngineServer:
 
         return get_runtime_context().get_task_deadline()
 
-    def __call__(self, request: dict) -> dict:
-        req = self._engine.submit(
+    def _submit(self, request: dict, stream: bool = False):
+        return self._engine.submit(
             list(request.get("tokens") or []),
             max_new_tokens=int(request.get("max_new_tokens", 16)),
             temperature=float(request.get("temperature", 0.0)),
-            deadline=self._deadline(request))
-        return {"tokens": self._engine.result(req, timeout_s=120.0)}
+            deadline=self._deadline(request), stream=stream,
+            denoising_steps=request.get("denoising_steps"),
+            remasking=request.get("remasking"),
+            confidence_threshold=request.get("confidence_threshold"))
+
+    def __call__(self, request: dict) -> dict:
+        return {"tokens": self._engine.result(self._submit(request),
+                                              timeout_s=120.0)}
 
     def generate(self, request: dict):
         """Streaming generation — tokens yield as decode steps emit
         them (pair with ``handle.options(stream=True)``)."""
-        yield from self._engine.stream_tokens(self._submit_stream(request))
+        yield from self._engine.stream_tokens(self._submit(request, True))
 
     def generate_batches(self, request: dict):
         """``generate`` as a replica streams it: lists of the tokens that
@@ -77,14 +87,7 @@ class LLMEngineServer:
         looks for this sibling); a caller of the handle sees single
         tokens either way."""
         yield from self._engine.stream_token_batches(
-            self._submit_stream(request))
-
-    def _submit_stream(self, request: dict):
-        return self._engine.submit(
-            list(request.get("tokens") or []),
-            max_new_tokens=int(request.get("max_new_tokens", 16)),
-            temperature=float(request.get("temperature", 0.0)),
-            deadline=self._deadline(request), stream=True)
+            self._submit(request, True))
 
     # --------------------------------------------------------- control path
 
