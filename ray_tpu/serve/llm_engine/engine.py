@@ -8,6 +8,18 @@ lowest-progress stream (recompute-on-resume) instead of failing it.
 The step's table is as wide as its longest live row needs, of three
 widths (``table_widths``), each a program the constructor has built.
 
+The loop keeps one decode step ahead where the family allows it
+(``Family.ahead``): step N+1 is launched on step N's tokens where they
+lie on the device (the program's ``prev``), and only then is N read and
+emitted, so the device runs N+1 while the host fetches, emits, sweeps
+and launches a prefill chunk. At most ONE step is in flight unread
+(``_Step``); a request's ``position``, ``last_token`` and ``output``
+move on at emission, and what lies ahead of them is that record.
+Which rows N+1 carries needs no value of N: a row ends by its count or
+its table's end, and a row joins from prefill with a token the host
+has. Whatever needs the values (a preemption rebuilds its victim's
+context from ``output``) reads the step in flight first.
+
 The model's family (``model.family(config)``, looked up once) gives the
 weights, the cache and the two programs: a stack of identical layers
 over one paged pool, or a hybrid's three caches (``hybrid.py``: the one
@@ -117,6 +129,9 @@ ENGINE_STAT_KEYS = (
     # Decode steps run at a table narrower than the whole
     # (``table_widths``): how often the width followed the context.
     "decode_steps_narrow",
+    # Decode steps launched while the step before was still unread, on
+    # its tokens where they lay on the device.
+    "decode_steps_ahead",
 )
 
 # The engine thread lets go of the interpreter inside every program call
@@ -160,6 +175,28 @@ class _PassClock:
     blocked on the device's answer, and whether a decode step ran."""
 
     __slots__ = ("fetch_ns", "decoded")
+
+
+class _Step:
+    """A decode step launched and not yet read, the engine thread's
+    alone: its rows as scheduled (``active`` and the row ``slots`` they
+    held), its host array (``rows``) at its table ``width``, the
+    positions its rows' contexts hold (``live``), whether the step
+    before it was unread at its launch (``ahead``); from the launch on
+    its tokens on the device (``out``) and the sampling key it returned,
+    split again by any first token sampled since (``key``)."""
+
+    __slots__ = ("active", "slots", "rows", "width", "live", "ahead",
+                 "out", "key")
+
+    def __init__(self, active, slots, rows, width, live, ahead):
+        self.active, self.slots, self.rows = active, slots, rows
+        self.width, self.live, self.ahead = width, live, ahead
+
+
+#: ``_grow_or_preempt_locked``: the table cannot grow without a victim
+#: while a step is in flight unread; read that step, then ask again.
+_UNREAD = "unread"
 
 
 class LLMEngine:
@@ -229,8 +266,15 @@ class LLMEngine:
         # splits it and returns the carry. Made by a program under the
         # engine's mesh, it is placed as that carry will be, so the
         # first step's program is every later step's too.
+        # ``_key`` is the key of the last step whose tokens were read;
+        # the step in flight (``_unread``) holds the one it returned.
+        # ``_no_prev`` is what a step with no step before it is given
+        # for ``prev``: made as the key is, so placed as a step's tokens.
         with jax_compat.set_mesh(mesh):
             self._key = jax.jit(lambda: jax.random.PRNGKey(seed + 1))()
+            self._no_prev = jax.jit(
+                lambda: jax.numpy.zeros((self.max_batch,), "int32"))()
+        self._unread: "_Step | None" = None
         self._counters: "dict[str, int]" = {k: 0 for k in ENGINE_STAT_KEYS}
         self._pass = _PassClock()
         self._lock = lock_witness.Condition("llm_engine.LLMEngine.state")
@@ -272,7 +316,7 @@ class LLMEngine:
         for width in self._widths:
             rows = self._family.pack_decode_rows(self.max_batch, width, ())
             args = (self.params, self._pool, rows, self._key,
-                    self._expert_stats)
+                    self._expert_stats, self._no_prev)
             with jax_compat.set_mesh(self._mesh):
                 self._decode_step.lower(*args).compile()
                 _, self._pool, _, _ = self._decode_step(*args)
@@ -532,12 +576,18 @@ class LLMEngine:
         Returns ``"ok"`` when the table covers the target,
         ``"victim"`` when ``req`` itself was preempted, ``"shed"``
         when nothing was left to preempt (the caller seals typed,
-        OUTSIDE the lock)."""
+        OUTSIDE the lock), and ``_UNREAD`` before any of that while a
+        decode step is in flight: a victim resumes from its ``output``,
+        which must hold every token the device has made, and that
+        step's emission may free the blocks (the caller reads it,
+        OUTSIDE the lock, and asks again)."""
         while True:
             try:
                 self._sched.cache.grow(req.block_table, n_tokens)
                 return "ok"
             except CacheExhaustedError:
+                if self._unread is not None:
+                    return _UNREAD
                 victim = self._sched.pick_victim()
                 if victim is None and self._sched.prefilling is req:
                     # No decode stream left to preempt and the pool
@@ -560,21 +610,27 @@ class LLMEngine:
         the interleave that keeps long prompts from stalling decode."""
         if self._sched.prefilling is None and not self._sched.waiting:
             return False  # only this thread claims: nothing to open
-        with tracing.phase("engine.prefill.schedule") as span, self._lock:
-            if self._sched.prefilling is None:
-                claimed = self._sched.claim_prefill()
-                if claimed is not None and claimed.preempted > 0:
-                    self._counters["resumes"] += 1
-            req = self._sched.prefilling
-            if req is None:
-                return False
-            span.set(req=req.rid)
-            n = min(self.prefill_chunk_len,
-                    len(req.context) - req.prefilled)
-            status = self._grow_or_preempt_locked(req, req.prefilled + n)
-            if status == "ok":
-                start = req.prefilled
-                table = list(req.block_table)
+        status = _UNREAD
+        while status == _UNREAD:
+            with tracing.phase("engine.prefill.schedule") as span, \
+                    self._lock:
+                if self._sched.prefilling is None:
+                    claimed = self._sched.claim_prefill()
+                    if claimed is not None and claimed.preempted > 0:
+                        self._counters["resumes"] += 1
+                req = self._sched.prefilling
+                if req is None:
+                    return False
+                span.set(req=req.rid)
+                n = min(self.prefill_chunk_len,
+                        len(req.context) - req.prefilled)
+                status = self._grow_or_preempt_locked(req,
+                                                      req.prefilled + n)
+                if status == "ok":
+                    start = req.prefilled
+                    table = list(req.block_table)
+            if status == _UNREAD and not self._read_unread():
+                return True  # the read failed: every request with it
         if status == "shed":
             self._seal(req, CacheExhaustedError(
                 "KV block pool exhausted mid-prefill"))
@@ -636,7 +692,13 @@ class LLMEngine:
         import jax.numpy as jnp
 
         if req.temperature > 0:
-            self._key, sub = jax.random.split(self._key)
+            # Off the head of the key's chain, which the step in
+            # flight holds while one is unread.
+            step = self._unread
+            if step is None:
+                self._key, sub = jax.random.split(self._key)
+            else:
+                step.key, sub = jax.random.split(step.key)
             token = jax.random.categorical(
                 sub, last_logits / max(req.temperature, 1e-4))
         else:
@@ -669,60 +731,129 @@ class LLMEngine:
         req.done.set()
 
     def _decode_tick(self) -> bool:
-        if not self._sched.active:
+        """Launch the next decode step, then read one: of a family that
+        goes ahead the step BEFORE it, which was in flight unread (the
+        new one stays in flight while the host emits, sweeps and runs a
+        prefill chunk), of another the step just launched."""
+        if self._unread is None and not self._sched.active:
             return False
-        with tracing.phase("engine.decode.schedule") as span, self._lock:
-            if not self._sched.active:
-                return False
-            # Grow every row's table for the token it is about to
-            # write; pressure preempts lowest-progress rows.
+        planned = _UNREAD
+        while planned is _UNREAD:
+            with tracing.phase("engine.decode.schedule") as span, \
+                    self._lock:
+                planned = self._plan_step_locked()
+                if isinstance(planned, _Step):
+                    span.set(rows=len(planned.active))
+            if planned is _UNREAD and not self._read_unread():
+                return True  # the read failed: every request with it
+        step, before = planned, self._unread
+        if step is not None:
+            self._maybe_chaos_slow_step()
+            try:
+                with tracing.phase("engine.decode.launch",
+                                   rows=len(step.active)), \
+                        jax_compat.set_mesh(self._mesh):
+                    step.out, self._pool, self._expert_stats, step.key = \
+                        self._decode_step(
+                            self.params, self._pool, step.rows,
+                            self._key if before is None else before.key,
+                            self._expert_stats,
+                            self._no_prev if before is None else before.out)
+            except Exception as exc:  # noqa: BLE001 — donated pool is gone
+                self._reset_after_failure(exc)
+                return True
+        if self._family.ahead:
+            # The new step stays in flight; the one before it is read.
+            self._unread, step = step, before
+        if step is not None:
+            self._read_step(step)
+        return True
+
+    def _plan_step_locked(self):
+        """The next decode step (caller holds the lock): a ``_Step``
+        to launch; None when no row has a pass to run; ``_UNREAD`` when
+        a table cannot grow before the step in flight is read. A row of
+        the step in flight is planned one position on, its token the
+        one in flight, unless that token is its last: it has one left
+        to make, or its table ends."""
+        unread, span = self._unread, self._span
+        active, ahead = [], []
+        # Grow every row's table for the token it is about to write.
+        if unread is None:
+            # Pressure preempts lowest-progress rows.
             for req in list(self._sched.active):
                 if req not in self._sched.active:
                     continue  # already preempted as a victim
-                self._grow_or_preempt_locked(req, req.position + self._span)
+                self._grow_or_preempt_locked(req, req.position + span)
             active = list(self._sched.active)
-            if not active:
-                return True  # everything preempted: progress made
-            span.set(rows=len(active))
-            # As held now: a row sealed while the step runs loses its.
-            slots = [req.slot for req in active]
-            longest = max(len(req.block_table) for req in active)
-            width = next(w for w in self._widths if w >= longest)
-            rows = self._family.pack_decode_rows(
-                self.max_batch, width, map(self._family.row_of, active),
-                slots)
+        else:
+            # Nothing is preempted while a step is unread: pressure
+            # asks for its read.
+            flying = set(unread.active)
+            for req in self._sched.active:
+                lead = req in flying
+                if lead and (req.remaining <= 1
+                             or req.position + 1 >= self.max_tokens):
+                    continue
+                if self._grow_or_preempt_locked(
+                        req, req.position + lead + span) == _UNREAD:
+                    return _UNREAD
+                active.append(req)
+                ahead.append(lead)
+        if not active:
+            return None  # none goes on, or everything preempted
+        # As held now: a row sealed while the step runs loses its.
+        slots = [req.slot for req in active]
+        longest = max(len(req.block_table) for req in active)
+        width = next(w for w in self._widths if w >= longest)
+        row_of = self._family.row_of
+        rows = self._family.pack_decode_rows(
+            self.max_batch, width,
+            map(row_of, active, ahead) if ahead else map(row_of, active),
+            slots)
+        live = sum(req.position for req in active) + span * len(active) \
+            + sum(ahead)
+        return _Step(active, slots, rows, width, live, unread is not None)
 
-        self._maybe_chaos_slow_step()
+    def _read_unread(self) -> bool:
+        """Read and emit the step in flight, leaving none."""
+        step, self._unread = self._unread, None
+        return self._read_step(step)
+
+    def _read_step(self, step: _Step) -> bool:
+        """Fetch ``step``'s tokens and emit them; False when the read
+        failed (every request has failed with it)."""
         try:
-            with tracing.phase("engine.decode.launch", rows=len(active)), \
-                    jax_compat.set_mesh(self._mesh):
-                out, self._pool, self._expert_stats, key = \
-                    self._decode_step(self.params, self._pool, rows,
-                                      self._key, self._expert_stats)
             with tracing.phase("engine.decode.fetch"):
-                out = self._fetch(np.asarray, out)
+                out = self._fetch(np.asarray, step.out)
+                # Let go of the device's copy HERE: freeing a device
+                # array gives up the interpreter, and done at the end of
+                # the tick that cost a pass 2 ms among 150 threads
+                # (PR 36). A step launched on it holds its own reference.
+                step.out = None
         except Exception as exc:  # noqa: BLE001 — donated pool is gone
             self._reset_after_failure(exc)
-            return True
+            return False
         # The step's own split of the key, kept once the step is known
         # to have run: a failed step leaves the key it was given.
-        self._key = key
+        self._key = step.key
         self._pass.decoded = True
+        active, width = step.active, step.width
         with tracing.phase("engine.decode.emit", rows=len(active)) as span:
             with self._lock:
                 self._counters["host_calls"] += 2  # the call, the read
                 self._counters["decode_steps"] += 1
+                self._counters["decode_steps_ahead"] += step.ahead
                 if len(active) >= 2:
                     self._counters["batched_decode_steps"] += 1
                 self._counters["block_rows"] += len(active)
-                self._counters["kv_positions_live"] += sum(
-                    req.position + self._span for req in active)
+                self._counters["kv_positions_live"] += step.live
                 self._counters["kv_positions_read"] += \
                     self.max_batch * width * self.block_size
                 if width < self.blocks_per_seq:
                     self._counters["decode_steps_narrow"] += 1
                 finished = whole = 0
-                for slot, req in zip(slots, active):
+                for slot, req in zip(step.slots, active):
                     self._counters["window_blocks_recycled"] += \
                         self._recycled(req.position,
                                        req.position + self._span)
@@ -755,7 +886,10 @@ class LLMEngine:
     def _reset_after_failure(self, exc: Exception) -> None:
         """A failed jitted call invalidated the donated pool: fail
         every in-flight request typed and rebuild: the loop stays
-        alive for the next request."""
+        alive for the next request. A decode step in flight unread is
+        dropped with them (its requests have failed), and ``_key`` stays
+        the key of the last step that was read."""
+        self._unread = None
         with self._lock:
             sched = self._sched
             victims = list(sched.waiting) + list(sched.active)

@@ -48,6 +48,7 @@ from ray_tpu.models import phi4flash as phi
 from ray_tpu.serve.llm_engine.model import (
     Family,
     pack_decode_rows,
+    row_tokens,
     sample_next,
 )
 
@@ -296,13 +297,14 @@ def pack_prefill_chunk(chunk_len: int, width: int, tokens, start: int,
 
 def make_engine_decode_step(config, block_size: int):
     """The ONE decode program, on ``model.pack_decode_rows``' array
-    (row ``i`` is row slot ``i``) and the carried sampling key."""
+    (row ``i`` is row slot ``i``), the carried sampling key and the step
+    before's tokens ``prev`` (``model.row_tokens``)."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode_step(params, cache, rows, key, expert_stats=None):
+    def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
         key, sub = jax.random.split(key)
         temps = lax.bitcast_convert_type(rows[:, 2], F32)
-        logits, cache = decode_forward(params, cache, rows[:, :1],
+        logits, cache = decode_forward(params, cache, row_tokens(rows, prev),
                                        rows[:, 1], rows[:, 3:], config,
                                        block_size)
         return sample_next(logits[:, -1, :], sub, temps), cache, \
@@ -339,4 +341,5 @@ FAMILY = Family(
     pack_prefill_chunk=pack_prefill_chunk,
     ring_positions=ring_positions,
     recurrent=True,
+    ahead=True,
 )

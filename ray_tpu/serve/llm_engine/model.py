@@ -63,8 +63,17 @@ from ray_tpu.serve.llm_engine.kv_cache import PagedKVCache
 from ray_tpu.serve.llm_engine.scheduler import MASKED
 
 
-def _row_of_one(req):
-    """An autoregressive row: its last token, at the next position."""
+#: In a decode row's token column: the row's token is the step before's,
+#: still on the device (the decode program's ``prev``).
+PREV = -1
+
+
+def _row_of_one(req, ahead: bool = False):
+    """An autoregressive row: its last token, at the next position.
+    ``ahead``: the row one pass on, while the pass before it is unread:
+    the token that pass makes, at the position after."""
+    if ahead:
+        return PREV, req.position + 1, req.temperature, req.block_table
     return req.last_token, req.position, req.temperature, req.block_table
 
 
@@ -83,7 +92,10 @@ class Family:
     their host arrays. ``ring_positions(config, block_size, chunk_len)``
     is how many positions a row of its window cache holds (0: it has
     none); ``recurrent``: a row owns a state slot that a request's first
-    chunk resets."""
+    chunk resets; ``ahead``: which rows a pass carries, and where, is
+    known before the values of the pass before it (a row yields one
+    token a pass and ends by its count), so the engine launches a pass
+    on the last one's tokens where they lie on the device."""
     init_params: Callable
     init_cache: Callable    # (config, num_blocks, block_size, rows, chunk)
     make_engine_decode_step: Callable
@@ -92,8 +104,10 @@ class Family:
     pack_prefill_chunk: Callable
     ring_positions: Callable = lambda config, block_size, chunk_len: 0
     recurrent: bool = False
+    ahead: bool = False
     # A busy row on the host: what ``pack_decode_rows`` is given for a
-    # request (``row_of(req)``), and what a pass made of it
+    # request (``row_of(req)``; ``row_of(req, True)`` of an ``ahead``
+    # family: the row one pass on), and what a pass made of it
     # (``advance(req, out)``, ``out`` the row's slice of the program's
     # first result): the request's state moved on, and (the tokens the
     # pass made final for emission, whether it was a finishing pass).
@@ -366,7 +380,8 @@ def pack_decode_rows(batch: int, width: int, active,
     block table, from ``active``'s ``(token, position, temperature,
     table)``, each in the row ``slots`` gives it (the engine: the
     request's row slot; without ``slots`` in order); the other rows stay
-    zero (inactive)."""
+    zero (inactive). A token of ``PREV`` stands for the one the step
+    before made for that row, which the host has not read."""
     rows = np.zeros((batch, 3 + width), dtype=np.int32)
     temps = rows[:, 2].view(np.float32)
     for i, (token, position, temperature, table) in zip(
@@ -376,20 +391,32 @@ def pack_decode_rows(batch: int, width: int, active,
     return rows
 
 
+def row_tokens(rows, prev=None):
+    """The tokens ``[B, 1]`` of ``pack_decode_rows``' array, a row's
+    entry of ``prev`` ``[B]`` where its token is ``PREV``."""
+    tokens = rows[:, :1]
+    if prev is None:
+        return tokens
+    return jnp.where(tokens < 0, prev[:, None], tokens)
+
+
 def make_engine_decode_step(config, block_size: int):
     """``make_decode_step`` as the engine calls it: on
     ``pack_decode_rows``' array, and on the carried sampling key, which
     is split here exactly as the host used to split it and comes back
     as the fourth result (not donated: a failed step leaves the
-    caller's key usable)."""
+    caller's key usable). ``prev`` is the first result of the step
+    before, ``[B]`` on the device and not donated either: a row whose
+    token is ``PREV`` takes its own entry of it. Without ``prev`` every
+    token is the array's."""
     body = _decode_body(config, block_size)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode_step(params, pool, rows, key, expert_stats=None):
+    def decode_step(params, pool, rows, key, expert_stats=None, prev=None):
         key, sub = jax.random.split(key)
         temps = lax.bitcast_convert_type(rows[:, 2], jnp.float32)
-        return (*body(params, pool, rows[:, :1], rows[:, 1], rows[:, 3:],
-                      sub, temps, expert_stats), key)
+        return (*body(params, pool, row_tokens(rows, prev), rows[:, 1],
+                      rows[:, 3:], sub, temps, expert_stats), key)
 
     return decode_step
 
@@ -556,12 +583,14 @@ def make_engine_block_step(config, block_size: int):
     so a later pass and the finishing pass write them again), then
     ``denoise``. On ``pack_block_rows``' array and the carried key;
     returns the blocks after the pass ``[B, block_length]``, the pool,
-    the expert counters and the key, as ``make_engine_decode_step``."""
+    the expert counters and the key, as ``make_engine_decode_step``;
+    ``prev`` is taken as there and not used (what a pass carries depends
+    on the values of the pass before it, so the host packs every one)."""
     size, mask_id = config.block_length, config.mask_token_id
     table_at = _BLOCK_HEAD + size
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode_step(params, pool, rows, key, expert_stats=None):
+    def decode_step(params, pool, rows, key, expert_stats=None, prev=None):
         key, sub = jax.random.split(key)
         floats = lax.bitcast_convert_type(rows[:, :_BLOCK_HEAD], jnp.float32)
         block = rows[:, _BLOCK_HEAD:table_at]
@@ -584,8 +613,8 @@ PAGED = Family(
     make_engine_prefill_chunk=make_engine_prefill_chunk,
     pack_decode_rows=pack_decode_rows,
     pack_prefill_chunk=pack_prefill_chunk,
+    ahead=True,
 )
-
 
 
 @functools.lru_cache(maxsize=None)
@@ -601,4 +630,5 @@ def _blockwise(block_length: int) -> Family:
         pack_decode_rows=functools.partial(pack_block_rows, block_length),
         row_of=block_row_of,
         advance=advance_block,
+        ahead=False,
     )

@@ -121,14 +121,16 @@ def test_fused_rms_norm_compiles_for_v5e(v5e_chip):
 
 
 def _lower_paged_step(program, config, batch, block, table, chip,
-                      width=None):
+                      width=None, prev=False):
     """``decode_step`` or ``prefill_chunk``, the plain program or, as
     ``engine_...``, the one the engine calls (one host array, the key
     carried), lowered on shapes placed on the described chip; the
     pool's shape beside it. A sparse configuration's step carries its
     expert accumulator. ``width``: the blocks of a row's table the
     engine's decode step is given (``engine.table_widths``; the pool
-    stays ``table`` blocks a row)."""
+    stays ``table`` blocks a row); ``prev``: with the step before's
+    tokens ``[batch]`` as the engine passes them (without: the
+    five-argument call of ``benchmark/sizing.py``)."""
     from ray_tpu._private.config import GLOBAL_CONFIG
     from ray_tpu.models import llama, moe
     from ray_tpu.serve.llm_engine import model as paged_model
@@ -151,7 +153,8 @@ def _lower_paged_step(program, config, batch, block, table, chip,
     if program == "engine_decode_step":
         lowered = paged_model.make_engine_decode_step(config, block).lower(
             params, pool, on_chip((batch, 3 + (width or table))),
-            on_chip((2,), jnp.uint32), stats)
+            on_chip((2,), jnp.uint32), stats,
+            *([on_chip((batch,))] if prev else []))
     elif program == "engine_prefill_chunk":
         lowered = paged_model.make_engine_prefill_chunk(
             config, block, chunk).lower(
@@ -305,6 +308,85 @@ def test_decode_step_at_each_table_width_on_v5e(v5e_chip, model, width):
                      text) is not None
     if width < 128:
         assert f"[2048,16,{kv},128]" not in text
+
+
+def _memory_of(compiled) -> tuple:
+    memory = compiled.memory_analysis()
+    return (memory.alias_size_in_bytes, memory.temp_size_in_bytes,
+            memory.argument_size_in_bytes)
+
+
+@pytest.mark.parametrize("width", [32, 64, 128])
+@pytest.mark.parametrize("model", ["mistral", "olmoe"])
+def test_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip, model,
+                                                          width):
+    """The program the engine runs since it keeps a step ahead: the
+    step before's tokens ``[16]`` int32 as a sixth argument, one select
+    in front of the embedding. Beside the five-argument program (which
+    ``benchmark/sizing.py`` still lowers) at the same width: the pool
+    aliased as much, the temporaries the same to within a few vectors of
+    16, the arguments 64 bytes more (the tokens, padded), and ``prev``
+    an argument that is read."""
+    config = _mistral_serve() if model == "mistral" else _olmoe(2)
+    without, pool_shape = _lower_paged_step(
+        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width)
+    with_prev, _ = _lower_paged_step(
+        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width,
+        prev=True)
+    assert len(with_prev.in_avals[0]) == len(without.in_avals[0]) + 1 == 6
+    compiled, before = with_prev.compile(), without.compile()
+    alias, temp, arguments = _memory_of(compiled)
+    alias_before, temp_before, arguments_before = _memory_of(before)
+    assert alias == alias_before >= 2 * 2 * math.prod(pool_shape)
+    assert abs(temp - temp_before) < 64 * 2 ** 10
+    assert 0 < arguments - arguments_before <= 4096
+    text = compiled.as_text()
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    # Kept by the program (jit drops an argument nothing reads).
+    def entry_arguments(hlo):
+        layout = hlo[hlo.index("entry_computation_layout={("):]
+        return layout[:layout.index(")->")]
+
+    assert "s32[16]{" in entry_arguments(text)
+    assert "s32[16]{" not in entry_arguments(before.as_text())
+
+
+@pytest.mark.parametrize("width", [64, 128, 256])
+def test_hybrid_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip,
+                                                                 width):
+    """The same for Phi-4-mini-flash's decode program (32 rows, 8 of its
+    32 layers): with the step before's tokens as a sixth argument the
+    three caches are still updated where they lie and the temporaries
+    are the five-argument program's."""
+    from ray_tpu.models import phi4flash
+    from ray_tpu.serve.llm_engine import hybrid
+
+    config = phi4flash.Phi4FlashConfig(num_layers=8)
+    rows, block, table, chunk = 32, 16, 256, 32
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: hybrid.FAMILY.init_params(
+        config, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
+        config, 1 + rows * table, block, rows, chunk)))
+    args = (params, cache,
+            on_chip(hybrid.pack_decode_rows(rows, width, ()), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None)
+    step = hybrid.make_engine_decode_step(config, block)
+    prev = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
+    alias, temp, arguments = _memory_of(step.lower(*args, prev).compile())
+    alias_before, temp_before, arguments_before = _memory_of(
+        step.lower(*args).compile())
+    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
+                      for c in jax.tree.leaves(cache))
+    assert alias == alias_before >= cache_bytes
+    assert abs(temp - temp_before) < 64 * 2 ** 10
+    assert 0 < arguments - arguments_before <= 4096
 
 
 @pytest.mark.parametrize("width", [64, 128, 256])

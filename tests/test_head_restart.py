@@ -107,6 +107,7 @@ def test_head_kill_with_inflight_batch_and_broadcast_drains(tmp_path):
             time.sleep(0.3)
         assert len(_alive_nodes(addr)) >= 3
 
+        ray_tpu.shutdown()  # a runtime an earlier file left in this worker
         runtime = ray_tpu.init(address=addr, num_cpus=0)
         deadline = time.monotonic() + 30
         while time.monotonic() < deadline and \
@@ -279,6 +280,7 @@ def test_head_kill_restart_cluster_resumes(tmp_path):
             time.sleep(0.3)  # head registers itself too -> 3 total
         assert len(_alive_nodes(addr)) >= 3
 
+        ray_tpu.shutdown()  # a runtime an earlier file left in this worker
         runtime = ray_tpu.init(address=addr, num_cpus=0)
 
         # State that must survive: internal KV, a job record, a named

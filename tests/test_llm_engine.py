@@ -644,21 +644,33 @@ def test_decode_program_is_compiled_once_over_turnover(meshed):
         engine.shutdown()
 
 
-@pytest.mark.parametrize("fails_at", ["the_call", "the_read", "the_chunk"])
+@pytest.mark.parametrize("fails_at", [
+    "the_call", "the_read", "the_chunk",
+    "the_call_with_a_step_in_flight", "the_read_with_a_step_in_flight"])
 def test_failed_step_leaves_a_usable_key(fails_at):
     """The key is not donated and is replaced only once a step's
     tokens were read: after ``_reset_after_failure`` the engine holds
     the key the failed step was given, and samples with it. A prefill
     program that raises fails its request the same way, and the engine
-    serves the next."""
+    serves the next. With a step in flight (the request's third call
+    fails, or its second result cannot be read, after its first step
+    was read): the step launched and not read is dropped with the
+    request, and the key is the one the last step READ returned."""
     import numpy as np
 
     from ray_tpu.serve.llm_engine import LLMEngine
 
     class Unreadable:
+        """A step's tokens that the host cannot read; the next step,
+        launched before the read, is still given the device's."""
+
+        def __init__(self, tokens):
+            self.tokens = tokens
+
         def __array__(self, *args, **kwargs):
             raise RuntimeError("the device lost the step")
 
+    in_flight = fails_at.endswith("_with_a_step_in_flight")
     engine = LLMEngine(_f32_tiny(), max_batch_size=2, max_seq_len=64,
                        block_size=8, prefill_chunk=8, seed=3)
     try:
@@ -666,6 +678,8 @@ def test_failed_step_leaves_a_usable_key(fails_at):
         assert len(engine.result(warm, timeout_s=120)) == 4
         step, chunk_step, key_before, failures = engine._decode_step, \
             engine._prefill_step, np.asarray(engine._key), []
+        keys = []  # the key each decode call of the doomed request returned
+        steps_before = engine.engine_stats()["decode_steps"]
 
         def failing_chunk(*args):
             if failures:
@@ -673,15 +687,22 @@ def test_failed_step_leaves_a_usable_key(fails_at):
             failures.append(fails_at)
             raise RuntimeError("the device refused the chunk")
 
-        def failing(params, pool, rows, key, expert_stats):
-            if failures:
-                return step(params, pool, rows, key, expert_stats)
+        def failing(params, pool, rows, key, expert_stats, prev):
+            if isinstance(prev, Unreadable):
+                prev = prev.tokens
+            due = len(keys) == (2 if fails_at.startswith("the_call_with")
+                                else 1 if in_flight else 0)
+            if failures or not due:
+                out = step(params, pool, rows, key, expert_stats, prev)
+                if not failures:
+                    keys.append(np.asarray(out[3]))
+                return out
             failures.append(fails_at)
-            if fails_at == "the_call":
+            if fails_at.startswith("the_call"):
                 raise RuntimeError("the device refused the step")
-            _, pool, expert_stats, key = step(params, pool, rows, key,
-                                              expert_stats)
-            return Unreadable(), pool, expert_stats, key
+            out, pool, expert_stats, key = step(params, pool, rows, key,
+                                                expert_stats, prev)
+            return Unreadable(out), pool, expert_stats, key
 
         if fails_at == "the_chunk":
             engine.__dict__["_prefill_step"] = failing_chunk
@@ -691,6 +712,12 @@ def test_failed_step_leaves_a_usable_key(fails_at):
         with pytest.raises(RuntimeError, match="the device"):
             engine.result(doomed, timeout_s=120)
         assert failures == [fails_at]
+        assert engine._unread is None
+        if in_flight:
+            # Its first step was read, nothing after it.
+            key_before = keys[0]
+            assert len(doomed.output) == 2
+            assert engine.engine_stats()["decode_steps"] == steps_before + 1
         np.testing.assert_array_equal(np.asarray(engine._key), key_before)
         after = engine.submit([6, 7, 8], max_new_tokens=5, temperature=0.9)
         out = engine.result(after, timeout_s=120)
@@ -699,6 +726,236 @@ def test_failed_step_leaves_a_usable_key(fails_at):
         assert (np.asarray(engine._key) != key_before).any()
     finally:
         engine.shutdown()
+
+
+# ------------------------------------------------------- one step ahead
+
+
+def _step_ahead_config(kind):
+    import jax.numpy as jnp
+
+    from ray_tpu.models import llama, phi4flash
+
+    if kind == "hybrid":
+        return phi4flash.Phi4FlashConfig.tiny(dtype=jnp.float32,
+                                              max_seq_len=64)
+    if kind == "olmoe":  # 8 experts of which 3 a token, QK-norm
+        return llama.LlamaConfig(
+            vocab_size=256, hidden_size=64, intermediate_size=32,
+            num_layers=3, num_heads=4, num_kv_heads=4, head_dim=16,
+            max_seq_len=64, remat=False, dtype=jnp.float32, num_experts=8,
+            experts_per_token=3, qk_norm=True)
+    return _f32_tiny()
+
+
+def _step_ahead_engine(config, params=None, at_once=False, **kwargs):
+    """An engine of 4 rows; ``at_once``: one that reads every decode
+    step right after its launch, as the loop did before it kept a step
+    ahead (its family says a row's next pass is not known early)."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    engine = LLMEngine(config, params, max_batch_size=4, max_seq_len=64,
+                       block_size=4, prefill_chunk=8, seed=7, **kwargs)
+    if at_once:
+        engine._family = dataclasses.replace(engine._family, ahead=False)
+    return engine
+
+
+def _serve_together(engine, requests):
+    """Submit ``(prompt, new tokens, temperature)`` requests under the
+    engine's lock, so that its loop meets them all at once and its
+    schedule does not depend on when this thread ran; their tokens."""
+    with engine._lock:
+        submitted = [engine.submit(prompt, max_new_tokens=new,
+                                   temperature=temperature)
+                     for prompt, new, temperature in requests]
+    return [engine.result(req, timeout_s=300) for req in submitted]
+
+
+# Rows join mid-run (prompts of one, three and two chunks are claimed in
+# turn while the first decodes) and end by count mid-run, the last
+# claimed first; every row is claimed before one ends, so a row's slot,
+# which its draw depends on, does not hang on when a slot came back.
+_JOINING = [(list(range(1, 4)), 18, 0.0), (list(range(5, 25)), 14, 0.8),
+            (list(range(30, 41)), 10, 0.0), ([9, 8, 7, 6, 5], 6, 0.6)]
+_SECOND_WAVE = [([3, 1, 4, 1, 5], 9, 0.7), (list(range(40, 58)), 12, 0.0),
+                ([2, 7], 15, 1.1)]
+# More requests than rows: a row that ends is refilled from the queue
+# (one step later when a step is ahead, so only greedy tokens compare).
+_REFILLING = [([1 + i] * (1 + 3 * i), 3 + (5 * i) % 11, 0.0)
+              for i in range(9)]
+
+
+@pytest.mark.parametrize("kind", ["dense", "olmoe", "hybrid"])
+def test_streams_are_those_of_an_engine_that_fetches_at_once(kind):
+    """Step N+1 runs on step N's tokens where they lie on the device:
+    every request, greedy or sampled from the seed, gets byte for byte
+    the tokens of an engine that reads each step before it schedules
+    the next, through rows joining and ending mid-run and through a
+    batch refilled from a queue; and the counter says the steps were
+    launched ahead."""
+    config = _step_ahead_config(kind)
+    want, got = [], []
+    at_once = _step_ahead_engine(config, at_once=True)
+    try:
+        for wave in (_JOINING, _SECOND_WAVE, _REFILLING):
+            want.append(_serve_together(at_once, wave))
+        stats_at_once = at_once.engine_stats()
+    finally:
+        at_once.shutdown()
+    engine = _step_ahead_engine(config, at_once.params)
+    try:
+        for wave in (_JOINING, _SECOND_WAVE, _REFILLING):
+            got.append(_serve_together(engine, wave))
+        stats = engine.engine_stats()
+        assert engine._unread is None  # nothing left in flight
+        programs = engine._decode_step._cache_size()
+    finally:
+        engine.shutdown()
+    assert got == want
+    assert [len(tokens) for tokens in got[0]] == [18, 14, 10, 6]
+    assert stats_at_once["decode_steps_ahead"] == 0
+    assert stats["decode_tokens"] == stats_at_once["decode_tokens"]
+    # Every step but a wave's first few (no step before them, or only
+    # rows that joined since) was launched before the last was read.
+    assert stats["decode_steps"] - 12 <= stats["decode_steps_ahead"] \
+        < stats["decode_steps"]
+    # A refilled row joins a step later: a few steps more, two calls each.
+    extra = stats["decode_steps"] - stats_at_once["decode_steps"]
+    assert 0 <= extra <= 9
+    assert stats["host_calls"] - stats_at_once["host_calls"] == 2 * extra
+    assert programs == 3  # one a width, the constructor's
+
+
+def test_preemption_reads_the_step_in_flight_first(paged_engine):
+    """``test_preemption_recompute_on_resume_exact``'s case with steps
+    ahead: a victim is rebuilt from its ``output``, so no step is in
+    flight unread when one is picked, and every request resumes to the
+    pressure-free tokens."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    prompts = [[1, 2, 3], [4, 5, 6, 7, 8], [9, 10], [11, 12, 13, 14]]
+    reference = [paged_engine.result(paged_engine.submit(
+        prompt, max_new_tokens=12), timeout_s=120) for prompt in prompts]
+    engine = LLMEngine(paged_engine.config, paged_engine.params,
+                       max_batch_size=4, max_seq_len=64, block_size=8,
+                       prefill_chunk=8, num_blocks=6, seed=0)
+    try:
+        preempt, unread_at_preemption = engine._sched.preempt, []
+
+        def preempting(victim):
+            unread_at_preemption.append(engine._unread)
+            # Every token made for it is in its output: the context it
+            # resumes from.
+            assert victim.remaining == \
+                victim.max_new_tokens - len(victim.output)
+            preempt(victim)
+
+        engine._sched.preempt = preempting
+        results = _serve_together(
+            engine, [(prompt, 12, 0.0) for prompt in prompts])
+        stats = engine.engine_stats()
+    finally:
+        engine.shutdown()
+    assert stats["preemptions"] > 0 and stats["resumes"] > 0, stats
+    assert unread_at_preemption == [None] * stats["preemptions"]
+    assert 0 < stats["decode_steps_ahead"] < stats["decode_steps"]
+    assert stats["finished"] == 4 and results == reference
+
+
+@pytest.mark.parametrize("how", ["expired", "cancelled"])
+def test_a_request_sealed_with_its_token_in_flight(paged_engine, how):
+    """A request whose budget dies, or that its caller seals, while the
+    step that makes its next token is unread: sealed typed, once; its
+    token is never emitted; its batchmates' streams are what they are
+    without it; and its row serves the next request."""
+    others = [([5, 6, 7], 40), ([8, 9], 36)]
+    want = [paged_engine.result(paged_engine.submit(
+        prompt, max_new_tokens=new), timeout_s=120)
+        for prompt, new in others + [([1, 2, 3, 4], 6)]]
+    before = paged_engine.engine_stats()
+    doomed = paged_engine.submit([3, 3, 3], max_new_tokens=44, stream=True)
+    batchmates = [paged_engine.submit(prompt, max_new_tokens=new)
+                  for prompt, new in others]
+    error = TaskTimeoutError("llm_generate", "llm_decode", 0.0) \
+        if how == "cancelled" else None
+    for _ in range(100_000):
+        with paged_engine._lock:
+            unread = paged_engine._unread
+            if unread is not None and doomed in unread.active \
+                    and len(doomed.output) >= 3:
+                emitted = list(doomed.output)
+                if how == "expired":
+                    doomed.deadline = time.time() - 1.0
+                    paged_engine._check_caller_deadline(doomed)
+                else:
+                    assert paged_engine._seal(doomed, error)
+                break
+        time.sleep(0.0005)
+    else:
+        pytest.fail("never saw the request in a step in flight")
+    with pytest.raises(TaskTimeoutError) as info:
+        paged_engine.result(doomed, timeout_s=120)
+    assert info.value.stage == "llm_decode"
+    assert [paged_engine.result(req, timeout_s=120)
+            for req in batchmates] == want[:2]
+    # The token in flight at the seal went nowhere.
+    assert doomed.output == emitted and len(emitted) < 44
+    assert list(paged_engine.stream_tokens(
+        paged_engine.submit([3, 3, 3], max_new_tokens=len(emitted) + 2,
+                            stream=True)))[:len(emitted)] == emitted
+    tenant = paged_engine.submit([1, 2, 3, 4], max_new_tokens=6)
+    assert paged_engine.result(tenant, timeout_s=120) == want[2]
+    after = paged_engine.engine_stats()
+    assert after["finished"] - before["finished"] == 4
+    assert after["deadline_expired"] - before["deadline_expired"] == \
+        (how == "expired")
+    assert after["blocks_allocated"] - before["blocks_allocated"] == \
+        after["blocks_freed"] - before["blocks_freed"]
+
+
+def test_the_decode_program_takes_the_last_steps_tokens_on_the_device():
+    """``prev``: a row whose token column holds ``PREV`` takes its entry
+    of the last step's result; the five-argument call, and a step whose
+    rows all carry their tokens, read ``rows`` alone."""
+    import jax
+    import numpy as np
+
+    from ray_tpu.serve.llm_engine import PagedKVCache
+    from ray_tpu.serve.llm_engine import model as paged_model
+
+    config = _f32_tiny()
+    params = paged_model.serving_params(config, seed=2)
+    step = paged_model.make_engine_decode_step(config, 4)
+    key = jax.random.PRNGKey(5)
+
+    def pool():
+        return PagedKVCache.init_pool(config, 9, 4)
+
+    first = paged_model.pack_decode_rows(
+        4, 2, [(17, 3, 0.0, [1, 2]), (40, 1, 0.7, [3]), (5, 6, 0.0, [4, 5])],
+        [0, 1, 3])
+    out, after_first, _, key_after = step(params, pool(), first, key, None)
+    host = np.asarray(out)
+    carried = [(int(host[0]), 4, 0.0, [1, 2]), (int(host[1]), 2, 0.7, [3]),
+               (23, 2, 0.0, [6])]  # two rows go on, the third is new
+    on_device = [(paged_model.PREV, *row[1:]) for row in carried[:2]] \
+        + carried[2:]
+    assert paged_model.PREV < 0
+    packed = paged_model.pack_decode_rows(4, 2, on_device, [0, 1, 2])
+    assert packed[:, 0].tolist() == [-1, -1, 23, 0]
+    copy = jax.tree.map(jax.numpy.copy, after_first)
+    want = step(params, after_first, paged_model.pack_decode_rows(
+        4, 2, carried, [0, 1, 2]), key_after, None)
+    got = step(params, copy, packed, key_after, None, out)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got[1][name]),
+                                      np.asarray(want[1][name]))
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(want[3]))
+    # With tokens in every row ``prev`` is not looked at.
+    again = step(params, pool(), first, key, None, out)
+    np.testing.assert_array_equal(np.asarray(again[0]), host)
 
 
 def test_engine_stats_ride_executor_stats(paged_engine):

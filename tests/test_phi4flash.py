@@ -6,6 +6,7 @@ positions a row, which every longer context wraps."""
 
 import functools
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -537,6 +538,49 @@ def test_preempted_requests_resume_to_the_same_tokens(engine):
         assert [results[i] for i in range(4)] == want
     finally:
         pressed.shutdown()
+
+
+def test_a_row_dropped_with_its_token_in_flight_leaves_a_clean_slot(engine):
+    """Steps run one ahead of the host's reads. A request sealed by its
+    caller while the step that advances its state is unread: the step
+    already queued still writes its ring and its state, before the
+    slot's next tenant's first chunk, which starts from zero: the
+    tenant, in the same slot, and the batchmate get the reference's
+    greedy tokens."""
+    cfg, params = engine.config, engine.params
+    mate, tenant = (p.tolist() for p in contexts_of([7, 11], seed=8))
+    before = engine.engine_stats()
+    doomed = engine.submit(contexts_of([9], seed=3)[0].tolist(),
+                           max_new_tokens=40)
+    batchmate = engine.submit(mate, max_new_tokens=30)
+    for _ in range(100_000):
+        with engine._lock:
+            unread = engine._unread
+            if unread is not None and doomed in unread.active \
+                    and len(doomed.output) >= 4:
+                slot = doomed.slot
+                assert engine._seal(doomed, RuntimeError("caller left"))
+                break
+        time.sleep(0.0005)
+    else:
+        pytest.fail("never saw the request in a step in flight")
+    with pytest.raises(RuntimeError, match="caller left"):
+        engine.result(doomed, timeout_s=300)
+    while doomed.slot >= 0:  # the sweep gives the slot back
+        time.sleep(0.0005)
+    next_tenant = engine.submit(tenant, max_new_tokens=10)
+    while next_tenant.slot < 0 and not next_tenant.done.is_set():
+        time.sleep(0.0005)
+    assert next_tenant.slot in (slot, -1)  # the same row, while it runs
+    assert engine.result(next_tenant, timeout_s=300) == \
+        greedy_by_reference(cfg, params, tenant, 10)
+    assert engine.result(batchmate, timeout_s=300) == \
+        greedy_by_reference(cfg, params, mate, 30)
+    after = engine.engine_stats()
+    steps = after["decode_steps"] - before["decode_steps"]
+    ahead = after["decode_steps_ahead"] - before["decode_steps_ahead"]
+    assert 0 < steps - 4 <= ahead < steps
+    assert after["state_resets"] - before["state_resets"] == 3
 
 
 def test_row_slots_are_given_back():

@@ -337,9 +337,9 @@ def record_passes(engine) -> list:
     """The host array of every pass the loop runs from now on."""
     step, seen = engine._decode_step, []
 
-    def recording(params, pool, rows, key, expert_stats):
+    def recording(params, pool, rows, key, expert_stats, prev):
         seen.append(np.array(rows))
-        return step(params, pool, rows, key, expert_stats)
+        return step(params, pool, rows, key, expert_stats, prev)
 
     engine.__dict__["_decode_step"] = recording
     return seen
@@ -618,3 +618,39 @@ def test_the_other_families_streams_are_what_they_were(family):
     finally:
         engine.shutdown()
     assert got == together
+
+
+def test_the_block_family_keeps_no_step_ahead():
+    """What a pass carries depends on the values of the pass before it
+    (a block's tokens, and under the dynamic rule how many positions it
+    fixed), so this family says so and the one loop reads every pass
+    before it schedules the next: none is ever in flight at a launch,
+    and the counter of steps launched ahead stays 0 while the
+    autoregressive families' flag is set."""
+    from ray_tpu.serve.llm_engine import hybrid
+
+    assert paged_model.PAGED.ahead and hybrid.FAMILY.ahead
+    engine = make_engine()
+    try:
+        assert not engine._family.ahead
+        step, in_flight = engine._decode_step, []
+
+        def watching(*args):
+            in_flight.append(engine._unread)
+            return step(*args)
+
+        engine.__dict__["_decode_step"] = watching
+        requests = [engine.submit(
+            prompt_of(n, seed=9), max_new_tokens=new, remasking=rule,
+            denoising_steps=steps) for n, new, rule, steps in SCHEDULES]
+        got = [engine.result(r, timeout_s=300) for r in requests]
+        stats = engine.engine_stats()
+        assert engine._unread is None
+    finally:
+        engine.shutdown()
+    assert got == [by_reference(prompt_of(n, seed=9), new, remasking=rule,
+                                denoising_steps=steps)
+                   for n, new, rule, steps in SCHEDULES]
+    assert stats["decode_steps"] == len(in_flight) > 10
+    assert in_flight == [None] * len(in_flight)
+    assert stats["decode_steps_ahead"] == 0

@@ -64,7 +64,7 @@ def record_steps(engine, compare_logits=False):
     seen = types.SimpleNamespace(widths=[], rows=[], preemptions=[],
                                  compared=0, worst=0.0, program=step)
 
-    def recording(params, pool, rows, key, expert_stats):
+    def recording(params, pool, rows, key, expert_stats, prev):
         width = rows.shape[1] - 3
         seen.widths.append(width)
         seen.rows.append(rows)
@@ -77,7 +77,7 @@ def record_steps(engine, compare_logits=False):
                          - np.asarray(logits(params, pool, wide)))[live]
             seen.compared += 1
             seen.worst = max(seen.worst, float(gap.max()))
-        return step(params, pool, rows, key, expert_stats)
+        return step(params, pool, rows, key, expert_stats, prev)
 
     engine.__dict__["_decode_step"] = recording
     return seen
