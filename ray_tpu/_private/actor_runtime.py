@@ -31,6 +31,7 @@ from ray_tpu.exceptions import (
     PendingCallsLimitExceeded,
     TaskCancelledError,
 )
+from ray_tpu.util import tracing
 
 
 class _ExitActor(BaseException):
@@ -48,6 +49,12 @@ class _ActorCall:
     # method runs so a call whose budget died queued behind earlier
     # calls seals TaskTimeoutError instead of executing.
     deadline: "float | None" = None
+    # While a sink is live where the call is submitted
+    # (``tracing.live()``): when its caller let go of it,
+    # ``time.monotonic_ns()``, and the caller's span context. The
+    # executors that ship a call to another process ship neither.
+    submitted_ns: int = 0
+    trace_ctx: "tuple | None" = None
 
 
 def _call_deadline_error(call: _ActorCall, cls_name: str):
@@ -211,11 +218,15 @@ class LocalActor:
             return
         from ray_tpu._private import request_context
 
-        ctx_token = request_context.set_deadline(call.deadline)
+        ctx_token = request_context.set_call(call.deadline,
+                                             call.submitted_ns)
+        span_token = tracing.attach(call.trace_ctx)
         try:
-            method = getattr(self._instance, call.method_name)
-            result = method(*call.args, **call.kwargs)
-            self._store_result(call, result)
+            with tracing.phase("runtime.actor.run") as hop:
+                self._describe(hop, call)
+                method = getattr(self._instance, call.method_name)
+                result = method(*call.args, **call.kwargs)
+                self._store_result(call, result)
         except _ExitActor:
             self._store_result(call, None)
             self.kill("exit_actor() was called", no_restart=True)
@@ -224,7 +235,8 @@ class LocalActor:
                 exc, format_traceback(exc),
                 f"{self._cls.__name__}.{call.method_name}"))
         finally:
-            request_context.reset_deadline(ctx_token)
+            tracing.detach(span_token)
+            request_context.reset_call(ctx_token)
 
     async def _execute_async(self, call: _ActorCall) -> None:
         with self._lock:
@@ -238,13 +250,18 @@ class LocalActor:
             return
         from ray_tpu._private import request_context
 
-        ctx_token = request_context.set_deadline(call.deadline)
+        ctx_token = request_context.set_call(call.deadline,
+                                             call.submitted_ns)
+        span_token = tracing.attach(call.trace_ctx)
         try:
-            method = getattr(self._instance, call.method_name)
-            result = method(*call.args, **call.kwargs)
-            if inspect.isawaitable(result):
-                result = await result
-            self._store_result(call, result)
+            # The span covers the awaits too.
+            with tracing.phase("runtime.actor.run") as hop:
+                self._describe(hop, call)
+                method = getattr(self._instance, call.method_name)
+                result = method(*call.args, **call.kwargs)
+                if inspect.isawaitable(result):
+                    result = await result
+                self._store_result(call, result)
         except _ExitActor:
             self._store_result(call, None)
             self.kill("exit_actor() was called", no_restart=True)
@@ -253,7 +270,17 @@ class LocalActor:
                 exc, format_traceback(exc),
                 f"{self._cls.__name__}.{call.method_name}"))
         finally:
-            request_context.reset_deadline(ctx_token)
+            tracing.detach(span_token)
+            request_context.reset_call(ctx_token)
+
+    def _describe(self, hop, call: _ActorCall) -> None:
+        """``runtime.actor.run``'s attributes, taken while a sink is
+        live: ``age_us`` is submit -> start, through the per-actor
+        submit-queue thread and this actor's mailbox."""
+        if hop.live:
+            hop.set(actor=self.actor_id.hex()[:8],
+                    method=f"{self._cls.__name__}.{call.method_name}",
+                    age_us=tracing.age_us(call.submitted_ns))
 
     def _store_result(self, call: _ActorCall, result: Any) -> None:
         store = self._runtime.store

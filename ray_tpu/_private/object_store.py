@@ -36,6 +36,7 @@ from ray_tpu.exceptions import (
     ObjectFreedError,
     ObjectLostError,
 )
+from ray_tpu.util import tracing
 
 
 def _sizeof(value: Any) -> int:
@@ -93,6 +94,10 @@ class ObjectEntry:
     managed_spill: bool = False
     # LRU signal for the managed victim policy (stamped on get).
     last_used: float = field(default_factory=time.monotonic)
+    # When it was sealed, ``time.monotonic_ns()``, while a trace sink
+    # was live (``tracing.stamp_ns``; else 0): a getter that waited for
+    # it reports how long after the seal it was awake.
+    sealed_ns: int = 0
 
 
 class _TornRestore(Exception):
@@ -350,6 +355,7 @@ class ObjectStore:
         entry.spilled_path = None
         entry.managed_spill = False
         entry.size_bytes = size_bytes
+        entry.sealed_ns = tracing.stamp_ns()
         self._memory_used += entry.size_bytes
         self._unspillable.discard(object_id)
 
@@ -531,6 +537,12 @@ class ObjectStore:
         with self._lock:
             entry = self._entries.get(object_id)
             return entry is not None and entry.sealed and not entry.freed
+
+    def sealed_ns(self, object_id: ObjectID) -> int:
+        """The entry's seal stamp (0: unknown, or sealed while no
+        trace sink was live)."""
+        entry = self._entries.get(object_id)
+        return entry.sealed_ns if entry is not None else 0
 
     def is_pending(self, object_id: ObjectID) -> bool:
         with self._lock:

@@ -23,14 +23,31 @@ _DEADLINE: "contextvars.ContextVar[float | None]" = contextvars.ContextVar(
     "ray_tpu_call_deadline", default=None)
 
 
-def set_deadline(deadline: "float | None"):
-    """Install the current call's absolute deadline (time.time());
-    returns the token for :func:`reset_deadline`."""
-    return _DEADLINE.set(deadline)
+# When the in-flight call's caller let go of it (``time.monotonic_ns()``
+# there; 0: not stamped, no trace sink was live): what a span the method
+# opens at its entry reports as its ``age_us``.
+_SUBMITTED_NS: "contextvars.ContextVar[int]" = contextvars.ContextVar(
+    "ray_tpu_call_submitted_ns", default=0)
 
 
-def reset_deadline(token) -> None:
-    _DEADLINE.reset(token)
+def set_call(deadline: "float | None", submitted_ns: int = 0):
+    """Install the current call's absolute deadline (time.time()) and,
+    for a call that was stamped, its submission stamp; returns the
+    token for :func:`reset_call`."""
+    return (_DEADLINE.set(deadline),
+            _SUBMITTED_NS.set(submitted_ns) if submitted_ns else None)
+
+
+def reset_call(token) -> None:
+    deadline, submitted = token
+    _DEADLINE.reset(deadline)
+    if submitted is not None:
+        _SUBMITTED_NS.reset(submitted)
+
+
+def current_submitted_ns() -> int:
+    """The in-flight call's submission stamp, or 0."""
+    return _SUBMITTED_NS.get()
 
 
 def current_deadline() -> "float | None":

@@ -4205,23 +4205,30 @@ class Runtime:
         task_default_deadline_s) arms an end-to-end budget: a call
         whose deadline dies queued seals TaskTimeoutError instead of
         executing."""
-        return_ids = [ObjectID() for _ in range(max(1, num_returns))]
-        self._pin_nested_arg_refs(args, kwargs)
-        for rid in return_ids:
-            self.store.create_pending(rid)
-        refs = [ObjectRef(rid) for rid in return_ids]
-        call = _ActorCall(method_name, args, kwargs, return_ids,
-                          deadline=self._absolute_deadline(deadline_s))
-
-        record = self.gcs.get_actor(actor_id)
-        if record is None or (record.state == "DEAD" and actor_id not in self._actors):
-            err = ActorDiedError(actor_id, (record.death_cause if record else None)
-                                 or "actor not found")
+        # What ``.remote()`` costs its caller.
+        with tracing.phase("runtime.actor.submit") as hop:
+            return_ids = [ObjectID() for _ in range(max(1, num_returns))]
+            self._pin_nested_arg_refs(args, kwargs)
             for rid in return_ids:
-                self.store.put_error(rid, err)
+                self.store.create_pending(rid)
+            refs = [ObjectRef(rid) for rid in return_ids]
+            call = _ActorCall(method_name, args, kwargs, return_ids,
+                              deadline=self._absolute_deadline(deadline_s))
+
+            record = self.gcs.get_actor(actor_id)
+            if record is None or (record.state == "DEAD" and actor_id not in self._actors):
+                err = ActorDiedError(actor_id, (record.death_cause if record else None)
+                                     or "actor not found")
+                for rid in return_ids:
+                    self.store.put_error(rid, err)
+                return refs
+            if hop.live:
+                hop.set(actor=actor_id.hex()[:8], method=method_name)
+                call.trace_ctx = tracing.make_trace_context()
+                # Last: the call's age starts where its caller lets go.
+                call.submitted_ns = tracing.stamp_ns()
+            self._actor_submit_queue(actor_id).put(call)
             return refs
-        self._actor_submit_queue(actor_id).put(call)
-        return refs
 
     def _actor_submit_queue(self, actor_id: ActorID):
         """Lazily start the per-actor ordered submission worker."""
@@ -4658,8 +4665,14 @@ class Runtime:
             if block_ctx is not None:
                 block_ctx.block()
             try:
-                results.append(self._materialize_value(
-                    ref.id(), self.store.get(ref.id(), timeout=remaining)))
+                # The caller's wait for a ref not yet sealed; age_us is
+                # sealed -> awake (the store stamps the entry).
+                with tracing.phase("runtime.get") as hop:
+                    value = self.store.get(ref.id(), timeout=remaining)
+                    if hop.live:
+                        hop.set(age_us=tracing.age_us(
+                            self.store.sealed_ns(ref.id())))
+                results.append(self._materialize_value(ref.id(), value))
             finally:
                 if block_ctx is not None:
                     block_ctx.unblock()

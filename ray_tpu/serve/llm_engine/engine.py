@@ -107,6 +107,12 @@ ENGINE_STAT_KEYS = (
     # that made progress.
     "first_tokens", "queue_wait_us", "prefill_us",
     "loop_wall_us", "loop_cpu_us", "fetch_wait_us", "decode_host_us",
+    # The CPU time of the whole process (``time.process_time_ns()``)
+    # when the counters are asked for: against ``loop_cpu_us`` over the
+    # same interval, the share of the process's CPU that the engine
+    # thread gets; the rest is the stream and client threads, the
+    # runtime's and the background.
+    "process_cpu_us",
     # Calls the engine thread made into JAX (a dispatch with its one
     # host array, a blocking read): two per decode step, one per
     # prefill chunk, and the first token's sampling and read.
@@ -349,7 +355,8 @@ class LLMEngine:
                name: str = "llm_generate",
                denoising_steps: "int | None" = None,
                remasking: "str | None" = None,
-               confidence_threshold: "float | None" = None
+               confidence_threshold: "float | None" = None,
+               request_id: "str | None" = None
                ) -> EngineRequest:
         """Admit one request (bounded; full queue / never-fits sheds
         typed through the SystemOverloadedError path). ``deadline`` is
@@ -357,7 +364,8 @@ class LLMEngine:
         ``get_runtime_context().get_task_deadline()``. The last three
         are a request's schedule under diffusion over blocks (passes a
         block, ``model.REMASKING``, the dynamic rule's threshold); left
-        out, the model configuration's; of no use to another model."""
+        out, the model configuration's; of no use to another model.
+        ``request_id`` names the request on its spans (``request``)."""
         max_new = max(1, min(int(max_new_tokens), self.max_tokens - 2))
         prompt = list(tokens) or [0]
         keep = max(1, self.max_tokens - max_new - 1)
@@ -377,7 +385,7 @@ class LLMEngine:
                     f"{paged_model.REMASKING}")
         req = EngineRequest(prompt, max_new, temperature,
                             deadline=deadline, name=name, stream=stream,
-                            **schedule)
+                            request_id=request_id, **schedule)
         with self._lock:
             if self._shutdown.is_set():
                 raise RuntimeError("LLM engine is shut down")
@@ -430,17 +438,25 @@ class LLMEngine:
         assert req.stream is not None, "submit(stream=True) first"
         while True:
             try:
-                kind, payload = req.stream.get(timeout=0.05)
+                kind, payload, emitted_ns = req.stream.get(timeout=0.05)
             except queue_mod.Empty:
                 self._check_caller_deadline(req)
                 continue
-            tokens = []
-            while kind == "tok":
-                tokens.append(payload)
-                try:
-                    kind, payload = req.stream.get_nowait()
-                except queue_mod.Empty:
-                    kind = None
+            # The hand-off from the engine thread's emission to this
+            # stream thread, from its wake-up to the yield; age_us: how
+            # long the oldest token it takes had waited by then.
+            with tracing.phase("llm.stream.take") as hop:
+                age_us = tracing.age_us(emitted_ns) if hop.live else None
+                tokens = []
+                while kind == "tok":
+                    tokens.append(payload)
+                    try:
+                        kind, payload, _ = req.stream.get_nowait()
+                    except queue_mod.Empty:
+                        kind = None
+                if hop.live:
+                    hop.set(request=req.request_id, tokens=len(tokens),
+                            age_us=age_us)
             if tokens:
                 yield tokens
             if kind == "end":
@@ -469,8 +485,8 @@ class LLMEngine:
             req.error = error
         self._record_sealed(req)
         if req.stream is not None:
-            req.stream.put(("err", error) if error is not None
-                           else ("end", None))
+            req.stream.put(("err", error, 0) if error is not None
+                           else ("end", None, 0))
         req.done.set()
         return True
 
@@ -485,6 +501,8 @@ class LLMEngine:
         if not tracing.TRACE_ON or req.trace_ctx is None:
             return
         trace_id, parent_id, _ = req.trace_ctx
+        # What ties these to the hops of the serve tier around them.
+        tie = {"request": req.request_id} if req.request_id else {}
         now = time.time()
         to_wall = now - req.sealed_ns / 1e9  # monotonic -> wall clock
         root = tracing.record_span(
@@ -492,6 +510,7 @@ class LLMEngine:
             trace_id, parent_id,
             {"req": req.rid, "prompt_tokens": len(req.tokens),
              "new_tokens": len(req.output), "preempted": req.preempted,
+             **tie,
              **({"error": type(req.error).__name__}
                 if req.error is not None else {})})
         stamps = (req.submitted_ns, req.claimed_ns, req.first_token_ns,
@@ -503,16 +522,19 @@ class LLMEngine:
                 tracing.record_span(
                     name, to_wall + start / 1e9,
                     to_wall + (end or req.sealed_ns) / 1e9, trace_id,
-                    root, {"req": req.rid})
+                    root, {"req": req.rid, **tie})
 
     def _deliver_locked(self, req: EngineRequest, tokens: list) -> bool:
         """Emit the tokens a pass made final for ``req``, in order; the
         request's first are stamped. True when the request is over: its
         limit reached, or its table's end."""
-        for token in tokens:
-            req.output.append(token)
-            if req.stream is not None:
-                req.stream.put(("tok", token))
+        if tokens and req.stream is not None:
+            # When the engine thread let go of them (0: no sink live);
+            # the stamp stays on this side of the stream's yield.
+            emitted_ns = tracing.stamp_ns()
+            for token in tokens:
+                req.stream.put(("tok", token, emitted_ns))
+        req.output.extend(tokens)
         req.remaining = req.max_new_tokens - len(req.output)
         if tokens and not req.first_token_ns:
             req.first_token_ns = time.monotonic_ns()
@@ -671,8 +693,10 @@ class LLMEngine:
         """The context is prefilled: the request enters the decode
         batch, with its first token where prefill yields one (not under
         diffusion over blocks, and not on a resume)."""
-        with tracing.phase("engine.prefill.first_token", req=req.rid), \
-                self._lock:
+        with tracing.phase("engine.prefill.first_token", req=req.rid) \
+                as first_token, self._lock:
+            if first_token.live:
+                first_token.set(request=req.request_id)
             req.position = len(req.context)
             first = []
             if req.sample_first:
@@ -727,7 +751,7 @@ class LLMEngine:
         req.sealed = True
         self._record_sealed(req)
         if req.stream is not None:
-            req.stream.put(("end", None))
+            req.stream.put(("end", None, 0))
         req.done.set()
 
     def _decode_tick(self) -> bool:
@@ -913,6 +937,7 @@ class LLMEngine:
                for key in ENGINE_STAT_KEYS}
         out["blocks_allocated"] = int(self._sched.cache.blocks_allocated)
         out["blocks_freed"] = int(self._sched.cache.blocks_freed)
+        out["process_cpu_us"] = time.process_time_ns() // 1000
         expert_stats = self._expert_stats
         if expert_stats is not None:
             # One transfer, here and nowhere else; it waits for the
@@ -975,6 +1000,8 @@ def merged_engine_stats() -> "dict | None":
     for engine in engines:
         for key, value in engine.engine_stats().items():
             out[key] += int(value)
+    # The process's, not an engine's: once, whatever the engines.
+    out["process_cpu_us"] = time.process_time_ns() // 1000
     return out
 
 

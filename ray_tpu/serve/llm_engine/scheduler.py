@@ -76,7 +76,7 @@ class EngineRequest:
         "prefilled", "sample_first", "remaining", "last_token",
         "preempted", "sealed", "error", "done", "stream",
         "rid", "submitted_ns", "claimed_ns", "first_token_ns", "sealed_ns",
-        "trace_ctx", "slot",
+        "trace_ctx", "slot", "request_id",
         "block", "passes", "denoising_steps", "remasking",
         "confidence_threshold",
     )
@@ -86,7 +86,8 @@ class EngineRequest:
                  temperature: float, deadline: "float | None" = None,
                  name: str = "llm_generate", stream: bool = False,
                  denoising_steps: int = 1, remasking: str = "sequential",
-                 confidence_threshold: float = 0.9):
+                 confidence_threshold: float = 0.9,
+                 request_id: "str | None" = None):
         self.tokens = list(tokens) or [0]
         self.max_new_tokens = int(max_new_tokens)
         self.temperature = float(temperature)
@@ -126,6 +127,10 @@ class EngineRequest:
         self.stream: "queue_mod.SimpleQueue | None" = (
             queue_mod.SimpleQueue() if stream else None)
         self.rid = next(self._rids)
+        # The streamed request this serves, as the serve tier names it
+        # (``serve.replica.stream_request_id``): ``request`` on this
+        # request's spans, so they pair with the hops around the engine.
+        self.request_id = request_id
         # Where the request's time went, ``time.monotonic_ns()``: the
         # engine sums queue wait and prefill time into its counters
         # from these, and records them as spans at the seal while

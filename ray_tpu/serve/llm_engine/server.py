@@ -25,6 +25,7 @@ waiting for. A full waiting queue or unservable request sheds
 
 from __future__ import annotations
 
+from ray_tpu.serve import replica
 from ray_tpu.serve.llm_engine.engine import LLMEngine
 
 
@@ -62,14 +63,20 @@ class LLMEngineServer:
         return get_runtime_context().get_task_deadline()
 
     def _submit(self, request: dict, stream: bool = False):
-        return self._engine.submit(
+        """The engine's request carries the streamed request's id (None
+        for a unary call), and the replica's admission span ends where
+        the engine's queue is reached."""
+        req = self._engine.submit(
             list(request.get("tokens") or []),
             max_new_tokens=int(request.get("max_new_tokens", 16)),
             temperature=float(request.get("temperature", 0.0)),
             deadline=self._deadline(request), stream=stream,
             denoising_steps=request.get("denoising_steps"),
             remasking=request.get("remasking"),
-            confidence_threshold=request.get("confidence_threshold"))
+            confidence_threshold=request.get("confidence_threshold"),
+            request_id=replica.current_stream_request_id())
+        replica.stream_request_admitted()
+        return req
 
     def __call__(self, request: dict) -> dict:
         return {"tokens": self._engine.result(self._submit(request),

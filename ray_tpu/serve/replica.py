@@ -8,11 +8,14 @@ handle_request (:391). Each replica tracks its ongoing-request count
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import inspect
 import threading
 import time
 from typing import Any
 
+from ray_tpu._private import request_context
 from ray_tpu.exceptions import TaskError
 from ray_tpu.util import tracing
 
@@ -20,6 +23,62 @@ from ray_tpu.util import tracing
 class BackPressureError(Exception):
     """Replica at max_ongoing_requests (reference: replica raises when
     over capacity so the router retries elsewhere)."""
+
+
+def stream_request_id(queue) -> "str | None":
+    """The id of one streamed request: its per-stream queue's actor id,
+    which the caller's handle and the replica both hold, so nothing new
+    crosses. Every span of the request's way in and of its tokens' way
+    out carries it as ``request``."""
+    try:
+        return queue.actor._actor_id.hex()[:16]
+    except AttributeError:  # not a util.queue.Queue
+        return None
+
+
+# The streamed request this thread is handling: (its id, its open
+# ``serve.replica.admit`` phase). The deployment's method reads the id
+# for what it submits to (an engine's request carries it) and says when
+# that is done, which ends the span.
+_STREAM_REQUEST: contextvars.ContextVar = contextvars.ContextVar(
+    "ray_tpu_serve_stream_request", default=(None, None))
+
+
+def current_stream_request_id() -> "str | None":
+    """Inside a streaming method of a deployment: the request's id."""
+    return _STREAM_REQUEST.get()[0]
+
+
+def stream_request_admitted() -> None:
+    """Inside a streaming method of a deployment: the request has
+    reached what serves it (an engine's queue). Ends
+    ``serve.replica.admit``; a method that never says so ends it with
+    its first chunk."""
+    request_id, admit = _STREAM_REQUEST.get()
+    if admit is not None:
+        _STREAM_REQUEST.set((request_id, None))
+        admit.__exit__(None, None, None)
+
+
+@contextlib.contextmanager
+def _stream_request(queue):
+    """A streamed request on this thread, from the call's start: its id,
+    and ``serve.replica.admit`` open (admission, the call slot taken, the
+    engine's queue reached) until ``stream_request_admitted``, the first
+    chunk or a failure. ``age_us``: since the caller's ``.remote()`` let
+    go of the call."""
+    request_id = stream_request_id(queue)
+    admit = tracing.phase("serve.replica.admit", cpu=True)
+    admit.__enter__()
+    if admit.live:
+        admit.set(request=request_id, age_us=tracing.age_us(
+            request_context.current_submitted_ns()))
+    token = _STREAM_REQUEST.set((request_id, admit))
+    try:
+        yield request_id
+    finally:
+        stream_request_admitted()
+        _STREAM_REQUEST.reset(token)
 
 
 class Replica:
@@ -125,6 +184,12 @@ class Replica:
         through the shared queue AS the generator yields, so the caller
         consumes while this replica still produces. Protocol:
         ("chunk", value)* then ("end", n) | ("err", exc)."""
+        with _stream_request(queue) as request_id:
+            return self._stream(method_name, args, kwargs, queue,
+                                request_id)
+
+    def _stream(self, method_name: str, args: tuple, kwargs: dict, queue,
+                request_id: "str | None") -> int:
         kwargs, token = self._admit(kwargs)
         n = 0
         # A streaming method may have a sibling ``<name>_batches`` that
@@ -141,11 +206,14 @@ class Replica:
             if not inspect.isgenerator(result):
                 result = iter([result])
             for chunk in result:
+                stream_request_admitted()
                 chunks = chunk if batched is not None else (chunk,)
                 try:
                     # The entry point's cost per chunk, and through
                     # the queue actor the core runtime's.
-                    with tracing.phase("serve.stream.put"):
+                    with tracing.phase("serve.stream.put") as hop:
+                        if hop.live:
+                            hop.set(request=request_id, tokens=len(chunks))
                         if len(chunks) == 1:
                             queue.put(("chunk", chunks[0]))
                         else:

@@ -17,7 +17,8 @@ from typing import Any
 
 from ray_tpu._private import metrics_history, perf_plane
 from ray_tpu.serve.long_poll import LongPollClient
-from ray_tpu.serve.replica import BackPressureError
+from ray_tpu.serve.replica import BackPressureError, stream_request_id
+from ray_tpu.util import tracing
 
 
 class DeploymentStreamingResponse:
@@ -44,6 +45,9 @@ class DeploymentStreamingResponse:
                  request=None, model_id=None, timeout_s: float = 300.0,
                  started=None):
         self._queue = queue
+        # The one id of this streamed request, on every span of its way
+        # in and of its tokens' way out (``stream_request_id``).
+        self._request_id = stream_request_id(queue)
         self._ref = object_ref
         self._router = router
         self._replica_idx = replica_idx
@@ -92,12 +96,27 @@ class DeploymentStreamingResponse:
             return False
         self._release()
         method_name, args, kwargs = self._request
-        idx, handle = self._router._pick(model_id=self._model_id,
-                                         skip_affinity=True)
-        self._replica_idx = idx
-        self._ref = handle.handle_request_streaming.remote(
-            method_name, args, kwargs, self._queue)
+        with tracing.phase("serve.handle.send", cpu=True,
+                           request=self._request_id):
+            idx, handle = self._router._pick(model_id=self._model_id,
+                                             skip_affinity=True)
+            self._replica_idx = idx
+            self._ref = handle.handle_request_streaming.remote(
+                method_name, args, kwargs, self._queue)
         return True
+
+    def _take(self) -> list:
+        """What waits in the queue actor, oldest LAST (``Empty`` after
+        ``_POLL_S`` of nothing): the hand-off from the queue actor to
+        this client thread, ending with the chunks in its hand."""
+        with tracing.phase("serve.stream.get") as hop:
+            queue = self._queue
+            taken = queue.get_available(self._TAKE, timeout=self._POLL_S)
+            if hop.live:
+                hop.set(request=self._request_id,
+                        tokens=sum(kind == "chunk" for kind, _ in taken),
+                        age_us=tracing.age_us(queue.oldest_landed_ns))
+        return taken[::-1]
 
     def __iter__(self):
         import time as _time
@@ -118,8 +137,7 @@ class DeploymentStreamingResponse:
             while not self._done:
                 try:
                     if not taken:
-                        taken = self._queue.get_available(
-                            self._TAKE, timeout=self._POLL_S)[::-1]
+                        taken = self._take()
                     kind, payload = taken.pop()
                 except Empty:
                     if _time.monotonic() > deadline:
@@ -521,15 +539,19 @@ class Router:
         started = time.monotonic()
         deadline = (time.time() + deadline_s
                     if deadline_s is not None else None)
-        idx, handle = self._pick(model_id=model_id)
         if stream_queue is not None:
-            ref = self._bind_deadline(
-                handle.handle_request_streaming, deadline).remote(
-                method_name, args, kwargs, stream_queue)
+            # The request's entry into the program.
+            with tracing.phase("serve.handle.send", cpu=True,
+                               request=stream_request_id(stream_queue)):
+                idx, handle = self._pick(model_id=model_id)
+                ref = self._bind_deadline(
+                    handle.handle_request_streaming, deadline).remote(
+                    method_name, args, kwargs, stream_queue)
             return DeploymentStreamingResponse(
                 stream_queue, ref, router=self, replica_idx=idx,
                 request=(method_name, args, kwargs), model_id=model_id,
                 started=started)
+        idx, handle = self._pick(model_id=model_id)
         ref = self._bind_deadline(
             handle.handle_request, deadline).remote(
             method_name, args, kwargs)

@@ -7,10 +7,16 @@ semantics).
 
 from __future__ import annotations
 
+import os
 import time
 from typing import Any
 
 import ray_tpu
+from ray_tpu.util import tracing
+
+# ``time.monotonic_ns()`` means the same to every process of one host,
+# and nothing to another's.
+_HOST = os.uname().nodename
 
 
 class Empty(Exception):
@@ -27,6 +33,8 @@ class _QueueActor:
         import threading
 
         self.maxsize = maxsize
+        # (item, when it landed here: ``tracing.stamp_ns()``, 0 while no
+        # trace sink is live). The stamp never leaves with the item.
         self._items: collections.deque = collections.deque()
         # A queue made with ``waiting_get`` runs two calls at a time: a
         # consumer's ``get_available`` waits HERE for a producer's put.
@@ -45,7 +53,7 @@ class _QueueActor:
         with self._arrived:
             if self.full():
                 return False
-            self._items.append(item)
+            self._items.append((item, tracing.stamp_ns()))
             self._arrived.notify_all()
             return True
 
@@ -54,30 +62,35 @@ class _QueueActor:
             if self.maxsize \
                     and len(self._items) + len(items) > self.maxsize:
                 return False
-            self._items.extend(items)
+            landed = tracing.stamp_ns()
+            self._items.extend((item, landed) for item in items)
             self._arrived.notify_all()
             return True
 
     def get_nowait(self):
         if not self._items:
             return False, None
-        return True, self._items.popleft()
+        return True, self._items.popleft()[0]
 
     def get_nowait_batch(self, num_items: int):
         if len(self._items) < num_items:
             return False, None
-        return True, [self._items.popleft() for _ in range(num_items)]
+        return True, [self._items.popleft()[0] for _ in range(num_items)]
 
-    def get_available(self, max_items: int, wait_s: float = 0.0) -> list:
+    def get_available(self, max_items: int, wait_s: float = 0.0) -> tuple:
         """Whatever is queued, oldest first, at most ``max_items``
         (possibly nothing), after waiting up to ``wait_s`` for the
         first (only a ``waiting_get`` queue is asked to wait: with one
-        call at a time the put that would end the wait could not run)."""
+        call at a time the put that would end the wait could not run).
+        Replies ``(items, when the oldest landed here or 0, this
+        host)``: ``Queue.get_available`` hands its caller the items."""
         with self._arrived:
             if not self._items and wait_s > 0:
                 self._arrived.wait(wait_s)
             take = min(max_items, len(self._items))
-            return [self._items.popleft() for _ in range(take)]
+            taken = [self._items.popleft() for _ in range(take)]
+        return ([item for item, _ in taken],
+                taken[0][1] if taken else 0, _HOST)
 
 
 class Queue:
@@ -97,6 +110,10 @@ class Queue:
         # whose consumer is gone then raises Full instead of retrying
         # for the life of its thread.
         self.put_timeout_s = put_timeout_s
+        # Of the last ``get_available`` that took something: when its
+        # oldest item landed in the actor, ``time.monotonic_ns()``; 0 if
+        # no trace sink was live there, or the actor is on another host.
+        self.oldest_landed_ns = 0
         options = dict(actor_options or {})
         if waiting_get:
             options.setdefault("max_concurrency", 2)
@@ -113,6 +130,7 @@ class Queue:
         self.actor = state["actor"]
         self.put_timeout_s = state.get("put_timeout_s")
         self.waiting_get = state.get("waiting_get", False)
+        self.oldest_landed_ns = 0
 
     # -- inspection ---------------------------------------------------
     def qsize(self) -> int:
@@ -201,9 +219,10 @@ class Queue:
             if self.waiting_get:
                 wait_s = 0.2 if deadline is None else \
                     max(0.0, min(0.2, deadline - time.monotonic()))
-            items = ray_tpu.get(
+            items, landed_ns, host = ray_tpu.get(
                 self.actor.get_available.remote(max_items, wait_s))
             if items:
+                self.oldest_landed_ns = landed_ns if host == _HOST else 0
                 return items
             if deadline is not None and time.monotonic() >= deadline:
                 raise Empty

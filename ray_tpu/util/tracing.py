@@ -31,13 +31,23 @@ subsystem behind ``ray timeline``. Here the tracer is built in:
   two sinks: a ``jax.profiler`` TraceMe, which a profiler session puts
   in the host plane on the device trace's clock (so a device idle gap
   can be read against what the host was doing), and a ``Span`` here
-  while tracing is armed. The serving engine's loop and the replica's
-  stream use it.
+  while tracing is armed. The serving engine's loop uses it.
+
+  The stream path and the core runtime under it use it too: every
+  hand-off of a request on its way in and of a token on its way out is
+  one ``phase`` on the thread that RECEIVES the work. While a sink is
+  live (``live()``: ``TRACE_ON`` or a profiler session) a hand-off
+  carries ``age_us``, how long its oldest item waited since the hop
+  before let go of it (``stamp_ns()`` there, ``age_us()`` here), and
+  ``request``, the id of the streamed request it belongs to; a phase
+  made with ``cpu=True`` (the hops a request makes once) also carries
+  ``cpu_us``, its thread's CPU time: wall less ``cpu_us`` is how long
+  the thread stood.
 
 Cost discipline: when tracing is disabled every instrumentation site
 pays one module-attribute branch (``if tracing.TRACE_ON:``) — the same
-contract as ``chaos.ACTIVE``; a ``phase`` also pays its TraceMe's
-enter and exit, which are inert outside a profiler session.
+contract as ``chaos.ACTIVE``; a ``phase`` also asks whether a profiler
+session runs (no TraceMe is built outside one) and reads no clock.
 """
 
 from __future__ import annotations
@@ -200,33 +210,81 @@ class _OffSpan(Span):
 
 _OFF_SPAN = _OffSpan(name="", span_id="", parent_id=None, start_time=0.0)
 
+_annotation_class = None
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` once jax is imported in this
+    process (never an import of its own: daemons without jax stay
+    without it), else None."""
+    global _annotation_class
+    if _annotation_class is None:
+        try:
+            _annotation_class = \
+                sys.modules["jax"].profiler.TraceAnnotation
+        except (KeyError, AttributeError):  # no jax, or half imported
+            return None
+    return _annotation_class
+
+
+def live() -> bool:
+    """Is a sink live: spans are recorded, or a profiler session runs?
+    What only a span would read (a stamp, a thread's CPU time, an
+    attribute's value) is taken only then."""
+    if TRACE_ON:
+        return True
+    annotation = _trace_annotation()
+    return annotation is not None and annotation.is_enabled()
+
+
+def stamp_ns() -> int:
+    """``time.monotonic_ns()`` for the hop that lets go of an item,
+    kept on the object that crosses to the next thread; 0 (no clock
+    read) while no sink is live."""
+    return time.monotonic_ns() if live() else 0
+
+
+def age_us(stamp_ns: int) -> "int | None":
+    """Microseconds since ``stamp_ns``; None for an item that was not
+    stamped (no sink was live where it was let go of)."""
+    if not stamp_ns:
+        return None
+    return max(0, time.monotonic_ns() - stamp_ns) // 1000
+
 
 class phase:
     """A phase of host work, instrumented once for two sinks.
 
     A ``jax.profiler.TraceAnnotation`` whenever jax is already imported
-    in this process (never an import of its own: daemons without jax
-    stay without it): inert while no profiler session runs, and inside
+    in this process: inert while no profiler session runs, and inside
     one it lands in the host plane on the device trace's clock. A
     ``Span`` (parented through the contextvar, sharing the enclosing
-    span's trace id) while ``TRACE_ON``. With neither live it costs the
-    TraceMe's enter and exit and nothing else."""
+    span's trace id) while ``TRACE_ON``. While either is live
+    (``self.live``) a phase made with ``cpu=True`` carries ``cpu_us``,
+    its thread's CPU time between enter and exit (two
+    ``time.thread_time_ns()``: 6 us each and in ticks of 10 ms on the
+    chip's machine, so for a hop a request makes once, not one a
+    token). With neither live a phase costs one lookup and a branch:
+    no TraceMe is built, no clock is read."""
 
-    __slots__ = ("_name", "_attrs", "_annotation", "_token", "span")
+    __slots__ = ("_name", "_attrs", "_annotation", "_token", "span",
+                 "live", "_cpu", "_cpu0")
     _records_span = True
 
-    def __init__(self, name: str, **attrs):
+    def __init__(self, name: str, cpu: bool = False, **attrs):
         self._name = name
         self._attrs = attrs
         self._annotation = None
         self.span: "Span | None" = None
+        self.live = False
+        self._cpu = cpu
 
     def __enter__(self) -> "phase":
-        jax = sys.modules.get("jax")
-        if jax is not None:
-            self._annotation = jax.profiler.TraceAnnotation(
-                self._name, **self._attrs)
+        annotation = _trace_annotation()
+        if annotation is not None and annotation.is_enabled():
+            self._annotation = annotation(self._name, **self._attrs)
             self._annotation.__enter__()
+            self.live = True
         if TRACE_ON and self._records_span:
             parent = _current_span.get()
             self.span = Span(
@@ -240,16 +298,26 @@ class phase:
                           else _new_id()),
             )
             self._token = _current_span.set(self.span)
+            self.live = True
+        if self._cpu and self.live:
+            # Inside the span's wall interval: cpu_us <= its duration.
+            self._cpu0 = time.thread_time_ns()
         return self
 
     def set(self, **attrs) -> None:
-        """Attributes known only once the phase is under way."""
+        """Attributes known only once the phase is under way (one
+        that is None is left out)."""
+        if not self.live:
+            return
+        attrs = {k: v for k, v in attrs.items() if v is not None}
         if self._annotation is not None:
             self._annotation.set_metadata(**attrs)
         if self.span is not None:
             self.span.attributes.update(attrs)
 
     def __exit__(self, exc_type, exc, tb) -> None:
+        if self._cpu and self.live:
+            self.set(cpu_us=(time.thread_time_ns() - self._cpu0) // 1000)
         span = self.span
         if span is not None:
             if exc is not None:
@@ -328,6 +396,23 @@ def make_trace_context(anchor: float | None = None) -> tuple | None:
         parent_id = None
     return (trace_id, parent_id, anchor if anchor is not None
             else time.time())
+
+
+def attach(ctx: "tuple | None"):
+    """Make a trace context that crossed from another thread (an actor
+    call's) the current one: spans opened here until ``detach`` are
+    children of the span that made it. Returns the token for
+    ``detach``; None, and nothing done, for no context."""
+    if ctx is None:
+        return None
+    return _current_span.set(Span(
+        name="", span_id=ctx[1], parent_id=None, start_time=ctx[2],
+        trace_id=ctx[0]))
+
+
+def detach(token) -> None:
+    if token is not None:
+        _current_span.reset(token)
 
 
 @contextlib.contextmanager

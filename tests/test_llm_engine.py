@@ -1049,7 +1049,7 @@ def test_server_control_path(paged_engine, engine_server, case):
         server.check_health()  # a shut-down engine is not a dead one
     else:
         request = {"tokens": [1, 2], "max_new_tokens": 2}
-        token = request_context.set_deadline(time.time() - 1.0)
+        token = request_context.set_call(time.time() - 1.0)
         try:
             # The call's inherited budget is dead ...
             with pytest.raises(TaskTimeoutError):
@@ -1057,7 +1057,7 @@ def test_server_control_path(paged_engine, engine_server, case):
             # ... and the request's own beats it.
             out = engine_server({**request, "deadline_s": 60.0})
         finally:
-            request_context.reset_deadline(token)
+            request_context.reset_call(token)
         assert len(out["tokens"]) == 2
 
 
