@@ -303,9 +303,19 @@ _DEFAULTS: dict[str, Any] = {
     "llm_block_size": 16,
     # Prefill chunk length: a long prompt prefills in fixed chunks of
     # this many tokens, interleaved with decode steps, so one long
-    # prompt cannot stall in-flight streams. Also the jit-cache bound:
-    # ONE prefill program total (every chunk pads to this shape).
-    "llm_prefill_chunk": 32,
+    # prompt cannot stall in-flight streams; every chunk pads to this
+    # shape (one prefill program a table width). An engine whose table
+    # is shorter takes the table's positions. 128, because a chunk of T
+    # tokens does T FLOP for each byte of bf16 weight it reads (the
+    # all-experts products of a sparse model too) and a v5e chip's
+    # ridge is 197e12 / 819e9 = 240: up to there a chunk costs the one
+    # read of the weights it pays anyway, so 128 tokens go for the
+    # price of 32 (on the chip, PR 38: a chunk of Mistral's 16 layers
+    # 11.1 ms at 32 tokens, 12.2 at 128, 14.1 at 256). 256 leaves the
+    # ridge (9.8 ms of arithmetic at the peak beside 9.2 ms of reading;
+    # the two sparse families' all-experts products become bound by
+    # arithmetic) and every token that waits behind a chunk pays it.
+    "llm_prefill_chunk": 128,
     # Bounded engine waiting queue: requests past this depth shed
     # typed (CacheExhaustedError -> SystemOverloadedError path ->
     # HTTP 503) instead of queueing unboundedly.

@@ -5,8 +5,9 @@ budgets, claim/advance ONE prefill chunk, then ONE fixed-shape decode
 step for every active stream — tokens stream out per step, finished
 rows free their blocks between steps, and cache pressure preempts the
 lowest-progress stream (recompute-on-resume) instead of failing it.
-The step's table is as wide as its longest live row needs, of three
-widths (``table_widths``), each a program the constructor has built.
+The step's table is as wide as its longest live row needs and a
+chunk's as wide as its request's, of three widths (``table_widths``),
+each a program the constructor has built.
 
 The loop keeps one decode step ahead where the family allows it
 (``Family.ahead``): step N+1 is launched on step N's tokens where they
@@ -162,11 +163,14 @@ _LIVE: "weakref.WeakSet" = weakref.WeakSet()
 
 
 def table_widths(blocks_per_seq: int) -> "tuple[int, ...]":
-    """The table widths a decode step may be given, in blocks, narrowest
-    first: a quarter, a half and the whole of a row's table, each in
-    whole blocks. Every row of a step gathers and attends over the
-    step's whole width, so a step takes the narrowest that holds its
-    longest live table. Three, because each is a program built before
+    """The table widths a decode step or a prefill chunk may be given,
+    in blocks, narrowest first: a quarter, a half and the whole of a
+    row's table, each in whole blocks. Every row of a step gathers and
+    attends over the step's whole width, so a step takes the narrowest
+    that holds its longest live table, and a chunk the narrowest that
+    holds its request's (at 128 tokens a chunk's float32 scores over
+    Mistral's whole table are 33 MB a layer: 12.81 ms a chunk for 12.24
+    at the quarter, PR 38). Three, because each is a program built before
     the engine serves: halving twice keeps the read within twice the
     longest context down to a quarter of the table, and a further rung
     would add a compile for ever less. A table under four blocks has
@@ -174,6 +178,18 @@ def table_widths(blocks_per_seq: int) -> "tuple[int, ...]":
     if blocks_per_seq < 4:
         return (blocks_per_seq,)
     return (-(-blocks_per_seq // 4), -(-blocks_per_seq // 2), blocks_per_seq)
+
+
+def default_prefill_chunk(max_tokens: int, block_size: int) -> int:
+    """The prefill chunk an engine is given no length for:
+    ``llm_prefill_chunk``, no longer than a row's table
+    (``max_tokens``), in whole paged blocks (and so in whole blocks of a
+    diffusion model, which the constructor holds to divide a paged
+    block)."""
+    from ray_tpu._private.config import GLOBAL_CONFIG
+
+    chunk = min(int(GLOBAL_CONFIG.llm_prefill_chunk), max_tokens)
+    return max(chunk // block_size, 1) * block_size
 
 
 class _PassClock:
@@ -226,16 +242,16 @@ class LLMEngine:
         self.max_batch = int(max_batch_size)
         self.max_len = int(max_seq_len or self.config.max_seq_len)
         self.block_size = int(block_size or GLOBAL_CONFIG.llm_block_size)
-        self.prefill_chunk_len = int(
-            prefill_chunk or GLOBAL_CONFIG.llm_prefill_chunk)
-        # Table width: blocks covering max_len, rounded up. The prefill
-        # program takes the whole table; a decode step the narrowest of
-        # ``_widths`` that holds its longest row's, so the decode program
-        # exists once a width, at [max_batch, width * block_size]
-        # attention width.
+        # Table width: blocks covering max_len, rounded up. A decode
+        # step takes the narrowest of ``_widths`` that holds its longest
+        # row's table and a prefill chunk the narrowest that holds its
+        # request's, so each program exists once a width, at
+        # [max_batch or 1, width * block_size] attention width.
         self.blocks_per_seq = -(-self.max_len // self.block_size)
         self.max_tokens = self.blocks_per_seq * self.block_size
         self._widths = table_widths(self.blocks_per_seq)
+        self.prefill_chunk_len = int(prefill_chunk or default_prefill_chunk(
+            self.max_tokens, self.block_size))
         if num_blocks is None:
             # Default pool: every row can hold a full-length sequence
             # (+ scratch). Smaller pools oversubscribe and lean on
@@ -288,7 +304,7 @@ class LLMEngine:
         if self.max_batch >= _MANY_ROWS \
                 and sys.getswitchinterval() > _SWITCH_INTERVAL_S:
             sys.setswitchinterval(_SWITCH_INTERVAL_S)
-        self._build_decode_programs()
+        self._build_programs()
         _LIVE.add(self)
         self._loop_thread = threading.Thread(
             target=self._engine_loop, name="llm-paged-engine", daemon=True)
@@ -306,19 +322,21 @@ class LLMEngine:
         return self._family.make_engine_prefill_chunk(
             self.config, self.block_size, self.prefill_chunk_len)
 
-    def _build_decode_programs(self) -> None:
-        """The decode program at every width, before the loop takes a
+    def _build_programs(self) -> None:
+        """Both programs at every width, before the loop takes a
         request: a width first met while serving would compile while
-        rows wait. Each is run once on rows that are all inactive (they
-        write the scratch block, advance no state, and their samples
-        are thrown away); the key and the expert counters the engine
-        holds are neither donated nor replaced, so a seeded request
-        samples what it would have without these runs. Built in turn:
-        from a warm cache a width costs 0.4 s, nearly all of it tracing
-        in Python, which threads do not share (three at once took 1.7 s
-        for 1.2 on the chip, PR 34). Lowered and compiled by name
-        first: the call then finds the program, and the two together
-        take 0.4 s where the call alone took 0.6."""
+        rows wait. The decode program is run once on rows that are all
+        inactive (they write the scratch block, advance no state, and
+        their samples are thrown away), the prefill program on a chunk
+        that is all padding (it writes the scratch block, no ring, and
+        zeros over the zeroed state of row slot 0); the key and the expert
+        counters the engine holds are neither donated nor replaced, so
+        a seeded request samples what it would have without these runs.
+        Built in turn: from a warm cache a width costs 0.4 s, nearly
+        all of it tracing in Python, which threads do not share (three
+        at once took 1.7 s for 1.2 on the chip, PR 34). Lowered and
+        compiled by name first: the call then finds the program, and
+        the two together take 0.4 s where the call alone took 0.6."""
         for width in self._widths:
             rows = self._family.pack_decode_rows(self.max_batch, width, ())
             args = (self.params, self._pool, rows, self._key,
@@ -326,6 +344,18 @@ class LLMEngine:
             with jax_compat.set_mesh(self._mesh):
                 self._decode_step.lower(*args).compile()
                 _, self._pool, _, _ = self._decode_step(*args)
+            chunk = self._family.pack_prefill_chunk(
+                self.prefill_chunk_len, width, (), 0, (), 0)
+            args = (self.params, self._pool, chunk, self._expert_stats)
+            with jax_compat.set_mesh(self._mesh):
+                self._prefill_step.lower(*args).compile()
+                _, self._pool, _ = self._prefill_step(*args)
+
+    def _rung(self, blocks: int) -> int:
+        """The narrowest of ``_widths`` that holds a table of ``blocks``:
+        what a decode step (its longest live row's) and a prefill chunk
+        (its request's) attend over."""
+        return next(w for w in self._widths if w >= blocks)
 
     def _new_pool(self) -> dict:
         """The family's cache, zeroed: one dict, donated to every step.
@@ -651,6 +681,8 @@ class LLMEngine:
                 if status == "ok":
                     start = req.prefilled
                     table = list(req.block_table)
+                    # As far as the request has it now.
+                    width = self._rung(len(table))
             if status == _UNREAD and not self._read_unread():
                 return True  # the read failed: every request with it
         if status == "shed":
@@ -666,7 +698,7 @@ class LLMEngine:
 
         with tracing.phase("engine.prefill.launch", req=req.rid, tokens=n):
             chunk = self._family.pack_prefill_chunk(
-                self.prefill_chunk_len, self.blocks_per_seq,
+                self.prefill_chunk_len, width,
                 req.context[start:start + n], start, table, req.slot)
             try:
                 with jax_compat.set_mesh(self._mesh):
@@ -829,7 +861,7 @@ class LLMEngine:
         # As held now: a row sealed while the step runs loses its.
         slots = [req.slot for req in active]
         longest = max(len(req.block_table) for req in active)
-        width = next(w for w in self._widths if w >= longest)
+        width = self._rung(longest)
         row_of = self._family.row_of
         rows = self._family.pack_decode_rows(
             self.max_batch, width,

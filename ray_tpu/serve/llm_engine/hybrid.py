@@ -13,7 +13,7 @@ of five kinds over THREE caches, one dict donated through every step.
   blocks a row for the window layers. Position ``p`` of the request in
   row slot ``r`` lies at ``[layer, r, p % ring]``; ``ring`` is the
   window, one prefill chunk and one block, rounded up to whole blocks
-  (``ring_positions``: 560 at 512 + 32 + 16), whatever the context: a
+  (``ring_positions``: 656 at 512 + 128 + 16), whatever the context: a
   chunk's first token still reads the window - 1 positions before it
   after the chunk's last is written. Which position a ring entry holds
   follows from the newest position written, so nothing is ever zeroed:
@@ -48,6 +48,7 @@ from ray_tpu.models import phi4flash as phi
 from ray_tpu.serve.llm_engine.model import (
     Family,
     pack_decode_rows,
+    row_beside_zeros,
     row_tokens,
     sample_next,
 )
@@ -242,11 +243,7 @@ def forward(params: dict, cache: dict, tokens, steps: _Steps,
     x, _ = lax.scan(back, x, (params["back"],
                               jnp.arange(config.back_periods)))
     if logits_at is not None:
-        # One position of each row, beside a row of zeros: a lone row
-        # against the table is a matrix-vector product, which the chip's
-        # compiler lowers as a float32 multiply-reduce over a float32
-        # copy of the whole table (as model.py's lone query row).
-        x = jnp.stack([x[:, logits_at], jnp.zeros_like(x[:, 0])], axis=1)
+        x = row_beside_zeros(x, logits_at)
     x = phi.layer_norm(x, params["final_norm"], eps, config.dtype)
     # The tied head, with the table as the product's LEFT operand (its
     # rows contracted as they lie): as the right one the chip's compiler
@@ -314,8 +311,9 @@ def make_engine_decode_step(config, block_size: int):
 
 
 def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
-    """The ONE prefill program, on ``pack_prefill_chunk``'s array; only
-    the logits of ``last_idx`` are computed."""
+    """The prefill program (one a table width the engine hands it), on
+    ``pack_prefill_chunk``'s array; only the logits of ``last_idx`` are
+    computed."""
     positions_at, table_at = 3 + chunk_len, 3 + 2 * chunk_len
 
     @functools.partial(jax.jit, donate_argnums=(1,))

@@ -26,8 +26,11 @@ reading and writing the PAGED pool:
   the same code, with a row of zeros beside a decode step's lone query
   row so that the product stays a matrix product;
 - **fixed shapes**: batch ``B``, table width ``M`` and chunk length
-  ``C`` are compile-time constants — ONE decode program and ONE
-  prefill program total, every step hits the jit cache;
+  ``C`` are compile-time constants: one decode program and one prefill
+  program a table width the engine hands them (``engine.table_widths``),
+  every step hits the jit cache;
+- **the head on one row**: a prefill chunk computes the final norm and
+  the logits of the one position that is read (its last real token);
 - **donation**: the pool is donated through every call (decode updates
   in place in HBM); on TPU wrap the calls in
   ``jax_compat.set_mesh(mesh)`` and the same jitted fns become pjit
@@ -245,15 +248,28 @@ def _expert_block(layer: dict, x: jax.Array, config):
     return x + moe.expert_ffn(layer, normed, combine, config.dtype), idx
 
 
+def row_beside_zeros(x: jax.Array, at: jax.Array) -> jax.Array:
+    """[B, T, E] -> [B, 2, E]: position ``at`` of each row, for a final
+    norm and a head on the ONE position that is read (a chunk of 128
+    tokens would else make 128 rows of the whole vocabulary in float32
+    to return one; the caller takes ``[:, 0]`` of the logits), beside a
+    row of zeros: a lone row against the head is a matrix-vector
+    product, which the chip's compiler lowers as a float32
+    multiply-reduce over a float32 copy of the whole table (see
+    ``_paged_attention_block``'s lone query row)."""
+    return jnp.stack([x[:, at], jnp.zeros_like(x[:, 0])], axis=1)
+
+
 def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
                    positions: jax.Array, block_tables: jax.Array,
                    config, block_size: int,
                    n_valid: "jax.Array | None" = None,
-                   busy: "jax.Array | None" = None):
+                   busy: "jax.Array | None" = None,
+                   logits_at: "jax.Array | None" = None):
     """Shared prefill/decode forward over the paged pool. Returns
-    (logits [B, T, V] f32, updated pool, expert counts, routing). The
-    pool is part of the scan's carry, so every layer updates the one
-    (donated) buffer.
+    (logits [B, T, V] f32, or [B, V] of position ``logits_at`` alone;
+    updated pool, expert counts, routing). The pool is part of the
+    scan's carry, so every layer updates the one (donated) buffer.
 
     The feed-forward is the configuration's: dense SwiGLU, or the
     routed experts. For those, ``counts`` is ``moe.routing_counts``
@@ -293,10 +309,14 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
     (x, pool_k, pool_v, counts), routing = lax.scan(
         layer_step, (x, pool["k"], pool["v"], counts),
         (params["layers"], jnp.arange(config.num_layers)))
+    if logits_at is not None:
+        x = row_beside_zeros(x, logits_at)
     x = llama.rms_norm(x, params["final_norm"], config.rms_norm_eps)
     logits = jnp.einsum("ble,ev->blv", x,
                         params["lm_head"].astype(config.dtype),
                         preferred_element_type=jnp.float32)
+    if logits_at is not None:
+        logits = logits[:, 0]
     return logits, {"k": pool_k, "v": pool_v}, counts, routing
 
 
@@ -340,12 +360,12 @@ def _prefill_body(config, block_size: int):
                       n_valid, last_idx, expert_stats=None):
         # tokens [1, C]; positions [1, C]; block_table [1, M];
         # n_valid/last_idx scalars (chunk padding past n_valid goes to
-        # scratch; last_idx indexes the final REAL token's logits).
+        # scratch; last_idx indexes the final REAL token, the one
+        # position whose logits are computed).
         logits, pool, counts, _ = _forward_paged(
             params, pool, tokens, positions, block_table, config,
-            block_size, n_valid=n_valid)
-        return logits[0, last_idx, :], pool, \
-            _accumulated(expert_stats, counts)
+            block_size, n_valid=n_valid, logits_at=last_idx)
+        return logits[0], pool, _accumulated(expert_stats, counts)
 
     return prefill_chunk
 
@@ -359,9 +379,10 @@ def make_decode_step(config, block_size: int):
 
 
 def make_prefill_chunk(config, block_size: int):
-    """The ONE prefill program: a fixed-length chunk of one request's
-    prompt scatters into its block table; only the final chunk's
-    ``last_idx`` logits row is consumed (the first generated token)."""
+    """The prefill program (one a table width it is handed): a
+    fixed-length chunk of one request's prompt scatters into its block
+    table; only the ``last_idx`` logits row is computed, and only the
+    final chunk's is consumed (the first generated token)."""
     return jax.jit(_prefill_body(config, block_size), donate_argnums=(1,))
 
 

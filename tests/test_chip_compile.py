@@ -127,8 +127,9 @@ def _lower_paged_step(program, config, batch, block, table, chip,
     carried), lowered on shapes placed on the described chip; the
     pool's shape beside it. A sparse configuration's step carries its
     expert accumulator. ``width``: the blocks of a row's table the
-    engine's decode step is given (``engine.table_widths``; the pool
-    stays ``table`` blocks a row); ``prev``: with the step before's
+    engine's decode step or prefill chunk is given
+    (``engine.table_widths``; the pool stays ``table`` blocks a row);
+    the chunk is the default's length; ``prev``: with the step before's
     tokens ``[batch]`` as the engine passes them (without: the
     five-argument call of ``benchmark/sizing.py``)."""
     from ray_tpu._private.config import GLOBAL_CONFIG
@@ -158,7 +159,8 @@ def _lower_paged_step(program, config, batch, block, table, chip,
     elif program == "engine_prefill_chunk":
         lowered = paged_model.make_engine_prefill_chunk(
             config, block, chunk).lower(
-                params, pool, on_chip((2 + 2 * chunk + table,)), stats)
+                params, pool, on_chip((2 + 2 * chunk + (width or table),)),
+                stats)
     elif program == "decode_step":
         lowered = paged_model.make_decode_step(config, block).lower(
             params, pool, on_chip((batch, 1)), on_chip((batch,)),
@@ -310,6 +312,116 @@ def test_decode_step_at_each_table_width_on_v5e(v5e_chip, model, width):
         assert f"[2048,16,{kv},128]" not in text
 
 
+def _sdar(num_layers=2):
+    """``benchmark/configs/sdar-30b-a3b-serve-1chip.json`` as the
+    harness builds it: SDAR-30B-A3B's widths, 2 of the cell's 7 layers."""
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=151936, hidden_size=2048, intermediate_size=768,
+        num_layers=num_layers, num_heads=32, num_kv_heads=4, head_dim=128,
+        max_seq_len=2048, rope_theta=1e6, rms_norm_eps=1e-6,
+        num_experts=128, experts_per_token=8, norm_topk_prob=True,
+        qk_norm="head", block_length=4, denoising_steps=2,
+        mask_token_id=151669)
+
+
+@pytest.mark.parametrize("width", [32, 64, 128])
+@pytest.mark.parametrize("model", ["mistral", "olmoe", "sdar"])
+def test_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, model, width):
+    """The engine's prefill program at the default chunk of 128 tokens
+    and at the three widths it is built at, for the three serve
+    configurations of identical layers (Mistral and OLMoE 16 rows, SDAR
+    32, over the whole pool). The pool is updated where it lies and
+    never copied; the head runs on the one row that is read, so nothing
+    the size of a chunk's logits exists (float32 ``[128, vocabulary]``:
+    16 MB for Mistral, 78 for SDAR); the scores are as wide as the rung
+    and no wider; no expert tensor is widened or transposed; and the
+    temporaries stay under 64 MiB."""
+    import re
+
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.serve.llm_engine.engine import table_widths
+
+    assert width in table_widths(128)
+    chunk = GLOBAL_CONFIG.llm_prefill_chunk
+    config, rows = {"mistral": (_mistral_serve(), 16),
+                    "olmoe": (_olmoe(2), 16), "sdar": (_sdar(), 32)}[model]
+    lowered, pool_shape = _lower_paged_step(
+        "engine_prefill_chunk", config, rows, 16, 128, v5e_chip, width=width)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
+    text = compiled.as_text()
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    vocabulary, positions = config.vocab_size, width * 16
+    assert re.search(rf"\[(1,)?{chunk},{vocabulary}\]", text) is None
+    assert re.search(rf"f32\[(1,)?2,{vocabulary}\]", text) is not None
+    # The chunk's scores: every query row against the rung's positions.
+    assert re.search(rf"f32\[[0-9,]*{chunk},{positions}\]", text) is not None
+    if width < 128:
+        assert re.search(rf"f32\[(\d+,){{2,}}{chunk},2048\]", text) is None
+    assert re.search(
+        rf"= f32\[({positions},16|16,{positions}),{config.num_kv_heads},128\]",
+        text) is None
+    if config.num_experts:
+        e, m = config.num_experts, config.intermediate_size
+        assert re.search(rf"f32\[(\d+,)?{e},(2048,{m}|{m},2048)\]",
+                         text) is None
+
+
+@pytest.mark.parametrize("width", [64, 128, 256])
+def test_hybrid_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, width):
+    """Phi-4-mini-flash's prefill program (published widths, 32 rows, a
+    table of 256 blocks of 16; 8 of its 32 layers) at the default chunk
+    and its three widths: the three caches updated where they lie, the
+    rings as long as the window, the chunk and a block (656 positions a
+    row), the one pool gathered at the chunk's width for its one
+    request, and logits of one row of the 200,064 words."""
+    import re
+
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.models import phi4flash
+    from ray_tpu.serve.llm_engine import hybrid
+
+    config = phi4flash.Phi4FlashConfig(num_layers=8)
+    rows, block, table = 32, 16, 256
+    chunk = GLOBAL_CONFIG.llm_prefill_chunk
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: hybrid.FAMILY.init_params(
+        config, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
+        config, 1 + rows * table, block, rows, chunk)))
+    assert cache["win_k"].shape[2] == 512 + chunk + block == 656
+    compiled = hybrid.make_engine_prefill_chunk(config, block, chunk).lower(
+        params, cache,
+        on_chip(hybrid.pack_prefill_chunk(chunk, width, (), 0, (), 0),
+                jnp.int32), None).compile()
+    memory = compiled.memory_analysis()
+    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
+                      for c in jax.tree.leaves(cache))
+    assert memory.alias_size_in_bytes >= cache_bytes
+    # The float32 scores of the 40 heads (116 MiB at the whole width).
+    assert memory.temp_size_in_bytes < 160 * 2 ** 20
+    text = compiled.as_text()
+    positions = width * block
+    assert f"bf16[{width},{block},1280]" in text
+    assert f"f32[1,40,{chunk},{positions}]" in text
+    assert re.search(rf"\[(1,)?{chunk},200064\]", text) is None
+    assert "f32[1,2,200064]" in text
+    assert [line for line in text.splitlines()
+            if " copy(" in line and "= bf16[1,8193,16,1280]" in line] == []
+    if width < table:
+        assert re.search(r"\[[0-9,]*4096[0-9,]*\]", text) is None
+
+
 def _memory_of(compiled) -> tuple:
     memory = compiled.memory_analysis()
     return (memory.alias_size_in_bytes, memory.temp_size_in_bytes,
@@ -364,7 +476,7 @@ def test_hybrid_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip,
     from ray_tpu.serve.llm_engine import hybrid
 
     config = phi4flash.Phi4FlashConfig(num_layers=8)
-    rows, block, table, chunk = 32, 16, 256, 32
+    rows, block, table, chunk = 32, 16, 256, 128
 
     def on_chip(tree, dtype=None):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
@@ -406,7 +518,7 @@ def test_hybrid_decode_step_at_each_table_width_on_v5e(v5e_chip, width):
 
     assert width in table_widths(256)
     config = phi4flash.Phi4FlashConfig(num_layers=8)
-    rows, block, table, chunk = 32, 16, 256, 32
+    rows, block, table, chunk = 32, 16, 256, 128
 
     def on_chip(tree, dtype=None):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(

@@ -9,6 +9,7 @@ import dataclasses
 import threading
 import time
 
+import prefill_chunk_cases
 import pytest
 
 import ray_tpu
@@ -379,6 +380,10 @@ def test_waiting_deadline_seals_typed_llm_queue(paged_engine):
     engine = LLMEngine(paged_engine.config, paged_engine.params,
                        max_batch_size=1, max_seq_len=64, block_size=8,
                        prefill_chunk=8, seed=0)
+    # Every program exists once the constructor returns, so the hog's 40
+    # steps would be over in 11 ms: each waits, and the one row is held
+    # well past the parked request's budget.
+    engine._maybe_chaos_slow_step = lambda: time.sleep(0.01)
     try:
         hog = engine.submit([1, 2], max_new_tokens=40)
         parked = engine.submit([3, 4], max_new_tokens=4,
@@ -729,6 +734,86 @@ def test_failed_step_leaves_a_usable_key(fails_at):
 
 
 # ------------------------------------------------------- one step ahead
+
+
+@pytest.mark.parametrize("chunk", prefill_chunk_cases.WIDTHS,
+                         ids=prefill_chunk_cases.WIDTH_IDS)
+def test_greedy_tokens_do_not_depend_on_the_chunk_width(chunk):
+    """A prompt shorter than a chunk, one past a chunk and no multiple
+    of it and one of more than two chunks: the same tokens whether the
+    prompt goes in eights, in thirty-twos or in the default's 128."""
+    prefill_chunk_cases.same_tokens_at(_f32_tiny(), chunk)
+
+
+def test_a_preemption_inside_a_wide_chunks_prompt_resumes_exact():
+    prefill_chunk_cases.resumes_to_the_same_tokens(_f32_tiny())
+
+
+@pytest.mark.parametrize("max_tokens, block, want", [
+    (2048, 16, 128), (4096, 16, 128), (64, 8, 64), (24, 8, 24),
+    (96, 8, 96), (144, 48, 96), (512, 256, 256)])
+def test_the_default_chunk_is_clamped_to_the_table(max_tokens, block, want):
+    """The knob's 128 tokens, no longer than a row's table, in whole
+    paged blocks (so in whole blocks of a diffusion model too)."""
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.serve.llm_engine.engine import default_prefill_chunk
+
+    assert GLOBAL_CONFIG.llm_prefill_chunk == 128
+    got = default_prefill_chunk(max_tokens, block)
+    assert got == want and got % block == 0 and got <= max_tokens
+
+
+def test_an_engine_with_a_short_table_takes_the_table_for_its_chunk(
+        paged_engine):
+    """20 positions asked for are a table of 24: the chunk is the table,
+    and a prompt that fills most of it goes in one chunk to the tokens
+    the fixture's engine (chunks of 8) serves."""
+    from ray_tpu.serve.llm_engine import LLMEngine
+
+    prompt = list(range(1, 18))
+    want = paged_engine.result(
+        paged_engine.submit(prompt, max_new_tokens=5), timeout_s=120)
+    engine = LLMEngine(paged_engine.config, paged_engine.params,
+                       max_batch_size=2, max_seq_len=20, block_size=8,
+                       seed=0)
+    try:
+        assert (engine.max_tokens, engine.prefill_chunk_len) == (24, 24)
+        before = engine.engine_stats()["prefill_chunks"]
+        assert engine.result(engine.submit(prompt, max_new_tokens=5),
+                             timeout_s=120) == want
+        assert engine.engine_stats()["prefill_chunks"] - before == 1
+    finally:
+        engine.shutdown()
+
+
+@pytest.mark.parametrize("kind", ["dense", "olmoe", "hybrid"])
+def test_the_prefill_program_makes_the_head_on_one_row(kind):
+    """A chunk returns the logits of its last real token alone: the
+    lowered program holds no ``[1, chunk, vocabulary]`` (nor ``[chunk,
+    vocabulary]``) tensor, in any dtype; the head's product is over the
+    one row and the row of zeros beside it."""
+    import re
+
+    import jax
+
+    from ray_tpu.serve.llm_engine import model as paged_model
+
+    # A vocabulary no other width of the tiny configurations equals.
+    config = dataclasses.replace(_step_ahead_config(kind), vocab_size=250)
+    family = paged_model.family(config)
+    block, chunk, table, rows = 4, 24, 16, 2
+    params = jax.eval_shape(
+        lambda: family.init_params(config, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: family.init_cache(
+        config, 1 + rows * table, block, rows, chunk))
+    text = family.make_engine_prefill_chunk(config, block, chunk).lower(
+        params, cache, family.pack_prefill_chunk(chunk, table, [1], 0, [1], 0),
+        None).as_text()
+    vocabulary = config.vocab_size
+    assert re.search(rf"tensor<(1x)?{chunk}x{vocabulary}x", text) is None
+    assert re.search(rf"tensor<{vocabulary}x(1x)?{chunk}x", text) is None
+    assert re.search(rf"tensor<(1x2x{vocabulary}|{vocabulary}x1x2)xf32>",
+                     text) is not None
 
 
 def _step_ahead_config(kind):

@@ -27,6 +27,7 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
+import prefill_chunk_cases
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -490,6 +491,19 @@ def test_engine_serves_a_sparse_model_and_counts_its_experts():
             assert output == [int(r.argmax()) for r in want]
     finally:
         engine.shutdown()
+
+
+@pytest.mark.parametrize("chunk", prefill_chunk_cases.WIDTHS,
+                         ids=prefill_chunk_cases.WIDTH_IDS)
+def test_greedy_tokens_do_not_depend_on_the_chunk_width(chunk):
+    """Every token of a chunk meets every expert whatever the chunk's
+    width: prompts under a chunk, past one and past two yield the same
+    tokens in eights, in thirty-twos and in the default's 128."""
+    prefill_chunk_cases.same_tokens_at(small(), chunk)
+
+
+def test_a_preemption_inside_a_wide_chunks_prompt_resumes_exact():
+    prefill_chunk_cases.resumes_to_the_same_tokens(small())
 
 
 def test_a_dense_engine_keeps_no_expert_accumulator(monkeypatch):

@@ -9,6 +9,7 @@ import threading
 import time
 
 import numpy as np
+import prefill_chunk_cases
 import pytest
 
 BLOCK, CHUNK, ROWS, TABLE = 4, 8, 4, 16      # 64 positions a row
@@ -604,6 +605,22 @@ def test_row_slots_are_given_back():
     sched.prefilling = None
     sched.active.append(second)
     assert sched.claim_prefill() is third and third.slot == 0
+
+
+@pytest.mark.parametrize("chunk", prefill_chunk_cases.WIDTHS,
+                         ids=prefill_chunk_cases.WIDTH_IDS)
+def test_greedy_tokens_do_not_depend_on_the_chunk_width(chunk):
+    """The ring is the window, a chunk and a block, so a chunk wider
+    than the window (8 positions here, the default's 128 in a chunk)
+    still reads the window before its first token; the state-space scan
+    carries through a chunk as it does through sixteen of them."""
+    prefill_chunk_cases.same_tokens_at(tiny(), chunk)
+
+
+def test_a_preemption_inside_a_wide_chunks_prompt_resumes_exact():
+    """A preempted hybrid request re-prefills from position 0, where
+    its first chunk resets the state its row slot held."""
+    prefill_chunk_cases.resumes_to_the_same_tokens(tiny())
 
 
 def test_the_family_is_looked_up_in_one_place():

@@ -26,6 +26,7 @@ import threading
 import jax
 import jax.numpy as jnp
 import numpy as np
+import prefill_chunk_cases
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -331,6 +332,19 @@ def test_the_engine_serves_what_the_reference_generates(
     assert got == by_reference(prompt, 10, remasking=rule,
                                denoising_steps=steps)
     assert len(got) == 10 and MASK not in got
+
+
+@pytest.mark.parametrize("chunk", prefill_chunk_cases.WIDTHS,
+                         ids=prefill_chunk_cases.WIDTH_IDS)
+def test_greedy_tokens_do_not_depend_on_the_chunk_width(chunk):
+    """A chunk holds whole blocks at every width (8, 32 and the
+    default's 128 are multiples of 4), and a prompt's remainder opens
+    the block in flight: the same tokens however the prompt went in."""
+    prefill_chunk_cases.same_tokens_at(CFG, chunk)
+
+
+def test_a_preemption_inside_a_wide_chunks_prompt_resumes_exact():
+    prefill_chunk_cases.resumes_to_the_same_tokens(CFG)
 
 
 def record_passes(engine) -> list:

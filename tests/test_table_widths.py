@@ -1,6 +1,7 @@
 """The decode step's table follows the longest live row: the engine hands
-each step the narrowest of ``engine.table_widths`` that holds it, and the
-program of every width exists before the first request. Tiny float32
+each step the narrowest of ``engine.table_widths`` that holds it, a
+prefill chunk the narrowest that holds its request's table, and both
+programs exist at every width before the first request. Tiny float32
 configurations of both families, blocks of 4: a table of 16 blocks has
 the widths 4, 8 and 16 (16, 32 and 64 positions)."""
 
@@ -83,6 +84,21 @@ def record_steps(engine, compare_logits=False):
     return seen
 
 
+def record_chunks(engine) -> list:
+    """Every prefill chunk the loop runs from now on: (its width in
+    blocks, its first position, its real tokens)."""
+    step, seen = engine._prefill_step, []
+    head = len(engine._family.pack_prefill_chunk(0, 0, (), 0, (), 0))
+
+    def recording(params, pool, chunk, expert_stats):
+        seen.append((len(chunk) - head - 2 * CHUNK,
+                     int(chunk[head + CHUNK]), int(chunk[0])))
+        return step(params, pool, chunk, expert_stats)
+
+    engine.__dict__["_prefill_step"] = recording
+    return seen
+
+
 def held_blocks(rows) -> int:
     """The longest table among a host array's rows (block 0 is the
     scratch block and the padding, never a request's)."""
@@ -109,24 +125,31 @@ def served(request):
     engine = make_engine(request.param)
     try:
         built = engine._decode_step._cache_size()
+        prefill = engine._prefill_step
+        prefill_built = prefill._cache_size()
         key_after_building = np.asarray(engine._key)
         seen = record_steps(engine, compare_logits=True)
+        chunks = record_chunks(engine)
         tokens = serve(engine)
         stats = engine.engine_stats()
         programs = seen.program._cache_size()
+        prefill_programs = prefill._cache_size()
     finally:
         engine.shutdown()
     whole = make_engine(request.param)
     try:
         whole._widths = (whole.blocks_per_seq,)
         whole_seen = record_steps(whole)
+        whole_chunks = record_chunks(whole)
         whole_tokens = serve(whole)
         whole_stats = whole.engine_stats()
     finally:
         whole.shutdown()
     return types.SimpleNamespace(
         family=request.param, engine=engine, seen=seen, tokens=tokens,
-        stats=stats, built=built, programs=programs,
+        stats=stats, built=built, programs=programs, chunks=chunks,
+        whole_chunks=whole_chunks, prefill_built=prefill_built,
+        prefill_programs=prefill_programs,
         key_after_building=key_after_building, whole_seen=whole_seen,
         whole_tokens=whole_tokens, whole_stats=whole_stats)
 
@@ -166,6 +189,21 @@ def test_every_step_has_the_narrowest_width_that_holds_its_rows(served):
                              if w >= held_blocks(rows))
 
 
+def test_every_chunk_has_the_narrowest_width_that_holds_its_table(served):
+    """A chunk attends over the rung that holds its request's table as
+    far as the chunk reaches: the long prompt's first two chunks (16
+    positions) at a quarter of the table, its last two at a half; the
+    engine held to the whole width answered the same
+    (``test_answers_do_not_depend_on_the_rung``)."""
+    assert sum(n for _, _, n in served.chunks) \
+        == served.stats["prefill_tokens"] == sum(len(p) for p, _ in REQUESTS)
+    for width, start, n in served.chunks:
+        assert width == next(w for w in served.engine._widths
+                             if w * BLOCK >= start + n)
+    assert [w for w, _, _ in served.chunks] == [4, 4, 8, 8, 4, 4]
+    assert {w for w, _, _ in served.whole_chunks} == {16}
+
+
 def test_counters_say_what_the_steps_read(served):
     """``kv_positions_read`` is rows x the step's width in positions,
     summed; ``decode_steps_narrow`` counts the steps under the whole
@@ -188,6 +226,7 @@ def test_no_program_is_built_after_the_constructor(served):
     """One program a width when the constructor returns, and the same
     count after a run that visited every one of them."""
     assert served.built == served.programs == 3
+    assert served.prefill_built == served.prefill_programs == 3
     assert set(served.seen.widths) == {4, 8, 16}
 
 
@@ -263,11 +302,11 @@ def test_a_fresh_pool_meets_the_programs_every_later_pool_meets():
         serve(engine)
         assert set(seen.widths) == {4, 8, 16}
         assert seen.program._cache_size() == 3
-        assert engine._prefill_step._cache_size() == 1
+        assert engine._prefill_step._cache_size() == 3
         engine._reset_after_failure(RuntimeError("a step failed"))
         serve(engine)
         assert seen.program._cache_size() == 3
-        assert engine._prefill_step._cache_size() == 1
+        assert engine._prefill_step._cache_size() == 3
     finally:
         engine.shutdown()
 
