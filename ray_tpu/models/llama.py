@@ -29,12 +29,18 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.parallel.ring_attention import (
     plain_attention,
     ring_attention,
     ring_attention_gspmd,
 )
+
+# checkpoint_name tags of q, k, v as _attention_block hands them to the
+# attention; remat_policy="attention" keeps them (and the flash
+# kernel's SAVED_NAMES) through the layer scan.
+ATTENTION_SAVED = ("attention_q", "attention_k", "attention_v")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,10 +57,17 @@ class LlamaConfig:
     rms_norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # Remat policy: "full" recomputes everything (min memory);
-    # "dots" saves matmul outputs and recomputes elementwise only —
-    # much less recompute FLOPs for ~2x the activation memory.
-    remat_policy: str = "full"
+    # What the layer scan keeps for the backward besides a layer's
+    # input (8 KB a token a layer at hidden 4096 in bf16).
+    # "attention": q, k, v as the attention takes them (after QK-norm
+    # and rope) and, under attention="flash", the kernel's output and
+    # lse: 20 KB a token a layer more (32 + 8 heads of 128), and the
+    # backward starts a layer at the flash backward kernels instead of
+    # running the projections and the forward kernel again; the norms,
+    # wo and the MLP are recomputed.
+    # "full": nothing but the input, everything recomputed: the floor
+    # for a model at its memory's edge.
+    remat_policy: str = "attention"
     # "plain" (full attention), "flash" (pallas blockwise kernel), or
     # "ring" (context parallel over sp axis — requires running inside
     # shard_map with an "sp" axis; "ring_local" when already inside).
@@ -305,6 +318,13 @@ def _attention_block(layer: dict, x: jax.Array, positions: jax.Array,
         tp = jax.lax.psum(1, tp_axis)
         h, kv = h // tp, kv // tp
     q, k, v = qkv_projections(layer, x, positions, config)
+    # Named here and not in qkv_projections, which the paged engine
+    # shares: the serving programs stay what they are.
+    q, k, v = (checkpoint_name(t, name)
+               for t, name in zip((q, k, v), ATTENTION_SAVED))
+    if config.remat:
+        # See forward's layer_step.
+        q, k, v = lax.optimization_barrier((q, k, v))
     if kv != h and config.attention != "flash":
         # flash_attention is GQA-native (kernels index head groups);
         # the other paths want materialized full-head kv.
@@ -365,6 +385,18 @@ def _moe_block(layer: dict, x: jax.Array,
     return x + out, aux
 
 
+def _remat_policy(name: str):
+    if name == "attention":
+        from ray_tpu.ops.flash_attention import SAVED_NAMES
+
+        return jax.checkpoint_policies.save_only_these_names(
+            *ATTENTION_SAVED, *SAVED_NAMES)
+    if name == "full":
+        return None
+    raise ValueError(
+        f"remat_policy={name!r}: expected 'attention' or 'full'")
+
+
 def forward(params: dict, tokens: jax.Array, config: LlamaConfig,
             positions: jax.Array | None = None,
             with_aux: bool = False, return_features: bool = False):
@@ -386,6 +418,16 @@ def forward(params: dict, tokens: jax.Array, config: LlamaConfig,
     def layer_step(carry, layer):
         x, aux_sum = carry
         x = _attention_block(layer, x, positions, config)
+        if config.remat:
+            # Two barriers a layer under remat, here and on q, k, v in
+            # _attention_block: the values the backward starts from
+            # exist once, whole, before what reads them. Without them
+            # the backward body's schedule, once it no longer runs the
+            # attention's forward, loses the MLP input's place in fast
+            # memory and the weight-gradient products slow by a third:
+            # the step came out SLOWER than under "full" (v5e, PERF.md
+            # section 6, PR 41). They change no value.
+            x = lax.optimization_barrier(x)
         if moe:
             x, aux = _moe_block(layer, x, config)
             aux_sum = aux_sum + aux
@@ -395,17 +437,8 @@ def forward(params: dict, tokens: jax.Array, config: LlamaConfig,
 
     step = layer_step
     if config.remat:
-        policy = None
-        if config.remat_policy == "dots":
-            # Saves weight-activation matmul outputs, recomputes
-            # elementwise AND the [L, L] attention scores (those are the
-            # batched dots — saving them would be O(B·H·L²)).
-            policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-        elif config.remat_policy != "full":
-            raise ValueError(
-                f"remat_policy={config.remat_policy!r}: expected 'full' "
-                f"or 'dots'")
-        step = jax.checkpoint(layer_step, prevent_cse=False, policy=policy)
+        step = jax.checkpoint(layer_step, prevent_cse=False,
+                              policy=_remat_policy(config.remat_policy))
     (x, aux_sum), _ = lax.scan(
         step, (x, jnp.zeros((), dtype=jnp.float32)), params["layers"])
     x = rms_norm(x, params["final_norm"], config.rms_norm_eps)

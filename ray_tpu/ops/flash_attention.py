@@ -44,6 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -368,10 +369,22 @@ def _flash_core(q, k, v, causal, block_q, block_k, interpret):
     return o
 
 
+# checkpoint_name tags of the two residuals only the kernel can make. A
+# jax.checkpoint policy that saves them (models/llama.py,
+# remat_policy="attention") starts its backward at the backward kernels;
+# without one the forward kernel runs again there.
+SAVED_NAMES = ("flash_o", "flash_lse")
+
+
 def _core_fwd(q, k, v, causal, block_q, block_k, interpret):
     # Only a differentiated call gets here.
     check_vmem_fit(q.shape[1], q.shape[2], q.dtype, backward=True)
     o, lse = _flash_fwd(q, k, v, causal, block_q, block_k, interpret)
+    # On the very values that go into the residuals: a name on the
+    # caller's transposed copy leaves these unnamed and the kernel
+    # runs twice.
+    o, lse = (checkpoint_name(t, name)
+              for t, name in zip((o, lse), SAVED_NAMES))
     return o, (q, k, v, o, lse)
 
 

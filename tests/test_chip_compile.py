@@ -32,14 +32,13 @@ fa = importlib.import_module("ray_tpu.ops.flash_attention")
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """Sharding on one chip of a described v5e:2x2. The persistent
+def v5e_devices():
+    """The four chips of a described v5e:2x2. The persistent
     compilation cache is off around these compiles: an entry written
     for a described device cannot be read back without the chip, and
     the next run would warn."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(
@@ -49,9 +48,17 @@ def v5e_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_devices):
+    """Sharding on one chip of the described v5e:2x2."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_devices[0])
 
 
 def _flash_grad(seq, heads, kv_heads, head_dim, chip):
@@ -565,6 +572,31 @@ def test_serving_params_never_hold_a_float32_expert_tensor_on_v5e(v5e_chip):
     assert memory.temp_size_in_bytes < 64 * 2 ** 20
     # bf16 throughout, to the tiling's padding of the small scales.
     assert 0 <= memory.output_size_in_bytes - 2 * config.num_params < 2 ** 20
+
+
+@pytest.mark.parametrize("cell, ceiling_gib", [
+    ("train-4k-1chip", 14.2), ("train-4k-fsdp2tp2", 14.9)])
+def test_train_step_fits_a_v5e_with_the_attention_kept(
+        v5e_devices, monkeypatch, capsys, cell, ceiling_gib):
+    """The two train configurations' whole steps, at the program's
+    default remat_policy, for the described chips: arguments plus
+    temporaries 14.00 GiB on one chip and 14.71 a chip on four (PR 41;
+    13.76 and 13.25 under "full") of the 15.75 a chip gives. A PR that
+    adds to what the layer scan keeps sees the memory here before the
+    chip does; the ceilings are those figures and a margin of 0.2."""
+    from benchmark import sizing, spec
+    from ray_tpu._private import jax_compat
+
+    # The kernels see the CPU backend during such a compile.
+    monkeypatch.setattr(jax_compat, "interpret_kernels", lambda: False)
+    sizing.size_train(spec.load_cell(cell), v5e_devices)
+    lines = [json.loads(line)
+             for line in capsys.readouterr().out.splitlines()]
+    step = next(x for x in lines if x.get("program") == "step")
+    assert step["arguments_plus_temporaries_gib"] < ceiling_gib, step
+    assert step["arguments_plus_temporaries_gib"] > 13.8, (
+        "under what \"full\" takes: is the default policy in force?", step)
+    assert lines[-1]["has_tpu_custom_call"], lines[-1]
 
 
 def test_vmem_rule_admits_the_main_path():

@@ -1,5 +1,9 @@
 """Llama model + sharded training-step tests on the virtual CPU mesh."""
 
+import contextlib
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,3 +127,69 @@ def test_param_axes_match_tree(tiny_cfg, tiny_params):
     axes = llama.param_logical_axes(tiny_cfg)
     jax.tree.map(lambda p, a: None, tiny_params, axes,
                  is_leaf=lambda x: isinstance(x, tuple))
+
+
+# ------------------------------------------------- the scan's remat policy
+
+
+def _flash_cfg(kv_heads, **kw):
+    return dataclasses.replace(
+        llama.LlamaConfig.tiny(), num_kv_heads=kv_heads, attention="flash",
+        dtype=jnp.float32, **kw)
+
+
+def _loss_of(cfg, tokens):
+    return lambda p: llama.loss_fn(p, tokens[:, :-1], tokens[:, 1:], cfg)
+
+
+@pytest.mark.parametrize("policy", ["attention", "full"])
+@pytest.mark.parametrize("kv_heads", [4, 2], ids=["equal", "grouped"])
+def test_remat_policy_changes_no_value(kv_heads, policy):
+    """What the scan keeps decides what is computed twice, never what:
+    loss and gradients under either policy are those of remat=False."""
+    base = _flash_cfg(kv_heads)
+    params = llama.init_params(base, jax.random.PRNGKey(0))
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0,
+                                base.vocab_size)
+    cfg = _flash_cfg(kv_heads, remat=True, remat_policy=policy)
+    want_loss, want = jax.jit(jax.value_and_grad(_loss_of(base, tokens)))(
+        params)
+    got_loss, got = jax.jit(jax.value_and_grad(_loss_of(cfg, tokens)))(
+        params)
+    np.testing.assert_allclose(got_loss, want_loss, rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("policy, forwards", [("attention", 1), ("full", 2)])
+@pytest.mark.parametrize("path", ["equal", "grouped", "shard_map"])
+def test_backward_runs_the_flash_forward_once(path, policy, forwards):
+    """The differentiated step holds the forward kernel once a layer
+    (the forward scan's body) when the scan keeps the kernel's o and
+    lse by name, and twice (again in the backward scan's body) under
+    "full". The names are set inside the kernel's custom_vjp rule and
+    have to survive the grouped path's vmap and the mesh path's
+    shard_map."""
+    cfg = _flash_cfg(4 if path == "equal" else 2, remat=True,
+                     remat_policy=policy)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    tokens = jnp.zeros((4, 33), dtype=jnp.int32)
+    mesh = contextlib.nullcontext()
+    if path == "shard_map":
+        mesh = jax.set_mesh(build_mesh(MeshConfig(dp=2, fsdp=2, tp=2)))
+    with mesh:
+        text = str(jax.make_jaxpr(jax.grad(_loss_of(cfg, tokens)))(params))
+    assert ("shard_map" in text) == (path == "shard_map")
+    kernels = re.findall(r"\bname=(flash_\w+)", text)
+    assert kernels.count("flash_fwd") == forwards, kernels
+    assert kernels.count("flash_bwd_dq") == 1, kernels
+    assert kernels.count("flash_bwd_dkv") == 1, kernels
+
+
+@pytest.mark.parametrize("policy", ["dots", "everything"])
+def test_unknown_remat_policy_raises(policy):
+    """"dots" went with PR 41: it had no caller and fits no cell."""
+    cfg = _flash_cfg(4, remat=True, remat_policy=policy)
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="remat_policy"):
+        llama.forward(params, jnp.zeros((1, 8), dtype=jnp.int32), cfg)

@@ -183,3 +183,28 @@ def test_pallas_calls_carry_their_kernel_name(kernel, traced):
 
     names = set(re.findall(r"\bname=(\w+)", str(traced())))
     assert kernel in names, names
+
+
+@pytest.mark.parametrize("saved, forwards", [(True, 1), (False, 2)])
+@pytest.mark.parametrize("kvh", [4, 2], ids=["equal", "grouped"])
+def test_checkpoint_policy_can_keep_the_kernels_residuals(kvh, saved,
+                                                          forwards):
+    """``SAVED_NAMES`` tag o and lse inside the custom_vjp's forward
+    rule: a jax.checkpoint that saves them differentiates to ONE
+    forward kernel, one that saves nothing runs it again, and the
+    gradients are the same."""
+    import re
+
+    from ray_tpu.ops.flash_attention import SAVED_NAMES
+
+    policy = jax.checkpoint_policies.save_only_these_names(
+        *SAVED_NAMES) if saved else None
+    q, k, v = _qkv(kvh=kvh)
+    grad = jax.grad(jax.checkpoint(_flash_loss, policy=policy),
+                    argnums=(0, 1, 2))
+    kernels = re.findall(r"\bname=(flash_\w+)",
+                         str(jax.make_jaxpr(grad)(q, k, v)))
+    assert kernels.count("flash_fwd") == forwards, kernels
+    for got, want in zip(grad(q, k, v),
+                         jax.grad(_flash_loss, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
