@@ -23,7 +23,13 @@ file gives, the paged engine's two programs over shuffled block tables
 (prefill in chunks, then batched decode, for the file's probe lengths
 and for one context as long as the table) against the configuration's
 plain float32 reference: logits, and for a sparse model the expert
-choices that differ.  ``--rehearse`` is the only way
+choices that differ (for a latent-attention configuration every row of
+the engine busy, and a context that ends at each of the table's three
+widths).  ``--cell <workload> --round-weights <dtype>`` runs instead
+that serve cell of the benchmark as ``benchmark/run.py`` does, with the
+replica's weights rounded and its check's left as the seed gives them:
+the control of the cell's ``logit_atol``, which has to come out not
+correct.  ``--rehearse`` is the only way
 this script accepts a CPU: tiny sizes, interpreted kernels, to find wrong
 paths and arguments before any chip time is spent.  Every phase fails
 the run; nothing is caught and continued.
@@ -58,12 +64,19 @@ ARGS.add_argument("--paged-logits", metavar="CONFIG_JSON", default=None,
                        "engine's programs against the configuration's "
                        "plain reference, logits and expert choices")
 ARGS.add_argument("--round-weights", metavar="DTYPE", default=None,
-                  help="with --paged-logits of a hybrid or block-diffusion "
-                       "configuration: the "
+                  help="with --paged-logits of a hybrid, block-diffusion or "
+                       "latent-attention configuration: the "
                        "programs run on weights rounded through this dtype "
                        "(float8_e4m3fn: the nearest precision below "
                        "bfloat16) while the reference keeps the weights as "
                        "they are; the comparison then has to FAIL")
+ARGS.add_argument("--cell", metavar="WORKLOAD", default=None,
+                  help="instead of the phases, with --round-weights: the "
+                       "benchmark's own run of that serve cell "
+                       "(benchmark/run.py's) with the weights the REPLICA "
+                       "builds rounded and the weights its check rebuilds "
+                       "as the seed gives them: the control of the cell's "
+                       "logit_atol, which has to come out not correct")
 ARGS.add_argument("--seed", type=int, default=0)
 
 # Stated tolerances. bf16 keeps 8 bits of mantissa (2^-8 = 4e-3 a
@@ -684,6 +697,9 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
     if paged_model.family(model_config).recurrent:
         return phase_hybrid_logits(config, model_config, model, reference,
                                    seed, rehearse, device, round_weights)
+    if getattr(model_config, "family", "") == "latent":
+        return phase_latent_logits(config, model_config, model, reference,
+                                   seed, rehearse, device, round_weights)
     if model_config.block_length:
         return phase_block_logits(config, model_config, model, reference,
                                   seed, rehearse, device, round_weights)
@@ -1265,6 +1281,310 @@ def phase_block_logits(config: dict, model_config, model: dict, reference,
               f"{worst_gap} under the reference's best logit")
 
 
+# Latent attention under hyper-connections (PR 44), on the v5e at the
+# published widths: the worst difference of a logit over the reference's
+# logit standard deviation. The cell's own measure (how far under the
+# reference's best logit the program's choice lies) is held to the
+# configuration's ``probes.logit_atol``, which says how it was set.
+# The share of expert choices the reference did not make too: 3.3, 3.4
+# and 3.7% of 313,360 sound (1.8% in the first expert layer, 5.5% in
+# the fifth: a token that took another expert once routes differently
+# after), 33.8% with float8-rounded weights (three seeds and one). A
+# rehearsal's toy of two expert layers reads 0.5% and 9.3%.
+LATENT_SAME_EXPERTS = 0.15
+LATENT_DIFFERING_SHARE = 0.10
+REHEARSED_DIFFERING_SHARE = 0.03
+
+
+def phase_latent_logits(config: dict, model_config, model: dict, reference,
+                        seed: int, rehearse: bool, device: dict,
+                        round_weights: "str | None") -> None:
+    """A latent-attention configuration at its real size, every row of
+    the engine busy: the file's ``probes.smoke_prompt_lengths`` (inside a
+    chunk and a paged block, across each), one context that ENDS at each
+    of the table's three widths (a quarter, a half, the whole), the rest
+    a few chunks long. Each is prefilled chunk by chunk (expanded, at
+    the narrowest width that holds it, as the engine hands a chunk its
+    table), then all rows decode their last ``probes.max_new_tokens``
+    positions together (absorbed), teacher-forced. Compared with the
+    float32 reference: the probes' decoded positions, and the last two
+    chunks' worth of each long context, the reference computed with its
+    queries in blocks and its head on the tail alone."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.serve.llm_engine import latent
+    from ray_tpu.serve.llm_engine import model as paged_model
+    from ray_tpu.serve.llm_engine.engine import table_widths
+
+    engine = config["engine"]
+    rows, max_len = engine["max_batch_size"], engine["max_seq_len"]
+    block = engine.get("block_size") or GLOBAL_CONFIG.llm_block_size
+    chunk = probe_chunk(engine, block)
+    width = -(-max_len // block)
+    widths = table_widths(width)
+    steps = config["probes"]["max_new_tokens"]
+    atol = config["probes"]["logit_atol"]
+    ends = [w * block for w in widths]
+    # The cell's own probe is one long context (the check's logits leave
+    # room for no more); the lengths that straddle a chunk and a paged
+    # block are this phase's, under their own key.
+    lengths = list(config["probes"].get(
+        "smoke_prompt_lengths", config["probes"]["prompt_lengths"]))[
+            :max(0, rows - len(ends))]
+    tail = min(2 * chunk, ends[0])
+    rng = np.random.default_rng([seed, 44])
+    filler = rng.integers(chunk, max(12 * chunk, chunk + 1),
+                          max(0, rows - len(lengths) - len(ends)))
+    filler = np.minimum(filler, max_len - steps)
+    contexts = [rng.integers(1, model_config.vocab_size, int(n))
+                for n in (*(n + steps for n in lengths), *ends,
+                          *(n + steps for n in filler))]
+    prefilled = [len(c) - steps for c in contexts]
+    long_rows = range(len(lengths), len(lengths) + len(ends))
+    compared = range(len(lengths) + len(ends))
+
+    served = paged_model.serving_params(model_config, None, seed)
+    if round_weights:
+        served = round_mantissa(served, round_weights)
+    cache = latent.init_cache(model_config, 1 + rows * width, block, rows,
+                              chunk)
+    say("latent", config=config["name"], layers=model_config.num_layers,
+        params=model_config.num_params, rows=rows, table=max_len,
+        contexts=[len(c) for c in contexts], round_weights=round_weights,
+        cache={k: [list(v.shape), str(v.dtype)] for k, v in cache.items()},
+        cache_gib=round(sum(v.nbytes for v in cache.values()) / 2 ** 30, 3),
+        device_bytes_in_use=device_bytes())
+    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
+    tables = np.zeros((rows, width), np.int32)
+    for turn in range(width):                 # no table is contiguous
+        for i, context in enumerate(contexts):
+            if turn < -(-len(context) // block):
+                tables[i, turn] = deck.pop()
+    shown_chunk = jax.jit(
+        lambda params, cache, tokens, positions, table, n_valid:
+        latent.forward(params, cache, tokens, positions, table, model_config,
+                       block, absorbed=False, n_valid=n_valid),
+        donate_argnums=(1,))
+    shown_step = jax.jit(
+        lambda params, cache, tokens, positions, tables:
+        latent.forward(params, cache, tokens, positions[:, None], tables,
+                       model_config, block, absorbed=True),
+        donate_argnums=(1,))
+
+    def rung(positions: int) -> int:
+        return next(w for w in widths if w * block >= positions)
+
+    got = [{} for _ in contexts]               # position -> logits row
+    chosen = [{} for _ in contexts]            # position -> [layers, k]
+    for i, context in enumerate(contexts):
+        first_kept = len(context) - tail if i in long_rows \
+            else prefilled[i] - 1
+        table = jnp.asarray(tables[i:i + 1, :rung(prefilled[i])])
+        for start in range(0, prefilled[i], chunk):
+            n = min(chunk, prefilled[i] - start)
+            logits, cache, _, routing = shown_chunk(
+                served, cache, *chunk_inputs(context, start, n, chunk),
+                table, np.int32(n))
+            if i in compared:
+                routing = np.asarray(routing)
+                for j in range(n):
+                    chosen[i][start + j] = routing[:, 0, j]
+                if start + n > first_kept:
+                    logits = np.asarray(logits[0], np.float32)
+                    for j in range(n):
+                        if start + j >= first_kept:
+                            got[i][start + j] = logits[j]
+    for step in range(steps):
+        last = np.zeros((rows, 1), np.int32)
+        positions = np.zeros((rows,), np.int32)
+        for i, context in enumerate(contexts):
+            last[i, 0] = context[prefilled[i] + step]
+            positions[i] = prefilled[i] + step
+        step_width = rung(int(positions.max()) + 1)
+        logits, cache, _, routing = shown_step(
+            served, cache, jnp.asarray(last), jnp.asarray(positions),
+            jnp.asarray(tables[:, :step_width]))
+        logits, routing = np.asarray(logits[:, 0], np.float32), \
+            np.asarray(routing)
+        for i in compared:
+            got[i][int(positions[i])] = logits[i]
+            chosen[i][int(positions[i])] = routing[:, i, 0]
+    check(bool(jax.jit(lambda pool: jnp.isfinite(pool).all())(
+        cache["latent"])), "the pool is not finite")
+    del cache, shown_chunk, shown_step
+    if round_weights:
+        del served
+        gc.collect()
+        served = paged_model.serving_params(model_config, None, seed)
+    params = served            # the reference's: as the seed gives them
+    gc.collect()
+    say("latent", programs="prefill chunks (expanded) over shuffled tables "
+        f"at the widths {ends}, then {steps} decode steps (absorbed) of "
+        f"{rows} busy rows", positions_compared=sum(len(g) for g in got),
+        device_bytes_in_use=device_bytes())
+
+    short = contexts[:len(lengths)]
+    want, theirs, offsets = [], [], []
+    if short:
+        padded = np.zeros((len(short), -(-max(map(len, short)) // 128) * 128),
+                          np.int32)
+        for i, context in enumerate(short):
+            padded[i, :len(context)] = context
+        logits, routing = jax.jit(lambda p, t: reference.forward(
+            p, t, model, with_routing=True))(params, jnp.asarray(padded))
+        want += list(np.asarray(logits))
+        theirs += list(np.moveaxis(np.asarray(routing), 1, 0))
+        offsets += [0] * len(short)
+    for i in long_rows:
+        logits, routing = jax.jit(lambda p, t: reference.forward(
+            p, t, model, with_routing=True, tail=tail))(
+                params, jnp.asarray(contexts[i][None]))
+        want.append(np.asarray(logits)[0])
+        theirs.append(np.asarray(routing)[:, 0])        # [layers, L, k]
+        offsets.append(len(contexts[i]) - tail)
+        say("latent", reference=f"context {len(contexts[i])} done")
+
+    worst, worst_gap, by_context, gaps = 0.0, 0.0, [], []
+    differing = choices = 0
+    differing_by_layer = np.zeros(model_config.sparse_layers, np.int64)
+    worst_same = 0.0
+    for i in compared:
+        length = len(contexts[i])
+        std = float(want[i][:length - offsets[i]].std())
+        ours = np.stack([chosen[i][p] for p in range(length)], 1)
+        other = ~(ours[..., :, None]
+                  == theirs[i][:, :length, None, :]).any(-1)   # [n, L, k]
+        differing += int(other.sum())
+        differing_by_layer += other.sum(axis=(1, 2))
+        choices += other.size
+        first_differing = int(np.argmax(other.any(axis=(0, 2)))) \
+            if other.any() else length
+        here = gap = 0.0
+        for position, logits in got[i].items():
+            row = want[i][position - offsets[i]]
+            error = float(np.abs(logits - row).max()) / std
+            if position < first_differing:
+                worst_same = max(worst_same, error)
+            here = max(here, error)
+            # What the cell's probes measure: how far under the
+            # reference's best logit the program's own choice lies.
+            gap = max(gap, float(row.max() - row[logits.argmax()]))
+        by_context.append(round(here, 4))
+        gaps.append(round(gap, 4))
+        worst, worst_gap = max(worst, here), max(worst_gap, gap)
+    bound = LATENT_SAME_EXPERTS
+    share = REHEARSED_DIFFERING_SHARE if rehearse else LATENT_DIFFERING_SHARE
+    say("latent", check="logits through the latent pool against the "
+        f"float32 reference {config['reference']}", device=device["kind"],
+        worst_diff_in_std=round(worst, 4), by_context=by_context,
+        contexts=[len(contexts[i]) for i in compared],
+        worst_argmax_gap=round(worst_gap, 4), argmax_gap_by_context=gaps,
+        logit_atol=atol, bound=bound,
+        worst_diff_before_a_differing_choice=round(worst_same, 4),
+        expert_choices_differing=differing, expert_choices=choices,
+        differing_by_layer=differing_by_layer.tolist(),
+        logit_std=round(float(want[0].std()), 3),
+        round_weights=round_weights)
+    if round_weights:
+        # A rehearsal's limit is loose (its file says why): there the
+        # share of differing expert choices alone tells the control.
+        check(differing > share * choices
+              and (rehearse or worst_gap > atol),
+              f"weights rounded through {round_weights} stayed inside the "
+              f"bounds ({differing} of {choices} choices differ, "
+              f"{worst_gap} <= {atol}): the comparison cannot tell a lower "
+              "precision")
+    else:
+        check(worst_same <= bound, f"logits off by {worst_same} standard "
+              "deviations with the same experts chosen")
+        check(worst_gap <= atol, f"the program's choice lies {worst_gap} "
+              "under the reference's best logit")
+        check(differing <= share * choices,
+              f"{differing} of {choices} expert choices differ")
+
+
+# ------------------------------------- a cell's check, its control (--cell)
+
+
+class _LastLine:
+    """A stream passed on, its last whole line that starts with
+    ``prefix`` kept."""
+
+    def __init__(self, stream, prefix: str):
+        self.stream, self.prefix, self.last, self._open = \
+            stream, prefix, "", ""
+
+    def write(self, text: str) -> int:
+        *whole, self._open = (self._open + text).split("\n")
+        self.last = next((line for line in reversed(whole)
+                          if line.startswith(self.prefix)), self.last)
+        return self.stream.write(text)
+
+    def flush(self) -> None:
+        self.stream.flush()
+
+
+def cell_with_rounded_replica(args, started: float) -> int:
+    """``--cell <workload> --round-weights <dtype>``: one run of the cell
+    as ``benchmark/run.py`` makes it (``benchmark/harness.py:main``: the
+    deployment, the probes served by the replica, the window, the check
+    against the plain reference), but the FIRST set of weights built in
+    this process, the replica's, is rounded to the dtype's mantissa, and
+    the second, which ``serve_cell.check_against_reference`` rebuilds
+    from the seed, is left as it is. The run has to come out not
+    correct, by ``reference_argmax_or_near_tie`` and by nothing else:
+    that is the lower reading a configuration's ``logit_atol`` is set
+    against."""
+    import contextlib
+
+    from benchmark import harness
+    from ray_tpu.serve.llm_engine import model
+
+    check(bool(args.round_weights), "--cell is the control of a cell's "
+          "check: give --round-weights (benchmark/run.py makes the sound "
+          "run)")
+    real, built = model.serving_params, []
+
+    def rounded_first(config, params=None, seed=0):
+        weights = real(config, params, seed)
+        built.append(seed)
+        return round_mantissa(weights, args.round_weights) \
+            if len(built) == 1 else weights
+
+    model.serving_params = rounded_first
+    out, err = _LastLine(sys.stdout, "{"), \
+        _LastLine(sys.stderr, "bench[correct] ")
+    # The window is short: the control reads no speed.
+    argv = ["--workload", args.cell, "--seed", str(args.seed),
+            "--seconds", "2" if args.rehearse else "20", "--trace", "0"]
+    try:
+        with contextlib.redirect_stdout(out), contextlib.redirect_stderr(err):
+            harness.main(argv + ["--rehearse"] * args.rehearse, started)
+    finally:
+        model.serving_params = real
+    result = json.loads(out.last)
+    compared = json.loads(err.last[len(err.prefix):])
+    failed = sorted(k for k, v in compared.items() if v is False)
+    say("cell", workload=args.cell, round_weights=args.round_weights,
+        weights_built=len(built), correct=result["correct"],
+        worst_gap=compared["worst_gap"], logit_atol=compared["logit_atol"],
+        checks_failed=failed)
+    check(len(built) == 2, f"{len(built)} sets of weights were built, not "
+          "the replica's and the check's")
+    # A rehearsal's limit is loose (its file says why): there the run
+    # shows only that the path holds.
+    check(args.rehearse or failed == ["reference_argmax_or_near_tie"],
+          f"the replica ran on weights rounded through "
+          f"{args.round_weights} and the cell's checks failed {failed}: "
+          "the limit has to refuse a lower precision, and nothing else "
+          "may fail")
+    print(json.dumps({"ok": True, "device": result["device"]}), flush=True)
+    return 0
+
+
 # ------------------------------------------------------- four chips: sharded
 
 
@@ -1376,6 +1696,8 @@ def main(argv: "list[str] | None" = None) -> int:
                 + f" --xla_force_host_platform_device_count={args.chips}")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     started = time.perf_counter()
+    if args.cell:
+        return cell_with_rounded_replica(args, started)
     # The program first (it does not touch jax): without it there is
     # nothing to smoke, and nothing is printed.
     import ray_tpu

@@ -1,15 +1,27 @@
 """Mixture-of-Experts SwiGLU layer: top-k routing, nothing dropped.
 
-The block as OLMoE publishes it (``OlmoeSparseMoeBlock``), which is
-also Mixtral's with ``norm_topk_prob``: router logits in float32, a
-softmax over ALL experts, the ``k`` largest probabilities with their
-indices, the weights left as they are or renormalised to sum to one,
-and every token served by all ``k`` of its experts (no capacity, no
-dropped token).
+Router logits in float32, a score for ALL experts, the ``k`` best with
+their indices, and every token served by all ``k`` of its experts (no
+capacity, no dropped token). The scoring is one of two:
+
+- ``"softmax"``, the block as OLMoE publishes it
+  (``OlmoeSparseMoeBlock``), which is also Mixtral's and SDAR's with
+  ``norm_topk_prob``: a softmax over all experts, the ``k`` largest
+  probabilities, left as they are or renormalised to sum to one;
+- ``"sigmoid"``, DeepSeek-V3's ``noaux_tc`` without groups, as
+  Xing4.0 publishes it: an expert's score is the sigmoid of its logit;
+  the ``k`` experts are those of the largest score PLUS a learned bias,
+  which takes no part in the weight; the weights are the chosen
+  experts' scores, renormalised with ``norm_topk_prob``, times a
+  ``scale`` (``routed_scaling_factor``).
+
+Beside the routed experts a model may have a shared expert, a plain
+SwiGLU every token passes with weight one (``shared_ffn``).
 
 One routing function (``route``) and one expert feed-forward
 (``expert_ffn``) serve ``llama.forward`` (training) and the paged
-engine (``serve/llm_engine/model.py``).
+engine (``serve/llm_engine/model.py``, and ``latent.py`` for the
+sigmoid scoring and the shared expert).
 
 ``expert_ffn`` is an all-experts product: every resident expert is
 applied to every token and the result is weighted by the (mostly zero)
@@ -28,7 +40,9 @@ probability per expert, scaled by E: Switch Transformer eq. 4, with the
 collapsing in training.
 
 Params per MoE layer (leading E = expert dim, logical "expert" -> ep):
-  w_router [H, E]; w_gate/w_up [E, H, M]; w_down [E, M, H].
+  w_router [H, E]; w_gate/w_up [E, H, M]; w_down [E, M, H]; with the
+  sigmoid scoring router_bias [E]; with a shared expert of width S
+  shared_gate/shared_up [H, S]; shared_down [S, H].
 """
 
 from __future__ import annotations
@@ -74,23 +88,37 @@ def moe_logical_axes() -> dict:
 
 
 def route(x: jax.Array, w_router: jax.Array, experts_per_token: int,
-          norm_topk_prob: bool = False):
-    """x [..., H] -> (probs [..., E], idx [..., k], weights [..., k]).
+          norm_topk_prob: bool = False, *, scoring: str = "softmax",
+          bias: "jax.Array | None" = None, scale: float = 1.0):
+    """x [..., H] -> (scores [..., E], idx [..., k], weights [..., k]).
 
     All float32: the logits are a float32 product at the highest
     precision (on a TPU a float32 matmul otherwise rounds its operands
-    to bf16), the softmax runs over all experts, and ``lax.top_k``
-    takes the ``k`` largest probabilities (ties to the lower index).
-    ``weights`` are those probabilities as they are, or divided by
-    their sum with ``norm_topk_prob``.
+    to bf16). ``scoring="softmax"``: the scores are a softmax over all
+    experts and ``lax.top_k`` takes the ``k`` largest (ties to the
+    lower index). ``scoring="sigmoid"``: the scores are the logits'
+    sigmoids, the ``k`` experts those of the largest ``score + bias``
+    (``bias`` [E], or none), and the weights the scores of the chosen,
+    without the bias. ``weights`` are those scores as they are, or
+    divided by their sum with ``norm_topk_prob``, then times ``scale``.
     """
     logits = jnp.einsum("...h,he->...e", x.astype(jnp.float32),
                         w_router.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, idx = lax.top_k(probs, experts_per_token)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = lax.top_k(probs, experts_per_token)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        biased = probs if bias is None else probs + bias.astype(jnp.float32)
+        _, idx = lax.top_k(biased, experts_per_token)
+        weights = jnp.take_along_axis(probs, idx, axis=-1)
+    else:
+        raise ValueError(f"scoring={scoring!r}: 'softmax' or 'sigmoid'")
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     return probs, idx, weights
 
 
@@ -128,6 +156,24 @@ def expert_ffn(layer: dict, x: jax.Array, combine: jax.Array,
     hidden = jnp.where(weight > 0, hidden, 0.0).astype(dtype)
     out = jnp.einsum("enm,emh->nh", hidden, layer["w_down"].astype(dtype))
     return out.reshape(b, t, h)
+
+
+def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+           w_down: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
+    """``W_down(silu(W_gate x) * W_up x)``: x [..., H] -> [..., H] in
+    ``dtype``; w_gate, w_up [H, M], w_down [M, H]."""
+    x = x.astype(dtype)
+    gate = jnp.einsum("...h,hm->...m", x, w_gate.astype(dtype))
+    up = jnp.einsum("...h,hm->...m", x, w_up.astype(dtype))
+    return jnp.einsum("...m,mh->...h", jax.nn.silu(gate) * up,
+                      w_down.astype(dtype))
+
+
+def shared_ffn(layer: dict, x: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
+    """The shared expert: a plain SwiGLU of every token, weight one,
+    unrouted."""
+    return swiglu(x, layer["shared_gate"], layer["shared_up"],
+                  layer["shared_down"], dtype)
 
 
 def load_balance_loss(probs: jax.Array, idx: jax.Array) -> jax.Array:

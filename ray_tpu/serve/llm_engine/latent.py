@@ -1,0 +1,191 @@
+"""The paged steps of a latent-attention model (``models/xing.py``):
+one pool that holds, a position and layer, ONE vector of
+``kv_lora_rank + qk_rope_head_dim`` values: no heads, no values of
+their own.
+
+- ``latent`` ``[layers, num_blocks, bs, pool_lanes]``: a position's
+  ``latent_dim`` values (576) in lanes rounded up to the chip's 128
+  (640), the tail zero: the chip tiles ``[.., 576]`` as ``[.., 640]``
+  in any case, and declared so the pool keeps its blocks contiguous
+  (``XingConfig.pool_lanes`` says what the other way cost). Paged by the
+  same block tables and allocator as a dense model's keys and values
+  (``kv_cache.PagedKVCache``), block 0 the scratch block. A pass writes
+  its positions' entries first and gathers them with the rest, as
+  ``model._forward_paged`` does.
+- The two programs read the gathered latents ``[B, S, pool_lanes]``
+  differently. The **decode step** reads them absorbed
+  (``xing.attend_absorbed``): one query a row, the query carried into
+  the latent space, scores and values taken on the latents where they
+  lie, every head sharing the one read. The **prefill chunk** expands
+  them (``xing.attend_expanded``) to keys and values a head for its
+  many queries.
+- The carry of the layer scans is the token's residual STREAMS ``[B, T,
+  hc_mult, C]`` in float32 beside the pool and the expert counters; the
+  stack is the leading dense layers, then the expert layers (two scans
+  over ``params["dense"]`` and ``params["sparse"]``).
+
+One token a row a pass, a row ends by its count: the packers, the row
+bookkeeping and the step in flight (``ahead``) are the dense model's.
+The two programs are traced under its names (``decode_step``,
+``prefill_chunk``) and take ONE host array each.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models import moe, xing
+from ray_tpu.models.llama import rms_norm
+from ray_tpu.serve.llm_engine.model import (
+    Family,
+    _accumulated,
+    pack_decode_rows,
+    pack_prefill_chunk,
+    row_beside_zeros,
+    row_tokens,
+    sample_next,
+)
+
+F32 = jnp.float32
+
+
+def init_cache(config, num_blocks: int, block_size: int, rows: int,
+               chunk_len: int) -> dict:
+    return {"latent": jnp.zeros((config.num_layers, num_blocks, block_size,
+                                 config.pool_lanes), config.dtype)}
+
+
+def _attention(w: dict, x, positions, pool, li, tables, config,
+               block_size: int, n_valid, absorbed: bool):
+    """One attention sublayer over layer ``li`` of the pool. x [B, T,
+    C] (normed) at ``positions`` [B, T]; tables [B, M]; positions of a
+    chunk at or past ``n_valid`` write the scratch block. Returns (out
+    [B, T, C], pool)."""
+    (B, T), M = positions.shape, tables.shape[1]
+    q_nope, q_rope = xing.latent_queries(w, x, positions, config)
+    entries = xing.latent_entries(w, x, positions, config)
+    blocks = jnp.take_along_axis(tables, positions // block_size, axis=1)
+    offsets = positions % block_size
+    if n_valid is not None:
+        in_range = jnp.arange(T)[None, :] < n_valid
+        blocks = jnp.where(in_range, blocks, 0)
+        offsets = jnp.where(in_range, offsets, 0)
+    pool = pool.at[li, blocks, offsets].set(entries.astype(pool.dtype))
+    # Flat index s == global position (append-ordered tables).
+    S = M * block_size
+    latents = pool[li, tables].reshape(B, S, -1)
+    mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]
+    attend = xing.attend_absorbed if absorbed else xing.attend_expanded
+    return attend(w, q_nope, q_rope, latents, mask, config), pool
+
+
+def forward(params: dict, cache: dict, tokens, positions, tables, config,
+            block_size: int, *, absorbed: bool, n_valid=None,
+            logits_at=None):
+    """tokens and positions [B, T], tables [B, M] -> (logits [B, T, V]
+    float32, or [B, V] of position ``logits_at`` alone; the cache;
+    the expert counters of this pass; the chosen experts [sparse layers,
+    B, T, k], which only a check reads). The tokens counted are a
+    chunk's first ``n_valid``, or (decode) the rows past position 0."""
+    dtype, eps = config.dtype, config.rms_norm_eps
+    x = params["embed"]["tokens"][tokens].astype(F32)
+    streams = jnp.broadcast_to(x[:, :, None, :],
+                               (*x.shape[:2], config.hc_mult, x.shape[-1]))
+    if n_valid is not None:
+        valid = jnp.broadcast_to(jnp.arange(tokens.shape[1]) < n_valid,
+                                 tokens.shape)
+    else:
+        valid = positions > 0
+    counts = jnp.zeros((len(moe.EXPERT_COUNTERS),), jnp.int32)
+
+    def layer_step(sparse: bool):
+        def step(carry, layer_and_index):
+            streams, pool, counts = carry
+            w, li = layer_and_index
+            h, post, res = xing.hyper_mix(w["hc_attn"], streams, config)
+            y, pool = _attention(
+                w, rms_norm(h, w["attn_norm"], eps).astype(dtype), positions,
+                pool, li, tables, config, block_size, n_valid, absorbed)
+            streams = xing.hyper_write(streams, y, post, res)
+            h, post, res = xing.hyper_mix(w["hc_ffn"], streams, config)
+            normed = rms_norm(h, w["mlp_norm"], eps)
+            if sparse:
+                y, idx = xing.sparse_ffn(w, normed, config)
+                counts = counts + moe.routing_counts(idx, valid,
+                                                     config.num_experts)
+            else:
+                y, idx = xing.dense_ffn(w, normed, config), None
+            return (xing.hyper_write(streams, y, post, res), pool,
+                    counts), idx
+        return step
+
+    carry, routing = (streams, cache["latent"], counts), None
+    dense = config.first_k_dense
+    if dense:
+        carry, _ = lax.scan(layer_step(False), carry,
+                            (params["dense"], jnp.arange(dense)))
+    if config.sparse_layers:
+        carry, routing = lax.scan(
+            layer_step(True), carry,
+            (params["sparse"], jnp.arange(dense, config.num_layers)))
+    streams, pool, counts = carry
+    x = jnp.sum(streams, axis=-2)
+    if logits_at is not None:
+        x = row_beside_zeros(x, logits_at)
+    x = rms_norm(x, params["final_norm"], eps).astype(dtype)
+    logits = jnp.einsum("ble,ev->blv", x, params["lm_head"].astype(dtype),
+                        preferred_element_type=F32)
+    if logits_at is not None:
+        logits = logits[:, 0]
+    return logits, {"latent": pool}, counts, routing
+
+
+def make_engine_decode_step(config, block_size: int):
+    """The ONE decode program, absorbed, on ``model.pack_decode_rows``'
+    array, the carried sampling key and the step before's tokens
+    ``prev`` (``model.row_tokens``); a row at position 0 is inactive."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
+        key, sub = jax.random.split(key)
+        temps = lax.bitcast_convert_type(rows[:, 2], F32)
+        logits, cache, counts, _ = forward(
+            params, cache, row_tokens(rows, prev), rows[:, 1:2],
+            rows[:, 3:], config, block_size, absorbed=True)
+        return sample_next(logits[:, -1, :], sub, temps), cache, \
+            _accumulated(expert_stats, counts), key
+
+    return decode_step
+
+
+def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
+    """The prefill program (one a table width the engine hands it),
+    expanded, on ``model.pack_prefill_chunk``'s array; only the logits
+    of ``last_idx`` are computed."""
+    positions_at, table_at = 2 + chunk_len, 2 + 2 * chunk_len
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_chunk(params, cache, chunk, expert_stats=None):
+        logits, cache, counts, _ = forward(
+            params, cache, chunk[None, 2:positions_at],
+            chunk[None, positions_at:table_at], chunk[None, table_at:],
+            config, block_size, absorbed=False, n_valid=chunk[0],
+            logits_at=chunk[1])
+        return logits[0], cache, _accumulated(expert_stats, counts)
+
+    return prefill_chunk
+
+
+FAMILY = Family(
+    init_params=xing.init_params,
+    init_cache=init_cache,
+    make_engine_decode_step=make_engine_decode_step,
+    make_engine_prefill_chunk=make_engine_prefill_chunk,
+    pack_decode_rows=pack_decode_rows,
+    pack_prefill_chunk=pack_prefill_chunk,
+    ahead=True,
+)

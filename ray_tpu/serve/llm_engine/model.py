@@ -39,7 +39,10 @@ reading and writing the PAGED pool:
 A configuration of another family (``family(config)``, the one place it
 is looked up) brings its own forward, cache and packers under the same
 two program names and the same one-array-a-pass contract:
-``hybrid.py`` for layers of several kinds over three caches, and
+``hybrid.py`` for layers of several kinds over three caches,
+``latent.py`` for latent attention over a pool of one vector a position
+(absorbed in the decode step, expanded in the prefill chunk) under a
+residual path of several streams, and
 ``BLOCKWISE`` below for generation by diffusion over blocks: the same
 layers and pool, ``T = block_length`` query rows a row of the batch, a
 mask that lets a position see all of its own block, and a pass that
@@ -121,13 +124,19 @@ class Family:
 def family(config) -> Family:
     """The one place a configuration's family is looked up: a
     configuration names it (``family = "hybrid"``:
-    ``models/phi4flash.py``) or has a ``block_length`` (generation by
-    diffusion over blocks); otherwise it is the stack of identical
-    layers over one paged pool of this module, one token a row a step."""
-    if getattr(config, "family", "paged") == "hybrid":
+    ``models/phi4flash.py``; ``"latent"``: ``models/xing.py``) or has a
+    ``block_length`` (generation by diffusion over blocks); otherwise it
+    is the stack of identical layers over one paged pool of this module,
+    one token a row a step."""
+    named = getattr(config, "family", "paged")
+    if named == "hybrid":
         from ray_tpu.serve.llm_engine import hybrid
 
         return hybrid.FAMILY
+    if named == "latent":
+        from ray_tpu.serve.llm_engine import latent
+
+        return latent.FAMILY
     if getattr(config, "block_length", 0) > 0:
         return _blockwise(config.block_length)
     return PAGED

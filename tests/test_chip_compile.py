@@ -556,6 +556,79 @@ def test_hybrid_decode_step_at_each_table_width_on_v5e(v5e_chip, width):
         assert f"[{rows},4096,1280]" not in text
 
 
+@pytest.mark.parametrize("width", [128, 256, 512])
+def test_latent_programs_at_each_table_width_on_v5e(v5e_chip, width):
+    """Xing4.0's two programs (published widths, 32 rows, a table of 512
+    blocks of 16; one dense and one expert layer: the scans make the
+    programs the same but for their length) at their three widths,
+    2,048, 4,096 and 8,192 positions a row. The pool of one vector a
+    position (576 values in 640 lanes) is updated where it lies and
+    never copied: declared 576 wide, the runtime lays it out with the
+    blocks along the lanes and both programs copy all of it twice. The
+    decode step gathers one view a layer and reads it absorbed, in
+    bf16; the prefill chunk expands its one row's view inside the score
+    product."""
+    import re
+
+    from ray_tpu.models import xing
+    from ray_tpu.serve.llm_engine import latent
+    from ray_tpu.serve.llm_engine.engine import table_widths
+
+    assert width in table_widths(512)
+    config = xing.XingConfig(
+        num_layers=2, first_k_dense=1,
+        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096})
+    rows, block, table, chunk = 32, 16, 512, 128
+    positions = width * block
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: xing.init_params(
+        config, jax.random.PRNGKey(0))), config.dtype)
+    cache = on_chip(jax.eval_shape(lambda: latent.init_cache(
+        config, 1 + rows * table, block, rows, chunk)))
+    pool = (2, 1 + rows * table, block, 640)
+    assert cache["latent"].shape == pool
+    pool_bytes = math.prod(pool) * 2
+    step = latent.make_engine_decode_step(config, block).lower(
+        params, cache,
+        on_chip(latent.FAMILY.pack_decode_rows(rows, width, ()), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None,
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
+    ).compile()
+    memory = step.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    gathered = rows * positions * 640 * 2          # one layer's view, bf16
+    assert memory.temp_size_in_bytes < gathered + 64 * 2 ** 20
+    text = step.as_text()
+    assert f"bf16[{rows},{positions},640]" in text
+    assert re.search(rf"= f32\[{rows},{positions},640\]", text) is None
+    assert f"f32[{rows},{positions},1,32]" in text          # the scores
+    shape = ",".join(map(str, pool))
+    assert [line for line in text.splitlines()
+            if " copy(" in line and f"= bf16[{shape}]" in line] == []
+    if width < table:
+        assert f"[{rows},8192,640]" not in text
+    prefill = latent.make_engine_prefill_chunk(config, block, chunk).lower(
+        params, cache,
+        on_chip(latent.FAMILY.pack_prefill_chunk(chunk, width, (), 0, (), 0),
+                jnp.int32), None).compile()
+    memory = prefill.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # The float32 scores of the 32 heads (128 MiB at the whole width).
+    assert memory.temp_size_in_bytes < 3 * 32 * chunk * positions * 4
+    text = prefill.as_text()
+    assert f"f32[32,{chunk},{positions}]" in text
+    assert [line for line in text.splitlines()
+            if " copy(" in line and f"= bf16[{shape}]" in line] == []
+    assert re.search(rf"\[(1,)?{chunk},131072\]", text) is None
+    assert "f32[1,2,131072]" in text
+
+
 def test_serving_params_never_hold_a_float32_expert_tensor_on_v5e(v5e_chip):
     """``[12, 64, 2048, 1024]`` is 6 GiB in float32: the cast runs in
     the initialisation's own program, which must keep no such buffer

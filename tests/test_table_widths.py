@@ -2,7 +2,9 @@
 each step the narrowest of ``engine.table_widths`` that holds it, a
 prefill chunk the narrowest that holds its request's table, and both
 programs exist at every width before the first request. Tiny float32
-configurations of both families, blocks of 4: a table of 16 blocks has
+configurations of the autoregressive families (identical layers over a
+pool of keys and values, a hybrid's three caches, latent attention over
+a pool of one vector a position), blocks of 4: a table of 16 blocks has
 the widths 4, 8 and 16 (16, 32 and 64 positions)."""
 
 import dataclasses
@@ -11,17 +13,19 @@ import types
 import numpy as np
 import pytest
 
-FAMILIES = ["paged", "hybrid"]
+FAMILIES = ["paged", "hybrid", "latent"]
 BLOCK, CHUNK, ROWS = 4, 8, 4
 
 
 def tiny(family):
     import jax.numpy as jnp
 
-    from ray_tpu.models import llama, phi4flash
+    from ray_tpu.models import llama, phi4flash, xing
 
     if family == "hybrid":
         return phi4flash.Phi4FlashConfig.tiny(dtype=jnp.float32)
+    if family == "latent":
+        return xing.XingConfig.tiny(dtype=jnp.float32)
     return dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.float32)
 
 
@@ -38,7 +42,7 @@ def step_logits(engine):
     it stands, nothing donated: what the step's program samples from."""
     import jax
 
-    from ray_tpu.serve.llm_engine import hybrid
+    from ray_tpu.serve.llm_engine import hybrid, latent
     from ray_tpu.serve.llm_engine import model as paged_model
 
     config, block = engine.config, engine.block_size
@@ -47,6 +51,11 @@ def step_logits(engine):
             return hybrid.decode_forward(
                 params, cache, rows[:, :1], rows[:, 1], rows[:, 3:], config,
                 block)[0][:, 0]
+    elif paged_model.family(config) is latent.FAMILY:
+        def logits(params, cache, rows):
+            return latent.forward(
+                params, cache, rows[:, :1], rows[:, 1:2], rows[:, 3:],
+                config, block, absorbed=True)[0][:, 0]
     else:
         def logits(params, cache, rows):
             return paged_model._forward_paged(
@@ -246,9 +255,10 @@ def test_building_the_programs_leaves_the_key_and_the_caches(served):
         assert all(value == 0 for value in stats.values()), stats
         for name, array in fresh._pool.items():
             written = np.asarray(array != 0)
-            if name in ("k", "v"):
+            if name in ("k", "v", "latent"):
                 # Inactive rows write the scratch block, and only it.
-                written = written[:, 1:] if written.ndim == 5 \
+                written = written[:, 1:] \
+                    if written.ndim == 5 or name == "latent" \
                     else written[0, 1:]
             assert not written.any(), name
     finally:
