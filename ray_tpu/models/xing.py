@@ -42,7 +42,9 @@ the same latents give the same output:
   the latent space, the scores ``q' . c_kv + q_rope . k_rope`` and the
   values ``u = softmax(.) c_kv`` are taken on the latents where they
   lie, then ``o = u W_uv`` (a decode step: one query a context, and
-  every head shares one read of the latents).
+  every head shares one read of the latents; the engine's step takes
+  ``u`` in ``ops/paged_latent_attention.py``, which reads the pool
+  through the block tables, and this is its plain form).
 
 **Feed-forward.** The first ``first_k_dense`` layers a SwiGLU of
 ``intermediate_size``; the others ``models/moe.py``'s routed experts
@@ -461,6 +463,29 @@ def attend_expanded(w: dict, q_nope, q_rope, latents, mask,
     return _out_projection(w, o, config)
 
 
+def absorbed_queries(w: dict, q_nope, q_rope, lanes: int,
+                     config: XingConfig):
+    """Queries [B, T, H, .] carried into the latent space: ``[q_nope
+    W_uk^T | q_rope | 0]`` [B, T, H, lanes], a pool entry's width, so
+    that ONE product with the entries as they lie gives the scores."""
+    dtype, nope = config.dtype, config.qk_nope_head_dim
+    q_latent = jnp.einsum("bthd,chd->bthc", q_nope,
+                          w["wkv_b"].astype(dtype)[..., :nope])
+    pad = lanes - config.latent_dim
+    return jnp.concatenate(
+        [q_latent, q_rope, jnp.zeros((*q_rope.shape[:-1], pad), dtype)],
+        axis=-1)
+
+
+def absorbed_output(w: dict, u, config: XingConfig):
+    """The heads' sums over the latents ``u`` [B, T, H, rank] through
+    ``W_uv`` and the output projection. Returns [B, T, C]."""
+    dtype, nope = config.dtype, config.qk_nope_head_dim
+    o = jnp.einsum("bthc,chd->bthd", u,
+                   w["wkv_b"].astype(dtype)[..., nope:])
+    return _out_projection(w, o, config)
+
+
 def attend_absorbed(w: dict, q_nope, q_rope, latents, mask,
                     config: XingConfig):
     """The same attention with ``W_uk`` carried into the query and
@@ -468,21 +493,15 @@ def attend_absorbed(w: dict, q_nope, q_rope, latents, mask,
     latents as they lie, ONE read of a position's entry for all heads
     (the query is padded with zeros to the entry's width, and the value
     product runs over the whole entry, its rotary tail dropped after: a
-    slice of the gathered view would be a copy of it)."""
-    dtype, rank = config.dtype, config.kv_lora_rank
-    w_kvb = w["wkv_b"].astype(dtype)
-    nope = config.qk_nope_head_dim
-    q_latent = jnp.einsum("bthd,chd->bthc", q_nope, w_kvb[..., :nope])
-    pad = latents.shape[-1] - config.latent_dim
-    q = jnp.concatenate(
-        [q_latent, q_rope, jnp.zeros((*q_rope.shape[:-1], pad), dtype)],
-        axis=-1)
+    slice of the gathered view would be a copy of it). The plain form,
+    over a gathered view: the engine's decode step computes it in
+    ``ops/paged_latent_attention.py``, through the tables."""
+    q = absorbed_queries(w, q_nope, q_rope, latents.shape[-1], config)
     scores = jnp.einsum("bthc,bsc->bhts", q, latents,
                         preferred_element_type=F32)
     u = jnp.einsum("bhts,bsc->bthc", _probabilities(scores, mask, config),
-                   latents)[..., :rank]
-    o = jnp.einsum("bthc,chd->bthd", u, w_kvb[..., nope:])
-    return _out_projection(w, o, config)
+                   latents)[..., :config.kv_lora_rank]
+    return absorbed_output(w, u, config)
 
 
 # -------------------------------------------------------------- feed-forward

@@ -1,10 +1,12 @@
 """ray_tpu.ops — pallas TPU kernels for the hot ops.
 
 The reference delegates all device kernels to torch/CUDA; here they are
-first-class: blockwise flash attention (flash_attention.py) and fused
-elementwise kernels (fused.py). Every op is differentiable (custom
-vjp) and falls back to pallas interpret mode off-TPU so the same code
-path runs in CPU tests.
+first-class: blockwise flash attention (flash_attention.py), fused
+elementwise kernels (fused.py) and the decode step's absorbed latent
+attention through the block tables (paged_latent_attention.py, which
+the serving engine imports from its module). The training ops are
+differentiable (custom vjp); every op falls back to pallas interpret
+mode off-TPU so the same code path runs in CPU tests.
 """
 
 from ray_tpu.ops.flash_attention import flash_attention, flash_attention_gspmd

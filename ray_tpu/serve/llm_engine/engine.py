@@ -126,11 +126,12 @@ ENGINE_STAT_KEYS = (
     "expert_choices", "expert_slots", "experts_touched",
     "expert_peak_choices",
     # Summed over decode steps: positions the rows' contexts hold in
-    # the full-attention pool, and positions the step gathered from it
-    # (the step's table width for every row). Then a hybrid model's
-    # per-row caches: blocks of a window ring written over with newer
-    # positions, and recurrent states started from zero (a request's
-    # first chunk, and its first again after a preemption).
+    # the full-attention pool, and positions the step read of it (the
+    # step's table width for every row where it gathers; the busy rows'
+    # own pages and fresh entries where ``Family.reads_by_row``). Then
+    # a hybrid model's per-row caches: blocks of a window ring written
+    # over with newer positions, and recurrent states started from zero
+    # (a request's first chunk, and its first again after a preemption).
     "kv_positions_live", "kv_positions_read",
     "window_blocks_recycled", "state_resets",
     # Decode steps run at a table narrower than the whole
@@ -166,15 +167,17 @@ def table_widths(blocks_per_seq: int) -> "tuple[int, ...]":
     """The table widths a decode step or a prefill chunk may be given,
     in blocks, narrowest first: a quarter, a half and the whole of a
     row's table, each in whole blocks. Every row of a step gathers and
-    attends over the step's whole width, so a step takes the narrowest
-    that holds its longest live table, and a chunk the narrowest that
-    holds its request's (at 128 tokens a chunk's float32 scores over
-    Mistral's whole table are 33 MB a layer: 12.81 ms a chunk for 12.24
-    at the quarter, PR 38). Three, because each is a program built before
-    the engine serves: halving twice keeps the read within twice the
-    longest context down to a quarter of the table, and a further rung
-    would add a compile for ever less. A table under four blocks has
-    the one width."""
+    attends over the step's whole width (in four families of five: a
+    latent model's decode step reads each row's own pages through the
+    tables, ``Family.reads_by_row``, and its width only sizes the host
+    array), so a step takes the narrowest that holds its longest live
+    table, and a chunk the narrowest that holds its request's (at 128
+    tokens a chunk's float32 scores over Mistral's whole table are 33
+    MB a layer: 12.81 ms a chunk for 12.24 at the quarter, PR 38).
+    Three, because each is a program built before the engine serves:
+    halving twice keeps the read within twice the longest context down
+    to a quarter of the table, and a further rung would add a compile
+    for ever less. A table under four blocks has the one width."""
     if blocks_per_seq < 4:
         return (blocks_per_seq,)
     return (-(-blocks_per_seq // 4), -(-blocks_per_seq // 2), blocks_per_seq)
@@ -203,17 +206,19 @@ class _Step:
     """A decode step launched and not yet read, the engine thread's
     alone: its rows as scheduled (``active`` and the row ``slots`` they
     held), its host array (``rows``) at its table ``width``, the
-    positions its rows' contexts hold (``live``), whether the step
-    before it was unread at its launch (``ahead``); from the launch on
-    its tokens on the device (``out``) and the sampling key it returned,
-    split again by any first token sampled since (``key``)."""
+    positions its rows' contexts hold (``live``) and those it reads of
+    the pool (``read``), whether the step before it was unread at its
+    launch (``ahead``); from the launch on its tokens on the device
+    (``out``) and the sampling key it returned, split again by any
+    first token sampled since (``key``)."""
 
-    __slots__ = ("active", "slots", "rows", "width", "live", "ahead",
-                 "out", "key")
+    __slots__ = ("active", "slots", "rows", "width", "live", "read",
+                 "ahead", "out", "key")
 
-    def __init__(self, active, slots, rows, width, live, ahead):
+    def __init__(self, active, slots, rows, width, live, read, ahead):
         self.active, self.slots, self.rows = active, slots, rows
-        self.width, self.live, self.ahead = width, live, ahead
+        self.width, self.live, self.read = width, live, read
+        self.ahead = ahead
 
 
 #: ``_grow_or_preempt_locked``: the table cannot grow without a victim
@@ -869,7 +874,18 @@ class LLMEngine:
             slots)
         live = sum(req.position for req in active) + span * len(active) \
             + sum(ahead)
-        return _Step(active, slots, rows, width, live, unread is not None)
+        block = self.block_size
+        if self._family.reads_by_row:
+            # Whole pages up to the step's own position, which is read
+            # from the step itself: never under ``live``.
+            leads = ahead or [False] * len(active)
+            read = block * sum(-(-(req.position + lead) // block)
+                               for req, lead in zip(active, leads)) \
+                + len(active)
+        else:
+            read = self.max_batch * width * block
+        return _Step(active, slots, rows, width, live, read,
+                     unread is not None)
 
     def _read_unread(self) -> bool:
         """Read and emit the step in flight, leaving none."""
@@ -904,8 +920,7 @@ class LLMEngine:
                     self._counters["batched_decode_steps"] += 1
                 self._counters["block_rows"] += len(active)
                 self._counters["kv_positions_live"] += step.live
-                self._counters["kv_positions_read"] += \
-                    self.max_batch * width * self.block_size
+                self._counters["kv_positions_read"] += step.read
                 if width < self.blocks_per_seq:
                     self._counters["decode_steps_narrow"] += 1
                 finished = whole = 0

@@ -10,15 +10,19 @@ their own.
   (``XingConfig.pool_lanes`` says what the other way cost). Paged by the
   same block tables and allocator as a dense model's keys and values
   (``kv_cache.PagedKVCache``), block 0 the scratch block. A pass writes
-  its positions' entries first and gathers them with the rest, as
-  ``model._forward_paged`` does.
-- The two programs read the gathered latents ``[B, S, pool_lanes]``
-  differently. The **decode step** reads them absorbed
-  (``xing.attend_absorbed``): one query a row, the query carried into
-  the latent space, scores and values taken on the latents where they
-  lie, every head sharing the one read. The **prefill chunk** expands
-  them (``xing.attend_expanded``) to keys and values a head for its
-  many queries.
+  its positions' entries where they belong.
+- The two programs read the pool differently. The **decode step**
+  reads it absorbed, one query a row, the query carried into the
+  latent space, scores and values taken on the latents where they lie,
+  every head sharing the one read, and THROUGH THE TABLES
+  (``ops/paged_latent_attention.py``): each row its own live pages,
+  each latent once, the row's fresh entry beside them, whatever the
+  step's table width; no gathered view exists
+  (``xing.attend_absorbed`` is the plain form over one). The **prefill
+  chunk** gathers its one row's view ``[1, S, pool_lanes]`` with the
+  chunk's entries written first, as ``model._forward_paged`` does, and
+  expands it (``xing.attend_expanded``) to keys and values a head for
+  its many queries.
 - The carry of the layer scans is the token's residual STREAMS ``[B, T,
   hc_mult, C]`` in float32 beside the pool and the expert counters; the
   stack is the leading dense layers, then the expert layers (two scans
@@ -40,6 +44,7 @@ from jax import lax
 
 from ray_tpu.models import moe, xing
 from ray_tpu.models.llama import rms_norm
+from ray_tpu.ops.paged_latent_attention import paged_latent_attention
 from ray_tpu.serve.llm_engine.model import (
     Family,
     _accumulated,
@@ -67,20 +72,31 @@ def _attention(w: dict, x, positions, pool, li, tables, config,
     [B, T, C], pool)."""
     (B, T), M = positions.shape, tables.shape[1]
     q_nope, q_rope = xing.latent_queries(w, x, positions, config)
-    entries = xing.latent_entries(w, x, positions, config)
+    entries = xing.latent_entries(w, x, positions, config).astype(pool.dtype)
     blocks = jnp.take_along_axis(tables, positions // block_size, axis=1)
     offsets = positions % block_size
     if n_valid is not None:
         in_range = jnp.arange(T)[None, :] < n_valid
         blocks = jnp.where(in_range, blocks, 0)
         offsets = jnp.where(in_range, offsets, 0)
-    pool = pool.at[li, blocks, offsets].set(entries.astype(pool.dtype))
+    written = pool.at[li, blocks, offsets].set(entries)
+    if absorbed:
+        # One position a row (T == 1). The kernel walks each row's own
+        # pages of the pool as the step found it and takes the row's
+        # fresh entry beside them; a row at position 0 is inactive.
+        at = positions[:, 0]
+        q = xing.absorbed_queries(w, q_nope, q_rope, pool.shape[-1], config)
+        u = paged_latent_attention(
+            q[:, 0], entries[:, 0], pool, tables,
+            jnp.where(at > 0, at + 1, 0), li, scale=config.softmax_scale,
+            width=config.kv_lora_rank)
+        return xing.absorbed_output(w, u[:, None], config), written
     # Flat index s == global position (append-ordered tables).
     S = M * block_size
-    latents = pool[li, tables].reshape(B, S, -1)
+    latents = written[li, tables].reshape(B, S, -1)
     mask = jnp.arange(S)[None, None, :] <= positions[:, :, None]
-    attend = xing.attend_absorbed if absorbed else xing.attend_expanded
-    return attend(w, q_nope, q_rope, latents, mask, config), pool
+    return xing.attend_expanded(w, q_nope, q_rope, latents, mask,
+                                config), written
 
 
 def forward(params: dict, cache: dict, tokens, positions, tables, config,
@@ -188,4 +204,5 @@ FAMILY = Family(
     pack_decode_rows=pack_decode_rows,
     pack_prefill_chunk=pack_prefill_chunk,
     ahead=True,
+    reads_by_row=True,
 )

@@ -101,7 +101,13 @@ class Family:
     chunk resets; ``ahead``: which rows a pass carries, and where, is
     known before the values of the pass before it (a row yields one
     token a pass and ends by its count), so the engine launches a pass
-    on the last one's tokens where they lie on the device."""
+    on the last one's tokens where they lie on the device;
+    ``reads_by_row``: its decode step reads the pool through the tables
+    a row at a time, each busy row the whole pages that hold its
+    positions before the step's own and its fresh entry beside them
+    (``ops/paged_latent_attention.py``), where the others gather the
+    step's table width for every row: what ``kv_positions_read``
+    counts."""
     init_params: Callable
     init_cache: Callable    # (config, num_blocks, block_size, rows, chunk)
     make_engine_decode_step: Callable
@@ -111,6 +117,7 @@ class Family:
     ring_positions: Callable = lambda config, block_size, chunk_len: 0
     recurrent: bool = False
     ahead: bool = False
+    reads_by_row: bool = False
     # A busy row on the host: what ``pack_decode_rows`` is given for a
     # request (``row_of(req)``; ``row_of(req, True)`` of an ``ahead``
     # family: the row one pass on), and what a pass made of it

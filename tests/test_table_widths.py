@@ -213,22 +213,44 @@ def test_every_chunk_has_the_narrowest_width_that_holds_its_table(served):
     assert {w for w, _, _ in served.whole_chunks} == {16}
 
 
+def positions_read(family, seen) -> int:
+    """What ``seen``'s steps read of the pool, from their host arrays:
+    rows x the step's width in positions where the step gathers; of a
+    latent engine, whose kernel walks each busy row's own pages, the
+    whole pages that hold the row's positions before its own, and its
+    own (which the step brings with it)."""
+    if family != "latent":
+        return sum(ROWS * w * BLOCK for w in seen.widths)
+    at = np.concatenate([rows[rows[:, 1] > 0, 1] for rows in seen.rows])
+    return int((-(-at // BLOCK) * BLOCK + 1).sum())
+
+
 def test_counters_say_what_the_steps_read(served):
-    """``kv_positions_read`` is rows x the step's width in positions,
-    summed; ``decode_steps_narrow`` counts the steps under the whole
-    width; the live positions are the same whichever width read them."""
+    """``kv_positions_read`` is what the steps read of the pool, summed
+    (``positions_read``); ``decode_steps_narrow`` counts the steps under
+    the whole width; the live positions are the same whichever width
+    read them, and so is what a latent engine reads: under one page a
+    row and step over what is live."""
     stats, widths = served.stats, served.seen.widths
     assert stats["decode_steps"] == len(widths)
-    assert stats["kv_positions_read"] == sum(
-        ROWS * w * BLOCK for w in widths)
+    assert stats["kv_positions_read"] == positions_read(
+        served.family, served.seen)
     assert stats["decode_steps_narrow"] == sum(w < 16 for w in widths) > 0
     whole = served.whole_stats
     assert whole["decode_steps_narrow"] == 0
-    assert whole["kv_positions_read"] == whole["decode_steps"] * ROWS * 64
+    assert whole["kv_positions_read"] == positions_read(
+        served.family, served.whole_seen)
     live = sum(new - 1 for _, new in REQUESTS)  # the first is a chunk's
     assert stats["decode_tokens"] == whole["decode_tokens"] == live
     assert stats["kv_positions_live"] == whole["kv_positions_live"]
-    assert stats["kv_positions_read"] < whole["kv_positions_read"]
+    if served.family == "latent":
+        assert stats["kv_positions_read"] == whole["kv_positions_read"]
+        over = stats["kv_positions_read"] - stats["kv_positions_live"]
+        assert 0 < over < BLOCK * stats["block_rows"]
+    else:
+        assert whole["kv_positions_read"] \
+            == whole["decode_steps"] * ROWS * 64
+        assert stats["kv_positions_read"] < whole["kv_positions_read"]
 
 
 def test_no_program_is_built_after_the_constructor(served):

@@ -457,6 +457,11 @@ def test_the_engine_serves_the_references_greedy_tokens(engine):
     assert 0 < stats["decode_steps_narrow"] < stats["decode_steps"]
     assert stats["decode_tokens"] == 30 - 3  # the first is prefill's
     assert 0 < stats["kv_positions_live"] < stats["kv_positions_read"]
+    # The step reads by row (``ops/paged_latent_attention.py``): each
+    # busy row's whole pages and its own entry, under a page over what
+    # is live, whatever the step's width.
+    assert stats["kv_positions_read"] < stats["kv_positions_live"] \
+        + BLOCK * stats["block_rows"]
     # The expert counters: 2 expert layers of 8 experts, 3 a token.
     layer_steps = 2 * (stats["decode_steps"] + stats["prefill_chunks"])
     assert stats["expert_slots"] == 8 * layer_steps
@@ -464,6 +469,26 @@ def test_the_engine_serves_the_references_greedy_tokens(engine):
         stats["decode_tokens"] + stats["prefill_tokens"])
     assert 0 < stats["experts_touched"] <= stats["expert_slots"]
     assert stats["expert_peak_choices"] >= stats["expert_choices"]
+
+
+@pytest.mark.parametrize("prompt, live, read", [
+    (7, 8 + 9, (8 + 1) + (8 + 1)),      # positions 7 and 8: two pages
+    (8, 9 + 10, (8 + 1) + (12 + 1)),    # 9 starts a third
+    (9, 10 + 11, (12 + 1) + (12 + 1))])
+def test_a_step_counts_the_pages_its_kernel_fetches(engine, prompt, live,
+                                                    read):
+    """Two decode steps of one row, by hand: a step at position ``p``
+    holds ``p + 1`` live positions and reads ``ceil(p / 4)`` pages of 4
+    and the row's own entry (``Family.reads_by_row``; the other
+    families count rows x the step's width:
+    ``tests/test_table_widths.py``)."""
+    before = engine.engine_stats()
+    request = engine.submit(list(range(1, prompt + 1)), max_new_tokens=3)
+    assert len(engine.result(request, timeout_s=300)) == 3
+    after = engine.engine_stats()
+    assert after["decode_steps"] - before["decode_steps"] == 2
+    assert after["kv_positions_live"] - before["kv_positions_live"] == live
+    assert after["kv_positions_read"] - before["kv_positions_read"] == read
 
 
 def test_a_preempted_request_resumes_to_the_same_tokens():
