@@ -9,17 +9,19 @@ The step's table is as wide as its longest live row needs and a
 chunk's as wide as its request's, of three widths (``table_widths``),
 each a program the constructor has built.
 
-The loop keeps one decode step ahead where the family allows it
-(``Family.ahead``): step N+1 is launched on step N's tokens where they
-lie on the device (the program's ``prev``), and only then is N read and
-emitted, so the device runs N+1 while the host fetches, emits, sweeps
-and launches a prefill chunk. At most ONE step is in flight unread
-(``_Step``); a request's ``position``, ``last_token`` and ``output``
-move on at emission, and what lies ahead of them is that record.
-Which rows N+1 carries needs no value of N: a row ends by its count or
-its table's end, and a row joins from prefill with a token the host
-has. Whatever needs the values (a preemption rebuilds its victim's
-context from ``output``) reads the step in flight first.
+The loop keeps one decode step ahead: step N+1 is launched on step N's
+result where it lies on the device (the program's ``prev``), and only
+then is N read and emitted, so the device runs N+1 while the host
+fetches, emits, sweeps and launches a prefill chunk. At most ONE step is
+in flight unread (``_Step``); a request's ``position``, ``last_token``,
+``block`` and ``output`` move on at emission, and what lies ahead of
+them is that record. Which rows N+1 carries, and where, the family
+tells from that state by counting (``Family.lead``, ``row_of(req,
+True)``): a row ends by its count or its table's end, and a row joins
+from prefill with tokens the host has. Whatever needs the values reads
+the step in flight first: a preemption rebuilds its victim's context
+from ``output``, and a row says so itself (``Family.ahead``) where its
+next pass hangs on them (a denoising pass under the dynamic rule).
 
 The model's family (``model.family(config)``, looked up once) gives the
 weights, the cache and the two programs: a stack of identical layers
@@ -36,7 +38,8 @@ positions in flight: a denoising pass fixes some of them, the block's
 tokens are emitted together once none is masked (a stream sees whole
 blocks), and one more pass, which yields nothing, stores the block's
 keys and values; its prefill yields no first token, and its table grows
-a block at a time.
+a block at a time. Between passes the block stays on the device: how
+many of its positions a pass fixes is known without their values.
 
 Counters ship as ``ENGINE_STAT_KEYS`` through the node-stats heartbeat
 piggyback (``ray_tpu_node_engine`` /metrics family) via the
@@ -296,11 +299,14 @@ class LLMEngine:
         # ``_key`` is the key of the last step whose tokens were read;
         # the step in flight (``_unread``) holds the one it returned.
         # ``_no_prev`` is what a step with no step before it is given
-        # for ``prev``: made as the key is, so placed as a step's tokens.
+        # for ``prev``: made as the key is, so placed as a step's first
+        # result, and of its shape (a token a row, or a block).
+        shape = (self.max_batch, block_length) if block_length \
+            else (self.max_batch,)
         with jax_compat.set_mesh(mesh):
             self._key = jax.jit(lambda: jax.random.PRNGKey(seed + 1))()
             self._no_prev = jax.jit(
-                lambda: jax.numpy.zeros((self.max_batch,), "int32"))()
+                lambda: jax.numpy.zeros(shape, "int32"))()
         self._unread: "_Step | None" = None
         self._counters: "dict[str, int]" = {k: 0 for k in ENGINE_STAT_KEYS}
         self._pass = _PassClock()
@@ -792,10 +798,9 @@ class LLMEngine:
         req.done.set()
 
     def _decode_tick(self) -> bool:
-        """Launch the next decode step, then read one: of a family that
-        goes ahead the step BEFORE it, which was in flight unread (the
-        new one stays in flight while the host emits, sweeps and runs a
-        prefill chunk), of another the step just launched."""
+        """Launch the next decode step, then read the one BEFORE it,
+        which was in flight unread: the new one stays in flight while
+        the host emits, sweeps and runs a prefill chunk."""
         if self._unread is None and not self._sched.active:
             return False
         planned = _UNREAD
@@ -823,23 +828,21 @@ class LLMEngine:
             except Exception as exc:  # noqa: BLE001 — donated pool is gone
                 self._reset_after_failure(exc)
                 return True
-        if self._family.ahead:
-            # The new step stays in flight; the one before it is read.
-            self._unread, step = step, before
-        if step is not None:
-            self._read_step(step)
+        self._unread = step
+        if before is not None:
+            self._read_step(before)
         return True
 
     def _plan_step_locked(self):
         """The next decode step (caller holds the lock): a ``_Step``
         to launch; None when no row has a pass to run; ``_UNREAD`` when
-        a table cannot grow before the step in flight is read. A row of
-        the step in flight is planned one position on, its token the
-        one in flight, unless that token is its last: it has one left
-        to make, or its table ends."""
-        unread, span = self._unread, self._span
-        active, ahead = [], []
-        # Grow every row's table for the token it is about to write.
+        the step in flight has to be read first: a table cannot grow, or
+        a row of it cannot tell its next pass by counting
+        (``Family.ahead``). A row of the step in flight is planned one
+        pass on, at the position that step moves it to
+        (``Family.lead``), unless that step is its last."""
+        unread, span, family = self._unread, self._span, self._family
+        # Grow every row's table for what its pass is about to write.
         if unread is None:
             # Pressure preempts lowest-progress rows.
             for req in list(self._sched.active):
@@ -847,41 +850,43 @@ class LLMEngine:
                     continue  # already preempted as a victim
                 self._grow_or_preempt_locked(req, req.position + span)
             active = list(self._sched.active)
+            ahead = [False] * len(active)
+            positions = [req.position for req in active]
         else:
             # Nothing is preempted while a step is unread: pressure
             # asks for its read.
+            active, ahead, positions = [], [], []
             flying = set(unread.active)
             for req in self._sched.active:
-                lead = req in flying
-                if lead and (req.remaining <= 1
-                             or req.position + 1 >= self.max_tokens):
-                    continue
+                position, flies = req.position, req in flying
+                if flies:
+                    if not family.ahead(req):
+                        return _UNREAD
+                    lead = family.lead(req, self.max_tokens)
+                    if lead is None:
+                        continue
+                    position += lead
                 if self._grow_or_preempt_locked(
-                        req, req.position + lead + span) == _UNREAD:
+                        req, position + span) == _UNREAD:
                     return _UNREAD
                 active.append(req)
-                ahead.append(lead)
+                ahead.append(flies)
+                positions.append(position)
         if not active:
             return None  # none goes on, or everything preempted
         # As held now: a row sealed while the step runs loses its.
         slots = [req.slot for req in active]
         longest = max(len(req.block_table) for req in active)
         width = self._rung(longest)
-        row_of = self._family.row_of
-        rows = self._family.pack_decode_rows(
-            self.max_batch, width,
-            map(row_of, active, ahead) if ahead else map(row_of, active),
-            slots)
-        live = sum(req.position for req in active) + span * len(active) \
-            + sum(ahead)
+        rows = family.pack_decode_rows(
+            self.max_batch, width, map(family.row_of, active, ahead), slots)
+        live = sum(positions) + span * len(active)
         block = self.block_size
-        if self._family.reads_by_row:
+        if family.reads_by_row:
             # Whole pages up to the step's own position, which is read
             # from the step itself: never under ``live``.
-            leads = ahead or [False] * len(active)
-            read = block * sum(-(-(req.position + lead) // block)
-                               for req, lead in zip(active, leads)) \
-                + len(active)
+            read = block * sum(-(-position // block)
+                               for position in positions) + len(active)
         else:
             read = self.max_batch * width * block
         return _Step(active, slots, rows, width, live, read,

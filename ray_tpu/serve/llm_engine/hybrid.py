@@ -339,5 +339,4 @@ FAMILY = Family(
     pack_prefill_chunk=pack_prefill_chunk,
     ring_positions=ring_positions,
     recurrent=True,
-    ahead=True,
 )

@@ -203,6 +203,5 @@ FAMILY = Family(
     make_engine_prefill_chunk=make_engine_prefill_chunk,
     pack_decode_rows=pack_decode_rows,
     pack_prefill_chunk=pack_prefill_chunk,
-    ahead=True,
     reads_by_row=True,
 )

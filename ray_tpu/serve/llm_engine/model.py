@@ -90,6 +90,15 @@ def _advance_one(req, out):
     return [req.last_token], False
 
 
+def _lead_one(req, max_tokens: int):
+    """An autoregressive row with a pass in flight: one position on,
+    unless that pass's token is its last (it has one left to make, or
+    its table ends)."""
+    if req.remaining <= 1 or req.position + 1 >= max_tokens:
+        return None
+    return 1
+
+
 @dataclasses.dataclass(frozen=True)
 class Family:
     """What the engine asks of a model family, chosen ONCE from the
@@ -98,16 +107,12 @@ class Family:
     their host arrays. ``ring_positions(config, block_size, chunk_len)``
     is how many positions a row of its window cache holds (0: it has
     none); ``recurrent``: a row owns a state slot that a request's first
-    chunk resets; ``ahead``: which rows a pass carries, and where, is
-    known before the values of the pass before it (a row yields one
-    token a pass and ends by its count), so the engine launches a pass
-    on the last one's tokens where they lie on the device;
-    ``reads_by_row``: its decode step reads the pool through the tables
-    a row at a time, each busy row the whole pages that hold its
-    positions before the step's own and its fresh entry beside them
-    (``ops/paged_latent_attention.py``), where the others gather the
-    step's table width for every row: what ``kv_positions_read``
-    counts."""
+    chunk resets; ``reads_by_row``: its decode step reads the pool
+    through the tables a row at a time, each busy row the whole pages
+    that hold its positions before the step's own and its fresh entry
+    beside them (``ops/paged_latent_attention.py``), where the others
+    gather the step's table width for every row: what
+    ``kv_positions_read`` counts."""
     init_params: Callable
     init_cache: Callable    # (config, num_blocks, block_size, rows, chunk)
     make_engine_decode_step: Callable
@@ -116,16 +121,24 @@ class Family:
     pack_prefill_chunk: Callable
     ring_positions: Callable = lambda config, block_size, chunk_len: 0
     recurrent: bool = False
-    ahead: bool = False
     reads_by_row: bool = False
     # A busy row on the host: what ``pack_decode_rows`` is given for a
-    # request (``row_of(req)``; ``row_of(req, True)`` of an ``ahead``
-    # family: the row one pass on), and what a pass made of it
+    # request (``row_of(req)``), and what a pass made of it
     # (``advance(req, out)``, ``out`` the row's slice of the program's
     # first result): the request's state moved on, and (the tokens the
     # pass made final for emission, whether it was a finishing pass).
     row_of: Callable = _row_of_one
     advance: Callable = _advance_one
+    # The same row while its pass is in flight unread, from the state
+    # that pass has not yet moved: ``ahead(req)``, whether what its NEXT
+    # pass carries follows from counting alone, so that the engine can
+    # launch it on this pass's result where it lies on the device (the
+    # program's ``prev``); if so ``lead(req, max_tokens)``, the positions
+    # the pass in flight moves the row on (None: that pass is its last,
+    # by its count or its table's end), and ``row_of(req, True)``, the
+    # row one pass on.
+    ahead: Callable = lambda req: True
+    lead: Callable = _lead_one
 
 
 def family(config) -> Family:
@@ -506,6 +519,18 @@ def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
 # of its tokens, so what a denoising pass wrote is stale; each pass
 # simply writes the block's positions again). The mask's id is never
 # served: it is left out of the argmax, the sample and the confidence.
+#
+# What a pass needs of the pass before it is the block's tokens alone,
+# and those lie on the device where that pass left them (its first
+# result, the next one's ``prev``). The rest is counting: a pass under
+# ``sequential`` or ``low_confidence_static`` fixes ``fix_count`` of the
+# masked positions, whichever they are; a block with none left gets its
+# finishing pass; after that the next block opens all masked at
+# ``position + size``; a row ends by its count or its table's end. So
+# the host packs a row's next pass while this one runs
+# (``block_row_of(req, True)``, ``block_lead``), and only a denoising
+# pass under ``low_confidence_dynamic``, which fixes as many positions
+# as pass its threshold, has to be read first (``block_counts``).
 
 #: Which masked positions a denoising pass fixes: the leftmost; those
 #: of the largest confidence; every one whose confidence is over the
@@ -524,14 +549,53 @@ def fix_count(block_length: int, denoising_steps: int, done: int) -> int:
     return block_length // steps + (done < block_length % steps)
 
 
-def block_row_of(req):
+def _masked_after(req) -> int:
+    """Masked positions the block keeps after the denoising pass its
+    schedule asks for next, under a rule that fixes by count."""
+    return max(req.block.count(MASKED) - fix_count(
+        len(req.block), req.denoising_steps, req.passes), 0)
+
+
+def block_row_of(req, ahead: bool = False):
     """``Family.row_of``: the request's block in flight and the pass
-    its schedule asks for next."""
-    fix = 0 if MASKED not in req.block else \
-        fix_count(len(req.block), req.denoising_steps, req.passes)
-    return (req.block, req.position, req.temperature, fix,
+    its schedule asks for next. ``ahead``: the row one pass on, while
+    that pass is unread, by counting: after a finishing pass the next
+    block, all masked, a block further; after a denoising pass the
+    block that pass leaves on the device (no block here: the program
+    takes it from ``prev``), fewer of it masked by what the pass fixes."""
+    size = len(req.block)
+    block, position, passes = req.block, req.position, req.passes
+    masked = block.count(MASKED)
+    if ahead and not masked:
+        block, position, passes, masked = [MASKED] * size, position + size, \
+            0, size
+    elif ahead:
+        block, passes, masked = None, passes + 1, _masked_after(req)
+    fix = fix_count(size, req.denoising_steps, passes) if masked else 0
+    return (block, position, req.temperature, fix,
             REMASKING.index(req.remasking), req.confidence_threshold,
             req.block_table)
+
+
+def block_counts(req) -> bool:
+    """``Family.ahead``: what the pass in flight leaves masked is known
+    without its values, unless it is a denoising pass under the dynamic
+    rule."""
+    return MASKED not in req.block or req.remasking != REMASKING[2]
+
+
+def block_lead(req, max_tokens: int):
+    """``Family.lead``, of a row that ``block_counts``: a finishing pass
+    in flight moves it a block on, unless its table ends there; a
+    denoising pass moves it nowhere, and is its last if it makes whole
+    a block that holds all the request still owes."""
+    size = len(req.block)
+    if MASKED not in req.block:
+        return size if req.position + size < max_tokens else None
+    if _masked_after(req):
+        return 0
+    known = len(req.tokens) + len(req.output) - req.position
+    return 0 if size - known < req.remaining else None
 
 
 def advance_block(req, out):
@@ -561,8 +625,11 @@ def pack_block_rows(block_length: int, batch: int, width: int, active,
     a block with nothing masked), the block's tokens (``MASKED`` where
     none is fixed) and the block table; from ``active``'s
     ``block_row_of`` tuples, placed as ``pack_decode_rows`` places
-    them. Rule, count and phase are values, not programs: rows in every
-    phase share one pass."""
+    them. A row with no block (None) runs on the one the pass before
+    left for it, which the host has not read: its phase is negative (a
+    finishing pass is the one that fixes nothing) and its block's
+    columns stay zero. Rule, count and phase are values, not programs:
+    rows in every phase share one pass."""
     table_at = _BLOCK_HEAD + block_length
     rows = np.zeros((batch, table_at + width), np.int32)
     floats = rows.view(np.float32)
@@ -570,8 +637,11 @@ def pack_block_rows(block_length: int, batch: int, width: int, active,
             in zip(slots or range(batch), active):
         rows[i, 0], floats[i, 1], rows[i, 2] = position, temperature, fix
         rows[i, 3], floats[i, 4] = rule, threshold
-        rows[i, 5] = _DENOISING if MASKED in block else _FINISHING
-        rows[i, _BLOCK_HEAD:table_at] = block
+        if block is None:
+            rows[i, 5] = -(_DENOISING if fix else _FINISHING)
+        else:
+            rows[i, 5] = _DENOISING if MASKED in block else _FINISHING
+            rows[i, _BLOCK_HEAD:table_at] = block
         rows[i, table_at:table_at + len(table)] = table
     return rows
 
@@ -620,9 +690,10 @@ def make_engine_block_step(config, block_size: int):
     so a later pass and the finishing pass write them again), then
     ``denoise``. On ``pack_block_rows``' array and the carried key;
     returns the blocks after the pass ``[B, block_length]``, the pool,
-    the expert counters and the key, as ``make_engine_decode_step``;
-    ``prev`` is taken as there and not used (what a pass carries depends
-    on the values of the pass before it, so the host packs every one)."""
+    the expert counters and the key, as ``make_engine_decode_step``.
+    ``prev`` is the first result of the pass before, on the device and
+    not donated: a row whose phase is negative runs on its own row of
+    it. Without ``prev`` every block is the array's."""
     size, mask_id = config.block_length, config.mask_token_id
     table_at = _BLOCK_HEAD + size
 
@@ -631,6 +702,8 @@ def make_engine_block_step(config, block_size: int):
         key, sub = jax.random.split(key)
         floats = lax.bitcast_convert_type(rows[:, :_BLOCK_HEAD], jnp.float32)
         block = rows[:, _BLOCK_HEAD:table_at]
+        if prev is not None:
+            block = jnp.where(rows[:, 5:6] < 0, prev, block)
         logits, pool, counts, _ = _forward_paged(
             params, pool, jnp.where(block == MASKED, mask_id, block),
             rows[:, :1] + jnp.arange(size), rows[:, table_at:], config,
@@ -650,7 +723,6 @@ PAGED = Family(
     make_engine_prefill_chunk=make_engine_prefill_chunk,
     pack_decode_rows=pack_decode_rows,
     pack_prefill_chunk=pack_prefill_chunk,
-    ahead=True,
 )
 
 
@@ -667,5 +739,6 @@ def _blockwise(block_length: int) -> Family:
         pack_decode_rows=functools.partial(pack_block_rows, block_length),
         row_of=block_row_of,
         advance=advance_block,
-        ahead=False,
+        ahead=block_counts,
+        lead=block_lead,
     )

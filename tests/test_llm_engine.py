@@ -835,14 +835,16 @@ def _step_ahead_config(kind):
 
 def _step_ahead_engine(config, params=None, at_once=False, **kwargs):
     """An engine of 4 rows; ``at_once``: one that reads every decode
-    step right after its launch, as the loop did before it kept a step
-    ahead (its family says a row's next pass is not known early)."""
+    step before it schedules the next, as the loop did before it kept a
+    step ahead (its family says of every row that its next pass is not
+    known early)."""
     from ray_tpu.serve.llm_engine import LLMEngine
 
     engine = LLMEngine(config, params, max_batch_size=4, max_seq_len=64,
                        block_size=4, prefill_chunk=8, seed=7, **kwargs)
     if at_once:
-        engine._family = dataclasses.replace(engine._family, ahead=False)
+        engine._family = dataclasses.replace(engine._family,
+                                             ahead=lambda req: False)
     return engine
 
 

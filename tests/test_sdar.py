@@ -18,7 +18,9 @@ fixed here no near-tie flips an argmax or an order of confidences; a
 flip would show as a failure, not pass silently.
 """
 
+import contextlib
 import dataclasses
+import functools
 import os
 import sys
 import threading
@@ -377,31 +379,37 @@ def test_rows_in_every_phase_share_one_pass():
     assert got == [by_reference(prompt_of(n, seed=9), new, remasking=rule,
                                 denoising_steps=steps)
                    for n, new, rule, steps in SCHEDULES]
-    phases = [set(rows[:, 5].tolist()) for rows in seen]
-    assert any({1, 2} <= p for p in phases), "no pass mixed the phases"
-    rules = [set(rows[rows[:, 5] > 0, 3].tolist()) for rows in seen]
+    # A row's phase; negative on the block the pass before left.
+    phases = [np.abs(rows[:, 5]) for rows in seen]
+    assert any({1, 2} <= set(p.tolist()) for p in phases), \
+        "no pass mixed the phases"
+    assert any((rows[:, 5] < 0).any() and (rows[:, 5] > 0).any()
+               for rows in seen), "no pass mixed blocks from both sides"
+    rules = [set(rows[p > 0, 3].tolist()) for rows, p in zip(seen, phases)]
     assert any(len(r) > 1 for r in rules), "no pass mixed the rules"
     assert engine._family.make_engine_decode_step \
         is paged_model.make_engine_block_step
     assert stats["decode_tokens"] == sum(s[1] for s in SCHEDULES)
-    assert stats["block_rows"] == sum((rows[:, 5] > 0).sum() for rows in seen)
-    assert stats["commit_rows"] == sum((rows[:, 5] == 2).sum()
-                                       for rows in seen)
+    assert stats["block_rows"] == sum((p > 0).sum() for p in phases)
+    assert stats["commit_rows"] == sum((p == 2).sum() for p in phases)
 
 
-def test_a_row_preempted_mid_block_resumes_to_the_same_tokens(engine):
-    """Cache pressure preempts rows whose block is half made: it is
-    thrown away, the prompt and the whole blocks emitted are prefilled
-    again, and the answer is the unpressed engine's."""
-    prompts = [prompt_of(n, seed=13) for n in (3, 5, 2, 4)]
-    want = [engine.result(engine.submit(p, max_new_tokens=14),
-                          timeout_s=300) for p in prompts]
+PRESSED_PROMPTS = [prompt_of(n, seed=13) for n in (3, 5, 2, 4)]
+
+
+@functools.lru_cache(maxsize=None)
+def served_under_pressure():
+    """Four requests of 14 tokens from four threads through an engine
+    of 7 paged blocks, once for the tests that read it: their answers,
+    the counters, and of every victim at its preemption whether its
+    block was half made and the step that was in flight unread."""
     pressed = make_engine(num_blocks=7)
-    half_made = []
+    victims = []
     preempt = pressed._sched.preempt
 
     def watching(victim):
-        half_made.append(victim.passes > 0 or MASKED not in victim.block)
+        victims.append((victim.passes > 0 or MASKED not in victim.block,
+                        pressed._unread))
         preempt(victim)
 
     pressed._sched.preempt = watching
@@ -409,8 +417,8 @@ def test_a_row_preempted_mid_block_resumes_to_the_same_tokens(engine):
         results = {}
 
         def generate(i):
-            results[i] = pressed.result(
-                pressed.submit(prompts[i], max_new_tokens=14), timeout_s=300)
+            results[i] = pressed.result(pressed.submit(
+                PRESSED_PROMPTS[i], max_new_tokens=14), timeout_s=300)
 
         threads = [threading.Thread(target=generate, args=(i,))
                    for i in range(4)]
@@ -421,9 +429,20 @@ def test_a_row_preempted_mid_block_resumes_to_the_same_tokens(engine):
         stats = pressed.engine_stats()
     finally:
         pressed.shutdown()
+    return [results[i] for i in range(4)], stats, victims
+
+
+def test_a_row_preempted_mid_block_resumes_to_the_same_tokens(engine):
+    """Cache pressure preempts rows whose block is half made: it is
+    thrown away, the prompt and the whole blocks emitted are prefilled
+    again, and the answer is the unpressed engine's."""
+    want = [engine.result(engine.submit(p, max_new_tokens=14),
+                          timeout_s=300) for p in PRESSED_PROMPTS]
+    got, stats, victims = served_under_pressure()
     assert stats["preemptions"] > 0 and stats["resumes"] > 0, stats
-    assert any(half_made), "no victim was inside a block"
-    assert [results[i] for i in range(4)] == want
+    assert any(half_made for half_made, _ in victims), \
+        "no victim was inside a block"
+    assert got == want
 
 
 def test_answers_do_not_depend_on_the_tables_rung():
@@ -634,37 +653,188 @@ def test_the_other_families_streams_are_what_they_were(family):
     assert got == together
 
 
-def test_the_block_family_keeps_no_step_ahead():
-    """What a pass carries depends on the values of the pass before it
-    (a block's tokens, and under the dynamic rule how many positions it
-    fixed), so this family says so and the one loop reads every pass
-    before it schedules the next: none is ever in flight at a launch,
-    and the counter of steps launched ahead stays 0 while the
-    autoregressive families' flag is set."""
-    from ray_tpu.serve.llm_engine import hybrid
-
-    assert paged_model.PAGED.ahead and hybrid.FAMILY.ahead
-    engine = make_engine()
+@contextlib.contextmanager
+def reading_first(engine):
+    """The engine as it was before this family kept a pass ahead: its
+    family says of every row that its next pass is not known early."""
+    family = engine._family
+    engine._family = dataclasses.replace(family, ahead=lambda req: False)
     try:
-        assert not engine._family.ahead
-        step, in_flight = engine._decode_step, []
-
-        def watching(*args):
-            in_flight.append(engine._unread)
-            return step(*args)
-
-        engine.__dict__["_decode_step"] = watching
-        requests = [engine.submit(
-            prompt_of(n, seed=9), max_new_tokens=new, remasking=rule,
-            denoising_steps=steps) for n, new, rule, steps in SCHEDULES]
-        got = [engine.result(r, timeout_s=300) for r in requests]
-        stats = engine.engine_stats()
-        assert engine._unread is None
+        yield
     finally:
-        engine.shutdown()
-    assert got == [by_reference(prompt_of(n, seed=9), new, remasking=rule,
-                                denoising_steps=steps)
-                   for n, new, rule, steps in SCHEDULES]
-    assert stats["decode_steps"] == len(in_flight) > 10
-    assert in_flight == [None] * len(in_flight)
-    assert stats["decode_steps_ahead"] == 0
+        engine._family = family
+
+
+def serve_watched(engine, schedules, seed=9):
+    """The schedules' streams, served together; the step in flight at
+    each launch (None: none); the counters' rise."""
+    step, in_flight = engine._decode_step, []
+
+    def watching(*args):
+        in_flight.append(engine._unread)
+        return step(*args)
+
+    before = engine.engine_stats()
+    engine.__dict__["_decode_step"] = watching
+    try:
+        with engine._lock:     # its loop meets them all at once
+            requests = [engine.submit(
+                prompt_of(n, seed=seed), max_new_tokens=new, remasking=rule,
+                denoising_steps=steps) for n, new, rule, steps in schedules]
+        got = [engine.result(r, timeout_s=300) for r in requests]
+        assert engine._unread is None  # nothing left in flight
+    finally:
+        engine.__dict__["_decode_step"] = step
+    after = engine.engine_stats()
+    return got, in_flight, {k: after[k] - before[k] for k in after}
+
+
+COUNTED = [(8, 16, "sequential", 2), (5, 14, "low_confidence_static", 4),
+           (2, 13, "low_confidence_static", 1), (19, 12, "sequential", 3)]
+
+
+@pytest.mark.parametrize("case", ["every-rule", "counted", "dynamic",
+                                  "pressed"])
+def test_the_block_family_keeps_a_pass_ahead(engine, case):
+    """What a pass needs of the one before it is the block, which lies
+    on the device, and counts the host has: the one loop launches pass
+    N+1 before it reads pass N, and the streams are the reference's and
+    those of an engine that reads every pass first. A denoising pass
+    under the dynamic rule fixes as many positions as pass its
+    threshold, so a row on one makes the loop read first; so does cache
+    pressure, which rebuilds a victim from what was emitted."""
+    if case == "pressed":
+        got, stats, victims = served_under_pressure()
+        assert got == [by_reference(p, 14) for p in PRESSED_PROMPTS]
+        assert len(victims) == stats["preemptions"] > 0
+        assert [unread for _, unread in victims] == [None] * len(victims)
+        assert 0 < stats["decode_steps_ahead"] < stats["decode_steps"]
+        return
+    schedules = {"every-rule": SCHEDULES, "counted": COUNTED,
+                 "dynamic": [(6, 12, "low_confidence_dynamic", 4),
+                             (3, 16, "sequential", 2)]}[case]
+    got, in_flight, stats = serve_watched(engine, schedules)
+    with reading_first(engine):
+        at_once, none_in_flight, stats_at_once = serve_watched(
+            engine, schedules)
+    assert got == at_once == [
+        by_reference(prompt_of(n, seed=9), new, remasking=rule,
+                     denoising_steps=steps)
+        for n, new, rule, steps in schedules]
+    steps, ahead = stats["decode_steps"], stats["decode_steps_ahead"]
+    assert steps == len(in_flight) > 10
+    assert ahead == sum(step is not None for step in in_flight)
+    assert none_in_flight == [None] * stats_at_once["decode_steps"]
+    assert stats_at_once["decode_steps_ahead"] == 0
+    # The same passes for every row, whoever read them when.
+    for key in ("decode_tokens", "block_rows", "commit_rows"):
+        assert stats[key] == stats_at_once[key], key
+    if case == "counted":
+        # Every pass but the first few (no pass before them, or only
+        # rows that joined since) went ahead.
+        assert steps - 4 <= ahead < steps
+    elif case == "dynamic":
+        # Four denoising passes a block under the dynamic rule, each
+        # read before the next is packed; the finishing passes and the
+        # other row's tail go ahead.
+        assert 0 < ahead <= steps - 4 * 3
+    else:
+        assert 0 < ahead < steps
+
+
+@pytest.mark.parametrize("rule", paged_model.REMASKING[:2])
+@pytest.mark.parametrize("steps", [1, 2, 3, 4, 9])
+@pytest.mark.parametrize("size", [2, 4, 8])
+def test_the_row_one_pass_on_is_known_by_counting(size, steps, rule):
+    """No engine: for prompts that leave 0 to ``size - 1`` known
+    positions in the first block, answers that end inside a block, at
+    its end and at the table's, every pass of the request: the row
+    ``block_row_of(req, True)`` tells before the pass is read is the
+    row ``block_row_of(req)`` tells after ``advance_block`` on what a
+    pass could have made, but for the block, which stays on the device;
+    and ``block_lead`` says the row ends where ``_deliver_locked``
+    then does, and else where it moved."""
+    import types
+
+    from ray_tpu.serve.llm_engine.scheduler import EngineRequest, Scheduler
+
+    max_tokens = 6 * size
+    engine = types.SimpleNamespace(max_tokens=max_tokens, _counters={
+        "first_tokens": 0, "queue_wait_us": 0, "prefill_us": 0})
+    rng = np.random.default_rng([size, steps])
+    sched = Scheduler(PagedKVCache(4, size, 6), 1, 4, max_tokens, size)
+    passes = 0
+    for known in range(size):
+        for new in (1, size - known, size - known + 1, 2 * size + 1,
+                    max_tokens):
+            req = EngineRequest(
+                list(range(1, 1 + size + known)), new, 0.0,
+                denoising_steps=steps, remasking=rule)
+            sched.waiting.append(req)
+            assert sched.claim_prefill() is req
+            sched.prefilling, req.position = None, len(req.context)
+            sched.release(req)
+            over = False
+            while not over:
+                assert paged_model.block_counts(req)
+                told = paged_model.block_row_of(req, True)
+                lead = paged_model.block_lead(req, max_tokens)
+                position = req.position
+                # What a pass could have made: ``fix`` of the masked
+                # positions fixed, whichever the rule picked.
+                block, _, _, fix, *_ = paged_model.block_row_of(req)
+                masked = [i for i, t in enumerate(block) if t == MASKED]
+                fixed = rng.permutation(masked)[:fix] if rule != "sequential" \
+                    else masked[:fix]
+                out = [7 if i in fixed else t for i, t in enumerate(block)]
+                tokens, committed = paged_model.advance_block(req, out)
+                assert committed == (not masked)
+                over = LLMEngine._deliver_locked(engine, req, tokens)
+                passes += 1
+                assert over == (lead is None), (known, new, req.block)
+                if over:
+                    break
+                assert req.position == position + lead
+                after = paged_model.block_row_of(req)
+                assert told[1:] == after[1:]
+                # A fresh block is packed; one a pass left is not.
+                assert told[0] == (after[0] if committed else None)
+                # Packed, the two differ by the phase's sign and the
+                # block's columns alone.
+                ahead, read = (paged_model.pack_block_rows(size, 1, 6, [row])
+                               for row in (told, after))
+                if not committed:
+                    assert ahead[0, 5] == -read[0, 5]
+                    assert not ahead[0, 6:6 + size].any()
+                    ahead[0, 5:6 + size] = read[0, 5:6 + size]
+                np.testing.assert_array_equal(ahead, read)
+    assert passes > 5 * size
+
+
+def test_a_row_on_the_block_before_takes_it_from_prev():
+    """``pack_block_rows`` marks such a row by a negative phase and
+    leaves its block's columns zero; the program runs it on its row of
+    ``prev`` and every other row on the array's, and without ``prev``
+    (as ``benchmark/sizing_family.py`` lowers it) on the array's alone."""
+    table = [1, 2]
+    known = ([5, MASKED, 9, MASKED], 8, 0.0, 1, 0, 0.9, table)
+    rows = paged_model.pack_block_rows(
+        SIZE, 3, 2, [known, (None, 8, 0.0, 1, 0, 0.9, table),
+                     (None, 8, 0.0, 0, 0, 0.9, table)])
+    assert rows[:, 5].tolist() == [1, -1, -2]
+    assert not rows[1:, 6:6 + SIZE].any()
+    step = paged_model.make_engine_block_step(CFG, BLOCK)
+    key = jax.random.PRNGKey(0)
+    prev = jnp.asarray([[1, 2, 3, 4], [5, MASKED, 9, MASKED], [5, 6, 9, 3]])
+
+    def run(rows, *prev):
+        pool = PagedKVCache.init_pool(CFG, 3, BLOCK)
+        return np.asarray(step(PARAMS, pool, rows, key, None, *prev)[0])
+
+    after = run(rows, prev)
+    host = paged_model.pack_block_rows(SIZE, 3, 2, [
+        known, known, ([5, 6, 9, 3], 8, 0.0, 0, 0, 0.9, table)])
+    np.testing.assert_array_equal(after, run(host))
+    np.testing.assert_array_equal(after, run(host, prev))
+    assert after[0].tolist() == after[1].tolist() != known[0]
+    assert after[2].tolist() == [5, 6, 9, 3]

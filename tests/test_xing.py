@@ -506,8 +506,10 @@ def test_greedy_tokens_do_not_depend_on_the_chunk_width(chunk):
 
 def test_the_family_follows_from_the_configuration():
     family = paged_model.family(tiny())
-    assert family is latent.FAMILY and family.ahead
-    assert not family.recurrent
+    assert family is latent.FAMILY and not family.recurrent
+    # One token a row a pass: the step in flight is the dense model's.
+    assert family.ahead is paged_model.PAGED.ahead
+    assert family.lead is paged_model.PAGED.lead
     assert family.row_of is paged_model.PAGED.row_of
     assert family.pack_decode_rows is paged_model.PAGED.pack_decode_rows
     assert family.pack_prefill_chunk is paged_model.PAGED.pack_prefill_chunk
