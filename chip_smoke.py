@@ -24,8 +24,10 @@ file gives, the paged engine's two programs over shuffled block tables
 and for one context as long as the table) against the configuration's
 plain float32 reference: logits, and for a sparse model the expert
 choices that differ (for a latent-attention configuration every row of
-the engine busy, and a context that ends at each of the table's three
-widths).  ``--cell <workload> --round-weights <dtype>`` runs instead
+the engine busy, a context that ends at each of the table's three
+widths, prefilled at that width, and every decode step through the one
+width its program is built at, the whole table).  ``--cell <workload>
+--round-weights <dtype>`` runs instead
 that serve cell of the benchmark as ``benchmark/run.py`` does, with the
 replica's weights rounded and its check's left as the seed gives them:
 the control of the cell's ``logit_atol``, which has to come out not
@@ -1306,7 +1308,9 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
     a few chunks long. Each is prefilled chunk by chunk (expanded, at
     the narrowest width that holds it, as the engine hands a chunk its
     table), then all rows decode their last ``probes.max_new_tokens``
-    positions together (absorbed), teacher-forced. Compared with the
+    positions together (absorbed, every step through the whole table:
+    the one width an engine builds that program at, since the step
+    reads each row's own pages), teacher-forced. Compared with the
     float32 reference: the probes' decoded positions, and the last two
     chunks' worth of each long context, the reference computed with its
     queries in blocks and its head on the tail alone."""
@@ -1403,10 +1407,9 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
         for i, context in enumerate(contexts):
             last[i, 0] = context[prefilled[i] + step]
             positions[i] = prefilled[i] + step
-        step_width = rung(int(positions.max()) + 1)
         logits, cache, _, routing = shown_step(
             served, cache, jnp.asarray(last), jnp.asarray(positions),
-            jnp.asarray(tables[:, :step_width]))
+            jnp.asarray(tables))
         logits, routing = np.asarray(logits[:, 0], np.float32), \
             np.asarray(routing)
         for i in compared:
@@ -1422,8 +1425,9 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
     params = served            # the reference's: as the seed gives them
     gc.collect()
     say("latent", programs="prefill chunks (expanded) over shuffled tables "
-        f"at the widths {ends}, then {steps} decode steps (absorbed) of "
-        f"{rows} busy rows", positions_compared=sum(len(g) for g in got),
+        f"at the widths {ends}, then {steps} decode steps (absorbed, at "
+        f"the whole table) of {rows} busy rows",
+        positions_compared=sum(len(g) for g in got),
         device_bytes_in_use=device_bytes())
 
     short = contexts[:len(lengths)]

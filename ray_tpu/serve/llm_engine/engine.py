@@ -5,9 +5,11 @@ budgets, claim/advance ONE prefill chunk, then ONE fixed-shape decode
 step for every active stream — tokens stream out per step, finished
 rows free their blocks between steps, and cache pressure preempts the
 lowest-progress stream (recompute-on-resume) instead of failing it.
-The step's table is as wide as its longest live row needs and a
-chunk's as wide as its request's, of three widths (``table_widths``),
-each a program the constructor has built.
+A chunk's table is as wide as its request's needs, of three widths
+(``table_widths``), and so is a step's, as wide as its longest live
+row's, where the step gathers its width; a step that reads by row
+(``Family.reads_by_row``) has the whole table. Each is a program the
+constructor has built, and it builds no other.
 
 The loop keeps one decode step ahead: step N+1 is launched on step N's
 result where it lies on the device (the program's ``prev``), and only
@@ -139,6 +141,7 @@ ENGINE_STAT_KEYS = (
     "window_blocks_recycled", "state_resets",
     # Decode steps run at a table narrower than the whole
     # (``table_widths``): how often the width followed the context.
+    # 0 where the step reads by row: its one width is the whole.
     "decode_steps_narrow",
     # Decode steps launched while the step before was still unread, on
     # its tokens where they lay on the device.
@@ -167,16 +170,18 @@ _LIVE: "weakref.WeakSet" = weakref.WeakSet()
 
 
 def table_widths(blocks_per_seq: int) -> "tuple[int, ...]":
-    """The table widths a decode step or a prefill chunk may be given,
-    in blocks, narrowest first: a quarter, a half and the whole of a
-    row's table, each in whole blocks. Every row of a step gathers and
-    attends over the step's whole width (in four families of five: a
-    latent model's decode step reads each row's own pages through the
-    tables, ``Family.reads_by_row``, and its width only sizes the host
-    array), so a step takes the narrowest that holds its longest live
-    table, and a chunk the narrowest that holds its request's (at 128
-    tokens a chunk's float32 scores over Mistral's whole table are 33
-    MB a layer: 12.81 ms a chunk for 12.24 at the quarter, PR 38).
+    """The table widths a prefill chunk or a decode step that gathers
+    may be given, in blocks, narrowest first: a quarter, a half and the
+    whole of a row's table, each in whole blocks. A chunk gathers and
+    attends over its whole width, and so does every row of a step of a
+    family that gathers, so a chunk takes the narrowest that holds its
+    request's table and such a step the narrowest that holds its
+    longest live one (at 128 tokens a chunk's float32 scores over
+    Mistral's whole table are 33 MB a layer: 12.81 ms a chunk for 12.24
+    at the quarter, PR 38). A step that reads each row's own pages
+    through the tables (``Family.reads_by_row``) reads the same at
+    every width: it has one program, at the whole table, and the
+    engine builds it once (``LLMEngine._step_widths``).
     Three, because each is a program built before the engine serves:
     halving twice keeps the read within twice the longest context down
     to a quarter of the table, and a further rung would add a compile
@@ -250,14 +255,18 @@ class LLMEngine:
         self.max_batch = int(max_batch_size)
         self.max_len = int(max_seq_len or self.config.max_seq_len)
         self.block_size = int(block_size or GLOBAL_CONFIG.llm_block_size)
-        # Table width: blocks covering max_len, rounded up. A decode
-        # step takes the narrowest of ``_widths`` that holds its longest
-        # row's table and a prefill chunk the narrowest that holds its
-        # request's, so each program exists once a width, at
-        # [max_batch or 1, width * block_size] attention width.
+        # Table width: blocks covering max_len, rounded up. A prefill
+        # chunk takes the narrowest of ``_widths`` that holds its
+        # request's table and a decode step the narrowest of
+        # ``_step_widths`` that holds its longest row's, so each
+        # program exists once a width it can be given, at [max_batch or
+        # 1, width * block_size] attention width. A step that reads by
+        # row reads the same at any width: it has the whole table.
         self.blocks_per_seq = -(-self.max_len // self.block_size)
         self.max_tokens = self.blocks_per_seq * self.block_size
         self._widths = table_widths(self.blocks_per_seq)
+        self._step_widths = self._widths[-1:] \
+            if self._family.reads_by_row else self._widths
         self.prefill_chunk_len = int(prefill_chunk or default_prefill_chunk(
             self.max_tokens, self.block_size))
         if num_blocks is None:
@@ -334,9 +343,10 @@ class LLMEngine:
             self.config, self.block_size, self.prefill_chunk_len)
 
     def _build_programs(self) -> None:
-        """Both programs at every width, before the loop takes a
-        request: a width first met while serving would compile while
-        rows wait. The decode program is run once on rows that are all
+        """Each program at every width it can be given (``_widths``,
+        ``_step_widths``), before the loop takes a request: a width
+        first met while serving would compile while rows wait. The
+        decode program is run once on rows that are all
         inactive (they write the scratch block, advance no state, and
         their samples are thrown away), the prefill program on a chunk
         that is all padding (it writes the scratch block, no ring, and
@@ -349,12 +359,14 @@ class LLMEngine:
         compiled by name first: the call then finds the program, and
         the two together take 0.4 s where the call alone took 0.6."""
         for width in self._widths:
-            rows = self._family.pack_decode_rows(self.max_batch, width, ())
-            args = (self.params, self._pool, rows, self._key,
-                    self._expert_stats, self._no_prev)
-            with jax_compat.set_mesh(self._mesh):
-                self._decode_step.lower(*args).compile()
-                _, self._pool, _, _ = self._decode_step(*args)
+            if width in self._step_widths:
+                rows = self._family.pack_decode_rows(
+                    self.max_batch, width, ())
+                args = (self.params, self._pool, rows, self._key,
+                        self._expert_stats, self._no_prev)
+                with jax_compat.set_mesh(self._mesh):
+                    self._decode_step.lower(*args).compile()
+                    _, self._pool, _, _ = self._decode_step(*args)
             chunk = self._family.pack_prefill_chunk(
                 self.prefill_chunk_len, width, (), 0, (), 0)
             args = (self.params, self._pool, chunk, self._expert_stats)
@@ -362,11 +374,13 @@ class LLMEngine:
                 self._prefill_step.lower(*args).compile()
                 _, self._pool, _ = self._prefill_step(*args)
 
-    def _rung(self, blocks: int) -> int:
-        """The narrowest of ``_widths`` that holds a table of ``blocks``:
-        what a decode step (its longest live row's) and a prefill chunk
-        (its request's) attend over."""
-        return next(w for w in self._widths if w >= blocks)
+    @staticmethod
+    def _rung(widths, blocks: int) -> int:
+        """The narrowest of ``widths`` that holds a table of ``blocks``:
+        what a prefill chunk (of ``_widths``, its request's) and a
+        decode step (of ``_step_widths``, its longest live row's) are
+        given."""
+        return next(w for w in widths if w >= blocks)
 
     def _new_pool(self) -> dict:
         """The family's cache, zeroed: one dict, donated to every step.
@@ -693,7 +707,7 @@ class LLMEngine:
                     start = req.prefilled
                     table = list(req.block_table)
                     # As far as the request has it now.
-                    width = self._rung(len(table))
+                    width = self._rung(self._widths, len(table))
             if status == _UNREAD and not self._read_unread():
                 return True  # the read failed: every request with it
         if status == "shed":
@@ -877,7 +891,7 @@ class LLMEngine:
         # As held now: a row sealed while the step runs loses its.
         slots = [req.slot for req in active]
         longest = max(len(req.block_table) for req in active)
-        width = self._rung(longest)
+        width = self._rung(self._step_widths, longest)
         rows = family.pack_decode_rows(
             self.max_batch, width, map(family.row_of, active, ahead), slots)
         live = sum(positions) + span * len(active)

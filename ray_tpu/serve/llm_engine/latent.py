@@ -16,8 +16,8 @@ their own.
   latent space, scores and values taken on the latents where they lie,
   every head sharing the one read, and THROUGH THE TABLES
   (``ops/paged_latent_attention.py``): each row its own live pages,
-  each latent once, the row's fresh entry beside them, whatever the
-  step's table width; no gathered view exists
+  each latent once, the row's fresh entry beside them: the step has
+  one program, at the whole table; no gathered view exists
   (``xing.attend_absorbed`` is the plain form over one). The **prefill
   chunk** gathers its one row's view ``[1, S, pool_lanes]`` with the
   chunk's entries written first, as ``model._forward_paged`` does, and

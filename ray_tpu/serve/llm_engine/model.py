@@ -26,9 +26,11 @@ reading and writing the PAGED pool:
   the same code, with a row of zeros beside a decode step's lone query
   row so that the product stays a matrix product;
 - **fixed shapes**: batch ``B``, table width ``M`` and chunk length
-  ``C`` are compile-time constants: one decode program and one prefill
-  program a table width the engine hands them (``engine.table_widths``),
-  every step hits the jit cache;
+  ``C`` are compile-time constants: one prefill program a table width
+  the engine hands it (``engine.table_widths``) and one decode program
+  a width a step can be given (the same three where the step gathers
+  its width, the whole table alone where it reads by row), every step
+  hits the jit cache;
 - **the head on one row**: a prefill chunk computes the final norm and
   the logits of the one position that is read (its last real token);
 - **donation**: the pool is donated through every call (decode updates

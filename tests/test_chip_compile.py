@@ -1,64 +1,32 @@
 """What the chip's compiler says, asked without the chip; and the rules
 that keep one process on each chip.
 
-The first half compiles the main path's kernels at real widths for a
-DESCRIBED ``v5e:2x2`` (guide on-chip-measurement, 2.3): the TPU compiler
-is installed here and refuses what the chip would refuse — a slice off
-the tiling, too much VMEM — which interpret mode never notices. Nothing
-runs, so these say nothing about results or times. Skipped where the
-topology cannot be described. The second half are CPU tests of the
-rules of PR 21: who may see a chip, where the compile cache lives, no
-CPU fallback on the measuring path.
+The first half compiles the train cells' main path (the flash kernels,
+the fused norm, the whole train step) at real widths for a DESCRIBED
+``v5e:2x2`` (``v5e_compile.py``, guide on-chip-measurement, 2.3); the
+serve cells' programs have a file a family beside this one
+(``test_chip_compile_paged.py``, ``_hybrid``, ``_block``, ``_latent``).
+The second half are CPU tests of the rules of PR 21: who may see a
+chip, where the compile cache lives, no CPU fallback on the measuring
+path.
 """
 
 import dataclasses
 import importlib
 import json
-import math
 import os
 import subprocess
 import sys
 
-os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
-
 import jax
 import jax.numpy as jnp
 import pytest
+from v5e_compile import v5e_chip, v5e_devices  # noqa: F401 — the fixtures
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # ray_tpu.ops re-exports the function under the module's name.
 fa = importlib.import_module("ray_tpu.ops.flash_attention")
-
-
-@pytest.fixture(scope="module")
-def v5e_devices():
-    """The four chips of a described v5e:2x2. The persistent
-    compilation cache is off around these compiles: an entry written
-    for a described device cannot be read back without the chip, and
-    the next run would warn."""
-    from jax.experimental import topologies
-    from jax.experimental.compilation_cache import compilation_cache
-
-    try:
-        topo = topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2")
-    except Exception as exc:  # noqa: BLE001 — no TPU compiler here
-        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc!r}")
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield topo.devices
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
-@pytest.fixture(scope="module")
-def v5e_chip(v5e_devices):
-    """Sharding on one chip of the described v5e:2x2."""
-    from jax.sharding import SingleDeviceSharding
-
-    return SingleDeviceSharding(v5e_devices[0])
 
 
 def _flash_grad(seq, heads, kv_heads, head_dim, chip):
@@ -125,591 +93,6 @@ def test_fused_rms_norm_compiles_for_v5e(v5e_chip):
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         x, scale).compile()
     assert "tpu_custom_call" in compiled.as_text()
-
-
-def _lower_paged_step(program, config, batch, block, table, chip,
-                      width=None, prev=False):
-    """``decode_step`` or ``prefill_chunk``, the plain program or, as
-    ``engine_...``, the one the engine calls (one host array, the key
-    carried), lowered on shapes placed on the described chip; the
-    pool's shape beside it. A sparse configuration's step carries its
-    expert accumulator. ``width``: the blocks of a row's table the
-    engine's decode step or prefill chunk is given
-    (``engine.table_widths``; the pool stays ``table`` blocks a row);
-    the chunk is the default's length; ``prev``: with the step before's
-    tokens ``[batch]`` as the engine passes them (without: the
-    five-argument call of ``benchmark/sizing.py``)."""
-    from ray_tpu._private.config import GLOBAL_CONFIG
-    from ray_tpu.models import llama, moe
-    from ray_tpu.serve.llm_engine import model as paged_model
-
-    def on_chip(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
-
-    params = jax.tree.map(
-        lambda s: on_chip(s.shape, config.dtype),
-        jax.eval_shape(
-            lambda: llama.init_params(config, jax.random.PRNGKey(0))))
-    pool_shape = (config.num_layers, 1 + batch * table, block,
-                  config.num_kv_heads, config.head_dim)
-    pool = {"k": on_chip(pool_shape, config.dtype),
-            "v": on_chip(pool_shape, config.dtype)}
-    stats = None
-    if config.num_experts > 0:
-        stats = on_chip(jax.eval_shape(moe.init_stats).shape)
-    chunk = GLOBAL_CONFIG.llm_prefill_chunk
-    if program == "engine_decode_step":
-        lowered = paged_model.make_engine_decode_step(config, block).lower(
-            params, pool, on_chip((batch, 3 + (width or table))),
-            on_chip((2,), jnp.uint32), stats,
-            *([on_chip((batch,))] if prev else []))
-    elif program == "engine_prefill_chunk":
-        lowered = paged_model.make_engine_prefill_chunk(
-            config, block, chunk).lower(
-                params, pool, on_chip((2 + 2 * chunk + (width or table),)),
-                stats)
-    elif program == "decode_step":
-        lowered = paged_model.make_decode_step(config, block).lower(
-            params, pool, on_chip((batch, 1)), on_chip((batch,)),
-            on_chip((batch, table)), on_chip((2,), jnp.uint32),
-            on_chip((batch,), jnp.float32), stats)
-    else:
-        lowered = paged_model.make_prefill_chunk(config, block).lower(
-            params, pool, on_chip((1, chunk)), on_chip((1, chunk)),
-            on_chip((1, table)), on_chip(()), on_chip(()), stats)
-    return lowered, pool_shape
-
-
-def test_engine_decode_compiles_for_v5e(v5e_chip):
-    """The paged engine's ONE decode program at chip_smoke.py's widths
-    (Llama-2-7B, 2 layers): fits one chip with room to spare."""
-    from ray_tpu.models import llama
-
-    config = dataclasses.replace(
-        llama.LlamaConfig.llama2_7b(), num_layers=2, max_seq_len=1024)
-    lowered, _ = _lower_paged_step("decode_step", config, 8, 16,
-                                   1024 // 16, v5e_chip)
-    memory = lowered.compile().memory_analysis()
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            < 8 * 2 ** 30)
-
-
-def _mistral_serve():
-    """The Mistral serve cells' widths, 2 of their 16 layers."""
-    from ray_tpu.models import llama
-
-    return llama.LlamaConfig(
-        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
-        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
-        max_seq_len=2048, rope_theta=1e6)
-
-
-@pytest.mark.parametrize("program", [
-    "decode_step", "prefill_chunk", "engine_decode_step",
-    "engine_prefill_chunk"])
-def test_paged_steps_update_the_pool_in_place_on_v5e(v5e_chip, program):
-    """The serve cells' widths (Mistral-7B-v0.3: 32 query on 8 key-value
-    heads of 128; 2 of its layers, 16 rows, 128 blocks of 16). The chip's
-    compiler must keep the donated pool where it is and repeat or widen
-    nothing the size of the gathered keys: at these sizes a repeated
-    float32 copy of them is 0.5 GiB and a copy of the 2-layer pool 0.13
-    GiB, and neither shows in a CPU test."""
-    lowered, pool_shape = _lower_paged_step(program, _mistral_serve(), 16,
-                                            16, 128, v5e_chip)
-    compiled = lowered.compile()
-    memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 0.25 * 2 ** 30
-    # k and v, two bytes an element, both updated where they were given.
-    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
-    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
-    assert [line for line in compiled.as_text().splitlines()
-            if " copy(" in line and pool_text in line] == []
-
-
-def _olmoe(num_layers=12):
-    """``benchmark/configs/olmoe-1b-7b-serve-1chip.json`` as the
-    harness builds it: OLMoE-1B-7B's widths, 12 of its 16 layers."""
-    from ray_tpu.models import llama
-
-    return llama.LlamaConfig(
-        vocab_size=50304, hidden_size=2048, intermediate_size=1024,
-        num_layers=num_layers, num_heads=16, num_kv_heads=16, head_dim=128,
-        max_seq_len=2048, num_experts=64, experts_per_token=8, qk_norm=True)
-
-
-# A float32 tensor the size of one layer's experts, alone or stacked.
-F32_EXPERTS = r"f32\[(\d+,)?64,(2048,1024|1024,2048)\]"
-
-
-@pytest.mark.parametrize("program, temporaries_mib", [
-    ("decode_step", 160), ("prefill_chunk", 16),
-    ("engine_decode_step", 160), ("engine_prefill_chunk", 16)])
-def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
-        v5e_chip, program, temporaries_mib):
-    """The OLMoE serve cell's two programs at its real size (16 rows x
-    2048 positions, 12 layers: 12.76 GiB of arguments). What must not
-    appear: a float32 copy of an expert tensor (1.5 GiB a layer) or a
-    transposed bf16 one (768 MiB a layer: a flat ``bth,ehm->btem``
-    product made the compiler transpose each [64, 2048, 1024] whole);
-    a copy of the donated pool; a float32 copy of a layer's gathered
-    keys (256 MiB: a decode step's lone query row per head made the
-    scores a matrix-vector product, which the compiler widened the keys
-    for, until ``_paged_attention_block`` put a row of zeros beside
-    it). The decode program's 129 MiB of temporaries are one gathered
-    ``bf16[2048,16,16,128]`` (with 16 key-value heads it no longer fits
-    the memory space Mistral's 64 MiB ones live in); the chunk program
-    has none to speak of."""
-    import re
-
-    lowered, pool_shape = _lower_paged_step(program, _olmoe(), 16, 16, 128,
-                                            v5e_chip)
-    compiled = lowered.compile()
-    memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < temporaries_mib * 2 ** 20
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            < 0.9 * 15.75 * 2 ** 30)
-    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
-    text = compiled.as_text()
-    assert re.search(F32_EXPERTS, text) is None
-    assert re.search(r"= f32\[(2048,16|16,2048),16,128\]", text) is None
-    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
-    assert [line for line in text.splitlines()
-            if " copy(" in line and pool_text in line] == []
-    # The accumulator rides along: int32 [2, 4] in, the same out.
-    assert "s32[2,4]" in text
-
-
-@pytest.mark.parametrize("width", [32, 64, 128])
-@pytest.mark.parametrize("model", ["mistral", "olmoe"])
-def test_decode_step_at_each_table_width_on_v5e(v5e_chip, model, width):
-    """The engine's decode program at the three widths it is built at
-    (``engine.table_widths`` of 128 blocks: 512, 1024 and 2048 positions
-    a row), 16 rows over the whole pool, for both 16-row serve
-    configurations: what the cases above hold the whole width to holds
-    at a quarter and a half of it. The pool is updated where it lies and
-    never copied; the gathered keys are never widened to float32 (D9's
-    row of zeros keeps the scores a bf16 matrix product with 16
-    key-value heads and no grouping); under the whole width there are
-    no temporaries to speak of."""
-    import re
-
-    from ray_tpu.serve.llm_engine.engine import table_widths
-
-    assert width in table_widths(128)
-    config = _mistral_serve() if model == "mistral" else _olmoe(2)
-    lowered, pool_shape = _lower_paged_step(
-        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width)
-    compiled = lowered.compile()
-    memory = compiled.memory_analysis()
-    # Under the whole width the gathered keys leave HBM's temporaries.
-    assert memory.temp_size_in_bytes < (160 if width == 128 else 16) * 2 ** 20
-    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
-    text = compiled.as_text()
-    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
-    assert [line for line in text.splitlines()
-            if " copy(" in line and pool_text in line] == []
-    positions, kv = width * 16, config.num_kv_heads
-    assert re.search(rf"= f32\[({positions},16|16,{positions}),{kv},128\]",
-                     text) is None
-    assert re.search(F32_EXPERTS, text) is None
-    # The gather is of this width, in the pool's dtype.
-    assert re.search(rf"bf16\[({positions},16|16,{positions}),{kv},128\]",
-                     text) is not None
-    if width < 128:
-        assert f"[2048,16,{kv},128]" not in text
-
-
-def _sdar(num_layers=2):
-    """``benchmark/configs/sdar-30b-a3b-serve-1chip.json`` as the
-    harness builds it: SDAR-30B-A3B's widths, 2 of the cell's 7 layers."""
-    from ray_tpu.models import llama
-
-    return llama.LlamaConfig(
-        vocab_size=151936, hidden_size=2048, intermediate_size=768,
-        num_layers=num_layers, num_heads=32, num_kv_heads=4, head_dim=128,
-        max_seq_len=2048, rope_theta=1e6, rms_norm_eps=1e-6,
-        num_experts=128, experts_per_token=8, norm_topk_prob=True,
-        qk_norm="head", block_length=4, denoising_steps=2,
-        mask_token_id=151669)
-
-
-@pytest.mark.parametrize("width", [32, 64, 128])
-@pytest.mark.parametrize("model", ["mistral", "olmoe", "sdar"])
-def test_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, model, width):
-    """The engine's prefill program at the default chunk of 128 tokens
-    and at the three widths it is built at, for the three serve
-    configurations of identical layers (Mistral and OLMoE 16 rows, SDAR
-    32, over the whole pool). The pool is updated where it lies and
-    never copied; the head runs on the one row that is read, so nothing
-    the size of a chunk's logits exists (float32 ``[128, vocabulary]``:
-    16 MB for Mistral, 78 for SDAR); the scores are as wide as the rung
-    and no wider; no expert tensor is widened or transposed; and the
-    temporaries stay under 64 MiB."""
-    import re
-
-    from ray_tpu._private.config import GLOBAL_CONFIG
-    from ray_tpu.serve.llm_engine.engine import table_widths
-
-    assert width in table_widths(128)
-    chunk = GLOBAL_CONFIG.llm_prefill_chunk
-    config, rows = {"mistral": (_mistral_serve(), 16),
-                    "olmoe": (_olmoe(2), 16), "sdar": (_sdar(), 32)}[model]
-    lowered, pool_shape = _lower_paged_step(
-        "engine_prefill_chunk", config, rows, 16, 128, v5e_chip, width=width)
-    compiled = lowered.compile()
-    memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 64 * 2 ** 20
-    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
-    text = compiled.as_text()
-    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
-    assert [line for line in text.splitlines()
-            if " copy(" in line and pool_text in line] == []
-    vocabulary, positions = config.vocab_size, width * 16
-    assert re.search(rf"\[(1,)?{chunk},{vocabulary}\]", text) is None
-    assert re.search(rf"f32\[(1,)?2,{vocabulary}\]", text) is not None
-    # The chunk's scores: every query row against the rung's positions.
-    assert re.search(rf"f32\[[0-9,]*{chunk},{positions}\]", text) is not None
-    if width < 128:
-        assert re.search(rf"f32\[(\d+,){{2,}}{chunk},2048\]", text) is None
-    assert re.search(
-        rf"= f32\[({positions},16|16,{positions}),{config.num_kv_heads},128\]",
-        text) is None
-    if config.num_experts:
-        e, m = config.num_experts, config.intermediate_size
-        assert re.search(rf"f32\[(\d+,)?{e},(2048,{m}|{m},2048)\]",
-                         text) is None
-
-
-@pytest.mark.parametrize("width", [64, 128, 256])
-def test_hybrid_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, width):
-    """Phi-4-mini-flash's prefill program (published widths, 32 rows, a
-    table of 256 blocks of 16; 8 of its 32 layers) at the default chunk
-    and its three widths: the three caches updated where they lie, the
-    rings as long as the window, the chunk and a block (656 positions a
-    row), the one pool gathered at the chunk's width for its one
-    request, and logits of one row of the 200,064 words."""
-    import re
-
-    from ray_tpu._private.config import GLOBAL_CONFIG
-    from ray_tpu.models import phi4flash
-    from ray_tpu.serve.llm_engine import hybrid
-
-    config = phi4flash.Phi4FlashConfig(num_layers=8)
-    rows, block, table = 32, 16, 256
-    chunk = GLOBAL_CONFIG.llm_prefill_chunk
-
-    def on_chip(tree, dtype=None):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
-
-    params = on_chip(jax.eval_shape(lambda: hybrid.FAMILY.init_params(
-        config, jax.random.PRNGKey(0))))
-    cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
-        config, 1 + rows * table, block, rows, chunk)))
-    assert cache["win_k"].shape[2] == 512 + chunk + block == 656
-    compiled = hybrid.make_engine_prefill_chunk(config, block, chunk).lower(
-        params, cache,
-        on_chip(hybrid.pack_prefill_chunk(chunk, width, (), 0, (), 0),
-                jnp.int32), None).compile()
-    memory = compiled.memory_analysis()
-    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
-                      for c in jax.tree.leaves(cache))
-    assert memory.alias_size_in_bytes >= cache_bytes
-    # The float32 scores of the 40 heads (116 MiB at the whole width).
-    assert memory.temp_size_in_bytes < 160 * 2 ** 20
-    text = compiled.as_text()
-    positions = width * block
-    assert f"bf16[{width},{block},1280]" in text
-    assert f"f32[1,40,{chunk},{positions}]" in text
-    assert re.search(rf"\[(1,)?{chunk},200064\]", text) is None
-    assert "f32[1,2,200064]" in text
-    assert [line for line in text.splitlines()
-            if " copy(" in line and "= bf16[1,8193,16,1280]" in line] == []
-    if width < table:
-        assert re.search(r"\[[0-9,]*4096[0-9,]*\]", text) is None
-
-
-def _memory_of(compiled) -> tuple:
-    memory = compiled.memory_analysis()
-    return (memory.alias_size_in_bytes, memory.temp_size_in_bytes,
-            memory.argument_size_in_bytes)
-
-
-@pytest.mark.parametrize("width", [32, 64, 128])
-@pytest.mark.parametrize("model", ["mistral", "olmoe"])
-def test_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip, model,
-                                                          width):
-    """The program the engine runs since it keeps a step ahead: the
-    step before's tokens ``[16]`` int32 as a sixth argument, one select
-    in front of the embedding. Beside the five-argument program (which
-    ``benchmark/sizing.py`` still lowers) at the same width: the pool
-    aliased as much, the temporaries the same to within a few vectors of
-    16, the arguments 64 bytes more (the tokens, padded), and ``prev``
-    an argument that is read."""
-    config = _mistral_serve() if model == "mistral" else _olmoe(2)
-    without, pool_shape = _lower_paged_step(
-        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width)
-    with_prev, _ = _lower_paged_step(
-        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width,
-        prev=True)
-    assert len(with_prev.in_avals[0]) == len(without.in_avals[0]) + 1 == 6
-    compiled, before = with_prev.compile(), without.compile()
-    alias, temp, arguments = _memory_of(compiled)
-    alias_before, temp_before, arguments_before = _memory_of(before)
-    assert alias == alias_before >= 2 * 2 * math.prod(pool_shape)
-    assert abs(temp - temp_before) < 64 * 2 ** 10
-    assert 0 < arguments - arguments_before <= 4096
-    text = compiled.as_text()
-    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
-    assert [line for line in text.splitlines()
-            if " copy(" in line and pool_text in line] == []
-    # Kept by the program (jit drops an argument nothing reads).
-    def entry_arguments(hlo):
-        layout = hlo[hlo.index("entry_computation_layout={("):]
-        return layout[:layout.index(")->")]
-
-    assert "s32[16]{" in entry_arguments(text)
-    assert "s32[16]{" not in entry_arguments(before.as_text())
-
-
-def test_block_step_with_prev_on_v5e(v5e_chip):
-    """The block family's decode program as the engine runs it since it
-    keeps a pass ahead (SDAR's widths, 2 of the cell's 7 layers, 32 rows
-    at the whole table of 128 blocks of 16): the blocks the pass before
-    left, ``[32, 4]`` int32, as a sixth argument and one select in front
-    of the embedding. Beside the five-argument program (which
-    ``benchmark/sizing_family.py`` still lowers): the pool aliased as
-    much, the temporaries (76 MiB: the float32 logits of 128 positions)
-    the same to within 0.5 MiB (the compiler assigns a few small buffers
-    to other memory spaces: 250 KiB), and ``prev`` an argument that is
-    read."""
-    from ray_tpu.models import moe
-    from ray_tpu.serve.llm_engine import model as paged_model
-
-    config, rows, block, table = _sdar(), 32, 16, 128
-    family = paged_model.family(config)
-
-    def on_chip(tree, dtype=None):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
-
-    params = on_chip(jax.eval_shape(lambda: family.init_params(
-        config, jax.random.PRNGKey(0))), config.dtype)
-    cache = on_chip(jax.eval_shape(lambda: family.init_cache(
-        config, 1 + rows * table, block, rows, 128)))
-    args = (params, cache,
-            on_chip(family.pack_decode_rows(rows, table, ()), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip),
-            on_chip(jax.eval_shape(moe.init_stats)))
-    step = family.make_engine_decode_step(config, block)
-    prev = jax.ShapeDtypeStruct((rows, config.block_length), jnp.int32,
-                                sharding=v5e_chip)
-    compiled, before = step.lower(*args, prev).compile(), \
-        step.lower(*args).compile()
-    alias, temp, arguments = _memory_of(compiled)
-    alias_before, temp_before, arguments_before = _memory_of(before)
-    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
-                      for c in jax.tree.leaves(cache))
-    assert alias == alias_before >= cache_bytes
-    assert abs(temp - temp_before) < 512 * 2 ** 10 < temp / 100
-    assert 0 < arguments - arguments_before <= 4096
-
-    def entry_arguments(hlo):
-        layout = hlo[hlo.index("entry_computation_layout={("):]
-        return layout[:layout.index(")->")]
-
-    assert "s32[32,4]{" in entry_arguments(compiled.as_text())
-    assert "s32[32,4]{" not in entry_arguments(before.as_text())
-
-
-@pytest.mark.parametrize("width", [64, 128, 256])
-def test_hybrid_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip,
-                                                                 width):
-    """The same for Phi-4-mini-flash's decode program (32 rows, 8 of its
-    32 layers): with the step before's tokens as a sixth argument the
-    three caches are still updated where they lie and the temporaries
-    are the five-argument program's."""
-    from ray_tpu.models import phi4flash
-    from ray_tpu.serve.llm_engine import hybrid
-
-    config = phi4flash.Phi4FlashConfig(num_layers=8)
-    rows, block, table, chunk = 32, 16, 256, 128
-
-    def on_chip(tree, dtype=None):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
-
-    params = on_chip(jax.eval_shape(lambda: hybrid.FAMILY.init_params(
-        config, jax.random.PRNGKey(0))))
-    cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
-        config, 1 + rows * table, block, rows, chunk)))
-    args = (params, cache,
-            on_chip(hybrid.pack_decode_rows(rows, width, ()), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None)
-    step = hybrid.make_engine_decode_step(config, block)
-    prev = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
-    alias, temp, arguments = _memory_of(step.lower(*args, prev).compile())
-    alias_before, temp_before, arguments_before = _memory_of(
-        step.lower(*args).compile())
-    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
-                      for c in jax.tree.leaves(cache))
-    assert alias == alias_before >= cache_bytes
-    assert abs(temp - temp_before) < 64 * 2 ** 10
-    assert 0 < arguments - arguments_before <= 4096
-
-
-@pytest.mark.parametrize("width", [64, 128, 256])
-def test_hybrid_decode_step_at_each_table_width_on_v5e(v5e_chip, width):
-    """Phi-4-mini-flash's decode program (published widths, 32 rows, a
-    table of 256 blocks of 16; 8 of its 32 layers: the scans make the
-    program the same but for their length) at its three widths, 1024,
-    2048 and 4096 positions a row: the three caches updated where they
-    lie, the one pool gathered at the step's width in bf16 and never
-    widened, and the temporaries (the gathered keys and values: 0.64 GiB
-    at the whole width) shrinking with it."""
-    import re
-
-    from ray_tpu.models import phi4flash
-    from ray_tpu.serve.llm_engine import hybrid
-    from ray_tpu.serve.llm_engine.engine import table_widths
-
-    assert width in table_widths(256)
-    config = phi4flash.Phi4FlashConfig(num_layers=8)
-    rows, block, table, chunk = 32, 16, 256, 128
-
-    def on_chip(tree, dtype=None):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
-
-    params = on_chip(jax.eval_shape(lambda: hybrid.FAMILY.init_params(
-        config, jax.random.PRNGKey(0))))
-    cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
-        config, 1 + rows * table, block, rows, chunk)))
-    compiled = hybrid.make_engine_decode_step(config, block).lower(
-        params, cache,
-        on_chip(hybrid.pack_decode_rows(rows, width, ()), jnp.int32),
-        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip),
-        None).compile()
-    memory = compiled.memory_analysis()
-    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
-                      for c in jax.tree.leaves(cache))
-    assert memory.alias_size_in_bytes >= cache_bytes
-    positions = width * block
-    gathered = 2 * rows * positions * 1280 * 2   # keys and values, bf16
-    assert memory.temp_size_in_bytes < gathered + 64 * 2 ** 20
-    text = compiled.as_text()
-    assert f"bf16[{rows},{positions},1280]" in text
-    assert re.search(rf"= f32\[{rows},{positions},1280\]", text) is None
-    assert [line for line in text.splitlines()
-            if " copy(" in line and "= bf16[1,8193,16,1280]" in line] == []
-    if width < table:
-        assert f"[{rows},4096,1280]" not in text
-
-
-@pytest.mark.parametrize("width", [128, 256, 512])
-def test_latent_programs_at_each_table_width_on_v5e(v5e_chip, width,
-                                                    monkeypatch):
-    """Xing4.0's two programs (published widths, 32 rows, a table of 512
-    blocks of 16; one dense and one expert layer: the scans make the
-    programs the same but for their length) at their three widths,
-    2,048, 4,096 and 8,192 positions a row. The pool of one vector a
-    position (576 values in 640 lanes) is updated where it lies and
-    never copied: declared 576 wide, the runtime lays it out with the
-    blocks along the lanes and both programs copy all of it twice. The
-    decode step reads it through the tables inside
-    ``ops/paged_latent_attention.py`` (compiled here for the v5e: its
-    tables of ``[32, 512]`` in SMEM, two buffers of 64 pages in VMEM):
-    no gathered view, no table-wide scores, no slice or copy of a layer
-    of the pool, temporaries of a few MiB; the prefill chunk expands
-    its one row's view inside the score product."""
-    import re
-
-    from ray_tpu._private import jax_compat
-    from ray_tpu.models import xing
-    from ray_tpu.serve.llm_engine import latent
-    from ray_tpu.serve.llm_engine.engine import table_widths
-
-    assert width in table_widths(512)
-    config = xing.XingConfig(
-        num_layers=2, first_k_dense=1,
-        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
-                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
-                      "original_max_position_embeddings": 4096})
-    rows, block, table, chunk = 32, 16, 512, 128
-    positions = width * block
-
-    def on_chip(tree, dtype=None):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
-
-    params = on_chip(jax.eval_shape(lambda: xing.init_params(
-        config, jax.random.PRNGKey(0))), config.dtype)
-    cache = on_chip(jax.eval_shape(lambda: latent.init_cache(
-        config, 1 + rows * table, block, rows, chunk)))
-    pool = (2, 1 + rows * table, block, 640)
-    assert cache["latent"].shape == pool
-    pool_bytes = math.prod(pool) * 2
-    # default_backend() is the CPU during a deviceless compile, and the
-    # decode step asks it whether its kernel interprets.
-    monkeypatch.setattr(jax_compat, "interpret_kernels", lambda: False)
-    step = latent.make_engine_decode_step(config, block).lower(
-        params, cache,
-        on_chip(latent.FAMILY.pack_decode_rows(rows, width, ()), jnp.int32),
-        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None,
-        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
-    ).compile()
-    memory = step.memory_analysis()
-    assert memory.alias_size_in_bytes >= pool_bytes
-    assert memory.temp_size_in_bytes < 64 * 2 ** 20
-    text = step.as_text()
-    shape = ",".join(map(str, pool))
-    calls = [line for line in text.splitlines()
-             if "custom-call(" in line and "paged_latent_attention" in line]
-    assert len(calls) == 2                  # a layer each, this short stack
-    for call in calls:
-        # The tables flat in SMEM, the rows' entries, the pool whole.
-        assert f"s32[{rows * width}]" in call and "bf16[32,640]" in call
-        assert f"bf16[{shape}]" in call
-    for view in (rf"\[{rows},{positions},640\]",        # a gathered view
-                 rf"\[{rows * width},{block},640\]",    # ... as gathered
-                 rf"f32\[{rows},{positions},(1,)?32\]",  # table-wide scores
-                 rf"pred\[{rows},{positions}\]",        # ... and their mask
-                 rf"= bf16\[(1,)?{pool[1]},{block},640\]"):  # a layer
-        assert re.search(view, text) is None, view
-    assert [line for line in text.splitlines()
-            if " copy(" in line and f"= bf16[{shape}]" in line] == []
-    prefill = latent.make_engine_prefill_chunk(config, block, chunk).lower(
-        params, cache,
-        on_chip(latent.FAMILY.pack_prefill_chunk(chunk, width, (), 0, (), 0),
-                jnp.int32), None).compile()
-    memory = prefill.memory_analysis()
-    assert memory.alias_size_in_bytes >= pool_bytes
-    # The float32 scores of the 32 heads (128 MiB at the whole width).
-    assert memory.temp_size_in_bytes < 3 * 32 * chunk * positions * 4
-    text = prefill.as_text()
-    assert f"f32[32,{chunk},{positions}]" in text
-    assert [line for line in text.splitlines()
-            if " copy(" in line and f"= bf16[{shape}]" in line] == []
-    assert re.search(rf"\[(1,)?{chunk},131072\]", text) is None
-    assert "f32[1,2,131072]" in text
-
-
-def test_serving_params_never_hold_a_float32_expert_tensor_on_v5e(v5e_chip):
-    """``[12, 64, 2048, 1024]`` is 6 GiB in float32: the cast runs in
-    the initialisation's own program, which must keep no such buffer
-    (float32 values exist inside its fusions only: the temporaries
-    say so, the text cannot)."""
-    from ray_tpu.models import llama
-
-    config = _olmoe()
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip)
-    compiled = jax.jit(lambda key: jax.tree.map(
-        lambda x: x.astype(config.dtype),
-        llama.init_params(config, key))).lower(key).compile()
-    memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < 64 * 2 ** 20
-    # bf16 throughout, to the tiling's padding of the small scales.
-    assert 0 <= memory.output_size_in_bytes - 2 * config.num_params < 2 ** 20
 
 
 @pytest.mark.parametrize("cell, ceiling_gib", [

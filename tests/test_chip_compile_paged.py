@@ -1,0 +1,308 @@
+"""The paged family's programs (identical layers over one pool of keys
+and values: the Mistral and OLMoE serve cells' two programs, and the
+prefill chunk they share with SDAR), compiled for a described ``v5e:2x2``
+(``v5e_compile.py``): what the chip's compiler makes of the pool, the
+gathered keys and the experts at the cells' real widths."""
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import pytest
+from v5e_compile import (  # noqa: F401 — the fixtures
+    _memory_of, _sdar, v5e_chip, v5e_devices)
+
+
+def _lower_paged_step(program, config, batch, block, table, chip,
+                      width=None, prev=False):
+    """``decode_step`` or ``prefill_chunk``, the plain program or, as
+    ``engine_...``, the one the engine calls (one host array, the key
+    carried), lowered on shapes placed on the described chip; the
+    pool's shape beside it. A sparse configuration's step carries its
+    expert accumulator. ``width``: the blocks of a row's table the
+    engine's decode step or prefill chunk is given
+    (``engine.table_widths``; the pool stays ``table`` blocks a row);
+    the chunk is the default's length; ``prev``: with the step before's
+    tokens ``[batch]`` as the engine passes them (without: the
+    five-argument call of ``benchmark/sizing.py``)."""
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.models import llama, moe
+    from ray_tpu.serve.llm_engine import model as paged_model
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree.map(
+        lambda s: on_chip(s.shape, config.dtype),
+        jax.eval_shape(
+            lambda: llama.init_params(config, jax.random.PRNGKey(0))))
+    pool_shape = (config.num_layers, 1 + batch * table, block,
+                  config.num_kv_heads, config.head_dim)
+    pool = {"k": on_chip(pool_shape, config.dtype),
+            "v": on_chip(pool_shape, config.dtype)}
+    stats = None
+    if config.num_experts > 0:
+        stats = on_chip(jax.eval_shape(moe.init_stats).shape)
+    chunk = GLOBAL_CONFIG.llm_prefill_chunk
+    if program == "engine_decode_step":
+        lowered = paged_model.make_engine_decode_step(config, block).lower(
+            params, pool, on_chip((batch, 3 + (width or table))),
+            on_chip((2,), jnp.uint32), stats,
+            *([on_chip((batch,))] if prev else []))
+    elif program == "engine_prefill_chunk":
+        lowered = paged_model.make_engine_prefill_chunk(
+            config, block, chunk).lower(
+                params, pool, on_chip((2 + 2 * chunk + (width or table),)),
+                stats)
+    elif program == "decode_step":
+        lowered = paged_model.make_decode_step(config, block).lower(
+            params, pool, on_chip((batch, 1)), on_chip((batch,)),
+            on_chip((batch, table)), on_chip((2,), jnp.uint32),
+            on_chip((batch,), jnp.float32), stats)
+    else:
+        lowered = paged_model.make_prefill_chunk(config, block).lower(
+            params, pool, on_chip((1, chunk)), on_chip((1, chunk)),
+            on_chip((1, table)), on_chip(()), on_chip(()), stats)
+    return lowered, pool_shape
+
+
+def test_engine_decode_compiles_for_v5e(v5e_chip):
+    """The paged engine's ONE decode program at chip_smoke.py's widths
+    (Llama-2-7B, 2 layers): fits one chip with room to spare."""
+    from ray_tpu.models import llama
+
+    config = dataclasses.replace(
+        llama.LlamaConfig.llama2_7b(), num_layers=2, max_seq_len=1024)
+    lowered, _ = _lower_paged_step("decode_step", config, 8, 16,
+                                   1024 // 16, v5e_chip)
+    memory = lowered.compile().memory_analysis()
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 8 * 2 ** 30)
+
+
+def _mistral_serve():
+    """The Mistral serve cells' widths, 2 of their 16 layers."""
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=32768, hidden_size=4096, intermediate_size=14336,
+        num_layers=2, num_heads=32, num_kv_heads=8, head_dim=128,
+        max_seq_len=2048, rope_theta=1e6)
+
+
+@pytest.mark.parametrize("program", [
+    "decode_step", "prefill_chunk", "engine_decode_step",
+    "engine_prefill_chunk"])
+def test_paged_steps_update_the_pool_in_place_on_v5e(v5e_chip, program):
+    """The serve cells' widths (Mistral-7B-v0.3: 32 query on 8 key-value
+    heads of 128; 2 of its layers, 16 rows, 128 blocks of 16). The chip's
+    compiler must keep the donated pool where it is and repeat or widen
+    nothing the size of the gathered keys: at these sizes a repeated
+    float32 copy of them is 0.5 GiB and a copy of the 2-layer pool 0.13
+    GiB, and neither shows in a CPU test."""
+    lowered, pool_shape = _lower_paged_step(program, _mistral_serve(), 16,
+                                            16, 128, v5e_chip)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.25 * 2 ** 30
+    # k and v, two bytes an element, both updated where they were given.
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in compiled.as_text().splitlines()
+            if " copy(" in line and pool_text in line] == []
+
+
+def _olmoe(num_layers=12):
+    """``benchmark/configs/olmoe-1b-7b-serve-1chip.json`` as the
+    harness builds it: OLMoE-1B-7B's widths, 12 of its 16 layers."""
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+        num_layers=num_layers, num_heads=16, num_kv_heads=16, head_dim=128,
+        max_seq_len=2048, num_experts=64, experts_per_token=8, qk_norm=True)
+
+
+# A float32 tensor the size of one layer's experts, alone or stacked.
+F32_EXPERTS = r"f32\[(\d+,)?64,(2048,1024|1024,2048)\]"
+
+
+@pytest.mark.parametrize("program, temporaries_mib", [
+    ("decode_step", 160), ("prefill_chunk", 16),
+    ("engine_decode_step", 160), ("engine_prefill_chunk", 16)])
+def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
+        v5e_chip, program, temporaries_mib):
+    """The OLMoE serve cell's two programs at its real size (16 rows x
+    2048 positions, 12 layers: 12.76 GiB of arguments). What must not
+    appear: a float32 copy of an expert tensor (1.5 GiB a layer) or a
+    transposed bf16 one (768 MiB a layer: a flat ``bth,ehm->btem``
+    product made the compiler transpose each [64, 2048, 1024] whole);
+    a copy of the donated pool; a float32 copy of a layer's gathered
+    keys (256 MiB: a decode step's lone query row per head made the
+    scores a matrix-vector product, which the compiler widened the keys
+    for, until ``_paged_attention_block`` put a row of zeros beside
+    it). The decode program's 129 MiB of temporaries are one gathered
+    ``bf16[2048,16,16,128]`` (with 16 key-value heads it no longer fits
+    the memory space Mistral's 64 MiB ones live in); the chunk program
+    has none to speak of."""
+    import re
+
+    lowered, pool_shape = _lower_paged_step(program, _olmoe(), 16, 16, 128,
+                                            v5e_chip)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < temporaries_mib * 2 ** 20
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 0.9 * 15.75 * 2 ** 30)
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
+    text = compiled.as_text()
+    assert re.search(F32_EXPERTS, text) is None
+    assert re.search(r"= f32\[(2048,16|16,2048),16,128\]", text) is None
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    # The accumulator rides along: int32 [2, 4] in, the same out.
+    assert "s32[2,4]" in text
+
+
+@pytest.mark.parametrize("width", [32, 64, 128])
+@pytest.mark.parametrize("model", ["mistral", "olmoe"])
+def test_decode_step_at_each_table_width_on_v5e(v5e_chip, model, width):
+    """The engine's decode program at the three widths it is built at
+    (``engine.table_widths`` of 128 blocks: 512, 1024 and 2048 positions
+    a row), 16 rows over the whole pool, for both 16-row serve
+    configurations: what the cases above hold the whole width to holds
+    at a quarter and a half of it. The pool is updated where it lies and
+    never copied; the gathered keys are never widened to float32 (D9's
+    row of zeros keeps the scores a bf16 matrix product with 16
+    key-value heads and no grouping); under the whole width there are
+    no temporaries to speak of."""
+    import re
+
+    from ray_tpu.serve.llm_engine.engine import table_widths
+
+    assert width in table_widths(128)
+    config = _mistral_serve() if model == "mistral" else _olmoe(2)
+    lowered, pool_shape = _lower_paged_step(
+        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    # Under the whole width the gathered keys leave HBM's temporaries.
+    assert memory.temp_size_in_bytes < (160 if width == 128 else 16) * 2 ** 20
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
+    text = compiled.as_text()
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    positions, kv = width * 16, config.num_kv_heads
+    assert re.search(rf"= f32\[({positions},16|16,{positions}),{kv},128\]",
+                     text) is None
+    assert re.search(F32_EXPERTS, text) is None
+    # The gather is of this width, in the pool's dtype.
+    assert re.search(rf"bf16\[({positions},16|16,{positions}),{kv},128\]",
+                     text) is not None
+    if width < 128:
+        assert f"[2048,16,{kv},128]" not in text
+
+
+@pytest.mark.parametrize("width", [32, 64, 128])
+@pytest.mark.parametrize("model", ["mistral", "olmoe", "sdar"])
+def test_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, model, width):
+    """The engine's prefill program at the default chunk of 128 tokens
+    and at the three widths it is built at, for the three serve
+    configurations of identical layers (Mistral and OLMoE 16 rows, SDAR
+    32, over the whole pool). The pool is updated where it lies and
+    never copied; the head runs on the one row that is read, so nothing
+    the size of a chunk's logits exists (float32 ``[128, vocabulary]``:
+    16 MB for Mistral, 78 for SDAR); the scores are as wide as the rung
+    and no wider; no expert tensor is widened or transposed; and the
+    temporaries stay under 64 MiB."""
+    import re
+
+    from ray_tpu._private.config import GLOBAL_CONFIG
+    from ray_tpu.serve.llm_engine.engine import table_widths
+
+    assert width in table_widths(128)
+    chunk = GLOBAL_CONFIG.llm_prefill_chunk
+    config, rows = {"mistral": (_mistral_serve(), 16),
+                    "olmoe": (_olmoe(2), 16), "sdar": (_sdar(), 32)}[model]
+    lowered, pool_shape = _lower_paged_step(
+        "engine_prefill_chunk", config, rows, 16, 128, v5e_chip, width=width)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
+    text = compiled.as_text()
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    vocabulary, positions = config.vocab_size, width * 16
+    assert re.search(rf"\[(1,)?{chunk},{vocabulary}\]", text) is None
+    assert re.search(rf"f32\[(1,)?2,{vocabulary}\]", text) is not None
+    # The chunk's scores: every query row against the rung's positions.
+    assert re.search(rf"f32\[[0-9,]*{chunk},{positions}\]", text) is not None
+    if width < 128:
+        assert re.search(rf"f32\[(\d+,){{2,}}{chunk},2048\]", text) is None
+    assert re.search(
+        rf"= f32\[({positions},16|16,{positions}),{config.num_kv_heads},128\]",
+        text) is None
+    if config.num_experts:
+        e, m = config.num_experts, config.intermediate_size
+        assert re.search(rf"f32\[(\d+,)?{e},(2048,{m}|{m},2048)\]",
+                         text) is None
+
+
+@pytest.mark.parametrize("width", [32, 64, 128])
+@pytest.mark.parametrize("model", ["mistral", "olmoe"])
+def test_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip, model,
+                                                          width):
+    """The program the engine runs since it keeps a step ahead: the
+    step before's tokens ``[16]`` int32 as a sixth argument, one select
+    in front of the embedding. Beside the five-argument program (which
+    ``benchmark/sizing.py`` still lowers) at the same width: the pool
+    aliased as much, the temporaries the same to within a few vectors of
+    16, the arguments 64 bytes more (the tokens, padded), and ``prev``
+    an argument that is read."""
+    config = _mistral_serve() if model == "mistral" else _olmoe(2)
+    without, pool_shape = _lower_paged_step(
+        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width)
+    with_prev, _ = _lower_paged_step(
+        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width,
+        prev=True)
+    assert len(with_prev.in_avals[0]) == len(without.in_avals[0]) + 1 == 6
+    compiled, before = with_prev.compile(), without.compile()
+    alias, temp, arguments = _memory_of(compiled)
+    alias_before, temp_before, arguments_before = _memory_of(before)
+    assert alias == alias_before >= 2 * 2 * math.prod(pool_shape)
+    assert abs(temp - temp_before) < 64 * 2 ** 10
+    assert 0 < arguments - arguments_before <= 4096
+    text = compiled.as_text()
+    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    # Kept by the program (jit drops an argument nothing reads).
+    def entry_arguments(hlo):
+        layout = hlo[hlo.index("entry_computation_layout={("):]
+        return layout[:layout.index(")->")]
+
+    assert "s32[16]{" in entry_arguments(text)
+    assert "s32[16]{" not in entry_arguments(before.as_text())
+
+
+def test_serving_params_never_hold_a_float32_expert_tensor_on_v5e(v5e_chip):
+    """``[12, 64, 2048, 1024]`` is 6 GiB in float32: the cast runs in
+    the initialisation's own program, which must keep no such buffer
+    (float32 values exist inside its fusions only: the temporaries
+    say so, the text cannot)."""
+    from ray_tpu.models import llama
+
+    config = _olmoe()
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip)
+    compiled = jax.jit(lambda key: jax.tree.map(
+        lambda x: x.astype(config.dtype),
+        llama.init_params(config, key))).lower(key).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    # bf16 throughout, to the tiling's padding of the small scales.
+    assert 0 <= memory.output_size_in_bytes - 2 * config.num_params < 2 ** 20

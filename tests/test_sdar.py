@@ -454,7 +454,7 @@ def test_answers_do_not_depend_on_the_tables_rung():
     def serve(pin_whole: bool):
         engine = make_engine()
         if pin_whole:
-            engine._widths = (engine.blocks_per_seq,)
+            engine._widths = engine._step_widths = (engine.blocks_per_seq,)
         try:
             seen = record_passes(engine)
             submitted = [engine.submit(p, max_new_tokens=n)

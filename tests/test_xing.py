@@ -28,7 +28,6 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-import prefill_chunk_cases
 import pytest
 from jax import lax
 
@@ -40,37 +39,16 @@ import chip_smoke  # noqa: E402
 from benchmark import spec  # noqa: E402
 from benchmark.reference import xing_decoder as reference  # noqa: E402
 from ray_tpu.models import moe, xing  # noqa: E402
-from ray_tpu.serve.llm_engine import LLMEngine  # noqa: E402
 from ray_tpu.serve.llm_engine import latent  # noqa: E402
 from ray_tpu.serve.llm_engine import model as paged_model  # noqa: E402
 from ray_tpu.serve.llm_engine.engine import table_widths  # noqa: E402
+from xing_tiny import (  # noqa: E402
+    BLOCK, CHUNK, ROWS, TABLE, contexts_of, numbers, reference_logits, tiny)
 
 F32_ATOL = 1e-4
 CONTROL_MOVES = 1e-2
-BLOCK, CHUNK, ROWS, TABLE = 4, 8, 4, 16      # a table of 64 positions
 CONFIG_FILE = os.path.join(REPO, "benchmark", "configs",
                            "xing4-29b-a4b-serve-1chip.json")
-
-
-def tiny(**changes) -> xing.XingConfig:
-    return xing.XingConfig.tiny(**{"dtype": jnp.float32, **changes})
-
-
-def numbers(cfg: xing.XingConfig) -> dict:
-    """What the reference is given: the configuration file's numbers
-    under their Hugging Face keys."""
-    out = {
-        "hc_mult": cfg.hc_mult, "hc_sinkhorn_iters": cfg.hc_sinkhorn_iters,
-        "hc_eps": cfg.hc_eps, "mhc_h_res_clamp_min": cfg.hc_clamp_min,
-        "mhc_h_res_clamp_max": cfg.hc_clamp_max,
-        "rms_norm_eps": cfg.rms_norm_eps, "rope_theta": cfg.rope_theta,
-        "qk_nope_head_dim": cfg.qk_nope_head_dim,
-        "qk_rope_head_dim": cfg.qk_rope_head_dim,
-        "kv_lora_rank": cfg.kv_lora_rank,
-        "num_experts_per_tok": cfg.experts_per_token,
-        "routed_scaling_factor": cfg.routed_scaling_factor}
-    out.update({f"rope_scaling_{k}": v for k, v in cfg.yarn.items()})
-    return out
 
 
 @pytest.fixture(scope="module")
@@ -103,9 +81,9 @@ def shown(cfg):
 def serve(cfg, params, contexts, prefilled, absorbed=True):
     """Each context's first ``prefilled`` positions through prefill
     chunks, the rest through batched decode steps, as the engine drives
-    its two programs: every pass at the narrowest of the table's three
-    widths that holds its longest row. Returns every position's logits
-    per context, and the chosen experts."""
+    its two programs: a chunk at the narrowest of the table's three
+    widths that holds its row, every step at the whole table. Returns
+    every position's logits per context, and the chosen experts."""
     chunk, step = shown(cfg)
     cache = latent.init_cache(cfg, 1 + ROWS * TABLE, BLOCK, ROWS, CHUNK)
     tables = np.zeros((ROWS, TABLE), np.int32)
@@ -135,8 +113,7 @@ def serve(cfg, params, contexts, prefilled, absorbed=True):
         active = [i for i, c in enumerate(contexts) if at[i] < len(c)]
         for i in active:
             tokens[i, 0], positions[i] = contexts[i][at[i]], at[i]
-        width = rung(positions.max() + 1)
-        step_tables = np.where(positions[:, None] > 0, tables, 0)[:, :width]
+        step_tables = np.where(positions[:, None] > 0, tables, 0)
         logits, cache, _, _ = step(
             params, cache, jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(step_tables), absorbed)
@@ -144,27 +121,6 @@ def serve(cfg, params, contexts, prefilled, absorbed=True):
             got[i][at[i]] = np.asarray(logits[i, 0])
             at[i] += 1
     return got
-
-
-_REFERENCE = {}
-
-
-def reference_logits(cfg, params, context):
-    """The reference's full forward; the context padded to the table's
-    64 positions (causal: what follows a position changes nothing at
-    it), so that it compiles once a configuration."""
-    if cfg not in _REFERENCE:
-        _REFERENCE[cfg] = jax.jit(lambda p, t: reference.forward(
-            p, t, numbers(cfg)))
-    padded = np.zeros((1, TABLE * BLOCK), np.int32)
-    padded[0, :len(context)] = context
-    return np.asarray(_REFERENCE[cfg](params, jnp.asarray(padded)))[
-        0, :len(context)]
-
-
-def contexts_of(lengths, seed=5):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(1, 256, n) for n in lengths]
 
 
 # (prefilled, decoded): inside one chunk and block; across a block and
@@ -419,105 +375,6 @@ def test_the_softmax_route_is_bit_equal_to_what_it_was(norm_topk_prob):
     then = jax.jit(lambda x, w: before(x, w, 8, norm_topk_prob)).lower(
         x, w_router).as_text()
     assert now == then
-
-
-# ------------------------------------------------- (6) through the engine
-
-
-@pytest.fixture(scope="module")
-def engine():
-    engine = LLMEngine(tiny(), max_batch_size=ROWS, max_seq_len=64,
-                       block_size=BLOCK, prefill_chunk=CHUNK, seed=11)
-    yield engine
-    engine.shutdown()
-
-
-def greedy_by_reference(cfg, params, prompt, new_tokens):
-    context = list(prompt)
-    for _ in range(new_tokens):
-        context.append(int(reference_logits(
-            cfg, params, np.asarray(context))[-1].argmax()))
-    return context[len(prompt):]
-
-
-def test_the_engine_serves_the_references_greedy_tokens(engine):
-    """The normal path: ``LLMEngine`` with the same scheduler, allocator
-    and stream path as a dense model, ragged requests batched, a step
-    launched on the last one's tokens before the host read them, rows
-    that pass the table's quarter and half widths."""
-    prompts = contexts_of([5, 13, 26], seed=4)
-    before = engine.engine_stats()
-    requests = [engine.submit(p.tolist(), max_new_tokens=10) for p in prompts]
-    for prompt, request in zip(prompts, requests):
-        assert engine.result(request, timeout_s=300) == greedy_by_reference(
-            engine.config, engine.params, prompt.tolist(), 10)
-    stats = {k: v - before[k] for k, v in engine.engine_stats().items()
-             if isinstance(v, int) and not isinstance(v, bool)}
-    assert stats["decode_steps_ahead"] > 0
-    assert 0 < stats["decode_steps_narrow"] < stats["decode_steps"]
-    assert stats["decode_tokens"] == 30 - 3  # the first is prefill's
-    assert 0 < stats["kv_positions_live"] < stats["kv_positions_read"]
-    # The step reads by row (``ops/paged_latent_attention.py``): each
-    # busy row's whole pages and its own entry, under a page over what
-    # is live, whatever the step's width.
-    assert stats["kv_positions_read"] < stats["kv_positions_live"] \
-        + BLOCK * stats["block_rows"]
-    # The expert counters: 2 expert layers of 8 experts, 3 a token.
-    layer_steps = 2 * (stats["decode_steps"] + stats["prefill_chunks"])
-    assert stats["expert_slots"] == 8 * layer_steps
-    assert stats["expert_choices"] == 2 * 3 * (
-        stats["decode_tokens"] + stats["prefill_tokens"])
-    assert 0 < stats["experts_touched"] <= stats["expert_slots"]
-    assert stats["expert_peak_choices"] >= stats["expert_choices"]
-
-
-@pytest.mark.parametrize("prompt, live, read", [
-    (7, 8 + 9, (8 + 1) + (8 + 1)),      # positions 7 and 8: two pages
-    (8, 9 + 10, (8 + 1) + (12 + 1)),    # 9 starts a third
-    (9, 10 + 11, (12 + 1) + (12 + 1))])
-def test_a_step_counts_the_pages_its_kernel_fetches(engine, prompt, live,
-                                                    read):
-    """Two decode steps of one row, by hand: a step at position ``p``
-    holds ``p + 1`` live positions and reads ``ceil(p / 4)`` pages of 4
-    and the row's own entry (``Family.reads_by_row``; the other
-    families count rows x the step's width:
-    ``tests/test_table_widths.py``)."""
-    before = engine.engine_stats()
-    request = engine.submit(list(range(1, prompt + 1)), max_new_tokens=3)
-    assert len(engine.result(request, timeout_s=300)) == 3
-    after = engine.engine_stats()
-    assert after["decode_steps"] - before["decode_steps"] == 2
-    assert after["kv_positions_live"] - before["kv_positions_live"] == live
-    assert after["kv_positions_read"] - before["kv_positions_read"] == read
-
-
-def test_a_preempted_request_resumes_to_the_same_tokens():
-    """Cache pressure preempts with a prompt half prefilled; the request
-    prefills again from position 0 over the latents' blocks it is dealt
-    anew, and both requests end as they do with room."""
-    prefill_chunk_cases.resumes_to_the_same_tokens(tiny())
-
-
-@pytest.mark.parametrize("chunk", prefill_chunk_cases.WIDTHS,
-                         ids=prefill_chunk_cases.WIDTH_IDS)
-def test_greedy_tokens_do_not_depend_on_the_chunk_width(chunk):
-    prefill_chunk_cases.same_tokens_at(tiny(), chunk)
-
-
-def test_the_family_follows_from_the_configuration():
-    family = paged_model.family(tiny())
-    assert family is latent.FAMILY and not family.recurrent
-    # One token a row a pass: the step in flight is the dense model's.
-    assert family.ahead is paged_model.PAGED.ahead
-    assert family.lead is paged_model.PAGED.lead
-    assert family.row_of is paged_model.PAGED.row_of
-    assert family.pack_decode_rows is paged_model.PAGED.pack_decode_rows
-    assert family.pack_prefill_chunk is paged_model.PAGED.pack_prefill_chunk
-    # The names the benchmark's readers find the programs by.
-    assert family.make_engine_decode_step(tiny(), BLOCK).__name__ \
-        == "decode_step"
-    assert family.make_engine_prefill_chunk(tiny(), BLOCK, CHUNK).__name__ \
-        == "prefill_chunk"
 
 
 # --------------------------------------------- (7) what the pool holds

@@ -1,0 +1,68 @@
+"""What the files that compile for a DESCRIBED ``v5e:2x2`` share
+(``test_chip_compile.py`` and its siblings by cell family, ``_paged``,
+``_hybrid``, ``_block`` and ``_latent``: a file each, so that ``--dist
+loadfile`` spreads 67 compiles that share nothing over the workers): the
+fixtures that describe the topology, imported by name into each file
+(module-scoped there, never ``autouse`` and not in ``conftest.py``:
+guide on-chip-measurement, 2.3), and the helpers more than one file
+uses. The TPU compiler is installed here and refuses what the chip
+would refuse (a slice off the tiling, too much VMEM), which interpret
+mode never notices. Nothing runs, so these say nothing about results or
+times. Skipped where the topology cannot be described."""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+
+import jax  # noqa: E402
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    """The four chips of a described v5e:2x2. The persistent
+    compilation cache is off around these compiles: an entry written
+    for a described device cannot be read back without the chip, and
+    the next run would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_devices):
+    """Sharding on one chip of the described v5e:2x2."""
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_devices[0])
+
+
+def _sdar(num_layers=2):
+    """``benchmark/configs/sdar-30b-a3b-serve-1chip.json`` as the
+    harness builds it: SDAR-30B-A3B's widths, 2 of the cell's 7 layers."""
+    from ray_tpu.models import llama
+
+    return llama.LlamaConfig(
+        vocab_size=151936, hidden_size=2048, intermediate_size=768,
+        num_layers=num_layers, num_heads=32, num_kv_heads=4, head_dim=128,
+        max_seq_len=2048, rope_theta=1e6, rms_norm_eps=1e-6,
+        num_experts=128, experts_per_token=8, norm_topk_prob=True,
+        qk_norm="head", block_length=4, denoising_steps=2,
+        mask_token_id=151669)
+
+
+def _memory_of(compiled) -> tuple:
+    memory = compiled.memory_analysis()
+    return (memory.alias_size_in_bytes, memory.temp_size_in_bytes,
+            memory.argument_size_in_bytes)
