@@ -122,11 +122,28 @@ def route(x: jax.Array, w_router: jax.Array, experts_per_token: int,
     return probs, idx, weights
 
 
-def combine_weights(idx: jax.Array, weights: jax.Array,
-                    num_experts: int) -> jax.Array:
+def _chosen(idx: jax.Array, num_experts: int, held, dtype) -> jax.Array:
+    """[..., k] choices over ``num_experts`` -> [..., k, held experts]
+    one-hot; a choice of an expert that is not held is a row of zeros
+    (``one_hot`` of an index outside its range)."""
+    if held is None:
+        return jax.nn.one_hot(idx, num_experts, dtype=dtype)
+    first, count = held
+    if not 0 <= first <= first + count <= num_experts:
+        raise ValueError(f"held={held}: outside the {num_experts} experts")
+    return jax.nn.one_hot(idx - first, count, dtype=dtype)
+
+
+def combine_weights(idx: jax.Array, weights: jax.Array, num_experts: int,
+                    held: "tuple[int, int] | None" = None) -> jax.Array:
     """[..., k] choices -> [..., E] float32: a token's weight for each
-    expert, zero for the experts it did not choose."""
-    chosen = jax.nn.one_hot(idx, num_experts, dtype=jnp.float32)
+    expert, zero for the experts it did not choose. ``held=(first,
+    count)``: the layer holds experts ``first .. first + count - 1`` of
+    the ``num_experts`` it routes over (one chip's share of a layer
+    whose experts are split over chips); the result is ``[..., count]``,
+    and a choice that landed on another chip's expert weighs nothing
+    here: that chip adds it."""
+    chosen = _chosen(idx, num_experts, held, jnp.float32)
     return jnp.einsum("...ke,...k->...e", chosen, weights)
 
 
@@ -134,7 +151,9 @@ def expert_ffn(layer: dict, x: jax.Array, combine: jax.Array,
                dtype=jnp.bfloat16) -> jax.Array:
     """sum_e combine[..., e] * W_down_e(silu(W_gate_e x) * W_up_e x).
 
-    x [B, T, H], combine [B, T, E] -> [B, T, H] in ``dtype``. Every
+    x [B, T, H], combine [B, T, E] -> [B, T, H] in ``dtype``; ``E`` is
+    the experts HELD (``combine_weights``' ``held``), which are the
+    layer's arrays' leading axis. Every
     expert with a non-zero weight contributes: nothing is dropped. An
     expert a token did not choose contributes exactly zero, whatever
     its activations come to (``where``, not a product with zero). The weight
@@ -205,8 +224,8 @@ def moe_mlp(layer: dict, x: jax.Array, *, experts_per_token: int = 1,
 # ------------------------------------------------ the engine's counters
 
 
-def routing_counts(idx: jax.Array, valid: jax.Array,
-                   num_experts: int) -> jax.Array:
+def routing_counts(idx: jax.Array, valid: jax.Array, num_experts: int,
+                   held: "tuple[int, int] | None" = None) -> jax.Array:
     """What one layer's routing of one step did, int32 in the order of
     ``EXPERT_COUNTERS``. idx [B, T, k]; valid [B, T]: tokens that
     carry a request (padding and inactive rows route too, uncounted).
@@ -214,12 +233,15 @@ def routing_counts(idx: jax.Array, valid: jax.Array,
     choices: valid tokens x k. slots: the experts on offer (E).
     touched: experts that got at least one choice. peak choices: E x
     the busiest expert's load, so that over ``choices`` it is the
-    largest load over the mean.
+    largest load over the mean. With ``held`` (``combine_weights``)
+    all four count the experts held: the choices that landed on them,
+    and ``E`` their number.
     """
-    chosen = jax.nn.one_hot(idx, num_experts, dtype=jnp.int32)  # [B,T,k,E]
+    chosen = _chosen(idx, num_experts, held, jnp.int32)         # [B,T,k,E]
+    offered = chosen.shape[-1]
     load = jnp.sum(chosen * valid[..., None, None], axis=(0, 1, 2))  # [E]
-    return jnp.stack([jnp.sum(load), jnp.int32(num_experts),
-                      jnp.sum(load > 0), num_experts * jnp.max(load)])
+    return jnp.stack([jnp.sum(load), jnp.int32(offered),
+                      jnp.sum(load > 0), offered * jnp.max(load)])
 
 
 def init_stats() -> jax.Array:

@@ -124,6 +124,12 @@ class XingConfig:
 
     #: Which forward, cache and weights ``serve/llm_engine`` gives it.
     family = "latent"
+    #: What the attention and feed-forward functions below ask of any
+    #: configuration they are given (``models/kimi_linear.py`` answers
+    #: otherwise): the rotary part of a query and a key is rotated, and
+    #: an expert layer holds every expert it routes over.
+    rotary = True
+    held = None
 
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
@@ -408,24 +414,35 @@ def rope(x: jax.Array, positions: jax.Array, config: XingConfig):
 def latent_queries(w: dict, x: jax.Array, positions: jax.Array,
                    config: XingConfig):
     """x [B, T, C] (normed) -> (q_nope [B, T, H, nope], q_rope [B, T,
-    H, rope], rotated)."""
+    H, rope], rotated where the configuration rotates). Without a
+    ``q_lora_rank`` the queries are one projection ``wq`` [C, H, nope +
+    rope]."""
     dtype, eps = config.dtype, config.rms_norm_eps
-    c_q = rms_norm(jnp.einsum("btc,cr->btr", x, w["wq_a"].astype(dtype)),
-                   w["q_norm"], eps)
-    q = jnp.einsum("btr,rhd->bthd", c_q, w["wq_b"].astype(dtype))
+    if config.q_lora_rank is None:
+        q = jnp.einsum("btc,chd->bthd", x, w["wq"].astype(dtype))
+    else:
+        c_q = rms_norm(jnp.einsum("btc,cr->btr", x, w["wq_a"].astype(dtype)),
+                       w["q_norm"], eps)
+        q = jnp.einsum("btr,rhd->bthd", c_q, w["wq_b"].astype(dtype))
     nope = config.qk_nope_head_dim
-    return q[..., :nope], rope(q[..., nope:], positions, config)
+    q_rope = q[..., nope:]
+    if config.rotary:
+        q_rope = rope(q_rope, positions, config)
+    return q[..., :nope], q_rope
 
 
 def latent_entries(w: dict, x: jax.Array, positions: jax.Array,
                    config: XingConfig) -> jax.Array:
     """x [B, T, C] (normed) -> [B, T, pool_lanes]: what these positions
-    leave in the cache, ``[RMSNorm(c_kv) | rotated k_rope]`` and zeros
-    up to the pool's width."""
+    leave in the cache, ``[RMSNorm(c_kv) | k_rope]`` (rotated where the
+    configuration rotates) and zeros up to the pool's width."""
     rank = config.kv_lora_rank
     kv = jnp.einsum("btc,cr->btr", x, w["wkv_a"].astype(config.dtype))
+    k_rope = kv[..., rank:]
+    if config.rotary:
+        k_rope = rope(k_rope, positions, config)
     entry = [rms_norm(kv[..., :rank], w["kv_norm"], config.rms_norm_eps),
-             rope(kv[..., rank:], positions, config)]
+             k_rope]
     pad = config.pool_lanes - config.latent_dim
     if pad:
         entry.append(jnp.zeros((*kv.shape[:-1], pad), kv.dtype))
@@ -515,13 +532,16 @@ def dense_ffn(w: dict, x: jax.Array, config: XingConfig) -> jax.Array:
 def sparse_ffn(w: dict, x: jax.Array, config: XingConfig):
     """The routed experts and the shared one. x [B, T, C] (normed,
     float32: the router reads it unrounded, the experts in ``dtype``).
-    Returns (out [B, T, C], the chosen experts [B, T, k])."""
+    Where the layer holds a share of the experts it routes over
+    (``config.held``), the chosen experts that are held. Returns (out
+    [B, T, C], the chosen experts [B, T, k])."""
     dtype = config.dtype
     _, idx, weights = moe.route(
         x, w["w_router"], config.experts_per_token, config.norm_topk_prob,
         scoring="sigmoid", bias=w["router_bias"],
         scale=config.routed_scaling_factor)
-    combine = moe.combine_weights(idx, weights, config.num_experts)
+    combine = moe.combine_weights(idx, weights, config.num_experts,
+                                  config.held)
     out = moe.expert_ffn(w, x, combine, dtype).astype(F32)
     if config.num_shared_experts:
         out = out + moe.shared_ffn(w, x, dtype).astype(F32)

@@ -1,0 +1,220 @@
+"""The paged steps of a model of linear-attention layers beside latent
+attention (``models/kimi_linear.py``): a matrix-valued recurrent state a
+row, and a latent pool that only some layers own, in ONE cache dict
+donated through every step.
+
+- ``latent`` ``[latent layers, num_blocks, bs, pool_lanes]``: the
+  latent family's pool (``latent.py``), with an entry for the layers
+  that ARE latent alone (3 of 13): a latent layer knows its pool index,
+  which is what ``latent._attention`` and the kernel behind it
+  (``ops/paged_latent_attention.py``) are handed as the layer. Paged by
+  the same tables and allocator as every pool, block 0 the scratch
+  block. The decode step reads it absorbed, through the tables, each
+  row its own live pages; the prefill chunk gathers its row's view and
+  expands it.
+- ``kda`` ``[KDA layers, rows, H, d, d]`` float32 and ``conv`` ``[KDA
+  layers, kernel - 1, rows, 3Hd]``: the delta rule's state and the
+  short convolutions' last inputs, one slot a row, as the hybrid
+  family's state (``hybrid.py``). (The rows lie BEFORE the minor
+  dimension of ``conv``: with the kernel's 3 there, ``[.., rows, 3,
+  3Hd]``, the chip tiles 3 rows as 4 or 8 and its compiler copied the
+  whole array into another tiling and back around every program, 0.35
+  ms of a decode step; my chip run, PR 50.) A request's row slot
+  (``EngineRequest.slot``) is its row of the decode step and its index
+  here, so the step reads and writes the state where it lies. A chunk
+  at position 0 starts from zeros IN THE PROGRAM, so a slot's last
+  tenant and a preempted request's stale state can never show (the
+  request is prefilled again from 0 on resume); a chunk's padding and
+  an inactive decode row (position 0) advance nothing.
+
+A state does not grow with the context and the pool does: both hang on
+the one allocator and the one set of row slots. The stack is the
+leading dense layer(s) written out, then a scan over PERIODS (KDA, KDA,
+latent, KDA) with the period's layers written out in the body
+(``kimi_linear`` says why); the carry is the residual stream in
+float32, the pool, the state, the convolutions' inputs and the expert
+counters. An expert layer adds the chosen experts it HOLDS
+(``config.held``); the counters count those.
+
+One token a row a pass, a row ends by its count: the packers, the row
+bookkeeping and the step in flight (``ahead``) are the dense model's,
+the prefill chunk's array the hybrid family's (it carries the row
+slot). The two programs are traced under the dense model's names
+(``decode_step``, ``prefill_chunk``) and take ONE host array each.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models import kimi_linear as kimi
+from ray_tpu.models import moe, xing
+from ray_tpu.models.llama import rms_norm
+from ray_tpu.serve.llm_engine.hybrid import pack_prefill_chunk
+from ray_tpu.serve.llm_engine.latent import _attention
+from ray_tpu.serve.llm_engine.model import (
+    Family,
+    _accumulated,
+    pack_decode_rows,
+    row_beside_zeros,
+    row_tokens,
+    sample_next,
+)
+
+F32 = jnp.float32
+
+
+def init_cache(config, num_blocks: int, block_size: int, rows: int,
+               chunk_len: int) -> dict:
+    heads, d = config.kda_heads, config.kda_head_dim
+    return {
+        "latent": jnp.zeros((config.latent_layers, num_blocks, block_size,
+                             config.pool_lanes), config.dtype),
+        "kda": jnp.zeros((config.kda_layers, rows, heads, d, d),
+                         config.state_dtype),
+        "conv": jnp.zeros((config.kda_layers, config.conv_kernel - 1, rows,
+                           3 * config.kda_width), config.dtype),
+    }
+
+
+def forward(params: dict, cache: dict, tokens, positions, tables, config,
+            block_size: int, *, slot=None, n_valid=None, logits_at=None):
+    """tokens and positions [B, T], tables [B, M] -> (logits [B, T, V]
+    float32, or [B, V] of position ``logits_at`` alone; the cache; the
+    expert counters of this pass; the chosen experts [periods, layers of
+    a period, B, T, k], which only a check reads). Without ``n_valid``
+    it is a decode step: ``T == 1``, row ``i`` is row slot ``i``, a row
+    at position 0 is inactive. With it, one request's chunk in row slot
+    ``slot``, its first ``n_valid`` positions real, from a zero state
+    where it starts at position 0."""
+    dtype, eps = config.dtype, config.rms_norm_eps
+    decode = n_valid is None
+    if decode:
+        valid = positions > 0
+    else:
+        valid = jnp.broadcast_to(jnp.arange(tokens.shape[1]) < n_valid,
+                                 tokens.shape)
+        fresh = positions[0, 0] == 0
+
+    def kda(w, h, state, conv, si):
+        if decode:
+            out, s, c = kimi.kda_step(w, h[:, 0], state[si], conv[si],
+                                      valid[:, 0], config)
+            return out[:, None], state.at[si].set(s), conv.at[si].set(c)
+        s = jnp.where(fresh, 0, state[si, slot])
+        c = jnp.where(fresh, 0, conv[si, :, slot])
+        out, s, c = kimi.kda_chunk(w, h[0], s, c, n_valid, config)
+        return out[None], state.at[si, slot].set(s), \
+            conv.at[si, :, slot].set(c)
+
+    def layer(carry, w, kind, sparse, si, pi):
+        """One layer: ``si`` its index among the KDA layers or ``pi``
+        among the latent ones, whichever it is."""
+        x, pool, state, conv, counts = carry
+        h = rms_norm(x, w["mixer_norm"], eps).astype(dtype)
+        if kind == kimi.KDA:
+            y, state, conv = kda(w["mixer"], h, state, conv, si)
+        else:
+            y, pool = _attention(w["mixer"], h, positions, pool, pi, tables,
+                                 config, block_size, n_valid, decode)
+        x = x + y.astype(F32)
+        h = rms_norm(x, w["ffn_norm"], eps)
+        if sparse:
+            y, idx = xing.sparse_ffn(w["ffn"], h, config)
+            counts = counts + moe.routing_counts(
+                idx, valid, config.num_experts, config.held)
+        else:
+            y, idx = xing.dense_ffn(w["ffn"], h, config), None
+        return (x + y.astype(F32), pool, state, conv, counts), idx
+
+    kinds, first = config.kinds, config.first_k_dense
+    carry = (params["embed"]["tokens"][tokens].astype(F32), cache["latent"],
+             cache["kda"], cache["conv"],
+             jnp.zeros((len(moe.EXPERT_COUNTERS),), jnp.int32))
+    for i, w in enumerate(params["first"]):
+        carry, _ = layer(carry, w, kinds[i], False,
+                         kinds[:i].count(kimi.KDA),
+                         kinds[:i].count(kimi.LATENT))
+    period = config.period_kinds
+    kda_before = kinds[:first].count(kimi.KDA)
+    latent_before = first - kda_before
+
+    def one_period(carry, layers_and_index):
+        layers, p = layers_and_index
+        chosen = []
+        for i, w in enumerate(layers):
+            carry, idx = layer(
+                carry, w, period[i], True,
+                kda_before + p * period.count(kimi.KDA)
+                + period[:i].count(kimi.KDA),
+                latent_before + p * period.count(kimi.LATENT)
+                + period[:i].count(kimi.LATENT))
+            chosen.append(idx)
+        return carry, jnp.stack(chosen)
+
+    carry, routing = lax.scan(
+        one_period, carry, (params["periods"], jnp.arange(config.periods)))
+    x, pool, state, conv, counts = carry
+    if logits_at is not None:
+        x = row_beside_zeros(x, logits_at)
+    x = rms_norm(x, params["final_norm"], eps).astype(dtype)
+    logits = jnp.einsum("ble,ev->blv", x, params["lm_head"].astype(dtype),
+                        preferred_element_type=F32)
+    if logits_at is not None:
+        logits = logits[:, 0]
+    return logits, {"latent": pool, "kda": state, "conv": conv}, counts, \
+        routing
+
+
+def make_engine_decode_step(config, block_size: int):
+    """The ONE decode program (the pool is read by row, absorbed; the
+    state where it lies), on ``model.pack_decode_rows``' array (row
+    ``i`` is row slot ``i``), the carried sampling key and the step
+    before's tokens ``prev`` (``model.row_tokens``)."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
+        key, sub = jax.random.split(key)
+        temps = lax.bitcast_convert_type(rows[:, 2], F32)
+        logits, cache, counts, _ = forward(
+            params, cache, row_tokens(rows, prev), rows[:, 1:2],
+            rows[:, 3:], config, block_size)
+        return sample_next(logits[:, -1, :], sub, temps), cache, \
+            _accumulated(expert_stats, counts), key
+
+    return decode_step
+
+
+def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
+    """The prefill program (one a table width the engine hands it): the
+    latent layers expanded, the KDA layers in the chunkwise form, on
+    ``hybrid.pack_prefill_chunk``'s array; only the logits of
+    ``last_idx`` are computed."""
+    positions_at, table_at = 3 + chunk_len, 3 + 2 * chunk_len
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_chunk(params, cache, chunk, expert_stats=None):
+        logits, cache, counts, _ = forward(
+            params, cache, chunk[None, 3:positions_at],
+            chunk[None, positions_at:table_at], chunk[None, table_at:],
+            config, block_size, slot=chunk[2], n_valid=chunk[0],
+            logits_at=chunk[1])
+        return logits[0], cache, _accumulated(expert_stats, counts)
+
+    return prefill_chunk
+
+
+FAMILY = Family(
+    init_params=kimi.init_params,
+    init_cache=init_cache,
+    make_engine_decode_step=make_engine_decode_step,
+    make_engine_prefill_chunk=make_engine_prefill_chunk,
+    pack_decode_rows=pack_decode_rows,
+    pack_prefill_chunk=pack_prefill_chunk,
+    recurrent=True,
+    reads_by_row=True,
+)

@@ -44,7 +44,8 @@ two program names and the same one-array-a-pass contract:
 ``hybrid.py`` for layers of several kinds over three caches,
 ``latent.py`` for latent attention over a pool of one vector a position
 (absorbed in the decode step, expanded in the prefill chunk) under a
-residual path of several streams, and
+residual path of several streams, ``linear.py`` for a matrix-valued
+recurrent state a row beside a latent pool that some layers own, and
 ``BLOCKWISE`` below for generation by diffusion over blocks: the same
 layers and pool, ``T = block_length`` query rows a row of the batch, a
 mask that lets a position see all of its own block, and a pass that
@@ -146,7 +147,8 @@ class Family:
 def family(config) -> Family:
     """The one place a configuration's family is looked up: a
     configuration names it (``family = "hybrid"``:
-    ``models/phi4flash.py``; ``"latent"``: ``models/xing.py``) or has a
+    ``models/phi4flash.py``; ``"latent"``: ``models/xing.py``;
+    ``"linear"``: ``models/kimi_linear.py``) or has a
     ``block_length`` (generation by diffusion over blocks); otherwise it
     is the stack of identical layers over one paged pool of this module,
     one token a row a step."""
@@ -159,6 +161,10 @@ def family(config) -> Family:
         from ray_tpu.serve.llm_engine import latent
 
         return latent.FAMILY
+    if named == "linear":
+        from ray_tpu.serve.llm_engine import linear
+
+        return linear.FAMILY
     if getattr(config, "block_length", 0) > 0:
         return _blockwise(config.block_length)
     return PAGED

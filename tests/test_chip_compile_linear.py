@@ -1,0 +1,78 @@
+"""The linear family's programs (Kimi-Linear: a float32 delta-rule state
+a row beside a latent pool that only the latent layers own) at the
+cell's real widths, compiled for a described ``v5e:2x2``
+(``v5e_compile.py``)."""
+
+import math
+import re
+
+import jax
+import jax.numpy as jnp
+from v5e_compile import v5e_chip, v5e_devices  # noqa: F401 — the fixtures
+
+
+def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip, monkeypatch):
+    """Kimi-Linear's two programs as an engine builds them (published
+    widths, 32 of 256 experts held, an eighth of the vocabulary, 64
+    rows, a table of 256 blocks of 16; the dense layer and ONE period:
+    the scan makes the programs the same but for their length). The
+    state ``f32[4,64,32,128,128]`` (0.5 GiB here, 1.25 at the cell's 10
+    KDA layers), the convolutions' inputs and the pool of the ONE latent
+    layer are updated where they lie: aliased, never copied whole. The
+    decode step reads the pool through the tables inside
+    ``ops/paged_latent_attention.py`` handed the POOL's layer index;
+    the prefill chunk's chunkwise form keeps its sub-chunks' decays
+    ``[2,32,64,64,128]`` (256 MiB in float32) inside the reductions
+    that use them: temporaries stay under a quarter of a GiB."""
+    from ray_tpu._private import jax_compat
+    from ray_tpu.models import kimi_linear as kimi
+    from ray_tpu.serve.llm_engine import linear
+
+    config = kimi.KimiLinearConfig(vocab_size=20480, num_layers=5,
+                                   experts_held=32)
+    assert config.kinds == ("kda", "kda", "kda", "latent", "kda")
+    rows, block, table, chunk = 64, 16, 256, 128
+    monkeypatch.setattr(jax_compat, "interpret_kernels", lambda: False)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: kimi.init_params(
+        config, jax.random.PRNGKey(0))), config.dtype)
+    cache = on_chip(jax.eval_shape(lambda: linear.init_cache(
+        config, 1 + rows * table, block, rows, chunk)))
+    assert {k: v.shape for k, v in cache.items()} == {
+        "latent": (1, 1 + rows * table, block, 640),
+        "kda": (4, rows, 32, 128, 128), "conv": (4, 3, rows, 12288)}
+    cache_bytes = sum(math.prod(v.shape) * v.dtype.itemsize
+                      for v in cache.values())
+    state = "f32[4,64,32,128,128]"
+    family = linear.FAMILY
+    step = family.make_engine_decode_step(config, block).lower(
+        params, cache,
+        on_chip(family.pack_decode_rows(rows, table, ()), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None,
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
+    ).compile()
+    prefill = family.make_engine_prefill_chunk(config, block, chunk).lower(
+        params, cache,
+        on_chip(family.pack_prefill_chunk(chunk, table, (), 0, (), 0),
+                jnp.int32), None).compile()
+    for program, limit in ((step, 0.125), (prefill, 0.25)):
+        memory = program.memory_analysis()
+        assert memory.alias_size_in_bytes >= cache_bytes
+        assert memory.temp_size_in_bytes < limit * 2 ** 30
+        text = program.as_text()
+        assert [line for line in text.splitlines()
+                if " copy(" in line and f"= {state}" in line] == []
+        # The head on the rows that are read, over the share of the
+        # vocabulary held.
+        assert re.search(r"f32\[(64|1,2),20480\]", text)
+    calls = [line for line in step.as_text().splitlines()
+             if "custom-call(" in line and "paged_latent_attention" in line]
+    assert len(calls) == 1                  # the one latent layer
+    assert "bf16[1,16385,16,640]" in calls[0] and "bf16[64,640]" in calls[0]
+    assert "paged_latent_attention" not in prefill.as_text()
+    # No gathered view of the pool in the step: it reads by row.
+    assert re.search(r"\[64,4096,640\]", step.as_text()) is None
