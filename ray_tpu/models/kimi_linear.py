@@ -25,7 +25,10 @@ the row slots and the paged latent pool); there is no training path.
     y = W_o [rms_head(o_t; w) * sigmoid(x W_ga W_gb)]
 
 Two forms give the same numbers. ``kda_step``: one token against each
-row's state (a decode step). ``kda_chunk``: one row's chunk in the
+row's state (a decode step; the rule itself inside
+``ops/kda_state_update.py``, which reads a head's state once and writes
+it once; ``kda_position`` is its plain form). ``kda_chunk``: one row's
+chunk in the
 CHUNKWISE form, sub-chunks of ``kda_subchunk`` (64) positions. With
 ``G_i`` the running sum of ``g`` inside a sub-chunk and ``u_i = beta_i
 (v_i - S'_i^T k_i)`` the correction a position writes,
@@ -95,6 +98,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.llama import rms_norm
+from ray_tpu.ops.kda_state_update import kda_state_update
 
 F32 = jnp.float32
 HIGHEST = lax.Precision.HIGHEST
@@ -433,11 +437,15 @@ def _kda_output(w: dict, x, o, config: KimiLinearConfig):
         dtype), w["wo"].astype(dtype))
 
 
-def kda_step(w: dict, x, s, conv, active, config: KimiLinearConfig):
+def kda_step(w: dict, x, s, conv, active, config: KimiLinearConfig,
+             layer=None):
     """One token for every row. x [B, C] (normed); s [B, H, d, d]
-    float32; conv [kernel - 1, B, 3Hd] (the convolutions' last inputs,
-    the oldest first); active [B] bool: an inactive row's state and
-    inputs stay as they are. Returns (out [B, C], s, conv)."""
+    float32, or with ``layer`` (an int32 scalar) the layers' stacked [L,
+    B, H, d, d], of which that layer alone is advanced, where it lies
+    (``ops/kda_state_update.py``); conv [kernel - 1, B, 3Hd] (the
+    convolutions' last inputs, the oldest first); active [B] bool: an
+    inactive row's state and inputs stay as they are. Returns (out [B,
+    C], s as it came, conv)."""
     u = _project(x, w["w_qkv"], config.dtype)
     window = jnp.concatenate([conv, u[None].astype(conv.dtype)], axis=0)
     conv = jnp.where(active[None, :, None], window[1:], conv)
@@ -448,15 +456,27 @@ def kda_step(w: dict, x, s, conv, active, config: KimiLinearConfig):
     # what it was, to the bit.
     g = jnp.where(active[:, None, None], g, 0.0)
     beta = jnp.where(active[:, None], beta, 0.0)
+    o, stacked = kda_state_update(
+        (s[None] if layer is None else s).astype(F32),
+        0 if layer is None else layer, q, k, v, g, beta)
+    s = stacked[0] if layer is None else stacked
+    return _kda_output(w, x, o, config), s.astype(config.state_dtype), conv
+
+
+def kda_position(q, k, v, g, beta, s):
+    """One position of the rule for every row: q, k, v, g [B, H, d],
+    beta [B, H], s [B, H, d, d], all float32 -> (o [B, H, d], s). The
+    plain form ``ops/kda_state_update.py`` is held to; no program calls
+    it (it passes over the state three times: a reduce for both
+    readings, then the update's read and its write)."""
     s = s * jnp.exp(g)[..., None]                                   # S'
-    # Both readings of S' in one pass over it: what the state predicts
-    # for k, and the output but for this position's own correction.
+    # Both readings of S': what the state predicts for k, and the
+    # output but for this position's own correction.
     predicted = jnp.sum(s * k[..., None], axis=-2)              # S'^T k
     carried = jnp.sum(s * q[..., None], axis=-2)                # S'^T q
     update = beta[..., None] * (v - predicted)                  # [B, H, d]
     s = s + k[..., None] * update[..., None, :]
-    o = carried + update * jnp.sum(q * k, axis=-1, keepdims=True)
-    return _kda_output(w, x, o, config), s.astype(config.state_dtype), conv
+    return carried + update * jnp.sum(q * k, axis=-1, keepdims=True), s
 
 
 def kda_recurrence(q, k, v, g, beta, s):

@@ -102,9 +102,9 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
 
     def kda(w, h, state, conv, si):
         if decode:
-            out, s, c = kimi.kda_step(w, h[:, 0], state[si], conv[si],
-                                      valid[:, 0], config)
-            return out[:, None], state.at[si].set(s), conv.at[si].set(c)
+            out, state, c = kimi.kda_step(w, h[:, 0], state, conv[si],
+                                          valid[:, 0], config, layer=si)
+            return out[:, None], state, conv.at[si].set(c)
         s = jnp.where(fresh, 0, state[si, slot])
         c = jnp.where(fresh, 0, conv[si, :, slot])
         out, s, c = kimi.kda_chunk(w, h[0], s, c, n_valid, config)

@@ -23,7 +23,14 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip, monkeypatch):
     ``ops/paged_latent_attention.py`` handed the POOL's layer index;
     the prefill chunk's chunkwise form keeps its sub-chunks' decays
     ``[2,32,64,64,128]`` (256 MiB in float32) inside the reductions
-    that use them: temporaries stay under a quarter of a GiB."""
+    that use them: temporaries stay under a quarter of a GiB. A KDA
+    layer's rule is ONE call of ``ops/kda_state_update.py`` on the
+    stacked state and the layer's index (PR 51): its ``q``, ``k``,
+    ``v``, ``g`` keep the shape ``f32[64,32,128]`` the trace's selectors
+    find the KDA operations by
+    (``benchmark/metrics/kda_state_roofline.kimi.json``), and nothing
+    else in the step takes the state, whole or a layer of it: the third
+    pass over it is gone by construction, not by the compiler's mood."""
     from ray_tpu._private import jax_compat
     from ray_tpu.models import kimi_linear as kimi
     from ray_tpu.serve.llm_engine import linear
@@ -69,7 +76,28 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip, monkeypatch):
         # The head on the rows that are read, over the share of the
         # vocabulary held.
         assert re.search(r"f32\[(64|1,2),20480\]", text)
-    calls = [line for line in step.as_text().splitlines()
+    lines = step.as_text().splitlines()
+    rule = [line for line in lines
+            if "custom-call(" in line and "kda_state_update" in line]
+    assert len(rule) == 4                   # the four KDA layers
+    for line in rule:
+        # The four vectors of a row and head among the operands, the
+        # output beside the state among the results; the state aliased.
+        operands = line.split("operand_layout_constraints={")[1].split(
+            "}, output_to_operand_aliasing")[0]
+        assert operands.count("f32[64,32,128]{") == 4 and state in operands
+        assert line.split(" custom-call(")[0].count("f32[64,32,128]{") == 1
+        assert "output_to_operand_aliasing={{1}: (6, {})}" in line
+    # What else names the state only hands it on (the entry's parameter,
+    # a loop's tuple and its elements); no operation has a layer of it.
+    hands_on = re.compile(
+        r" (parameter|get-tuple-element|tuple|while)\(|^ENTRY |^%|^HloModule")
+    assert [line[:200] for line in lines
+            if state in line and line not in rule
+            and not hands_on.search(line.strip())] == []
+    assert not any("f32[64,32,128,128]" in line for line in lines)
+    assert "kda_state_update" not in prefill.as_text()
+    calls = [line for line in lines
              if "custom-call(" in line and "paged_latent_attention" in line]
     assert len(calls) == 1                  # the one latent layer
     assert "bf16[1,16385,16,640]" in calls[0] and "bf16[64,640]" in calls[0]
