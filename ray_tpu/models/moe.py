@@ -18,21 +18,31 @@ capacity, no dropped token). The scoring is one of two:
 Beside the routed experts a model may have a shared expert, a plain
 SwiGLU every token passes with weight one (``shared_ffn``).
 
-One routing function (``route``) and one expert feed-forward
-(``expert_ffn``) serve ``llama.forward`` (training) and the paged
-engine (``serve/llm_engine/model.py``, and ``latent.py`` for the
-sigmoid scoring and the shared expert).
+One routing function (``route``) serves ``llama.forward`` (training)
+and the paged engine (``serve/llm_engine/model.py``, and ``latent.py``
+and ``linear.py`` for the sigmoid scoring and the shared expert); the
+expert feed-forward has two forms of the same arithmetic.
 
-``expert_ffn`` is an all-experts product: every resident expert is
+``expert_ffn`` (training's ``moe_mlp``, and the plain form the tests
+hold the other to) is an all-experts product: every resident expert is
 applied to every token and the result is weighted by the (mostly zero)
-combine weights. That reads each expert's three matrices once, which is
-the floor of a decode step or a prefill chunk (16 or 32 tokens x k
-choices touch most experts anyway) and costs ``E / k`` times the
+combine weights. That reads EVERY held expert's three matrices once,
+whatever share of them the tokens chose, and costs ``E / k`` times the
 arithmetic of a sparse dispatch, which is why training at scale wants
 tokens sorted by expert and a grouped product instead (ROADMAP R1,
 open). With experts sharded over ``ep`` (logical axis "expert") each
 shard applies its own experts and the contraction over experts becomes
 an all-reduce.
+
+``touched_expert_ffn`` (serving: the engine's forward has no gradient,
+and a decode step or a prefill chunk carries 16 to 128 tokens) reads
+only the experts that some token weighs above zero, each once, which is
+the floor of such a pass: a step's tokens x ``k`` choices leave a tenth
+to a half of the held experts unchosen. It is one kernel an expert layer
+(``ops/grouped_expert_ffn.py``) over the layers' STACKED expert tensors
+and the layer's index, so a family's scan closes over the three
+tensors (``split_experts``) and hands the index; a layer's slice handed
+to a kernel would be a copy of all its experts.
 
 A load-balancing auxiliary loss (fraction of the choices x mean router
 probability per expert, scaled by E: Switch Transformer eq. 4, with the
@@ -51,6 +61,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+# A layer's expert tensors, [E, H, M], [E, H, M] and [E, M, H].
+EXPERT_TENSORS = ("w_gate", "w_up", "w_down")
 # The engine's expert counters, in the order ``routing_counts`` fills
 # them (``engine.ENGINE_STAT_KEYS`` documents each).
 EXPERT_COUNTERS = ("expert_choices", "expert_slots", "experts_touched",
@@ -174,6 +186,32 @@ def expert_ffn(layer: dict, x: jax.Array, combine: jax.Array,
     hidden = (jax.nn.silu(gate) * up).astype(jnp.float32) * weight
     hidden = jnp.where(weight > 0, hidden, 0.0).astype(dtype)
     out = jnp.einsum("enm,emh->nh", hidden, layer["w_down"].astype(dtype))
+    return out.reshape(b, t, h)
+
+
+def split_experts(layers: dict) -> "tuple[dict, dict]":
+    """A stack of expert layers (every array's leading axis the layer)
+    -> (its three expert tensors, which ``touched_expert_ffn`` takes
+    whole; the rest, which a scan slices a layer at a time)."""
+    return ({k: layers[k] for k in EXPERT_TENSORS},
+            {k: v for k, v in layers.items() if k not in EXPERT_TENSORS})
+
+
+def touched_expert_ffn(experts: dict, index, x: jax.Array,
+                       combine: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
+    """``expert_ffn`` of layer ``index`` (an int32 scalar) of ``experts``,
+    the layers' stacked tensors (w_gate, w_up ``[L, E, H, M]``, w_down
+    ``[L, E, M, H]``, held in ``dtype``), reading only the experts with
+    a weight above zero in ``combine``. x [B, T, H], combine [B, T, E]
+    -> [B, T, H] in ``dtype``; the same rounding points, an unchosen
+    expert contributing exactly zero, nothing dropped."""
+    from ray_tpu.ops.grouped_expert_ffn import grouped_expert_ffn
+
+    b, t, h = x.shape
+    out = grouped_expert_ffn(
+        *(experts[k].astype(dtype) for k in EXPERT_TENSORS), index,
+        x.astype(dtype).reshape(b * t, h),
+        combine.reshape(b * t, combine.shape[-1]))
     return out.reshape(b, t, h)
 
 

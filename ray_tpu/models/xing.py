@@ -529,9 +529,13 @@ def dense_ffn(w: dict, x: jax.Array, config: XingConfig) -> jax.Array:
     return moe.swiglu(x, w["w_gate"], w["w_up"], w["w_down"], config.dtype)
 
 
-def sparse_ffn(w: dict, x: jax.Array, config: XingConfig):
+def sparse_ffn(w: dict, x: jax.Array, config: XingConfig, experts: dict,
+               index):
     """The routed experts and the shared one. x [B, T, C] (normed,
     float32: the router reads it unrounded, the experts in ``dtype``).
+    ``w`` is the layer's router and shared expert; its routed experts
+    are layer ``index`` of ``experts``, the layers' stacked tensors
+    (``moe.split_experts``), of which only the chosen are read.
     Where the layer holds a share of the experts it routes over
     (``config.held``), the chosen experts that are held. Returns (out
     [B, T, C], the chosen experts [B, T, k])."""
@@ -542,7 +546,8 @@ def sparse_ffn(w: dict, x: jax.Array, config: XingConfig):
         scale=config.routed_scaling_factor)
     combine = moe.combine_weights(idx, weights, config.num_experts,
                                   config.held)
-    out = moe.expert_ffn(w, x, combine, dtype).astype(F32)
+    out = moe.touched_expert_ffn(experts, index, x, combine,
+                                 dtype).astype(F32)
     if config.num_shared_experts:
         out = out + moe.shared_ffn(w, x, dtype).astype(F32)
     return out, idx

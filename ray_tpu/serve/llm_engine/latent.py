@@ -118,7 +118,9 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
         valid = positions > 0
     counts = jnp.zeros((len(moe.EXPERT_COUNTERS),), jnp.int32)
 
-    def layer_step(sparse: bool):
+    def layer_step(experts: "dict | None"):
+        sparse = experts is not None
+
         def step(carry, layer_and_index):
             streams, pool, counts = carry
             w, li = layer_and_index
@@ -130,7 +132,8 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
             h, post, res = xing.hyper_mix(w["hc_ffn"], streams, config)
             normed = rms_norm(h, w["mlp_norm"], eps)
             if sparse:
-                y, idx = xing.sparse_ffn(w, normed, config)
+                y, idx = xing.sparse_ffn(w, normed, config, experts,
+                                         li - config.first_k_dense)
                 counts = counts + moe.routing_counts(idx, valid,
                                                      config.num_experts)
             else:
@@ -142,12 +145,15 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
     carry, routing = (streams, cache["latent"], counts), None
     dense = config.first_k_dense
     if dense:
-        carry, _ = lax.scan(layer_step(False), carry,
+        carry, _ = lax.scan(layer_step(None), carry,
                             (params["dense"], jnp.arange(dense)))
     if config.sparse_layers:
+        # The expert tensors stay out of the scanned ``xs``: the kernel
+        # takes them stacked and the layer's index among the sparse.
+        experts, layers = moe.split_experts(params["sparse"])
         carry, routing = lax.scan(
-            layer_step(True), carry,
-            (params["sparse"], jnp.arange(dense, config.num_layers)))
+            layer_step(experts), carry,
+            (layers, jnp.arange(dense, config.num_layers)))
     streams, pool, counts = carry
     x = jnp.sum(streams, axis=-2)
     if logits_at is not None:

@@ -111,9 +111,11 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
         return out[None], state.at[si, slot].set(s), \
             conv.at[si, :, slot].set(c)
 
-    def layer(carry, w, kind, sparse, si, pi):
+    def layer(carry, w, kind, si, pi, experts=None, p=None):
         """One layer: ``si`` its index among the KDA layers or ``pi``
-        among the latent ones, whichever it is."""
+        among the latent ones, whichever it is; an expert layer is layer
+        ``p`` of ``experts``, its place in the period's stacked expert
+        tensors."""
         x, pool, state, conv, counts = carry
         h = rms_norm(x, w["mixer_norm"], eps).astype(dtype)
         if kind == kimi.KDA:
@@ -123,8 +125,8 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
                                  config, block_size, n_valid, decode)
         x = x + y.astype(F32)
         h = rms_norm(x, w["ffn_norm"], eps)
-        if sparse:
-            y, idx = xing.sparse_ffn(w["ffn"], h, config)
+        if experts is not None:
+            y, idx = xing.sparse_ffn(w["ffn"], h, config, experts, p)
             counts = counts + moe.routing_counts(
                 idx, valid, config.num_experts, config.held)
         else:
@@ -136,28 +138,33 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
              cache["kda"], cache["conv"],
              jnp.zeros((len(moe.EXPERT_COUNTERS),), jnp.int32))
     for i, w in enumerate(params["first"]):
-        carry, _ = layer(carry, w, kinds[i], False,
-                         kinds[:i].count(kimi.KDA),
+        carry, _ = layer(carry, w, kinds[i], kinds[:i].count(kimi.KDA),
                          kinds[:i].count(kimi.LATENT))
     period = config.period_kinds
     kda_before = kinds[:first].count(kimi.KDA)
     latent_before = first - kda_before
+
+    # The expert tensors stay out of the scanned ``xs``: the kernel takes
+    # a place's stack over the periods whole, and the period's index.
+    stacks, rest = zip(*(moe.split_experts(w["ffn"])
+                         for w in params["periods"]))
+    layers = [{**w, "ffn": ffn} for w, ffn in zip(params["periods"], rest)]
 
     def one_period(carry, layers_and_index):
         layers, p = layers_and_index
         chosen = []
         for i, w in enumerate(layers):
             carry, idx = layer(
-                carry, w, period[i], True,
+                carry, w, period[i],
                 kda_before + p * period.count(kimi.KDA)
                 + period[:i].count(kimi.KDA),
                 latent_before + p * period.count(kimi.LATENT)
-                + period[:i].count(kimi.LATENT))
+                + period[:i].count(kimi.LATENT), stacks[i], p)
             chosen.append(idx)
         return carry, jnp.stack(chosen)
 
     carry, routing = lax.scan(
-        one_period, carry, (params["periods"], jnp.arange(config.periods)))
+        one_period, carry, (layers, jnp.arange(config.periods)))
     x, pool, state, conv, counts = carry
     if logits_at is not None:
         x = row_beside_zeros(x, logits_at)

@@ -273,16 +273,18 @@ def _paged_attention_block(layer: dict, x: jax.Array,
     return x + out, pool_k, pool_v
 
 
-def _expert_block(layer: dict, x: jax.Array, config):
+def _expert_block(layer: dict, experts: dict, index, x: jax.Array, config):
     """The sparse feed-forward of one layer (``models/moe.py``: every
-    token through all ``experts_per_token`` of its experts). Returns
-    (out, the chosen experts [B, T, k])."""
+    token through all ``experts_per_token`` of its experts, which are
+    layer ``index`` of ``experts``, the layers' stacked expert tensors).
+    Returns (out, the chosen experts [B, T, k])."""
     normed = llama.rms_norm(x, layer["mlp_norm"], config.rms_norm_eps)
     _, idx, weights = moe.route(normed, layer["w_router"],
                                 config.experts_per_token,
                                 config.norm_topk_prob)
     combine = moe.combine_weights(idx, weights, config.num_experts)
-    return x + moe.expert_ffn(layer, normed, combine, config.dtype), idx
+    return x + moe.touched_expert_ffn(experts, index, normed, combine,
+                                      config.dtype), idx
 
 
 def row_beside_zeros(x: jax.Array, at: jax.Array) -> jax.Array:
@@ -319,8 +321,11 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
     says which rows are ``busy`` [B])."""
     x = params["embed"]["tokens"].astype(config.dtype)[tokens]
     sparse = config.num_experts > 0
-    counts = None
+    counts, layers = None, params["layers"]
     if sparse:
+        # The expert tensors stay out of the scanned ``xs``: the kernel
+        # takes them stacked and the layer's index.
+        experts, layers = moe.split_experts(layers)
         counts = jnp.zeros((len(moe.EXPERT_COUNTERS),), jnp.int32)
         if n_valid is not None:
             valid = jnp.broadcast_to(jnp.arange(tokens.shape[1]) < n_valid,
@@ -339,13 +344,13 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
         if not sparse:
             return (llama._mlp_block(layer, x, config), pool_k, pool_v,
                     counts), None
-        x, idx = _expert_block(layer, x, config)
+        x, idx = _expert_block(layer, experts, li, x, config)
         counts = counts + moe.routing_counts(idx, valid, config.num_experts)
         return (x, pool_k, pool_v, counts), idx
 
     (x, pool_k, pool_v, counts), routing = lax.scan(
         layer_step, (x, pool["k"], pool["v"], counts),
-        (params["layers"], jnp.arange(config.num_layers)))
+        (layers, jnp.arange(config.num_layers)))
     if logits_at is not None:
         x = row_beside_zeros(x, logits_at)
     x = llama.rms_norm(x, params["final_norm"], config.rms_norm_eps)

@@ -8,10 +8,11 @@ import math
 import jax
 import jax.numpy as jnp
 from v5e_compile import (  # noqa: F401 — the fixtures
-    _memory_of, _sdar, v5e_chip, v5e_devices)
+    _memory_of, _sdar, assert_experts_reach_the_kernel_whole,
+    compiled_kernels, v5e_chip, v5e_devices)
 
 
-def test_block_step_with_prev_on_v5e(v5e_chip):
+def test_block_step_with_prev_on_v5e(v5e_chip, compiled_kernels):
     """The block family's decode program as the engine runs it since it
     keeps a pass ahead (SDAR's widths, 2 of the cell's 7 layers, 32 rows
     at the whole table of 128 blocks of 16): the blocks the pass before
@@ -21,7 +22,9 @@ def test_block_step_with_prev_on_v5e(v5e_chip):
     much, the temporaries (76 MiB: the float32 logits of 128 positions)
     the same to within 0.5 MiB (the compiler assigns a few small buffers
     to other memory spaces: 250 KiB), and ``prev`` an argument that is
-    read."""
+    read. A pass's expert layer is ONE call of
+    ``ops/grouped_expert_ffn.py`` on the three stacked tensors (PR 52);
+    nothing else takes an expert tensor, a layer of it or a copy."""
     from ray_tpu.models import moe
     from ray_tpu.serve.llm_engine import model as paged_model
 
@@ -57,5 +60,8 @@ def test_block_step_with_prev_on_v5e(v5e_chip):
         layout = hlo[hlo.index("entry_computation_layout={("):]
         return layout[:layout.index(")->")]
 
+    for program in (compiled, before):
+        assert_experts_reach_the_kernel_whole(
+            program.as_text(), (2, 128, 2048, 768), 1)
     assert "s32[32,4]{" in entry_arguments(compiled.as_text())
     assert "s32[32,4]{" not in entry_arguments(before.as_text())

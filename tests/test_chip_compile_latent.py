@@ -7,12 +7,14 @@ import math
 import jax
 import jax.numpy as jnp
 import pytest
-from v5e_compile import v5e_chip, v5e_devices  # noqa: F401 — the fixtures
+from v5e_compile import (  # noqa: F401 — the fixtures
+    assert_experts_reach_the_kernel_whole, compiled_kernels, v5e_chip,
+    v5e_devices)
 
 
 @pytest.mark.parametrize("width", [128, 256, 512])
 def test_latent_programs_at_each_table_width_on_v5e(v5e_chip, width,
-                                                    monkeypatch):
+                                                    compiled_kernels):
     """Xing4.0's programs as an engine builds them (published widths, 32
     rows, a table of 512 blocks of 16; one dense and one expert layer:
     the scans make the programs the same but for their length): the
@@ -27,10 +29,13 @@ def test_latent_programs_at_each_table_width_on_v5e(v5e_chip, width,
     for the v5e: its tables of ``[32, 512]`` in SMEM, two buffers of 64
     pages in VMEM): no gathered view, no table-wide scores, no slice or
     copy of a layer of the pool, temporaries of a few MiB; the prefill
-    chunk expands its one row's view inside the score product."""
+    chunk expands its one row's view inside the score product. In both
+    programs the expert layer is ONE call of
+    ``ops/grouped_expert_ffn.py`` on the sparse layers' three stacked
+    tensors and the layer's index among them (PR 52): nothing else
+    takes an expert tensor, a layer of it or a copy of it."""
     import re
 
-    from ray_tpu._private import jax_compat
     from ray_tpu.models import xing
     from ray_tpu.serve.llm_engine import latent
     from ray_tpu.serve.llm_engine.engine import table_widths
@@ -69,11 +74,9 @@ def test_latent_programs_at_each_table_width_on_v5e(v5e_chip, width,
             if " copy(" in line and f"= bf16[{shape}]" in line] == []
     assert re.search(rf"\[(1,)?{chunk},131072\]", text) is None
     assert "f32[1,2,131072]" in text
+    assert_experts_reach_the_kernel_whole(text, (1, 64, 3584, 1024), 1)
     if width < table:
         return      # the engine builds no decode step there
-    # default_backend() is the CPU during a deviceless compile, and the
-    # decode step asks it whether its kernel interprets.
-    monkeypatch.setattr(jax_compat, "interpret_kernels", lambda: False)
     step = latent.make_engine_decode_step(config, block).lower(
         params, cache,
         on_chip(latent.FAMILY.pack_decode_rows(rows, width, ()), jnp.int32),
@@ -99,3 +102,4 @@ def test_latent_programs_at_each_table_width_on_v5e(v5e_chip, width,
         assert re.search(view, text) is None, view
     assert [line for line in text.splitlines()
             if " copy(" in line and f"= bf16[{shape}]" in line] == []
+    assert_experts_reach_the_kernel_whole(text, (1, 64, 3584, 1024), 1)

@@ -8,10 +8,13 @@ import re
 
 import jax
 import jax.numpy as jnp
-from v5e_compile import v5e_chip, v5e_devices  # noqa: F401 — the fixtures
+from v5e_compile import (  # noqa: F401 — the fixtures
+    HANDS_ON, assert_experts_reach_the_kernel_whole, compiled_kernels,
+    v5e_chip, v5e_devices)
 
 
-def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip, monkeypatch):
+def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip,
+                                                    compiled_kernels):
     """Kimi-Linear's two programs as an engine builds them (published
     widths, 32 of 256 experts held, an eighth of the vocabulary, 64
     rows, a table of 256 blocks of 16; the dense layer and ONE period:
@@ -30,8 +33,11 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip, monkeypatch):
     find the KDA operations by
     (``benchmark/metrics/kda_state_roofline.kimi.json``), and nothing
     else in the step takes the state, whole or a layer of it: the third
-    pass over it is gone by construction, not by the compiler's mood."""
-    from ray_tpu._private import jax_compat
+    pass over it is gone by construction, not by the compiler's mood.
+    In both programs each of the period's four expert layers is ONE
+    call of ``ops/grouped_expert_ffn.py`` on its place's three tensors,
+    stacked over the periods, and the period's index (PR 52): nothing
+    else takes an expert tensor, a layer of it or a copy of it."""
     from ray_tpu.models import kimi_linear as kimi
     from ray_tpu.serve.llm_engine import linear
 
@@ -39,7 +45,6 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip, monkeypatch):
                                    experts_held=32)
     assert config.kinds == ("kda", "kda", "kda", "latent", "kda")
     rows, block, table, chunk = 64, 16, 256, 128
-    monkeypatch.setattr(jax_compat, "interpret_kernels", lambda: False)
 
     def on_chip(tree, dtype=None):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
@@ -76,6 +81,7 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip, monkeypatch):
         # The head on the rows that are read, over the share of the
         # vocabulary held.
         assert re.search(r"f32\[(64|1,2),20480\]", text)
+        assert_experts_reach_the_kernel_whole(text, (1, 32, 2304, 1024), 4)
     lines = step.as_text().splitlines()
     rule = [line for line in lines
             if "custom-call(" in line and "kda_state_update" in line]
@@ -90,11 +96,9 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip, monkeypatch):
         assert "output_to_operand_aliasing={{1}: (6, {})}" in line
     # What else names the state only hands it on (the entry's parameter,
     # a loop's tuple and its elements); no operation has a layer of it.
-    hands_on = re.compile(
-        r" (parameter|get-tuple-element|tuple|while)\(|^ENTRY |^%|^HloModule")
     assert [line[:200] for line in lines
             if state in line and line not in rule
-            and not hands_on.search(line.strip())] == []
+            and not HANDS_ON.search(line.strip())] == []
     assert not any("f32[64,32,128,128]" in line for line in lines)
     assert "kda_state_update" not in prefill.as_text()
     calls = [line for line in lines

@@ -11,7 +11,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 from v5e_compile import (  # noqa: F401 — the fixtures
-    _memory_of, _sdar, v5e_chip, v5e_devices)
+    _memory_of, _sdar, assert_experts_reach_the_kernel_whole,
+    compiled_kernels, v5e_chip, v5e_devices)
+
+# An expert model's programs hold ``ops/grouped_expert_ffn.py``.
+pytestmark = pytest.mark.usefixtures("compiled_kernels")
 
 
 def _lower_paged_step(program, config, batch, block, table, chip,
@@ -145,7 +149,10 @@ def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
     it). The decode program's 129 MiB of temporaries are one gathered
     ``bf16[2048,16,16,128]`` (with 16 key-value heads it no longer fits
     the memory space Mistral's 64 MiB ones live in); the chunk program
-    has none to speak of."""
+    has none to speak of. An expert layer is ONE call of
+    ``ops/grouped_expert_ffn.py`` on the three stacked tensors and the
+    layer's index (PR 52), and nothing else in either program takes an
+    expert tensor, a layer of it or a copy of it."""
     import re
 
     lowered, pool_shape = _lower_paged_step(program, _olmoe(), 16, 16, 128,
@@ -164,6 +171,7 @@ def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
             if " copy(" in line and pool_text in line] == []
     # The accumulator rides along: int32 [2, 4] in, the same out.
     assert "s32[2,4]" in text
+    assert_experts_reach_the_kernel_whole(text, (12, 64, 2048, 1024), 1)
 
 
 @pytest.mark.parametrize("width", [32, 64, 128])
@@ -199,6 +207,10 @@ def test_decode_step_at_each_table_width_on_v5e(v5e_chip, model, width):
     assert re.search(rf"= f32\[({positions},16|16,{positions}),{kv},128\]",
                      text) is None
     assert re.search(F32_EXPERTS, text) is None
+    if model == "olmoe":
+        assert_experts_reach_the_kernel_whole(text, (2, 64, 2048, 1024), 1)
+    else:           # no expert layer: no kernel of theirs
+        assert "grouped_expert_ffn" not in text
     # The gather is of this width, in the pool's dtype.
     assert re.search(rf"bf16\[({positions},16|16,{positions}),{kv},128\]",
                      text) is not None
@@ -251,6 +263,9 @@ def test_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, model, width):
         e, m = config.num_experts, config.intermediate_size
         assert re.search(rf"f32\[(\d+,)?{e},(2048,{m}|{m},2048)\]",
                          text) is None
+        assert_experts_reach_the_kernel_whole(text, (2, e, 2048, m), 1)
+    else:
+        assert "grouped_expert_ffn" not in text
 
 
 @pytest.mark.parametrize("width", [32, 64, 128])

@@ -136,7 +136,8 @@ def test_one_expert_receives_every_token_and_drops_none(path):
     if path == "llama._moe_block":
         got, _ = llama._moe_block(layer, x, cfg)
     else:
-        got, idx = paged_model._expert_block(layer, x, cfg)
+        experts = {k: layer[k][None] for k in moe.EXPERT_TENSORS}
+        got, idx = paged_model._expert_block(layer, experts, 0, x, cfg)
         assert (np.asarray(idx) == 0).any(-1).all()  # expert 0, every token
         counts = np.asarray(moe.routing_counts(
             idx, jnp.ones(x.shape[:2], bool), cfg.num_experts))

@@ -331,10 +331,11 @@ def test_sigmoid_routing_picks_by_score_plus_bias_and_weighs_by_score():
 
 def test_the_shared_expert_is_counted_once(weights):
     cfg = tiny()
-    w = jax.tree.map(lambda a: a[0], weights(cfg)["sparse"])
+    stack = weights(cfg)["sparse"]
+    w = jax.tree.map(lambda a: a[0], stack)
     m = jnp.asarray(np.random.default_rng(1).standard_normal((2, 6, 64)),
                     jnp.float32)
-    out, idx = xing.sparse_ffn(w, m, cfg)
+    out, idx = xing.sparse_ffn(w, m, cfg, moe.split_experts(stack)[0], 0)
     with jax.default_matmul_precision("highest"):
         ref_idx, ref_weights = reference.route(m, w, numbers(cfg))
         want = reference.experts(m, w, ref_idx, ref_weights)

@@ -11,6 +11,7 @@ mode never notices. Nothing runs, so these say nothing about results or
 times. Skipped where the topology cannot be described."""
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
 
@@ -46,6 +47,45 @@ def v5e_chip(v5e_devices):
     from jax.sharding import SingleDeviceSharding
 
     return SingleDeviceSharding(v5e_devices[0])
+
+
+# A line of a compiled program's text that only hands a buffer on: the
+# entry's parameter, a loop's tuple and its elements, a computation's head.
+HANDS_ON = re.compile(
+    r" (parameter|get-tuple-element|tuple|while)\(|^ENTRY |^%|^HloModule")
+
+
+@pytest.fixture
+def compiled_kernels(monkeypatch):
+    """``default_backend()`` is the CPU during a deviceless compile, and
+    a pallas kernel asks it whether to interpret: steered here, in the
+    test, to the kernel the chip's compiler takes."""
+    from ray_tpu._private import jax_compat
+
+    monkeypatch.setattr(jax_compat, "interpret_kernels", lambda: False)
+
+
+def assert_experts_reach_the_kernel_whole(text, stack, calls):
+    """``text``: a compiled serving program of a model whose expert
+    layers' ``w_gate`` is stacked ``stack`` = (layers, E, H, M). It
+    holds ``calls`` calls of ``ops/grouped_expert_ffn.py`` (one an
+    expert layer of a scan's body), each handed the three stacked
+    tensors WHOLE; and nothing else takes an expert tensor, a layer of
+    it or a copy of it in any dtype: what else names one only hands it
+    on (the entry's parameter, a loop's tuple and its elements)."""
+    layers, e, h, m = stack
+    lines = text.splitlines()
+    kernel = [line for line in lines
+              if "custom-call(" in line and "grouped_expert_ffn" in line]
+    assert len(kernel) == calls
+    for line in kernel:
+        operands = line.split("operand_layout_constraints={")[1]
+        assert operands.count(f"bf16[{layers},{e},{h},{m}]{{") == 2
+        assert operands.count(f"bf16[{layers},{e},{m},{h}]{{") == 1
+    tensor = re.compile(rf"\[(\d+,)?{e},({h},{m}|{m},{h})\]")
+    assert [line[:200] for line in lines
+            if tensor.search(line) and line not in kernel
+            and not HANDS_ON.search(line.strip())] == []
 
 
 def _sdar(num_layers=2):
