@@ -15,8 +15,7 @@ from __future__ import annotations
 import functools
 import re
 
-from benchmark import stats
-from benchmark.readers import trace_idle_by_span
+from benchmark import stats, trace_reduce
 
 PROGRAM_SPANS = r"^(engine|serve|llm|runtime)\."
 
@@ -44,7 +43,7 @@ def values(spans: list, pattern: str, attr: str) -> list:
 
 
 def read(metric: dict, run: dict):
-    path = trace_idle_by_span.find_trace(metric)
+    path = trace_reduce.find_trace(metric)
     found = path and values(attributed_spans(path), metric["spans"],
                             metric["attr"])
     if not found:

@@ -14,8 +14,8 @@ from __future__ import annotations
 
 import re
 
-from benchmark import stats
-from benchmark.readers import trace_idle_by_span, trace_span_attr
+from benchmark import stats, trace_reduce
+from benchmark.readers import trace_span_attr
 
 EDGES = {"start": 1, "end": 2}
 
@@ -49,7 +49,7 @@ def pairs_ns(spans: list, metric: dict) -> list:
 
 
 def read(metric: dict, run: dict):
-    path = trace_idle_by_span.find_trace(metric)
+    path = trace_reduce.find_trace(metric)
     if path is None:
         return None
     found = pairs_ns(trace_span_attr.attributed_spans(path), metric)

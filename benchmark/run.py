@@ -4,7 +4,7 @@ line of standard output is the result."""
 
 import time
 
-_STARTED = time.perf_counter()  # before any import: set-up starts here
+_STARTED = time.perf_counter()  # before any import: the process starts here
 
 import os  # noqa: E402
 import sys  # noqa: E402

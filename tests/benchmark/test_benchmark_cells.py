@@ -2,6 +2,7 @@
 the CPU with a 2 s window: the last line's keys, the declared names and
 units. The numbers are not looked at: a CPU measures nothing."""
 
+import json
 import os
 import sys
 
@@ -29,6 +30,9 @@ def test_cell_rehearses_end_to_end(workload):
     assert compared.startswith("bench[correct] {")
     assert ("logit_atol" in compared) != ("loss_rtol" in compared)
     assert set(out) == RESULT_KEYS
+    # ... and the result's line, under a key of its own that comes last.
+    assert list(out)[-1] == "compared"
+    assert out["compared"] == json.loads(compared[len("bench[correct] "):])
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0
     want = declared("end_to_end", workload)

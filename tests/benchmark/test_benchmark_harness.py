@@ -26,7 +26,7 @@ from cells import (  # noqa: E402
                            "prefill_tokens_per_chunk"}),
     ("train-4k-1chip", set()),  # its readers need a device's trace
     ("serve-longgen-closed", {"decode_batch_occupancy",
-                              "stream_backlog_rows"}),
+                              "engine_stood_ms_per_step"}),
 ])
 def test_traced_run_reports_per_layer_metrics(workload, surely):
     out = result_line(run_cell("--workload", workload, "--seed", "4",

@@ -42,8 +42,6 @@ def test_the_metric_is_declared_for_its_one_cell(name):
         "name": name, "unit": "x", "better": "lower",
         "source": "program_counter", "layer": "Engine scheduler and cache",
         "moves": moves, "workloads": [cell]}
-    # Appended after everything the benchmark had.
-    assert [m["name"] for m in bench["per_layer"]][-3:] == list(NEW)
     # The cell reports the end-to-end metric the new one should move.
     assert moves in {m["name"] for m in spec.load_cell(cell).end_to_end}
     # A file of its own, beside the one whose formula it shares.

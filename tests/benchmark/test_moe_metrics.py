@@ -23,13 +23,19 @@ NEW_METRICS = {
     **{name + ".moe": MOE for name in (
         "decode_step_device_ms", "decode_batch_occupancy",
         "device_idle_share", "hbm_peak_share", "engine_host_ms_per_step",
-        "idle_ms_per_step_launch", "idle_ms_per_step_fetch",
+        # (PR 53 retired idle_ms_per_step_launch.moe and _fetch.moe: 0.35
+        # and 0.0026 ms of a 14 ms step on the ledger's PR 52 line, the
+        # device busy under both spans since PR 36; device_idle_share.moe
+        # and breakdown.idle_gaps carry what they were for.)
         "expert_ffn_time_share", "kv_gather_time_share",
         "experts_touched_share", "expert_load_max_over_mean",
         "expert_ffn_roofline")},
     **{name + ".512": SHORT for name in (
         "train_step_device_ms", "train_step_mfu", "flash_time_share",
-        "device_idle_share", "hbm_peak_share")},
+        # (PR 53 retired hbm_peak_share.512: 66.684, the very number of
+        # hbm_peak_share.train in train-4k-1chip: one configuration,
+        # 8,192 tokens a step in both.)
+        "device_idle_share")},
 }
 # The published widths (OLMoE-1B-7B) at the cell's depth.
 OLMOE = {"hidden_size": 2048, "intermediate_size": 1024, "num_experts": 64,
@@ -174,3 +180,49 @@ def test_the_selectors_match_the_chips_operation_text():
     assert [k for k, t in texts.items() if re.search(experts, t)] == \
         ["gate", "down"]
     assert [k for k, t in texts.items() if re.search(gather, t)] == ["gather"]
+
+
+def op_texts() -> dict:
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "olmoe_op_texts.json")) as f:
+        return json.load(f)
+
+
+def at_width(text: str, blocks: int) -> str:
+    """The printed operation with the decode step's gather at another
+    width of the table: 16 rows x 32, 64 or 128 blocks of 16 positions
+    (a quarter, a half, the whole of 2,048; the chip's run had 512)."""
+    for shape in ("[{},16,16,128]", "s32[{}]", "[16,16,{},1,2]",
+                  "[16,{},16,128]", "pred[16,{}]"):
+        text = text.replace(shape.format(512), shape.format(blocks))
+    return text
+
+
+@pytest.mark.parametrize("blocks", [512, 1024, 2048])
+def test_the_selectors_match_the_v5es_own_text_at_every_width(blocks):
+    """PR 53: each operation the v5e printed for the two programs in a
+    traced run of this cell is owned by the selector of its part and by
+    no other, at the quarter width the cell's steps run at and at the
+    two wider ones (the gather's pattern names the role, with the
+    number of blocks left open; it spelt the whole width until PR 53 and
+    read 0.0 on the ledger's PR 47 and PR 52 lines)."""
+    cell = {m["name"]: m for m in spec.load_cell(MOE).per_layer}
+    owners = {"experts": cell["expert_ffn_time_share.moe"]["ops"],
+              "kv_gather": cell["kv_gather_time_share.moe"]["ops"]}
+    texts = op_texts()
+    seen = []
+    for program in ("decode_step", "prefill_chunk"):
+        for op in texts[program]:
+            text = at_width(op["text"], blocks) \
+                if program == "decode_step" else op["text"]
+            seen.append((program, op["owner"]))
+            for name, ops in owners.items():
+                assert bool(re.search(ops, text)) == (name == op["owner"]), \
+                    (name, text)
+    # Two gathers a step (keys, values), one expert call, and the rest.
+    assert seen.count(("decode_step", "kv_gather")) == 2
+    assert seen.count(("decode_step", "experts")) == 1
+    assert ("decode_step", None) in seen
+    if blocks != 512:
+        assert f"bf16[{blocks},16,16,128]" in at_width(
+            texts["decode_step"][1]["text"], blocks)

@@ -24,7 +24,11 @@ CELL = "serve-phi4flash-reason-closed"
 NEW_METRICS = [name + ".phi" for name in (
     "decode_step_device_ms", "decode_batch_occupancy", "device_idle_share",
     "hbm_peak_share", "engine_host_ms_per_step", "host_calls_per_step",
-    "stream_backlog_rows", "kv_read_over_live", "shared_kv_time_share",
+    # (PR 53 retired stream_backlog_rows.phi: it read a negative count
+    # of rows, -0.0075 / -0.0119 on the ledger's PR 52 line;
+    # stream_take_age_ms_p95 and stream_get_age_ms_p95 measure the same
+    # backlog in ms.)
+    "kv_read_over_live", "shared_kv_time_share",
     "window_kv_time_share", "ssm_time_share", "decode_step_roofline")]
 # The published widths (catalog row Phi-4-mini-flash-reasoning).
 PHI = {"hidden_size": 2560, "intermediate_size": 10240,
@@ -63,12 +67,15 @@ def test_the_cell_is_what_the_issue_states():
     assert (cell["config"], cell["traffic"], cell["chips"]) == \
         ("phi4-mini-flash-serve-1chip", "reason-closed", 1)
     assert len(cell["why"]) <= 200
-    assert len(bench["workloads"]) == 7
-    assert sum(w["chips"] == 4 for w in bench["workloads"]) == 1
+    # At most a quarter of the cells, rounded down, take four chips, and
+    # at least the one (no count of cells is pinned: later PRs add).
+    assert 1 <= sum(w["chips"] == 4 for w in bench["workloads"]) \
+        <= max(1, len(bench["workloads"]) // 4)
     loaded = spec.load_cell(CELL)
     assert {m["name"] for m in loaded.end_to_end} == \
         {"serve_tokens_per_s", "setup_s"}
-    assert set(NEW_METRICS) == {m["name"] for m in loaded.per_layer}
+    # At least what PR 33 promised: a later PR adds to the cell.
+    assert set(NEW_METRICS) <= {m["name"] for m in loaded.per_layer}
     traffic = loaded.traffic
     assert traffic["generator"] == "closed_clients"
     assert (traffic["clients"], traffic["requests_per_client"]) == (48, 8)
@@ -207,7 +214,11 @@ def test_the_selectors_match_the_chips_operation_text():
     """Operations of the decode program as the v5e's compiler printed
     them in this cell's traced run (my chip run, PR 33; the ``hlo`` stat
     of the trace's events, operands cut short): each selector finds its
-    own and none of another's, and none finds the MLP or the head."""
+    own and none of another's, and none finds the MLP or the head. These
+    are the WHOLE-width step's, with PR 33's ring of 560 positions (the
+    chunk was 32): the patterns, which since PR 53 leave the ring's
+    length open and name the table's three widths, own them as before;
+    the next test holds them to the text of PR 53's run."""
     cell = per_layer()
     selectors = {name: cell[name + "_time_share.phi"]["ops"]
                  for name in ("shared_kv", "window_kv", "ssm")}
@@ -299,3 +310,54 @@ def test_the_selectors_match_the_chips_operation_text():
         found = [name for name, ops in selectors.items()
                  if re.search(ops, text)]
         assert found == ([owner] if owner else []), (what, found)
+
+
+def op_texts() -> dict:
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "phi_op_texts.json")) as f:
+        return json.load(f)
+
+
+def at_width(text: str, width: int) -> str:
+    """The printed operation at another width of the step's table: the
+    chip's run had 2,048 positions a row, gathered as 32 x 128 = 4,096
+    blocks; no other number of the decode program's text is either."""
+    return re.sub(r"\b(2048|4096)\b", lambda m: str(
+        width * int(m.group(1)) // 2048), text)
+
+
+@pytest.mark.parametrize("width", [1024, 2048, 4096])
+def test_the_selectors_match_the_v5es_own_text_at_every_width(width):
+    """PR 53: each operation the v5e printed for the two programs in a
+    traced run of this cell is owned by the selector of its part and by
+    no other, at the half width the traced window's steps ran at and at
+    the quarter and the whole (`shared_kv_time_share.phi` spelt 4096
+    until PR 53 and saw the whole-width steps alone: 5.3; the ring is
+    656 positions and `window_kv_time_share.phi` spelt 560: 0.0; ledger,
+    PR 52)."""
+    cell = per_layer()
+    owners = {name: cell[name + "_time_share.phi"]["ops"]
+              for name in ("shared_kv", "window_kv", "ssm")}
+    texts = op_texts()
+    seen = []
+    for program in ("decode_step", "prefill_chunk"):
+        for op in texts[program]:
+            text = at_width(op["text"], width) \
+                if program == "decode_step" else op["text"]
+            seen.append((program, op["owner"]))
+            for name, ops in owners.items():
+                assert bool(re.search(ops, text)) == (name == op["owner"]), \
+                    (name, text)
+    for owner in ("shared_kv", "window_kv", "ssm", None):
+        assert ("decode_step", owner) in seen
+    # The chunk's view of the pool is one row's ([1,1024,1280]) and is
+    # nobody's; its writes into the rings are the window's.
+    assert ("prefill_chunk", "shared_kv") not in seen
+    assert ("prefill_chunk", "window_kv") in seen
+    assert f"bf16[32,{width},1280]" in at_width(
+        texts["decode_step"][0]["text"], width)
+    # A ring of another length is still a ring, and never the pool.
+    longer = texts["decode_step"][7]["text"].replace("656", "784")
+    assert "bf16[8,32,784,1280]" in longer
+    assert re.search(owners["window_kv"], longer)
+    assert not re.search(owners["shared_kv"], longer)

@@ -27,8 +27,9 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 # no published file may offer such a key under `share` or `cut`.
 WIDTH_LIKE = re.compile(r"(_dim|_rank|_size|_width|_factor|_per_tok"
                         r"|_per_token)$")
-# `cut` is for depth, positions and dtype only.
-MAY_BE_CUT = re.compile(r"layer|depth|position|context|dtype")
+# `cut` is for depth, positions and dtype only; a count of leading
+# dense layers (`first_k_dense_replace`) is depth too (PR 54).
+MAY_BE_CUT = re.compile(r"layer|depth|position|context|dtype|dense_replace")
 FLOOR_EXPERTS, FLOOR_VOCABULARY_SHARE = 8, 8  # model-configs guide, 4
 
 

@@ -134,7 +134,8 @@ def test_the_cell_is_what_the_issue_states():
     loaded = spec.load_cell(CELL)
     assert {m["name"] for m in loaded.end_to_end} == \
         {"serve_tokens_per_s", "setup_s"}
-    assert set(NEW_METRICS) == {m["name"] for m in loaded.per_layer}
+    # At least what PR 35 promised: a later PR adds to the cell.
+    assert set(NEW_METRICS) <= {m["name"] for m in loaded.per_layer}
     traffic = loaded.traffic
     assert traffic["generator"] == "closed_clients"
     assert (traffic["clients"], traffic["requests_per_client"]) == (48, 8)

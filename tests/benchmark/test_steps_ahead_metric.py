@@ -3,10 +3,12 @@ while the step before them was unread, as one quantity in two entries
 (the closed cells report ``serve_tokens_per_s``, the open one
 ``token_gap_p95_ms``). Each entry is held to its own file, found and
 read through the harness's own loader, from canned counters. The
-block-diffusion cell, where the share is 0 by the family's nature, is
-not among the cells: ``test_sdar_metrics.py`` holds that cell to the set
-of metrics PR 35 gave it, and this PR may edit no benchmark file.
-Nothing here is a measurement."""
+block-diffusion cell is not among the cells: when PR 36 was written the
+share was 0 there by the family's nature and ``test_sdar_metrics.py``
+held that cell to exactly PR 35's metrics. Since PR 46 that family keeps
+a pass ahead too (its block stays on the device), and since PR 53 the
+cell is held to AT LEAST PR 35's metrics, so a ``.sdar`` entry can
+follow as new files. Nothing here is a measurement."""
 
 import json
 import os

@@ -21,11 +21,7 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from benchmark import spec, trace_reduce  # noqa: E402
-from benchmark.readers import (  # noqa: E402
-    trace_idle_by_span,
-    trace_span_attr,
-    trace_span_pair,
-)
+from benchmark.readers import trace_span_attr, trace_span_pair  # noqa: E402
 
 CLOSED3 = ["serve-longgen-closed", "serve-olmoe-longgen-closed",
            "serve-phi4flash-reason-closed"]
@@ -246,7 +242,7 @@ def test_a_trace_recorded_on_the_v5e_has_none_of_the_spans():
     path = os.path.join(REPO, "tests", "benchmark", "data",
                         "train-4k-1chip.v5e.xplane.pb")
     assert trace_span_attr.attributed_spans(path) == []
-    assert trace_idle_by_span.program_spans(path, r"^bench\.fence$")
+    assert trace_reduce.program_spans(path, r"^bench\.fence$")
 
 
 def test_the_span_table_partitions_the_cpu_by_kind_of_thread():
@@ -303,11 +299,10 @@ def test_the_entry_is_its_files(name):
         assert file[key] == declared[key]
     assert file["cells"] == cells and file["reader"] == reader
     assert len(file["what"]) > 60
-    # An end-to-end metric every one of the cells reports; the ten
-    # stand at the end of the list, after everything that was there.
+    # An end-to-end metric every one of the cells reports (the entry is
+    # found by its name: where it stands in the list says nothing).
     (moved,) = [m for m in bench["end_to_end"] if m["name"] == moves]
     assert set(cells) <= set(moved["workloads"])
-    assert {m["name"] for m in bench["per_layer"][-10:]} == set(ENTRIES)
     assert "serve-sdar-blockgen-closed" not in cells
 
 
