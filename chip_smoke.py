@@ -1294,6 +1294,9 @@ def phase_block_logits(config: dict, model_config, model: dict, reference,
 # after), 33.8% with float8-rounded weights (three seeds and one). A
 # rehearsal's toy of two expert layers reads 0.5% and 9.3%.
 LATENT_SAME_EXPERTS = 0.15
+# A configuration whose REHEARSAL cannot be held to it says its own under
+# ``rehearsal.probes.smoke_same_experts`` with the readings that set it;
+# every other run, and every run on the chip, is held to this one.
 LATENT_DIFFERING_SHARE = 0.10
 REHEARSED_DIFFERING_SHARE = 0.03
 # The linear family's state (PR 50): the worst head's error over its
@@ -1328,10 +1331,11 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
 
     A configuration of the linear family (``llm_engine/linear.py``:
     Kimi-Linear's delta-rule state a row beside a latent pool that some
-    layers own) is driven the same way, row ``i`` in row slot ``i``, the
-    chunks in the chunkwise form and the steps against the state; of the
-    long contexts the STATE after the last position is compared too,
-    every KDA layer and head, with the reference's token-by-token one."""
+    layers own, or Solar-Open2's beside gathered key and value pools) is
+    driven the same way, row ``i`` in row slot ``i``, the chunks in the
+    chunkwise form and the steps against the state; of the long contexts
+    the STATE after the last position is compared too, every KDA layer
+    and head, with the reference's token-by-token one."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1447,8 +1451,11 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
         for i in compared:
             got[i][int(positions[i])] = logits[i]
             chosen[i][int(positions[i])] = routing[:, i, 0]
-    check(bool(jax.jit(lambda pool: jnp.isfinite(pool).all())(
-        cache["latent"])), "the pool is not finite")
+    # The pool: the latent family's and Kimi-Linear's ``latent``, or the
+    # keys and values of a linear configuration whose full layers gather.
+    for part in sorted(set(cache) - {"kda", "conv"}):
+        check(bool(jax.jit(lambda pool: jnp.isfinite(pool).all())(
+            cache[part])), f"the pool ({part}) is not finite")
     states = {i: np.asarray(cache["kda"][:, i]) for i in long_rows} \
         if stateful else {}
     del cache, shown_chunk, shown_step
@@ -1527,7 +1534,8 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
         by_context.append(round(here, 4))
         gaps.append(round(gap, 4))
         worst, worst_gap = max(worst, here), max(worst_gap, gap)
-    bound = LATENT_SAME_EXPERTS
+    bound = config["probes"].get("smoke_same_experts", LATENT_SAME_EXPERTS) \
+        if rehearse else LATENT_SAME_EXPERTS
     share = LATENT_DIFFERING_SHARE if not rehearse or stateful \
         else REHEARSED_DIFFERING_SHARE
     say(name, check="logits through the latent pool against the "

@@ -19,6 +19,8 @@ the row slots and the paged latent pool); there is no training path.
     g = -exp(A_log_h) softplus(x W_fa W_fb + dt_bias)        float32,
         a head AND channel;  a = exp(g) in (0, 1)^d
     beta = sigmoid(x W_beta)                                 a head
+        (times ``kda_beta_scale``: 2 where a configuration lets the
+        transition's eigenvalue along k, 1 - beta, go negative)
     S' = diag(a_t) S_(t-1);  S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
     o_t = S_t^T q_t                S [d, d] a head, float32, zero at a
                                    request's start
@@ -110,13 +112,107 @@ _PUBLISHED_KINDS = {
     "head_dim": 128, "num_heads": 32, "short_conv_kernel_size": 4}
 
 
-def _frozen(group) -> tuple:
+def frozen_group(group) -> tuple:
     return tuple(sorted((k, tuple(v) if isinstance(v, (list, tuple)) else v)
                         for k, v in dict(group).items()))
 
 
+class DeltaStack:
+    """What a configuration of the linear family says of its stack and
+    of its KDA layers, from ``kinds`` (the mixer of each built layer),
+    ``first_k_dense``, ``num_layers``, the published
+    ``linear_attn_config`` group and the experts held: this module's
+    ``KimiLinearConfig`` and ``solar_open2.SolarOpen2Config`` (whose
+    full layers are of another kind) both are one."""
+
+    #: ``beta = kda_beta_scale * sigmoid(.)``: 2 where the published
+    #: configuration lets the transition's eigenvalues go negative.
+    kda_beta_scale = 1.0
+
+    @property
+    def _group(self) -> dict:
+        return dict(self.linear_attn_config)
+
+    @property
+    def period_kinds(self) -> tuple:
+        """The shortest run of layers the stack behind the leading
+        layers repeats: (kda, kda, latent, kda) as Kimi-Linear
+        publishes it."""
+        rest = self.kinds[self.first_k_dense:]
+        for period in range(1, len(rest) + 1):
+            if len(rest) % period == 0 and all(
+                    kind == rest[i % period] for i, kind in enumerate(rest)):
+                return rest[:period]
+
+    @property
+    def periods(self) -> int:
+        return (self.num_layers - self.first_k_dense) \
+            // len(self.period_kinds)
+
+    @property
+    def sparse_layers(self) -> int:
+        return self.num_layers - self.first_k_dense
+
+    @property
+    def kda_layers(self) -> int:
+        return self.kinds.count(KDA)
+
+    @property
+    def full_layers(self) -> int:
+        """The layers that own an entry of the paged pool."""
+        return self.num_layers - self.kda_layers
+
+    # ------------------------------------------------------ the widths
+
+    @property
+    def kda_heads(self) -> int:
+        return self._group["num_heads"]
+
+    @property
+    def kda_head_dim(self) -> int:
+        return self._group["head_dim"]
+
+    @property
+    def kda_width(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def conv_kernel(self) -> int:
+        return self._group["short_conv_kernel_size"]
+
+    @property
+    def held(self) -> tuple:
+        """(first, count): the experts this chip holds of the
+        ``num_experts`` the router chooses among (``moe.combine_weights``)."""
+        return self.first_expert, self.experts_held
+
+    def _settle_held(self) -> None:
+        """``experts_held`` None is every expert; the share is checked."""
+        if self.experts_held is None:
+            object.__setattr__(self, "experts_held", self.num_experts)
+        first, count = self.held
+        if not 0 <= first <= first + count <= self.num_experts:
+            raise ValueError(f"experts {first}..{first + count - 1} held of "
+                             f"{self.num_experts}")
+        if self.experts_per_token > self.num_experts:
+            raise ValueError("more experts a token than experts")
+
+    @property
+    def kda_mixer_params(self) -> int:
+        c, hd, d = self.hidden_size, self.kda_width, self.kda_head_dim
+        return (3 * c * hd + 3 * hd * self.conv_kernel + self.kda_heads + hd
+                + 2 * (c * d + d * hd) + c * self.kda_heads + d + hd * c)
+
+    @property
+    def sparse_ffn_params(self) -> int:
+        """One expert layer's router, held and shared experts."""
+        expert = 3 * self.hidden_size * self.moe_intermediate_size
+        return (self.hidden_size * self.num_experts + self.num_experts
+                + (self.experts_held + self.num_shared_experts) * expert)
+
+
 @dataclasses.dataclass(frozen=True)
-class KimiLinearConfig:
+class KimiLinearConfig(DeltaStack):
     vocab_size: int = 163840
     hidden_size: int = 2304
     intermediate_size: int = 9216       # the leading dense layer's SwiGLU
@@ -156,18 +252,13 @@ class KimiLinearConfig:
     family = "linear"
     #: ``mla_use_nope``: nothing is rotated (``xing.latent_queries``).
     rotary = False
+    #: The kind of the layers that own the pool (``linear.FAMILIES``).
+    full_kind = LATENT
 
     def __post_init__(self):
-        object.__setattr__(self, "linear_attn_config", _frozen(
+        object.__setattr__(self, "linear_attn_config", frozen_group(
             self.linear_attn_config or _PUBLISHED_KINDS))
-        if self.experts_held is None:
-            object.__setattr__(self, "experts_held", self.num_experts)
-        first, count = self.held
-        if not 0 <= first <= first + count <= self.num_experts:
-            raise ValueError(f"experts {first}..{first + count - 1} held of "
-                             f"{self.num_experts}")
-        if self.experts_per_token > self.num_experts:
-            raise ValueError("more experts a token than experts")
+        self._settle_held()
         # ``kinds`` raises where a built layer is in neither list.
         if not 0 <= self.first_k_dense < len(self.kinds):
             raise ValueError("no layer behind the leading ones")
@@ -190,10 +281,6 @@ class KimiLinearConfig:
     # ------------------------------------------------------- the stack
 
     @property
-    def _group(self) -> dict:
-        return dict(self.linear_attn_config)
-
-    @property
     def kinds(self) -> tuple:
         """The mixer of each built layer, ``"kda"`` or ``"latent"``."""
         group = self._group
@@ -207,55 +294,10 @@ class KimiLinearConfig:
         return tuple(out)
 
     @property
-    def period_kinds(self) -> tuple:
-        """The shortest run of layers the stack behind the leading
-        layers repeats: (kda, kda, latent, kda) as published."""
-        rest = self.kinds[self.first_k_dense:]
-        for period in range(1, len(rest) + 1):
-            if len(rest) % period == 0 and all(
-                    kind == rest[i % period] for i, kind in enumerate(rest)):
-                return rest[:period]
-
-    @property
-    def periods(self) -> int:
-        return (self.num_layers - self.first_k_dense) \
-            // len(self.period_kinds)
-
-    @property
-    def sparse_layers(self) -> int:
-        return self.num_layers - self.first_k_dense
-
-    @property
-    def kda_layers(self) -> int:
-        return self.kinds.count(KDA)
-
-    @property
     def latent_layers(self) -> int:
-        return self.kinds.count(LATENT)
+        return self.full_layers
 
     # ------------------------------------------------------ the widths
-
-    @property
-    def kda_heads(self) -> int:
-        return self._group["num_heads"]
-
-    @property
-    def kda_head_dim(self) -> int:
-        return self._group["head_dim"]
-
-    @property
-    def kda_width(self) -> int:
-        return self.kda_heads * self.kda_head_dim
-
-    @property
-    def conv_kernel(self) -> int:
-        return self._group["short_conv_kernel_size"]
-
-    @property
-    def held(self) -> tuple:
-        """(first, count): the experts this chip holds of the
-        ``num_experts`` the router chooses among (``moe.combine_weights``)."""
-        return self.first_expert, self.experts_held
 
     @property
     def latent_dim(self) -> int:
@@ -277,111 +319,113 @@ class KimiLinearConfig:
 
     @property
     def num_params(self) -> int:
-        c, hd, d = self.hidden_size, self.kda_width, self.kda_head_dim
-        kda = (3 * c * hd + 3 * hd * self.conv_kernel + self.kda_heads + hd
-               + 2 * (c * d + d * hd) + c * self.kda_heads + d + hd * c)
+        c, kda = self.hidden_size, self.kda_mixer_params
         latent = (c * self.num_heads * self.qk_head_dim
                   + c * self.latent_dim + self.kv_lora_rank
                   + self.kv_lora_rank * self.num_heads
                   * (self.qk_nope_head_dim + self.v_head_dim)
                   + self.num_heads * self.v_head_dim * c)
-        expert = 3 * c * self.moe_intermediate_size
-        sparse = (c * self.num_experts + self.num_experts
-                  + (self.experts_held + self.num_shared_experts) * expert)
         total = 2 * self.vocab_size * c + c
         for layer, kind in enumerate(self.kinds):
             total += 2 * c + (kda if kind == KDA else latent)
             total += 3 * c * self.intermediate_size \
-                if layer < self.first_k_dense else sparse
+                if layer < self.first_k_dense else self.sparse_ffn_params
         return total
 
 
 # ---------------------------------------------------------------------- init
 
 
-def init_params(config: KimiLinearConfig, key: jax.Array) -> dict:
-    """Random float32 weights. Norm scales are drawn about one;
-    ``a_log`` is log U(1, 16) and ``dt_bias`` the inverse softplus of a
-    step drawn log-uniformly in 0.001..0.1, so that a state forgets over
-    tens to thousands of positions and not at once; the router, its bias
-    and the experts' down-projections as ``xing.init_params`` draws
-    them."""
+def dense_init(key, fan_in, *shape):
+    return jax.random.normal(key, shape, F32) * fan_in ** -0.5
+
+
+def norm_init(key, *shape):
+    return 1.0 + 0.1 * jax.random.normal(key, shape, F32)
+
+
+def init_kda_mixer(config, key, *lead) -> dict:
+    """``a_log`` is log U(1, 16) and ``dt_bias`` the inverse softplus of
+    a step drawn log-uniformly in 0.001..0.1, so that a state forgets
+    over tens to thousands of positions and not at once."""
     c, hd, d = config.hidden_size, config.kda_width, config.kda_head_dim
-    heads = config.num_heads
+    keys = jax.random.split(key, 11)
+    step = jnp.exp(jax.random.uniform(
+        keys[3], (*lead, hd), F32, math.log(0.001), math.log(0.1)))
+    return {
+        "w_qkv": dense_init(keys[0], c, *lead, c, 3 * hd),
+        "conv_w": dense_init(keys[1], config.conv_kernel, *lead,
+                             config.conv_kernel, 3 * hd),
+        "a_log": jnp.log(jax.random.uniform(
+            keys[2], (*lead, config.kda_heads), F32, 1.0, 16.0)),
+        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+        "f_a": dense_init(keys[4], c, *lead, c, d),
+        "f_b": dense_init(keys[5], d, *lead, d, hd),
+        "g_a": dense_init(keys[6], c, *lead, c, d),
+        "g_b": dense_init(keys[7], d, *lead, d, hd),
+        "w_beta": dense_init(keys[8], c, *lead, c, config.kda_heads),
+        "o_norm": norm_init(keys[9], *lead, d),
+        "wo": dense_init(keys[10], hd, *lead, hd, c),
+    }
 
-    def dense_init(key, fan_in, *shape):
-        return jax.random.normal(key, shape, F32) * fan_in ** -0.5
 
-    def norm_init(key, *shape):
-        return 1.0 + 0.1 * jax.random.normal(key, shape, F32)
+def init_latent_mixer(config, key, *lead) -> dict:
+    c, heads, rank = config.hidden_size, config.num_heads, config.kv_lora_rank
+    keys = jax.random.split(key, 5)
+    return {
+        "wq": dense_init(keys[0], c, *lead, c, heads, config.qk_head_dim),
+        "wkv_a": dense_init(keys[1], c, *lead, c, config.latent_dim),
+        "kv_norm": norm_init(keys[2], *lead, rank),
+        "wkv_b": dense_init(keys[3], rank, *lead, rank, heads,
+                            config.qk_nope_head_dim + config.v_head_dim),
+        "wo": dense_init(keys[4], heads * config.v_head_dim, *lead,
+                         heads, config.v_head_dim, c),
+    }
 
-    def kda_mixer(key, *lead):
-        keys = jax.random.split(key, 11)
-        step = jnp.exp(jax.random.uniform(
-            keys[3], (*lead, hd), F32, math.log(0.001), math.log(0.1)))
-        return {
-            "w_qkv": dense_init(keys[0], c, *lead, c, 3 * hd),
-            "conv_w": dense_init(keys[1], config.conv_kernel, *lead,
-                                 config.conv_kernel, 3 * hd),
-            "a_log": jnp.log(jax.random.uniform(
-                keys[2], (*lead, config.kda_heads), F32, 1.0, 16.0)),
-            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-            "f_a": dense_init(keys[4], c, *lead, c, d),
-            "f_b": dense_init(keys[5], d, *lead, d, hd),
-            "g_a": dense_init(keys[6], c, *lead, c, d),
-            "g_b": dense_init(keys[7], d, *lead, d, hd),
-            "w_beta": dense_init(keys[8], c, *lead, c, config.kda_heads),
-            "o_norm": norm_init(keys[9], *lead, d),
-            "wo": dense_init(keys[10], hd, *lead, hd, c),
-        }
 
-    def latent_mixer(key, *lead):
-        keys = jax.random.split(key, 5)
-        rank = config.kv_lora_rank
-        return {
-            "wq": dense_init(keys[0], c, *lead, c, heads,
-                             config.qk_head_dim),
-            "wkv_a": dense_init(keys[1], c, *lead, c, config.latent_dim),
-            "kv_norm": norm_init(keys[2], *lead, rank),
-            "wkv_b": dense_init(keys[3], rank, *lead, rank, heads,
-                                config.qk_nope_head_dim + config.v_head_dim),
-            "wo": dense_init(keys[4], heads * config.v_head_dim, *lead,
-                             heads, config.v_head_dim, c),
-        }
+def init_ffn(config, key, sparse: bool, *lead) -> dict:
+    """The router, its bias and the experts' down-projections as
+    ``xing.init_params`` draws them."""
+    c = config.hidden_size
+    keys = jax.random.split(key, 8)
+    if not sparse:
+        m = config.intermediate_size
+        return {"w_gate": dense_init(keys[0], c, *lead, c, m),
+                "w_up": dense_init(keys[1], c, *lead, c, m),
+                "w_down": dense_init(keys[2], m, *lead, m, c)}
+    m, held = config.moe_intermediate_size, config.experts_held
+    out = {
+        "w_router": dense_init(
+            keys[0], c / config.router_init_scale ** 2, *lead, c,
+            config.num_experts),
+        "router_bias": config.router_bias_scale * jax.random.normal(
+            keys[1], (*lead, config.num_experts), F32),
+        "w_gate": dense_init(keys[2], c, *lead, held, c, m),
+        "w_up": dense_init(keys[3], c, *lead, held, c, m),
+        "w_down": dense_init(keys[4], m, *lead, held, m, c)
+        * config.expert_init_scale,
+    }
+    m = config.num_shared_experts * m
+    if m:
+        out.update({"shared_gate": dense_init(keys[5], c, *lead, c, m),
+                    "shared_up": dense_init(keys[6], c, *lead, c, m),
+                    "shared_down": dense_init(keys[7], m, *lead, m, c)})
+    return out
 
-    def ffn(key, sparse, *lead):
-        keys = jax.random.split(key, 8)
-        if not sparse:
-            m = config.intermediate_size
-            return {"w_gate": dense_init(keys[0], c, *lead, c, m),
-                    "w_up": dense_init(keys[1], c, *lead, c, m),
-                    "w_down": dense_init(keys[2], m, *lead, m, c)}
-        m, held = config.moe_intermediate_size, config.experts_held
-        out = {
-            "w_router": dense_init(
-                keys[0], c / config.router_init_scale ** 2, *lead, c,
-                config.num_experts),
-            "router_bias": config.router_bias_scale * jax.random.normal(
-                keys[1], (*lead, config.num_experts), F32),
-            "w_gate": dense_init(keys[2], c, *lead, held, c, m),
-            "w_up": dense_init(keys[3], c, *lead, held, c, m),
-            "w_down": dense_init(keys[4], m, *lead, held, m, c)
-            * config.expert_init_scale,
-        }
-        m = config.num_shared_experts * m
-        if m:
-            out.update({"shared_gate": dense_init(keys[5], c, *lead, c, m),
-                        "shared_up": dense_init(keys[6], c, *lead, c, m),
-                        "shared_down": dense_init(keys[7], m, *lead, m, c)})
-        return out
+
+def init_stack(config, key: jax.Array, mixers: dict) -> dict:
+    """Random float32 weights of a stack of the linear family (the
+    module's head has the tree): ``mixers`` gives each kind of layer its
+    mixer's ``init(config, key, *lead)``. Norm scales are drawn about
+    one."""
+    c = config.hidden_size
 
     def layer(key, kind, sparse, *lead):
         keys = jax.random.split(key, 4)
-        mixer = kda_mixer if kind == KDA else latent_mixer
         return {"mixer_norm": norm_init(keys[0], *lead, c),
-                "mixer": mixer(keys[1], *lead),
+                "mixer": mixers[kind](config, keys[1], *lead),
                 "ffn_norm": norm_init(keys[2], *lead, c),
-                "ffn": ffn(keys[3], sparse, *lead)}
+                "ffn": init_ffn(config, keys[3], sparse, *lead)}
 
     keys = jax.random.split(key, 5)
     kinds, dense = config.kinds, config.first_k_dense
@@ -395,6 +439,11 @@ def init_params(config: KimiLinearConfig, key: jax.Array) -> dict:
                     for kind, k in zip(config.period_kinds, jax.random.split(
                         keys[4], len(config.period_kinds)))],
     }
+
+
+def init_params(config: KimiLinearConfig, key: jax.Array) -> dict:
+    return init_stack(config, key, {KDA: init_kda_mixer,
+                                    LATENT: init_latent_mixer})
 
 
 # ----------------------------------------------------------------- KDA mixer
@@ -423,6 +472,8 @@ def _kda_inputs(w: dict, x, conved, config: KimiLinearConfig):
     g = -jnp.exp(w["a_log"].astype(F32))[:, None] * jax.nn.softplus(
         (f + w["dt_bias"].astype(F32)).reshape(*lead, heads, d))
     beta = jax.nn.sigmoid(_project(x, w["w_beta"], dtype))
+    if config.kda_beta_scale != 1.0:
+        beta = config.kda_beta_scale * beta
     return q, k, v, g, beta
 
 

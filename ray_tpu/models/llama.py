@@ -285,8 +285,18 @@ def qkv_projections(layer: dict, x: jax.Array, positions: jax.Array,
     projections, QK-norm where the configuration has it, rotary
     embedding on q and k. x [B, L, E] -> q [B, L, H, D], k, v
     [B, L, KV, D] (local heads inside a manual-tp body)."""
+    return qkv_of_normed(
+        layer, rms_norm(x, layer["attn_norm"], config.rms_norm_eps),
+        positions, config)
+
+
+def qkv_of_normed(layer: dict, normed: jax.Array, positions: jax.Array,
+                  config):
+    """``qkv_projections`` behind the input norm, for a block that
+    norms its own input (``serve/llm_engine/linear.py``). A
+    configuration that says ``rotary = False`` (``models/solar_open2.py``:
+    ``use_rope`` false) rotates nothing."""
     dtype = config.dtype
-    normed = rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
     q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
     k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
     v = jnp.einsum("ble,ekd->blkd", normed, layer["wv"].astype(dtype))
@@ -296,8 +306,9 @@ def qkv_projections(layer: dict, x: jax.Array, positions: jax.Array,
     elif config.qk_norm:
         q = _norm_over_heads(q, layer["q_norm"], config.rms_norm_eps)
         k = _norm_over_heads(k, layer["k_norm"], config.rms_norm_eps)
-    q = rope(q, positions, config.rope_theta)
-    k = rope(k, positions, config.rope_theta)
+    if getattr(config, "rotary", True):
+        q = rope(q, positions, config.rope_theta)
+        k = rope(k, positions, config.rope_theta)
     return q, k, v
 
 

@@ -1,17 +1,25 @@
-"""The paged steps of a model of linear-attention layers beside latent
-attention (``models/kimi_linear.py``): a matrix-valued recurrent state a
-row, and a latent pool that only some layers own, in ONE cache dict
-donated through every step.
+"""The paged steps of a model of linear-attention layers beside full
+attention every few layers (``models/kimi_linear.py``: latent attention;
+``models/solar_open2.py``: gated softmax attention of grouped queries):
+a matrix-valued recurrent state a row, and a pool that only the full
+layers own, in ONE cache dict donated through every step. A full layer
+knows its pool index, its place among the full layers.
 
-- ``latent`` ``[latent layers, num_blocks, bs, pool_lanes]``: the
-  latent family's pool (``latent.py``), with an entry for the layers
-  that ARE latent alone (3 of 13): a latent layer knows its pool index,
-  which is what ``latent._attention`` and the kernel behind it
+- ``latent`` ``[latent layers, num_blocks, bs, pool_lanes]`` (where the
+  full layers are latent): the latent family's pool (``latent.py``),
+  with an entry for the layers that ARE latent alone (3 of 13); the
+  index is what ``latent._attention`` and the kernel behind it
   (``ops/paged_latent_attention.py``) are handed as the layer. Paged by
   the same tables and allocator as every pool, block 0 the scratch
   block. The decode step reads it absorbed, through the tables, each
   row its own live pages; the prefill chunk gathers its row's view and
   expands it.
+- ``k`` and ``v`` ``[full layers, num_blocks, bs, kv heads, d]`` (where
+  they are grouped softmax attention): the dense family's pools
+  (``model.paged_attention``, which is handed the index as the layer),
+  1 of 4 layers here. Both programs GATHER the width of the table they
+  are handed, so the decode step exists at each of the engine's table
+  widths (``FAMILIES``: ``reads_by_row`` False).
 - ``kda`` ``[KDA layers, rows, H, d, d]`` float32 and ``conv`` ``[KDA
   layers, kernel - 1, rows, 3Hd]``: the delta rule's state and the
   short convolutions' last inputs, one slot a row, as the hybrid
@@ -29,10 +37,11 @@ donated through every step.
 
 A state does not grow with the context and the pool does: both hang on
 the one allocator and the one set of row slots. The stack is the
-leading dense layer(s) written out, then a scan over PERIODS (KDA, KDA,
-latent, KDA) with the period's layers written out in the body
-(``kimi_linear`` says why); the carry is the residual stream in
-float32, the pool, the state, the convolutions' inputs and the expert
+leading dense layer(s) written out (Solar-Open2 has none), then a scan
+over PERIODS ((KDA, KDA, latent, KDA), or (full, KDA, KDA, KDA)) with
+the period's layers written out in the body (``kimi_linear`` says why);
+the carry is the residual stream in float32, the pool's entries of the
+cache dict, the state, the convolutions' inputs and the expert
 counters. An expert layer adds the chosen experts it HOLDS
 (``config.held``); the counters count those.
 
@@ -45,6 +54,7 @@ slot). The two programs are traced under the dense model's names
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -52,7 +62,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import kimi_linear as kimi
-from ray_tpu.models import moe, xing
+from ray_tpu.models import moe, solar_open2, xing
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.serve.llm_engine.hybrid import pack_prefill_chunk
 from ray_tpu.serve.llm_engine.latent import _attention
@@ -60,6 +70,7 @@ from ray_tpu.serve.llm_engine.model import (
     Family,
     _accumulated,
     pack_decode_rows,
+    paged_attention,
     row_beside_zeros,
     row_tokens,
     sample_next,
@@ -71,9 +82,17 @@ F32 = jnp.float32
 def init_cache(config, num_blocks: int, block_size: int, rows: int,
                chunk_len: int) -> dict:
     heads, d = config.kda_heads, config.kda_head_dim
+    if config.full_kind == kimi.LATENT:
+        pool = {"latent": jnp.zeros((config.full_layers, num_blocks,
+                                     block_size, config.pool_lanes),
+                                    config.dtype)}
+    else:
+        shape = (config.full_layers, num_blocks, block_size,
+                 config.num_kv_heads, config.head_dim)
+        pool = {"k": jnp.zeros(shape, config.dtype),
+                "v": jnp.zeros(shape, config.dtype)}
     return {
-        "latent": jnp.zeros((config.latent_layers, num_blocks, block_size,
-                             config.pool_lanes), config.dtype),
+        **pool,
         "kda": jnp.zeros((config.kda_layers, rows, heads, d, d),
                          config.state_dtype),
         "conv": jnp.zeros((config.kda_layers, config.conv_kernel - 1, rows,
@@ -113,16 +132,24 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
 
     def layer(carry, w, kind, si, pi, experts=None, p=None):
         """One layer: ``si`` its index among the KDA layers or ``pi``
-        among the latent ones, whichever it is; an expert layer is layer
+        among the full ones, whichever it is; an expert layer is layer
         ``p`` of ``experts``, its place in the period's stacked expert
-        tensors."""
+        tensors. ``pool`` is the cache dict's pool entries: the latent
+        pool, or the keys' and the values'."""
         x, pool, state, conv, counts = carry
         h = rms_norm(x, w["mixer_norm"], eps).astype(dtype)
         if kind == kimi.KDA:
             y, state, conv = kda(w["mixer"], h, state, conv, si)
+        elif kind == kimi.LATENT:
+            y, latent = _attention(
+                w["mixer"], h, positions, pool["latent"], pi, tables, config,
+                block_size, n_valid, decode)
+            pool = {"latent": latent}
         else:
-            y, pool = _attention(w["mixer"], h, positions, pool, pi, tables,
-                                 config, block_size, n_valid, decode)
+            y, k, v = paged_attention(
+                w["mixer"], h, positions, pool["k"], pool["v"], pi, tables,
+                config, block_size, n_valid)
+            pool = {"k": k, "v": v}
         x = x + y.astype(F32)
         h = rms_norm(x, w["ffn_norm"], eps)
         if experts is not None:
@@ -134,15 +161,17 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
         return (x + y.astype(F32), pool, state, conv, counts), idx
 
     kinds, first = config.kinds, config.first_k_dense
-    carry = (params["embed"]["tokens"][tokens].astype(F32), cache["latent"],
+    carry = (params["embed"]["tokens"][tokens].astype(F32),
+             {k: v for k, v in cache.items() if k not in ("kda", "conv")},
              cache["kda"], cache["conv"],
              jnp.zeros((len(moe.EXPERT_COUNTERS),), jnp.int32))
     for i, w in enumerate(params["first"]):
         carry, _ = layer(carry, w, kinds[i], kinds[:i].count(kimi.KDA),
-                         kinds[:i].count(kimi.LATENT))
+                         i - kinds[:i].count(kimi.KDA))
     period = config.period_kinds
     kda_before = kinds[:first].count(kimi.KDA)
-    latent_before = first - kda_before
+    full_before = first - kda_before
+    kda_a_period = period.count(kimi.KDA)
 
     # The expert tensors stay out of the scanned ``xs``: the kernel takes
     # a place's stack over the periods whole, and the period's index.
@@ -154,12 +183,12 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
         layers, p = layers_and_index
         chosen = []
         for i, w in enumerate(layers):
+            kda_so_far = period[:i].count(kimi.KDA)
             carry, idx = layer(
                 carry, w, period[i],
-                kda_before + p * period.count(kimi.KDA)
-                + period[:i].count(kimi.KDA),
-                latent_before + p * period.count(kimi.LATENT)
-                + period[:i].count(kimi.LATENT), stacks[i], p)
+                kda_before + p * kda_a_period + kda_so_far,
+                full_before + p * (len(period) - kda_a_period)
+                + (i - kda_so_far), stacks[i], p)
             chosen.append(idx)
         return carry, jnp.stack(chosen)
 
@@ -173,15 +202,16 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
                         preferred_element_type=F32)
     if logits_at is not None:
         logits = logits[:, 0]
-    return logits, {"latent": pool, "kda": state, "conv": conv}, counts, \
-        routing
+    return logits, {**pool, "kda": state, "conv": conv}, counts, routing
 
 
 def make_engine_decode_step(config, block_size: int):
-    """The ONE decode program (the pool is read by row, absorbed; the
-    state where it lies), on ``model.pack_decode_rows``' array (row
-    ``i`` is row slot ``i``), the carried sampling key and the step
-    before's tokens ``prev`` (``model.row_tokens``)."""
+    """The decode program (a latent pool is read by row, absorbed: ONE
+    program; key and value pools are gathered at the width of the
+    table in the rows' array: one a width; the state where it lies),
+    on ``model.pack_decode_rows``' array (row ``i`` is row slot ``i``),
+    the carried sampling key and the step before's tokens ``prev``
+    (``model.row_tokens``)."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
@@ -198,7 +228,8 @@ def make_engine_decode_step(config, block_size: int):
 
 def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
     """The prefill program (one a table width the engine hands it): the
-    latent layers expanded, the KDA layers in the chunkwise form, on
+    full layers over their row's gathered view (latent layers expanded),
+    the KDA layers in the chunkwise form, on
     ``hybrid.pack_prefill_chunk``'s array; only the logits of
     ``last_idx`` are computed."""
     positions_at, table_at = 3 + chunk_len, 3 + 2 * chunk_len
@@ -225,3 +256,12 @@ FAMILY = Family(
     recurrent=True,
     reads_by_row=True,
 )
+
+#: By the kind of a configuration's full layers (``config.full_kind``):
+#: a latent pool is read by row; key and value pools are gathered, the
+#: step's table width for every row, as the dense family gathers them.
+FAMILIES = {
+    kimi.LATENT: FAMILY,
+    solar_open2.GQA: dataclasses.replace(
+        FAMILY, init_params=solar_open2.init_params, reads_by_row=False),
+}

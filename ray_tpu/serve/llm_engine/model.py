@@ -45,7 +45,9 @@ two program names and the same one-array-a-pass contract:
 ``latent.py`` for latent attention over a pool of one vector a position
 (absorbed in the decode step, expanded in the prefill chunk) under a
 residual path of several streams, ``linear.py`` for a matrix-valued
-recurrent state a row beside a latent pool that some layers own, and
+recurrent state a row beside a pool that some layers own (a latent pool
+read by row, or key and value pools gathered by ``paged_attention``
+here), and
 ``BLOCKWISE`` below for generation by diffusion over blocks: the same
 layers and pool, ``T = block_length`` query rows a row of the batch, a
 mask that lets a position see all of its own block, and a pass that
@@ -148,7 +150,8 @@ def family(config) -> Family:
     """The one place a configuration's family is looked up: a
     configuration names it (``family = "hybrid"``:
     ``models/phi4flash.py``; ``"latent"``: ``models/xing.py``;
-    ``"linear"``: ``models/kimi_linear.py``) or has a
+    ``"linear"``: ``models/kimi_linear.py`` and ``models/solar_open2.py``,
+    by the kind of their full layers) or has a
     ``block_length`` (generation by diffusion over blocks); otherwise it
     is the stack of identical layers over one paged pool of this module,
     one token a row a step."""
@@ -164,7 +167,7 @@ def family(config) -> Family:
     if named == "linear":
         from ray_tpu.serve.llm_engine import linear
 
-        return linear.FAMILY
+        return linear.FAMILIES[config.full_kind]
     if getattr(config, "block_length", 0) > 0:
         return _blockwise(config.block_length)
     return PAGED
@@ -201,27 +204,48 @@ def _paged_attention_block(layer: dict, x: jax.Array,
                            block_tables: jax.Array, config,
                            block_size: int,
                            n_valid: "jax.Array | None" = None):
-    """One attention block over layer ``li`` of the paged pool.
+    """One attention block over layer ``li`` of the paged pool: the
+    input norm, ``paged_attention`` and the residual. x: [B, T, E]
+    new-token activations. Returns (out, pool_k, pool_v)."""
+    normed = llama.rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
+    out, pool_k, pool_v = paged_attention(
+        layer, normed, positions, pool_k, pool_v, li, block_tables, config,
+        block_size, n_valid)
+    return x + out, pool_k, pool_v
 
-    x: [B, T, E] new-token activations at global ``positions`` [B, T]
-    (T=1 decode, T=chunk prefill). pool_k/pool_v: the WHOLE pool,
-    [layers, num_blocks, bs, kv, d], written at ``[li, block, offset]``
-    and gathered at ``[li, block_tables]`` (no layer slice is taken
-    out or put back). block_tables: [B, M] (append-ordered block ids,
-    0-padded). ``n_valid``: optional scalar — positions at/after it
-    scatter to the scratch block instead of the table (prefill chunk
-    padding).
+
+def paged_attention(layer: dict, normed: jax.Array, positions: jax.Array,
+                    pool_k: jax.Array, pool_v: jax.Array, li: jax.Array,
+                    block_tables: jax.Array, config, block_size: int,
+                    n_valid: "jax.Array | None" = None):
+    """Grouped softmax attention over entry ``li`` of the paged pool,
+    behind the block's input norm and before its residual.
+
+    normed: [B, T, E] the new tokens' NORMED activations at global
+    ``positions`` [B, T] (T=1 decode, T=chunk prefill). pool_k/pool_v:
+    the WHOLE pool, [entries, num_blocks, bs, kv, d], written at ``[li,
+    block, offset]`` and gathered at ``[li, block_tables]`` (no entry is
+    taken out or put back); an entry is a layer, or a layer's place
+    among those that own one (``linear.py``). block_tables: [B, M]
+    (append-ordered block ids, 0-padded). ``n_valid``: optional scalar
+    — positions at/after it scatter to the scratch block instead of
+    the table (prefill chunk padding).
 
     The gathered keys/values stay ``[B, S, kv, d]`` in the pool's
     dtype; the queries are grouped ``[B, T, kv, reps, d]`` so each
     key-value head serves its ``reps`` query heads without being
     repeated. Scores accumulate in float32 and the softmax is float32.
-    Returns (out, pool_k, pool_v).
+    Queries and keys are rotated unless the configuration says ``rotary
+    = False``; a layer with ``wg`` [E, H, d] (beside ``wq``: a dense
+    layer's ``w_gate`` is its feed-forward's) weighs each head's output,
+    channel by channel, by the sigmoid of that projection of the normed
+    input (float32) before ``wo``. Returns (out [B, T, E], pool_k,
+    pool_v).
     """
     dtype = config.dtype
     h, kv_heads, d = config.num_heads, config.num_kv_heads, config.head_dim
     (B, T), M = positions.shape, block_tables.shape[1]
-    q, k, v = llama.qkv_projections(layer, x, positions, config)
+    q, k, v = llama.qkv_of_normed(layer, normed, positions, config)
 
     # Scatter: token at global position p writes block_table[p // bs]
     # offset p % bs. Padding/inactive rows redirect to scratch block 0
@@ -268,9 +292,13 @@ def _paged_attention_block(layer: dict, x: jax.Array,
     out = jnp.einsum("bkrts,bskd->btkrd", probs, values.astype(dtype))
     if lone_row:
         out = out[:, :, :, :reps]
-    out = jnp.einsum("blhd,hde->ble", out.reshape(B, T, h, d),
-                     layer["wo"].astype(dtype))
-    return x + out, pool_k, pool_v
+    out = out.reshape(B, T, h, d)
+    if "wg" in layer:
+        gate = jnp.einsum("ble,ehd->blhd", normed, layer["wg"].astype(dtype),
+                          preferred_element_type=jnp.float32)
+        out = (out * jax.nn.sigmoid(gate)).astype(dtype)
+    out = jnp.einsum("blhd,hde->ble", out, layer["wo"].astype(dtype))
+    return out, pool_k, pool_v
 
 
 def _expert_block(layer: dict, experts: dict, index, x: jax.Array, config):

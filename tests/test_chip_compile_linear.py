@@ -108,3 +108,94 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip,
     assert "paged_latent_attention" not in prefill.as_text()
     # No gathered view of the pool in the step: it reads by row.
     assert re.search(r"\[64,4096,640\]", step.as_text()) is None
+
+
+def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
+                                                   compiled_kernels):
+    """Solar-Open2's programs as an engine builds them (published
+    widths, 40 of 320 experts held, an eighth of the vocabulary, 64
+    rows, a table of 256 blocks of 16; one period, which IS the cell's
+    depth): the decode step at the quarter and at the whole table, the
+    prefill chunk at the whole. The state ``f32[3,64,64,128,128]`` (0.75
+    GiB: 4 MiB a row and layer), the convolutions' inputs and the key
+    and value pools of the ONE full layer are updated where they lie:
+    aliased, never copied whole. A KDA layer's rule is ONE call of
+    ``ops/kda_state_update.py`` at 64 heads (a grid of 64 rows by 4
+    blocks of 16 heads, 4,096 scalars of ``beta`` prefetched), and
+    nothing else in the step takes the state. The full layer GATHERS:
+    the step's program at a width has that width's view of the pools
+    (the quarter's temporaries stay under a quarter of a GiB, the
+    whole's under 0.75: one gathered tensor and the scores), and no
+    kernel of another family's pool is called. Each of the four expert
+    layers is ONE call of ``ops/grouped_expert_ffn.py``."""
+    from ray_tpu.models import solar_open2 as solar
+    from ray_tpu.serve.llm_engine import linear
+    from ray_tpu.serve.llm_engine import model as paged_model
+
+    config = solar.SolarOpen2Config(vocab_size=24576, num_layers=4,
+                                    experts_held=40)
+    assert config.kinds == ("gqa", "kda", "kda", "kda")
+    rows, block, table, chunk = 64, 16, 256, 128
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
+
+    family = paged_model.family(config)
+    assert family is linear.FAMILIES["gqa"] and not family.reads_by_row
+    params = on_chip(jax.eval_shape(lambda: family.init_params(
+        config, jax.random.PRNGKey(0))), config.dtype)
+    cache = on_chip(jax.eval_shape(lambda: family.init_cache(
+        config, 1 + rows * table, block, rows, chunk)))
+    pool = (1, 1 + rows * table, block, 8, 128)
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": pool, "v": pool, "kda": (3, rows, 64, 128, 128),
+        "conv": (3, 3, rows, 24576)}
+    cache_bytes = sum(math.prod(v.shape) * v.dtype.itemsize
+                      for v in cache.values())
+    assert round(cache_bytes / 2 ** 30, 2) == 1.78
+    state = "f32[3,64,64,128,128]"
+
+    def step_at(width):
+        return family.make_engine_decode_step(config, block).lower(
+            params, cache,
+            on_chip(family.pack_decode_rows(rows, width, ()), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None,
+            jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
+        ).compile()
+
+    narrow, step = step_at(table // 4), step_at(table)
+    prefill = family.make_engine_prefill_chunk(config, block, chunk).lower(
+        params, cache,
+        on_chip(family.pack_prefill_chunk(chunk, table, (), 0, (), 0),
+                jnp.int32), None).compile()
+    for program, limit in ((narrow, 0.25), (step, 0.75), (prefill, 0.25)):
+        memory = program.memory_analysis()
+        assert memory.alias_size_in_bytes >= cache_bytes
+        assert memory.temp_size_in_bytes < limit * 2 ** 30
+        text = program.as_text()
+        assert [line for line in text.splitlines()
+                if " copy(" in line and f"= {state}" in line] == []
+        # The head on the rows that are read, over the share of the
+        # vocabulary held.
+        assert re.search(r"f32\[(64|1,2),24576\]", text)
+        assert_experts_reach_the_kernel_whole(text, (1, 40, 4096, 1280), 4)
+        assert "paged_latent_attention" not in text
+    for program, gathered in ((narrow, 64 * 64), (step, 64 * 256)):
+        lines = program.as_text().splitlines()
+        rule = [line for line in lines
+                if "custom-call(" in line and "kda_state_update" in line]
+        assert len(rule) == 3                   # the three KDA layers
+        for line in rule:
+            operands = line.split("operand_layout_constraints={")[1].split(
+                "}, output_to_operand_aliasing")[0]
+            assert operands.count("f32[64,64,128]{") == 4 \
+                and state in operands and "f32[4096]{" in operands
+            assert "output_to_operand_aliasing={{1}: (6, {})}" in line
+        assert [line[:200] for line in lines
+                if state in line and line not in rule
+                and not HANDS_ON.search(line.strip())] == []
+        # The view of the pools this width gathers, and no other.
+        assert f"bf16[{gathered},16,8,128]" in program.as_text()
+    assert "bf16[16384,16,8,128]" not in narrow.as_text()
+    assert "kda_state_update" not in prefill.as_text()
