@@ -1331,7 +1331,7 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
 
     A configuration of the linear family (``llm_engine/linear.py``:
     Kimi-Linear's delta-rule state a row beside a latent pool that some
-    layers own, or Solar-Open2's beside gathered key and value pools) is
+    layers own, or Solar-Open2's beside key and value pools) is
     driven the same way, row ``i`` in row slot ``i``, the chunks in the
     chunkwise form and the steps against the state; of the long contexts
     the STATE after the last position is compared too, every KDA layer
@@ -1452,7 +1452,7 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
             got[i][int(positions[i])] = logits[i]
             chosen[i][int(positions[i])] = routing[:, i, 0]
     # The pool: the latent family's and Kimi-Linear's ``latent``, or the
-    # keys and values of a linear configuration whose full layers gather.
+    # keys and values of a linear configuration of grouped full layers.
     for part in sorted(set(cache) - {"kda", "conv"}):
         check(bool(jax.jit(lambda pool: jnp.isfinite(pool).all())(
             cache[part])), f"the pool ({part}) is not finite")

@@ -3,12 +3,14 @@
 The reference delegates all device kernels to torch/CUDA; here they are
 first-class: blockwise flash attention (flash_attention.py), fused
 elementwise kernels (fused.py), the decode step's absorbed latent
-attention through the block tables (paged_latent_attention.py), one
+attention through the block tables (paged_latent_attention.py), its
+grouped softmax attention over key and value pools through the same
+tables (paged_kv_attention.py), one
 position of the gated delta rule on the state where it lies
 (kda_state_update.py) and the feed-forward of the experts a serving
 pass touched, out of the layers' stacked weights
 (grouped_expert_ffn.py); the serving engine's families import the last
-three from their modules. The training ops are
+four from their modules. The training ops are
 differentiable (custom vjp); every op falls back to pallas interpret
 mode off-TPU so the same code path runs in CPU tests.
 """

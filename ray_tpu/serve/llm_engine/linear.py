@@ -17,9 +17,11 @@ knows its pool index, its place among the full layers.
 - ``k`` and ``v`` ``[full layers, num_blocks, bs, kv heads, d]`` (where
   they are grouped softmax attention): the dense family's pools
   (``model.paged_attention``, which is handed the index as the layer),
-  1 of 4 layers here. Both programs GATHER the width of the table they
-  are handed, so the decode step exists at each of the engine's table
-  widths (``FAMILIES``: ``reads_by_row`` False).
+  1 of 4 layers here. The decode step reads them through the tables,
+  each row its own live pages (``by_row``: the kernel of
+  ``ops/paged_kv_attention.py``), so it is ONE program at the whole
+  table (``FAMILIES``: ``reads_by_row``); the prefill chunk gathers
+  the width of the table it is handed.
 - ``kda`` ``[KDA layers, rows, H, d, d]`` float32 and ``conv`` ``[KDA
   layers, kernel - 1, rows, 3Hd]``: the delta rule's state and the
   short convolutions' last inputs, one slot a row, as the hybrid
@@ -118,6 +120,9 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
         valid = jnp.broadcast_to(jnp.arange(tokens.shape[1]) < n_valid,
                                  tokens.shape)
         fresh = positions[0, 0] == 0
+    # What the family declares is what its step does: the engine builds
+    # ONE decode program and counts the reads by row on the same word.
+    by_row = decode and FAMILIES[config.full_kind].reads_by_row
 
     def kda(w, h, state, conv, si):
         if decode:
@@ -148,7 +153,7 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
         else:
             y, k, v = paged_attention(
                 w["mixer"], h, positions, pool["k"], pool["v"], pi, tables,
-                config, block_size, n_valid)
+                config, block_size, n_valid, by_row=by_row)
             pool = {"k": k, "v": v}
         x = x + y.astype(F32)
         h = rms_norm(x, w["ffn_norm"], eps)
@@ -206,9 +211,9 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
 
 
 def make_engine_decode_step(config, block_size: int):
-    """The decode program (a latent pool is read by row, absorbed: ONE
-    program; key and value pools are gathered at the width of the
-    table in the rows' array: one a width; the state where it lies),
+    """The ONE decode program (the pool read by row through the tables:
+    a latent pool absorbed, key and value pools by the grouped queries'
+    kernel; the state where it lies),
     on ``model.pack_decode_rows``' array (row ``i`` is row slot ``i``),
     the carried sampling key and the step before's tokens ``prev``
     (``model.row_tokens``)."""
@@ -257,11 +262,10 @@ FAMILY = Family(
     reads_by_row=True,
 )
 
-#: By the kind of a configuration's full layers (``config.full_kind``):
-#: a latent pool is read by row; key and value pools are gathered, the
-#: step's table width for every row, as the dense family gathers them.
+#: By the kind of a configuration's full layers (``config.full_kind``).
+#: Either kind's decode step reads its pool by row (``forward``).
 FAMILIES = {
     kimi.LATENT: FAMILY,
     solar_open2.GQA: dataclasses.replace(
-        FAMILY, init_params=solar_open2.init_params, reads_by_row=False),
+        FAMILY, init_params=solar_open2.init_params),
 }

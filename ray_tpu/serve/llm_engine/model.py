@@ -45,15 +45,16 @@ two program names and the same one-array-a-pass contract:
 ``latent.py`` for latent attention over a pool of one vector a position
 (absorbed in the decode step, expanded in the prefill chunk) under a
 residual path of several streams, ``linear.py`` for a matrix-valued
-recurrent state a row beside a pool that some layers own (a latent pool
-read by row, or key and value pools gathered by ``paged_attention``
-here), and
+recurrent state a row beside a pool that some layers own (a latent
+pool, or key and value pools through ``paged_attention`` here; the
+decode step reads either by row), and
 ``BLOCKWISE`` below for generation by diffusion over blocks: the same
 layers and pool, ``T = block_length`` query rows a row of the batch, a
 mask that lets a position see all of its own block, and a pass that
 fixes 0 to ``block_length`` of a row's masked positions.
 
-Runs on CPU under tier-1 (plain jnp/einsum — no pallas dependency);
+Runs on CPU under tier-1 (plain jnp/einsum: the one pallas kernel
+here, ``by_row``, is reached by the linear family's decode step alone);
 the block/gather structure is what the Ragged Paged Attention kernel
 (arxiv 2604.15464) implements natively on TPU.
 """
@@ -115,7 +116,8 @@ class Family:
     chunk resets; ``reads_by_row``: its decode step reads the pool
     through the tables a row at a time, each busy row the whole pages
     that hold its positions before the step's own and its fresh entry
-    beside them (``ops/paged_latent_attention.py``), where the others
+    beside them (``ops/paged_latent_attention.py`` a latent pool,
+    ``ops/paged_kv_attention.py`` key and value pools), where the others
     gather the step's table width for every row: what
     ``kv_positions_read`` counts."""
     init_params: Callable
@@ -214,52 +216,16 @@ def _paged_attention_block(layer: dict, x: jax.Array,
     return x + out, pool_k, pool_v
 
 
-def paged_attention(layer: dict, normed: jax.Array, positions: jax.Array,
-                    pool_k: jax.Array, pool_v: jax.Array, li: jax.Array,
-                    block_tables: jax.Array, config, block_size: int,
-                    n_valid: "jax.Array | None" = None):
-    """Grouped softmax attention over entry ``li`` of the paged pool,
-    behind the block's input norm and before its residual.
-
-    normed: [B, T, E] the new tokens' NORMED activations at global
-    ``positions`` [B, T] (T=1 decode, T=chunk prefill). pool_k/pool_v:
-    the WHOLE pool, [entries, num_blocks, bs, kv, d], written at ``[li,
-    block, offset]`` and gathered at ``[li, block_tables]`` (no entry is
-    taken out or put back); an entry is a layer, or a layer's place
-    among those that own one (``linear.py``). block_tables: [B, M]
-    (append-ordered block ids, 0-padded). ``n_valid``: optional scalar
-    — positions at/after it scatter to the scratch block instead of
-    the table (prefill chunk padding).
-
-    The gathered keys/values stay ``[B, S, kv, d]`` in the pool's
-    dtype; the queries are grouped ``[B, T, kv, reps, d]`` so each
-    key-value head serves its ``reps`` query heads without being
-    repeated. Scores accumulate in float32 and the softmax is float32.
-    Queries and keys are rotated unless the configuration says ``rotary
-    = False``; a layer with ``wg`` [E, H, d] (beside ``wq``: a dense
-    layer's ``w_gate`` is its feed-forward's) weighs each head's output,
-    channel by channel, by the sigmoid of that projection of the normed
-    input (float32) before ``wo``. Returns (out [B, T, E], pool_k,
-    pool_v).
-    """
+def _attend_gathered(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
+                     li: jax.Array, block_tables: jax.Array,
+                     positions: jax.Array, config, block_size: int):
+    """q [B, T, H, d] at ``positions`` over the gathered view of entry
+    ``li`` of the WRITTEN pools, the table's whole width for every row.
+    Returns [B, T, H, d]."""
     dtype = config.dtype
     h, kv_heads, d = config.num_heads, config.num_kv_heads, config.head_dim
     (B, T), M = positions.shape, block_tables.shape[1]
-    q, k, v = llama.qkv_of_normed(layer, normed, positions, config)
-
-    # Scatter: token at global position p writes block_table[p // bs]
-    # offset p % bs. Padding/inactive rows redirect to scratch block 0
-    # (never gathered past the causal mask).
-    blocks = jnp.take_along_axis(block_tables, positions // block_size,
-                                 axis=1)                      # [B, T]
-    offsets = positions % block_size
-    if n_valid is not None:
-        in_range = jnp.arange(T)[None, :] < n_valid
-        blocks = jnp.where(in_range, blocks, 0)
-        offsets = jnp.where(in_range, offsets, 0)
-    pool_k = pool_k.at[li, blocks, offsets].set(k.astype(pool_k.dtype))
-    pool_v = pool_v.at[li, blocks, offsets].set(v.astype(pool_v.dtype))
-
+    reps = h // kv_heads
     # Gather: the request's whole context, by block table. Flat index
     # s == global position (append-ordered tables).
     S = M * block_size
@@ -268,7 +234,6 @@ def paged_attention(layer: dict, normed: jax.Array, positions: jax.Array,
 
     # Query head k * reps + r reads key-value head k: the mapping of
     # llama._attention_block's jnp.repeat(k, reps, axis=2).
-    reps = h // kv_heads
     q = q.reshape(B, T, kv_heads, reps, d)
     # One query row per key-value head (a decode step without grouping)
     # is a matrix-vector product, which the TPU's compiler lowers as a
@@ -292,13 +257,83 @@ def paged_attention(layer: dict, normed: jax.Array, positions: jax.Array,
     out = jnp.einsum("bkrts,bskd->btkrd", probs, values.astype(dtype))
     if lone_row:
         out = out[:, :, :, :reps]
-    out = out.reshape(B, T, h, d)
+    return out.reshape(B, T, h, d)
+
+
+def paged_attention(layer: dict, normed: jax.Array, positions: jax.Array,
+                    pool_k: jax.Array, pool_v: jax.Array, li: jax.Array,
+                    block_tables: jax.Array, config, block_size: int,
+                    n_valid: "jax.Array | None" = None,
+                    by_row: bool = False):
+    """Grouped softmax attention over entry ``li`` of the paged pool,
+    behind the block's input norm and before its residual.
+
+    normed: [B, T, E] the new tokens' NORMED activations at global
+    ``positions`` [B, T] (T=1 decode, T=chunk prefill). pool_k/pool_v:
+    the WHOLE pool, [entries, num_blocks, bs, kv, d], written at ``[li,
+    block, offset]`` and gathered at ``[li, block_tables]`` (no entry is
+    taken out or put back); an entry is a layer, or a layer's place
+    among those that own one (``linear.py``). block_tables: [B, M]
+    (append-ordered block ids, 0-padded). ``n_valid``: optional scalar
+    — positions at/after it scatter to the scratch block instead of
+    the table (prefill chunk padding).
+
+    The gathered keys/values stay ``[B, S, kv, d]`` in the pool's
+    dtype; the queries are grouped ``[B, T, kv, reps, d]`` so each
+    key-value head serves its ``reps`` query heads without being
+    repeated. Scores accumulate in float32 and the softmax is float32.
+    Queries and keys are rotated unless the configuration says ``rotary
+    = False``; a layer with ``wg`` [E, H, d] (beside ``wq``: a dense
+    layer's ``w_gate`` is its feed-forward's) weighs each head's output,
+    channel by channel, by the sigmoid of that projection of the normed
+    input (float32) before ``wo``.
+
+    ``by_row`` (static; a decode step whose family says
+    ``reads_by_row``, ``T == 1``): nothing is gathered. The kernel of
+    ``ops/paged_kv_attention.py`` walks each row's own pages of the
+    pools as the step found them and takes the row's fresh key and
+    value beside them; a row at position 0 is inactive and attends
+    over nothing. Returns (out [B, T, E], pool_k, pool_v).
+    """
+    dtype = config.dtype
+    h, kv_heads, d = config.num_heads, config.num_kv_heads, config.head_dim
+    B, T = positions.shape
+    q, k, v = llama.qkv_of_normed(layer, normed, positions, config)
+
+    # Scatter: token at global position p writes block_table[p // bs]
+    # offset p % bs. Padding/inactive rows redirect to scratch block 0
+    # (never gathered past the causal mask).
+    blocks = jnp.take_along_axis(block_tables, positions // block_size,
+                                 axis=1)                      # [B, T]
+    offsets = positions % block_size
+    if n_valid is not None:
+        in_range = jnp.arange(T)[None, :] < n_valid
+        blocks = jnp.where(in_range, blocks, 0)
+        offsets = jnp.where(in_range, offsets, 0)
+    k, v = k.astype(pool_k.dtype), v.astype(pool_v.dtype)
+    written = (pool_k.at[li, blocks, offsets].set(k),
+               pool_v.at[li, blocks, offsets].set(v))
+    reps = h // kv_heads
+    if by_row:
+        # Imported where it is used, as ``moe.touched_expert_ffn``
+        # imports its kernel: a family that gathers never loads pallas.
+        from ray_tpu.ops.paged_kv_attention import paged_kv_attention
+
+        assert T == 1, "a row's pages are read for ONE query position"
+        at = positions[:, 0]
+        out = paged_kv_attention(
+            q[:, 0].reshape(B, kv_heads, reps, d), k[:, 0], v[:, 0],
+            pool_k, pool_v, block_tables, jnp.where(at > 0, at + 1, 0), li,
+            scale=d ** -0.5).reshape(B, T, h, d)
+    else:
+        out = _attend_gathered(q, *written, li, block_tables, positions,
+                               config, block_size)
     if "wg" in layer:
         gate = jnp.einsum("ble,ehd->blhd", normed, layer["wg"].astype(dtype),
                           preferred_element_type=jnp.float32)
         out = (out * jax.nn.sigmoid(gate)).astype(dtype)
     out = jnp.einsum("blhd,hde->ble", out, layer["wo"].astype(dtype))
-    return out, pool_k, pool_v
+    return (out, *written)
 
 
 def _expert_block(layer: dict, experts: dict, index, x: jax.Array, config):

@@ -115,19 +115,23 @@ def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
     """Solar-Open2's programs as an engine builds them (published
     widths, 40 of 320 experts held, an eighth of the vocabulary, 64
     rows, a table of 256 blocks of 16; one period, which IS the cell's
-    depth): the decode step at the quarter and at the whole table, the
-    prefill chunk at the whole. The state ``f32[3,64,64,128,128]`` (0.75
-    GiB: 4 MiB a row and layer), the convolutions' inputs and the key
-    and value pools of the ONE full layer are updated where they lie:
-    aliased, never copied whole. A KDA layer's rule is ONE call of
+    depth): the ONE decode step, at the whole table, and the prefill
+    chunk at the whole. The state ``f32[3,64,64,128,128]`` (0.75 GiB: 4
+    MiB a row and layer), the convolutions' inputs and the key and value
+    pools of the ONE full layer are updated where they lie: aliased,
+    never copied whole. A KDA layer's rule is ONE call of
     ``ops/kda_state_update.py`` at 64 heads (a grid of 64 rows by 4
     blocks of 16 heads, 4,096 scalars of ``beta`` prefetched), and
-    nothing else in the step takes the state. The full layer GATHERS:
-    the step's program at a width has that width's view of the pools
-    (the quarter's temporaries stay under a quarter of a GiB, the
-    whole's under 0.75: one gathered tensor and the scores), and no
-    kernel of another family's pool is called. Each of the four expert
-    layers is ONE call of ``ops/grouped_expert_ffn.py``."""
+    nothing else in the step takes the state. The full layer reads BY
+    ROW (PR 56): ONE call of ``ops/paged_kv_attention.py`` in the step,
+    handed both pools WHOLE, the grouped queries ``bf16[64,64,128]`` and
+    the rows' fresh keys and values; no gathered view of the pools
+    (``[64,4096,8,128]``, ``[16384,16,8,128]``) in the step, whose
+    temporaries stay under a sixteenth of a GiB where the gathered
+    step's were held under 0.75; the prefill chunk keeps the gathered
+    view of its one row and calls no such kernel. No kernel of another
+    family's pool is called. Each of the four expert layers is ONE call
+    of ``ops/grouped_expert_ffn.py``."""
     from ray_tpu.models import solar_open2 as solar
     from ray_tpu.serve.llm_engine import linear
     from ray_tpu.serve.llm_engine import model as paged_model
@@ -142,7 +146,7 @@ def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
             s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
 
     family = paged_model.family(config)
-    assert family is linear.FAMILIES["gqa"] and not family.reads_by_row
+    assert family is linear.FAMILIES["gqa"] and family.reads_by_row
     params = on_chip(jax.eval_shape(lambda: family.init_params(
         config, jax.random.PRNGKey(0))), config.dtype)
     cache = on_chip(jax.eval_shape(lambda: family.init_cache(
@@ -156,20 +160,17 @@ def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
     assert round(cache_bytes / 2 ** 30, 2) == 1.78
     state = "f32[3,64,64,128,128]"
 
-    def step_at(width):
-        return family.make_engine_decode_step(config, block).lower(
-            params, cache,
-            on_chip(family.pack_decode_rows(rows, width, ()), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None,
-            jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
-        ).compile()
-
-    narrow, step = step_at(table // 4), step_at(table)
+    step = family.make_engine_decode_step(config, block).lower(
+        params, cache,
+        on_chip(family.pack_decode_rows(rows, table, ()), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None,
+        jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
+    ).compile()
     prefill = family.make_engine_prefill_chunk(config, block, chunk).lower(
         params, cache,
         on_chip(family.pack_prefill_chunk(chunk, table, (), 0, (), 0),
                 jnp.int32), None).compile()
-    for program, limit in ((narrow, 0.25), (step, 0.75), (prefill, 0.25)):
+    for program, limit in ((step, 1 / 16), (prefill, 0.25)):
         memory = program.memory_analysis()
         assert memory.alias_size_in_bytes >= cache_bytes
         assert memory.temp_size_in_bytes < limit * 2 ** 30
@@ -181,21 +182,42 @@ def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
         assert re.search(r"f32\[(64|1,2),24576\]", text)
         assert_experts_reach_the_kernel_whole(text, (1, 40, 4096, 1280), 4)
         assert "paged_latent_attention" not in text
-    for program, gathered in ((narrow, 64 * 64), (step, 64 * 256)):
-        lines = program.as_text().splitlines()
-        rule = [line for line in lines
-                if "custom-call(" in line and "kda_state_update" in line]
-        assert len(rule) == 3                   # the three KDA layers
-        for line in rule:
-            operands = line.split("operand_layout_constraints={")[1].split(
-                "}, output_to_operand_aliasing")[0]
-            assert operands.count("f32[64,64,128]{") == 4 \
-                and state in operands and "f32[4096]{" in operands
-            assert "output_to_operand_aliasing={{1}: (6, {})}" in line
-        assert [line[:200] for line in lines
-                if state in line and line not in rule
-                and not HANDS_ON.search(line.strip())] == []
-        # The view of the pools this width gathers, and no other.
-        assert f"bf16[{gathered},16,8,128]" in program.as_text()
-    assert "bf16[16384,16,8,128]" not in narrow.as_text()
+    lines = step.as_text().splitlines()
+    rule = [line for line in lines
+            if "custom-call(" in line and "kda_state_update" in line]
+    assert len(rule) == 3                   # the three KDA layers
+    for line in rule:
+        operands = line.split("operand_layout_constraints={")[1].split(
+            "}, output_to_operand_aliasing")[0]
+        assert operands.count("f32[64,64,128]{") == 4 \
+            and state in operands and "f32[4096]{" in operands
+        assert "output_to_operand_aliasing={{1}: (6, {})}" in line
+    assert [line[:200] for line in lines
+            if state in line and line not in rule
+            and not HANDS_ON.search(line.strip())] == []
     assert "kda_state_update" not in prefill.as_text()
+    # The one full layer: the pools whole, the queries grouped, the
+    # rows' fresh keys and values; the tables, the lengths, the entry.
+    def kv_calls(text):
+        # By the call's line: a program's table of source files may name
+        # ``tests/test_paged_kv_attention.py`` where that file ran first
+        # on this worker and a cached trace carries its frames.
+        return [line for line in text.splitlines()
+                if "custom-call(" in line and "paged_kv_attention" in line]
+
+    calls = kv_calls(step.as_text())
+    assert len(calls) == 1
+    operands = calls[0].split("operand_layout_constraints={")[1]
+    assert operands.count("bf16[1,16385,16,8,128]{") == 2
+    assert operands.count("bf16[64,8,128]{") == 2
+    assert operands.count("bf16[64,64,128]{") == 1
+    assert "s32[16384]{" in operands and "s32[64]{" in operands
+    assert kv_calls(prefill.as_text()) == []
+    # No gathered view of the pools in the step, and no copy of one:
+    # what else names a pool hands it on or writes the step's position.
+    assert re.search(r"\[64,4096,8,128\]|\[16384,16,8,128\]",
+                     step.as_text()) is None
+    assert [line[:200] for line in lines
+            if " copy(" in line and "bf16[1,16385,16,8,128]" in line] == []
+    # The chunk gathers its one row's view at the table it is handed.
+    assert "bf16[256,16,8,128]" in prefill.as_text()

@@ -48,17 +48,19 @@ def test_the_engine_serves_the_references_greedy_tokens(engine):
     stats = {k: v - before[k] for k, v in engine.engine_stats().items()
              if isinstance(v, int) and not isinstance(v, bool)}
     assert stats["decode_steps_ahead"] > 0
-    # The step GATHERS: a decode program at each of the table's three
-    # widths, as the dense family has them, and the narrow ones used.
-    assert engine._widths == engine._step_widths == (4, 8, 16)
+    # The step reads BY ROW (``ops/paged_kv_attention.py``): ONE decode
+    # program, at the whole table; the prefill chunk keeps the ladder.
+    assert engine._family.reads_by_row
+    assert engine._widths == (4, 8, 16) and engine._step_widths == (16,)
     assert (engine._decode_step._cache_size(),
-            engine._prefill_step._cache_size()) == (3, 3)
-    assert 0 < stats["decode_steps_narrow"] <= stats["decode_steps"]
+            engine._prefill_step._cache_size()) == (1, 3)
+    assert stats["decode_steps_narrow"] == 0 < stats["decode_steps"]
     # A state a request: reset on its first chunk, counted.
     assert stats["state_resets"] == stats["first_tokens"] == 6
-    # The full layers' positions: every row of the step's width read.
-    assert 0 < stats["kv_positions_live"] < stats["kv_positions_read"]
-    assert stats["kv_positions_read"] % (ROWS * BLOCK) == 0
+    # The full layers' positions: each busy row the whole pages up to
+    # its own position, so never a page a row more than what is live.
+    over = stats["kv_positions_read"] - stats["kv_positions_live"]
+    assert 0 <= over < BLOCK * stats["decode_tokens"]
     # The expert counters count the experts HELD: 8 layers of 8 held of
     # 16, 3 choices a token of which about half land here.
     layer_steps = 8 * (stats["decode_steps"] + stats["prefill_chunks"])
@@ -84,7 +86,7 @@ def test_the_smoke_drives_the_family_at_its_rehearsal_size(capsys):
     """``chip_smoke.py --paged-logits`` on the cell's configuration at
     the file's rehearsal size: every row busy, contexts that end at the
     table's three widths, the chunks in the chunkwise form and the steps
-    against the state and the gathered pools, logits, expert choices and
+    against the state and the pools read by row, logits, expert choices and
     the state itself by layer against the plain reference. It shows
     that the path holds; the chip run holds the first KDA layer's state
     under ``STATE_ERROR``."""
@@ -99,7 +101,7 @@ def test_the_smoke_drives_the_family_at_its_rehearsal_size(capsys):
     out = capsys.readouterr().out
     assert "smoke[linear] check=" in out
     assert "solar_open2_decoder" in out and "contexts=[12, 16, 32, 64]" in out
-    # The pools of the ONE full layer, gathered: 4 rows of 4 blocks of 16.
+    # The pools of the ONE full layer: 4 rows of 4 blocks of 16.
     assert '"k": [[1, 17, 16, 2, 16], "bfloat16"]' in out
     states = out.split("state_error_by_long_context_and_layer=")[1]
     assert states.count("[") == 4         # three long contexts, by layer

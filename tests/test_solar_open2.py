@@ -261,11 +261,11 @@ def test_a_reused_row_slot_starts_from_zero(weights):
 def test_the_family_follows_from_the_configuration():
     family = paged_model.family(tiny())
     assert family is linear.FAMILIES[solar.GQA] is not linear.FAMILY
-    # A state a row beside a pool that is GATHERED: the decode program
-    # at each of the engine's table widths.
-    assert family.recurrent and not family.reads_by_row
-    assert dataclasses.replace(family, reads_by_row=True,
-                               init_params=kimi.init_params) == linear.FAMILY
+    # A state a row beside pools that the decode step reads BY ROW, as
+    # Kimi-Linear's reads its latent pool: ONE decode program.
+    assert family.recurrent and family.reads_by_row
+    assert dataclasses.replace(
+        family, init_params=kimi.init_params) == linear.FAMILY
     assert family.init_params is solar.init_params
     assert family.pack_prefill_chunk is hybrid.pack_prefill_chunk
     assert family.pack_decode_rows is paged_model.PAGED.pack_decode_rows
@@ -280,6 +280,31 @@ def test_the_family_follows_from_the_configuration():
         "v": ((1, 9, 16, 8, 128), "bfloat16"),
         "kda": ((3, 64, 64, 128, 128), "float32"),
         "conv": ((3, 3, 64, 24576), "bfloat16")}
+
+
+@pytest.mark.parametrize("by_row", [True, False], ids=["by-row", "gathers"])
+def test_the_decode_step_reads_as_its_family_says(by_row, monkeypatch):
+    """``Family.reads_by_row`` is the ONE word the engine's decode
+    widths, its counters and the step's read hang on: a family that
+    says no gathers in its step too, and neither calls the kernel in a
+    prefill chunk."""
+    cfg = tiny()
+    monkeypatch.setitem(linear.FAMILIES, solar.GQA, dataclasses.replace(
+        linear.FAMILIES[solar.GQA], reads_by_row=by_row))
+    params = jax.eval_shape(
+        lambda: solar.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: fresh_cache(cfg))
+    rows = jnp.ones((ROWS, 1), jnp.int32)
+    tables = jnp.zeros((ROWS, TABLE), jnp.int32)
+    step = jax.make_jaxpr(lambda p, c: linear.forward(
+        p, c, rows, rows, tables, cfg, BLOCK))(params, cache)
+    chunk = jax.make_jaxpr(lambda p, c: linear.forward(
+        p, c, jnp.ones((1, CHUNK), jnp.int32),
+        jnp.arange(CHUNK, dtype=jnp.int32)[None], tables[:1], cfg, BLOCK,
+        slot=0, n_valid=jnp.int32(CHUNK), logits_at=jnp.int32(0)))(
+            params, cache)
+    assert ("paged_kv_attention" in str(step)) == by_row
+    assert "paged_kv_attention" not in str(chunk)
 
 
 # --------------- (e) the full layer: the dense family's block, told more
