@@ -55,7 +55,13 @@ On the record: every phase of a loop pass is a ``tracing.phase`` (an
 clock what the host did in a device idle gap; the time counters
 (``loop_wall_us`` ... ``decode_host_us``) say the same to ``/metrics``
 without a session, and ``host_calls`` counts the calls into JAX, each
-of which lets go of the interpreter; a request's four stamps become the
+of which lets go of the interpreter; ``launches_starved`` counts the
+launches that found the device drained (``starved`` on the two launch
+spans), session or none; a leaf span that takes the engine's lock
+carries ``lock_wait_us``, from asking to holding, and the collector's
+pauses are the process's ``gc_pause_us``, ``gc_full_collections`` and
+the span ``runtime.gc`` (``util/tracing.py:record_gc``); a request's
+four stamps become the
 spans ``llm.request`` > ``llm.queue`` / ``llm.prefill`` /
 ``llm.decode`` at its seal while tracing is armed.
 
@@ -146,6 +152,19 @@ ENGINE_STAT_KEYS = (
     # Decode steps launched while the step before was still unread, on
     # its tokens where they lay on the device.
     "decode_steps_ahead",
+    # Decode steps and prefill chunks launched onto a device that had
+    # already finished everything launched before (``_drained``): the
+    # chip stood until that launch landed. ``ahead`` says the host did
+    # not wait for a step's tokens; this says the chip did wait for the
+    # host. Once a request by construction: the first step after its
+    # first token's blocking read.
+    "launches_starved",
+    # The collector's pauses, the whole process's as ``process_cpu_us``
+    # is, read when the counters are asked for
+    # (``tracing.gc_counters``): wall time inside collections of every
+    # generation, during which every thread stands, and the number of
+    # full ones (generation 2).
+    "gc_pause_us", "gc_full_collections",
 )
 
 # The engine thread lets go of the interpreter inside every program call
@@ -216,17 +235,41 @@ class _Step:
     held), its host array (``rows``) at its table ``width``, the
     positions its rows' contexts hold (``live``) and those it reads of
     the pool (``read``), whether the step before it was unread at its
-    launch (``ahead``); from the launch on its tokens on the device
-    (``out``) and the sampling key it returned, split again by any
-    first token sampled since (``key``)."""
+    launch (``ahead``); from the launch on whether it found the device
+    drained (``starved``), its tokens on the device (``out``) and the
+    sampling key it returned, split again by any first token sampled
+    since (``key``)."""
 
     __slots__ = ("active", "slots", "rows", "width", "live", "read",
-                 "ahead", "out", "key")
+                 "ahead", "starved", "out", "key")
 
     def __init__(self, active, slots, rows, width, live, read, ahead):
         self.active, self.slots, self.rows = active, slots, rows
         self.width, self.live, self.read = width, live, read
         self.ahead = ahead
+
+
+class _Held:
+    """The engine's lock, taken inside the leaf span ``span``: while
+    the span is live the time from asking to holding goes on it as
+    ``lock_wait_us`` (two ``monotonic_ns``); otherwise no clock is
+    read."""
+
+    __slots__ = ("_lock", "_span")
+
+    def __init__(self, lock, span):
+        self._lock, self._span = lock, span
+
+    def __enter__(self) -> None:
+        if not self._span.live:
+            self._lock.acquire()
+            return
+        asked = time.monotonic_ns()
+        self._lock.acquire()
+        self._span.set(lock_wait_us=(time.monotonic_ns() - asked) // 1000)
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self._lock.release()
 
 
 #: ``_grow_or_preempt_locked``: the table cannot grow without a victim
@@ -326,6 +369,7 @@ class LLMEngine:
             sys.setswitchinterval(_SWITCH_INTERVAL_S)
         self._build_programs()
         _LIVE.add(self)
+        tracing.record_gc(self)
         self._loop_thread = threading.Thread(
             target=self._engine_loop, name="llm-paged-engine", daemon=True)
         self._loop_thread.start()
@@ -621,7 +665,7 @@ class LLMEngine:
             else tracing.profiler_phase
         with phase("engine.iteration"):
             with phase("engine.sweep") as sweep:
-                with self._lock:
+                with _Held(self._lock, sweep):
                     newly_expired = self._sched.sweep_expired()
                     for req in newly_expired:
                         self._counters["deadline_expired"] += 1
@@ -690,7 +734,7 @@ class LLMEngine:
         status = _UNREAD
         while status == _UNREAD:
             with tracing.phase("engine.prefill.schedule") as span, \
-                    self._lock:
+                    _Held(self._lock, span):
                 if self._sched.prefilling is None:
                     claimed = self._sched.claim_prefill()
                     if claimed is not None and claimed.preempted > 0:
@@ -721,10 +765,13 @@ class LLMEngine:
             # nothing to prefill, all of it opens the block in flight.
             return self._enter_decode(req, None)
 
-        with tracing.phase("engine.prefill.launch", req=req.rid, tokens=n):
+        with tracing.phase("engine.prefill.launch", req=req.rid,
+                           tokens=n) as launch:
             chunk = self._family.pack_prefill_chunk(
                 self.prefill_chunk_len, width,
                 req.context[start:start + n], start, table, req.slot)
+            starved = self._drained()
+            launch.set(starved=starved)
             try:
                 with jax_compat.set_mesh(self._mesh):
                     last_logits, self._pool, self._expert_stats = \
@@ -736,6 +783,7 @@ class LLMEngine:
             with self._lock:
                 self._counters["host_calls"] += 1
                 self._counters["prefill_chunks"] += 1
+                self._counters["launches_starved"] += starved
                 self._counters["prefill_tokens"] += n
                 self._counters["window_blocks_recycled"] += \
                     self._recycled(start, start + n)
@@ -751,7 +799,7 @@ class LLMEngine:
         batch, with its first token where prefill yields one (not under
         diffusion over blocks, and not on a resume)."""
         with tracing.phase("engine.prefill.first_token", req=req.rid) \
-                as first_token, self._lock:
+                as first_token, _Held(self._lock, first_token):
             if first_token.live:
                 first_token.set(request=req.request_id)
             req.position = len(req.context)
@@ -820,7 +868,7 @@ class LLMEngine:
         planned = _UNREAD
         while planned is _UNREAD:
             with tracing.phase("engine.decode.schedule") as span, \
-                    self._lock:
+                    _Held(self._lock, span):
                 planned = self._plan_step_locked()
                 if isinstance(planned, _Step):
                     span.set(rows=len(planned.active))
@@ -831,8 +879,10 @@ class LLMEngine:
             self._maybe_chaos_slow_step()
             try:
                 with tracing.phase("engine.decode.launch",
-                                   rows=len(step.active)), \
+                                   rows=len(step.active)) as launch, \
                         jax_compat.set_mesh(self._mesh):
+                    step.starved = self._drained()
+                    launch.set(starved=step.starved)
                     step.out, self._pool, self._expert_stats, step.key = \
                         self._decode_step(
                             self.params, self._pool, step.rows,
@@ -846,6 +896,16 @@ class LLMEngine:
         if before is not None:
             self._read_step(before)
         return True
+
+    def _drained(self) -> int:
+        """1 where the device has finished everything this engine
+        launched: asked just before a launch, it says the chip stands
+        until that launch lands. Every program takes the cache and
+        hands it back, so the cache the engine holds is a result of the
+        newest one launched, of either kind; its first array is asked
+        ``is_ready()``, which does not wait (and holds on to the
+        interpreter: it is no call of ``host_calls``)."""
+        return int(next(iter(self._pool.values())).is_ready())
 
     def _plan_step_locked(self):
         """The next decode step (caller holds the lock): a ``_Step``
@@ -931,10 +991,11 @@ class LLMEngine:
         self._pass.decoded = True
         active, width = step.active, step.width
         with tracing.phase("engine.decode.emit", rows=len(active)) as span:
-            with self._lock:
+            with _Held(self._lock, span):
                 self._counters["host_calls"] += 2  # the call, the read
                 self._counters["decode_steps"] += 1
                 self._counters["decode_steps_ahead"] += step.ahead
+                self._counters["launches_starved"] += step.starved
                 if len(active) >= 2:
                     self._counters["batched_decode_steps"] += 1
                 self._counters["block_rows"] += len(active)
@@ -1004,6 +1065,7 @@ class LLMEngine:
         out["blocks_allocated"] = int(self._sched.cache.blocks_allocated)
         out["blocks_freed"] = int(self._sched.cache.blocks_freed)
         out["process_cpu_us"] = time.process_time_ns() // 1000
+        out.update(tracing.gc_counters())
         expert_stats = self._expert_stats
         if expert_stats is not None:
             # One transfer, here and nowhere else; it waits for the
@@ -1045,6 +1107,7 @@ class LLMEngine:
         for req in victims:
             self._seal(req, RuntimeError("LLM engine shut down"))
         self._loop_thread.join(timeout=5.0)
+        tracing.forget_gc(self)
 
     def __del__(self):
         if hasattr(self, "_shutdown"):  # the constructor got that far
@@ -1068,6 +1131,7 @@ def merged_engine_stats() -> "dict | None":
             out[key] += int(value)
     # The process's, not an engine's: once, whatever the engines.
     out["process_cpu_us"] = time.process_time_ns() // 1000
+    out.update(tracing.gc_counters())
     return out
 
 

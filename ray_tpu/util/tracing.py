@@ -44,6 +44,15 @@ subsystem behind ``ray timeline``. Here the tracer is built in:
   ``cpu_us``, its thread's CPU time: wall less ``cpu_us`` is how long
   the thread stood.
 
+  The collector's pauses are on the record the same way: while a
+  process hosts an engine (``record_gc``) a ``gc.callbacks`` entry sums
+  every collection's wall time and counts the full ones
+  (``gc_counters()``: ``gc_pause_us``, ``gc_full_collections``), and
+  while a sink is live a collection of generation 1 or 2 is one
+  ``phase`` too, ``runtime.gc`` (``generation``, ``collected``,
+  ``pause_us``), on the thread that tripped it: every other thread
+  stands for as long.
+
 Cost discipline: when tracing is disabled every instrumentation site
 pays one module-attribute branch (``if tracing.TRACE_ON:``) — the same
 contract as ``chaos.ACTIVE``; a ``phase`` also asks whether a profiler
@@ -54,12 +63,14 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import itertools
 import json
 import os
 import sys
 import threading
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Iterator
 
@@ -337,6 +348,76 @@ class profiler_phase(phase):
 
     __slots__ = ()
     _records_span = False
+
+
+# The collector's pauses. A collection runs on the thread that tripped
+# it with the interpreter held, so every other thread of the process
+# stands until it ends; a full one over a serving process's heap takes
+# 0.04 to 0.23 s. The counters are the process's, not an engine's, and
+# monotonic; the two globals below are written with the interpreter
+# held and by one collection at a time (the collector does not nest).
+_GC_SPAN_FROM = 1   # the youngest generation whose collections are spans
+_gc_users: "weakref.WeakSet" = weakref.WeakSet()
+_gc_pause_ns = 0
+_gc_full = 0
+_gc_started_ns = 0
+_gc_phase: "phase | None" = None
+
+
+def _on_gc(event: str, info: dict) -> None:
+    """The ``gc.callbacks`` entry: two clock reads and two additions a
+    collection; a ``phase`` as well for a generation from
+    ``_GC_SPAN_FROM`` on, which reads no clock of its own and builds
+    nothing while no sink is live (generation 0, 18 a second at 0.7 ms
+    in the fullest serve cell's process, is never a span; generation 1
+    takes 1.5 to 2.4 ms there and a full one 0.13 to 0.23 s)."""
+    global _gc_pause_ns, _gc_full, _gc_started_ns, _gc_phase
+    if event == "start":
+        if info["generation"] >= _GC_SPAN_FROM and live():
+            _gc_phase = phase("runtime.gc", generation=info["generation"])
+            _gc_phase.__enter__()
+        _gc_started_ns = time.monotonic_ns()
+        return
+    if not _gc_started_ns:
+        return  # the callback went in while this collection ran
+    pause_ns = time.monotonic_ns() - _gc_started_ns
+    _gc_started_ns = 0
+    _gc_pause_ns += pause_ns
+    _gc_full += info["generation"] == 2
+    opened, _gc_phase = _gc_phase, None
+    if opened is not None:
+        opened.set(collected=info["collected"], pause_us=pause_ns // 1000)
+        opened.__exit__(None, None, None)
+
+
+def record_gc(user) -> None:
+    """Put the collector's pauses on the record for as long as ``user``
+    (an engine) lives or until it says ``forget_gc``: one callback a
+    process, however many users."""
+    _gc_users.add(user)
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def forget_gc(user) -> None:
+    """``user`` is done; the callback goes with the last one."""
+    global _gc_started_ns, _gc_phase
+    _gc_users.discard(user)
+    if not _gc_users and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+        # Asked from inside a collection (an engine's finaliser): its
+        # "stop" will not come.
+        opened, _gc_phase, _gc_started_ns = _gc_phase, None, 0
+        if opened is not None:
+            opened.__exit__(None, None, None)
+
+
+def gc_counters() -> dict:
+    """``gc_pause_us``: wall time inside collections of every
+    generation since the callback went in; ``gc_full_collections``:
+    those of generation 2 among them."""
+    return {"gc_pause_us": _gc_pause_ns // 1000,
+            "gc_full_collections": _gc_full}
 
 
 @contextlib.contextmanager

@@ -318,9 +318,12 @@ def test_building_the_programs_leaves_the_key_and_the_caches(served):
     np.testing.assert_array_equal(served.key_after_building,
                                   np.asarray(jax.random.PRNGKey(5 + 1)))
     stats = dict(served.stats_after_building)
-    # (``process_cpu_us`` is the process's clock, not a count of
-    # what this engine did.)
+    # (``process_cpu_us`` is the process's clock and the collector's
+    # two counters the process's too, not counts of what this engine
+    # did.)
     assert stats.pop("process_cpu_us") > 0
+    assert stats.pop("gc_pause_us") >= 0
+    assert stats.pop("gc_full_collections") >= 0
     assert all(value == 0 for value in stats.values()), stats
     assert served.written_after_building
     for name, written in served.written_after_building.items():
