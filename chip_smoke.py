@@ -760,7 +760,8 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
     shown_step = jax.jit(
         lambda params, pool, tokens, positions, tables:
         paged_model._forward_paged(params, pool, tokens, positions[:, None],
-                                   tables, model_config, block),
+                                   tables, model_config, block,
+                                   by_row=paged_model.PAGED.reads_by_row),
         donate_argnums=(1,))
 
     got = [[] for _ in contexts]        # (position, logits row)

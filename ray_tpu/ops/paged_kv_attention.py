@@ -111,16 +111,17 @@ def _kernel(tables_ref, lengths_ref, entry_ref,      # scalar prefetch
 
     def start(row, c, slot, n):
         """Start the DMAs of chunk ``c`` of ``row``, ``n`` pages of each
-        pool. A whole chunk's are written out (the scalar unit issues
-        them back to back), a row's last chunk's are a loop of ``n``."""
+        pool. A whole chunk's are written out when LOWERED (the scalar
+        unit issues them back to back; traced once: they were half the
+        kernel's trace), a row's last chunk's are a loop of ``n``."""
         def one(i):
             for copy in page_copies(row, c, slot, i):
                 copy.start()
 
         @pl.when(n == pages_per_chunk)
         def _():
-            for i in range(pages_per_chunk):
-                one(i)
+            lax.fori_loop(0, pages_per_chunk, lambda i, _: one(i), None,
+                          unroll=True)
 
         @pl.when(n < pages_per_chunk)
         def _():

@@ -74,6 +74,7 @@ typed (caller-side seal, stage recorded) instead of hanging streams.
 from __future__ import annotations
 
 import functools
+import importlib
 import os
 import sys
 import threading
@@ -193,14 +194,14 @@ def table_widths(blocks_per_seq: int) -> "tuple[int, ...]":
     may be given, in blocks, narrowest first: a quarter, a half and the
     whole of a row's table, each in whole blocks. A chunk gathers and
     attends over its whole width, and so does every row of a step of a
-    family that gathers, so a chunk takes the narrowest that holds its
-    request's table and such a step the narrowest that holds its
-    longest live one (at 128 tokens a chunk's float32 scores over
-    Mistral's whole table are 33 MB a layer: 12.81 ms a chunk for 12.24
-    at the quarter, PR 38). A step that reads each row's own pages
-    through the tables (``Family.reads_by_row``) reads the same at
-    every width: it has one program, at the whole table, and the
-    engine builds it once (``LLMEngine._step_widths``).
+    family that gathers (a hybrid's, a pass of diffusion over blocks),
+    so a chunk takes the narrowest that holds its request's table and
+    such a step the narrowest that holds its longest live one (at 128
+    tokens a chunk's float32 scores over Mistral's whole table are 33 MB
+    a layer: 12.81 ms a chunk for 12.24 at the quarter, PR 38). A step
+    that reads each row's own pages through the tables
+    (``Family.reads_by_row``) reads the same at every width: it has one
+    program, at the whole table (``LLMEngine._step_widths``).
     Three, because each is a program built before the engine serves:
     halving twice keeps the read within twice the longest context down
     to a quarter of the table, and a further rung would add a compile
@@ -294,6 +295,11 @@ class LLMEngine:
 
         self.config = config or llama.LlamaConfig.tiny()
         self._family = paged_model.family(self.config)
+        if self._family.reads_by_row:
+            # Its step reads through a pallas kernel, whose modules take
+            # a second to import: on a thread, beside the weights' program.
+            threading.Thread(target=importlib.import_module, daemon=True,
+                             args=("jax.experimental.pallas.tpu",)).start()
         self.params = paged_model.serving_params(self.config, params, seed)
         self.max_batch = int(max_batch_size)
         self.max_len = int(max_seq_len or self.config.max_seq_len)
@@ -356,9 +362,9 @@ class LLMEngine:
         shape = (self.max_batch, block_length) if block_length \
             else (self.max_batch,)
         with jax_compat.set_mesh(mesh):
-            self._key = jax.jit(lambda: jax.random.PRNGKey(seed + 1))()
-            self._no_prev = jax.jit(
-                lambda: jax.numpy.zeros(shape, "int32"))()
+            self._key, self._no_prev = jax.jit(lambda: (
+                jax.random.PRNGKey(seed + 1),
+                jax.numpy.zeros(shape, "int32")))()
         self._unread: "_Step | None" = None
         self._counters: "dict[str, int]" = {k: 0 for k in ENGINE_STAT_KEYS}
         self._pass = _PassClock()

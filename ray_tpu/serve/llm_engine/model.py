@@ -1,4 +1,4 @@
-"""Jitted paged-attention steps: gather-by-block-table prefill/decode.
+"""Jitted paged-attention steps: a chunk gathers, a step reads by row.
 
 The forward of ``models.llama`` for one chunk or one token per row,
 reading and writing the PAGED pool:
@@ -11,26 +11,25 @@ reading and writing the PAGED pool:
 - **scatter**: each new token's k/v lands at
   ``pool[layer, block_table[pos // bs], pos % bs]`` — one indexed write
   (``.at[layer, blocks, offsets].set``) per layer inside the scan;
-- **gather**: attention keys/values are ``pool[layer, block_table]`` →
-  ``[B, M, bs, kv, d]`` reshaped to the flat ``[B, S, kv, d]`` view
-  where flat index ``s`` IS the token's global position (tables are
-  append-ordered), so the standard causal mask ``s <= position`` is
-  the one a contiguous cache would use. They stay in the pool's dtype
-  and are never repeated per query head;
+- **gather** (a chunk, a block pass): attention keys/values are
+  ``pool[layer, block_table]`` → ``[B, M, bs, kv, d]`` reshaped to the
+  flat ``[B, S, kv, d]`` view where flat index ``s`` IS the token's
+  global position (tables are append-ordered), so the standard causal
+  mask ``s <= position`` is the one a contiguous cache would use. They
+  stay in the pool's dtype and are never repeated per query head;
 - **grouped attention**: queries are viewed ``[B, T, kv, reps, d]`` and
-  contracted against the gathered keys with float32 accumulation
-  (products of bf16 values are exact in float32, so widening the
-  operands first would change nothing); scale, mask and softmax run in
-  float32, the probabilities are cast to ``config.dtype`` and
-  contracted with the gathered values. ``reps == 1`` (no grouping) is
-  the same code, with a row of zeros beside a decode step's lone query
-  row so that the product stays a matrix product;
+  contracted against the gathered keys with float32 accumulation;
+  scale, mask and softmax run in float32, the probabilities are cast
+  to ``config.dtype`` and contracted with the gathered values. ``reps
+  == 1`` (no grouping) is the same code;
+- **by row** (a decode step, ``Family.reads_by_row``): nothing is
+  gathered; ``ops/paged_kv_attention.py`` walks each busy row's own
+  pages where they lie, in the same precisions, an inactive row none;
 - **fixed shapes**: batch ``B``, table width ``M`` and chunk length
   ``C`` are compile-time constants: one prefill program a table width
   the engine hands it (``engine.table_widths``) and one decode program
-  a width a step can be given (the same three where the step gathers
-  its width, the whole table alone where it reads by row), every step
-  hits the jit cache;
+  a width a step can be given (the whole table alone by row; the same
+  three for the block pass, which gathers), every step hits the cache;
 - **the head on one row**: a prefill chunk computes the final norm and
   the logits of the one position that is read (its last real token);
 - **donation**: the pool is donated through every call (decode updates
@@ -53,10 +52,9 @@ layers and pool, ``T = block_length`` query rows a row of the batch, a
 mask that lets a position see all of its own block, and a pass that
 fixes 0 to ``block_length`` of a row's masked positions.
 
-Runs on CPU under tier-1 (plain jnp/einsum: the one pallas kernel
-here, ``by_row``, is reached by the linear family's decode step alone);
-the block/gather structure is what the Ragged Paged Attention kernel
-(arxiv 2604.15464) implements natively on TPU.
+Runs on CPU under tier-1 (plain jnp/einsum, and the ``by_row`` kernel
+interpreted); the block/gather structure is what the Ragged Paged
+Attention kernel (arxiv 2604.15464) implements natively on TPU.
 """
 
 from __future__ import annotations
@@ -193,8 +191,9 @@ def serving_params(config, params: "dict | None" = None,
 
     if params is None:
         init_params = family(config).init_params
-        return jax.jit(lambda key: cast(init_params(config, key)))(
-            jax.random.PRNGKey(seed))
+        # The key is made IN the program: before it, two more to fetch.
+        return jax.jit(lambda seed: cast(init_params(
+            config, jax.random.PRNGKey(seed))))(np.uint32(seed % 2 ** 32))
     if all(x.dtype == config.dtype for x in jax.tree.leaves(params)):
         return params
     return jax.jit(cast)(params)
@@ -205,14 +204,15 @@ def _paged_attention_block(layer: dict, x: jax.Array,
                            pool_v: jax.Array, li: jax.Array,
                            block_tables: jax.Array, config,
                            block_size: int,
-                           n_valid: "jax.Array | None" = None):
+                           n_valid: "jax.Array | None" = None,
+                           by_row: bool = False):
     """One attention block over layer ``li`` of the paged pool: the
     input norm, ``paged_attention`` and the residual. x: [B, T, E]
     new-token activations. Returns (out, pool_k, pool_v)."""
     normed = llama.rms_norm(x, layer["attn_norm"], config.rms_norm_eps)
     out, pool_k, pool_v = paged_attention(
         layer, normed, positions, pool_k, pool_v, li, block_tables, config,
-        block_size, n_valid)
+        block_size, n_valid, by_row)
     return x + out, pool_k, pool_v
 
 
@@ -221,7 +221,8 @@ def _attend_gathered(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
                      positions: jax.Array, config, block_size: int):
     """q [B, T, H, d] at ``positions`` over the gathered view of entry
     ``li`` of the WRITTEN pools, the table's whole width for every row.
-    Returns [B, T, H, d]."""
+    Returns [B, T, H, d]. (For a chunk or a block: ONE query row a head
+    the TPU's compiler lowers over a float32 copy of the keys, PR 25.)"""
     dtype = config.dtype
     h, kv_heads, d = config.num_heads, config.num_kv_heads, config.head_dim
     (B, T), M = positions.shape, block_tables.shape[1]
@@ -235,14 +236,6 @@ def _attend_gathered(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     # Query head k * reps + r reads key-value head k: the mapping of
     # llama._attention_block's jnp.repeat(k, reps, axis=2).
     q = q.reshape(B, T, kv_heads, reps, d)
-    # One query row per key-value head (a decode step without grouping)
-    # is a matrix-vector product, which the TPU's compiler lowers as a
-    # float32 multiply-reduce over a float32 copy of the gathered keys
-    # (10 ms of a 36 ms step at OLMoE's 16 x 16 heads, PR 25). A second
-    # row of zeros keeps it a matrix product on the keys as they lie.
-    lone_row = T * reps == 1
-    if lone_row:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, 1), (0, 0)))
     scores = jnp.einsum("btkrd,bskd->bkrts", q, keys,
                         preferred_element_type=jnp.float32)
     scores *= d ** -0.5
@@ -255,8 +248,6 @@ def _attend_gathered(q: jax.Array, pool_k: jax.Array, pool_v: jax.Array,
     scores = jnp.where(mask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
     out = jnp.einsum("bkrts,bskd->btkrd", probs, values.astype(dtype))
-    if lone_row:
-        out = out[:, :, :, :reps]
     return out.reshape(B, T, h, d)
 
 
@@ -315,8 +306,7 @@ def paged_attention(layer: dict, normed: jax.Array, positions: jax.Array,
                pool_v.at[li, blocks, offsets].set(v))
     reps = h // kv_heads
     if by_row:
-        # Imported where it is used, as ``moe.touched_expert_ffn``
-        # imports its kernel: a family that gathers never loads pallas.
+        # Imported where it is used: one that gathers never loads pallas.
         from ray_tpu.ops.paged_kv_attention import paged_kv_attention
 
         assert T == 1, "a row's pages are read for ONE query position"
@@ -357,8 +347,7 @@ def row_beside_zeros(x: jax.Array, at: jax.Array) -> jax.Array:
     to return one; the caller takes ``[:, 0]`` of the logits), beside a
     row of zeros: a lone row against the head is a matrix-vector
     product, which the chip's compiler lowers as a float32
-    multiply-reduce over a float32 copy of the whole table (see
-    ``_paged_attention_block``'s lone query row)."""
+    multiply-reduce over a float32 copy of the whole table."""
     return jnp.stack([x[:, at], jnp.zeros_like(x[:, 0])], axis=1)
 
 
@@ -367,11 +356,13 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
                    config, block_size: int,
                    n_valid: "jax.Array | None" = None,
                    busy: "jax.Array | None" = None,
-                   logits_at: "jax.Array | None" = None):
+                   logits_at: "jax.Array | None" = None,
+                   by_row: bool = False):
     """Shared prefill/decode forward over the paged pool. Returns
     (logits [B, T, V] f32, or [B, V] of position ``logits_at`` alone;
     updated pool, expert counts, routing). The pool is part of the
     scan's carry, so every layer updates the one (donated) buffer.
+    ``by_row`` (static) is ``paged_attention``'s, for a decode step.
 
     The feed-forward is the configuration's: dense SwiGLU, or the
     routed experts. For those, ``counts`` is ``moe.routing_counts``
@@ -403,7 +394,7 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
         layer, li = layer_and_index
         x, pool_k, pool_v = _paged_attention_block(
             layer, x, positions, pool_k, pool_v, li, block_tables,
-            config, block_size, n_valid=n_valid)
+            config, block_size, n_valid=n_valid, by_row=by_row)
         if not sparse:
             return (llama._mlp_block(layer, x, config), pool_k, pool_v,
                     counts), None
@@ -451,7 +442,7 @@ def _decode_body(config, block_size: int):
         # expert_stats: moe.init_stats() of a sparse model, or None.
         logits, pool, counts, _ = _forward_paged(
             params, pool, tokens, positions[:, None], block_tables,
-            config, block_size)
+            config, block_size, by_row=PAGED.reads_by_row)
         return sample_next(logits[:, -1, :], key, temps), pool, \
             _accumulated(expert_stats, counts)
 
@@ -799,6 +790,7 @@ PAGED = Family(
     make_engine_prefill_chunk=make_engine_prefill_chunk,
     pack_decode_rows=pack_decode_rows,
     pack_prefill_chunk=pack_prefill_chunk,
+    reads_by_row=True,
 )
 
 
@@ -808,9 +800,10 @@ def _blockwise(block_length: int) -> Family:
     paged pool and the plain prefill program (under the block mask: a
     paged block and a chunk hold whole blocks, the engine checks; what
     it returns of logits is not read, prefill yields no token), and the
-    block pass for a decode step."""
+    block pass for a decode step, which gathers (its ``T`` is not 1)."""
     return dataclasses.replace(
         PAGED,
+        reads_by_row=False,
         make_engine_decode_step=make_engine_block_step,
         pack_decode_rows=functools.partial(pack_block_rows, block_length),
         row_of=block_row_of,

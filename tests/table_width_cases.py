@@ -1,17 +1,19 @@
 """The table a decode step and a prefill chunk are given, for one family
 a file: ``test_table_widths.py`` (identical layers over a pool of keys
-and values), ``test_table_widths_hybrid.py`` (a hybrid's three caches)
-and ``test_table_widths_latent.py`` (latent attention over a pool of one
-vector a position) each name their ``FAMILY`` and import these cases, so
-that ``--dist loadfile`` can give each family's engines a worker of
-their own. A file builds two or three tiny engines: the one ``served``
+and values), ``test_table_widths_hybrid.py`` (a hybrid's three caches),
+``test_table_widths_latent.py`` (latent attention over a pool of one
+vector a position) and ``test_table_widths_block.py`` (diffusion over
+blocks of 4 positions, on the paged family's layers and pool) each name
+their ``FAMILY`` and import these cases, so that ``--dist loadfile``
+can give each family's engines a worker of their own. A file builds two or three tiny engines: the one ``served``
 runs twice, the one whose compiles are counted serves under pressure,
 and (a model without experts) one is built and served under a mesh.
 
 The engine hands a prefill chunk the narrowest of ``engine.table_widths``
-that holds its request's table and a decode step that gathers the
-narrowest that holds its longest live row's; a decode step that reads by
-row (the latent family's) has the whole table at every step, and one
+that holds its request's table and a decode step that gathers (the
+hybrid's and the block family's) the narrowest that holds its longest
+live row's; a decode step that reads by row (``BY_ROW``: the paged and
+the latent family's) has the whole table at every step, and one
 program. Every program exists before the first request. Tiny float32
 configurations, blocks of 4: a table of 16 blocks has the widths 4, 8
 and 16 (16, 32 and 64 positions)."""
@@ -23,6 +25,9 @@ import numpy as np
 import pytest
 
 BLOCK, CHUNK, ROWS = 4, 8, 4
+#: The families whose decode step reads each row's own pages
+#: (``Family.reads_by_row``).
+BY_ROW = ("paged", "latent")
 
 
 def pytest_generate_tests(metafunc):
@@ -42,7 +47,12 @@ def tiny(family):
         return phi4flash.Phi4FlashConfig.tiny(dtype=jnp.float32)
     if family == "latent":
         return xing.XingConfig.tiny(dtype=jnp.float32)
-    return dataclasses.replace(llama.LlamaConfig.tiny(), dtype=jnp.float32)
+    # ``block``: the same layers under the mask by blocks, a pass of two
+    # a block; ``model.family`` finds ``_blockwise(4)``.
+    return dataclasses.replace(
+        llama.LlamaConfig.tiny(), dtype=jnp.float32,
+        **({"block_length": BLOCK, "mask_token_id": 255,
+            "denoising_steps": 2} if family == "block" else {}))
 
 
 def make_engine(family, max_seq_len=64, **kwargs):
@@ -57,12 +67,28 @@ def step_widths(family, widths):
     """The widths a decode step of ``family`` may be given, of a table's
     ``widths``: all of them where it gathers, the whole where it reads
     by row."""
-    return widths[-1:] if family == "latent" else widths
+    return widths[-1:] if family in BY_ROW else widths
+
+
+def row_head(engine) -> int:
+    """The columns of a decode step's host array before a row's table:
+    3, or a block pass's 6 and its block."""
+    return engine._family.pack_decode_rows(1, 0, ()).shape[1]
+
+
+def busy_rows(engine, rows):
+    """Which rows of a decode step's host array carry a request: a
+    position past 0, or a block pass's phase."""
+    if getattr(engine.config, "block_length", 0):
+        return rows[:, 5] != 0
+    return rows[:, 1] > 0
 
 
 def step_logits(engine):
-    """A decode step's logits ``[rows, vocab]`` on the engine's cache as
-    it stands, nothing donated: what the step's program samples from."""
+    """A decode step's logits ``[rows, vocab]`` (a block pass's
+    ``[rows, block_length, vocab]``, on the block its host array shows)
+    on the engine's cache as it stands, nothing donated: what the
+    step's program samples from."""
     import jax
 
     from ray_tpu.serve.llm_engine import hybrid, latent
@@ -79,11 +105,21 @@ def step_logits(engine):
             return latent.forward(
                 params, cache, rows[:, :1], rows[:, 1:2], rows[:, 3:],
                 config, block, absorbed=True)[0][:, 0]
+    elif getattr(config, "block_length", 0):
+        size = config.block_length
+
+        def logits(params, cache, rows):
+            tokens = rows[:, 6:6 + size]
+            return paged_model._forward_paged(
+                params, cache, jax.numpy.where(
+                    tokens < 0, config.mask_token_id, tokens),
+                rows[:, :1] + jax.numpy.arange(size), rows[:, 6 + size:],
+                config, block, busy=rows[:, 5] != 0)[0]
     else:
         def logits(params, cache, rows):
             return paged_model._forward_paged(
                 params, cache, rows[:, :1], rows[:, 1:2], rows[:, 3:],
-                config, block)[0][:, 0]
+                config, block, by_row=True)[0][:, 0]
     return jax.jit(logits)
 
 
@@ -94,18 +130,20 @@ def record_steps(engine, compare_logits=False):
     from the whole width's on the same cache."""
     step, whole = engine._decode_step, engine.blocks_per_seq
     logits = step_logits(engine) if compare_logits else None
+    head = row_head(engine)
     seen = types.SimpleNamespace(widths=[], rows=[], preemptions=[],
-                                 compared=0, worst=0.0, program=step)
+                                 compared=0, worst=0.0, program=step,
+                                 head=head)
 
     def recording(params, pool, rows, key, expert_stats, prev):
-        width = rows.shape[1] - 3
+        width = rows.shape[1] - head
         seen.widths.append(width)
         seen.rows.append(rows)
         seen.preemptions.append(engine._counters["preemptions"])
         if logits is not None and width < whole:
-            wide = np.zeros((rows.shape[0], 3 + whole), np.int32)
+            wide = np.zeros((rows.shape[0], head + whole), np.int32)
             wide[:, :rows.shape[1]] = rows
-            live = rows[:, 1] > 0
+            live = busy_rows(engine, rows)
             gap = np.abs(np.asarray(logits(params, pool, rows))
                          - np.asarray(logits(params, pool, wide)))[live]
             seen.compared += 1
@@ -131,10 +169,11 @@ def record_chunks(engine) -> list:
     return seen
 
 
-def held_blocks(rows) -> int:
-    """The longest table among a host array's rows (block 0 is the
-    scratch block and the padding, never a request's)."""
-    return int((rows[:, 3:] != 0).sum(axis=1).max())
+def held_blocks(rows, head: int = 3) -> int:
+    """The longest table among a host array's rows, whose tables start
+    at column ``head`` (block 0 is the scratch block and the padding,
+    never a request's)."""
+    return int((rows[:, head:] != 0).sum(axis=1).max())
 
 
 def counted(engine) -> dict:
@@ -147,6 +186,14 @@ def counted(engine) -> dict:
 # half while it generates, and finishes first) and two short ones that
 # cross a quarter after it has gone.
 REQUESTS = [(list(range(1, 28)), 9), ([7, 8, 9], 27), ([3, 1, 4], 26)]
+
+
+def prefilled(family, prompt) -> int:
+    """The positions of ``prompt`` that prefill chunks write: all of
+    it, or (diffusion over blocks) its whole blocks, the rest opening
+    the first block in flight."""
+    return len(prompt) // BLOCK * BLOCK if family == "block" \
+        else len(prompt)
 
 
 def serve(engine, requests=REQUESTS):
@@ -217,7 +264,7 @@ def test_answers_do_not_depend_on_the_rung(served):
         == set(step_widths(served.family, (4, 8, 16)))
     assert set(served.whole_seen.widths) == {16}
     assert served.seen.compared == sum(w < 16 for w in widths)
-    if served.family == "latent":
+    if served.family in BY_ROW:
         assert served.seen.compared == 0 and len(widths) >= 26
         return
     ups = [(a, b) for a, b in zip(widths, widths[1:]) if b > a]
@@ -231,11 +278,12 @@ def test_answers_do_not_depend_on_the_rung(served):
 def test_every_step_has_the_narrowest_width_that_holds_its_rows(served):
     """Of the widths a step of the family may be given: the whole, at
     every step, where it reads by row."""
+    head = served.seen.head
     for width, rows in zip(served.seen.widths, served.seen.rows):
-        assert rows.shape == (ROWS, 3 + width)
+        assert rows.shape == (ROWS, head + width)
         assert width == next(w for w in served.engine._step_widths
-                             if w >= held_blocks(rows))
-    assert min(held_blocks(rows) for rows in served.seen.rows) <= 4
+                             if w >= held_blocks(rows, head))
+    assert min(held_blocks(rows, head) for rows in served.seen.rows) <= 4
 
 
 def test_every_chunk_has_the_narrowest_width_that_holds_its_table(served):
@@ -244,22 +292,28 @@ def test_every_chunk_has_the_narrowest_width_that_holds_its_table(served):
     two chunks (16 positions) at a quarter of the table, its last two at
     a half; the engine held to the whole width answered the same
     (``test_answers_do_not_depend_on_the_rung``)."""
+    totals = [prefilled(served.family, p) for p, _ in REQUESTS]
     assert sum(n for _, _, n in served.chunks) \
-        == served.stats["prefill_tokens"] == sum(len(p) for p, _ in REQUESTS)
+        == served.stats["prefill_tokens"] == sum(totals)
     for width, start, n in served.chunks:
         assert width == next(w for w in served.engine._widths
                              if w * BLOCK >= start + n)
-    assert [w for w, _, _ in served.chunks] == [4, 4, 8, 8, 4, 4]
+    # (Of a prompt of 27 diffusion over blocks prefills 24, and of one
+    # of 3 nothing: three chunks.)
+    assert [w for w, _, _ in served.chunks] == [
+        next(w for w in (4, 8, 16) if w * BLOCK >= min(start + CHUNK, total))
+        for total in totals for start in range(0, total, CHUNK)]
+    assert len(served.chunks) == (3 if served.family == "block" else 6)
     assert {w for w, _, _ in served.whole_chunks} == {16}
 
 
 def positions_read(family, seen) -> int:
     """What ``seen``'s steps read of the pool, from their host arrays:
-    rows x the step's width in positions where the step gathers; of a
-    latent engine, whose kernel walks each busy row's own pages, the
-    whole pages that hold the row's positions before its own, and its
-    own (which the step brings with it)."""
-    if family != "latent":
+    rows x the step's width in positions where the step gathers; of
+    an engine whose kernel walks each busy row's own pages, the whole
+    pages that hold the row's positions before its own, and its own
+    (which the step brings with it)."""
+    if family not in BY_ROW:
         return sum(ROWS * w * BLOCK for w in seen.widths)
     at = np.concatenate([rows[rows[:, 1] > 0, 1] for rows in seen.rows])
     return int((-(-at // BLOCK) * BLOCK + 1).sum())
@@ -269,9 +323,9 @@ def test_counters_say_what_the_steps_read(served):
     """``kv_positions_read`` is what the steps read of the pool, summed
     (``positions_read``); ``decode_steps_narrow`` counts the steps under
     the whole width, none where the step reads by row; the live
-    positions are the same whichever width read them, and so is what a
-    latent engine reads: under one page a row and step over what is
-    live."""
+    positions are the same whichever width read them, and so is what
+    an engine that reads by row reads: under one page a row and step
+    over what is live."""
     stats, widths = served.stats, served.seen.widths
     assert stats["decode_steps"] == len(widths)
     assert stats["kv_positions_read"] == positions_read(
@@ -281,10 +335,12 @@ def test_counters_say_what_the_steps_read(served):
     assert whole["decode_steps_narrow"] == 0
     assert whole["kv_positions_read"] == positions_read(
         served.family, served.whole_seen)
-    live = sum(new - 1 for _, new in REQUESTS)  # the first is a chunk's
+    # The first is a chunk's, but for diffusion over blocks, whose
+    # prefill yields no token.
+    live = sum(new - (served.family != "block") for _, new in REQUESTS)
     assert stats["decode_tokens"] == whole["decode_tokens"] == live
     assert stats["kv_positions_live"] == whole["kv_positions_live"]
-    if served.family == "latent":
+    if served.family in BY_ROW:
         assert stats["decode_steps_narrow"] == 0
         assert stats["kv_positions_read"] == whole["kv_positions_read"]
         over = stats["kv_positions_read"] - stats["kv_positions_live"]
@@ -386,12 +442,13 @@ def test_preempting_the_longest_row_lets_the_width_fall(family, pressed):
     assert pressed.got == pressed.want
     stats, seen = pressed.stats, pressed.seen
     assert stats["preemptions"] >= 1 and stats["resumes"] >= 1
+    held = [held_blocks(rows, seen.head) for rows in seen.rows]
     fell = [i for i in range(1, len(seen.widths))
             if seen.preemptions[i] > seen.preemptions[i - 1]
-            and held_blocks(seen.rows[i]) < held_blocks(seen.rows[i - 1])]
+            and held[i] < held[i - 1]]
     assert fell, list(zip(seen.widths, seen.preemptions))
     i = fell[0]
-    assert held_blocks(seen.rows[i - 1]) > 8 >= held_blocks(seen.rows[i])
+    assert held[i - 1] > 8 >= held[i]
     assert (seen.widths[i - 1], seen.widths[i]) == tuple(
         next(w for w in steps if w >= blocks) for blocks in (16, 8))
     assert seen.program._cache_size() == len(steps)

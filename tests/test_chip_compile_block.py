@@ -1,15 +1,88 @@
 """The block family's decode program (diffusion over blocks: SDAR's
 widths) as the engine runs it, compiled for a described ``v5e:2x2``
-(``v5e_compile.py``); its prefill chunk is the paged family's
-(``test_chip_compile_paged.py``)."""
+(``v5e_compile.py``), at the whole table and at each rung of the
+gathered decode ladder, which it still runs; its prefill chunk is the
+paged family's (``test_chip_compile_paged.py``)."""
 
 import math
 
 import jax
 import jax.numpy as jnp
+import pytest
 from v5e_compile import (  # noqa: F401 — the fixtures
     _memory_of, _sdar, assert_experts_reach_the_kernel_whole,
     compiled_kernels, v5e_chip, v5e_devices)
+
+
+def _block_step(v5e_chip, width=128):
+    """The block family's decode program at SDAR's widths (2 of the
+    cell's 7 layers, 32 rows over a pool of 128 blocks of 16 a row) and
+    its arguments without ``prev``, as shapes on the described chip, the
+    rows' tables ``width`` blocks wide; the cache's shapes and ``prev``'s
+    beside them."""
+    from ray_tpu.models import moe
+    from ray_tpu.serve.llm_engine import model as paged_model
+
+    config, rows, block, table = _sdar(), 32, 16, 128
+    family = paged_model.family(config)
+    assert family is paged_model._blockwise(4) and not family.reads_by_row
+
+    def on_chip(tree, dtype=None):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
+
+    params = on_chip(jax.eval_shape(lambda: family.init_params(
+        config, jax.random.PRNGKey(0))), config.dtype)
+    cache = on_chip(jax.eval_shape(lambda: family.init_cache(
+        config, 1 + rows * table, block, rows, 128)))
+    args = (params, cache,
+            on_chip(family.pack_decode_rows(rows, width, ()), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip),
+            on_chip(jax.eval_shape(moe.init_stats)))
+    prev = jax.ShapeDtypeStruct((rows, config.block_length), jnp.int32,
+                                sharding=v5e_chip)
+    return family.make_engine_decode_step(config, block), args, cache, prev
+
+
+def _cache_bytes(cache) -> int:
+    return sum(math.prod(c.shape) * c.dtype.itemsize
+               for c in jax.tree.leaves(cache))
+
+
+@pytest.mark.parametrize("width", [32, 64, 128])
+def test_block_step_at_each_table_width_on_v5e(v5e_chip, compiled_kernels,
+                                               width):
+    """The gathered decode ladder, which the block family still runs
+    (``_blockwise`` says ``reads_by_row=False``: a pass has 4 query rows
+    a row under the block's horizon; the paged family it is made of
+    reads by row since PR 58 and has ONE decode program): the block
+    pass at the three widths the engine builds it at
+    (``engine.table_widths`` of 128 blocks), 32 rows over the whole
+    pool. The gather is of this width and no wider, in the pool's dtype
+    and never widened to float32; no call of
+    ``ops/paged_kv_attention.py``; the pool is updated where it lies
+    and never copied."""
+    import re
+
+    from ray_tpu.serve.llm_engine.engine import table_widths
+
+    assert width in table_widths(128)
+    step, args, cache, prev = _block_step(v5e_chip, width)
+    compiled = step.lower(*args, prev).compile()
+    assert _memory_of(compiled)[0] >= _cache_bytes(cache)
+    text = compiled.as_text()
+    pool_text = "= bf16[" + ",".join(map(str, cache["k"].shape)) + "]"
+    assert [line for line in text.splitlines()
+            if " copy(" in line and pool_text in line] == []
+    gathered = 32 * width                       # pages a layer gathers
+    assert re.search(rf"bf16\[({gathered},16|16,{gathered}),4,128\]",
+                     text) is not None
+    assert re.search(rf"= f32\[({gathered},16|16,{gathered}),4,128\]",
+                     text) is None
+    if width < 128:
+        assert f"[{32 * 128},16,4,128]" not in text
+    assert "paged_kv_attention" not in text
+    assert_experts_reach_the_kernel_whole(text, (2, 128, 2048, 768), 1)
 
 
 def test_block_step_with_prev_on_v5e(v5e_chip, compiled_kernels):
@@ -25,34 +98,12 @@ def test_block_step_with_prev_on_v5e(v5e_chip, compiled_kernels):
     read. A pass's expert layer is ONE call of
     ``ops/grouped_expert_ffn.py`` on the three stacked tensors (PR 52);
     nothing else takes an expert tensor, a layer of it or a copy."""
-    from ray_tpu.models import moe
-    from ray_tpu.serve.llm_engine import model as paged_model
-
-    config, rows, block, table = _sdar(), 32, 16, 128
-    family = paged_model.family(config)
-
-    def on_chip(tree, dtype=None):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
-
-    params = on_chip(jax.eval_shape(lambda: family.init_params(
-        config, jax.random.PRNGKey(0))), config.dtype)
-    cache = on_chip(jax.eval_shape(lambda: family.init_cache(
-        config, 1 + rows * table, block, rows, 128)))
-    args = (params, cache,
-            on_chip(family.pack_decode_rows(rows, table, ()), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip),
-            on_chip(jax.eval_shape(moe.init_stats)))
-    step = family.make_engine_decode_step(config, block)
-    prev = jax.ShapeDtypeStruct((rows, config.block_length), jnp.int32,
-                                sharding=v5e_chip)
+    step, args, cache, prev = _block_step(v5e_chip)
     compiled, before = step.lower(*args, prev).compile(), \
         step.lower(*args).compile()
     alias, temp, arguments = _memory_of(compiled)
     alias_before, temp_before, arguments_before = _memory_of(before)
-    cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
-                      for c in jax.tree.leaves(cache))
-    assert alias == alias_before >= cache_bytes
+    assert alias == alias_before >= _cache_bytes(cache)
     assert abs(temp - temp_before) < 512 * 2 ** 10 < temp / 100
     assert 0 < arguments - arguments_before <= 4096
 

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 from v5e_compile import (  # noqa: F401 — the fixtures
     HANDS_ON, assert_experts_reach_the_kernel_whole, compiled_kernels,
-    v5e_chip, v5e_devices)
+    kv_attention_calls, v5e_chip, v5e_devices)
 
 
 def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip,
@@ -198,21 +198,14 @@ def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
     assert "kda_state_update" not in prefill.as_text()
     # The one full layer: the pools whole, the queries grouped, the
     # rows' fresh keys and values; the tables, the lengths, the entry.
-    def kv_calls(text):
-        # By the call's line: a program's table of source files may name
-        # ``tests/test_paged_kv_attention.py`` where that file ran first
-        # on this worker and a cached trace carries its frames.
-        return [line for line in text.splitlines()
-                if "custom-call(" in line and "paged_kv_attention" in line]
-
-    calls = kv_calls(step.as_text())
+    calls = kv_attention_calls(step.as_text())
     assert len(calls) == 1
     operands = calls[0].split("operand_layout_constraints={")[1]
     assert operands.count("bf16[1,16385,16,8,128]{") == 2
     assert operands.count("bf16[64,8,128]{") == 2
     assert operands.count("bf16[64,64,128]{") == 1
     assert "s32[16384]{" in operands and "s32[64]{" in operands
-    assert kv_calls(prefill.as_text()) == []
+    assert kv_attention_calls(prefill.as_text()) == []
     # No gathered view of the pools in the step, and no copy of one:
     # what else names a pool hands it on or writes the step's position.
     assert re.search(r"\[64,4096,8,128\]|\[16384,16,8,128\]",

@@ -2,7 +2,8 @@
 and values: the Mistral and OLMoE serve cells' two programs, and the
 prefill chunk they share with SDAR), compiled for a described ``v5e:2x2``
 (``v5e_compile.py``): what the chip's compiler makes of the pool, the
-gathered keys and the experts at the cells' real widths."""
+decode step's read by row, a chunk's gathered keys and the experts at
+the cells' real widths."""
 
 import dataclasses
 import math
@@ -12,7 +13,7 @@ import jax.numpy as jnp
 import pytest
 from v5e_compile import (  # noqa: F401 — the fixtures
     _memory_of, _sdar, assert_experts_reach_the_kernel_whole,
-    compiled_kernels, v5e_chip, v5e_devices)
+    compiled_kernels, kv_attention_calls, v5e_chip, v5e_devices)
 
 # An expert model's programs hold ``ops/grouped_expert_ffn.py``.
 pytestmark = pytest.mark.usefixtures("compiled_kernels")
@@ -102,9 +103,9 @@ def test_paged_steps_update_the_pool_in_place_on_v5e(v5e_chip, program):
     """The serve cells' widths (Mistral-7B-v0.3: 32 query on 8 key-value
     heads of 128; 2 of its layers, 16 rows, 128 blocks of 16). The chip's
     compiler must keep the donated pool where it is and repeat or widen
-    nothing the size of the gathered keys: at these sizes a repeated
-    float32 copy of them is 0.5 GiB and a copy of the 2-layer pool 0.13
-    GiB, and neither shows in a CPU test."""
+    nothing the size of a chunk's gathered keys: at these sizes a
+    repeated float32 copy of them is 0.5 GiB and a copy of the 2-layer
+    pool 0.13 GiB, and neither shows in a CPU test."""
     lowered, pool_shape = _lower_paged_step(program, _mistral_serve(), 16,
                                             16, 128, v5e_chip)
     compiled = lowered.compile()
@@ -132,24 +133,23 @@ def _olmoe(num_layers=12):
 F32_EXPERTS = r"f32\[(\d+,)?64,(2048,1024|1024,2048)\]"
 
 
-@pytest.mark.parametrize("program, temporaries_mib", [
-    ("decode_step", 160), ("prefill_chunk", 16),
-    ("engine_decode_step", 160), ("engine_prefill_chunk", 16)])
-def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
-        v5e_chip, program, temporaries_mib):
+@pytest.mark.parametrize("program", [
+    "decode_step", "prefill_chunk", "engine_decode_step",
+    "engine_prefill_chunk"])
+def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(v5e_chip,
+                                                           program):
     """The OLMoE serve cell's two programs at its real size (16 rows x
     2048 positions, 12 layers: 12.76 GiB of arguments). What must not
     appear: a float32 copy of an expert tensor (1.5 GiB a layer) or a
     transposed bf16 one (768 MiB a layer: a flat ``bth,ehm->btem``
     product made the compiler transpose each [64, 2048, 1024] whole);
     a copy of the donated pool; a float32 copy of a layer's gathered
-    keys (256 MiB: a decode step's lone query row per head made the
-    scores a matrix-vector product, which the compiler widened the keys
-    for, until ``_paged_attention_block`` put a row of zeros beside
-    it). The decode program's 129 MiB of temporaries are one gathered
-    ``bf16[2048,16,16,128]`` (with 16 key-value heads it no longer fits
-    the memory space Mistral's 64 MiB ones live in); the chunk program
-    has none to speak of. An expert layer is ONE call of
+    keys (256 MiB: a gathered decode step's lone query row per head
+    made the scores a matrix-vector product, which the compiler widened
+    the keys for; the step reads by row since PR 58 and gathers
+    nothing: its 129 MiB of temporaries, one gathered
+    ``bf16[2048,16,16,128]``, went with that); neither program has
+    temporaries to speak of. An expert layer is ONE call of
     ``ops/grouped_expert_ffn.py`` on the three stacked tensors and the
     layer's index (PR 52), and nothing else in either program takes an
     expert tensor, a layer of it or a copy of it."""
@@ -159,7 +159,7 @@ def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
                                             v5e_chip)
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
-    assert memory.temp_size_in_bytes < temporaries_mib * 2 ** 20
+    assert memory.temp_size_in_bytes < 16 * 2 ** 20
     assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 0.9 * 15.75 * 2 ** 30)
     assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
@@ -174,48 +174,57 @@ def test_sparse_paged_steps_fit_and_widen_no_expert_on_v5e(
     assert_experts_reach_the_kernel_whole(text, (12, 64, 2048, 1024), 1)
 
 
-@pytest.mark.parametrize("width", [32, 64, 128])
+@pytest.mark.parametrize("program", ["decode_step", "engine_decode_step"])
 @pytest.mark.parametrize("model", ["mistral", "olmoe"])
-def test_decode_step_at_each_table_width_on_v5e(v5e_chip, model, width):
-    """The engine's decode program at the three widths it is built at
-    (``engine.table_widths`` of 128 blocks: 512, 1024 and 2048 positions
-    a row), 16 rows over the whole pool, for both 16-row serve
-    configurations: what the cases above hold the whole width to holds
-    at a quarter and a half of it. The pool is updated where it lies and
-    never copied; the gathered keys are never widened to float32 (D9's
-    row of zeros keeps the scores a bf16 matrix product with 16
-    key-value heads and no grouping); under the whole width there are
-    no temporaries to speak of."""
+def test_the_one_decode_program_reads_by_row_on_v5e(v5e_chip, model,
+                                                    program):
+    """The paged family's ONE decode program (``PAGED.reads_by_row``,
+    PR 58: the engine builds it at the whole table alone, 128 blocks of
+    16, 16 rows), plain and as the engine calls it, for both 16-row
+    serve configurations. The layers' scan holds ONE call of
+    ``ops/paged_kv_attention.py``, handed both pools WHOLE, the grouped
+    queries, the rows' fresh keys and values, the tables and the
+    lengths; no gathered view of the pools at any rung the gathered step
+    had (``[512 | 1024 | 2048, 16, kv, 128]``) in any dtype; the pool is
+    updated where it lies and never copied; the temporaries stay under a
+    sixteenth of a GiB (Solar's bound, ``test_chip_compile_linear.py``;
+    OLMoE's gathered step held 129 MiB). On the record (``ROADMAP.md``
+    D9): the copy of a layer's ``wq`` sliced off the stacked weights
+    (``constant_dynamic-slice_fusion``, 0.75 ms of a Mistral step; ledger,
+    PR 57) did NOT go with the gathered form: it is the projection's
+    operand, not the read's."""
     import re
 
-    from ray_tpu.serve.llm_engine.engine import table_widths
-
-    assert width in table_widths(128)
     config = _mistral_serve() if model == "mistral" else _olmoe(2)
-    lowered, pool_shape = _lower_paged_step(
-        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width)
+    lowered, pool_shape = _lower_paged_step(program, config, 16, 16, 128,
+                                            v5e_chip)
     compiled = lowered.compile()
     memory = compiled.memory_analysis()
-    # Under the whole width the gathered keys leave HBM's temporaries.
-    assert memory.temp_size_in_bytes < (160 if width == 128 else 16) * 2 ** 20
+    assert memory.temp_size_in_bytes < 2 ** 30 // 16
     assert memory.alias_size_in_bytes >= 2 * 2 * math.prod(pool_shape)
     text = compiled.as_text()
-    pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
+    pool_text = "bf16[" + ",".join(map(str, pool_shape)) + "]"
     assert [line for line in text.splitlines()
-            if " copy(" in line and pool_text in line] == []
-    positions, kv = width * 16, config.num_kv_heads
-    assert re.search(rf"= f32\[({positions},16|16,{positions}),{kv},128\]",
-                     text) is None
+            if " copy(" in line and f"= {pool_text}" in line] == []
+    heads, kv = config.num_heads, config.num_kv_heads
+    calls = kv_attention_calls(text)
+    assert len(calls) == 1
+    operands = calls[0].split("operand_layout_constraints={")[1]
+    assert operands.count(pool_text + "{") == 2
+    assert operands.count(f"bf16[16,{kv},128]{{") == 2 + (heads == kv)
+    assert operands.count(f"bf16[16,{heads},128]{{") == 1 + 2 * (heads == kv)
+    assert "s32[2048]{" in operands and "s32[16]{" in operands
+    assert re.search(rf"\[(512|1024|2048),16,{kv},128\]", text) is None
     assert re.search(F32_EXPERTS, text) is None
     if model == "olmoe":
         assert_experts_reach_the_kernel_whole(text, (2, 64, 2048, 1024), 1)
     else:           # no expert layer: no kernel of theirs
         assert "grouped_expert_ffn" not in text
-    # The gather is of this width, in the pool's dtype.
-    assert re.search(rf"bf16\[({positions},16|16,{positions}),{kv},128\]",
-                     text) is not None
-    if width < 128:
-        assert f"[2048,16,{kv},128]" not in text
+    wq_copy = re.search(
+        rf"constant_dynamic-slice_fusion[.\d]* = bf16\[1,{config.hidden_size}"
+        rf",{heads},128\]", text)
+    assert wq_copy is not None, \
+        "the wq copy went: strike it from ROADMAP.md D9 and PERF.md 7 (P1)"
 
 
 @pytest.mark.parametrize("width", [32, 64, 128])
@@ -268,23 +277,21 @@ def test_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, model, width):
         assert "grouped_expert_ffn" not in text
 
 
-@pytest.mark.parametrize("width", [32, 64, 128])
 @pytest.mark.parametrize("model", ["mistral", "olmoe"])
-def test_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip, model,
-                                                          width):
+def test_decode_step_with_prev_on_v5e(v5e_chip, model):
     """The program the engine runs since it keeps a step ahead: the
     step before's tokens ``[16]`` int32 as a sixth argument, one select
     in front of the embedding. Beside the five-argument program (which
-    ``benchmark/sizing.py`` still lowers) at the same width: the pool
-    aliased as much, the temporaries the same to within a few vectors of
-    16, the arguments 64 bytes more (the tokens, padded), and ``prev``
-    an argument that is read."""
+    ``benchmark/sizing.py`` still lowers) at the one width a step that
+    reads by row has, the whole table: the pool aliased as much, the
+    temporaries the same to within a few vectors of 16, the arguments 64
+    bytes more (the tokens, padded), ``prev`` an argument that is read,
+    and the read the same ONE call of ``ops/paged_kv_attention.py``."""
     config = _mistral_serve() if model == "mistral" else _olmoe(2)
     without, pool_shape = _lower_paged_step(
-        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width)
+        "engine_decode_step", config, 16, 16, 128, v5e_chip)
     with_prev, _ = _lower_paged_step(
-        "engine_decode_step", config, 16, 16, 128, v5e_chip, width=width,
-        prev=True)
+        "engine_decode_step", config, 16, 16, 128, v5e_chip, prev=True)
     assert len(with_prev.in_avals[0]) == len(without.in_avals[0]) + 1 == 6
     compiled, before = with_prev.compile(), without.compile()
     alias, temp, arguments = _memory_of(compiled)
@@ -296,6 +303,8 @@ def test_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip, model,
     pool_text = "= bf16[" + ",".join(map(str, pool_shape)) + "]"
     assert [line for line in text.splitlines()
             if " copy(" in line and pool_text in line] == []
+    assert len(kv_attention_calls(text)) \
+        == len(kv_attention_calls(before.as_text())) == 1
     # Kept by the program (jit drops an argument nothing reads).
     def entry_arguments(hlo):
         layout = hlo[hlo.index("entry_computation_layout={("):]

@@ -225,3 +225,62 @@ def test_paged_attention_by_row_is_its_gathered_form(gated):
     assert not np.asarray(got[0]).any()
     for a, b in zip(written, gathered):
         np.testing.assert_array_equal(a, b)
+
+
+#: name -> (query heads, key-value heads, experts, of them a token's)
+ENGINE_SHAPES = {"mistral": (8, 2, 0, 0), "olmoe": (16, 16, 8, 2)}
+
+
+@pytest.mark.parametrize("shape", list(ENGINE_SHAPES))
+def test_the_paged_decode_step_by_row_is_its_gathered_form(shape):
+    """The paged family's whole decode forward (``_forward_paged``, what
+    ``make_decode_step`` and the engine's program sample from) by row
+    against the same forward gathered, float32, on a tiny Mistral-shaped
+    model (4 queries a key-value head) and a tiny OLMoE-shaped one (1 of
+    16, QK-norm, routed experts), over one pool of random keys and
+    values behind shuffled tables. The rows: an inactive one (position
+    0: what it returns is never read), the shortest a step can carry
+    (position 1: one position in the pool and its own), one whose own
+    position is a page's last, one whose context ends with a page, one a
+    position past a chunk of 16 pages, and one at its table's end. The
+    logits agree to the order of summation, the pools come back written
+    alike, and a sparse model's experts are the same choices."""
+    from ray_tpu.models import llama
+    from ray_tpu.ops.paged_kv_attention import PAGES_PER_CHUNK as CHUNK_PAGES
+
+    heads, kv, experts, chosen = ENGINE_SHAPES[shape]
+    block, table = 4, CHUNK_PAGES + 4
+    config = llama.LlamaConfig(
+        vocab_size=128, hidden_size=32, intermediate_size=48, num_layers=2,
+        num_heads=heads, num_kv_heads=kv, head_dim=8,
+        max_seq_len=table * block, remat=False, dtype=jnp.float32,
+        num_experts=experts, experts_per_token=chosen,
+        qk_norm=bool(experts))
+    at = np.asarray([0, 1, block - 1, 2 * block, CHUNK_PAGES * block + 1,
+                     table * block - 1], np.int32)
+    rows, rng = len(at), np.random.default_rng(58)
+    params = llama.init_params(config, jax.random.PRNGKey(58))
+    pool_shape = (config.num_layers, 1 + rows * table + 3, block, kv, 8)
+    pool = {name: jnp.asarray(rng.normal(size=pool_shape), jnp.float32)
+            for name in ("k", "v")}
+    tables = rng.permutation(np.arange(1, pool_shape[1]))[
+        :rows * table].reshape(rows, table).astype(np.int32)
+    tables[0] = 0                          # an inactive row's zeros
+    tokens = rng.integers(1, config.vocab_size, (rows, 1)).astype(np.int32)
+    (got, written, _, routed), (want, gathered, _, routing) = [
+        jax.jit(functools.partial(
+            model._forward_paged, config=config, block_size=block,
+            by_row=by_row))(params, pool, tokens, at[:, None], tables)
+        for by_row in (True, False)]
+    assert 0.1 < float(np.asarray(want[1:]).std())
+    np.testing.assert_allclose(got[1:], want[1:], atol=2e-5, rtol=0)
+    assert np.isfinite(np.asarray(got[0])).all()
+    for name in ("k", "v"):
+        # The first layer's entries bit for bit; the second's are of
+        # activations that differ by the order of a sum. Block 0 is the
+        # scratch block: the inactive row's, whose output differs.
+        np.testing.assert_array_equal(written[name][0], gathered[name][0])
+        np.testing.assert_allclose(written[name][:, 1:],
+                                   gathered[name][:, 1:], atol=2e-5, rtol=0)
+    if experts:
+        np.testing.assert_array_equal(routed[:, 1:], routing[:, 1:])

@@ -282,12 +282,9 @@ def test_the_family_follows_from_the_configuration():
         "conv": ((3, 3, 64, 24576), "bfloat16")}
 
 
-@pytest.mark.parametrize("by_row", [True, False], ids=["by-row", "gathers"])
-def test_the_decode_step_reads_as_its_family_says(by_row, monkeypatch):
-    """``Family.reads_by_row`` is the ONE word the engine's decode
-    widths, its counters and the step's read hang on: a family that
-    says no gathers in its step too, and neither calls the kernel in a
-    prefill chunk."""
+def _linear_programs(by_row, monkeypatch):
+    """The linear family's decode step and prefill chunk as jaxprs, its
+    ``reads_by_row`` set to ``by_row``."""
     cfg = tiny()
     monkeypatch.setitem(linear.FAMILIES, solar.GQA, dataclasses.replace(
         linear.FAMILIES[solar.GQA], reads_by_row=by_row))
@@ -303,6 +300,48 @@ def test_the_decode_step_reads_as_its_family_says(by_row, monkeypatch):
         jnp.arange(CHUNK, dtype=jnp.int32)[None], tables[:1], cfg, BLOCK,
         slot=0, n_valid=jnp.int32(CHUNK), logits_at=jnp.int32(0)))(
             params, cache)
+    return by_row, step, chunk
+
+
+def _engine_programs(block_length):
+    """The engine's two programs of identical layers over one pool, as
+    jaxprs on their packers' arrays: the paged family's, or with a
+    ``block_length`` the family of diffusion over blocks."""
+    cfg = dataclasses.replace(
+        llama.LlamaConfig.tiny(), dtype=jnp.float32,
+        block_length=block_length, mask_token_id=255)
+    family = paged_model.family(cfg)
+    assert family is (paged_model._blockwise(block_length) if block_length
+                      else paged_model.PAGED)
+    params = jax.eval_shape(
+        lambda: family.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: family.init_cache(
+        cfg, 1 + ROWS * TABLE, BLOCK, ROWS, CHUNK))
+    key = jax.random.PRNGKey(0)
+    step = jax.make_jaxpr(family.make_engine_decode_step(cfg, BLOCK))(
+        params, cache, family.pack_decode_rows(ROWS, TABLE, ()), key)
+    chunk = jax.make_jaxpr(
+        family.make_engine_prefill_chunk(cfg, BLOCK, CHUNK))(
+            params, cache, family.pack_prefill_chunk(CHUNK, TABLE, (), 0,
+                                                     (), 0))
+    return family.reads_by_row, step, chunk
+
+
+@pytest.mark.parametrize("case", [
+    "linear-by-row", "linear-gathers", "paged", "block"])
+def test_the_decode_step_reads_as_its_family_says(case, monkeypatch):
+    """``Family.reads_by_row`` is the ONE word the engine's decode
+    widths, its counters and the step's read hang on: a family that
+    says no gathers in its step too (the linear family told so; the
+    family of diffusion over blocks, whose pass has ``block_length``
+    query rows a row, though it is made of the paged family, which
+    reads by row), and none calls the kernel in a prefill chunk."""
+    if case.startswith("linear"):
+        by_row, step, chunk = _linear_programs(case == "linear-by-row",
+                                               monkeypatch)
+    else:
+        by_row, step, chunk = _engine_programs(4 if case == "block" else 0)
+        assert by_row == (case == "paged")
     assert ("paged_kv_attention" in str(step)) == by_row
     assert "paged_kv_attention" not in str(chunk)
 

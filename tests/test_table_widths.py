@@ -1,7 +1,12 @@
 """``table_width_cases.py`` on identical layers over a pool of keys and
-values (the hybrid and the latent family have a file each beside this
-one, so that ``--dist loadfile`` spreads their engines over workers),
-and what holds for a table whatever the family: the widths themselves."""
+values, whose decode step reads each row's own pages
+(``ops/paged_kv_attention.py``): ONE decode program, at the whole table,
+under a one-device mesh too, and a prefill chunk the three widths (the
+hybrid and the latent family have a file each beside this one, so that
+``--dist loadfile`` spreads their engines over workers; the gathered
+decode ladder is the hybrid's there and the block family's in
+``test_sdar.py``), and what holds for a table whatever the family: the
+widths themselves."""
 
 import pytest
 

@@ -88,6 +88,15 @@ def assert_experts_reach_the_kernel_whole(text, stack, calls):
             and not HANDS_ON.search(line.strip())] == []
 
 
+def kv_attention_calls(text) -> list:
+    """The calls of ``ops/paged_kv_attention.py`` in a compiled
+    program's text, by the call's line: a program's table of source
+    files may name ``tests/test_paged_kv_attention.py`` where that file
+    ran first on this worker and a cached trace carries its frames."""
+    return [line for line in text.splitlines()
+            if "custom-call(" in line and "paged_kv_attention" in line]
+
+
 def _sdar(num_layers=2):
     """``benchmark/configs/sdar-30b-a3b-serve-1chip.json`` as the
     harness builds it: SDAR-30B-A3B's widths, 2 of the cell's 7 layers."""
