@@ -5,6 +5,7 @@ what was seen to the declared metrics, print one line."""
 from __future__ import annotations
 
 import argparse
+import importlib
 import json
 import os
 import shutil
@@ -163,10 +164,41 @@ def main(argv, started: float) -> int:
     # the program ends here with no result and another code than 0.
     import ray_tpu  # noqa: F401
 
+    lacking = lacks_the_model(cell.config)
+    if lacking:
+        raise SystemExit(lacking)
     try:
         return measured(cell, args, device, found, started)
     except (Exception, SystemExit) as exc:  # noqa: BLE001 — reported
         return raised(exc, device)
+
+
+def lacks_the_model(config: dict) -> "str | None":
+    """What to end with, where this tree's program has no module for the
+    configuration's model (PR 59): the module of ``builder.path`` is
+    imported before anything is built, outside ``main``'s ``try``, and
+    a ``ModuleNotFoundError`` that names that module itself (or a
+    package above it) ends the run as a missing ``ray_tpu`` does, with no
+    result and another code than 0. The driver tries a new cell on the
+    parent commit with the new benchmark files laid over it and takes
+    that for "the parent cannot run it"; PR 55 needed a file of its own
+    for it (``solar_builder.py``, which went). A module that is there
+    and raises, or that lacks the attribute, is a run that broke: the
+    build inside the ``try`` meets it again and it gets its result's
+    line."""
+    path = config.get("builder", {}).get("path")
+    if path is None:
+        return None  # a kind of cell that builds no model configuration
+    module = path.rpartition(".")[0]
+    try:
+        importlib.import_module(module)
+    except ModuleNotFoundError as exc:
+        if exc.name and (module + ".").startswith(exc.name + "."):
+            return (f"no module {exc.name}: this program cannot build "
+                    f"{config.get('name')} ({path})")
+    except Exception:  # noqa: BLE001 — raised again where it is reported
+        pass
+    return None
 
 
 def raised(exc: BaseException, device: dict) -> int:
@@ -223,8 +255,6 @@ def measured(cell, args, device: dict, found: float, started: float) -> int:
     say("setup", workload=cell.name, seed=args.seed, seconds=args.seconds,
         trace=args.trace, device=device, compile_cache=cache,
         config=cell.config["name"], kind=cell.config["kind"])
-
-    import importlib
 
     cell_runner = importlib.import_module(
         f"benchmark.{cell.config['kind']}_cell")

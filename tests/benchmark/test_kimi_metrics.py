@@ -21,18 +21,24 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from cells import named, renamed  # noqa: E402
+
 from benchmark import flops, kimi_cost, peaks, spec, trace_reduce  # noqa: E402
 
 CELL = "serve-kimi-linear-reason-closed"
 CONFIG = "kimi-linear-48b-a3b-serve-1chip"
 # Thirteen, not the issue's twenty: ``per_layer`` holds at most 128 and
 # had 115 (CHANGES.md says which seven went and why).
-NEW_METRICS = [name + ".kimi" for name in (
+NEW_METRICS = [named(name, "kimi") for name in (
     "decode_step_device_ms", "prefill_chunk_device_ms", "device_idle_share",
     "hbm_peak_share", "decode_batch_occupancy", "expert_ffn_time_share",
     "latent_attn_time_share", "kda_time_share", "kda_chunk_time_share",
     "expert_choices_here_share", "kda_state_roofline", "expert_ffn_roofline",
-    "decode_step_roofline")]
+    "decode_step_roofline",
+    # Since PR 59, as further cells on a survivor's list:
+    "engine_host_ms_per_step", "host_calls_per_step", "kv_read_over_live",
+    "decode_steps_ahead_share")]
 # The catalog row Kimi-Linear-48B-A3B-Instruct of the model-configs
 # guide, every key of its `config`.
 CATALOG = {
@@ -249,7 +255,7 @@ def canned_run() -> dict:
             "harness": {}, "traffic": {}}
 
 
-CANNED = {
+CANNED = renamed({
     "decode_step_device_ms.kimi": 18.0,
     "prefill_chunk_device_ms.kimi": 15.0,
     "device_idle_share.kimi": None,     # busy_and_window wants real lines
@@ -262,14 +268,19 @@ CANNED = {
     "kda_time_share.kimi": 100 * 3 * 10 * 0.6e6 / (3 * 18e6),
     "latent_attn_time_share.kimi": 100 * 3 * 3 * 0.25e6 / (3 * 18e6),
     "kda_chunk_time_share.kimi": 100 * 10 * 0.5e6 / 15e6,
-}
+    "engine_host_ms_per_step.kimi": 3.5,
+    "host_calls_per_step.kimi": 2.25,
+    "kv_read_over_live.kimi": 1308 / 1300,
+    "decode_steps_ahead_share.kimi": 100.0,
+})
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_new_metric_file_loads_and_reads_a_canned_run(name, monkeypatch):
     loaded = spec.load_cell(CELL)
     metric = {m["name"]: m for m in loaded.per_layer}[name]
-    assert metric["cells"] == metric["workloads"] == [CELL]
+    assert CELL in metric["cells"]
+    assert metric["cells"] == metric["workloads"]
     assert metric["moves"] == "serve_tokens_per_s"
     assert metric["layer"] in {m["layer"] for m in bench_json()["per_layer"]
                                if CELL not in m.get("workloads", [])}

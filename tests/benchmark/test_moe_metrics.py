@@ -16,21 +16,26 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from cells import named  # noqa: E402
+
 from benchmark import flops, moe_cost, peaks, spec, trace_reduce  # noqa: E402
 
 MOE, SHORT = "serve-olmoe-longgen-closed", "train-512-1chip"
 NEW_METRICS = {
-    **{name + ".moe": MOE for name in (
+    **{named(name, "moe"): MOE for name in (
         "decode_step_device_ms", "decode_batch_occupancy",
         "device_idle_share", "hbm_peak_share", "engine_host_ms_per_step",
         # (PR 53 retired idle_ms_per_step_launch.moe and _fetch.moe: 0.35
         # and 0.0026 ms of a 14 ms step on the ledger's PR 52 line, the
-        # device busy under both spans since PR 36; device_idle_share.moe
-        # and breakdown.idle_gaps carry what they were for.)
-        "expert_ffn_time_share", "kv_gather_time_share",
+        # device busy under both spans since PR 36; device_idle_share
+        # and breakdown.idle_gaps carry what they were for. PR 59 retired
+        # kv_gather_time_share.moe: the gathers it selected left the step
+        # with PR 58, 7.5874 -> 0.0495% on the ledger's PR 58 line.)
+        "expert_ffn_time_share",
         "experts_touched_share", "expert_load_max_over_mean",
         "expert_ffn_roofline")},
-    **{name + ".512": SHORT for name in (
+    **{named(name, "512"): SHORT for name in (
         "train_step_device_ms", "train_step_mfu", "flash_time_share",
         # (PR 53 retired hbm_peak_share.512: 66.684, the very number of
         # hbm_peak_share.train in train-4k-1chip: one configuration,
@@ -51,7 +56,8 @@ def bench_json() -> dict:
 def test_new_metric_file_loads_through_the_cell(name, cell, monkeypatch):
     loaded = spec.load_cell(cell)
     metric = {m["name"]: m for m in loaded.per_layer}[name]
-    assert metric["cells"] == metric["workloads"] == [cell]
+    assert cell in metric["cells"]
+    assert metric["cells"] == metric["workloads"]
     assert metric["moves"] in {m["name"] for m in loaded.end_to_end}
     reader = spec.load_module(loaded.roots, "readers", metric["reader"])
     # Nothing to read (no trace, no such counter, as on the parent
@@ -158,12 +164,12 @@ def test_the_selectors_match_the_chips_operation_text():
     """The shapes as the v5e's compiler prints them in the decode
     program of this configuration (deviceless compile and the traced
     runs of PR 25): the expert products by an expert tensor among their
-    operands, whole or as a layer of the stacked weights; the two
-    table-wide gathers by their result."""
+    operands, whole or as a layer of the stacked weights. The two
+    table-wide gathers of the step before PR 58 are operations that no
+    selector owns (their entry went with them, PR 59)."""
     cell = {m["name"]: m for m in spec.load_cell(MOE).per_layer}
     experts = cell["expert_ffn_time_share.moe"]["ops"]
     assert experts == cell["expert_ffn_roofline.moe"]["ops"]
-    gather = cell["kv_gather_time_share.moe"]["ops"]
     texts = {
         "gate": "%fusion.181 = bf16[64,16,1024]{2,1,0:T(8,128)(2,1)S(1)} "
                 "fusion(bf16[12,64,2048,1024]{3,2,1,0:T(8,128)(2,1)} %p.1, "
@@ -179,7 +185,9 @@ def test_the_selectors_match_the_chips_operation_text():
     }
     assert [k for k, t in texts.items() if re.search(experts, t)] == \
         ["gate", "down"]
-    assert [k for k, t in texts.items() if re.search(gather, t)] == ["gather"]
+    others = [m["ops"] for m in cell.values()
+              if "ops" in m and m["ops"] != experts]
+    assert not [ops for ops in others if re.search(ops, texts["gather"])]
 
 
 def op_texts() -> dict:
@@ -202,13 +210,15 @@ def at_width(text: str, blocks: int) -> str:
 def test_the_selectors_match_the_v5es_own_text_at_every_width(blocks):
     """PR 53: each operation the v5e printed for the two programs in a
     traced run of this cell is owned by the selector of its part and by
-    no other, at the quarter width the cell's steps run at and at the
-    two wider ones (the gather's pattern names the role, with the
-    number of blocks left open; it spelt the whole width until PR 53 and
-    read 0.0 on the ledger's PR 47 and PR 52 lines)."""
+    no other, at the quarter width the cell's steps ran at and at the
+    two wider ones. The saved gathers are the step's before PR 58 (it
+    reads by row since): operations that no selector owns, as the rest
+    of the step; ``kv_gather_time_share.moe`` went with them (PR 59),
+    and a selector for the by-row kernel's share is the next
+    configuration's to bring."""
     cell = {m["name"]: m for m in spec.load_cell(MOE).per_layer}
-    owners = {"experts": cell["expert_ffn_time_share.moe"]["ops"],
-              "kv_gather": cell["kv_gather_time_share.moe"]["ops"]}
+    owners = {"experts": cell["expert_ffn_time_share.moe"]["ops"]}
+    assert not [name for name in cell if name.startswith("kv_gather")]
     texts = op_texts()
     seen = []
     for program in ("decode_step", "prefill_chunk"):

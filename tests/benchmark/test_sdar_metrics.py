@@ -18,15 +18,20 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from cells import named, renamed  # noqa: E402
+
 from benchmark import flops, peaks, sdar_cost, spec, trace_reduce  # noqa: E402
 
 CELL = "serve-sdar-blockgen-closed"
-NEW_METRICS = [name + ".sdar" for name in (
+NEW_METRICS = [named(name, "sdar") for name in (
     "decode_step_device_ms", "device_idle_share", "hbm_peak_share",
     "engine_host_ms_per_step", "host_calls_per_step", "kv_read_over_live",
     "decode_batch_occupancy", "tokens_per_row_pass", "commit_pass_share",
     "experts_touched_share", "expert_load_max_over_mean",
-    "expert_ffn_time_share", "block_step_roofline", "expert_ffn_roofline")]
+    "expert_ffn_time_share", "block_step_roofline", "expert_ffn_roofline",
+    # Since PR 59, as further cells on a survivor's list:
+    "decode_steps_ahead_share")]
 # The published widths (catalog row SDAR-30B-A3B-Chat), cut to 7 layers.
 SDAR = {"hidden_size": 2048, "intermediate_size": 6144,
         "moe_intermediate_size": 768, "num_attention_heads": 32,
@@ -40,7 +45,8 @@ TINY = {"hidden_size": 8, "intermediate_size": 99, "moe_intermediate_size": 4,
 # A window of 1,000 passes of 31 busy rows whose contexts hold 500
 # positions, 300 chunks of 30 tokens beside them, as the engine counts.
 COUNTERS = {
-    "decode_steps": 1000, "block_rows": 31_000, "commit_rows": 10_300,
+    "decode_steps": 1000, "decode_steps_ahead": 990, "block_rows": 31_000,
+    "commit_rows": 10_300,
     "decode_tokens": 41_400, "prefill_chunks": 300, "prefill_tokens": 9_000,
     "kv_positions_live": 31_000 * 500, "kv_positions_read": 32 * 1024 * 1000,
     "decode_host_us": 2_500_000, "host_calls": 2_300,
@@ -87,7 +93,7 @@ def canned_run() -> dict:
             "harness": {}, "traffic": {}}
 
 
-CANNED = {
+CANNED = renamed({
     "decode_step_device_ms.sdar": 20.0,
     "device_idle_share.sdar": None,     # busy_and_window wants real lines
     "hbm_peak_share.sdar": 100 * 11 / 16.9,
@@ -100,14 +106,16 @@ CANNED = {
     "experts_touched_share.sdar": 100 * 120 / 128,
     "expert_load_max_over_mean.sdar": 2.0,
     "expert_ffn_time_share.sdar": 100 * (3 * 14e6 + 7e6) / 72e6,
-}
+    "decode_steps_ahead_share.sdar": 99.0,
+})
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_new_metric_file_loads_and_reads_a_canned_run(name, monkeypatch):
     loaded = spec.load_cell(CELL)
     metric = {m["name"]: m for m in loaded.per_layer}[name]
-    assert metric["cells"] == metric["workloads"] == [CELL]
+    assert CELL in metric["cells"]
+    assert metric["cells"] == metric["workloads"]
     assert metric["moves"] == "serve_tokens_per_s"
     assert metric["layer"] in {m["layer"] for m in bench_json()["per_layer"]
                                if CELL not in m.get("workloads", [])}

@@ -22,23 +22,27 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from cells import named, renamed  # noqa: E402
+
 from benchmark import (  # noqa: E402
     flops, peaks, solar_cost, spec, trace_reduce)
 
 CELL = "serve-solar-open2-reason-closed"
 CONFIG = "solar-open2-250b-serve-1chip"
-# Thirteen, not the issue's fourteen: with 127 of 128 entries
-# ``test_per_layer_table.py``'s made-up cell has room for ONE entry, pops
-# it, and then fails on the cell that is left with none instead of on the
-# file without an entry (CHANGES.md, PR 55): ``decode_batch_occupancy``
-# stayed out, which ``decode_tokens / decode_steps`` of any run's line
-# gives.
-NEW_METRICS = [name + ".solar" for name in (
+# Thirteen when PR 55 wrote them, not the issue's fourteen (at 127 of 128
+# entries ``test_per_layer_table.py``'s last test failed, so
+# ``decode_batch_occupancy`` stayed out); PR 59's fold gave it its place,
+# on the survivor's list with the host-side three.
+NEW_METRICS = [named(name, "solar") for name in (
     "decode_step_device_ms", "prefill_chunk_device_ms", "device_idle_share",
     "hbm_peak_share", "kv_read_over_live", "expert_choices_here_share",
     "expert_ffn_time_share", "kda_time_share", "kda_chunk_time_share",
     "gated_attn_time_share", "kda_state_roofline", "expert_ffn_roofline",
-    "decode_step_roofline")]
+    "decode_step_roofline",
+    # Since PR 59, as further cells on a survivor's list:
+    "engine_host_ms_per_step", "host_calls_per_step",
+    "decode_batch_occupancy", "decode_steps_ahead_share")]
 # The catalog row Solar-Open2-250B of the model-configs guide, every key
 # of its `config`.
 CATALOG = {
@@ -264,7 +268,7 @@ def canned_run() -> dict:
             "harness": {}, "traffic": {}}
 
 
-CANNED = {
+CANNED = renamed({
     "decode_step_device_ms.solar": 16.0,
     "prefill_chunk_device_ms.solar": 9.0,
     "device_idle_share.solar": None,    # busy_and_window wants real lines
@@ -277,14 +281,19 @@ CANNED = {
     "kda_time_share.solar": 100 * 3 * 3 * 1.2e6 / (3 * 16e6),
     "gated_attn_time_share.solar": 100 * 3 * 2 * 1.5e6 / (3 * 16e6),
     "kda_chunk_time_share.solar": 100 * 3 * 0.5e6 / 9e6,
-}
+    "engine_host_ms_per_step.solar": 3.5,
+    "host_calls_per_step.solar": 2.25,
+    "decode_batch_occupancy.solar": 100 * 63 / 64,
+    "decode_steps_ahead_share.solar": 100.0,
+})
 
 
 @pytest.mark.parametrize("name", NEW_METRICS)
 def test_new_metric_file_loads_and_reads_a_canned_run(name, monkeypatch):
     loaded = spec.load_cell(CELL)
     metric = {m["name"]: m for m in loaded.per_layer}[name]
-    assert metric["cells"] == metric["workloads"] == [CELL]
+    assert CELL in metric["cells"]
+    assert metric["cells"] == metric["workloads"]
     assert metric["moves"] == "serve_tokens_per_s"
     assert metric["layer"] in {m["layer"] for m in bench_json()["per_layer"]
                                if CELL not in m.get("workloads", [])}

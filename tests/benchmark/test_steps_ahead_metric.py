@@ -2,13 +2,12 @@
 while the step before them was unread, as one quantity in two entries
 (the closed cells report ``serve_tokens_per_s``, the open one
 ``token_gap_p95_ms``). Each entry is held to its own file, found and
-read through the harness's own loader, from canned counters. The
-block-diffusion cell is not among the cells: when PR 36 was written the
-share was 0 there by the family's nature and ``test_sdar_metrics.py``
-held that cell to exactly PR 35's metrics. Since PR 46 that family keeps
-a pass ahead too (its block stays on the device), and since PR 53 the
-cell is held to AT LEAST PR 35's metrics, so a ``.sdar`` entry can
-follow as new files. Nothing here is a measurement."""
+read through the harness's own loader, from canned counters, once for
+every cell it lists. The block-diffusion cell was not among them: when
+PR 36 was written the share was 0 there by the family's nature. Since
+PR 46 that family keeps a pass ahead too (its block stays on the
+device), and PR 59 put it on the list with the Kimi and Solar cells.
+Nothing here is a measurement."""
 
 import json
 import os
@@ -24,9 +23,14 @@ if REPO not in sys.path:
 from benchmark import spec  # noqa: E402
 
 ENTRIES = {
+    # Every closed-loop serving cell since PR 59 (three when PR 36 wrote
+    # it, Xing's a copy of its own since PR 44; the SDAR, Kimi and Solar
+    # cells waited for a place in a full table).
     "decode_steps_ahead_share": ("serve_tokens_per_s", [
         "serve-longgen-closed", "serve-olmoe-longgen-closed",
-        "serve-phi4flash-reason-closed"]),
+        "serve-phi4flash-reason-closed", "serve-sdar-blockgen-closed",
+        "serve-xing4-longdoc-closed", "serve-kimi-linear-reason-closed",
+        "serve-solar-open2-reason-closed"]),
     "decode_steps_ahead_share.open": ("token_gap_p95_ms",
                                       ["serve-chat-steady"]),
 }
