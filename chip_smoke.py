@@ -72,6 +72,14 @@ ARGS.add_argument("--round-weights", metavar="DTYPE", default=None,
                        "(float8_e4m3fn: the nearest precision below "
                        "bfloat16) while the reference keeps the weights as "
                        "they are; the comparison then has to FAIL")
+ARGS.add_argument("--state-dtype", metavar="DTYPE", default=None,
+                  help="with --paged-logits or --cell of a configuration "
+                       "whose model keeps a recurrent state in float32 "
+                       "(``state_dtype``): the programs keep it in this "
+                       "dtype (bfloat16) instead; a second control, run as "
+                       "--round-weights is (it exits 1 where the comparison "
+                       "passed: at Jamba's published widths it does at two "
+                       "seeds of three, PERF.md 6, PR 60)")
 ARGS.add_argument("--cell", metavar="WORKLOAD", default=None,
                   help="instead of the phases, with --round-weights: the "
                        "benchmark's own run of that serve cell "
@@ -676,10 +684,12 @@ REHEARSED_OTHER_EXPERT = 0.5
 
 
 def phase_paged_logits(path: str, seed: int, rehearse: bool,
-                       device: dict, round_weights: "str | None" = None
-                       ) -> None:
+                       device: dict, round_weights: "str | None" = None,
+                       state_dtype: "str | None" = None) -> None:
     """The engine's jitted steps at a benchmark configuration's size,
     driven as the engine drives them, outside any timed window."""
+    import dataclasses
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -692,6 +702,9 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
     with open(path) as f:
         config = spec.rehearsed(json.load(f), rehearse)
     model_config = spec.build_model_config(config)
+    if state_dtype:
+        model_config = dataclasses.replace(
+            model_config, state_dtype=jnp.dtype(state_dtype))
     model = spec.model_numbers(config)
     reference = spec.load_module(
         [os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -701,7 +714,10 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
                                    seed, rehearse, device, round_weights)
     if paged_model.family(model_config).recurrent:
         return phase_hybrid_logits(config, model_config, model, reference,
-                                   seed, rehearse, device, round_weights)
+                                   seed, rehearse, device, round_weights,
+                                   state_dtype)
+    check(not state_dtype, "--state-dtype is for a configuration whose "
+          "model keeps a recurrent state")
     if model_config.block_length:
         return phase_block_logits(config, model_config, model, reference,
                                   seed, rehearse, device, round_weights)
@@ -916,10 +932,59 @@ HYBRID_LOGITS = 0.4
 REHEARSED_HYBRID_LOGITS = 0.35  # 64 wide: a rounding is a larger share
 
 
+# A state-space stack's FIRST layer's state behind a context's last
+# position (``cache["ssm"][0]``; before it lie the embedding and one
+# norm), against the reference's position-by-position scan from zero:
+# the distance over the norm, of the state's SLOWEST column (``A = -1``:
+# an entry lives 1 / dt positions, 10 to 1,000, so a rounding a position
+# adds up there first), the worse of the two compared contexts (1,164
+# and 3,584 positions). On the v5e at the published widths (my chip
+# runs, PR 60, call F; three seeds each): sound 0.0041 to 0.0050 (what
+# bfloat16 ``u``, ``dt``, ``B`` and ``C`` put in; the same at both
+# contexts); the state kept in bfloat16 0.0143, 0.0275, 0.0406 (a single
+# context at least 0.0123); weights rounded to float8 0.113. The bound is
+# the middle of 0.0050 and 0.0143 in ratio, 1.7 times from either. The
+# whole state's distance reads 0.0046 to 0.0053 sound and 0.0082 to
+# 0.0238 in bfloat16: it separates too, by less. This is what tells the
+# state's precision: the logits do not (the configuration's
+# ``probes.logit_atol_why``).
+MAMBA_FIRST_STATE = 0.0085
+
+
+def first_state_error(states: dict, contexts: list, reference, params,
+                      model: dict) -> float:
+    """The worst compared context's error of ``states`` (row -> the
+    cache's first layer's state, float32) by the measure above; 0.0
+    where the reference has no ``first_state`` (the hybrid family)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    worst, whole, slowest = 0.0, [], []
+    first_state = jax.jit(lambda p, t: reference.first_state(p, t, model))
+    for i, state in states.items():
+        want = np.asarray(first_state(params, jnp.asarray(contexts[i][None])))[0]
+        off = state - want
+        whole.append(float(np.linalg.norm(off) / np.linalg.norm(want)))
+        slowest.append(float(np.linalg.norm(off[:, 0])
+                             / np.linalg.norm(want[:, 0])))
+        worst = max(worst, slowest[-1])
+    if states:
+        say("hybrid", check="the first layer's state behind each compared "
+            "context's last position against the reference's scan",
+            slowest_column=[round(x, 6) for x in slowest],
+            whole_state=[round(x, 6) for x in whole],
+            bound=MAMBA_FIRST_STATE)
+    return worst
+
+
 def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
                         seed: int, rehearse: bool, device: dict,
-                        round_weights: "str | None") -> None:
-    """A hybrid configuration's three caches at its real size: every
+                        round_weights: "str | None",
+                        state_dtype: "str | None" = None) -> None:
+    """A hybrid configuration's three caches (``llm_engine/hybrid.py``),
+    or a state-space stack's state and pools (``llm_engine/mamba.py``:
+    the same two forwards under the same names), at its real size: every
     row of the engine busy (the probes' lengths, one context nearly as
     long as the table, the rest a few chunks long), prefilled chunk by
     chunk and then decoded together, as the engine drives its two
@@ -933,9 +998,13 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
     import numpy as np
 
     from ray_tpu._private.config import GLOBAL_CONFIG
-    from ray_tpu.serve.llm_engine import hybrid
+    from ray_tpu.serve.llm_engine import hybrid, mamba
     from ray_tpu.serve.llm_engine import model as paged_model
 
+    family = paged_model.family(model_config)
+    # The same two forwards and cache, by name, in either module.
+    programs = mamba if family is mamba.FAMILY else hybrid
+    control = round_weights or (state_dtype and f"a state in {state_dtype}")
     engine = config["engine"]
     rows, max_len = engine["max_batch_size"], engine["max_seq_len"]
     block = engine.get("block_size") or GLOBAL_CONFIG.llm_block_size
@@ -960,10 +1029,10 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
         served = round_mantissa(served, round_weights)
     say("hybrid", config=config["name"], layers=model_config.num_layers,
         params=model_config.num_params, rows=rows, table=max_len,
-        contexts=[len(c) for c in contexts], round_weights=round_weights,
-        ring=hybrid.ring_positions(model_config, block, chunk),
+        contexts=[len(c) for c in contexts], control=control,
+        ring=family.ring_positions(model_config, block, chunk),
         device_bytes_in_use=device_bytes())
-    cache = hybrid.init_cache(model_config, 1 + rows * width, block, rows,
+    cache = programs.init_cache(model_config, 1 + rows * width, block, rows,
                               chunk)
     say("hybrid", cache={k: [list(v.shape), str(v.dtype)]
                          for k, v in cache.items()},
@@ -976,12 +1045,12 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
                 tables[i, turn] = deck.pop()
     shown_chunk = jax.jit(
         lambda params, cache, tokens, positions, table, slot, n_valid:
-        hybrid.chunk_forward(params, cache, tokens, positions, table, slot,
+        programs.chunk_forward(params, cache, tokens, positions, table, slot,
                              n_valid, model_config, block),
         donate_argnums=(1,))
     shown_step = jax.jit(
         lambda params, cache, tokens, positions, tables:
-        hybrid.decode_forward(params, cache, tokens, positions, tables,
+        programs.decode_forward(params, cache, tokens, positions, tables,
                               model_config, block), donate_argnums=(1,))
 
     got = [{} for _ in contexts]               # position -> logits row
@@ -1012,6 +1081,10 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
             got[i][int(positions[i])] = logits[i]
     check(all(bool(jnp.isfinite(v.astype(jnp.float32)).all())
               for v in cache.values()), "a cache is not finite")
+    # Row ``i`` lies in row slot ``i``: the first layer's state behind
+    # each compared context's last position.
+    states = {i: np.asarray(cache["ssm"][0, i], np.float32)
+              for i in compared} if hasattr(reference, "first_state") else {}
     del cache, shown_chunk, shown_step
     if round_weights:
         del served
@@ -1049,6 +1122,8 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
                             float(row.max() - row[logits.argmax()]))
         by_context.append(round(here, 4))
         worst = max(worst, here)
+    state_error = first_state_error(states, contexts, reference, params,
+                                    model)
     bound = REHEARSED_HYBRID_LOGITS if rehearse else HYBRID_LOGITS
     say("hybrid", check="logits through the three caches against the "
         f"float32 reference {config['reference']}", device=device["kind"],
@@ -1056,13 +1131,20 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
         contexts=[len(contexts[i]) for i in compared],
         worst_argmax_gap=round(worst_gap, 4),
         logit_std=round(float(want[0].std()), 3), bound=bound,
-        round_weights=round_weights)
-    if round_weights:
-        check(worst > bound, f"weights rounded through {round_weights} "
+        control=control)
+    if state_dtype and not rehearse:
+        # The state's own control: the logits cannot tell it (PR 60).
+        check(state_error > MAMBA_FIRST_STATE, f"a state in {state_dtype} "
+              f"read {state_error} <= {MAMBA_FIRST_STATE}: the comparison "
+              "cannot tell the state's precision")
+    elif control:
+        check(worst > bound, f"the programs on {control} "
               f"stayed inside the bound ({worst} <= {bound}): the "
               "comparison cannot tell a lower precision")
     else:
         check(worst <= bound, f"logits off by {worst} standard deviations")
+        check(rehearse or state_error <= MAMBA_FIRST_STATE,
+              f"the first layer's state off by {state_error}")
 
 
 # Diffusion over blocks (PR 35), on the v5e at the published widths
@@ -1607,22 +1689,34 @@ def cell_with_rounded_replica(args, started: float) -> int:
     that is the lower reading a configuration's ``logit_atol`` is set
     against."""
     import contextlib
+    import dataclasses
 
-    from benchmark import harness
+    from benchmark import harness, spec
     from ray_tpu.serve.llm_engine import model
 
-    check(bool(args.round_weights), "--cell is the control of a cell's "
-          "check: give --round-weights (benchmark/run.py makes the sound "
-          "run)")
+    check(bool(args.round_weights or args.state_dtype), "--cell is the "
+          "control of a cell's check: give --round-weights or --state-dtype "
+          "(benchmark/run.py makes the sound run)")
     real, built = model.serving_params, []
+    real_config = spec.build_model_config
 
     def rounded_first(config, params=None, seed=0):
         weights = real(config, params, seed)
         built.append(seed)
         return round_mantissa(weights, args.round_weights) \
-            if len(built) == 1 else weights
+            if len(built) == 1 and args.round_weights else weights
+
+    def other_state(config):
+        import jax.numpy as jnp
+
+        return dataclasses.replace(real_config(config),
+                                   state_dtype=jnp.dtype(args.state_dtype))
 
     model.serving_params = rounded_first
+    if args.state_dtype:
+        # The replica keeps its state so; the weights and the reference,
+        # which ask the configuration nothing of it, are what they were.
+        spec.build_model_config = other_state
     out, err = _LastLine(sys.stdout, "{"), \
         _LastLine(sys.stderr, "bench[correct] ")
     # The window is short: the control reads no speed.
@@ -1633,12 +1727,15 @@ def cell_with_rounded_replica(args, started: float) -> int:
             harness.main(argv + ["--rehearse"] * args.rehearse, started)
     finally:
         model.serving_params = real
+        spec.build_model_config = real_config
     result = json.loads(out.last)
     compared = json.loads(err.last[len(err.prefix):])
     failed = sorted(k for k, v in compared.items() if v is False)
     say("cell", workload=args.cell, round_weights=args.round_weights,
-        weights_built=len(built), correct=result["correct"],
-        worst_gap=compared["worst_gap"], logit_atol=compared["logit_atol"],
+        state_dtype=args.state_dtype, weights_built=len(built), correct=result["correct"],
+        worst_gap=compared["worst_gap"], mean_gap=compared["mean_gap"],
+        gap_statistic=compared["gap_statistic"],
+        logit_atol=compared["logit_atol"],
         checks_failed=failed)
     check(len(built) == 2, f"{len(built)} sets of weights were built, not "
           "the replica's and the check's")
@@ -1646,7 +1743,8 @@ def cell_with_rounded_replica(args, started: float) -> int:
     # shows only that the path holds.
     check(args.rehearse or failed == ["reference_argmax_or_near_tie"],
           f"the replica ran on weights rounded through "
-          f"{args.round_weights} and the cell's checks failed {failed}: "
+          f"{args.round_weights} (its state in {args.state_dtype}) and the "
+          f"cell's checks failed {failed}: "
           "the limit has to refuse a lower precision, and nothing else "
           "may fail")
     print(json.dumps({"ok": True, "device": result["device"]}), flush=True)
@@ -1785,7 +1883,7 @@ def main(argv: "list[str] | None" = None) -> int:
 
     if args.paged_logits:
         phase_paged_logits(args.paged_logits, args.seed, args.rehearse,
-                           device, args.round_weights)
+                           device, args.round_weights, args.state_dtype)
     elif args.chips == 4:
         phase_sharded(sz, args.seed, device)
     else:

@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models.llama import rms_norm
+
 F32 = jnp.float32
 
 
@@ -288,12 +290,19 @@ def residual_mlp(x, w: dict, config: Phi4FlashConfig):
 # -------------------------------------------------------------- state space
 
 
-def _ssm_inputs(w: dict, u, config: Phi4FlashConfig):
+def _ssm_inputs(w: dict, u, config):
     """From the convolved, activated ``u`` [..., Di]: the step ``dt``
-    [..., Di], ``B`` and ``C`` [..., N] (float32) and ``A`` [Di, N]."""
+    [..., Di], ``B`` and ``C`` [..., N] (float32) and ``A`` [Di, N]. A
+    mixer with ``dt_norm``, ``b_norm`` and ``c_norm`` (``models/jamba.py``)
+    passes the three outputs of ``x_proj`` through an RMSNorm each
+    (``config.rms_norm_eps``) first; Phi's has none."""
     r, n = config.dt_rank, config.d_state
     proj = matmul_f32(u, w["x_proj"])
     dt_r, b, c = proj[..., :r], proj[..., r:r + n], proj[..., r + n:]
+    if "dt_norm" in w:
+        eps = config.rms_norm_eps
+        dt_r, b, c = (rms_norm(x, w[name], eps) for x, name in (
+            (dt_r, "dt_norm"), (b, "b_norm"), (c, "c_norm")))
     dt = jax.nn.softplus(matmul_f32(dt_r, w["dt_proj"])
                          + w["dt_bias"].astype(F32))
     return dt, b, c, -jnp.exp(w["A_log"].astype(F32))
@@ -309,23 +318,32 @@ def _ssm_update(s, dt, u, b, c, a, state_dtype):
     return s, jnp.einsum("...dn,...n->...d", s.astype(F32), c)
 
 
-def _ssm_out(w: dict, y, u, z, config: Phi4FlashConfig):
+def _ssm_out(w: dict, y, u, z, config):
     """(the layer's output, its memory ``y`` before the gate)."""
     y = (y + w["D"].astype(F32) * u.astype(F32)).astype(config.dtype)
     return matmul(y * jax.nn.silu(z), w["out_proj"], config.dtype), y
 
 
-def ssm_step(w: dict, h, s, conv, active, config: Phi4FlashConfig):
+def ssm_step(w: dict, h, s, conv, active, config, taps_first=False):
     """One token for every row. h [B, E]; s [B, Di, N]; conv [B,
-    d_conv - 1, Di] (the convolution's last inputs); active [B] bool:
-    an inactive row's state is not advanced. Returns (out [B, E],
-    memory [B, Di], s, conv)."""
+    d_conv - 1, Di] (the convolution's last inputs), or with
+    ``taps_first`` [d_conv - 1, B, Di] (``llm_engine/mamba.py``: the
+    rows before the minor dimension, where the chip tiles them and not
+    the three taps); active [B] bool: an inactive row's state is not
+    advanced. Returns (out [B, E], memory [B, Di] (the output before
+    the gate, which only Phi's gated memory units read), s, conv)."""
     u, z = jnp.split(matmul(h, w["in_proj"], config.dtype), 2, axis=-1)
-    window = jnp.concatenate([conv, u[:, None].astype(conv.dtype)], axis=1)
-    conv = jnp.where(active[:, None, None], window[:, 1:], conv)
+    if taps_first:
+        window = jnp.concatenate([conv, u[None].astype(conv.dtype)], axis=0)
+        conv = jnp.where(active[None, :, None], window[1:], conv)
+        taps = "kbd,kd->bd"
+    else:
+        window = jnp.concatenate([conv, u[:, None].astype(conv.dtype)],
+                                 axis=1)
+        conv = jnp.where(active[:, None, None], window[:, 1:], conv)
+        taps = "bkd,kd->bd"
     u = jax.nn.silu(
-        (jnp.einsum("bkd,kd->bd", window.astype(F32),
-                    w["conv_w"].astype(F32))
+        (jnp.einsum(taps, window.astype(F32), w["conv_w"].astype(F32))
          + w["conv_b"].astype(F32)).astype(config.dtype))
     dt, b, c, a = _ssm_inputs(w, u, config)
     dt = jnp.where(active[:, None], dt, 0.0)
@@ -333,7 +351,7 @@ def ssm_step(w: dict, h, s, conv, active, config: Phi4FlashConfig):
     return (*_ssm_out(w, y, u, z, config), s, conv)
 
 
-def ssm_chunk(w: dict, h, s, conv, n_valid, config: Phi4FlashConfig):
+def ssm_chunk(w: dict, h, s, conv, n_valid, config):
     """A chunk of one row from a carried state. h [C, E]; s [Di, N];
     conv [d_conv - 1, Di]; positions at or past ``n_valid`` are padding
     and advance nothing. Returns (out [C, E], memory [C, Di], s,

@@ -171,13 +171,22 @@ def _kernel(tables_ref, lengths_ref, entry_ref,      # scalar prefetch
             jnp.int32, (heads, 1), 0) // reps
         # The row's own position: its score is its own head's column of
         # q . k_new^T, its value its own head's row of v_new.
-        own = head_of_query == lax.broadcasted_iota(jnp.int32, (1, kv), 1)
-        s_own = lax.dot_general(q, k_new, (((1,), (1,)), ((), ())),
-                                preferred_element_type=F32)
-        m0 = jnp.sum(jnp.where(own, s_own, 0.0), axis=1,
-                     keepdims=True) * scale            # [heads, 1]
-        acc0 = jnp.dot(own.astype(v_new.dtype), v_new,
-                       preferred_element_type=F32)     # [heads, d]
+        if kv == 1:
+            # ONE key-value head: every query is of it (a product with
+            # one column or one row is no matrix product to the chip's
+            # compiler, which refuses it).
+            m0 = jnp.sum(q.astype(F32) * k_new.astype(F32), axis=1,
+                         keepdims=True) * scale        # [heads, 1]
+            acc0 = jnp.broadcast_to(v_new.astype(F32), (heads, d))
+        else:
+            own = head_of_query == lax.broadcasted_iota(
+                jnp.int32, (1, kv), 1)
+            s_own = lax.dot_general(q, k_new, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=F32)
+            m0 = jnp.sum(jnp.where(own, s_own, 0.0), axis=1,
+                         keepdims=True) * scale        # [heads, 1]
+            acc0 = jnp.dot(own.astype(v_new.dtype), v_new,
+                           preferred_element_type=F32)  # [heads, d]
         l0 = jnp.ones((heads, 1), F32)
 
         # A chunk as it lies: column ``s * kv + k`` is head ``k`` of the
@@ -219,9 +228,14 @@ def _kernel(tables_ref, lengths_ref, entry_ref,      # scalar prefetch
             # (a weight of zero times it would still be a NaN's NaN).
             @pl.when(live < chunk)
             def _():
-                shape = (pages_per_chunk, block, 1, 1)
-                at = lax.broadcasted_iota(jnp.int32, shape, 0) * block \
-                    + lax.broadcasted_iota(jnp.int32, shape, 1)
+                if vbuf.ndim == 5:      # a page lies [block, kv, d]
+                    shape = (pages_per_chunk, block, 1, 1)
+                    at = lax.broadcasted_iota(jnp.int32, shape, 0) * block \
+                        + lax.broadcasted_iota(jnp.int32, shape, 1)
+                else:                   # [block * kv, d]: row s * kv + k
+                    shape = (pages_per_chunk, block * kv, 1)
+                    at = lax.broadcasted_iota(jnp.int32, shape, 0) * block \
+                        + lax.broadcasted_iota(jnp.int32, shape, 1) // kv
                 vbuf[slot] = jnp.where(at < live, vbuf[slot],
                                        jnp.zeros_like(vbuf[slot]))
 
@@ -258,10 +272,11 @@ def paged_kv_attention(q, k_new, v_new, pool_k, pool_v, tables, lengths,
     ``tables[b]``, and over ``k_new[b, k]`` and ``v_new[b, k]``. Returns
     ``[B, kv, reps, d]`` in q's dtype."""
     (rows, kv, reps, d), table_width = q.shape, tables.shape[1]
-    block = pool_k.shape[2]
+    page = pool_k.shape[2:]
+    block = page[0] if len(page) == 3 else page[0] // kv
     if not (k_new.shape == v_new.shape == (rows, kv, d)
             and pool_k.shape == pool_v.shape
-            and pool_k.shape[3:] == (kv, d)):
+            and page in ((block, kv, d), (block * kv, d))):
         raise ValueError(
             f"paged_kv_attention: queries {q.shape}, fresh keys "
             f"{k_new.shape} and values {v_new.shape}, pools "
@@ -275,7 +290,7 @@ def paged_kv_attention(q, k_new, v_new, pool_k, pool_v, tables, lengths,
         table_width=table_width, block=block, kv=kv, reps=reps)
     vmem = {} if interpret else {"memory_space": pltpu.VMEM}
     fresh = pl.BlockSpec((1, kv, d), lambda b, *_: (b, 0, 0), **vmem)
-    buffer = pltpu.VMEM((2, pages_per_chunk, block, kv, d), pool_k.dtype)
+    buffer = pltpu.VMEM((2, pages_per_chunk, *page), pool_k.dtype)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -292,10 +292,12 @@ def pack_prefill_chunk(chunk_len: int, width: int, tokens, start: int,
     return chunk
 
 
-def make_engine_decode_step(config, block_size: int):
+def make_engine_decode_step(config, block_size: int,
+                            decode_forward=decode_forward):
     """The ONE decode program, on ``model.pack_decode_rows``' array
     (row ``i`` is row slot ``i``), the carried sampling key and the step
-    before's tokens ``prev`` (``model.row_tokens``)."""
+    before's tokens ``prev`` (``model.row_tokens``). ``decode_forward``:
+    another family's of this one's signature (``mamba.py``)."""
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
@@ -310,10 +312,11 @@ def make_engine_decode_step(config, block_size: int):
     return decode_step
 
 
-def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
+def make_engine_prefill_chunk(config, block_size: int, chunk_len: int,
+                              chunk_forward=chunk_forward):
     """The prefill program (one a table width the engine hands it), on
     ``pack_prefill_chunk``'s array; only the logits of ``last_idx`` are
-    computed."""
+    computed. ``chunk_forward``: as ``make_engine_decode_step``'s."""
     positions_at, table_at = 3 + chunk_len, 3 + 2 * chunk_len
 
     @functools.partial(jax.jit, donate_argnums=(1,))

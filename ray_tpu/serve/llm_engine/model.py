@@ -46,7 +46,8 @@ two program names and the same one-array-a-pass contract:
 residual path of several streams, ``linear.py`` for a matrix-valued
 recurrent state a row beside a pool that some layers own (a latent
 pool, or key and value pools through ``paged_attention`` here; the
-decode step reads either by row), and
+decode step reads either by row), ``mamba.py`` for a state-space stack
+whose few attention layers own key and value pools of one head, and
 ``BLOCKWISE`` below for generation by diffusion over blocks: the same
 layers and pool, ``T = block_length`` query rows a row of the batch, a
 mask that lets a position see all of its own block, and a pass that
@@ -151,7 +152,8 @@ def family(config) -> Family:
     configuration names it (``family = "hybrid"``:
     ``models/phi4flash.py``; ``"latent"``: ``models/xing.py``;
     ``"linear"``: ``models/kimi_linear.py`` and ``models/solar_open2.py``,
-    by the kind of their full layers) or has a
+    by the kind of their full layers; ``"mamba"``: ``models/jamba.py``,
+    a state-space stack around a few attention layers) or has a
     ``block_length`` (generation by diffusion over blocks); otherwise it
     is the stack of identical layers over one paged pool of this module,
     one token a row a step."""
@@ -168,6 +170,10 @@ def family(config) -> Family:
         from ray_tpu.serve.llm_engine import linear
 
         return linear.FAMILIES[config.full_kind]
+    if named == "mamba":
+        from ray_tpu.serve.llm_engine import mamba
+
+        return mamba.FAMILY
     if getattr(config, "block_length", 0) > 0:
         return _blockwise(config.block_length)
     return PAGED
@@ -263,8 +269,11 @@ def paged_attention(layer: dict, normed: jax.Array, positions: jax.Array,
     ``positions`` [B, T] (T=1 decode, T=chunk prefill). pool_k/pool_v:
     the WHOLE pool, [entries, num_blocks, bs, kv, d], written at ``[li,
     block, offset]`` and gathered at ``[li, block_tables]`` (no entry is
-    taken out or put back); an entry is a layer, or a layer's place
-    among those that own one (``linear.py``). block_tables: [B, M]
+    taken out or put back), or [entries, num_blocks, bs * kv, d], a
+    page's positions and heads in one dimension (``mamba.py``: ONE
+    key-value head costs what it holds); an entry is a layer, or a
+    layer's place among those that own one (``linear.py``,
+    ``mamba.py``). block_tables: [B, M]
     (append-ordered block ids, 0-padded). ``n_valid``: optional scalar
     — positions at/after it scatter to the scratch block instead of
     the table (prefill chunk padding).
@@ -302,8 +311,12 @@ def paged_attention(layer: dict, normed: jax.Array, positions: jax.Array,
         blocks = jnp.where(in_range, blocks, 0)
         offsets = jnp.where(in_range, offsets, 0)
     k, v = k.astype(pool_k.dtype), v.astype(pool_v.dtype)
-    written = (pool_k.at[li, blocks, offsets].set(k),
-               pool_v.at[li, blocks, offsets].set(v))
+    at = (li, blocks, offsets)
+    if pool_k.ndim == 4:
+        # Pages that lie [bs * kv, d] (``mamba.py``): row s * kv + k.
+        at = (li, blocks[..., None],
+              offsets[..., None] * kv_heads + jnp.arange(kv_heads))
+    written = (pool_k.at[at].set(k), pool_v.at[at].set(v))
     reps = h // kv_heads
     if by_row:
         # Imported where it is used: one that gathers never loads pallas.

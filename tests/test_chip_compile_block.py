@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import pytest
 from v5e_compile import (  # noqa: F401 — the fixtures
     _memory_of, _sdar, assert_experts_reach_the_kernel_whole,
-    compiled_kernels, v5e_chip, v5e_devices)
+    compiled_kernels, kernel_calls, kv_attention_calls, pallas_calls,
+    v5e_chip, v5e_devices)
 
 
 def _block_step(v5e_chip, width=128):
@@ -81,7 +82,12 @@ def test_block_step_at_each_table_width_on_v5e(v5e_chip, compiled_kernels,
                      text) is None
     if width < 128:
         assert f"[{32 * 128},16,4,128]" not in text
-    assert "paged_kv_attention" not in text
+    # By the call's line, not the whole text: the table of source files
+    # names ``tests/test_paged_kv_attention.py`` where that file ran
+    # first on this worker (``v5e_compile.kernel_calls``). Every kernel
+    # the pass calls, by any name, is the experts'.
+    assert kv_attention_calls(text) == []
+    assert pallas_calls(text) == kernel_calls(text, "grouped_expert_ffn")
     assert_experts_reach_the_kernel_whole(text, (2, 128, 2048, 768), 1)
 
 

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 import pytest
 from v5e_compile import (  # noqa: F401 — the fixtures
     _memory_of, _sdar, assert_experts_reach_the_kernel_whole,
-    compiled_kernels, kv_attention_calls, v5e_chip, v5e_devices)
+    compiled_kernels, kernel_calls, kv_attention_calls, pallas_calls,
+    v5e_chip, v5e_devices)
 
 # An expert model's programs hold ``ops/grouped_expert_ffn.py``.
 pytestmark = pytest.mark.usefixtures("compiled_kernels")
@@ -218,8 +219,9 @@ def test_the_one_decode_program_reads_by_row_on_v5e(v5e_chip, model,
     assert re.search(F32_EXPERTS, text) is None
     if model == "olmoe":
         assert_experts_reach_the_kernel_whole(text, (2, 64, 2048, 1024), 1)
-    else:           # no expert layer: no kernel of theirs
-        assert "grouped_expert_ffn" not in text
+    else:           # no expert layer: no kernel of theirs, by any name
+        assert kernel_calls(text, "grouped_expert_ffn") == []
+        assert pallas_calls(text) == calls
     wq_copy = re.search(
         rf"constant_dynamic-slice_fusion[.\d]* = bf16\[1,{config.hidden_size}"
         rf",{heads},128\]", text)
@@ -273,8 +275,9 @@ def test_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, model, width):
         assert re.search(rf"f32\[(\d+,)?{e},(2048,{m}|{m},2048)\]",
                          text) is None
         assert_experts_reach_the_kernel_whole(text, (2, e, 2048, m), 1)
-    else:
-        assert "grouped_expert_ffn" not in text
+    else:           # a dense chunk gathers: no kernel at all, by any name
+        assert kernel_calls(text, "grouped_expert_ffn") == []
+        assert pallas_calls(text) == []
 
 
 @pytest.mark.parametrize("model", ["mistral", "olmoe"])

@@ -5,7 +5,10 @@ tables]`` with the rows' fresh keys and values written first). Shapes:
 a tiny float32 one (2 key-value heads of 2 queries, 32 wide, blocks of
 4: only the order of summation differs, 1e-5) and the published head
 size and block (128, 16) in bfloat16 for 8 queries a key-value head of
-8 (Solar-Open2), 4 of 8 (Mistral), 1 of 16 (OLMoE) and 1 of 8: both
+8 (Solar-Open2), 4 of 8 (Mistral), 1 of 16 (OLMoE), 1 of 8, and 20
+queries on ONE key-value head over pools whose pages lie ``[block * kv,
+d]`` (Jamba: ``llm_engine/mamba.py``; the tiny shape over such pools
+too, two heads a position): both
 sides round the probabilities to bfloat16, the kernel before the
 division by the softmax's sum and the plain form after, so they are
 held within 2e-2 of the output's largest value.
@@ -40,6 +43,8 @@ SHAPES = {
     "reps4_kv8": (8, 4, 128, 16, jnp.bfloat16),
     "reps1_kv16": (16, 1, 128, 16, jnp.bfloat16),
     "reps1_kv8": (8, 1, 128, 16, jnp.bfloat16),
+    "reps20_kv1_flat": (1, 20, 128, 16, jnp.bfloat16),
+    "tiny_flat": (2, 2, 32, 4, jnp.float32),
 }
 
 
@@ -55,7 +60,9 @@ def case(shape):
     """(config, block, pools, tables, the kernel's side and the plain
     form's, jitted) of one shape, over the same random queries and
     fresh keys and values; the pools, the tables, the lengths and the
-    entry are arguments of the jitted sides, so each compiles once."""
+    entry are arguments of the jitted sides, so each compiles once. A
+    ``_flat`` shape hands the kernel the same pools with a page's
+    positions and heads in one dimension."""
     config, block = _config(shape)
     kv, d, dtype = config.num_kv_heads, config.head_dim, config.dtype
     reps = config.num_heads // kv
@@ -69,11 +76,16 @@ def case(shape):
     tables = np.random.default_rng(56).permutation(
         np.arange(1, blocks))[:ROWS * TABLE].reshape(ROWS, TABLE)
 
+    def lies(pool):
+        if not shape.endswith("_flat"):
+            return pool
+        return pool.reshape(ENTRIES, blocks, block * kv, d)
+
     @jax.jit
     def kernel(pool_k, pool_v, tables, lengths, li):
         return paged_kv_attention(
-            q, k_new, v_new, pool_k, pool_v, tables, lengths, li,
-            scale=d ** -0.5, pages_per_chunk=PAGES_PER_CHUNK)
+            q, k_new, v_new, lies(pool_k), lies(pool_v), tables, lengths,
+            li, scale=d ** -0.5, pages_per_chunk=PAGES_PER_CHUNK)
 
     @jax.jit
     def plain(pool_k, pool_v, tables, lengths, li):
@@ -137,7 +149,8 @@ def test_each_entry_of_the_pools(shape, li):
     assert np.abs(np.asarray(got - other, np.float32)).max() > 1e-2
 
 
-@pytest.mark.parametrize("shape", ["tiny", "reps8_kv8", "reps1_kv16"])
+@pytest.mark.parametrize("shape", ["tiny", "reps8_kv8", "reps1_kv16",
+                                   "reps20_kv1_flat", "tiny_flat"])
 @pytest.mark.parametrize("poison", [np.nan, 1e30])
 def test_what_lies_past_a_row_is_never_read_into_the_sum(shape, poison):
     """Every position of the entry that no row attends over through the
@@ -225,6 +238,58 @@ def test_paged_attention_by_row_is_its_gathered_form(gated):
     assert not np.asarray(got[0]).any()
     for a, b in zip(written, gathered):
         np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("kv", [1, 2])
+def test_paged_attention_over_pages_of_positions_and_heads_in_one(kv):
+    """``model.paged_attention`` over pools ``[entries, blocks, bs * kv,
+    d]`` (``llm_engine/mamba.py``: ONE key-value head, 5 queries on it,
+    and two heads for the layout's sake): by row and gathered agree
+    with each other and with the gathered branch over the dense family's
+    ``[.., bs, kv, d]`` pools of the same content, and the pools come
+    back written alike, a chunk's padding on the scratch block."""
+    block, e, d, reps = 4, 40, 8, 5
+    config = types.SimpleNamespace(
+        num_heads=kv * reps, num_kv_heads=kv, head_dim=d,
+        dtype=jnp.float32, block_length=0, qk_norm=False, rotary=False,
+        rms_norm_eps=1e-6)
+    blocks = 1 + ROWS * TABLE
+    keys = iter(jax.random.split(jax.random.PRNGKey(60), 8))
+    layer = {name: jax.random.normal(next(keys), shape) * e ** -0.5
+             for name, shape in (("wq", (e, kv * reps, d)),
+                                 ("wk", (e, kv, d)), ("wv", (e, kv, d)),
+                                 ("wo", (kv * reps, d, e)))}
+    pools = [jax.random.normal(next(keys), (ENTRIES, blocks, block, kv, d))
+             for _ in range(2)]
+    flat = [pool.reshape(ENTRIES, blocks, block * kv, d) for pool in pools]
+    tables = jnp.asarray(np.random.default_rng(60).permutation(
+        np.arange(1, blocks)).reshape(ROWS, TABLE))
+    normed = jax.random.normal(next(keys), (ROWS, 1, e))
+    positions = jnp.asarray([[0], [block + 1], [TABLE * block - 1]])
+
+    def attend(pools, by_row, normed=normed, positions=positions,
+               tables=tables, n_valid=None):
+        return jax.jit(functools.partial(
+            model.paged_attention, config=config, block_size=block,
+            by_row=by_row))(layer, normed, positions, *pools, 1, tables,
+                            n_valid=n_valid)
+
+    (got, *written), (want, *gathered) = attend(flat, True), \
+        attend(flat, False)
+    dense, *dense_written = attend(pools, False)
+    np.testing.assert_allclose(got[1:], want[1:], atol=1e-5)
+    np.testing.assert_array_equal(want, dense)
+    for a, b, c in zip(written, gathered, dense_written):
+        np.testing.assert_array_equal(a, b)
+        np.testing.assert_array_equal(a.reshape(c.shape), c)
+    # A chunk of one row, its last two positions padding.
+    chunk = jax.random.normal(next(keys), (1, 6, e))
+    at = jnp.arange(3, 9)[None]
+    (got, *written), (want, *dense_written) = (
+        attend(p, False, chunk, at, tables[:1], 4) for p in (flat, pools))
+    np.testing.assert_array_equal(got, want)
+    for a, c in zip(written, dense_written):
+        np.testing.assert_array_equal(a.reshape(c.shape), c)
 
 
 #: name -> (query heads, key-value heads, experts, of them a token's)

@@ -88,13 +88,29 @@ def assert_experts_reach_the_kernel_whole(text, stack, calls):
             and not HANDS_ON.search(line.strip())] == []
 
 
-def kv_attention_calls(text) -> list:
-    """The calls of ``ops/paged_kv_attention.py`` in a compiled
-    program's text, by the call's line: a program's table of source
-    files may name ``tests/test_paged_kv_attention.py`` where that file
-    ran first on this worker and a cached trace carries its frames."""
+def kernel_calls(text, kernel: str) -> list:
+    """The calls of one of ``ops/``'s kernels in a compiled program's
+    text, by the call's line: a program's table of source files may name
+    the kernel's own file or ``tests/test_paged_kv_attention.py`` where
+    another file ran first on this worker and a cached trace carries its
+    frames (under ``--dist loadfile`` which files share a worker changes
+    with every file a PR adds: PR 60 met Mistral's programs naming
+    ``grouped_expert_ffn.py`` so)."""
     return [line for line in text.splitlines()
-            if "custom-call(" in line and "paged_kv_attention" in line]
+            if "custom-call(" in line and kernel in line]
+
+
+def pallas_calls(text) -> list:
+    """Every call of a pallas kernel in a compiled program's text,
+    whatever the kernel is called: the chip's compiler spells each
+    ``custom_call_target="tpu_custom_call"``."""
+    return [line for line in text.splitlines()
+            if "custom-call(" in line
+            and 'custom_call_target="tpu_custom_call"' in line]
+
+
+def kv_attention_calls(text) -> list:
+    return kernel_calls(text, "paged_kv_attention")
 
 
 def _sdar(num_layers=2):
