@@ -755,6 +755,9 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
             jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use"))
     check(all(x.dtype == model_config.dtype for x in jax.tree.leaves(params)),
           "serving_params left a weight wider than the compute dtype")
+    # The programs take the tree as an engine holds it; the reference
+    # reads ``params``, which shares every other leaf with it.
+    laid = paged_model.lay_for_serving(params)
 
     # Tables dealt from one shuffled deck, so none is contiguous.
     deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
@@ -787,11 +790,11 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
             n = min(chunk, prefilled[i] - start)
             args = (*chunk_inputs(context, start, n, chunk),
                     jnp.asarray(tables[i:i + 1]))
-            last, pool, _ = prefill(params, pool, *args, np.int32(n),
+            last, pool, _ = prefill(laid, pool, *args, np.int32(n),
                                     np.int32(n - 1))
             # Again through the showing forward: the same values written
             # where they were, every position's logits and choices out.
-            logits, pool, _, routing = shown_chunk(params, pool, *args,
+            logits, pool, _, routing = shown_chunk(laid, pool, *args,
                                                    np.int32(n))
             logits = np.asarray(logits[0], np.float32)
             routing = np.asarray(routing) if sparse else None
@@ -818,9 +821,9 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
         step_tables = np.where(positions[:, None] > 0, tables, 0)
         args = (jnp.asarray(last), jnp.asarray(positions),
                 jnp.asarray(step_tables))
-        nxt, pool, _ = decode(params, pool, *args, jax.random.PRNGKey(0),
+        nxt, pool, _ = decode(laid, pool, *args, jax.random.PRNGKey(0),
                               jnp.zeros((rows,), jnp.float32))
-        logits, pool, _, routing = shown_step(params, pool, *args)
+        logits, pool, _, routing = shown_step(laid, pool, *args)
         logits, nxt = np.asarray(logits[:, 0], np.float32), np.asarray(nxt)
         routing = np.asarray(routing) if sparse else None
         for i in active:
@@ -832,7 +835,7 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
             at[i] += 1
     check(np.isfinite(np.asarray(pool["k"][:, :, 0, 0, 0],
                                  np.float32)).all(), "pool not finite")
-    del pool, prefill, decode, shown_chunk, shown_step
+    del pool, prefill, decode, shown_chunk, shown_step, laid
     gc.collect()
     say("paged", programs="prefill chunks over shuffled tables, then batched "
         "decode steps through the pool", sequences=[len(c) for c in contexts],
@@ -1209,6 +1212,7 @@ def phase_block_logits(config: dict, model_config, model: dict, reference,
     served = paged_model.serving_params(model_config, None, seed)
     if round_weights:
         served = round_mantissa(served, round_weights)
+    laid = paged_model.lay_for_serving(served)   # as an engine holds it
     say("blocks", config=config["name"], layers=model_config.num_layers,
         params=model_config.num_params, rows=rows, table=max_len,
         contexts=[len(c) for c in contexts], round_weights=round_weights,
@@ -1234,7 +1238,7 @@ def phase_block_logits(config: dict, model_config, model: dict, reference,
         for start in range(0, prefilled[i], chunk):
             n = min(chunk, prefilled[i] - start)
             _, pool, _ = prefill(
-                served, pool, *chunk_inputs(context, start, n, chunk),
+                laid, pool, *chunk_inputs(context, start, n, chunk),
                 jnp.asarray(tables[i:i + 1]), np.int32(n), np.int32(n - 1))
 
     # Row i's turn t (its t-th pass) is given at global pass t + i % 3:
@@ -1260,7 +1264,7 @@ def phase_block_logits(config: dict, model_config, model: dict, reference,
                     paged_model.fix_count(size, steps, t) if t < steps
                     else 0, 0, 0.9, tables[i]))
         slots = sorted(at)
-        after, pool, _, key = step(served, pool, jnp.asarray(
+        after, pool, _, key = step(laid, pool, jnp.asarray(
             family.pack_decode_rows(rows, width, active, slots)), key)
         # The same pass through the showing forward, from the same rows
         # (an inactive row: zeros, as the packer leaves it).
@@ -1271,7 +1275,7 @@ def phase_block_logits(config: dict, model_config, model: dict, reference,
             starts[i] = start
         busy = np.isin(np.arange(rows), slots)
         logits, pool, routing = shown(
-            served, pool, jnp.asarray(tokens),
+            laid, pool, jnp.asarray(tokens),
             jnp.asarray(starts + np.arange(size)),
             jnp.asarray(np.where(busy[:, None], tables, 0)),
             jnp.asarray(busy))
@@ -1290,7 +1294,7 @@ def phase_block_logits(config: dict, model_config, model: dict, reference,
                     chosen[i][at[i] + o] = routing[:, i, o]
     check(np.isfinite(np.asarray(pool["k"][:, :, 0, 0, 0],
                                  np.float32)).all(), "pool not finite")
-    del pool, prefill, step, shown
+    del pool, prefill, step, shown, laid
     if round_weights:
         del served
         gc.collect()
@@ -1700,8 +1704,8 @@ def cell_with_rounded_replica(args, started: float) -> int:
     real, built = model.serving_params, []
     real_config = spec.build_model_config
 
-    def rounded_first(config, params=None, seed=0):
-        weights = real(config, params, seed)
+    def rounded_first(config, params=None, seed=0, **how):
+        weights = real(config, params, seed, **how)
         built.append(seed)
         return round_mantissa(weights, args.round_weights) \
             if len(built) == 1 and args.round_weights else weights

@@ -295,11 +295,27 @@ def qkv_of_normed(layer: dict, normed: jax.Array, positions: jax.Array,
     """``qkv_projections`` behind the input norm, for a block that
     norms its own input (``serve/llm_engine/linear.py``). A
     configuration that says ``rotary = False`` (``models/solar_open2.py``:
-    ``use_rope`` false) rotates nothing."""
+    ``use_rope`` false) rotates nothing.
+
+    Takes the projections' weights in either layout, by what the layer
+    holds: ``wq`` [E, H, D], ``wk`` and ``wv`` [E, KV, D], as
+    ``init_params`` lays them (training, ``forward``, the families that
+    bring their own layer dicts: three products), or ``wqkv`` [E, (H + 2
+    KV) D], the three side by side in that order, as
+    ``serve/llm_engine/model.py:lay_for_serving`` lays them for an
+    engine (ONE product, split after it: the same sums of the same
+    products). Norms and rotation come after the split, the same for
+    both."""
     dtype = config.dtype
-    q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
-    k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
-    v = jnp.einsum("ble,ekd->blkd", normed, layer["wv"].astype(dtype))
+    if "wqkv" in layer:
+        h, kv, d = config.num_heads, config.num_kv_heads, config.head_dim
+        qkv = jnp.einsum("ble,ef->blf", normed, layer["wqkv"].astype(dtype))
+        q, k, v = (t.reshape(*t.shape[:2], -1, d) for t in jnp.split(
+            qkv, [h * d, (h + kv) * d], axis=-1))
+    else:
+        q = jnp.einsum("ble,ehd->blhd", normed, layer["wq"].astype(dtype))
+        k = jnp.einsum("ble,ekd->blkd", normed, layer["wk"].astype(dtype))
+        v = jnp.einsum("ble,ekd->blkd", normed, layer["wv"].astype(dtype))
     if config.qk_norm == "head":
         q = rms_norm(q, layer["q_norm"], config.rms_norm_eps)
         k = rms_norm(k, layer["k_norm"], config.rms_norm_eps)

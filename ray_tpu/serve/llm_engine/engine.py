@@ -279,7 +279,17 @@ _UNREAD = "unread"
 
 
 class LLMEngine:
-    """Paged-KV continuous-batching engine (token-in/token-out)."""
+    """Paged-KV continuous-batching engine (token-in/token-out).
+
+    ``params``: the family's ``init_params`` tree (any dtype; cast by
+    ``model.serving_params``), a tree an engine already holds
+    (``engine.params``), or None to build the weights here from
+    ``seed``. The engine HOLDS, and every program takes, the family's
+    laying of it (``Family.lay_params``; the paged and block families:
+    ``model.lay_for_serving``, a layer's ``wq``, ``wk`` and ``wv`` as
+    one ``wqkv [n, E, (H + 2 KV) D]``, which a step reads where it lies
+    in the stack): ``engine.params`` is that tree, each weight on the
+    device once. A caller's arrays are never donated or changed."""
 
     def __init__(self, config=None, params=None, *,
                  max_batch_size: int = 8, max_seq_len: "int | None" = None,
@@ -300,7 +310,10 @@ class LLMEngine:
             # a second to import: on a thread, beside the weights' program.
             threading.Thread(target=importlib.import_module, daemon=True,
                              args=("jax.experimental.pallas.tpu",)).start()
-        self.params = paged_model.serving_params(self.config, params, seed)
+        # In the layout the family's programs take, before anything else
+        # is on the device.
+        self.params = paged_model.serving_params(self.config, params, seed,
+                                                 laid=True)
         self.max_batch = int(max_batch_size)
         self.max_len = int(max_seq_len or self.config.max_seq_len)
         self.block_size = int(block_size or GLOBAL_CONFIG.llm_block_size)

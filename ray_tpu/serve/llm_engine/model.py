@@ -128,6 +128,11 @@ class Family:
     ring_positions: Callable = lambda config, block_size, chunk_len: 0
     recurrent: bool = False
     reads_by_row: bool = False
+    # ``init_params``' tree in the layout the family's programs read
+    # where it lies: pure, applied ONCE to the weights an engine will
+    # hold (``serving_params(laid=True)``; this module's:
+    # ``lay_for_serving``); a tree already so passes through.
+    lay_params: Callable = lambda params: params
     # A busy row on the host: what ``pack_decode_rows`` is given for a
     # request (``row_of(req)``), and what a pass made of it
     # (``advance(req, out)``, ``out`` the row's slice of the program's
@@ -180,7 +185,7 @@ def family(config) -> Family:
 
 
 def serving_params(config, params: "dict | None" = None,
-                   seed: int = 0) -> dict:
+                   seed: int = 0, laid: bool = False) -> dict:
     """The weights an engine serves, held in the compute dtype.
 
     ``params=None`` builds them here from ``PRNGKey(seed)``: weights at
@@ -191,18 +196,73 @@ def serving_params(config, params: "dict | None" = None,
     that dtype changes no result and halves what each step reads.
     A caller that wants a reference rebuilds the same weights by calling
     this with the same seed.
+
+    Returns the layout of the family's ``init_params`` (this module's:
+    ``wq`` [n, E, H, D], ``wk`` and ``wv`` [n, E, KV, D]) and keeps
+    doing so: the benchmark's references, ``chip_smoke.py`` and
+    ``benchmark/sizing.py`` read that one, as training and
+    ``llama.forward`` do. ``laid`` is an ENGINE's call: the same values
+    in the layout the family's programs take (``Family.lay_params``),
+    which is what it holds. Built here, they are laid in the program
+    that makes them: no weight exists twice, and an engine's start has
+    no program more (the laying alone compiles for 0.9 s at Mistral's
+    widths, under the second from which the persistent cache keeps a
+    program: my chip run, PR 61). A caller's tree in the compute dtype
+    is laid over the leaves that change alone, and never donated.
     """
+    lay = family(config).lay_params if laid else lambda tree: tree
+
     def cast(tree):
         return jax.tree.map(lambda x: x.astype(config.dtype), tree)
 
     if params is None:
         init_params = family(config).init_params
         # The key is made IN the program: before it, two more to fetch.
-        return jax.jit(lambda seed: cast(init_params(
-            config, jax.random.PRNGKey(seed))))(np.uint32(seed % 2 ** 32))
+        return jax.jit(lambda seed: lay(cast(init_params(
+            config, jax.random.PRNGKey(seed)))))(np.uint32(seed % 2 ** 32))
     if all(x.dtype == config.dtype for x in jax.tree.leaves(params)):
+        return lay(params)
+    return jax.jit(lambda tree: lay(cast(tree)))(params)
+
+
+#: A layer's three projections in front of the scores, in the order
+#: ``lay_for_serving`` puts them side by side.
+PROJECTIONS = ("wq", "wk", "wv")
+
+
+@jax.jit
+def _side_by_side(*stacked):
+    """[n, E, heads, D] each -> [n, E, all their heads * D]."""
+    n, e = stacked[0].shape[:2]
+    return jnp.concatenate([w.reshape(n, e, -1) for w in stacked], axis=-1)
+
+
+def lay_for_serving(params: dict) -> dict:
+    """``llama.init_params``' tree as an engine holds it: the layers'
+    ``wq`` [n, E, H, D], ``wk`` and ``wv`` [n, E, KV, D] become ONE
+    ``wqkv`` [n, E, (H + 2 KV) D], side by side in that order, which
+    ``llama.qkv_of_normed`` takes by finding it. Pure, and the same
+    values; a tree that is laid already passes through. On arrays it is
+    one program over those three leaves: every other leaf of the result
+    IS the one given, and once the caller lets the three go the device
+    holds each weight once (nothing is donated: no result is the size of
+    an operand, so a donation would alias nothing, and a caller's arrays
+    stay the caller's).
+
+    Why: sliced off the stack, a layer of ``wq`` is tiled over (H, D)
+    and the contracted E lies in no tile, so the chip's compiler copied
+    each of the three into a layout its product could take in front of
+    every product of every step (``constant_dynamic-slice_fusion``: 1.13
+    of an 11.39 ms Mistral step; ledger, PR 60). A layer of ``wqkv`` is
+    tiled over (E, width), as the feed-forward's weights are, and is
+    sliced inside its product's fusion
+    (``tests/test_chip_compile_paged.py``)."""
+    layers = params["layers"]
+    if "wqkv" in layers:
         return params
-    return jax.jit(cast)(params)
+    kept = {k: v for k, v in layers.items() if k not in PROJECTIONS}
+    kept["wqkv"] = _side_by_side(*(layers[name] for name in PROJECTIONS))
+    return {**params, "layers": kept}
 
 
 def _paged_attention_block(layer: dict, x: jax.Array,
@@ -804,6 +864,7 @@ PAGED = Family(
     pack_decode_rows=pack_decode_rows,
     pack_prefill_chunk=pack_prefill_chunk,
     reads_by_row=True,
+    lay_params=lay_for_serving,
 )
 
 

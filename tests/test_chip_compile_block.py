@@ -32,8 +32,9 @@ def _block_step(v5e_chip, width=128):
         return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
             s.shape, dtype or s.dtype, sharding=v5e_chip), tree)
 
-    params = on_chip(jax.eval_shape(lambda: family.init_params(
-        config, jax.random.PRNGKey(0))), config.dtype)
+    # As the engine holds them: the family's laying of ``init_params``.
+    params = on_chip(jax.eval_shape(lambda: family.lay_params(
+        family.init_params(config, jax.random.PRNGKey(0)))), config.dtype)
     cache = on_chip(jax.eval_shape(lambda: family.init_cache(
         config, 1 + rows * table, block, rows, 128)))
     args = (params, cache,

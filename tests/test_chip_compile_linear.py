@@ -98,7 +98,7 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip,
     # a loop's tuple and its elements); no operation has a layer of it.
     assert [line[:200] for line in lines
             if state in line and line not in rule
-            and not HANDS_ON.search(line.strip())] == []
+            and not HANDS_ON.search(line)] == []
     assert not any("f32[64,32,128,128]" in line for line in lines)
     assert "kda_state_update" not in prefill.as_text()
     calls = [line for line in lines
@@ -194,7 +194,7 @@ def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
         assert "output_to_operand_aliasing={{1}: (6, {})}" in line
     assert [line[:200] for line in lines
             if state in line and line not in rule
-            and not HANDS_ON.search(line.strip())] == []
+            and not HANDS_ON.search(line)] == []
     assert "kda_state_update" not in prefill.as_text()
     # The one full layer: the pools whole, the queries grouped, the
     # rows' fresh keys and values; the tables, the lengths, the entry.

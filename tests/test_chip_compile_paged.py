@@ -14,7 +14,7 @@ import pytest
 from v5e_compile import (  # noqa: F401 — the fixtures
     _memory_of, _sdar, assert_experts_reach_the_kernel_whole,
     compiled_kernels, kernel_calls, kv_attention_calls, pallas_calls,
-    v5e_chip, v5e_devices)
+    projection_layers_made, v5e_chip, v5e_devices)
 
 # An expert model's programs hold ``ops/grouped_expert_ffn.py``.
 pytestmark = pytest.mark.usefixtures("compiled_kernels")
@@ -30,8 +30,14 @@ def _lower_paged_step(program, config, batch, block, table, chip,
     engine's decode step or prefill chunk is given
     (``engine.table_widths``; the pool stays ``table`` blocks a row);
     the chunk is the default's length; ``prev``: with the step before's
-    tokens ``[batch]`` as the engine passes them (without: the
-    five-argument call of ``benchmark/sizing.py``)."""
+    tokens ``[batch]`` (a block family's: blocks ``[batch,
+    block_length]``) as the engine passes them (without: the
+    five-argument call of ``benchmark/sizing.py``). The weights'
+    shapes are ``llama.init_params``' for a plain program, which
+    ``chip_smoke.py`` and ``benchmark/sizing.py`` hand that tree, and
+    for an engine's what the ENGINE holds: the family's laying of it
+    (``model.lay_for_serving``: ``wqkv`` for ``wq``, ``wk`` and ``wv``),
+    and the decode program the configuration's family's own."""
     from ray_tpu._private.config import GLOBAL_CONFIG
     from ray_tpu.models import llama, moe
     from ray_tpu.serve.llm_engine import model as paged_model
@@ -39,10 +45,13 @@ def _lower_paged_step(program, config, batch, block, table, chip,
     def on_chip(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
 
+    family = paged_model.family(config)
+    lay = family.lay_params if program.startswith("engine_") \
+        else lambda tree: tree
     params = jax.tree.map(
         lambda s: on_chip(s.shape, config.dtype),
         jax.eval_shape(
-            lambda: llama.init_params(config, jax.random.PRNGKey(0))))
+            lambda: lay(llama.init_params(config, jax.random.PRNGKey(0)))))
     pool_shape = (config.num_layers, 1 + batch * table, block,
                   config.num_kv_heads, config.head_dim)
     pool = {"k": on_chip(pool_shape, config.dtype),
@@ -52,10 +61,12 @@ def _lower_paged_step(program, config, batch, block, table, chip,
         stats = on_chip(jax.eval_shape(moe.init_stats).shape)
     chunk = GLOBAL_CONFIG.llm_prefill_chunk
     if program == "engine_decode_step":
-        lowered = paged_model.make_engine_decode_step(config, block).lower(
-            params, pool, on_chip((batch, 3 + (width or table))),
-            on_chip((2,), jnp.uint32), stats,
-            *([on_chip((batch,))] if prev else []))
+        rows = family.pack_decode_rows(batch, width or table, ())
+        before = (batch, config.block_length) if config.block_length \
+            else (batch,)
+        lowered = family.make_engine_decode_step(config, block).lower(
+            params, pool, on_chip(rows.shape), on_chip((2,), jnp.uint32),
+            stats, *([on_chip(before)] if prev else []))
     elif program == "engine_prefill_chunk":
         lowered = paged_model.make_engine_prefill_chunk(
             config, block, chunk).lower(
@@ -189,11 +200,11 @@ def test_the_one_decode_program_reads_by_row_on_v5e(v5e_chip, model,
     had (``[512 | 1024 | 2048, 16, kv, 128]``) in any dtype; the pool is
     updated where it lies and never copied; the temporaries stay under a
     sixteenth of a GiB (Solar's bound, ``test_chip_compile_linear.py``;
-    OLMoE's gathered step held 129 MiB). On the record (``ROADMAP.md``
-    D9): the copy of a layer's ``wq`` sliced off the stacked weights
-    (``constant_dynamic-slice_fusion``, 0.75 ms of a Mistral step; ledger,
-    PR 57) did NOT go with the gathered form: it is the projection's
-    operand, not the read's."""
+    OLMoE's gathered step held 129 MiB). The copy of a layer's ``wq``
+    sliced off the stacked weights did NOT go with the gathered form (it
+    was the projection's operand, not the read's): it went with the
+    layout an engine holds (PR 61,
+    ``test_no_layer_of_the_projections_is_copied_on_v5e``)."""
     import re
 
     config = _mistral_serve() if model == "mistral" else _olmoe(2)
@@ -222,11 +233,39 @@ def test_the_one_decode_program_reads_by_row_on_v5e(v5e_chip, model,
     else:           # no expert layer: no kernel of theirs, by any name
         assert kernel_calls(text, "grouped_expert_ffn") == []
         assert pallas_calls(text) == calls
-    wq_copy = re.search(
-        rf"constant_dynamic-slice_fusion[.\d]* = bf16\[1,{config.hidden_size}"
-        rf",{heads},128\]", text)
-    assert wq_copy is not None, \
-        "the wq copy went: strike it from ROADMAP.md D9 and PERF.md 7 (P1)"
+
+
+@pytest.mark.parametrize("model,program", [
+    ("mistral", "engine_decode_step"), ("mistral", "engine_prefill_chunk"),
+    ("olmoe", "engine_decode_step"), ("sdar", "engine_decode_step")])
+def test_no_layer_of_the_projections_is_copied_on_v5e(v5e_chip, model,
+                                                      program):
+    """On the shapes an ENGINE holds (``model.lay_for_serving`` over
+    ``llama.init_params``: ``wqkv [n, E, (H + 2 KV) D]``), Mistral's
+    decode step and prefill chunk, OLMoE's decode step and SDAR's block
+    pass run no operation that makes a layer of the projections'
+    weights, in any layout: the layer is sliced inside the ONE product's
+    fusion, as the feed-forward's are. Until PR 61 each program held
+    three to five (``constant_dynamic-slice_fusion.6/.7/.8``, ``bf16[1,
+    E, H | KV, 128]`` in ``S(1)``, the chunk's second rewrite of ``wq``
+    and asynchronous slices of ``wk`` and ``wv``: 1.13 ms of an 11.39 ms
+    Mistral step; ledger, PR 60), and on ``init_params``' own shapes,
+    which ``chip_smoke.py`` and ``benchmark/sizing.py`` still lower, it
+    holds them yet: the helper has to see those."""
+    config, rows = {"mistral": (_mistral_serve(), 16),
+                    "olmoe": (_olmoe(2), 16), "sdar": (_sdar(), 32)}[model]
+    lowered, _ = _lower_paged_step(program, config, rows, 16, 128, v5e_chip,
+                                   prev=program == "engine_decode_step")
+    assert projection_layers_made(lowered.compile().as_text(), config) == []
+    if model == "sdar":
+        return
+    # The plain program on the tree ``serving_params`` returns: the same
+    # step with the three weights apart, and the copies the ledger named.
+    plain, _ = _lower_paged_step(program[len("engine_"):], config, rows, 16,
+                                 128, v5e_chip)
+    apart = projection_layers_made(plain.compile().as_text(), config)
+    assert len(apart) >= 3 and any(
+        "constant_dynamic-slice_fusion" in line for line in apart)
 
 
 @pytest.mark.parametrize("width", [32, 64, 128])

@@ -474,6 +474,7 @@ def test_engine_programs_equal_the_plain_programs(family, dtype_name):
 
 def test_engine_serves_a_sparse_model_and_counts_its_experts():
     from ray_tpu.serve.llm_engine import ENGINE_STAT_KEYS, LLMEngine
+    from ray_tpu.serve.llm_engine.model import serving_params
 
     assert set(moe.EXPERT_COUNTERS) <= set(ENGINE_STAT_KEYS)
     cfg = small()
@@ -495,10 +496,13 @@ def test_engine_serves_a_sparse_model_and_counts_its_experts():
         assert stats["expert_choices"] <= stats["expert_peak_choices"]
         assert stats["expert_peak_choices"] // cfg.num_experts <= \
             stats["expert_choices"] // cfg.experts_per_token
+        # The reference reads ``init_params``' layout: the seed's weights
+        # as ``serving_params`` returns them, not the tree the engine laid.
+        weights = serving_params(cfg, None, 0)
         for prompt, output in zip(prompts, outputs):
             row = prompt + output[:-1]
             want = np.asarray(reference.forward(
-                engine.params, jnp.asarray([row], jnp.int32),
+                weights, jnp.asarray([row], jnp.int32),
                 hf_keys(cfg))[0])[len(prompt) - 1:]
             assert output == [int(r.argmax()) for r in want]
     finally:

@@ -10,6 +10,7 @@ would refuse (a slice off the tiling, too much VMEM), which interpret
 mode never notices. Nothing runs, so these say nothing about results or
 times. Skipped where the topology cannot be described."""
 
+import math
 import os
 import re
 
@@ -85,7 +86,39 @@ def assert_experts_reach_the_kernel_whole(text, stack, calls):
     tensor = re.compile(rf"\[(\d+,)?{e},({h},{m}|{m},{h})\]")
     assert [line[:200] for line in lines
             if tensor.search(line) and line not in kernel
-            and not HANDS_ON.search(line.strip())] == []
+            and not HANDS_ON.search(line)] == []
+
+
+def projection_layers_made(text, config) -> list:
+    """The instructions of a compiled serving program that MAKE a layer
+    of the stacked query, key and value weights, in any layout: outside
+    every fused computation (the entry, the layers' loop: what the
+    chip runs as an operation of its own), a result in bfloat16 with the
+    hidden size among its dimensions and as many elements as a layer of
+    ``wq``, of ``wk`` or of the three side by side. A layer of ``wo``
+    has ``wq``'s count and is told by its order ([H, D, E]: its
+    asynchronous slice is the one such line these programs hold); what
+    only hands a buffer on, or names it anew (``bitcast``), makes
+    nothing. A layer sliced INSIDE its product's fusion, as the
+    feed-forward's weights are, has no line here."""
+    e, d = config.hidden_size, config.head_dim
+    h, kv = config.num_heads, config.num_kv_heads
+    sizes = {e * heads * d for heads in (h, kv, h + 2 * kv)}
+    fused = set(re.findall(r"calls=%([\w\-.]+)", text))
+    made, inside = [], None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w\-.]+) \(", line)
+        if head:
+            inside = head.group(2)
+        result = re.match(r"\s*(ROOT )?%[\w\-.]+ = bf16\[([\d,]+)\]\S* "
+                          r"([\w\-]+)\(", line)
+        if inside in fused or not result or result.group(3) == "bitcast" \
+                or HANDS_ON.search(line):
+            continue
+        dims = [int(n) for n in result.group(2).split(",") if n != "1"]
+        if e in dims and math.prod(dims) in sizes and dims != [h, d, e]:
+            made.append(line.strip()[:200])
+    return made
 
 
 def kernel_calls(text, kernel: str) -> list:
