@@ -9,32 +9,35 @@ capability of the TPU build. Design per the pallas guide
   in VMEM and streams k/v tiles from the per-(b,h) VMEM block with
   online softmax (running max/denominator) — O(block) VMEM, no [L, L]
   scores materialized in HBM. Also emits the per-row logsumexp (LSE)
-  residual for the backward.
+  residual for the backward, as a lane-dense row ``f32[BH, 1, L]``.
 - backward: two fused kernels using the saved LSE (no online softmax
   needed — probabilities are recomputed exactly as exp(s - lse)):
   * dq kernel, grid (batch*heads, L/block_q): for one q tile, loop over
     k tiles at-or-left-of the diagonal accumulating
-    dq += (p ∘ (dO·Vᵀ - D)) · K.
-  * dk/dv kernel, grid (batch*heads, L/block_k): for one k tile, loop
-    over q tiles at-or-below the diagonal accumulating
-    dv += pᵀ·dO and dk += (p ∘ (dO·Vᵀ - D))ᵀ · Q.
-  D = rowsum(dO ∘ O) is recomputed per q tile from the O residual —
-  cheaper than a third pass or an HBM round-trip.
+    dq += (p ∘ (dO·Vᵀ - D)) · K. It forms D = rowsum(dO ∘ O) for its q
+    tile, once a row, and hands it on as a second lane-dense row.
+  * dk/dv kernel, grid (batch*heads, L/block_k), in the keys' own
+    orientation: for one k tile, loop over q tiles at-or-below the
+    diagonal with sᵀ = K·Qᵀ, pᵀ = exp(sᵀ - lse), accumulating
+    dv += pᵀ·dO and dk += (pᵀ ∘ (V·dOᵀ - D)) · Q. lse and D are rows
+    there as they lie, and no score-sized tile is transposed.
 - matmul operands stay bf16 (MXU native) with
   preferred_element_type=f32 accumulation; softmax statistics are f32.
 - causal programs stop their k loop at the diagonal (work ∝ L²/2), and
-  the dk/dv kernel starts its q loop there.
+  the dk/dv kernel starts its q loop there. Only the blocks the
+  diagonal crosses are masked (``_k_block_bounds``, ``_crossed``): the
+  blocks wholly on the visible side run with no iota, compare or select.
 
 On the CPU platform (tests / virtual mesh) the kernels run in interpret
 mode; on every other backend they are compiled, and a test or a
 rehearsal that wants interpret mode elsewhere passes ``interpret=True``.
 ``_blockwise_reference`` remains as the correctness oracle for tests.
 
-The k/v blocks of the forward and dq kernels, and the q/o/dO/lse blocks
-of the dk/dv kernel, are whole-sequence blocks held in VMEM, so the
-sequence length a call can take is bounded: ``check_vmem_fit`` refuses a
-shape beyond the bound at trace time (interpret mode never notices, the
-chip's compiler says RESOURCE_EXHAUSTED).
+The k/v blocks of the forward and dq kernels, and the q/dO blocks and
+lse/D rows of the dk/dv kernel, are whole-sequence blocks held in VMEM,
+so the sequence length a call can take is bounded: ``check_vmem_fit``
+refuses a shape beyond the bound at trace time (interpret mode never
+notices, the chip's compiler says RESOURCE_EXHAUSTED).
 """
 
 from __future__ import annotations
@@ -55,7 +58,15 @@ NEG_INF = -1e30
 
 # Mosaic's scoped-VMEM limit for one kernel on a v5e (libtpu 0.0.34).
 VMEM_LIMIT_BYTES = 16 * 2 ** 20
-_TILE_BYTES = 2 ** 19  # the q/o (or k/v, dk/dv) tiles beside the blocks
+_LANES = 128
+
+
+def _beside_the_blocks(head_dim: int) -> int:
+    """What a kernel holds beside its whole-sequence blocks at tiles of
+    512: three score-sized float32 tiles and the q, o, dO and
+    accumulator tiles. Fitted to where the v5e's compiler draws the
+    line (3.3 MiB at heads of 64, 3.6 at 128), not derived."""
+    return 3 * 2 ** 20 + 9 * 2 ** 9 * head_dim
 
 
 def check_vmem_fit(seq_len: int, head_dim: int, dtype,
@@ -63,37 +74,108 @@ def check_vmem_fit(seq_len: int, head_dim: int, dtype,
     """Raise ValueError for a shape whose whole-sequence blocks cannot
     stay resident under ``VMEM_LIMIT_BYTES``.
 
-    Forward (and dq): the k and v blocks. Backward (dk/dv): the q, o
-    and dO blocks with the head padded to a full 128-lane tile, and the
-    lse column, which pads to a lane tile too. All are double-buffered.
-    This counts the kernel's own buffers; XLA also places operands in
-    VMEM as it sees fit, so the chip's compiler draws the real line a
-    little to either side. Checked by deviceless compiles for a v5e in
-    bf16 at head widths 64 and 128 (tests/test_chip_compile.py holds
-    both sides): what it refuses at 4 query groups per kv head fails to
-    compile, L=8192 at 1 or 2 groups is refused though it would still
-    compile, L=6144 at 4 groups is admitted and the compiler refuses it.
+    Forward (and dq): the k and v blocks. Backward (dk/dv): the q and dO
+    blocks and the lse and delta rows, which are lane-dense (4 bytes a
+    position each). A head narrower than 128 pads to a full lane tile;
+    all are double-buffered. This counts the kernel's own buffers; XLA
+    also places operands in VMEM as it sees fit, so the chip's compiler
+    draws the real line a little to either side. Checked by deviceless
+    compiles for a v5e in bf16 (tests/test_chip_compile.py holds both
+    sides at heads of 128): L=12288 is admitted and compiles, forward
+    and backward, at heads of 64 and of 128 and at 1, 2 or 4 query
+    groups per kv head; L=12800 is refused and the compiler refuses it
+    at heads of 128 (the dq kernel), at heads of 64 it is admitted and
+    compiles and 13312 is refused by both. A forward alone is refused a
+    tile or two before the compiler would (13312 at heads of 128).
     Streaming these blocks lifts the bound (ROADMAP S2).
     """
     itemsize = jnp.dtype(dtype).itemsize
+    width = max(head_dim, _LANES)
     if backward:
-        row = 2 * (3 * max(head_dim, 128) * itemsize + 128 * 4)
-        what = "q, o, dO and lse blocks of the dk/dv kernel"
+        row = 2 * (2 * width * itemsize + 2 * 4)
+        what = "q and dO blocks and lse and delta rows of the dk/dv kernel"
     else:
-        row = 2 * 2 * head_dim * itemsize
+        row = 2 * 2 * width * itemsize
         what = "k and v blocks"
-    need = seq_len * row + _TILE_BYTES
+    beside = _beside_the_blocks(head_dim)
+    need = seq_len * row + beside
     if need > VMEM_LIMIT_BYTES:
         raise ValueError(
             f"flash_attention: sequence length {seq_len} at head width "
             f"{head_dim} ({jnp.dtype(dtype).name}) keeps about "
-            f"{need / 2 ** 20:.1f} MiB of whole-sequence {what} "
+            f"{need / 2 ** 20:.1f} MiB of whole-sequence {what} and tiles "
             f"resident, over the {VMEM_LIMIT_BYTES // 2 ** 20} MiB VMEM "
             f"limit of one kernel; the longest sequence this shape can "
-            f"take is {(VMEM_LIMIT_BYTES - _TILE_BYTES) // row}")
+            f"take is {(VMEM_LIMIT_BYTES - beside) // row}")
 
 
-# ------------------------------------------------------------------ forward
+# ------------------------------------------------------------------ kernels
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T: the matrix unit reads b as it lies
+
+
+def _dot_nt(a, b):
+    return lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _as_row(column):
+    """f32 [n, 1] -> [1, n]: one transpose of a lane tile's width, made
+    once a q tile (never a block)."""
+    n = column.shape[0]
+    return jnp.broadcast_to(column, (n, _LANES)).T[:1]
+
+
+def _as_column(row):
+    """f32 [1, n] -> [n, 1], the same way."""
+    n = row.shape[1]
+    return jnp.broadcast_to(row, (_LANES, n)).T[:, :1]
+
+
+def _positions(tile, row0, col0):
+    """Positions of a score tile's rows, [n, 1], and columns, [1, m],
+    from those of its first row and column."""
+    rows = row0 + lax.broadcasted_iota(jnp.int32, (tile.shape[0], 1), 0)
+    cols = col0 + lax.broadcasted_iota(jnp.int32, (1, tile.shape[1]), 1)
+    return rows, cols
+
+
+def _crossed(body, carry, first, end, count):
+    """The blocks [first, end) the diagonal crosses, masked. Where their
+    number is known at trace time (``count``: the tiles of one side
+    divide the other's) they are straight-line code, which the chip's
+    scheduler overlaps with the kernel's head and tail: a loop of ONE
+    iteration cost 0.3 to 0.5 us more than the block itself (v5e,
+    PERF.md section 6, PR 64). A loop otherwise."""
+    masked = functools.partial(body, masked=True)
+    if count is None:
+        return lax.fori_loop(first, end, masked, carry)
+    for t in range(count):
+        carry = masked(first + t, carry)
+    return carry
+
+
+def _whole_tiles(inner: int, outer: int):
+    """How many tiles of ``inner`` make one of ``outer``, None if not a
+    whole number."""
+    return outer // inner if outer % inner == 0 else None
+
+
+def _k_block_bounds(qi, block_q: int, block_k: int, causal: bool,
+                    seq_len: int):
+    """(unmasked, end, count) for the q tile ``qi``: k blocks
+    [0, unmasked) lie wholly at or left of the tile's FIRST query and
+    need no mask, blocks [unmasked, end) reach up to its last query and
+    are masked (``count`` of them where that is known at trace time);
+    those beyond are not visited. From positions, not from equal
+    indices: block_q and block_k may differ."""
+    if not causal:
+        return seq_len // block_k, seq_len // block_k, 0
+    first = qi * block_q
+    end = lax.div(first + block_q + block_k - 1, block_k)
+    count = _whole_tiles(block_k, block_q)
+    if count is None:
+        return lax.div(first + 1, block_k), end, None
+    return end - count, end, count
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
@@ -102,26 +184,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
     q = q_ref[0]                                      # [bq, D] bf16
     d = q.shape[-1]
 
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-
-    if causal:
-        # Only k blocks at or left of the diagonal.
-        num_k_blocks = lax.div(qi * block_q, block_k) + pl.cdiv(
-            block_q, block_k)
-        num_k_blocks = jnp.minimum(num_k_blocks, seq_len // block_k)
-    else:
-        num_k_blocks = seq_len // block_k
-
-    def body(j, carry):
+    def body(j, carry, masked):
         m_prev, l_prev, acc = carry
         k = k_ref[0, pl.ds(j * block_k, block_k), :]   # bf16
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        # bf16 × bf16 on the MXU, f32 accumulation; scale applied to the
+        # bf16 x bf16 on the MXU, f32 accumulation; scale applied to the
         # f32 result (not the bf16 operand) to keep softmax numerics.
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            k_pos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
+        s = _dot_nt(q, k) * scale
+        if masked:
+            q_pos, k_pos = _positions(s, qi * block_q, j * block_k)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -131,17 +202,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q: int,
                                     preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
-    m0 = jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32)
-    l0 = jnp.zeros((block_q, 1), dtype=jnp.float32)
-    acc0 = jnp.zeros((block_q, d), dtype=jnp.float32)
-    m_fin, l_fin, acc = lax.fori_loop(0, num_k_blocks, body, (m0, l0, acc0))
+    carry = (jnp.full((block_q, 1), NEG_INF, dtype=jnp.float32),
+             jnp.zeros((block_q, 1), dtype=jnp.float32),
+             jnp.zeros((block_q, d), dtype=jnp.float32))
+    unmasked, end, count = _k_block_bounds(qi, block_q, block_k, causal,
+                                           seq_len)
+    carry = lax.fori_loop(0, unmasked,
+                          functools.partial(body, masked=False), carry)
+    if causal:
+        carry = _crossed(body, carry, unmasked, end, count)
+    m_fin, l_fin, acc = carry
     l_safe = jnp.maximum(l_fin, 1e-30)
     o_ref[0] = (acc / l_safe).astype(o_ref.dtype)
-    lse_ref[0, :, 0] = (m_fin + jnp.log(l_safe))[:, 0]
+    lse_ref[0] = _as_row(m_fin + jnp.log(l_safe))
 
 
 def _fit_block(requested: int, seq_len: int) -> int:
-    """Largest divisor of seq_len ≤ requested — the grid and k-loop use
+    """Largest divisor of seq_len <= requested: the grid and k-loop use
     exact tiling, so a non-dividing block would silently drop tail rows/
     keys. Correctness over tile-shape preference."""
     b = min(requested, seq_len)
@@ -158,7 +235,8 @@ def _specs(shapes_and_maps, interpret):
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                interpret: bool):
-    """q/k/v: [BH, L, D] → (o [BH, L, D], lse [BH, L, 1] f32)."""
+    """q/k/v: [BH, L, D] -> (o [BH, L, D], lse [BH, 1, L] f32: a row a
+    head, lane-dense in HBM and in the kernels)."""
     bh, seq_len, d = q.shape
     block_q = _fit_block(block_q, seq_len)
     block_k = _fit_block(block_k, seq_len)
@@ -173,7 +251,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
     ], interpret)
     out_specs = _specs([
         ((1, block_q, d), lambda b, i: (b, i, 0)),
-        ((1, block_q, 1), lambda b, i: (b, i, 0)),
+        ((1, 1, block_q), lambda b, i: (b, 0, i)),
     ], interpret)
     return pl.pallas_call(
         kernel,
@@ -182,7 +260,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         out_specs=out_specs,
         out_shape=[
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((bh, seq_len, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, 1, seq_len), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
@@ -192,91 +270,99 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 # ----------------------------------------------------------------- backward
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, *,
-                   block_q: int, block_k: int, scale: float, causal: bool,
-                   seq_len: int):
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref,
+                   delta_ref, *, block_q: int, block_k: int, scale: float,
+                   causal: bool, seq_len: int):
     qi = pl.program_id(1)
     q = q_ref[0]                                       # [bq, D] bf16
     do = do_ref[0]                                     # [bq, D] bf16
     o = o_ref[0]
-    lse = lse_ref[0]                                   # [bq, 1] f32
+    lse = _as_column(lse_ref[0])                       # [bq, 1] f32
     d = q.shape[-1]
 
-    # D_i = rowsum(dO ∘ O), f32.
+    # D_i = rowsum(dO * O), f32: formed here, once a row, and handed to
+    # the dk/dv kernel as a row.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)            # [bq, 1]
+    delta_ref[0] = _as_row(delta)
 
-    q_pos = qi * block_q + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-
-    if causal:
-        num_k_blocks = lax.div(qi * block_q, block_k) + pl.cdiv(
-            block_q, block_k)
-        num_k_blocks = jnp.minimum(num_k_blocks, seq_len // block_k)
-    else:
-        num_k_blocks = seq_len // block_k
-
-    def body(j, dq_acc):
+    def body(j, dq_acc, masked):
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            k_pos = j * block_k + lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1)
+        s = _dot_nt(q, k) * scale
+        if masked:
+            q_pos, k_pos = _positions(s, qi * block_q, j * block_k)
             s = jnp.where(q_pos >= k_pos, s, NEG_INF)
         p = jnp.exp(s - lse)                           # [bq, bk] f32
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+        dp = _dot_nt(do, v)
         ds = p * (dp - delta)                          # [bq, bk] f32
         return dq_acc + jnp.dot(ds.astype(k.dtype), k,
                                 preferred_element_type=jnp.float32)
 
-    dq0 = jnp.zeros((block_q, d), dtype=jnp.float32)
-    dq = lax.fori_loop(0, num_k_blocks, body, dq0)
+    dq = jnp.zeros((block_q, d), dtype=jnp.float32)
+    unmasked, end, count = _k_block_bounds(qi, block_q, block_k, causal,
+                                           seq_len)
+    if causal:
+        # First (a sum's order is free): straight-line code here shares
+        # its stretch with the relayouts above, which a loop would not
+        # hide (dq 3.05 -> 2.81 ms a layer at 4k, PERF.md 6, PR 64; in
+        # dk/dv, whose head is light, the crossed blocks last LOST).
+        dq = _crossed(body, dq, unmasked, end, count)
+    dq = lax.fori_loop(0, unmasked, functools.partial(body, masked=False),
+                       dq)
     dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dk_ref,
+def _bwd_dkv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dk_ref,
                     dv_ref, *, block_q: int, block_k: int, scale: float,
                     causal: bool, seq_len: int):
+    """In the keys' orientation: scores, probabilities and their
+    gradients are [bk, bq] tiles, which the two accumulating products
+    read as they lie; lse and delta come in as rows."""
     ki = pl.program_id(1)
     k = k_ref[0]                                       # [bk, D] bf16
     v = v_ref[0]
     d = k.shape[-1]
 
-    k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-
-    num_q_blocks = seq_len // block_q
-    if causal:
-        # q blocks strictly left of this k tile never attend to it.
-        first_q_block = lax.div(ki * block_k, block_q)
-    else:
-        first_q_block = 0
-
-    def body(i, carry):
+    def body(i, carry, masked):
         dk_acc, dv_acc = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :]
-        do = do_ref[0, pl.ds(i * block_q, block_q), :]
-        o = o_ref[0, pl.ds(i * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(i * block_q, block_q), :]   # [bq, 1] f32
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = i * block_q + lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse)                           # [bq, bk] f32
-        pt = p.astype(do.dtype).T                      # [bk, bq]
-        dv_acc = dv_acc + jnp.dot(pt, do,
+        rows = pl.ds(i * block_q, block_q)
+        q = q_ref[0, rows, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, :, rows]                      # [1, bq] f32
+        delta = delta_ref[0, :, rows]
+        st = _dot_nt(k, q) * scale                     # [bk, bq] f32
+        if masked:
+            k_pos, q_pos = _positions(st, ki * block_k, i * block_q)
+            st = jnp.where(q_pos >= k_pos, st, NEG_INF)
+        pt = jnp.exp(st - lse)
+        dv_acc = dv_acc + jnp.dot(pt.astype(do.dtype), do,
                                   preferred_element_type=jnp.float32)
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1, keepdims=True)        # [bq, 1]
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)        # [bq, bk]
-        dk_acc = dk_acc + jnp.dot(ds.T, q,
+        dpt = _dot_nt(v, do)
+        dst = (pt * (dpt - delta)).astype(q.dtype)
+        dk_acc = dk_acc + jnp.dot(dst, q,
                                   preferred_element_type=jnp.float32)
         return dk_acc, dv_acc
 
-    dk0 = jnp.zeros((block_k, d), dtype=jnp.float32)
-    dv0 = jnp.zeros((block_k, d), dtype=jnp.float32)
-    dk, dv = lax.fori_loop(first_q_block, num_q_blocks, body, (dk0, dv0))
+    carry = (jnp.zeros((block_k, d), dtype=jnp.float32),
+             jnp.zeros((block_k, d), dtype=jnp.float32))
+    num_q_blocks = seq_len // block_q
+    unmasked = 0
+    if causal:
+        # q tiles before ``first`` never attend to this k tile; tiles
+        # [first, unmasked) hold a query before its last key and are
+        # masked; from ``unmasked`` on every query sees every key.
+        first = lax.div(ki * block_k, block_q)
+        count = _whole_tiles(block_q, block_k)
+        if count is None:
+            unmasked = jnp.minimum(
+                lax.div((ki + 1) * block_k + block_q - 2, block_q),
+                num_q_blocks)
+        else:
+            unmasked = first + count
+        carry = _crossed(body, carry, first, unmasked, count)
+    dk, dv = lax.fori_loop(unmasked, num_q_blocks,
+                           functools.partial(body, masked=False), carry)
     dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -291,18 +377,19 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, block_q: int,
               seq_len=seq_len)
 
     full = ((1, seq_len, d), lambda b, i: (b, 0, 0))
-    full_lse = ((1, seq_len, 1), lambda b, i: (b, 0, 0))
+    full_row = ((1, 1, seq_len), lambda b, i: (b, 0, 0))
     q_tile = ((1, block_q, d), lambda b, i: (b, i, 0))
-    q_lse = ((1, block_q, 1), lambda b, i: (b, i, 0))
+    q_row = ((1, 1, block_q), lambda b, i: (b, 0, i))
     k_tile = ((1, block_k, d), lambda b, i: (b, i, 0))
 
-    dq = pl.pallas_call(
+    dq, delta = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
         grid=(bh, seq_len // block_q),
-        in_specs=_specs([q_tile, full, full, q_tile, q_lse, q_tile],
+        in_specs=_specs([q_tile, full, full, q_tile, q_row, q_tile],
                         interpret),
-        out_specs=_specs([q_tile], interpret)[0],
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=_specs([q_tile, q_row], interpret),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(lse.shape, jnp.float32)],
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, o, lse, do)
@@ -310,14 +397,14 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, block_q: int,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kw),
         grid=(bh, seq_len // block_k),
-        in_specs=_specs([full, k_tile, k_tile, full, full_lse, full],
+        in_specs=_specs([full, k_tile, k_tile, full_row, full_row, full],
                         interpret),
         out_specs=_specs([k_tile, k_tile], interpret),
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=interpret,
         name="flash_bwd_dkv",
-    )(q, k, v, o, lse, do)
+    )(q, k, v, lse, delta, do)
     return dq, dk, dv
 
 

@@ -11,10 +11,13 @@ chip, where the compile cache lives, no CPU fallback on the measuring
 path.
 """
 
+import contextlib
 import dataclasses
 import importlib
+import io
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -48,7 +51,9 @@ def _flash_grad(seq, heads, kv_heads, head_dim, chip):
 @pytest.mark.parametrize("seq,heads,kv_heads,head_dim", [
     (2048, 16, 8, 64),     # short heads of 64: half a lane tile
     (2048, 32, 32, 128),   # Llama-2-7B widths: chip_smoke.py's train phase
-    (4096, 32, 8, 128),    # Llama-3-8B widths at the longest admitted 4k
+    (4096, 32, 8, 128),    # Llama-3-8B widths at the train cells' 4k
+    (8192, 32, 8, 128),    # and at the llama3_8b preset's own max_seq_len
+    (12288, 32, 8, 128),   # the longest the rule admits in tiles of 512
 ])
 def test_flash_kernels_compile_for_v5e(v5e_chip, seq, heads, kv_heads,
                                        head_dim):
@@ -59,22 +64,27 @@ def test_flash_kernels_compile_for_v5e(v5e_chip, seq, heads, kv_heads,
 
 
 @pytest.mark.parametrize("side", ["rule", "compiler"])
-def test_flash_backward_refused_at_8k(v5e_chip, monkeypatch, side):
-    """L=8192 at 32/8 heads of 128 (the llama3_8b preset's own
-    max_seq_len): the dk/dv kernel's whole-sequence blocks do not fit.
-    The rule refuses it at trace time, by name; and with the rule out
-    of the way the chip's compiler refuses it too, so the rule is not
-    refusing something that would have worked."""
+def test_flash_backward_refused_beyond_12k(v5e_chip, monkeypatch, side):
+    """L=12800 at 32/8 heads of 128, one tile of 512 past the longest
+    admitted length (12288, which compiles above): the whole-sequence k
+    and v blocks of the forward and dq kernels, and the q and dO blocks
+    of dk/dv, no longer fit beside the score tiles. The rule refuses it
+    at trace time, by name; and with the rule out of the way the chip's
+    compiler refuses it too, so the rule is not refusing something that
+    would have worked. (8192 was the bound while dk/dv kept o and a
+    lane-padded lse resident too: PR 64.)"""
     if side == "rule":
-        fn, shapes = _flash_grad(8192, 32, 8, 128, v5e_chip)
+        fn, shapes = _flash_grad(12800, 32, 8, 128, v5e_chip)
         with pytest.raises(ValueError) as info:
             fn.lower(*shapes)
         message = str(info.value)
-        assert "8192" in message and "128" in message
-        assert "16 MiB" in message and "6348" in message
+        assert "12800" in message and "128" in message
+        assert "16 MiB" in message and "12736" in message
+        with pytest.raises(ValueError, match="dk/dv kernel.*12540"):
+            fa.check_vmem_fit(12800, 128, jnp.bfloat16, backward=True)
         return
     monkeypatch.setattr(fa, "check_vmem_fit", lambda *a, **k: None)
-    fn, shapes = _flash_grad(8192, 32, 8, 128, v5e_chip)
+    fn, shapes = _flash_grad(12800, 32, 8, 128, v5e_chip)
     with pytest.raises(Exception, match="(?i)vmem"):
         fn.lower(*shapes).compile()
 
@@ -95,36 +105,102 @@ def test_fused_rms_norm_compiles_for_v5e(v5e_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("cell, ceiling_gib", [
-    ("train-4k-1chip", 14.2), ("train-4k-fsdp2tp2", 14.9)])
-def test_train_step_fits_a_v5e_with_the_attention_kept(
-        v5e_devices, monkeypatch, capsys, cell, ceiling_gib):
-    """The two train configurations' whole steps, at the program's
-    default remat_policy, for the described chips: arguments plus
-    temporaries 14.00 GiB on one chip and 14.71 a chip on four (PR 41;
-    13.76 and 13.25 under "full") of the 15.75 a chip gives. A PR that
-    adds to what the layer scan keeps sees the memory here before the
-    chip does; the ceilings are those figures and a margin of 0.2."""
+@pytest.fixture(scope="module")
+def sized_step(v5e_devices):
+    """cell -> (the lines benchmark/sizing.py prints for a train cell,
+    the text of its compiled step), each compiled once a module for the
+    described chips. The kernels see the CPU backend during such a
+    compile, so ``interpret_kernels`` is steered here."""
     from benchmark import sizing, spec
     from ray_tpu._private import jax_compat
 
-    # The kernels see the CPU backend during such a compile.
-    monkeypatch.setattr(jax_compat, "interpret_kernels", lambda: False)
-    sizing.size_train(spec.load_cell(cell), v5e_devices)
-    lines = [json.loads(line)
-             for line in capsys.readouterr().out.splitlines()]
+    sized = {}
+
+    def size(cell):
+        if cell in sized:
+            return sized[cell]
+        texts, printed = {}, io.StringIO()
+        report = sizing.report
+
+        def keep_text(name, program, compiled):
+            texts[program] = compiled.as_text()
+            report(name, program, compiled)
+
+        with pytest.MonkeyPatch.context() as patch, \
+                contextlib.redirect_stdout(printed):
+            patch.setattr(jax_compat, "interpret_kernels", lambda: False)
+            patch.setattr(sizing, "report", keep_text)
+            sizing.size_train(spec.load_cell(cell), v5e_devices)
+        sized[cell] = ([json.loads(line)
+                        for line in printed.getvalue().splitlines()],
+                       texts["step"])
+        return sized[cell]
+
+    return size
+
+
+@pytest.mark.parametrize("cell, ceiling_gib", [
+    ("train-4k-1chip", 14.0), ("train-4k-fsdp2tp2", 14.85)])
+def test_train_step_fits_a_v5e_with_the_attention_kept(
+        sized_step, cell, ceiling_gib):
+    """The two train configurations' whole steps, at the program's
+    default remat_policy, for the described chips: arguments plus
+    temporaries 13.79 GiB on one chip and 14.64 a chip on four (PR 64,
+    whose lane-dense lse took 0.22 and 0.07 off PR 41's 14.00 and
+    14.71; 13.17 and 13.07 under "full") of the 15.75 a chip gives. A
+    PR that adds to what the layer scan keeps sees the memory here
+    before the chip does; the ceilings are those figures and a margin of
+    0.2."""
+    lines, _ = sized_step(cell)
     step = next(x for x in lines if x.get("program") == "step")
     assert step["arguments_plus_temporaries_gib"] < ceiling_gib, step
-    assert step["arguments_plus_temporaries_gib"] > 13.8, (
+    assert step["arguments_plus_temporaries_gib"] > 13.5, (
         "under what \"full\" takes: is the default policy in force?", step)
     assert lines[-1]["has_tpu_custom_call"], lines[-1]
 
 
+OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def test_train_step_keeps_the_mlp_input_in_fast_memory(sized_step):
+    """What a change to the layer scan must not lose (PERF.md 7 (I),
+    ROADMAP S2 (d')): in the one-chip step the backward's two
+    weight-gradient products of gate and up (the fusions that write the
+    stacked ``f32[layers, hidden, intermediate]`` gradient) read the
+    MLP's 64 MiB input from fast memory, ``S(1)`` on the operand in the
+    compiled text; on the chip its loss cost 3 to 6 ms a step (PR 41).
+    And the kernels' lse crosses the step as lane-dense rows: no float32
+    buffer of four or more dimensions ends ``,4096,1]`` (the column the
+    chip padded to 128 lanes, 128 MiB a scan body, until PR 64)."""
+    _, text = sized_step("train-4k-1chip")
+    shapes = {}
+    for line in text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ", line)
+        if made:
+            shapes.setdefault(made.group(1), made.group(2))
+    products = [line for line in text.splitlines()
+                if re.match(r"\s*%[\w.\-]+ = f32\[2,4096,14336\]\S* fusion\(",
+                            line) and "kind=kOutput" in line]
+    assert len(products) == 2, products
+    for line in products:
+        operands = OPERAND.findall(line.split(" fusion(", 1)[1].split(")")[0])
+        inputs = [shapes[name] for name in operands
+                  if shapes.get(name, "").startswith("bf16[2,4096,4096]")]
+        assert len(inputs) == 1 and "S(1)" in inputs[0], (inputs, line[:300])
+    assert not re.search(r"f32\[(\d+,){2,}4096,1\]", text)
+    assert re.search(r"f32\[4,16,1,4096\]\{3,2,1,0:T\(1,128\)", text)
+
+
 def test_vmem_rule_admits_the_main_path():
-    for seq, head_dim in ((2048, 64), (2048, 128), (4096, 128)):
+    for seq, head_dim in ((2048, 64), (2048, 128), (4096, 128),
+                          (8192, 128), (12288, 64), (12288, 128)):
         fa.check_vmem_fit(seq, head_dim, jnp.bfloat16)
         fa.check_vmem_fit(seq, head_dim, jnp.bfloat16, backward=True)
-    fa.check_vmem_fit(8192, 128, jnp.bfloat16)  # forward alone still fits
+    # A head of 64 pads to a lane tile: the same blocks, a little less
+    # beside them (the compiler takes 12800 there and refuses 13312).
+    fa.check_vmem_fit(12800, 64, jnp.bfloat16, backward=True)
+    with pytest.raises(ValueError, match="sequence length 13312"):
+        fa.check_vmem_fit(13312, 64, jnp.bfloat16)
     with pytest.raises(ValueError, match="sequence length 16384"):
         fa.check_vmem_fit(16384, 128, jnp.bfloat16)
 

@@ -159,6 +159,60 @@ def test_flash_attention_non_divisible_seq():
     np.testing.assert_allclose(g1, g2, atol=1e-4, rtol=1e-4)
 
 
+def _pallas_calls(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("l, block_q, block_k, h, kvh", [
+    (256, 64, 128, 4, 4),    # four q tiles over two k tiles
+    (256, 128, 64, 4, 4),    # and the other way round
+    (256, 64, 128, 8, 2),    # 4 query heads a key-value head
+    (200, 64, 128, 4, 2),    # _fit_block: 50 and 100
+    (231, 64, 128, 2, 2),    # odd divisors: 33 and 77, three tiles by seven
+    (256, 64, 64, 4, 2),     # equal tiles: the crossed block is straight-line
+    (67, 64, 64, 2, 2),      # a prime length: tiles of ONE position
+])
+def test_flash_attention_over_several_blocks(l, block_q, block_k, h, kvh,
+                                             causal):
+    """Several blocks a row with block_q != block_k: the unmasked and
+    the masked loops of all three kernels both run, and where the
+    diagonal crosses a block is not where the indices are equal. The
+    forward and the three gradients against the plain form; and lse and
+    delta cross the kernels' boundaries as rows."""
+    q, k, v = _qkv(l=l, h=h, kvh=kvh, seed=l + block_q)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=causal, block_q=block_q,
+                               block_k=block_k)
+
+    def plain(q, k, v):
+        k, v = (jnp.repeat(x, h // kvh, axis=2) for x in (k, v))
+        return plain_attention(q, k, v, causal=causal)
+
+    np.testing.assert_allclose(flash(q, k, v), plain(q, k, v), atol=1e-5,
+                               rtol=1e-5)
+    grads = [jax.grad(lambda *a, f=f: jnp.sum(f(*a) ** 2), argnums=(0, 1, 2))
+             for f in (flash, plain)]
+    for got, want in zip(grads[0](q, k, v), grads[1](q, k, v)):
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+    calls = list(_pallas_calls(jax.make_jaxpr(grads[0])(q, k, v).jaxpr))
+    assert len(calls) == 3, calls
+    for eqn in calls:
+        for var in (*eqn.invars, *eqn.outvars):
+            assert not (var.aval.dtype == jnp.float32
+                        and var.aval.shape[-1] == 1), (
+                eqn.params["name"], var.aval)
+    rows = [var.aval.shape for eqn in calls for var in eqn.outvars
+            if var.aval.dtype == jnp.float32 and var.aval.shape[-2:] == (1, l)]
+    assert len(rows) == 2, rows  # the forward's lse, dq's delta
+
+
 # ------------------------------------------------------------ kernel names
 
 
