@@ -491,6 +491,101 @@ def chunk_inputs(context, start: int, n: int, chunk: int):
     return jnp.asarray(tokens), jnp.asarray(positions)
 
 
+def shuffled_tables(rng, rows: int, width: int, contexts, block: int):
+    """A table ``[rows, width]`` a context, dealt a block a turn from one
+    shuffled deck of the pool's blocks, so that none is contiguous."""
+    import numpy as np
+
+    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
+    tables = np.zeros((rows, width), np.int32)
+    for turn in range(width):
+        for i, context in enumerate(contexts):
+            if turn < -(-len(context) // block):
+                tables[i, turn] = deck.pop()
+    return tables
+
+
+def drive_forward(model_config, block: int, chunk: int, served, cache,
+                  contexts, prefilled, tables, steps: int, first_kept,
+                  compared, table_width=lambda i: None):
+    """Every row of the engine busy through its family's ONE forward
+    (``model.Family.forward``), as the engine drives the two programs
+    that wrap it: context ``i`` in row slot ``i``, its first
+    ``prefilled[i]`` positions chunk by chunk (on the first
+    ``table_width(i)`` blocks of its table, as the engine hands a chunk
+    the narrowest width that holds it; the whole where None), then all
+    rows ``steps`` decode steps together through the whole table,
+    teacher-forced. A recurrent state advances with every call, so the
+    forward is run once, showing every position's logits. Returns
+    (position -> logits row of each compared context from
+    ``first_kept[i]`` on, position -> its chosen experts [expert layers,
+    k]: none of a dense model, the cache)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.serve.llm_engine import model as paged_model
+
+    family = paged_model.family(model_config)
+    rows = len(tables)
+
+    def forward(params, cache, tokens, positions, tables, **of_a_chunk):
+        logits, cache, _, routing = family.forward(
+            params, cache, tokens, positions, tables, model_config, block,
+            **of_a_chunk)
+        if routing is not None:
+            # [expert layers, B, T, k], however the family groups them
+            # (the linear family's: by period).
+            routing = routing.reshape(-1, *routing.shape[-3:])
+        return logits, cache, routing
+
+    shown_chunk = jax.jit(
+        lambda params, cache, tokens, positions, table, slot, n_valid:
+        forward(params, cache, tokens, positions, table, slot=slot,
+                n_valid=n_valid), donate_argnums=(1,))
+    shown_step = jax.jit(
+        lambda params, cache, tokens, positions, tables:
+        forward(params, cache, tokens, positions[:, None], tables),
+        donate_argnums=(1,))
+
+    got = [{} for _ in contexts]               # position -> logits row
+    chosen = [{} for _ in contexts]            # position -> [layers, k]
+    for i, context in enumerate(contexts):
+        table = jnp.asarray(tables[i:i + 1, :table_width(i)])
+        for start in range(0, prefilled[i], chunk):
+            n = min(chunk, prefilled[i] - start)
+            logits, cache, routing = shown_chunk(
+                served, cache, *chunk_inputs(context, start, n, chunk),
+                table, np.int32(i), np.int32(n))
+            if i not in compared:
+                continue
+            if routing is not None:
+                routing = np.asarray(routing)
+                for j in range(n):
+                    chosen[i][start + j] = routing[:, 0, j]
+            if start + n > first_kept[i]:
+                logits = np.asarray(logits[0], np.float32)
+                for j in range(n):
+                    if start + j >= first_kept[i]:
+                        got[i][start + j] = logits[j]
+    for step in range(steps):
+        last = np.zeros((rows, 1), np.int32)
+        positions = np.zeros((rows,), np.int32)
+        for i, context in enumerate(contexts):
+            last[i, 0] = context[prefilled[i] + step]
+            positions[i] = prefilled[i] + step
+        logits, cache, routing = shown_step(
+            served, cache, jnp.asarray(last), jnp.asarray(positions),
+            jnp.asarray(tables))
+        logits = np.asarray(logits[:, 0], np.float32)
+        routing = None if routing is None else np.asarray(routing)
+        for i in compared:
+            got[i][int(positions[i])] = logits[i]
+            if routing is not None:
+                chosen[i][int(positions[i])] = routing[:, i, 0]
+    return got, chosen, cache
+
+
 def paged_prefill_logits(config, params, prompt, block_size: int,
                          chunk: int, blocks_per_seq: int):
     """Logits of the first generated position from the engine's own
@@ -759,29 +854,21 @@ def phase_paged_logits(path: str, seed: int, rehearse: bool,
     # reads ``params``, which shares every other leaf with it.
     laid = paged_model.lay_for_serving(params)
 
-    # Tables dealt from one shuffled deck, so none is contiguous.
-    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
-    tables = np.zeros((rows, width), np.int32)
-    for turn in range(width):
-        for i, context in enumerate(contexts):
-            if turn < -(-len(context) // block):
-                tables[i, turn] = deck.pop()
+    tables = shuffled_tables(rng, rows, width, contexts, block)
     pool = PagedKVCache.init_pool(model_config, 1 + rows * width, block)
     prefill = paged_model.make_prefill_chunk(model_config, block)
     decode = paged_model.make_decode_step(model_config, block)
     # The same forward the two programs wrap, showing every position's
     # logits and the experts chosen; the pool donated as they donate it.
+    forward = paged_model.family(model_config).forward
     shown_chunk = jax.jit(
         lambda params, pool, tokens, positions, table, n_valid:
-        paged_model._forward_paged(params, pool, tokens, positions, table,
-                                   model_config, block, n_valid=n_valid),
-        donate_argnums=(1,))
+        forward(params, pool, tokens, positions, table, model_config, block,
+                n_valid=n_valid), donate_argnums=(1,))
     shown_step = jax.jit(
         lambda params, pool, tokens, positions, tables:
-        paged_model._forward_paged(params, pool, tokens, positions[:, None],
-                                   tables, model_config, block,
-                                   by_row=paged_model.PAGED.reads_by_row),
-        donate_argnums=(1,))
+        forward(params, pool, tokens, positions[:, None], tables,
+                model_config, block), donate_argnums=(1,))
 
     got = [[] for _ in contexts]        # (position, logits row)
     chosen = [{} for _ in contexts]     # position -> experts [layers, k]
@@ -987,26 +1074,21 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
                         state_dtype: "str | None" = None) -> None:
     """A hybrid configuration's three caches (``llm_engine/hybrid.py``),
     or a state-space stack's state and pools (``llm_engine/mamba.py``:
-    the same two forwards under the same names), at its real size: every
+    a forward of the same signature), at its real size: every
     row of the engine busy (the probes' lengths, one context nearly as
     long as the table, the rest a few chunks long), prefilled chunk by
     chunk and then decoded together, as the engine drives its two
-    programs; every compared position's logits against the float32
-    reference's full forward. A recurrent state advances with every
-    call, so the forward the two programs wrap is run once, showing
-    every position's logits (the cell's probes hold the programs
-    themselves to the reference)."""
+    programs (``drive_forward``); every compared position's logits
+    against the float32 reference's full forward (the cell's probes hold
+    the programs themselves to the reference)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu._private.config import GLOBAL_CONFIG
-    from ray_tpu.serve.llm_engine import hybrid, mamba
     from ray_tpu.serve.llm_engine import model as paged_model
 
     family = paged_model.family(model_config)
-    # The same two forwards and cache, by name, in either module.
-    programs = mamba if family is mamba.FAMILY else hybrid
     control = round_weights or (state_dtype and f"a state in {state_dtype}")
     engine = config["engine"]
     rows, max_len = engine["max_batch_size"], engine["max_seq_len"]
@@ -1035,60 +1117,24 @@ def phase_hybrid_logits(config: dict, model_config, model: dict, reference,
         contexts=[len(c) for c in contexts], control=control,
         ring=family.ring_positions(model_config, block, chunk),
         device_bytes_in_use=device_bytes())
-    cache = programs.init_cache(model_config, 1 + rows * width, block, rows,
+    cache = family.init_cache(model_config, 1 + rows * width, block, rows,
                               chunk)
     say("hybrid", cache={k: [list(v.shape), str(v.dtype)]
                          for k, v in cache.items()},
         cache_gib=round(sum(v.nbytes for v in cache.values()) / 2 ** 30, 3))
-    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
-    tables = np.zeros((rows, width), np.int32)
-    for turn in range(width):                 # no table is contiguous
-        for i, context in enumerate(contexts):
-            if turn < -(-len(context) // block):
-                tables[i, turn] = deck.pop()
-    shown_chunk = jax.jit(
-        lambda params, cache, tokens, positions, table, slot, n_valid:
-        programs.chunk_forward(params, cache, tokens, positions, table, slot,
-                             n_valid, model_config, block),
-        donate_argnums=(1,))
-    shown_step = jax.jit(
-        lambda params, cache, tokens, positions, tables:
-        programs.decode_forward(params, cache, tokens, positions, tables,
-                              model_config, block), donate_argnums=(1,))
-
-    got = [{} for _ in contexts]               # position -> logits row
-    for i, context in enumerate(contexts):
-        first_kept = prefilled[i] - 1 if i < len(lengths) \
-            else len(context) - tail
-        for start in range(0, prefilled[i], chunk):
-            n = min(chunk, prefilled[i] - start)
-            logits, cache = shown_chunk(
-                served, cache, *chunk_inputs(context, start, n, chunk),
-                jnp.asarray(tables[i:i + 1]), np.int32(i), np.int32(n))
-            if i in compared and start + n > first_kept:
-                logits = np.asarray(logits[0], np.float32)
-                for j in range(n):
-                    if start + j >= first_kept:
-                        got[i][start + j] = logits[j]
-    for step in range(steps):
-        last = np.zeros((rows, 1), np.int32)
-        positions = np.zeros((rows,), np.int32)
-        for i, context in enumerate(contexts):
-            last[i, 0] = context[prefilled[i] + step]
-            positions[i] = prefilled[i] + step
-        logits, cache = shown_step(served, cache, jnp.asarray(last),
-                                   jnp.asarray(positions),
-                                   jnp.asarray(tables))
-        logits = np.asarray(logits[:, 0], np.float32)
-        for i in compared:
-            got[i][int(positions[i])] = logits[i]
+    tables = shuffled_tables(rng, rows, width, contexts, block)
+    got, _, cache = drive_forward(
+        model_config, block, chunk, served, cache, contexts, prefilled,
+        tables, steps, [prefilled[i] - 1 if i < len(lengths)
+                        else len(context) - tail
+                        for i, context in enumerate(contexts)], compared)
     check(all(bool(jnp.isfinite(v.astype(jnp.float32)).all())
               for v in cache.values()), "a cache is not finite")
     # Row ``i`` lies in row slot ``i``: the first layer's state behind
     # each compared context's last position.
     states = {i: np.asarray(cache["ssm"][0, i], np.float32)
               for i in compared} if hasattr(reference, "first_state") else {}
-    del cache, shown_chunk, shown_step
+    del cache
     if round_weights:
         del served
         gc.collect()
@@ -1219,16 +1265,12 @@ def phase_block_logits(config: dict, model_config, model: dict, reference,
         block_length=size, denoising_steps=steps,
         device_bytes_in_use=device_bytes())
     pool = PagedKVCache.init_pool(model_config, 1 + rows * width, block)
-    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
-    tables = np.zeros((rows, width), np.int32)
-    for turn in range(width):                 # no table is contiguous
-        for i, context in enumerate(contexts):
-            if turn < -(-len(context) // block):
-                tables[i, turn] = deck.pop()
+    tables = shuffled_tables(rng, rows, width, contexts, block)
     prefill = paged_model.make_prefill_chunk(model_config, block)
     step = family.make_engine_decode_step(model_config, block)
+
     def show(params, pool, tokens, positions, tables, busy):
-        logits, pool, _, routing = paged_model._forward_paged(
+        logits, pool, _, routing = family.forward(
             params, pool, tokens, positions, tables, model_config, block,
             busy=busy)
         return logits, pool, routing
@@ -1428,7 +1470,6 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
     import numpy as np
 
     from ray_tpu._private.config import GLOBAL_CONFIG
-    from ray_tpu.serve.llm_engine import latent, linear
     from ray_tpu.serve.llm_engine import model as paged_model
     from ray_tpu.serve.llm_engine.engine import table_widths
 
@@ -1465,7 +1506,7 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
     served = paged_model.serving_params(model_config, None, seed)
     if round_weights:
         served = round_mantissa(served, round_weights)
-    cache = (linear if stateful else latent).init_cache(
+    cache = paged_model.family(model_config).init_cache(
         model_config, 1 + rows * width, block, rows, chunk)
     say(name, config=config["name"], layers=model_config.num_layers,
         params=model_config.num_params, rows=rows, table=max_len,
@@ -1473,71 +1514,14 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
         cache={k: [list(v.shape), str(v.dtype)] for k, v in cache.items()},
         cache_gib=round(sum(v.nbytes for v in cache.values()) / 2 ** 30, 3),
         device_bytes_in_use=device_bytes())
-    deck = [int(b) for b in rng.permutation(np.arange(1, 1 + rows * width))]
-    tables = np.zeros((rows, width), np.int32)
-    for turn in range(width):                 # no table is contiguous
-        for i, context in enumerate(contexts):
-            if turn < -(-len(context) // block):
-                tables[i, turn] = deck.pop()
-    def forward(params, cache, tokens, positions, tables, n_valid=None,
-                slot=None):
-        """Either family's forward, a chunk's (``n_valid``, in row slot
-        ``slot``) or a step's; the routing [expert layers, B, T, k]."""
-        if not stateful:
-            return latent.forward(params, cache, tokens, positions, tables,
-                                  model_config, block,
-                                  absorbed=n_valid is None, n_valid=n_valid)
-        logits, cache, counts, routing = linear.forward(
-            params, cache, tokens, positions, tables, model_config, block,
-            slot=slot, n_valid=n_valid)
-        return logits, cache, counts, routing.reshape(-1, *routing.shape[2:])
-
-    shown_chunk = jax.jit(
-        lambda params, cache, tokens, positions, table, n_valid, slot:
-        forward(params, cache, tokens, positions, table, n_valid, slot),
-        donate_argnums=(1,))
-    shown_step = jax.jit(
-        lambda params, cache, tokens, positions, tables:
-        forward(params, cache, tokens, positions[:, None], tables),
-        donate_argnums=(1,))
-
-    def rung(positions: int) -> int:
-        return next(w for w in widths if w * block >= positions)
-
-    got = [{} for _ in contexts]               # position -> logits row
-    chosen = [{} for _ in contexts]            # position -> [layers, k]
-    for i, context in enumerate(contexts):
-        first_kept = len(context) - tail if i in long_rows \
-            else prefilled[i] - 1
-        table = jnp.asarray(tables[i:i + 1, :rung(prefilled[i])])
-        for start in range(0, prefilled[i], chunk):
-            n = min(chunk, prefilled[i] - start)
-            logits, cache, _, routing = shown_chunk(
-                served, cache, *chunk_inputs(context, start, n, chunk),
-                table, np.int32(n), np.int32(i))
-            if i in compared:
-                routing = np.asarray(routing)
-                for j in range(n):
-                    chosen[i][start + j] = routing[:, 0, j]
-                if start + n > first_kept:
-                    logits = np.asarray(logits[0], np.float32)
-                    for j in range(n):
-                        if start + j >= first_kept:
-                            got[i][start + j] = logits[j]
-    for step in range(steps):
-        last = np.zeros((rows, 1), np.int32)
-        positions = np.zeros((rows,), np.int32)
-        for i, context in enumerate(contexts):
-            last[i, 0] = context[prefilled[i] + step]
-            positions[i] = prefilled[i] + step
-        logits, cache, _, routing = shown_step(
-            served, cache, jnp.asarray(last), jnp.asarray(positions),
-            jnp.asarray(tables))
-        logits, routing = np.asarray(logits[:, 0], np.float32), \
-            np.asarray(routing)
-        for i in compared:
-            got[i][int(positions[i])] = logits[i]
-            chosen[i][int(positions[i])] = routing[:, i, 0]
+    tables = shuffled_tables(rng, rows, width, contexts, block)
+    got, chosen, cache = drive_forward(
+        model_config, block, chunk, served, cache, contexts, prefilled,
+        tables, steps, [len(context) - tail if i in long_rows
+                        else prefilled[i] - 1
+                        for i, context in enumerate(contexts)], compared,
+        table_width=lambda i: next(w for w in widths
+                                   if w * block >= prefilled[i]))
     # The pool: the latent family's and Kimi-Linear's ``latent``, or the
     # keys and values of a linear configuration of grouped full layers.
     for part in sorted(set(cache) - {"kda", "conv"}):
@@ -1545,7 +1529,7 @@ def phase_latent_logits(config: dict, model_config, model: dict, reference,
             cache[part])), f"the pool ({part}) is not finite")
     states = {i: np.asarray(cache["kda"][:, i]) for i in long_rows} \
         if stateful else {}
-    del cache, shown_chunk, shown_step
+    del cache
     if round_weights:
         del served
         gc.collect()

@@ -11,7 +11,11 @@ latency-driven controller policy autoscales replicas from the live
 Layout:
 
 - ``kv_cache``  the paged block pool + per-request block tables
-- ``model``     the jitted gather-by-block-table prefill/decode steps
+- ``model``     what a model family is to the engine (``Family``: its
+  weights, its cache and ONE forward), the engine's two jitted programs
+  and their host arrays written once over that forward, and the paged
+  family's forward; ``hybrid``, ``latent``, ``linear``, ``mamba``: a
+  cache and a forward each
 - ``scheduler`` request lifecycle: bounded admission, chunked-prefill
   interleave, preemption on cache pressure, deadline sweep
 - ``engine``    the engine loop + counters (``ENGINE_STAT_KEYS``)

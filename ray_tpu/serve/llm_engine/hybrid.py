@@ -31,27 +31,16 @@ A request's row slot (``EngineRequest.slot``) is its row of the decode
 step and its index into the ring and the state, so the decode step
 reads and writes both where they lie. The layer pattern compiles as two
 scans over periods with the two middle layers between them. The two
-programs are traced under the names of the dense model's
-(``decode_step``, ``prefill_chunk``) and take ONE host array each.
+programs are ``model.py``'s, over ``forward`` here.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 
 from ray_tpu.models import phi4flash as phi
-from ray_tpu.serve.llm_engine.model import (
-    Family,
-    pack_decode_rows,
-    row_beside_zeros,
-    row_tokens,
-    sample_next,
-)
+from ray_tpu.serve.llm_engine.model import Family, row_beside_zeros
 
 F32 = jnp.float32
 
@@ -193,11 +182,21 @@ class _ChunkSteps(_Steps):
             conv.at[li, self.slot].set(c)
 
 
-def forward(params: dict, cache: dict, tokens, steps: _Steps,
-            logits_at=None):
-    """tokens [B, T] -> (logits [B, T, V] float32, or [B, V] of position
-    ``logits_at`` alone; the updated cache)."""
-    config = steps.config
+def forward(params: dict, cache: dict, tokens, positions, tables, config,
+            block_size: int, *, slot=None, n_valid=None, logits_at=None):
+    """tokens and positions [B, T], tables [B, M] -> (logits [B, T, V]
+    float32, or [B, V] of position ``logits_at`` alone; the updated
+    cache; None twice: no experts). Without ``n_valid`` it is a decode
+    step: ``T == 1``, row ``i`` is row slot ``i``, a row at position 0
+    is inactive. With it, one request's chunk in row slot ``slot``, its
+    first ``n_valid`` positions real."""
+    if n_valid is None:
+        steps = _DecodeSteps(config, block_size, cache, positions, tables,
+                             positions > 0)
+    else:
+        valid = jnp.arange(tokens.shape[1])[None, :] < n_valid
+        steps = _ChunkSteps(config, block_size, cache, positions, tables,
+                            valid, slot=slot, n_valid=n_valid)
     eps, half = config.layer_norm_eps, config.num_layers // 2
     # The residual stream is float32 (phi.residual_mlp says why).
     x = params["embed"]["tokens"][tokens].astype(F32)
@@ -255,79 +254,7 @@ def forward(params: dict, cache: dict, tokens, steps: _Steps,
     if logits_at is not None:
         logits = logits[:, 0]
     return logits, {"k": pool_k, "v": pool_v, "win_k": win_k,
-                    "win_v": win_v, "ssm": ssm, "conv": conv}
-
-
-def decode_forward(params, cache, tokens, positions, tables, config,
-                   block_size: int):
-    """tokens [B, 1], positions [B], tables [B, M]; a row at position 0
-    is inactive. Returns (logits [B, 1, V], cache)."""
-    positions = positions[:, None]
-    steps = _DecodeSteps(config, block_size, cache, positions, tables,
-                         positions > 0)
-    return forward(params, cache, tokens, steps)
-
-
-def chunk_forward(params, cache, tokens, positions, table, slot, n_valid,
-                  config, block_size: int, logits_at=None):
-    """tokens and positions [1, C], table [1, M]; the chunk's first
-    ``n_valid`` positions are real. Returns (logits, cache)."""
-    valid = jnp.arange(tokens.shape[1])[None, :] < n_valid
-    steps = _ChunkSteps(config, block_size, cache, positions, table, valid,
-                        slot=slot, n_valid=n_valid)
-    return forward(params, cache, tokens, steps, logits_at)
-
-
-def pack_prefill_chunk(chunk_len: int, width: int, tokens, start: int,
-                       table, slot: int) -> np.ndarray:
-    """The prefill program's host array, int32 ``[3 + 2 * chunk_len +
-    width]``: ``n_valid``, ``last_idx``, the row slot, then the chunk's
-    tokens, their positions and the block table, each zero-padded."""
-    n = len(tokens)
-    chunk = np.zeros((3 + 2 * chunk_len + width,), dtype=np.int32)
-    chunk[0], chunk[1], chunk[2] = n, n - 1, slot
-    chunk[3:3 + n] = tokens
-    chunk[3 + chunk_len:3 + chunk_len + n] = np.arange(start, start + n)
-    chunk[3 + 2 * chunk_len:3 + 2 * chunk_len + len(table)] = table
-    return chunk
-
-
-def make_engine_decode_step(config, block_size: int,
-                            decode_forward=decode_forward):
-    """The ONE decode program, on ``model.pack_decode_rows``' array
-    (row ``i`` is row slot ``i``), the carried sampling key and the step
-    before's tokens ``prev`` (``model.row_tokens``). ``decode_forward``:
-    another family's of this one's signature (``mamba.py``)."""
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
-        key, sub = jax.random.split(key)
-        temps = lax.bitcast_convert_type(rows[:, 2], F32)
-        logits, cache = decode_forward(params, cache, row_tokens(rows, prev),
-                                       rows[:, 1], rows[:, 3:], config,
-                                       block_size)
-        return sample_next(logits[:, -1, :], sub, temps), cache, \
-            expert_stats, key
-
-    return decode_step
-
-
-def make_engine_prefill_chunk(config, block_size: int, chunk_len: int,
-                              chunk_forward=chunk_forward):
-    """The prefill program (one a table width the engine hands it), on
-    ``pack_prefill_chunk``'s array; only the logits of ``last_idx`` are
-    computed. ``chunk_forward``: as ``make_engine_decode_step``'s."""
-    positions_at, table_at = 3 + chunk_len, 3 + 2 * chunk_len
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_chunk(params, cache, chunk, expert_stats=None):
-        logits, cache = chunk_forward(
-            params, cache, chunk[None, 3:positions_at],
-            chunk[None, positions_at:table_at], chunk[None, table_at:],
-            chunk[2], chunk[0], config, block_size, logits_at=chunk[1])
-        return logits[0], cache, expert_stats
-
-    return prefill_chunk
+                    "win_v": win_v, "ssm": ssm, "conv": conv}, None, None
 
 
 FAMILY = Family(
@@ -336,10 +263,7 @@ FAMILY = Family(
     init_params=lambda config, key: phi.init_params(config, key,
                                                     config.dtype),
     init_cache=init_cache,
-    make_engine_decode_step=make_engine_decode_step,
-    make_engine_prefill_chunk=make_engine_prefill_chunk,
-    pack_decode_rows=pack_decode_rows,
-    pack_prefill_chunk=pack_prefill_chunk,
+    forward=forward,
     ring_positions=ring_positions,
     recurrent=True,
 )

@@ -28,32 +28,20 @@ their own.
   stack is the leading dense layers, then the expert layers (two scans
   over ``params["dense"]`` and ``params["sparse"]``).
 
-One token a row a pass, a row ends by its count: the packers, the row
-bookkeeping and the step in flight (``ahead``) are the dense model's.
-The two programs are traced under its names (``decode_step``,
-``prefill_chunk``) and take ONE host array each.
+One token a row a pass, a row ends by its count: the row bookkeeping
+and the step in flight (``ahead``) are the dense model's, the two
+programs ``model.py``'s, over ``forward`` here.
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import moe, xing
 from ray_tpu.models.llama import rms_norm
 from ray_tpu.ops.paged_latent_attention import paged_latent_attention
-from ray_tpu.serve.llm_engine.model import (
-    Family,
-    _accumulated,
-    pack_decode_rows,
-    pack_prefill_chunk,
-    row_beside_zeros,
-    row_tokens,
-    sample_next,
-)
+from ray_tpu.serve.llm_engine.model import Family, row_beside_zeros
 
 F32 = jnp.float32
 
@@ -100,13 +88,20 @@ def _attention(w: dict, x, positions, pool, li, tables, config,
 
 
 def forward(params: dict, cache: dict, tokens, positions, tables, config,
-            block_size: int, *, absorbed: bool, n_valid=None,
-            logits_at=None):
+            block_size: int, *, slot=None, n_valid=None, logits_at=None,
+            absorbed: "bool | None" = None):
     """tokens and positions [B, T], tables [B, M] -> (logits [B, T, V]
     float32, or [B, V] of position ``logits_at`` alone; the cache;
     the expert counters of this pass; the chosen experts [sparse layers,
-    B, T, k], which only a check reads). The tokens counted are a
-    chunk's first ``n_valid``, or (decode) the rows past position 0."""
+    B, T, k], which only a check reads). Without ``n_valid`` it is a
+    decode step (``T == 1``, a row at position 0 inactive), absorbed;
+    with it a chunk whose first ``n_valid`` positions are real,
+    expanded; the tokens counted are those, or the rows past position
+    0. ``slot`` is of no use to it: no row owns a cache. ``absorbed`` is
+    a keyword only because the expanded step is the reference of the
+    absorbed one (``tests/test_xing.py``)."""
+    if absorbed is None:
+        absorbed = n_valid is None
     dtype, eps = config.dtype, config.rms_norm_eps
     x = params["embed"]["tokens"][tokens].astype(F32)
     streams = jnp.broadcast_to(x[:, :, None, :],
@@ -166,48 +161,9 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
     return logits, {"latent": pool}, counts, routing
 
 
-def make_engine_decode_step(config, block_size: int):
-    """The ONE decode program, absorbed, on ``model.pack_decode_rows``'
-    array, the carried sampling key and the step before's tokens
-    ``prev`` (``model.row_tokens``); a row at position 0 is inactive."""
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
-        key, sub = jax.random.split(key)
-        temps = lax.bitcast_convert_type(rows[:, 2], F32)
-        logits, cache, counts, _ = forward(
-            params, cache, row_tokens(rows, prev), rows[:, 1:2],
-            rows[:, 3:], config, block_size, absorbed=True)
-        return sample_next(logits[:, -1, :], sub, temps), cache, \
-            _accumulated(expert_stats, counts), key
-
-    return decode_step
-
-
-def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
-    """The prefill program (one a table width the engine hands it),
-    expanded, on ``model.pack_prefill_chunk``'s array; only the logits
-    of ``last_idx`` are computed."""
-    positions_at, table_at = 2 + chunk_len, 2 + 2 * chunk_len
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_chunk(params, cache, chunk, expert_stats=None):
-        logits, cache, counts, _ = forward(
-            params, cache, chunk[None, 2:positions_at],
-            chunk[None, positions_at:table_at], chunk[None, table_at:],
-            config, block_size, absorbed=False, n_valid=chunk[0],
-            logits_at=chunk[1])
-        return logits[0], cache, _accumulated(expert_stats, counts)
-
-    return prefill_chunk
-
-
 FAMILY = Family(
     init_params=xing.init_params,
     init_cache=init_cache,
-    make_engine_decode_step=make_engine_decode_step,
-    make_engine_prefill_chunk=make_engine_prefill_chunk,
-    pack_decode_rows=pack_decode_rows,
-    pack_prefill_chunk=pack_prefill_chunk,
+    forward=forward,
     reads_by_row=True,
 )

@@ -47,35 +47,27 @@ cache dict, the state, the convolutions' inputs and the expert
 counters. An expert layer adds the chosen experts it HOLDS
 (``config.held``); the counters count those.
 
-One token a row a pass, a row ends by its count: the packers, the row
-bookkeeping and the step in flight (``ahead``) are the dense model's,
-the prefill chunk's array the hybrid family's (it carries the row
-slot). The two programs are traced under the dense model's names
-(``decode_step``, ``prefill_chunk``) and take ONE host array each.
+One token a row a pass, a row ends by its count: the row bookkeeping
+and the step in flight (``ahead``) are the dense model's, the two
+programs ``model.py``'s, over ``forward`` here (the prefill chunk's
+array carries the row slot, as every ``recurrent`` family's).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import kimi_linear as kimi
 from ray_tpu.models import moe, solar_open2, xing
 from ray_tpu.models.llama import rms_norm
-from ray_tpu.serve.llm_engine.hybrid import pack_prefill_chunk
 from ray_tpu.serve.llm_engine.latent import _attention
 from ray_tpu.serve.llm_engine.model import (
     Family,
-    _accumulated,
-    pack_decode_rows,
     paged_attention,
     row_beside_zeros,
-    row_tokens,
-    sample_next,
 )
 
 F32 = jnp.float32
@@ -210,54 +202,10 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
     return logits, {**pool, "kda": state, "conv": conv}, counts, routing
 
 
-def make_engine_decode_step(config, block_size: int):
-    """The ONE decode program (the pool read by row through the tables:
-    a latent pool absorbed, key and value pools by the grouped queries'
-    kernel; the state where it lies),
-    on ``model.pack_decode_rows``' array (row ``i`` is row slot ``i``),
-    the carried sampling key and the step before's tokens ``prev``
-    (``model.row_tokens``)."""
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
-        key, sub = jax.random.split(key)
-        temps = lax.bitcast_convert_type(rows[:, 2], F32)
-        logits, cache, counts, _ = forward(
-            params, cache, row_tokens(rows, prev), rows[:, 1:2],
-            rows[:, 3:], config, block_size)
-        return sample_next(logits[:, -1, :], sub, temps), cache, \
-            _accumulated(expert_stats, counts), key
-
-    return decode_step
-
-
-def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
-    """The prefill program (one a table width the engine hands it): the
-    full layers over their row's gathered view (latent layers expanded),
-    the KDA layers in the chunkwise form, on
-    ``hybrid.pack_prefill_chunk``'s array; only the logits of
-    ``last_idx`` are computed."""
-    positions_at, table_at = 3 + chunk_len, 3 + 2 * chunk_len
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_chunk(params, cache, chunk, expert_stats=None):
-        logits, cache, counts, _ = forward(
-            params, cache, chunk[None, 3:positions_at],
-            chunk[None, positions_at:table_at], chunk[None, table_at:],
-            config, block_size, slot=chunk[2], n_valid=chunk[0],
-            logits_at=chunk[1])
-        return logits[0], cache, _accumulated(expert_stats, counts)
-
-    return prefill_chunk
-
-
 FAMILY = Family(
     init_params=kimi.init_params,
     init_cache=init_cache,
-    make_engine_decode_step=make_engine_decode_step,
-    make_engine_prefill_chunk=make_engine_prefill_chunk,
-    pack_decode_rows=pack_decode_rows,
-    pack_prefill_chunk=pack_prefill_chunk,
+    forward=forward,
     recurrent=True,
     reads_by_row=True,
 )

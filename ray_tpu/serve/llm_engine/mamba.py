@@ -37,18 +37,13 @@ not scanned: a period's slice of them would be a copy of seven layers).
 The carry is the residual stream in float32, the pools, the state and
 the convolutions' inputs.
 
-One token a row a pass, a row ends by its count: the packers, the row
-bookkeeping and the step in flight are the dense model's, the prefill
-chunk's array AND the two programs the hybrid family's (its
-``make_engine_decode_step`` and ``make_engine_prefill_chunk`` over this
-module's ``decode_forward`` and ``chunk_forward``), traced under the
-dense model's names (``decode_step``, ``prefill_chunk``), ONE host array
-each.
+One token a row a pass, a row ends by its count: the row bookkeeping
+and the step in flight are the dense model's, the two programs
+``model.py``'s, over ``forward`` here (the prefill chunk's array carries
+the row slot, as every ``recurrent`` family's).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -57,11 +52,8 @@ from jax import lax
 from ray_tpu.models import jamba, moe
 from ray_tpu.models import phi4flash as phi
 from ray_tpu.models.llama import rms_norm
-from ray_tpu.serve.llm_engine import hybrid
-from ray_tpu.serve.llm_engine.hybrid import pack_prefill_chunk
 from ray_tpu.serve.llm_engine.model import (
     Family,
-    pack_decode_rows,
     paged_attention,
     row_beside_zeros,
 )
@@ -86,8 +78,8 @@ def init_cache(config, num_blocks: int, block_size: int, rows: int,
 def forward(params: dict, cache: dict, tokens, positions, tables, config,
             block_size: int, *, slot=None, n_valid=None, logits_at=None):
     """tokens and positions [B, T], tables [B, M] -> (logits [B, T, V]
-    float32, or [B, V] of position ``logits_at`` alone; the cache).
-    Without ``n_valid`` it is a decode step: ``T == 1``, row ``i`` is row
+    float32, or [B, V] of position ``logits_at`` alone; the cache; None
+    twice: no experts). Without ``n_valid`` it is a decode step: ``T == 1``, row ``i`` is row
     slot ``i``, a row at position 0 is inactive. With it, one request's
     chunk in row slot ``slot``, its first ``n_valid`` positions real,
     from a zero state where it starts at position 0."""
@@ -163,24 +155,8 @@ def forward(params: dict, cache: dict, tokens, positions, tables, config,
     logits = jnp.moveaxis(logits, 0, -1)
     if logits_at is not None:
         logits = logits[:, 0]
-    return logits, {"k": pool_k, "v": pool_v, "ssm": state, "conv": conv}
-
-
-def decode_forward(params, cache, tokens, positions, tables, config,
-                   block_size: int):
-    """tokens [B, 1], positions [B], tables [B, M]; a row at position 0
-    is inactive. Returns (logits [B, 1, V], cache)."""
-    return forward(params, cache, tokens, positions[:, None], tables,
-                   config, block_size)
-
-
-def chunk_forward(params, cache, tokens, positions, table, slot, n_valid,
-                  config, block_size: int, logits_at=None):
-    """tokens and positions [1, C], table [1, M]; the chunk's first
-    ``n_valid`` positions are real. Returns (logits, cache)."""
-    return forward(params, cache, tokens, positions, table, config,
-                   block_size, slot=slot, n_valid=n_valid,
-                   logits_at=logits_at)
+    return logits, {"k": pool_k, "v": pool_v, "ssm": state,
+                    "conv": conv}, None, None
 
 
 FAMILY = Family(
@@ -189,14 +165,7 @@ FAMILY = Family(
     init_params=lambda config, key: jamba.init_params(config, key,
                                                       config.dtype),
     init_cache=init_cache,
-    # The hybrid family's two programs over this module's forwards: the
-    # same host arrays, the same names in a trace.
-    make_engine_decode_step=functools.partial(
-        hybrid.make_engine_decode_step, decode_forward=decode_forward),
-    make_engine_prefill_chunk=functools.partial(
-        hybrid.make_engine_prefill_chunk, chunk_forward=chunk_forward),
-    pack_decode_rows=pack_decode_rows,
-    pack_prefill_chunk=pack_prefill_chunk,
+    forward=forward,
     recurrent=True,
     reads_by_row=True,
 )

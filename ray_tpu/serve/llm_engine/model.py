@@ -38,8 +38,9 @@ reading and writing the PAGED pool:
   (params/pool sharded via ``ray_tpu.parallel.sharding``).
 
 A configuration of another family (``family(config)``, the one place it
-is looked up) brings its own forward, cache and packers under the same
-two program names and the same one-array-a-pass contract:
+is looked up) brings its own cache and ONE forward of ``Family``'s
+signature; the two programs and their one-array-a-pass contract are
+written once, here, over it:
 ``hybrid.py`` for layers of several kinds over three caches,
 ``latent.py`` for latent attention over a pool of one vector a position
 (absorbed in the decode step, expanded in the prefill chunk) under a
@@ -104,27 +105,141 @@ def _lead_one(req, max_tokens: int):
     return 1
 
 
+# The engine's two programs and the packers of their host arrays,
+# written ONCE over whatever forward the configuration's family names
+# (``Family.forward``). Everything a pass decides on the host is in ONE
+# int32 array, so that a pass is one call into JAX (the transfer rides
+# the dispatch), and the sampling key is carried on the device. They are
+# traced under the names ``decode_step`` and ``prefill_chunk``, by which
+# the benchmark's readers find them in a trace. Each array's layout is
+# known to its packer and its program, here, alone.
+
+
+def pack_decode_rows(batch: int, width: int, active,
+                     slots=None) -> np.ndarray:
+    """The decode program's host array, int32 ``[batch, 3 + width]``:
+    per row its token, position, temperature (the float32's bits) and
+    block table, from ``active``'s ``(token, position, temperature,
+    table)``, each in the row ``slots`` gives it (the engine: the
+    request's row slot; without ``slots`` in order); the other rows stay
+    zero (inactive). A token of ``PREV`` stands for the one the step
+    before made for that row, which the host has not read."""
+    rows = np.zeros((batch, 3 + width), dtype=np.int32)
+    temps = rows[:, 2].view(np.float32)
+    for i, (token, position, temperature, table) in zip(
+            slots or range(batch), active):
+        rows[i, 0], rows[i, 1], temps[i] = token, position, temperature
+        rows[i, 3:3 + len(table)] = table
+    return rows
+
+
+def row_tokens(rows, prev=None):
+    """The tokens ``[B, 1]`` of ``pack_decode_rows``' array, a row's
+    entry of ``prev`` ``[B]`` where its token is ``PREV``."""
+    tokens = rows[:, :1]
+    if prev is None:
+        return tokens
+    return jnp.where(tokens < 0, prev[:, None], tokens)
+
+
+def make_engine_decode_step(config, block_size: int):
+    """The ONE batched decode program of the configuration's family:
+    every busy row advances one token through a shared ``[B, 1]`` step
+    of its ``forward`` (row ``i`` is row slot ``i``; an inactive row
+    carries an all-zero table and position 0: scratch writes, no state
+    moved, a discarded sample). On ``pack_decode_rows``' array and the
+    carried sampling key, which is split here and comes back as the
+    fourth result (not donated: a failed step leaves the caller's key
+    usable). ``prev`` is the first result of the step before, ``[B]`` on
+    the device and not donated either: a row whose token is ``PREV``
+    takes its own entry of it. Without ``prev`` every token is the
+    array's. ``expert_stats``: ``moe.init_stats()`` of a sparse model,
+    or None."""
+    forward = family(config).forward
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def decode_step(params, cache, rows, key, expert_stats=None, prev=None):
+        key, sub = jax.random.split(key)
+        temps = lax.bitcast_convert_type(rows[:, 2], jnp.float32)
+        logits, cache, counts, _ = forward(
+            params, cache, row_tokens(rows, prev), rows[:, 1:2],
+            rows[:, 3:], config, block_size)
+        return sample_next(logits[:, -1, :], sub, temps), cache, \
+            _accumulated(expert_stats, counts), key
+
+    return decode_step
+
+
+def _chunk_layout(recurrent: bool, chunk_len: int):
+    """Where the prefill program's host array holds the chunk's tokens,
+    their positions and the block table: behind ``n_valid``,
+    ``last_idx`` and, where a row owns a state, its row slot."""
+    tokens_at = 2 + recurrent
+    return tokens_at, tokens_at + chunk_len, tokens_at + 2 * chunk_len
+
+
+def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
+    """The prefill program of the configuration's family (one a table
+    width the engine hands it): a fixed-length chunk of one request's
+    prompt through its ``forward``, on ``Family.pack_prefill_chunk``'s
+    array. Only the ``last_idx`` logits row is computed, and only the
+    final chunk's is consumed (the first generated token)."""
+    of = family(config)
+    tokens_at, positions_at, table_at = _chunk_layout(of.recurrent, chunk_len)
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def prefill_chunk(params, cache, chunk, expert_stats=None):
+        logits, cache, counts, _ = of.forward(
+            params, cache, chunk[None, tokens_at:positions_at],
+            chunk[None, positions_at:table_at], chunk[None, table_at:],
+            config, block_size, slot=chunk[2] if of.recurrent else None,
+            n_valid=chunk[0], logits_at=chunk[1])
+        return logits[0], cache, _accumulated(expert_stats, counts)
+
+    return prefill_chunk
+
+
 @dataclasses.dataclass(frozen=True)
 class Family:
     """What the engine asks of a model family, chosen ONCE from the
-    configuration by ``family``: its weights, its cache (one dict,
-    donated through every step), its two programs and the packers of
-    their host arrays. ``ring_positions(config, block_size, chunk_len)``
-    is how many positions a row of its window cache holds (0: it has
-    none); ``recurrent``: a row owns a state slot that a request's first
-    chunk resets; ``reads_by_row``: its decode step reads the pool
-    through the tables a row at a time, each busy row the whole pages
-    that hold its positions before the step's own and its fresh entry
-    beside them (``ops/paged_latent_attention.py`` a latent pool,
+    configuration by ``family``. A family's module supplies its weights
+    (``init_params``), its cache (``init_cache``: one dict, donated
+    through every step) and ONE forward over it, of the one signature
+
+        forward(params, cache, tokens [B, T], positions [B, T],
+                tables [B, M], config, block_size, *, slot=None,
+                n_valid=None, logits_at=None)
+            -> (logits, cache, expert counts, routing)
+
+    a decode step where ``n_valid`` is None (``T == 1``, row ``i`` is
+    row slot ``i``, a row at position 0 inactive), else one request's
+    chunk in row slot ``slot`` whose first ``n_valid`` positions are
+    real; logits [B, T, V] float32, or [B, V] of position ``logits_at``
+    alone; counts and routing None for a dense model. The two programs
+    and the packers of their host arrays are this module's, over that
+    forward: no family writes them, but one whose decode PASS is another
+    (diffusion over blocks) names that pass's program and packer.
+
+    ``ring_positions(config, block_size, chunk_len)`` is how many
+    positions a row of its window cache holds (0: it has none);
+    ``recurrent``: a row owns a state slot that a request's first chunk
+    resets, so the chunk's host array carries the slot;
+    ``reads_by_row``: its decode step reads the pool through the tables
+    a row at a time, each busy row the whole pages that hold its
+    positions before the step's own and its fresh entry beside them
+    (``ops/paged_latent_attention.py`` a latent pool,
     ``ops/paged_kv_attention.py`` key and value pools), where the others
     gather the step's table width for every row: what
     ``kv_positions_read`` counts."""
     init_params: Callable
     init_cache: Callable    # (config, num_blocks, block_size, rows, chunk)
-    make_engine_decode_step: Callable
-    make_engine_prefill_chunk: Callable
-    pack_decode_rows: Callable
-    pack_prefill_chunk: Callable
+    forward: Callable
+    # This module's, unless the family's decode PASS is another. A maker
+    # is handed the configuration and finds the family by it; the
+    # chunk's packer is handed none, and is the method below.
+    make_engine_decode_step: Callable = make_engine_decode_step
+    make_engine_prefill_chunk: Callable = make_engine_prefill_chunk
+    pack_decode_rows: Callable = pack_decode_rows
     ring_positions: Callable = lambda config, block_size, chunk_len: 0
     recurrent: bool = False
     reads_by_row: bool = False
@@ -150,6 +265,25 @@ class Family:
     # row one pass on.
     ahead: Callable = lambda req: True
     lead: Callable = _lead_one
+
+    def pack_prefill_chunk(self, chunk_len: int, width: int, tokens,
+                           start: int, table, slot: int = 0) -> np.ndarray:
+        """The prefill program's host array, int32 ``[2 + 2 * chunk_len
+        + width]``, or 3 + for a ``recurrent`` family: ``n_valid``,
+        ``last_idx``, for such a family the request's row ``slot`` (of
+        no use to a model without a per-row cache), then the chunk's
+        ``tokens`` (at most ``chunk_len``, at global positions
+        ``start...``), their positions and the request's block table,
+        each zero-padded."""
+        tokens_at, positions_at, table_at = _chunk_layout(self.recurrent,
+                                                          chunk_len)
+        n = len(tokens)
+        chunk = np.zeros((table_at + width,), dtype=np.int32)
+        chunk[:tokens_at] = (n, n - 1, slot)[:tokens_at]
+        chunk[tokens_at:tokens_at + n] = tokens
+        chunk[positions_at:positions_at + n] = np.arange(start, start + n)
+        chunk[table_at:table_at + len(table)] = table
+        return chunk
 
 
 def family(config) -> Family:
@@ -426,16 +560,21 @@ def row_beside_zeros(x: jax.Array, at: jax.Array) -> jax.Array:
 
 def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
                    positions: jax.Array, block_tables: jax.Array,
-                   config, block_size: int,
+                   config, block_size: int, *, slot=None,
                    n_valid: "jax.Array | None" = None,
-                   busy: "jax.Array | None" = None,
                    logits_at: "jax.Array | None" = None,
-                   by_row: bool = False):
-    """Shared prefill/decode forward over the paged pool. Returns
+                   busy: "jax.Array | None" = None,
+                   by_row: "bool | None" = None):
+    """The paged family's ``Family.forward``, prefill and decode, over
+    the paged pool. Returns
     (logits [B, T, V] f32, or [B, V] of position ``logits_at`` alone;
     updated pool, expert counts, routing). The pool is part of the
     scan's carry, so every layer updates the one (donated) buffer.
-    ``by_row`` (static) is ``paged_attention``'s, for a decode step.
+    ``slot`` is of no use to it: no row owns a cache. ``by_row``
+    (static) is ``paged_attention``'s: a decode step of a family that
+    ``reads_by_row`` (the block pass gathers because its family says
+    so), and a keyword only because the gathered step is the reference
+    of the one by row (``tests/test_paged_kv_attention.py``).
 
     The feed-forward is the configuration's: dense SwiGLU, or the
     routed experts. For those, ``counts`` is ``moe.routing_counts``
@@ -446,6 +585,8 @@ def _forward_paged(params: dict, pool: dict, tokens: jax.Array,
     past 0: an inactive row carries position 0, and a request's next
     token never does (a block in flight can start at 0, so its program
     says which rows are ``busy`` [B])."""
+    if by_row is None:
+        by_row = n_valid is None and family(config).reads_by_row
     x = params["embed"]["tokens"].astype(config.dtype)[tokens]
     sparse = config.num_experts > 0
     counts, layers = None, params["layers"]
@@ -507,7 +648,8 @@ def sample_next(last, key, temps):
 
 
 def _decode_body(config, block_size: int):
-    """One decode step, traced by whichever program wraps it."""
+    """One decode step on its arguments apart, for ``make_decode_step``
+    (the engine's program is ``make_engine_decode_step``)."""
 
     def decode_step(params, pool, tokens, positions, block_tables, key,
                     temps, expert_stats=None):
@@ -515,7 +657,7 @@ def _decode_body(config, block_size: int):
         # expert_stats: moe.init_stats() of a sparse model, or None.
         logits, pool, counts, _ = _forward_paged(
             params, pool, tokens, positions[:, None], block_tables,
-            config, block_size, by_row=PAGED.reads_by_row)
+            config, block_size)
         return sample_next(logits[:, -1, :], key, temps), pool, \
             _accumulated(expert_stats, counts)
 
@@ -523,7 +665,8 @@ def _decode_body(config, block_size: int):
 
 
 def _prefill_body(config, block_size: int):
-    """One prefill chunk, traced by whichever program wraps it."""
+    """One prefill chunk on its arguments apart, for
+    ``make_prefill_chunk`` (the engine's: ``make_engine_prefill_chunk``)."""
 
     def prefill_chunk(params, pool, tokens, positions, block_table,
                       n_valid, last_idx, expert_stats=None):
@@ -553,95 +696,6 @@ def make_prefill_chunk(config, block_size: int):
     table; only the ``last_idx`` logits row is computed, and only the
     final chunk's is consumed (the first generated token)."""
     return jax.jit(_prefill_body(config, block_size), donate_argnums=(1,))
-
-
-# The engine's programs: the same two bodies, with everything a pass
-# decides on the host in ONE int32 array, so that a pass is one call
-# into JAX (the transfer rides the dispatch), and with the sampling key
-# carried on the device. They are traced under the plain programs'
-# names, by which the benchmark's readers find them in a trace. Each
-# array's layout is known to its packer and its program, here, alone.
-
-
-def pack_decode_rows(batch: int, width: int, active,
-                     slots=None) -> np.ndarray:
-    """The decode program's host array, int32 ``[batch, 3 + width]``:
-    per row its token, position, temperature (the float32's bits) and
-    block table, from ``active``'s ``(token, position, temperature,
-    table)``, each in the row ``slots`` gives it (the engine: the
-    request's row slot; without ``slots`` in order); the other rows stay
-    zero (inactive). A token of ``PREV`` stands for the one the step
-    before made for that row, which the host has not read."""
-    rows = np.zeros((batch, 3 + width), dtype=np.int32)
-    temps = rows[:, 2].view(np.float32)
-    for i, (token, position, temperature, table) in zip(
-            slots or range(batch), active):
-        rows[i, 0], rows[i, 1], temps[i] = token, position, temperature
-        rows[i, 3:3 + len(table)] = table
-    return rows
-
-
-def row_tokens(rows, prev=None):
-    """The tokens ``[B, 1]`` of ``pack_decode_rows``' array, a row's
-    entry of ``prev`` ``[B]`` where its token is ``PREV``."""
-    tokens = rows[:, :1]
-    if prev is None:
-        return tokens
-    return jnp.where(tokens < 0, prev[:, None], tokens)
-
-
-def make_engine_decode_step(config, block_size: int):
-    """``make_decode_step`` as the engine calls it: on
-    ``pack_decode_rows``' array, and on the carried sampling key, which
-    is split here exactly as the host used to split it and comes back
-    as the fourth result (not donated: a failed step leaves the
-    caller's key usable). ``prev`` is the first result of the step
-    before, ``[B]`` on the device and not donated either: a row whose
-    token is ``PREV`` takes its own entry of it. Without ``prev`` every
-    token is the array's."""
-    body = _decode_body(config, block_size)
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def decode_step(params, pool, rows, key, expert_stats=None, prev=None):
-        key, sub = jax.random.split(key)
-        temps = lax.bitcast_convert_type(rows[:, 2], jnp.float32)
-        return (*body(params, pool, row_tokens(rows, prev), rows[:, 1],
-                      rows[:, 3:], sub, temps, expert_stats), key)
-
-    return decode_step
-
-
-def pack_prefill_chunk(chunk_len: int, width: int, tokens, start: int,
-                       table, slot: int = 0) -> np.ndarray:
-    """The prefill program's host array, int32 ``[2 + 2 * chunk_len +
-    width]``: ``n_valid``, ``last_idx``, then the chunk's ``tokens``
-    (at most ``chunk_len``, at global positions ``start...``), their
-    positions and the request's block table, each zero-padded. The
-    request's row ``slot`` is of no use to a model without a per-row
-    cache."""
-    n = len(tokens)
-    chunk = np.zeros((2 + 2 * chunk_len + width,), dtype=np.int32)
-    chunk[0], chunk[1] = n, n - 1
-    chunk[2:2 + n] = tokens
-    chunk[2 + chunk_len:2 + chunk_len + n] = np.arange(start, start + n)
-    chunk[2 + 2 * chunk_len:2 + 2 * chunk_len + len(table)] = table
-    return chunk
-
-
-def make_engine_prefill_chunk(config, block_size: int, chunk_len: int):
-    """``make_prefill_chunk`` as the engine calls it: on
-    ``pack_prefill_chunk``'s array."""
-    body = _prefill_body(config, block_size)
-    positions_at, table_at = 2 + chunk_len, 2 + 2 * chunk_len
-
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_chunk(params, pool, chunk, expert_stats=None):
-        return body(params, pool, chunk[None, 2:positions_at],
-                    chunk[None, positions_at:table_at],
-                    chunk[None, table_at:], chunk[0], chunk[1],
-                    expert_stats)
-
-    return prefill_chunk
 
 
 # ------------------------------------------------------------------------
@@ -859,10 +913,7 @@ PAGED = Family(
     init_params=llama.init_params,
     init_cache=lambda config, num_blocks, block_size, rows, chunk_len:
     PagedKVCache.init_pool(config, num_blocks, block_size),
-    make_engine_decode_step=make_engine_decode_step,
-    make_engine_prefill_chunk=make_engine_prefill_chunk,
-    pack_decode_rows=pack_decode_rows,
-    pack_prefill_chunk=pack_prefill_chunk,
+    forward=_forward_paged,
     reads_by_row=True,
     lay_params=lay_for_serving,
 )

@@ -91,35 +91,22 @@ def step_logits(engine):
     step's program samples from."""
     import jax
 
-    from ray_tpu.serve.llm_engine import hybrid, latent
-    from ray_tpu.serve.llm_engine import model as paged_model
-
     config, block = engine.config, engine.block_size
-    if paged_model.family(config) is hybrid.FAMILY:
-        def logits(params, cache, rows):
-            return hybrid.decode_forward(
-                params, cache, rows[:, :1], rows[:, 1], rows[:, 3:], config,
-                block)[0][:, 0]
-    elif paged_model.family(config) is latent.FAMILY:
-        def logits(params, cache, rows):
-            return latent.forward(
-                params, cache, rows[:, :1], rows[:, 1:2], rows[:, 3:],
-                config, block, absorbed=True)[0][:, 0]
-    elif getattr(config, "block_length", 0):
+    forward = engine._family.forward
+    if getattr(config, "block_length", 0):
         size = config.block_length
 
         def logits(params, cache, rows):
             tokens = rows[:, 6:6 + size]
-            return paged_model._forward_paged(
+            return forward(
                 params, cache, jax.numpy.where(
                     tokens < 0, config.mask_token_id, tokens),
                 rows[:, :1] + jax.numpy.arange(size), rows[:, 6 + size:],
                 config, block, busy=rows[:, 5] != 0)[0]
     else:
         def logits(params, cache, rows):
-            return paged_model._forward_paged(
-                params, cache, rows[:, :1], rows[:, 1:2], rows[:, 3:],
-                config, block, by_row=True)[0][:, 0]
+            return forward(params, cache, rows[:, :1], rows[:, 1:2],
+                           rows[:, 3:], config, block)[0][:, 0]
     return jax.jit(logits)
 
 

@@ -38,9 +38,10 @@ def test_hybrid_prefill_chunk_at_each_table_width_on_v5e(v5e_chip, width):
     cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
         config, 1 + rows * table, block, rows, chunk)))
     assert cache["win_k"].shape[2] == 512 + chunk + block == 656
-    compiled = hybrid.make_engine_prefill_chunk(config, block, chunk).lower(
+    family = hybrid.FAMILY
+    compiled = family.make_engine_prefill_chunk(config, block, chunk).lower(
         params, cache,
-        on_chip(hybrid.pack_prefill_chunk(chunk, width, (), 0, (), 0),
+        on_chip(family.pack_prefill_chunk(chunk, width, (), 0, (), 0),
                 jnp.int32), None).compile()
     memory = compiled.memory_analysis()
     cache_bytes = sum(math.prod(c.shape) * c.dtype.itemsize
@@ -82,9 +83,10 @@ def test_hybrid_decode_step_with_prev_at_each_table_width_on_v5e(v5e_chip,
     cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
         config, 1 + rows * table, block, rows, chunk)))
     args = (params, cache,
-            on_chip(hybrid.pack_decode_rows(rows, width, ()), jnp.int32),
+            on_chip(hybrid.FAMILY.pack_decode_rows(rows, width, ()),
+                    jnp.int32),
             jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None)
-    step = hybrid.make_engine_decode_step(config, block)
+    step = hybrid.FAMILY.make_engine_decode_step(config, block)
     prev = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
     alias, temp, arguments = _memory_of(step.lower(*args, prev).compile())
     alias_before, temp_before, arguments_before = _memory_of(
@@ -123,9 +125,10 @@ def test_hybrid_decode_step_at_each_table_width_on_v5e(v5e_chip, width):
         config, jax.random.PRNGKey(0))))
     cache = on_chip(jax.eval_shape(lambda: hybrid.init_cache(
         config, 1 + rows * table, block, rows, chunk)))
-    compiled = hybrid.make_engine_decode_step(config, block).lower(
+    compiled = hybrid.FAMILY.make_engine_decode_step(config, block).lower(
         params, cache,
-        on_chip(hybrid.pack_decode_rows(rows, width, ()), jnp.int32),
+        on_chip(hybrid.FAMILY.pack_decode_rows(rows, width, ()),
+                jnp.int32),
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip),
         None).compile()
     memory = compiled.memory_analysis()

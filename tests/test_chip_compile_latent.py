@@ -60,9 +60,10 @@ def test_latent_programs_at_each_table_width_on_v5e(v5e_chip, width,
     pool = (2, 1 + rows * table, block, 640)
     assert cache["latent"].shape == pool
     pool_bytes, shape = math.prod(pool) * 2, ",".join(map(str, pool))
-    prefill = latent.make_engine_prefill_chunk(config, block, chunk).lower(
+    family = latent.FAMILY
+    prefill = family.make_engine_prefill_chunk(config, block, chunk).lower(
         params, cache,
-        on_chip(latent.FAMILY.pack_prefill_chunk(chunk, width, (), 0, (), 0),
+        on_chip(family.pack_prefill_chunk(chunk, width, (), 0, (), 0),
                 jnp.int32), None).compile()
     memory = prefill.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes
@@ -77,9 +78,9 @@ def test_latent_programs_at_each_table_width_on_v5e(v5e_chip, width,
     assert_experts_reach_the_kernel_whole(text, (1, 64, 3584, 1024), 1)
     if width < table:
         return      # the engine builds no decode step there
-    step = latent.make_engine_decode_step(config, block).lower(
+    step = family.make_engine_decode_step(config, block).lower(
         params, cache,
-        on_chip(latent.FAMILY.pack_decode_rows(rows, width, ()), jnp.int32),
+        on_chip(family.pack_decode_rows(rows, width, ()), jnp.int32),
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=v5e_chip), None,
         jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=v5e_chip)
     ).compile()

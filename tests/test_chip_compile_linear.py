@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 from v5e_compile import (  # noqa: F401 — the fixtures
     HANDS_ON, assert_experts_reach_the_kernel_whole, compiled_kernels,
-    kv_attention_calls, v5e_chip, v5e_devices)
+    kernel_calls, kv_attention_calls, v5e_chip, v5e_devices)
 
 
 def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip,
@@ -100,12 +100,12 @@ def test_linear_programs_at_the_cells_widths_on_v5e(v5e_chip,
             if state in line and line not in rule
             and not HANDS_ON.search(line)] == []
     assert not any("f32[64,32,128,128]" in line for line in lines)
-    assert "kda_state_update" not in prefill.as_text()
+    assert kernel_calls(prefill.as_text(), "kda_state_update") == []
     calls = [line for line in lines
              if "custom-call(" in line and "paged_latent_attention" in line]
     assert len(calls) == 1                  # the one latent layer
     assert "bf16[1,16385,16,640]" in calls[0] and "bf16[64,640]" in calls[0]
-    assert "paged_latent_attention" not in prefill.as_text()
+    assert kernel_calls(prefill.as_text(), "paged_latent_attention") == []
     # No gathered view of the pool in the step: it reads by row.
     assert re.search(r"\[64,4096,640\]", step.as_text()) is None
 
@@ -181,7 +181,7 @@ def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
         # vocabulary held.
         assert re.search(r"f32\[(64|1,2),24576\]", text)
         assert_experts_reach_the_kernel_whole(text, (1, 40, 4096, 1280), 4)
-        assert "paged_latent_attention" not in text
+        assert kernel_calls(text, "paged_latent_attention") == []
     lines = step.as_text().splitlines()
     rule = [line for line in lines
             if "custom-call(" in line and "kda_state_update" in line]
@@ -195,7 +195,7 @@ def test_solar_programs_at_the_cells_widths_on_v5e(v5e_chip,
     assert [line[:200] for line in lines
             if state in line and line not in rule
             and not HANDS_ON.search(line)] == []
-    assert "kda_state_update" not in prefill.as_text()
+    assert kernel_calls(prefill.as_text(), "kda_state_update") == []
     # The one full layer: the pools whole, the queries grouped, the
     # rows' fresh keys and values; the tables, the lengths, the entry.
     calls = kv_attention_calls(step.as_text())

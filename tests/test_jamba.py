@@ -57,7 +57,7 @@ def prefill(program, params, cache, context, n, table, slot):
     logits (of position ``n - 1``)."""
     logits = None
     for start in range(0, n, CHUNK):
-        chunk = mamba.pack_prefill_chunk(
+        chunk = mamba.FAMILY.pack_prefill_chunk(
             CHUNK, TABLE, list(context[start:min(start + CHUNK, n)]), start,
             table, slot)
         logits, cache, _ = program(params, cache, jnp.asarray(chunk))
@@ -68,10 +68,10 @@ def prefill(program, params, cache, context, n, table, slot):
 def shown(cfg):
     """``mamba.forward`` as the two programs wrap it, showing every
     position's logits, jitted once a configuration."""
-    return (jax.jit(lambda p, c, t, at, table, slot, n: mamba.chunk_forward(
-                p, c, t, at, table, slot, n, cfg, BLOCK)),
-            jax.jit(lambda p, c, t, at, tables: mamba.decode_forward(
-                p, c, t, at, tables, cfg, BLOCK)))
+    return (jax.jit(lambda p, c, t, at, table, slot, n: mamba.forward(
+                p, c, t, at, table, cfg, BLOCK, slot=slot, n_valid=n)[:2]),
+            jax.jit(lambda p, c, t, at, tables: mamba.forward(
+                p, c, t, at[:, None], tables, cfg, BLOCK)[:2]))
 
 
 def logits_through_the_cache(cfg, params, contexts, prefilled, tables):
@@ -80,7 +80,7 @@ def logits_through_the_cache(cfg, params, contexts, prefilled, tables):
     the two programs wrap: {row: [logits of each position from the last
     prefilled on]}."""
     cache = new_cache(cfg)
-    chunk_forward, decode_forward = shown(cfg)
+    shown_chunk, shown_step = shown(cfg)
     got = {}
     for i, (context, n) in enumerate(zip(contexts, prefilled)):
         for start in range(0, n, CHUNK):
@@ -89,7 +89,7 @@ def logits_through_the_cache(cfg, params, contexts, prefilled, tables):
             tokens[0, :m] = context[start:start + m]
             positions = np.zeros((1, CHUNK), np.int32)
             positions[0, :m] = np.arange(start, start + m)
-            logits, cache = chunk_forward(
+            logits, cache = shown_chunk(
                 params, cache, jnp.asarray(tokens), jnp.asarray(positions),
                 jnp.asarray(tables[i:i + 1]), i, m)
         got[i] = [np.asarray(logits[0, m - 1])]
@@ -99,7 +99,7 @@ def logits_through_the_cache(cfg, params, contexts, prefilled, tables):
         for i, (context, n) in enumerate(zip(contexts, prefilled)):
             if n + step < len(context):
                 tokens[i, 0], positions[i] = context[n + step], n + step
-        logits, cache = decode_forward(
+        logits, cache = shown_step(
             params, cache, jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(tables))
         for i in got:
@@ -163,7 +163,7 @@ def test_the_engines_programs_yield_the_references_greedy_tokens(
     token, key, prev = int(np.argmax(logits)), jax.random.PRNGKey(0), None
     for _ in range(6):
         context.append(token)
-        packed = mamba.pack_decode_rows(
+        packed = mamba.FAMILY.pack_decode_rows(
             ROWS, TABLE,
             [(paged_model.PREV if prev is not None else token,
               len(context) - 1, 0.0, table)], slots=[2])
@@ -210,7 +210,7 @@ def test_padding_and_inactive_rows_advance_nothing(cfg, params, programs):
     _, cache = prefill(chunk_program, params, new_cache(cfg), context, 13,
                        tables[0], 0)
     before = jax.tree.map(np.asarray, cache)
-    packed = mamba.pack_decode_rows(
+    packed = mamba.FAMILY.pack_decode_rows(
         ROWS, TABLE, [(int(context[13]), 13, 0.0, tables[3])], slots=[3])
     _, after, _, _ = step(params, cache, jnp.asarray(packed),
                           jax.random.PRNGKey(0))
@@ -224,7 +224,7 @@ def test_padding_and_inactive_rows_advance_nothing(cfg, params, programs):
     _, single = prefill(chunk_program, params, new_cache(cfg), context, 1,
                         tables[0], 0)
     for at in range(1, 13):
-        packed = mamba.pack_decode_rows(
+        packed = mamba.FAMILY.pack_decode_rows(
             ROWS, TABLE, [(int(context[at]), at, 0.0, tables[0])], slots=[0])
         _, single, _, _ = step(params, single, jnp.asarray(packed),
                                jax.random.PRNGKey(0))

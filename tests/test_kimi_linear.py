@@ -288,7 +288,10 @@ def test_the_family_follows_from_the_configuration():
     assert family is linear.FAMILY
     # True together for the first time: a state a row, a pool read by row.
     assert family.recurrent and family.reads_by_row
-    assert family.pack_prefill_chunk is hybrid.pack_prefill_chunk
+    # The chunk's array carries the row slot, as the hybrid family's.
+    assert family.pack_prefill_chunk(4, 3, [5, 6], 8, [1, 2], 3).tolist() \
+        == hybrid.FAMILY.pack_prefill_chunk(4, 3, [5, 6], 8, [1, 2],
+                                            3).tolist()
     assert family.pack_decode_rows is paged_model.PAGED.pack_decode_rows
     assert family.ahead is paged_model.PAGED.ahead
     assert family.make_engine_decode_step(tiny(), BLOCK).__name__ \

@@ -122,7 +122,10 @@ def test_the_family_follows_from_the_configuration():
     assert family.lead is paged_model.PAGED.lead
     assert family.row_of is paged_model.PAGED.row_of
     assert family.pack_decode_rows is paged_model.PAGED.pack_decode_rows
-    assert family.pack_prefill_chunk is paged_model.PAGED.pack_prefill_chunk
+    # No row owns a cache: the chunk's array carries no row slot.
+    assert family.pack_prefill_chunk(4, 3, [5, 6], 8, [1, 2], 3).tolist() \
+        == paged_model.PAGED.pack_prefill_chunk(4, 3, [5, 6], 8, [1, 2],
+                                                3).tolist()
     # The names the benchmark's readers find the programs by.
     assert family.make_engine_decode_step(tiny(), BLOCK).__name__ \
         == "decode_step"

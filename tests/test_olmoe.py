@@ -285,7 +285,7 @@ def test_paged_programs_match_the_reference_logits(dtype_name):
     shown = jax.jit(lambda params, pool, tokens, positions, tables:
                     paged_model._forward_paged(
                         params, pool, tokens, positions[:, None], tables,
-                        cfg, block, by_row=paged_model.PAGED.reads_by_row))
+                        cfg, block))
     stats = moe.init_stats()
 
     got = [[] for _ in prompts]       # logits from the last prompt token on
@@ -428,7 +428,7 @@ def test_engine_programs_equal_the_plain_programs(family, dtype_name):
             tokens[0, :n] = prompt[start:start + n]
             positions = np.zeros((1, chunk), np.int32)
             positions[0, :n] = np.arange(start, start + n)
-            packed = paged_model.pack_prefill_chunk(
+            packed = paged_model.PAGED.pack_prefill_chunk(
                 chunk, width, prompt[start:start + n], start,
                 [int(b) for b in bt[i] if b])
             assert packed.dtype == np.int32 and packed.ndim == 1

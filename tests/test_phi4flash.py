@@ -48,12 +48,12 @@ def programs(cfg):
     params = model.serving_params(cfg, None, seed=11)
     chunk = jax.jit(
         lambda params, cache, tokens, positions, table, slot, n_valid:
-        hybrid.chunk_forward(params, cache, tokens, positions, table, slot,
-                             n_valid, cfg, BLOCK), donate_argnums=(1,))
+        hybrid.forward(params, cache, tokens, positions, table, cfg, BLOCK,
+                       slot=slot, n_valid=n_valid)[:2], donate_argnums=(1,))
     step = jax.jit(
         lambda params, cache, tokens, positions, tables:
-        hybrid.decode_forward(params, cache, tokens, positions, tables, cfg,
-                              BLOCK), donate_argnums=(1,))
+        hybrid.forward(params, cache, tokens, positions[:, None], tables,
+                       cfg, BLOCK)[:2], donate_argnums=(1,))
     return params, chunk, step
 
 
@@ -634,5 +634,5 @@ def test_the_family_is_looked_up_in_one_place():
     rows = model.pack_decode_rows(4, 3, [(7, 5, 0.5, [2, 9])], [2])
     assert rows[2, :2].tolist() == [7, 5] and rows[2, 3:5].tolist() == [2, 9]
     assert not rows[[0, 1, 3]].any()
-    chunk = hybrid.pack_prefill_chunk(4, 3, [5, 6], 8, [1, 2], slot=3)
+    chunk = hybrid.FAMILY.pack_prefill_chunk(4, 3, [5, 6], 8, [1, 2], slot=3)
     assert chunk.tolist() == [2, 1, 3, 5, 6, 0, 0, 8, 9, 0, 0, 1, 2, 0]

@@ -145,8 +145,10 @@ class Passes:
         self.prefill = paged_model.make_prefill_chunk(cfg, BLOCK)
         self.shown = jax.jit(
             lambda params, pool, tokens, positions, table:
+            # A block pass gathers, also under the control's causal
+            # configuration, whose own family would read by row.
             paged_model._forward_paged(params, pool, tokens, positions,
-                                       table, cfg, BLOCK)[:2],
+                                       table, cfg, BLOCK, by_row=False)[:2],
             donate_argnums=(1,))
 
     def prefilled(self, context: list) -> "Passes":

@@ -267,7 +267,10 @@ def test_the_family_follows_from_the_configuration():
     assert dataclasses.replace(
         family, init_params=kimi.init_params) == linear.FAMILY
     assert family.init_params is solar.init_params
-    assert family.pack_prefill_chunk is hybrid.pack_prefill_chunk
+    # The chunk's array carries the row slot, as the hybrid family's.
+    assert family.pack_prefill_chunk(4, 3, [5, 6], 8, [1, 2], 3).tolist() \
+        == hybrid.FAMILY.pack_prefill_chunk(4, 3, [5, 6], 8, [1, 2],
+                                            3).tolist()
     assert family.pack_decode_rows is paged_model.PAGED.pack_decode_rows
     assert family.make_engine_decode_step(tiny(), BLOCK).__name__ \
         == "decode_step"
