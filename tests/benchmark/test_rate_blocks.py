@@ -186,7 +186,8 @@ def test_block_rates_at_both_sizes_by_hand():
 # ------------------------------------------------------ the traffic files
 
 # sha256 of every traffic file at PR 52's commit (65b4760): PR 54 edits
-# ``longdoc-closed.json`` alone.
+# ``longdoc-closed.json`` alone. PR 67 moved ``chat-steady``'s rate and
+# ``rate_why`` and nothing else of it: its sum is PR 67's.
 AT_THE_PARENT = {
     "batch16x512":
         "71331401d023b165f0ec769e5882ba0ddaa1901e65080a6d628f99d3fcde94cb",
@@ -197,7 +198,7 @@ AT_THE_PARENT = {
     "blockgen-closed":
         "317966ddfe88808225fdbd6e8b1676a2b2ba483d8063447ceaf10523589dfa1d",
     "chat-steady":
-        "81113d59e0dac869ea5fd9fe2b4ea20eede0948e9c29f4a8f5f8ac7db21f117c",
+        "8f8ad8b3accb0e4efee8396d0a56ad275024456fb72ab7c7643da29f9f1c9533",
     "longgen-closed":
         "2410eac1ac179a5605cce200a839653ba098f7ac5ae95d90aac4264d280db6d7",
     "reason-closed":
